@@ -24,7 +24,7 @@ def train_step(scale):
 
 def main():
     env = {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
         "HOROVOD_CYCLE_TIME": "0.2",
     }

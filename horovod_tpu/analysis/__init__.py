@@ -10,7 +10,7 @@ package catches them in CI instead of on a TPU reservation.
 Six engines:
 
 * **user-script rules** (``user_rules.py``): HVD001–HVD006, AST checks
-  over training scripts for the deadlock/divergence hazard taxonomy —
+  over training scripts for the deadlock/divergence hazard classes —
   rank/except/jit hazards see through one level of helper functions.
 * **lock-order self-check** (``lock_order.py``): HVD101–HVD103, a
   lock-acquisition-graph deadlock detector over our own threaded modules

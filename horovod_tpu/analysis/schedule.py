@@ -604,14 +604,13 @@ def _entry_tail_distopt_step():
     import jax
     import jax.numpy as jnp
     import optax
-    from ..compat import axis_size
     from ..optim.distributed import fused_tail_reduce_tree
 
     spec = _grads_spec()
     tx = optax.adam(1e-3)
 
     def step(grads, params):
-        present = jnp.ones((axis_size(_AXIS),), jnp.float32)
+        present = jnp.ones((jax.lax.axis_size(_AXIS),), jnp.float32)
         reduced, _state = fused_tail_reduce_tree(
             grads, _AXIS, "hvd_local", op="average",
             threshold_bytes=_THRESHOLD, tail_policy="stale",

@@ -1,6 +1,6 @@
 """Engine 1: AST rules over user training scripts (HVD001–HVD006).
 
-The hazard taxonomy is the classic Horovod one (deadlock from
+The hazard classification is the classic Horovod one (deadlock from
 rank-conditional collectives, divergence from a missing initial
 broadcast, order divergence from unordered submission — see
 docs/analysis.md for the catalog with examples).  Every check is

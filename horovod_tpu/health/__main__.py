@@ -73,8 +73,6 @@ def _smoke() -> int:
     from .evaluate import HealthEvaluator
     from ..optim.distributed import DistributedOptimizer
     from ..runner.rpc import JsonRpcServer
-    from ..runtime import apply_force_platform
-    apply_force_platform()
 
     n = 4
     if len(jax.devices()) < n:

@@ -127,8 +127,6 @@ def _smoke() -> int:
     from ..serving.models import toy_echo_forward
     from ..serving.plane import ServingPlane
     from ..serving.worker import ServingWorker
-    from ..runtime import apply_force_platform
-    apply_force_platform()
 
     plane = ServingPlane(tick_ms=2.0, max_batch=8, seq_buckets="8,16",
                          deadline_ms=0)

@@ -26,35 +26,34 @@ def _disabled() -> bool:
 
 
 def load(auto_build: bool = True):
-    """Import ``_hvd_core``, building it on demand; returns module or None."""
+    """Import ``_hvd_core``, building it on demand; returns module or None.
+
+    Only an artefact at least as new as ``core.cpp`` is imported: a stale
+    one is rebuilt (or, where building is not allowed, left alone) rather
+    than loaded in place of the source that sits beside it.
+    """
     global _core, _attempted
     if _disabled():
         return None
     if _attempted:
         return _core
-    try:
-        from . import _hvd_core  # type: ignore
-        _attempted = True
-        _core = _hvd_core
-        logger.debug("native core loaded: %s", _hvd_core.__file__)
-        return _core
-    except ImportError:
-        pass
-    build_env = os.environ.get(
-        "HOROVOD_TPU_NATIVE_BUILD", "1").strip().lower()
-    if not auto_build or build_env in ("0", "false", "no", "off"):
-        # not a full attempt: leave memoization open so a later caller that
-        # allows building can still succeed
-        return None
+    from . import build
+    if not build.built():
+        build_env = os.environ.get(
+            "HOROVOD_TPU_NATIVE_BUILD", "1").strip().lower()
+        if not auto_build or build_env in ("0", "false", "no", "off"):
+            # not a full attempt: leave memoization open so a later caller
+            # that allows building can still succeed
+            return None
     _attempted = True
     try:
-        from . import build
         if build.build():
             from . import _hvd_core  # type: ignore
             _core = _hvd_core
-            logger.debug("native core built+loaded: %s", _hvd_core.__file__)
+            logger.debug("native core loaded: %s", _hvd_core.__file__)
     except Exception:  # noqa: BLE001 - any failure means Python fallback
-        logger.debug("native core unavailable", exc_info=True)
+        logger.warning("native core unavailable; using the Python control "
+                       "plane", exc_info=True)
         _core = None
     return _core
 

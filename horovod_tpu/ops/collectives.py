@@ -71,14 +71,6 @@ _m_tail_rounds = _metrics.counter(
 # ---------------------------------------------------------------------------
 
 
-def axis_size_p(axis_name: str) -> int:
-    """Static size of a named mapped axis at trace time (the version
-    shim lives in :mod:`horovod_tpu.compat`; this alias keeps the
-    kernel-module call sites stable)."""
-    from ..compat import axis_size
-    return axis_size(axis_name)
-
-
 # ---------------------------------------------------------------------------
 # quantized collective staging (block-scaled int8/fp8 wire formats)
 # ---------------------------------------------------------------------------
@@ -105,7 +97,7 @@ def quantized_sum_scatter_p(flat, axis_name: str, fmt: WireFormat,
     fp32 SUM tile of length ``len(flat)//n`` and ``residual`` is this
     worker's local quantization error (``error_feedback=True``) or None.
     """
-    n = axis_size_p(axis_name)
+    n = lax.axis_size(axis_name)
     q, s = quantize_blocks(flat, fmt)
     residual = None
     if error_feedback:
@@ -156,7 +148,7 @@ def quantized_allreduce_p(x, axis_name: str, fmt: WireFormat,
     if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
         raise ValueError(
             f"quantized allreduce supports op=Sum/Average, got {op!r}")
-    n = axis_size_p(axis_name)
+    n = lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1).astype(jnp.float32)
     total = flat.shape[0]
@@ -228,7 +220,7 @@ def tail_allreduce_p(chunk, cross_axis: str, tail_policy: str = "strict",
             f"tail_policy must be one of {TAIL_POLICIES}, got "
             f"{tail_policy!r}")
     fmt = resolve_wire_format(wire_format)
-    n = axis_size_p(cross_axis)
+    n = lax.axis_size(cross_axis)
     if tail_policy == "strict":
         if fmt is not None:
             red, _ = quantized_allreduce_p(chunk, cross_axis, fmt,
@@ -1185,7 +1177,7 @@ def hierarchical_allreduce_p(x, cross_axis: str, local_axis: str,
     path is byte-identical to the pre-tail schedule.
     """
     fmt = resolve_wire_format(wire_format)
-    group = axis_size_p(local_axis)
+    group = lax.axis_size(local_axis)
     shape = x.shape
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % group
@@ -1213,7 +1205,7 @@ def hierarchical_allreduce_p(x, cross_axis: str, local_axis: str,
     if pad:
         red = red[:flat.shape[0] - pad]
     if op == ReduceOp.AVERAGE:
-        red = red / (group * axis_size_p(cross_axis))
+        red = red / (group * lax.axis_size(cross_axis))
     red = red.reshape(shape)
     if tail_policy == "stale":
         return red, new_state

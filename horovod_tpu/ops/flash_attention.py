@@ -20,24 +20,24 @@ rule without the 128-lane padding the naive ``[B, H, T]`` layout needs.
 
 Falls back cleanly: :func:`supported` gates on platform/shape so callers
 (e.g. ``local_attention``) can pick the XLA blockwise path on CPU meshes
-or odd shapes.  ``HOROVOD_FLASH_ATTENTION=0`` disables the kernel.
+or odd shapes — on a TPU backend each refused shape is logged once, at
+WARNING, with the test that refused it.  ``HOROVOD_FLASH_ATTENTION=0``
+disables the kernel.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
-try:  # pallas ships with jax; guard for exotic builds
-    from jax.experimental import pallas as pl
-    _HAS_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAS_PALLAS = False
+logger = logging.getLogger("horovod_tpu")
 
 NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
@@ -67,48 +67,60 @@ def _block_sizes(t_q: int, t_kv: int):
 def _sds(shape, dtype, *operands):
     """ShapeDtypeStruct carrying the union of the operands' varying mesh
     axes — required for pallas_call outputs under shard_map check_vma."""
-    vma = None
-    for x in operands:
-        try:
-            v = jax.typeof(x).vma
-        except AttributeError:
-            continue
-        vma = v if vma is None else (vma | v)
-    if vma is not None:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_refused(kernel: str, shapes: tuple, reason: str) -> None:
+    logger.warning("%s kernel refused shapes %s (%s); falling back to "
+                   "the XLA path", kernel, shapes, reason)
+
+
+def _verdict(kernel: str, reason: Optional[str], *operands) -> bool:
+    """``reason is None``, said aloud where it matters: on a TPU the XLA
+    path is a slower program than the one the caller named, so each
+    refused (kernel, shapes, reason) is logged once, at WARNING."""
+    if reason is not None and jax.default_backend() == "tpu":
+        _warn_refused(kernel, tuple(tuple(x.shape) for x in operands),
+                      reason)
+    return reason is None
+
+
+def _refusal(q, k, v) -> Optional[str]:
+    """Which test keeps the Pallas kernel off this call; None = it runs."""
+    if os.environ.get("HOROVOD_FLASH_ATTENTION", "1") in ("0", "false"):
+        return "HOROVOD_FLASH_ATTENTION is off"
+    if not _INTERPRET and jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}, not tpu"
+    if q.ndim != 4 or k.ndim != 4:
+        return "q and k must be rank 4"
+    B, T, H, D = q.shape
+    Tk, Hkv = k.shape[1], k.shape[2]
+    if v.shape != k.shape or q.shape[0] != k.shape[0] or k.shape[3] != D:
+        return "v must match k, and k must match q in batch and head_dim"
+    if H % Hkv:
+        return f"kv heads {Hkv} do not divide query heads {H}"
+    if D % 64 or D > 256:
+        return f"head_dim {D} is not a multiple of 64 up to 256"
+    bq, bk = _block_sizes(T, Tk)
+    if T % bq or Tk % bk or bq % 128 or bk % 128:
+        return (f"blocks ({bq}, {bk}) must divide the sequence lengths "
+                f"({T}, {Tk}) and be multiples of 128")
+    if q.dtype not in (jnp.bfloat16, jnp.float32):
+        return f"dtype {q.dtype} is neither bfloat16 nor float32"
+    g = H // Hkv
+    # fwd holds k+v [Tk, D]; bwd dkv holds q+do [g*T, D] per group
+    resident = max(2 * Tk * D, 2 * g * T * D) * q.dtype.itemsize
+    if resident > _VMEM_BUDGET:
+        return (f"resident buffers need {resident} bytes of VMEM, over "
+                f"the {_VMEM_BUDGET} budget")
+    return None
 
 
 def supported(q, k, v, causal: bool = True) -> bool:
     """True when the Pallas kernel can run this shape on this backend."""
-    if not _HAS_PALLAS:
-        return False
-    if os.environ.get("HOROVOD_FLASH_ATTENTION", "1") in ("0", "false"):
-        return False
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return False
-    if q.ndim != 4 or k.ndim != 4:
-        return False
-    B, T, H, D = q.shape
-    Tk, Hkv = k.shape[1], k.shape[2]
-    if v.shape != k.shape or q.shape[0] != k.shape[0] or k.shape[3] != D:
-        return False
-    if H % Hkv:
-        return False
-    if D % 64 or D > 256:
-        return False
-    bq, bk = _block_sizes(T, Tk)
-    if T % bq or Tk % bk or bq % 128 or bk % 128:
-        return False
-    if q.dtype not in (jnp.bfloat16, jnp.float32):
-        return False
-    esz = q.dtype.itemsize if hasattr(q.dtype, "itemsize") else 2
-    g = H // Hkv
-    # fwd holds k+v [Tk, D]; bwd dkv holds q+do [g*T, D] per group
-    resident = max(2 * Tk * D, 2 * g * T * D) * esz
-    if resident > _VMEM_BUDGET:
-        return False
-    return True
+    return _verdict("flash_attention", _refusal(q, k, v), q, k, v)
 
 
 # ---------------------------------------------------------------- forward

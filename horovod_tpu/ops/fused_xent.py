@@ -36,14 +36,10 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAS_PALLAS = False
-
+from .flash_attention import _sds, _verdict
 
 _VMEM_CAP = 100 * 1024 * 1024  # leave headroom below the 128MB VMEM
 
@@ -71,19 +67,13 @@ def _compiler_params(br: int, bv: int, d: int):
     grant = max(32 * 1024 * 1024, min(_vmem_budget(br, bv, d), _VMEM_CAP))
     return pltpu.CompilerParams(vmem_limit_bytes=grant)
 
-from .flash_attention import _sds
-
 NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
 
 
 def _extra_vma(x, like):
-    """Mesh axes ``like`` varies over that ``x`` does not (empty when
-    the vma type system is unavailable)."""
-    try:
-        return tuple(sorted(jax.typeof(like).vma - jax.typeof(x).vma))
-    except (AttributeError, TypeError):
-        return ()
+    """Mesh axes ``like`` varies over that ``x`` does not."""
+    return tuple(sorted(jax.typeof(like).vma - jax.typeof(x).vma))
 
 
 def _match_vma(x, like):
@@ -94,10 +84,7 @@ def _match_vma(x, like):
     extra = _extra_vma(x, like)
     if not extra:
         return x
-    try:
-        return lax.pcast(x, extra, to="varying")
-    except (AttributeError, ValueError):  # older jax spells it pvary
-        return lax.pvary(x, extra)
+    return lax.pcast(x, extra, to="varying")
 
 
 def _blocks(n_rows: int, vocab: int):
@@ -107,30 +94,38 @@ def _blocks(n_rows: int, vocab: int):
     return br, bv
 
 
-def supported(h, w, targets) -> bool:
-    """True when the fused kernel can run this shape on this backend."""
-    if not _HAS_PALLAS:
-        return False
+def _refusal(h, w, targets):
+    """Which test keeps the fused kernel off this call; None = it runs."""
     if os.environ.get("HOROVOD_FUSED_XENT", "1") in ("0", "false"):
-        return False
+        return "HOROVOD_FUSED_XENT is off"
     if not _INTERPRET and jax.default_backend() != "tpu":
-        return False
+        return f"backend is {jax.default_backend()}, not tpu"
     if h.ndim != 3 or w.ndim != 2 or targets.ndim != 2:
-        return False
+        return "h, w, targets must be rank 3, 2, 2"
     N = h.shape[0] * h.shape[1]
     D = h.shape[2]
     V = w.shape[0]
     if w.shape[1] != D or targets.shape[:2] != h.shape[:2]:
-        return False
+        return "w and targets do not match h"
     if D % 128:
-        return False
+        return f"d_model {D} is not a multiple of 128"
     br, bv = _blocks(N, V)
     if br is None or bv is None:
-        return False
+        return (f"no row block divides {N} tokens or no vocab block "
+                f"divides {V}")
     # shapes whose kernel working set cannot fit VMEM (large D: the
     # budget passes 100MB between D=8192 and D=16384) must take the
     # chunked-XLA loss instead of failing Mosaic compilation
-    return _vmem_budget(br, bv, D) <= _VMEM_CAP
+    need = _vmem_budget(br, bv, D)
+    if need > _VMEM_CAP:
+        return (f"working set needs {need} bytes of VMEM, over the "
+                f"{_VMEM_CAP} cap")
+    return None
+
+
+def supported(h, w, targets) -> bool:
+    """True when the fused kernel can run this shape on this backend."""
+    return _verdict("fused_xent", _refusal(h, w, targets), h, w, targets)
 
 
 # ---------------------------------------------------------------- forward

@@ -40,24 +40,8 @@ import optax
 from .. import chaos as _chaos
 from .. import runtime
 from ..compression import Compression, resolve_wire_format
+from ..parallel.vma import as_varying
 from ..runtime import ReduceOp
-
-
-def _axis_size(axis_name: str):
-    """Static size of a named mapped axis at trace time (the version
-    shim lives in ``horovod_tpu.compat``; import is lazy to keep this
-    module importable without jax fully initialized)."""
-    from ..compat import axis_size
-    return axis_size(axis_name)
-
-
-def _psum_scatter(x, axis_name: str):
-    """Tiled 1-D reduce-scatter (``compat.psum_scatter``: on a jax
-    without ``lax.psum_scatter`` the psum+slice fallback materializes
-    the full reduction and the no-psum schedule gates fail LOUDLY by
-    design — see the shim's docstring)."""
-    from ..compat import psum_scatter
-    return psum_scatter(x, axis_name)
 
 
 def _tree_leaves_sorted(tree):
@@ -235,7 +219,7 @@ def fused_reduce_tree(grads, axis_name: str, op: str = ReduceOp.AVERAGE,
                 red = jax.lax.psum(wire, r_axes) if r_axes else wire
                 red = compression.decompress(red, ctx)
                 if op == ReduceOp.AVERAGE:
-                    red = red / (_axis_size(axis_name)
+                    red = red / (jax.lax.axis_size(axis_name)
                                  if global_n is None else global_n)
                 # a bucket whose spec shards over the data axis itself
                 # (1-D FSDP) arrived fully reduced: r_axes is empty, no
@@ -324,7 +308,7 @@ class SpecPlan(NamedTuple):
         """Trace-time global batch degree (prod of all axis sizes)."""
         n = 1
         for a in (self.data_axis,) + self.model_axes:
-            n *= _axis_size(a)
+            n *= jax.lax.axis_size(a)
         return n
 
 
@@ -438,7 +422,6 @@ def fused_tail_reduce_tree(grads, cross_axis: str, local_axis: str,
     the return value is ``(reduced_tree, new_tail_state)``; other
     policies return ``(reduced_tree, None)``.
     """
-    from ..compat import axis_size
     from ..ops.collectives import hierarchical_allreduce_p
     from ..ops.fusion import pad_to_multiple
     threshold_bytes = _resolve_threshold(threshold_bytes)
@@ -452,8 +435,8 @@ def fused_tail_reduce_tree(grads, cross_axis: str, local_axis: str,
     buckets, _sigs = _plan_buckets(leaves, names, op, 1.0, 1.0,
                                    threshold_bytes,
                                    tail_policy=tail_policy)
-    G = axis_size(cross_axis)
-    L = axis_size(local_axis)
+    G = jax.lax.axis_size(cross_axis)
+    L = jax.lax.axis_size(local_axis)
     if present is None:
         present = jnp.ones((G,), jnp.float32)
     stale = tail_policy == "stale"
@@ -655,7 +638,7 @@ def fused_reduce_scatter_tree(grads, axis_name: str,
             treedef=jax.tree_util.tree_structure(grads), order=(),
             shapes=(), buckets=()))
         return empty if fmt is None else empty + (residual,)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     # names ride the single path walk: a chaos rule matching name=
     # must not be silently inert under sharded_update, and verdicts
     # carry the same tensor names as the other fused paths
@@ -720,7 +703,8 @@ def fused_reduce_scatter_tree(grads, axis_name: str,
                     off += sz
             else:
                 wire, ctx = compression.compress(buf)
-                tile = _psum_scatter(wire, axis_name)
+                tile = jax.lax.psum_scatter(
+                    wire, axis_name, scatter_dimension=0, tiled=True)
                 tile = compression.decompress(tile, ctx)
             if op == ReduceOp.AVERAGE:
                 tile = tile / global_n
@@ -1298,7 +1282,7 @@ def DistributedGradientTransform(
             p_shards = None
             if params is not None:
                 p_leaves, _p_names, _p_specs, p_layout = _sharded_layout(
-                    params, _axis_size(axis_name), op, prescale_factor,
+                    params, jax.lax.axis_size(axis_name), op, prescale_factor,
                     postscale_factor, _resolve_threshold(threshold_bytes),
                     align=fmt.block_size if fmt else 1,
                     spec_plan=spec_plan)
@@ -1373,13 +1357,12 @@ def DistributedGradientTransform(
         that the reduction of the accumulated mean.
         """
         from . import overlap as _ov
-        from ..compat import pcast_varying
         if sharded:
             if fired:
                 if fire is not None:
                     # plan once; both cond branches reuse the layout
                     _leaves, layout = _ov.build_layout(
-                        grads, _ov_plan, shards=_axis_size(axis_name))
+                        grads, _ov_plan, shards=jax.lax.axis_size(axis_name))
                     tiles = jax.lax.cond(
                         fire,
                         lambda g: _ov.carve_tiles(g, _ov_plan,
@@ -1418,8 +1401,8 @@ def DistributedGradientTransform(
         if fired and fire is not None:
             reduced = jax.lax.cond(
                 fire,
-                lambda g: pcast_varying(g, axis_name),
-                lambda g: pcast_varying(_ov.reduce_full(g, _ov_plan),
+                lambda g: as_varying(g, axis_name),
+                lambda g: as_varying(_ov.reduce_full(g, _ov_plan),
                                         axis_name),
                 grads)
         else:
@@ -1443,7 +1426,7 @@ def DistributedGradientTransform(
             if fmt is not None and _ov_plan is None else None)
         if sharded:
             try:
-                n = _axis_size(axis_name)
+                n = jax.lax.axis_size(axis_name)
             except NameError as exc:
                 raise ValueError(
                     f"sharded_update=True: init must run INSIDE the "
@@ -1501,9 +1484,8 @@ def DistributedGradientTransform(
                 acc_prev, g, inner_state = args
                 updates, new_inner = _ov_step(g, inner_state, params,
                                               fired, extra_acc=acc_prev)
-                from ..compat import pcast_varying
                 return (updates,
-                        pcast_varying(_zeros(acc_prev), axis_name),
+                        as_varying(_zeros(acc_prev), axis_name),
                         new_inner)
 
             def ov_skip_step(args):
@@ -1547,12 +1529,6 @@ def DistributedGradientTransform(
             return jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, a.dtype), tree)
 
-        def _as_varying(tree):
-            # compat.pcast_varying: pcast on new jax, identity on 0.4.x
-            # (no varying-manual-axes tracking to align there)
-            from ..compat import pcast_varying
-            return pcast_varying(tree, axis_name)
-
         def do_step(args):
             acc, inner_state, residual = args
             mean_acc = jax.tree_util.tree_map(lambda a: a / k, acc)
@@ -1575,7 +1551,7 @@ def DistributedGradientTransform(
                 # like the sentinel, the snapshot cadence divides the
                 # BOUNDARY ordinal, not the raw micro-step counter
                 _emit_recovery(count // k, count, new_inner, new_res)
-            return (updates, _as_varying(_fresh_zeros(acc)), new_inner,
+            return (updates, as_varying(_fresh_zeros(acc), axis_name), new_inner,
                     new_res)
 
         def skip_step(args):

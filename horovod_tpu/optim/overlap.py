@@ -70,8 +70,7 @@ from jax import lax
 
 from .. import metrics as _metrics
 from .. import tracing as _tracing
-from ..compat import axis_size as _axis_size
-from ..compat import pcast_varying, psum_scatter
+from ..parallel.vma import as_varying
 from ..runtime import ReduceOp
 
 logger = logging.getLogger("horovod_tpu")
@@ -500,7 +499,7 @@ def reduce_full(tree, plan: OverlapPlan, force_root: bool = False):
             else:
                 red = lax.psum(buf, r_axes) if r_axes else buf
                 if plan.op == ReduceOp.AVERAGE:
-                    red = red / (_axis_size(plan.axis_name)
+                    red = red / (lax.axis_size(plan.axis_name)
                                  if global_n is None else global_n)
             if plan.postscale != 1.0:
                 red = red * jnp.asarray(plan.postscale, red.dtype)
@@ -530,7 +529,7 @@ def scatter_tiles(tree, plan: OverlapPlan, force_root: bool = False,
     t_stage = _tracing.now() if _tracing.ACTIVE else 0.0
     if layout is None:
         leaves, layout = build_layout(tree, plan,
-                                      shards=_axis_size(plan.axis_name),
+                                      shards=lax.axis_size(plan.axis_name),
                                       force_root=force_root)
     else:
         from .distributed import _tree_leaves_sorted
@@ -562,9 +561,10 @@ def scatter_tiles(tree, plan: OverlapPlan, force_root: bool = False,
                     buf.astype(jnp.float32), plan.axis_name, plan.fmt)
                 tile = tile.astype(buf.dtype)
             else:
-                tile = psum_scatter(buf, plan.axis_name)
+                tile = lax.psum_scatter(buf, plan.axis_name,
+                                        scatter_dimension=0, tiled=True)
             if plan.op == ReduceOp.AVERAGE:
-                tile = tile / (_axis_size(plan.axis_name)
+                tile = tile / (lax.axis_size(plan.axis_name)
                                if global_n is None else global_n)
             if plan.postscale != 1.0:
                 tile = tile * jnp.asarray(plan.postscale, tile.dtype)
@@ -607,7 +607,7 @@ def carve_tiles(tree, plan: OverlapPlan, layout: Optional[OverlapLayout]
     params it carves the tile the 1/N inner update runs against."""
     if layout is None:
         leaves, layout = build_layout(tree, plan,
-                                      shards=_axis_size(plan.axis_name))
+                                      shards=lax.axis_size(plan.axis_name))
     else:
         from .distributed import _tree_leaves_sorted
         leaves, _names, _order = _tree_leaves_sorted(tree)
@@ -693,8 +693,7 @@ def grad_tap(tree):
     def gbwd(fire, ct):
         red = lax.cond(
             fire,
-            lambda c: pcast_varying(_tap_dispatch(c, plan),
-                                    plan.axis_name),
+            lambda c: as_varying(_tap_dispatch(c, plan), plan.axis_name),
             lambda c: c, ct)
         # fire is boolean: its cotangent is the zero of float0
         return (np.zeros((), dtype=jax.dtypes.float0), red)

@@ -24,24 +24,14 @@ def as_varying(tree, axis_name, like=None):
     """
     if axis_name is None:
         return tree
-    if like is not None:
-        try:
-            if axis_name not in jax.typeof(like).vma:
-                return tree  # VMA tracking off in this context
-        except AttributeError:  # pragma: no cover - aval without .vma
-            return tree
-    pcast = getattr(jax.lax, "pcast", None)
+    if like is not None and axis_name not in jax.typeof(like).vma:
+        return tree  # VMA tracking off in this context
 
     def cast(x):
+        if axis_name in jax.typeof(x).vma:
+            return x  # already varying over this axis
         try:
-            if axis_name in jax.typeof(x).vma:
-                return x  # already varying over this axis
-        except AttributeError:
-            pass
-        if pcast is None:  # pragma: no cover - API fallback
-            return jax.lax.pvary(x, axis_name)
-        try:
-            return pcast(x, axis_name, to="varying")
+            return jax.lax.pcast(x, axis_name, to="varying")
         except ValueError:
             return x
 

@@ -19,8 +19,6 @@ _POLL_S = 0.05
 
 
 def main(control_dir: str) -> int:
-    from horovod_tpu.runtime import apply_force_platform
-    apply_force_platform()
     import horovod_tpu as hvd
     hvd.init()
     rank = int(os.environ.get("HOROVOD_RANK", hvd.rank()))

@@ -137,6 +137,10 @@ def run_launcher(args: argparse.Namespace) -> int:
         return run_elastic_launcher(args)
     hosts = effective_hosts(args.hosts, args.hostfile, args.np)
     slots = assign_slots(hosts, args.np)
+    contested = spawn.chips_contested(slots, os.environ)
+    if contested:
+        print(f"hvdrun: {contested}", file=sys.stderr)
+        return 2
     addr = _coordinator_addr(hosts, args.network_interface)
     if args.verbose:
         for s in slots:

@@ -10,6 +10,7 @@ subprocesses.
 
 from __future__ import annotations
 
+import glob
 import os
 import shlex
 import signal
@@ -48,12 +49,76 @@ def ensure_job_secret(base_env: Optional[Dict[str, str]] = None) -> str:
     return key
 
 
+# libtpu's grid of one-chip processes for a host whose chips are all taken
+# by local slots, keyed by slots on the host.  2x2 is the v5e four-chip
+# host this was proven on (CHANGES.md PR 21); jax's own multi-process test
+# launcher uses the same contract.
+TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
+def _chip_per_slot(slot: SlotAssignment) -> bool:
+    return slot.cross_size == 1 and slot.local_size in TPU_PROCESS_BOUNDS
+
+
+def tpu_chip_env(slot: SlotAssignment, coordinator_port: int
+                 ) -> Dict[str, str]:
+    """libtpu's environment giving one local slot a chip of its own:
+    visible chip, per-process and process bounds, the local processes'
+    addresses and this one's port and task id.  Empty when the slot is
+    alone on its host (one process drives every chip there) and for
+    layouts in which nothing is known to work — :func:`chips_contested`
+    is the launcher's answer to those.  Means nothing to a CPU backend.
+
+    libtpu numbers the processes by their chips' coordinates, not by
+    task id, and which chip sits where differs from host to host: so
+    ``jax.process_index()``, and with it ``hvd.rank()``, is some
+    permutation of the slots' ranks.
+    """
+    if not _chip_per_slot(slot):
+        return {}
+    ports = [coordinator_port + 1 + i for i in range(slot.local_size)]
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": TPU_PROCESS_BOUNDS[slot.local_size],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot.local_rank]),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+    }
+
+
+def chips_contested(slots: List[SlotAssignment],
+                    env: Dict[str, str]) -> Optional[str]:
+    """Why starting ``slots`` would set local children fighting over this
+    host's chips, or None.  Looks at device nodes, never at jax: a
+    launcher that touched the chip would hold it against its children."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    nodes = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
+    if not nodes:
+        return None
+    for slot in slots:
+        if (is_local(slot.hostname) and slot.local_size > 1
+                and (slot.local_size > len(nodes)
+                     or not _chip_per_slot(slot))):
+            return (
+                f"{slot.local_size} processes on {slot.hostname}, which has "
+                f"{len(nodes)} TPU chip(s), would fight over them: one "
+                f"chip per process is set up for single-host jobs with "
+                f"{sorted(TPU_PROCESS_BOUNDS)} local slots only.  Run one "
+                f"process per host (it drives every chip), or set "
+                f"JAX_PLATFORMS=cpu for a CPU run.")
+    return None
+
+
 def worker_env(slot: SlotAssignment, coordinator_addr: str,
                coordinator_port: int,
                base_env: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     """The full §3.4 environment contract for one worker."""
     env = dict(base_env if base_env is not None else os.environ)
     env.update(slot.to_env())
+    env.update(tpu_chip_env(slot, coordinator_port))
     env.update({
         # reference names kept for script compatibility; the address points
         # at the JAX coordination service, not a Gloo store

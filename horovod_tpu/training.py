@@ -426,8 +426,7 @@ def spec_shard(tree, specs, axis: str):
     """This shard's slice of every leaf sharded over ``axis`` — the
     inverse of :func:`spec_all_gather` (full values in, local shards
     out, sliced by ``lax.axis_index(axis)`` along the spec'd dim)."""
-    from .compat import axis_size as _axis_size
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
 
     def shard_leaf(spec, leaf):
@@ -554,14 +553,6 @@ def _make_llama_fsdp_overlap_step(cfg: LlamaConfig, pmesh: ParallelMesh,
     program: gather sharded params → tap-armed backward (per-layer
     reduce-scatters inside the scan) → 1/dp-tile optimizer step →
     boundary all-gather of updates → slice shards back to storage."""
-    from .compat import has_new_shard_map
-    if not has_new_shard_map():
-        raise ValueError(
-            "make_llama_fsdp_step(overlap=True) needs the new-API "
-            "jax.shard_map (compat.has_new_shard_map): this jax build "
-            "only ships the experimental 0.4.x shape, whose check_rep "
-            "transposes differently — run the GSPMD fsdp step "
-            "(overlap=False) on this build, or upgrade jax")
     from .optim import overlap as _ovl
     from .optim.distributed import (DistributedGradientTransform,
                                     state_partition_specs)

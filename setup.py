@@ -47,4 +47,7 @@ setup(
         ],
     },
     python_requires=">=3.10",
+    # the oldest jax the code is written for: jax.shard_map with
+    # check_vma, lax.pcast, jax.typeof(...).vma, jax.enable_x64
+    install_requires=["jax>=0.9.0"],
 )

@@ -174,7 +174,7 @@ def test_negotiated_autotune_identical_across_processes():
     results = run(
         helpers_runner.negotiated_autotune_fn, np=2,
         env={
-            "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
             "HOROVOD_CYCLE_TIME": "0.2",
@@ -256,7 +256,7 @@ def test_negotiated_autotune_survives_leader_join():
     results = run(
         helpers_runner.autotune_leader_join_fn, np=2,
         env={
-            "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
             "HOROVOD_CYCLE_TIME": "0.2",

@@ -10,7 +10,8 @@ Three layers of coverage:
    the real RPC protocol — the whole join choreography (assignment poll,
    release gate, notification push, running/result reports) in
    milliseconds instead of per-process jax imports.
-3. The leader-join flake (VERDICT.md weak #3, BENCH_NOTE_r05): a lost
+3. The leader-join flake (seen in the July 2026 builder runs on an
+   earlier installation; not measured on the current code): a lost
    ``hosts_updated`` push strands an incumbent on the stale epoch, so
    the new epoch never forms until that worker's own failure detection
    fires — observed once mid-session as a join timeout.  Reproduced

@@ -499,15 +499,6 @@ def test_hierarchical_dcn_stage_quantized():
 # eager engine: negotiated per-bucket wire format end to end
 # ---------------------------------------------------------------------------
 
-from horovod_tpu.compat import has_new_shard_map
-
-_NEEDS_SHARD_MAP = pytest.mark.skipif(
-    not has_new_shard_map(),
-    reason="stacked eager dispatch needs jax.shard_map (absent on this "
-           "container's jax 0.4.37; the whole stacked path fails at seed)")
-
-
-@_NEEDS_SHARD_MAP
 def test_engine_dispatches_quantized_bucket(hvd, monkeypatch):
     """With HOROVOD_COMPRESSION active (and DCN-only off: the 8-dev CPU
     mesh is flat), an eager allreduce rides the quantized staging: the
@@ -531,7 +522,6 @@ def test_engine_dispatches_quantized_bucket(hvd, monkeypatch):
         assert 'hvd_wire_compression_ratio{format="int8"}' in text
 
 
-@_NEEDS_SHARD_MAP
 def test_engine_dcn_only_keeps_flat_mesh_full_width(hvd, monkeypatch):
     """The default DCN-only policy: on a flat mesh with no hierarchical
     stage the dispatch stays full-width even though the format is

@@ -28,7 +28,7 @@ FAST_EXAMPLES = [
 def test_example_runs_clean(script):
     env = dict(os.environ)
     env.update({
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "HOROVOD_CYCLE_TIME": "0.2",
         "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
     })

@@ -18,7 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _env():
     return {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
         "HOROVOD_CYCLE_TIME": "0.2",

@@ -230,3 +230,32 @@ def test_flash_block_env_override(monkeypatch):
 
     monkeypatch.delenv("HOROVOD_FLASH_BLOCK")
     assert fa._block_sizes(1024, 1024) == (512, 512)
+
+
+def test_refusal_on_a_tpu_backend_is_logged_once_per_shape(
+        monkeypatch, caplog):
+    """On a TPU the XLA path is a slower program than the one the caller
+    named: supported() says which test refused the shape, once.  On any
+    other backend the XLA path is the expected one and nothing is said."""
+    import logging
+
+    from horovod_tpu.ops import fused_xent
+
+    q = jax.ShapeDtypeStruct((1, 128, 4, 48), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 128, 2, 48), jnp.bfloat16)
+    h = jax.ShapeDtypeStruct((2, 64, 96), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((512, 96), jnp.float32)
+    y = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    fa._warn_refused.cache_clear()
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        assert not fa.supported(q, kv, kv)
+        assert not fused_xent.supported(h, w, y)
+        assert not caplog.records
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for _ in range(2):
+            assert not fa.supported(q, kv, kv)
+            assert not fused_xent.supported(h, w, y)
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 2 and all("falling back" in m for m in said)
+    assert "flash_attention" in said[0] and "head_dim 48" in said[0]
+    assert "fused_xent" in said[1] and "d_model 96" in said[1]

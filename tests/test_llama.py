@@ -375,7 +375,7 @@ def test_bench_llama8b_dp_mode_forced_measurement():
     env.update({
         "HOROVOD_BENCH_MODEL": "llama8b_dp",
         "HOROVOD_BENCH_8B_FORCE": "1",
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "PYTHONPATH": repo,
     })
@@ -409,8 +409,7 @@ def test_bench_llama8b_dp_mode_rehearsal_fallback():
     env.pop("XLA_FLAGS", None)
     env.update({
         "HOROVOD_BENCH_MODEL": "llama8b_dp",
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
-        "HOROVOD_BENCH_SKIP_PROBE": "1",
+        "JAX_PLATFORMS": "cpu",
         # small seq: the asserted contract (chips==64, n_params>7e9) is
         # seq-independent, and the full-seq trace is already covered by
         # test_llama3_8b_aot_rehearsal_subprocess; this also keeps the

@@ -637,7 +637,7 @@ def test_two_process_scrape_and_merge():
     import helpers_runner
     from horovod_tpu.runner import run
     env = {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": os.path.dirname(os.path.dirname(__file__)) + ":"
         + os.path.dirname(__file__),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",

@@ -190,6 +190,19 @@ def test_kill_switch_env(monkeypatch):
     assert loader.load() is not None
 
 
+def test_stale_artefact_is_not_imported(monkeypatch):
+    """An artefact older than core.cpp is rebuilt, or — where building
+    is not allowed — left alone: never loaded in the source's place."""
+    from horovod_tpu.native import build
+    monkeypatch.setattr(loader, "_core", None)
+    monkeypatch.setattr(loader, "_attempted", False)
+    monkeypatch.setattr(build, "built", lambda: False)
+    assert loader.load(auto_build=False) is None
+    monkeypatch.setenv("HOROVOD_TPU_NATIVE_BUILD", "0")
+    assert loader.load() is None
+    assert loader._attempted is False  # a later caller may still build
+
+
 def test_negotiate_decide_parity():
     """Native negotiate_decide matches the Python decision loop on random
     announcement multisets (reference: controller.cc ComputeResponseList

@@ -13,6 +13,7 @@ from horovod_tpu.runner import parse_args
 from horovod_tpu.runner.hosts import (
     HostInfo, SlotAssignment, assign_slots, effective_hosts, parse_hostfile,
     parse_hosts)
+from horovod_tpu.runner import spawn
 from horovod_tpu.runner.spawn import remote_command, worker_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -101,6 +102,56 @@ def test_worker_env_contract():
     assert env["PATH"] == "/bin"  # base env preserved
 
 
+def test_worker_env_gives_each_local_slot_its_own_chip():
+    """Four slots on one host: libtpu's one-chip-per-process contract,
+    distinct chip, port and task id per slot.  A slot alone on its host
+    (it drives every chip) and a multi-host job get nothing."""
+    slots = assign_slots([HostInfo("localhost", 4)], 4)
+    envs = [worker_env(s, "127.0.0.1", 29410, base_env={}) for s in slots]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_ADDRESSES"] == ",".join(
+            f"localhost:{x['TPU_PROCESS_PORT']}" for x in envs)
+    alone = assign_slots([HostInfo("localhost", 1)], 1)[0]
+    two_hosts = assign_slots([HostInfo("a", 4), HostInfo("b", 4)], 8)[0]
+    for slot in (alone, two_hosts):
+        assert not any(k.startswith("TPU_")
+                       for k in worker_env(slot, "h", 1, base_env={}))
+
+
+def test_launcher_refuses_local_slots_that_would_share_chips(
+        monkeypatch, capsys):
+    """Two local slots on a host with TPU device nodes and no chip-per-
+    process layout: hvdrun says why and starts nothing.  The same job
+    sent to the CPU is not its business."""
+    import glob
+    monkeypatch.setattr(
+        glob, "glob",
+        lambda pat: ["/dev/accel0", "/dev/accel1"] if "accel" in pat else [])
+    slots = assign_slots([HostInfo("localhost", 2)], 2)
+    assert spawn.chips_contested(slots, {"JAX_PLATFORMS": "cpu"}) \
+        is None
+    assert "has 2 TPU chip(s)" in spawn.chips_contested(slots, {})
+    four = assign_slots([HostInfo("localhost", 4)], 4)
+    assert "has 2 TPU chip(s)" in spawn.chips_contested(four, {})
+    monkeypatch.setattr(
+        glob, "glob",
+        lambda pat: [f"/dev/vfio/{i}" for i in range(4)] if "vfio" in pat
+        else [])
+    assert spawn.chips_contested(four, {}) is None
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(spawn, "spawn_workers", lambda *a, **k: pytest.fail(
+        "spawned despite contested chips"))
+    from horovod_tpu.runner import launch
+    assert launch.main(["-np", "2", "python", "train.py"]) == 2
+    assert "one chip per process" in capsys.readouterr().err
+
+
 def test_remote_command_construction():
     """Assert the generated ssh command line (reference: mpirun cmdline
     asserts in test_run.py)."""
@@ -124,7 +175,7 @@ def test_remote_command_construction():
 
 def _run_env():
     return {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
         # keep worker JAX quiet and CPU-only, one device per process
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",

@@ -178,7 +178,7 @@ def test_zero_grad_guard(thvd):
 
 def test_torch_two_process_training_matches_single():
     env = {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
         "HOROVOD_CYCLE_TIME": "0.2",
@@ -313,7 +313,7 @@ def test_torch_state_run_wrapper_available(thvd):
 
 def test_torch_reducescatter_two_process():
     env = {
-        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO + ":" + os.path.join(REPO, "tests"),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
         "HOROVOD_CYCLE_TIME": "0.2",

@@ -111,7 +111,6 @@ def _spawn_and_collect(transport: str, args) -> dict:
                     KV_ADDR_ENV: f"127.0.0.1:{server.port}",
                     KV_WATCH_ENV: "1" if transport == "watch" else "0",
                     "JAX_PLATFORMS": "cpu",
-                    "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
                     "PYTHONPATH": REPO + os.pathsep
                     + env.get("PYTHONPATH", ""),
                 })

@@ -177,7 +177,6 @@ class _Phase:
         for wid in range(n_workers):
             env = dict(os.environ)
             env.update({"JAX_PLATFORMS": "cpu",
-                        "HOROVOD_TPU_FORCE_PLATFORM": "cpu",
                         "PYTHONPATH": REPO + os.pathsep
                         + env.get("PYTHONPATH", "")})
             env.pop("HOROVOD_SECRET_KEY", None)

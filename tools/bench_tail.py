@@ -222,7 +222,6 @@ def bench_ab_sharded(jax, G, L, steps):
     perturb."""
     import jax.numpy as jnp
     import numpy as np
-    from horovod_tpu import compat
     from horovod_tpu.ops import collectives
 
     dim, rows = 24, 32
@@ -239,7 +238,8 @@ def bench_ab_sharded(jax, G, L, steps):
     def step(p_flat, m, v, t, xb, yb, fire, present):
         g = jax.grad(lambda q: _loss(jnp, split(q), xb, yb))(p_flat)
         gp = jnp.concatenate([g, jnp.zeros((pad,), g.dtype)]) if pad else g
-        chunk = compat.psum_scatter(gp, LOCAL)        # ICI stage: 1/L tile
+        chunk = jax.lax.psum_scatter(               # ICI stage: 1/L tile
+            gp, LOCAL, scatter_dimension=0, tiled=True)
 
         def armed(c):
             r, _, _ = collectives.tail_allreduce_p(

@@ -92,7 +92,7 @@ dist_smoke() {  # $1 = a wheel or sdist under dist/ (exactly one)
   rm -rf "$WHEEL_TGT"/*
   pip install --no-deps --no-build-isolation --quiet \
     --target "$WHEEL_TGT" "$1"
-  (cd /tmp && HOROVOD_TPU_FORCE_PLATFORM=cpu PYTHONPATH="$WHEEL_TGT" \
+  (cd /tmp && JAX_PLATFORMS=cpu PYTHONPATH="$WHEEL_TGT" \
     REPO_DIR="$REPO_DIR" python - <<'PYEOF'
 import os, sys
 repo = os.environ["REPO_DIR"]
