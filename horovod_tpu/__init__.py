@@ -17,6 +17,10 @@ Quick start (data-parallel training, the reference's core use case)::
     # bucket-fused and all-reduced over ICI automatically.
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the ``import`` span opens here, closes below
+
 from .version import __version__  # noqa: F401
 
 # --- core runtime (reference: horovod/common/basics.py) ---------------------
@@ -72,6 +76,12 @@ from . import chaos  # noqa: F401
 # training-health telemetry (docs/observability.md "Training health"):
 # hvd.health.note_loss / on_unhealthy are the user hooks
 from . import health  # noqa: F401
+
+# start-up's first span (docs/observability.md "Start-up and the jitted
+# step"): this file top to bottom, jax's import included where the
+# caller had not imported it yet
+from . import tracing as _tracing
+_tracing.span("setup", "import", _IMPORT_T0, _time.monotonic(), round=-1)
 
 
 def __getattr__(name):

@@ -285,19 +285,28 @@ def make_dp_finetune_step(cfg: BertConfig, mesh, axis: str, optimizer,
     """
     import optax
     from jax.sharding import PartitionSpec as P
+    from ..training import SCOPE_FORWARD, SCOPE_OPTIMIZER, SCOPE_REDUCE
     par = ParallelSpec(dp_axis=axis)
+
+    def forward(params, tokens, labels):
+        with jax.named_scope(SCOPE_FORWARD):
+            return loss_fn(params, tokens, labels, cfg, par)
 
     @jax.jit
     def step(params, opt_state, tokens, labels):
         def shard(params, opt_state, tokens, labels):
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, tokens, labels, cfg, par)
+            loss, grads = jax.value_and_grad(forward)(params, tokens, labels)
             if reduce_grads:
-                grads = jax.tree_util.tree_map(
-                    lambda g: lax.pmean(g, axis), grads)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return params, opt_state, lax.pmean(loss, axis)
+                with jax.named_scope(SCOPE_REDUCE):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: lax.pmean(g, axis), grads)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
+            with jax.named_scope(SCOPE_REDUCE):
+                loss = lax.pmean(loss, axis)
+            return params, opt_state, loss
         return jax.shard_map(
             shard, mesh=mesh, in_specs=(P(), P(), P(axis), P(axis)),
             out_specs=(P(), P(), P()), check_vma=True)(

@@ -191,6 +191,7 @@ def _flash_fwd_bhtd(q, k, v, causal, scale):
             _sds((B, H, nq, bq), jnp.float32, q, k, v),
         ],
         interpret=_INTERPRET,
+        name="hvd_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -307,6 +308,7 @@ def _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale, dlse=None):
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=_sds((B, H, T, D), q.dtype, q, k, v, do),
         interpret=_INTERPRET,
+        name="hvd_flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -330,6 +332,7 @@ def _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale, dlse=None):
             _sds((B, Hkv, Tk, D), v.dtype, q, k, v, do),
         ],
         interpret=_INTERPRET,
+        name="hvd_flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -35,6 +35,8 @@ import jax
 import jax.extend.backend
 import numpy as np
 
+from . import metrics as _metrics
+from . import tracing as _tracing
 from .config import Config
 from .exceptions import NotInitializedError
 
@@ -255,6 +257,47 @@ def _require_init() -> _RuntimeState:
     return _STATE
 
 
+_m_compile_cache = _metrics.counter(
+    "hvd_compile_cache_total",
+    "Programs looked up in jax's persistent compilation cache",
+    labels=("result",))
+
+#: jax.monitoring duration events that become ``compile`` spans, by the
+#: ``stage`` each span carries.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_compile_listeners_installed = False
+
+
+def _on_compile_duration(event, duration, fun_name="", **_):
+    """One closed ``compile`` span per function jax traces, lowers or
+    compiles (``backend`` is the compile, or the load from the persistent
+    cache), named by the function: which one recompiled, and when.
+    Functions traced inside another's trace (every ``jnp`` helper: a
+    ResNet-50 step has thousands) lie inside that one's span and get
+    none of their own."""
+    stage = _COMPILE_STAGES.get(event)
+    if stage is not None and _tracing.ACTIVE:
+        if stage == "trace" and not jax.core.trace_ctx.is_top_level():
+            return
+        t1 = _tracing.now()
+        _tracing.span("compile", fun_name, t1 - duration, t1, round=-1,
+                      stage=stage)
+
+
+def _on_compile_event(event, **_):
+    result = _CACHE_RESULTS.get(event)
+    if result is not None and _metrics.ACTIVE:
+        _m_compile_cache.inc(result=result)
+
+
 def use_compile_cache() -> str:
     """Keep jax's persistent compilation cache in a directory that does
     not move, and return it.
@@ -263,8 +306,16 @@ def use_compile_cache() -> str:
     nothing is set here.  Otherwise the cache is ``<checkout>/.jax_cache``,
     derived from this package's location: the directory is part of what
     a cached program is found by, so a per-run path never hits.  Takes
-    effect for every compile that follows the call.
+    effect for every compile that follows the call, and so do the
+    ``compile`` spans and ``hvd_compile_cache_total``, which the first
+    call hooks onto ``jax.monitoring``.
     """
+    global _compile_listeners_installed
+    if not _compile_listeners_installed:
+        _compile_listeners_installed = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_compile_event)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
@@ -273,6 +324,238 @@ def use_compile_cache() -> str:
         ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def _rendezvous() -> Config:
+    """``init.rendezvous``: the configuration from the environment, the
+    elastic driver's assignment, and ``jax.distributed.initialize`` where
+    the job has more than one process."""
+    cfg = Config.from_env()
+    _setup_logging(cfg)
+
+    _STATE.config = cfg
+
+    # Elastic rendezvous retry loop: a worker blocked in a stale
+    # epoch's coordination-service barrier (its peers died before
+    # joining) must not hang forever — each attempt re-fetches the
+    # driver's CURRENT assignment (reference: elastic rendezvous
+    # re-query, §3.5), so when the driver bumps the epoch mid-wait
+    # the next attempt rendezvouses into the new world.
+    start_deadline = time.monotonic() + float(os.environ.get(
+        "HOROVOD_ELASTIC_START_TIMEOUT", "600"))
+    attempt = 0
+    while True:
+        if cfg.elastic:
+            from .elastic import worker as elastic_worker
+            # first attempt wants an epoch newer than the last one this
+            # worker saw (request_reform guarantees the bump); retries
+            # accept the latest published epoch, whatever it is
+            min_ep = (None if attempt == 0
+                      else max(elastic_worker._last_epoch, 0))
+            asg = elastic_worker.fetch_assignment(min_epoch=min_ep)
+            if asg is not None:
+                cfg.rank = asg["rank"]
+                cfg.size = asg["size"]
+                cfg.local_rank = asg["local_rank"]
+                cfg.local_size = asg["local_size"]
+                cfg.cross_rank = asg["cross_rank"]
+                cfg.cross_size = asg["cross_size"]
+                cfg.rendezvous_addr = asg["coordinator_addr"]
+                cfg.rendezvous_port = asg["coordinator_port"]
+                cfg.num_processes = asg["size"]
+                cfg.process_id = asg["rank"]
+
+        # Multi-process rendezvous via the JAX coordination service
+        # (the TPU-native replacement for MPI/Gloo rendezvous, SURVEY.md
+        # §5.8).  Process count/id resolution: prefer the launcher's
+        # explicit HOROVOD_NUM_PROCESSES/PROCESS_ID; fall back to the
+        # cross_* vars (one process per host driving all its chips) and
+        # finally to rank/size (one process per worker).
+        n_procs = cfg.num_processes or cfg.cross_size or cfg.size
+        if not (n_procs is not None and n_procs > 1
+                and cfg.rendezvous_addr):
+            break  # single-process: nothing to rendezvous
+        coordinator = (
+            f"{cfg.rendezvous_addr}:{cfg.rendezvous_port or 9999}")
+        if cfg.process_id is not None:
+            proc_id = cfg.process_id
+        elif cfg.num_processes is None and cfg.cross_rank is not None:
+            proc_id = cfg.cross_rank
+        else:
+            proc_id = cfg.rank
+        dist_kwargs = {}
+        if cfg.elastic:
+            # survive peer death instead of LOG(FATAL)-ing: collectives
+            # fail with a catchable error (→ HorovodInternalError path)
+            # and this process can re-rendezvous at the next epoch
+            jax.config.update("jax_enable_recoverability", True)
+            hb = int(os.environ.get(
+                "HOROVOD_ELASTIC_HEARTBEAT_TIMEOUT", "10"))
+            # init timeout gates EPOCH FORMATION only (post-init
+            # death is the heartbeat's job).  Two pressures: it must
+            # cover the slowest member's spawn + jax import on an
+            # oversubscribed host (30 s is too tight for 3 workers
+            # on one core), but a member stuck in RegisterTask is
+            # UNINTERRUPTIBLE until this deadline LOG(FATAL)s it —
+            # so it must not exceed the driver's start_timeout or
+            # stuck members stay a full epoch out of phase with the
+            # driver's re-forms.
+            dist_kwargs = dict(
+                heartbeat_timeout_seconds=hb,
+                shutdown_timeout_seconds=hb,
+                initialization_timeout=int(os.environ.get(
+                    "HOROVOD_ELASTIC_INIT_TIMEOUT", "60")))
+        try:
+            # a prior solo epoch (job shrunk to 1 process: distributed
+            # init skipped) may have lazily created local backends;
+            # they must go before the world re-forms
+            from jax._src import xla_bridge as _xb
+            if _xb.backends_are_initialized():
+                jax.extend.backend.clear_backends()
+        except Exception:  # noqa: BLE001 - internal API drift
+            logger.debug("pre-init backend clear skipped",
+                         exc_info=True)
+        try:
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=n_procs,
+                process_id=proc_id,
+                **dist_kwargs,
+            )
+            _STATE.owns_jax_distributed = True
+            break
+        except Exception as e:  # noqa: BLE001 - barrier timeout /
+            # half-dead coordinator; non-elastic jobs fail loudly
+            if not cfg.elastic or time.monotonic() > start_deadline:
+                raise
+            attempt += 1
+            logger.warning(
+                "elastic rendezvous attempt %d failed (%s); "
+                "re-fetching assignment", attempt, e)
+            try:
+                jax.distributed.shutdown()
+            except Exception:  # noqa: BLE001 - partial init
+                pass
+
+    return cfg
+
+
+def _bring_up_devices(cfg: Config, process_sets):
+    """``init.backend``: the first ``jax.devices()`` of the process (where
+    the TPU client comes up), the global mesh and the process sets."""
+    # Invalidate compiled-kernel caches from a previous incarnation:
+    # device ids collide across re-inits but the device objects (and
+    # their runtime clients) are new, so stale jitted fns would fail
+    # with "incompatible devices".
+    from .ops.collectives import reset_kernel_caches
+    reset_kernel_caches()
+
+    _STATE.devices = list(jax.devices())
+    _STATE.global_mesh = jax.sharding.Mesh(
+        np.array(_STATE.devices), (cfg.worker_axis,))
+    _STATE.lead_worker_rank = (
+        jax.process_index() * jax.local_device_count())
+
+    _STATE.process_set_table.clear()
+    global_ps = ProcessSet(None)
+    _STATE.process_set_table.register(
+        global_ps, _STATE.devices, cfg.worker_axis)
+    _STATE.global_process_set = global_ps
+    if process_sets:
+        for ps in process_sets:
+            _STATE.process_set_table.register(
+                ps, _STATE.devices, cfg.worker_axis)
+
+
+def _start_observability(cfg: Config) -> str:
+    """``init.observability``: metrics, timeline, stall inspector, tracing
+    and health.  Returns the namespace of this incarnation, which the
+    controller's keys share with the spans' epoch."""
+    # metrics exposition + flight recorder env contract (SIGUSR1
+    # dump handler, HOROVOD_METRICS_DUMP snapshots,
+    # HOROVOD_METRICS_PORT scrape server); idempotent across
+    # elastic re-inits
+    _metrics.init_from_env()
+    if _metrics.RECORDING:
+        _metrics.event("runtime.init", process=jax.process_index(),
+                       processes=jax.process_count())
+    from .timeline import Timeline
+    from .stall import StallInspector
+    _STATE.timeline = Timeline(
+        cfg.timeline_path, mark_cycles=cfg.timeline_mark_cycles,
+        use_native=cfg.use_native_core)
+    # straggler-score -> elastic-blacklist bridge (OptiReduce tail
+    # prescription): a host whose EWMA lateness crosses
+    # HOROVOD_TAIL_BLACKLIST_SCORE is reported to the elastic
+    # driver as a SOFT failure — it feeds the blacklist before the
+    # host dies outright.  Best effort and a no-op outside the
+    # elastic driver (no endpoint exported).
+    def _report_straggler(process, score):
+        from .elastic import worker as _ew
+        _ew.report_straggler(process, score)
+
+    _STATE.stall_inspector = StallInspector(
+        check_time=cfg.stall_check_time,
+        shutdown_time=cfg.stall_shutdown_time,
+        disabled=cfg.stall_check_disable,
+        use_native=cfg.use_native_core,
+        blacklist_score=cfg.tail_blacklist_score,
+        on_straggler=_report_straggler)
+
+    # Controller keys are namespaced per incarnation so init→shutdown→
+    # init against a persistent coordination service never reads the
+    # previous incarnation's rounds: elastic re-forms share the
+    # driver's epoch; plain re-inits count generations in lockstep.
+    global _INIT_GENERATION
+    _INIT_GENERATION += 1
+    if cfg.elastic:
+        from .elastic import worker as elastic_worker
+        ns = f"e{max(elastic_worker._last_epoch, 0)}"
+    else:
+        ns = f"g{_INIT_GENERATION}"
+    # distributed-tracing identity/context (tracing/): spans carry
+    # this worker's process rank, host, and elastic epoch so the
+    # driver's /trace/job merge can assign one pid per host and
+    # correlate rounds across incarnations
+    _tracing.init_from_env()
+    _tracing.set_identity(
+        process=jax.process_index(),
+        host=os.environ.get("HOROVOD_HOSTNAME") or None,
+        epoch=int(ns[1:]))
+    # training-health evaluator identity (health/): verdicts carry
+    # this worker's rank/host so the driver's /health/job merge
+    # attributes them; history survives elastic re-inits (a
+    # post-mortem scrape wants the pre-reform verdicts)
+    from . import health as _health
+    _health.init_from_env()
+    _health.set_identity(
+        process=jax.process_index(),
+        host=os.environ.get("HOROVOD_HOSTNAME") or None)
+    return ns
+
+
+def _start_engine(cfg: Config, ns: str):
+    """``init.engine``: autotuner, negotiation controller and the
+    background collective engine, started."""
+    if cfg.autotune:
+        from .autotune import ParameterManager
+        # hierarchical collectives need a valid (groups, group_size)
+        # factorization of the global set; without one the GP's hier
+        # dimension would be inert and waste its sample budget
+        _STATE.autotuner = ParameterManager(
+            cfg, hier_available=(
+                _STATE.global_process_set.hier_shape() is not None))
+
+    # The background collective engine (reference: BackgroundThreadLoop)
+    # with its cross-process negotiation controller (controller.cc).
+    from .ops.controller import Controller
+    from .ops.engine import CollectiveEngine
+    _STATE.engine = CollectiveEngine(
+        cfg, _STATE.global_mesh, _STATE.timeline,
+        _STATE.stall_inspector, _STATE.autotuner,
+        controller=Controller(cfg, _STATE.stall_inspector,
+                              namespace=ns))
+    _STATE.engine.start()
 
 
 def init(comm=None, process_sets: Optional[Sequence[ProcessSet]] = None):
@@ -295,223 +578,28 @@ def init(comm=None, process_sets: Optional[Sequence[ProcessSet]] = None):
     with _STATE._init_lock:
         if _STATE.initialized:
             return
-        use_compile_cache()
         if comm is not None:
             raise ValueError(
                 "horovod_tpu.init(comm=...) with a custom communicator is not "
                 "supported on TPU; use process_sets for sub-groups.")
-        cfg = Config.from_env()
-        _setup_logging(cfg)
-
-        _STATE.config = cfg
-
-        # Elastic rendezvous retry loop: a worker blocked in a stale
-        # epoch's coordination-service barrier (its peers died before
-        # joining) must not hang forever — each attempt re-fetches the
-        # driver's CURRENT assignment (reference: elastic rendezvous
-        # re-query, §3.5), so when the driver bumps the epoch mid-wait
-        # the next attempt rendezvouses into the new world.
-        start_deadline = time.monotonic() + float(os.environ.get(
-            "HOROVOD_ELASTIC_START_TIMEOUT", "600"))
-        attempt = 0
-        while True:
-            if cfg.elastic:
-                from .elastic import worker as elastic_worker
-                # first attempt wants an epoch newer than the last one this
-                # worker saw (request_reform guarantees the bump); retries
-                # accept the latest published epoch, whatever it is
-                min_ep = (None if attempt == 0
-                          else max(elastic_worker._last_epoch, 0))
-                asg = elastic_worker.fetch_assignment(min_epoch=min_ep)
-                if asg is not None:
-                    cfg.rank = asg["rank"]
-                    cfg.size = asg["size"]
-                    cfg.local_rank = asg["local_rank"]
-                    cfg.local_size = asg["local_size"]
-                    cfg.cross_rank = asg["cross_rank"]
-                    cfg.cross_size = asg["cross_size"]
-                    cfg.rendezvous_addr = asg["coordinator_addr"]
-                    cfg.rendezvous_port = asg["coordinator_port"]
-                    cfg.num_processes = asg["size"]
-                    cfg.process_id = asg["rank"]
-
-            # Multi-process rendezvous via the JAX coordination service
-            # (the TPU-native replacement for MPI/Gloo rendezvous, SURVEY.md
-            # §5.8).  Process count/id resolution: prefer the launcher's
-            # explicit HOROVOD_NUM_PROCESSES/PROCESS_ID; fall back to the
-            # cross_* vars (one process per host driving all its chips) and
-            # finally to rank/size (one process per worker).
-            n_procs = cfg.num_processes or cfg.cross_size or cfg.size
-            if not (n_procs is not None and n_procs > 1
-                    and cfg.rendezvous_addr):
-                break  # single-process: nothing to rendezvous
-            coordinator = (
-                f"{cfg.rendezvous_addr}:{cfg.rendezvous_port or 9999}")
-            if cfg.process_id is not None:
-                proc_id = cfg.process_id
-            elif cfg.num_processes is None and cfg.cross_rank is not None:
-                proc_id = cfg.cross_rank
-            else:
-                proc_id = cfg.rank
-            dist_kwargs = {}
-            if cfg.elastic:
-                # survive peer death instead of LOG(FATAL)-ing: collectives
-                # fail with a catchable error (→ HorovodInternalError path)
-                # and this process can re-rendezvous at the next epoch
-                jax.config.update("jax_enable_recoverability", True)
-                hb = int(os.environ.get(
-                    "HOROVOD_ELASTIC_HEARTBEAT_TIMEOUT", "10"))
-                # init timeout gates EPOCH FORMATION only (post-init
-                # death is the heartbeat's job).  Two pressures: it must
-                # cover the slowest member's spawn + jax import on an
-                # oversubscribed host (30 s is too tight for 3 workers
-                # on one core), but a member stuck in RegisterTask is
-                # UNINTERRUPTIBLE until this deadline LOG(FATAL)s it —
-                # so it must not exceed the driver's start_timeout or
-                # stuck members stay a full epoch out of phase with the
-                # driver's re-forms.
-                dist_kwargs = dict(
-                    heartbeat_timeout_seconds=hb,
-                    shutdown_timeout_seconds=hb,
-                    initialization_timeout=int(os.environ.get(
-                        "HOROVOD_ELASTIC_INIT_TIMEOUT", "60")))
-            try:
-                # a prior solo epoch (job shrunk to 1 process: distributed
-                # init skipped) may have lazily created local backends;
-                # they must go before the world re-forms
-                from jax._src import xla_bridge as _xb
-                if _xb.backends_are_initialized():
-                    jax.extend.backend.clear_backends()
-            except Exception:  # noqa: BLE001 - internal API drift
-                logger.debug("pre-init backend clear skipped",
-                             exc_info=True)
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=n_procs,
-                    process_id=proc_id,
-                    **dist_kwargs,
-                )
-                _STATE.owns_jax_distributed = True
-                break
-            except Exception as e:  # noqa: BLE001 - barrier timeout /
-                # half-dead coordinator; non-elastic jobs fail loudly
-                if not cfg.elastic or time.monotonic() > start_deadline:
-                    raise
-                attempt += 1
-                logger.warning(
-                    "elastic rendezvous attempt %d failed (%s); "
-                    "re-fetching assignment", attempt, e)
-                try:
-                    jax.distributed.shutdown()
-                except Exception:  # noqa: BLE001 - partial init
-                    pass
-
-        # Invalidate compiled-kernel caches from a previous incarnation:
-        # device ids collide across re-inits but the device objects (and
-        # their runtime clients) are new, so stale jitted fns would fail
-        # with "incompatible devices".
-        from .ops.collectives import reset_kernel_caches
-        reset_kernel_caches()
-
-        _STATE.devices = list(jax.devices())
-        n = len(_STATE.devices)
-        _STATE.global_mesh = jax.sharding.Mesh(
-            np.array(_STATE.devices), (cfg.worker_axis,))
-        _STATE.lead_worker_rank = (
-            jax.process_index() * jax.local_device_count())
-
-        _STATE.process_set_table.clear()
-        global_ps = ProcessSet(None)
-        _STATE.process_set_table.register(
-            global_ps, _STATE.devices, cfg.worker_axis)
-        _STATE.global_process_set = global_ps
-        if process_sets:
-            for ps in process_sets:
-                _STATE.process_set_table.register(
-                    ps, _STATE.devices, cfg.worker_axis)
-
-        # Observability subsystems.
-        from . import metrics as _metrics
-        # metrics exposition + flight recorder env contract (SIGUSR1
-        # dump handler, HOROVOD_METRICS_DUMP snapshots,
-        # HOROVOD_METRICS_PORT scrape server); idempotent across
-        # elastic re-inits
-        _metrics.init_from_env()
-        if _metrics.RECORDING:
-            _metrics.event("runtime.init", process=jax.process_index(),
-                           processes=jax.process_count())
-        from .timeline import Timeline
-        from .stall import StallInspector
-        _STATE.timeline = Timeline(
-            cfg.timeline_path, mark_cycles=cfg.timeline_mark_cycles,
-            use_native=cfg.use_native_core)
-        # straggler-score -> elastic-blacklist bridge (OptiReduce tail
-        # prescription): a host whose EWMA lateness crosses
-        # HOROVOD_TAIL_BLACKLIST_SCORE is reported to the elastic
-        # driver as a SOFT failure — it feeds the blacklist before the
-        # host dies outright.  Best effort and a no-op outside the
-        # elastic driver (no endpoint exported).
-        def _report_straggler(process, score):
-            from .elastic import worker as _ew
-            _ew.report_straggler(process, score)
-
-        _STATE.stall_inspector = StallInspector(
-            check_time=cfg.stall_check_time,
-            shutdown_time=cfg.stall_shutdown_time,
-            disabled=cfg.stall_check_disable,
-            use_native=cfg.use_native_core,
-            blacklist_score=cfg.tail_blacklist_score,
-            on_straggler=_report_straggler)
-
-        if cfg.autotune:
-            from .autotune import ParameterManager
-            # hierarchical collectives need a valid (groups, group_size)
-            # factorization of the global set; without one the GP's hier
-            # dimension would be inert and waste its sample budget
-            _STATE.autotuner = ParameterManager(
-                cfg, hier_available=global_ps.hier_shape() is not None)
-
-        # The background collective engine (reference: BackgroundThreadLoop)
-        # with its cross-process negotiation controller (controller.cc).
-        # Controller keys are namespaced per incarnation so init→shutdown→
-        # init against a persistent coordination service never reads the
-        # previous incarnation's rounds: elastic re-forms share the
-        # driver's epoch; plain re-inits count generations in lockstep.
-        global _INIT_GENERATION
-        _INIT_GENERATION += 1
-        if cfg.elastic:
-            from .elastic import worker as elastic_worker
-            ns = f"e{max(elastic_worker._last_epoch, 0)}"
-        else:
-            ns = f"g{_INIT_GENERATION}"
-        # distributed-tracing identity/context (tracing/): spans carry
-        # this worker's process rank, host, and elastic epoch so the
-        # driver's /trace/job merge can assign one pid per host and
-        # correlate rounds across incarnations
-        from . import tracing as _tracing
-        _tracing.init_from_env()
-        _tracing.set_identity(
-            process=jax.process_index(),
-            host=os.environ.get("HOROVOD_HOSTNAME") or None,
-            epoch=int(ns[1:]))
-        # training-health evaluator identity (health/): verdicts carry
-        # this worker's rank/host so the driver's /health/job merge
-        # attributes them; history survives elastic re-inits (a
-        # post-mortem scrape wants the pre-reform verdicts)
-        from . import health as _health
-        _health.init_from_env()
-        _health.set_identity(
-            process=jax.process_index(),
-            host=os.environ.get("HOROVOD_HOSTNAME") or None)
-        from .ops.controller import Controller
-        from .ops.engine import CollectiveEngine
-        _STATE.engine = CollectiveEngine(
-            cfg, _STATE.global_mesh, _STATE.timeline,
-            _STATE.stall_inspector, _STATE.autotuner,
-            controller=Controller(cfg, _STATE.stall_inspector,
-                                  namespace=ns))
-        _STATE.engine.start()
+        # start-up's own spans (docs/observability.md "Start-up and the
+        # jitted step"): one for the call, one for each part below
+        with _tracing.scope("setup", "init"):
+            use_compile_cache()
+            with _tracing.scope("setup", "init.rendezvous"):
+                cfg = _rendezvous()
+            with _tracing.scope("setup", "init.backend"):
+                _bring_up_devices(cfg, process_sets)
+            with _tracing.scope("setup", "init.native"):
+                if cfg.use_native_core:
+                    # built or loaded here once; the stall inspector,
+                    # timeline and controller below find it loaded
+                    from .native import loader
+                    loader.load()
+            with _tracing.scope("setup", "init.observability"):
+                ns = _start_observability(cfg)
+            with _tracing.scope("setup", "init.engine"):
+                _start_engine(cfg, ns)
 
         _STATE.initialized = True
         atexit.register(shutdown)
@@ -522,7 +610,7 @@ def init(comm=None, process_sets: Optional[Sequence[ProcessSet]] = None):
             record_running()
         logger.info(
             "horovod_tpu initialized: %d workers (%d local), process %d/%d",
-            n, jax.local_device_count(), jax.process_index(),
+            len(_STATE.devices), jax.local_device_count(), jax.process_index(),
             jax.process_count())
 
 
@@ -532,7 +620,6 @@ def shutdown():
         if not _STATE.initialized:
             return
         try:
-            from . import metrics as _metrics
             if _metrics.RECORDING:
                 _metrics.event("runtime.shutdown")
             _metrics.stop_exposition()
@@ -625,11 +712,15 @@ def start_profiler(logdir: str):
     while active, the engine's per-dispatch TraceAnnotation ranges land
     in the same Perfetto trace as XLA's collective/kernel spans, giving
     the merged framework+device view SURVEY §5.1 prescribes.  View with
-    ``tensorboard --logdir`` or Perfetto.
+    ``tensorboard --logdir`` or Perfetto.  The python tracer is off:
+    on, eight steps of a jitted loop were 9 MB of ``$builtins
+    isinstance`` (PERF.md, PR 23) around the spans that say something.
     """
     _require_init()
     import jax.profiler
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
 
 
 def stop_profiler():

@@ -32,7 +32,9 @@ span schema and offset method: docs/observability.md.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Optional
 
 from . import critical, merge  # noqa: F401  (re-export for driver/tools)
@@ -98,6 +100,39 @@ def span(cat: str, name: str, t0: float, t1: float,
     guard on ``tracing.ACTIVE``)."""
     if ACTIVE:
         _BUFFER.add(cat, name, t0, t1, round=round, group=group, **args)
+
+
+class _OpenScopes(threading.local):
+    """``seqs``: this thread's open scopes, outermost first."""
+
+    def __init__(self):
+        self.seqs = []
+
+
+_OPEN = _OpenScopes()
+
+
+@contextlib.contextmanager
+def scope(cat: str, name: str, **args):
+    """One closed span around the block, for host code off the hot path
+    (start-up: ``hvd.init()`` and its phases).  The span carries
+    ``parent``, the ``seq`` of the scope that encloses it on this thread
+    (None at the top), and a ``jax.profiler.TraceAnnotation`` named
+    ``hvd.<name>`` stays open for the same interval, so under
+    ``hvd.start_profiler()`` the span also sits on the host's line of
+    the device trace, on that trace's clock."""
+    import jax.profiler
+    seqs = _OPEN.seqs
+    parent = seqs[-1] if seqs else None
+    seq = _BUFFER.reserve() if ACTIVE else None
+    seqs.append(seq)
+    t0 = now()
+    try:
+        with jax.profiler.TraceAnnotation(f"hvd.{name}"):
+            yield
+    finally:
+        seqs.pop()
+        span(cat, name, t0, now(), seq=seq, parent=parent, **args)
 
 
 def set_context(round: Optional[int] = None, cycle: Optional[int] = None,

@@ -109,18 +109,28 @@ class SpanBuffer:
                 self._group = str(group)
 
     # -- recording ------------------------------------------------------------
-    def add(self, cat: str, name: str, t0: float, t1: float,
-            round: Optional[int] = None, group: Optional[str] = None,
-            **args):
-        """Record one closed span.  ``round=None``/``group=None``
-        inherit the current context; args must be JSON-serializable
-        (they ride the scrape reply verbatim)."""
+    def reserve(self) -> int:
+        """Take the next ``seq`` now, for a span that closes later: its
+        children close first and name it as their ``parent``."""
         with self._lock:
             self._seq += 1
+            return self._seq
+
+    def add(self, cat: str, name: str, t0: float, t1: float,
+            round: Optional[int] = None, group: Optional[str] = None,
+            seq: Optional[int] = None, **args):
+        """Record one closed span.  ``round=None``/``group=None``
+        inherit the current context; ``seq`` is one :meth:`reserve`
+        handed out (None takes the next); args must be
+        JSON-serializable (they ride the scrape reply verbatim)."""
+        with self._lock:
+            if seq is None:
+                self._seq += 1
+                seq = self._seq
             if len(self._spans) >= self.capacity:
                 self.dropped += 1
             self._spans.append({
-                "seq": self._seq, "cat": str(cat), "name": str(name),
+                "seq": int(seq), "cat": str(cat), "name": str(name),
                 "t0": float(t0), "t1": float(t1),
                 "round": self._round if round is None else int(round),
                 "group": self._group if group is None else str(group),

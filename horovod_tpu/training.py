@@ -29,6 +29,16 @@ import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+# Names the jitted data-parallel steps put on their parts
+# (``jax.named_scope``: metadata only, the compiled program is the same).
+# They reach ``compiled.as_text()`` as ``op_name="jit(..)/../<name>/.."``
+# and xprof's op names: the forward pass reads ``jvp(hvd_forward)``, the
+# backward pass ``transpose(jvp(hvd_forward))``.
+SCOPE_FORWARD = "hvd_forward"      # model and loss, inside the differentiated fn
+SCOPE_REDUCE = "hvd_reduce"        # explicit gradient scaling / pmeans
+SCOPE_OPTIMIZER = "hvd_optimizer"  # optimizer.update + apply_updates
+SCOPE_SYNC_BN = "hvd_sync_bn"      # SyncBN's psum of the batch statistics
+
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec
 from .parallel.mesh import ParallelMesh
@@ -663,30 +673,33 @@ def make_classifier_train_step(forward_fn, model_init_fn, pmesh: ParallelMesh,
     data_spec = P(dp_axis)
 
     def local_loss(params, state, images, labels):
-        logits, new_state = forward_fn(params, state, images, train=True,
-                                       axis_name=bn_axis)
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, labels).mean()
-        acc = (logits.argmax(-1) == labels).mean()
+        with jax.named_scope(SCOPE_FORWARD):
+            logits, new_state = forward_fn(params, state, images,
+                                           train=True, axis_name=bn_axis)
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, labels).mean()
+            acc = (logits.argmax(-1) == labels).mean()
         return loss, (new_state, acc)
 
     def shard_step(params, state, opt_state, images, labels):
         (loss, (state, acc)), grads = jax.value_and_grad(
             local_loss, has_aux=True)(params, state, images, labels)
         if dp > 1:
-            # check_vma inserted the cross-shard psum; normalize the
-            # summed gradient of the per-shard mean losses
-            grads = jax.tree_util.tree_map(
-                lambda g: g * jnp.asarray(1.0 / dp, g.dtype), grads)
-            loss = lax.pmean(loss, "dp")
-            acc = lax.pmean(acc, "dp")
-            if not sync_bn:
-                # unsynced batch stats diverge per shard; average so the
-                # replicated state stays identical everywhere
-                state = jax.tree_util.tree_map(
-                    lambda s: lax.pmean(s, "dp"), state)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+            with jax.named_scope(SCOPE_REDUCE):
+                # check_vma inserted the cross-shard psum; normalize the
+                # summed gradient of the per-shard mean losses
+                grads = jax.tree_util.tree_map(
+                    lambda g: g * jnp.asarray(1.0 / dp, g.dtype), grads)
+                loss = lax.pmean(loss, "dp")
+                acc = lax.pmean(acc, "dp")
+                if not sync_bn:
+                    # unsynced batch stats diverge per shard; average so
+                    # the replicated state stays identical everywhere
+                    state = jax.tree_util.tree_map(
+                        lambda s: lax.pmean(s, "dp"), state)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, state, opt_state, loss, acc
 
     step_fn = jax.jit(jax.shard_map(
