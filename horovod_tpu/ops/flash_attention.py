@@ -18,6 +18,29 @@ The logsumexp residual is stored blocked as ``[B, H, nq, bq]`` — the
 (nq, bq) trailing dims are full blocks, which satisfies Mosaic's tiling
 rule without the 128-lane padding the naive ``[B, H, T]`` layout needs.
 
+Two paths, chosen from the shapes alone (:func:`_pack`; no knob):
+
+* **tiled** — the sequence spans several blocks (``nq > 1`` or
+  ``nkv > 1``): one (batch, head, block) per grid step, the
+  online-softmax loop over kv blocks, ``hvd_flash_fwd`` /
+  ``hvd_flash_dq`` / ``hvd_flash_dkv`` in ``[B, H, T, D]`` layout.
+* **packed** — the whole sequence is one block (BERT's 128 tokens; any
+  ``T <= 512``): a grid step costs about 0.4 us on a v5e whatever it
+  does, and one (batch, head) pair of a short sequence is less work
+  than that.  So a step takes a pack of ``bb`` batch rows x ``hb`` heads,
+  as large as ``_VMEM_BUDGET`` holds.  The kernels read the caller's
+  layout as ``[B, T, H*D]`` (a free reshape: no transposes around the
+  call), cut on 128-lane tiles; with ``D = 64`` a tile holds two heads
+  and each head's products run on the whole tile with the other head's
+  lanes zeroed, which costs the MXU nothing (it is 128 wide either way)
+  and keeps every load and store aligned.  With all of S in one block
+  the backward is one kernel, ``hvd_flash_bwd``: S, P, dP and dS are
+  computed once and dq, dk, dv written from them.  Same products in the
+  same dtypes as the tiled kernels.
+
+``hvd_flash_kernel_total{kernel, path}`` counts the kernels built, once
+per traced call site, so a program says which path its shapes took.
+
 Falls back cleanly: :func:`supported` gates on platform/shape so callers
 (e.g. ``local_attention``) can pick the XLA blockwise path on CPU meshes
 or odd shapes — on a TPU backend each refused shape is logged once, at
@@ -37,11 +60,24 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .. import metrics as _metrics
+
 logger = logging.getLogger("horovod_tpu")
 
 NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
+_LANES = 128
+
+_m_kernels = _metrics.counter(
+    "hvd_flash_kernel_total",
+    "Flash-attention Pallas kernels built, one per traced call site",
+    labels=("kernel", "path"))
+
+
+def _count(kernel: str, path: str) -> None:
+    if _metrics.ACTIVE:
+        _m_kernels.inc(kernel=kernel, path=path)
 
 
 def _block_sizes(t_q: int, t_kv: int):
@@ -62,6 +98,52 @@ def _block_sizes(t_q: int, t_kv: int):
     bq = min(blk, t_q)
     bk = min(blk, t_kv)
     return bq, bk
+
+
+def _lane_tile(D):
+    """(lanes, heads) of one lane tile of the packed layout ``[B, T, H*D]``:
+    128 lanes holding ``128 // D`` heads, or one head of ``D`` lanes."""
+    lanes = max(D, _LANES)
+    return lanes, lanes // D
+
+
+def _packed_resident(bb, hb, g, T, Tk, D, itemsize):
+    """VMEM bytes a packed grid step holds, counted for the backward
+    kernel, the larger: its nine blocks (q, do, dq of ``hb`` heads, k, v,
+    dk, dv of ``hb // g``, two rows of statistics), each double-buffered
+    by the pipeline, and six fp32 ``[T, Tk]`` temporaries of the one pair
+    in flight."""
+    blocks = (itemsize * D * (3 * T * hb + 4 * Tk * (hb // g))
+              + 2 * hb * 8 * T * 4)         # a (1, T) fp32 row pads to 8
+    return 2 * bb * blocks + 6 * T * Tk * 4
+
+
+def _pack(B, H, Hkv, T, Tk, D, itemsize):
+    """``(bb, hb)``: batch rows and query heads one grid step takes.
+
+    ``(1, 1)`` is the tiled path.  A sequence that is one block
+    (``nq == nkv == 1``) is packed: the largest ``hb`` dividing ``H``,
+    then the largest ``bb`` dividing ``B``, that
+    :func:`_packed_resident` keeps under ``_VMEM_BUDGET``.  The packed
+    kernels cut ``[B, T, H*D]`` on 128-lane tiles, so ``hb`` holds whole
+    tiles (two heads at ``D = 64``) and whole GQA groups; where a head
+    neither fills nor evenly divides 128 lanes, or a tile of several
+    heads would meet a GQA group, the shape stays on the tiled path."""
+    g = H // Hkv
+    lanes, per_tile = _lane_tile(D)
+    if ((T, Tk) != _block_sizes(T, Tk) or lanes % _LANES
+            or per_tile * D != lanes or (per_tile > 1 and g > 1)):
+        return 1, 1
+
+    def fits(bb, hb):
+        return _packed_resident(bb, hb, g, T, Tk, D,
+                                itemsize) <= _VMEM_BUDGET
+
+    for hb in range(H, 0, -1):
+        if H % hb == 0 and hb % (per_tile * g) == 0 and fits(1, hb):
+            return max(b for b in range(1, B + 1)
+                       if B % b == 0 and fits(b, hb)), hb
+    return 1, 1
 
 
 def _sds(shape, dtype, *operands):
@@ -170,6 +252,7 @@ def _flash_fwd_bhtd(q, k, v, causal, scale):
     bq, bk = _block_sizes(T, Tk)
     nq, nkv = T // bq, Tk // bk
 
+    _count("fwd", "tiled")
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bk=bk, nkv=nkv)
     out, lse = pl.pallas_call(
@@ -293,6 +376,8 @@ def _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale, dlse=None):
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
 
+    _count("dq", "tiled")
+    _count("dkv", "tiled")
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, bk=bk,
                           nkv=nkv),
@@ -337,6 +422,182 @@ def _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale, dlse=None):
     return dq, dk, dv
 
 
+# ------------------------------------------------- packed (one-block) path
+# The whole sequence in one block: no loop over kv blocks, no running
+# maximum to correct, and a grid step takes a pack of (batch row, head)
+# pairs.  Arrays are [B, T, H*D], cut on lane tiles of max(D, 128).
+
+def _head_of(x, w, D):
+    """Lane tile ``x`` with every head but its ``w``-th zeroed: a product
+    contracted over the tile then sums that head's lanes alone, and one
+    that keeps the tile's lanes is zero outside them."""
+    if x.shape[1] == D:
+        return x
+    lane = lax.broadcasted_iota(jnp.int32, (1, x.shape[1]), 1)
+    return jnp.where(lane // D == w, x, jnp.zeros_like(x))
+
+
+def _add(acc, x):
+    return x if acc is None else acc + x
+
+
+def _scores(q, k, scale, causal):
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    if causal:
+        rows = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols <= rows, s, NEG_INF)
+    return s
+
+
+def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                       causal, D, g):
+    bb, T, width = q_ref.shape
+    L, per_tile = _lane_tile(D)
+
+    def row(b, carry):
+        for t in range(width // L):          # static: lane tiles, heads
+            kv = (t // g) * L
+            q = q_ref[b, :, t * L:(t + 1) * L]
+            k, v = k_ref[b, :, kv:kv + L], v_ref[b, :, kv:kv + L]
+            o = None
+            for w in range(per_tile):
+                vw = _head_of(v, w, D)
+                s = _scores(q, _head_of(k, w, D), scale, causal)
+                m = s.max(axis=-1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = p.sum(axis=-1, keepdims=True)
+                o = _add(o, jnp.dot(p.astype(vw.dtype), vw,
+                                    preferred_element_type=jnp.float32) / l)
+                lse_ref[b, t * per_tile + w, 0, :] = (
+                    m + jnp.log(l)).reshape(T)
+            o_ref[b, :, t * L:(t + 1) * L] = o.astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, bb, row, 0)
+
+
+def _packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, *, scale, causal, D, g):
+    bb, T, width = q_ref.shape
+    L, per_tile = _lane_tile(D)
+
+    def row(b, carry):
+        for c in range(width // L // g):     # static: kv tiles, their group
+            kv = slice(c * L, (c + 1) * L)
+            k, v = k_ref[b, :, kv], v_ref[b, :, kv].astype(jnp.float32)
+            dk = dv = None
+            for t in range(c * g, (c + 1) * g):
+                lanes = slice(t * L, (t + 1) * L)
+                q = q_ref[b, :, lanes]
+                do = do_ref[b, :, lanes].astype(jnp.float32)
+                dq = None
+                for w in range(per_tile):
+                    h = t * per_tile + w
+                    # dow's zeros keep dp to this head: v stays whole
+                    qw, kw, dow = (_head_of(x, w, D) for x in (q, k, do))
+                    lse = lse_ref[b, h, 0, :].reshape(T, 1)
+                    delta = delta_ref[b, h, 0, :].reshape(T, 1)
+                    p = jnp.exp(_scores(qw, kw, scale, causal) - lse)
+                    dv = _add(dv, lax.dot_general(
+                        p, dow, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                    dp = lax.dot_general(dow, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                    ds = p * (dp - delta) * scale
+                    dq = _add(dq, jnp.dot(
+                        ds.astype(kw.dtype), kw,
+                        preferred_element_type=jnp.float32))
+                    dk = _add(dk, lax.dot_general(
+                        ds, qw.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                dq_ref[b, :, lanes] = dq.astype(dq_ref.dtype)
+            dk_ref[b, :, kv] = dk.astype(dk_ref.dtype)
+            dv_ref[b, :, kv] = dv.astype(dv_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, bb, row, 0)
+
+
+def _packed_specs(q, k, D, pack):
+    B, T, HD = q.shape
+    H = HD // D
+    g = H // (k.shape[2] // D)
+    bb, hb = pack
+    q_blk = pl.BlockSpec((bb, T, hb * D), lambda b, h: (b, 0, h))
+    kv_blk = pl.BlockSpec((bb, k.shape[1], (hb // g) * D),
+                          lambda b, h: (b, 0, h))
+    row_blk = pl.BlockSpec((bb, hb, 1, T), lambda b, h: (b, h, 0, 0))
+    return (B // bb, H // hb), q_blk, kv_blk, row_blk, g
+
+
+def _packed_fwd(q, k, v, causal, scale, D, pack):
+    """q [B,T,H*D], k/v [B,Tk,Hkv*D] → (out [B,T,H*D], lse [B,H,1,T])."""
+    grid, q_blk, kv_blk, row_blk, g = _packed_specs(q, k, D, pack)
+    B, T, HD = q.shape
+    _count("fwd", "packed")
+    return pl.pallas_call(
+        functools.partial(_packed_fwd_kernel, scale=scale, causal=causal,
+                          D=D, g=g),
+        grid=grid,
+        in_specs=[q_blk, kv_blk, kv_blk],
+        out_specs=[q_blk, row_blk],
+        out_shape=[
+            _sds(q.shape, q.dtype, q, k, v),
+            _sds((B, HD // D, 1, T), jnp.float32, q, k, v),
+        ],
+        interpret=_INTERPRET,
+        name="hvd_flash_fwd",
+    )(q, k, v)
+
+
+def _packed_bwd(q, k, v, out, lse, do, dlse, causal, scale, D, pack):
+    grid, q_blk, kv_blk, row_blk, g = _packed_specs(q, k, D, pack)
+    B, T, HD = q.shape
+    # delta as in the tiled path (rowsum(dO * O), less the lse cotangent),
+    # per head of the flat layout
+    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        B, T, HD // D, D).sum(-1).transpose(0, 2, 1)[:, :, None, :]
+    delta = delta - dlse.astype(jnp.float32)
+    _count("bwd", "packed")
+    return pl.pallas_call(
+        functools.partial(_packed_bwd_kernel, scale=scale, causal=causal,
+                          D=D, g=g),
+        grid=grid,
+        in_specs=[q_blk, kv_blk, kv_blk, q_blk, row_blk, row_blk],
+        out_specs=[q_blk, kv_blk, kv_blk],
+        out_shape=[
+            _sds(q.shape, q.dtype, q, k, v, do),
+            _sds(k.shape, k.dtype, q, k, v, do),
+            _sds(v.shape, v.dtype, q, k, v, do),
+        ],
+        interpret=_INTERPRET,
+        name="hvd_flash_bwd",
+    )(q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _packed_attention_lse(q, k, v, causal, scale, D, pack):
+    return _packed_fwd(q, k, v, causal, scale, D, pack)
+
+
+def _packed_attention_lse_fwd(q, k, v, causal, scale, D, pack):
+    out, lse = _packed_fwd(q, k, v, causal, scale, D, pack)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _packed_attention_lse_bwd(causal, scale, D, pack, res, cotangents):
+    do, dlse = cotangents
+    q, k, v, out, lse = res
+    return tuple(_packed_bwd(q, k, v, out, lse, do, dlse, causal, scale, D,
+                             pack))
+
+
+_packed_attention_lse.defvjp(_packed_attention_lse_fwd,
+                             _packed_attention_lse_bwd)
+
+
 # ------------------------------------------------------------- public op
 # The GQA group reshape in _dkv_kernel's q block assumes query heads of
 # one kv group are contiguous (head h ↔ kv head h // g), matching
@@ -365,16 +626,30 @@ _flash_attention_lse.defvjp(_flash_attention_lse_fwd,
                             _flash_attention_lse_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None):
-    """Fused exact attention.  ``q [B,T,H,D]``, ``k/v [B,Tk,Hkv,D]``."""
+def _attention_lse(q, k, v, causal, sm_scale):
+    """Both public entry points: ``(out [B,T,H,D], lse [B,H,T])`` by the
+    path :func:`_pack` gives these shapes."""
     scale = float(sm_scale if sm_scale is not None
                   else q.shape[-1] ** -0.5)
+    B, T, H, D = q.shape
+    Tk, Hkv = k.shape[1], k.shape[2]
+    pack = _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize)
+    if pack != (1, 1):
+        out, lse = _packed_attention_lse(
+            q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
+            v.reshape(B, Tk, Hkv * D), bool(causal), scale, D, pack)
+        return out.reshape(B, T, H, D), lse.reshape(B, H, T)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out, _ = _flash_attention_lse(qt, kt, vt, bool(causal), scale)
-    return out.transpose(0, 2, 1, 3)
+    out, lse = _flash_attention_lse(qt, kt, vt, bool(causal), scale)
+    return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
+
+
+def flash_attention(q, k, v, causal: bool = True,
+                    sm_scale: Optional[float] = None):
+    """Fused exact attention.  ``q [B,T,H,D]``, ``k/v [B,Tk,Hkv,D]``."""
+    return _attention_lse(q, k, v, causal, sm_scale)[0]
 
 
 def flash_attention_lse(q, k, v, causal: bool = True,
@@ -387,11 +662,4 @@ def flash_attention_lse(q, k, v, causal: bool = True,
     through both outputs (the lse cotangent folds into the backward
     kernels' delta term).
     """
-    scale = float(sm_scale if sm_scale is not None
-                  else q.shape[-1] ** -0.5)
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out, lse = _flash_attention_lse(qt, kt, vt, bool(causal), scale)
-    B, H, T, _ = qt.shape
-    return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
+    return _attention_lse(q, k, v, causal, sm_scale)

@@ -39,6 +39,30 @@ def make_qkv(B, T, H, Hkv, D, dtype=jnp.float32, seed=0):
     return q, k, v
 
 
+# One-block shapes and the pack (bb, hb) each must take; (1, 1) is the
+# tiled path.  In the last two the pack the rule would prefer does not
+# fit: all six heads under a smaller budget, all six rows under the real.
+PACKED_CASES = [
+    # (B, T, H, Hkv, D), causal, dtype, VMEM budget, pack
+    ((4, 128, 12, 12, 64), False, jnp.float32, None, (1, 12)),   # BERT
+    ((4, 128, 12, 12, 64), False, jnp.bfloat16, None, (2, 12)),
+    ((2, 128, 4, 4, 64), True, jnp.float32, None, (2, 4)),
+    ((1, 128, 8, 2, 128), True, jnp.float32, None, (1, 8)),      # GQA
+    ((2, 128, 8, 2, 64), False, jnp.float32, None, (1, 1)),      # GQA, D=64
+    ((3, 128, 6, 6, 64), True, jnp.float32, 2 << 20, (1, 2)),
+    ((6, 128, 2, 2, 128), False, jnp.float32, None, (3, 2)),
+]
+
+
+def _budget(monkeypatch, budget):
+    if budget is not None:
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", budget)
+
+
+def _tol(dtype):
+    return 2e-3 if dtype == jnp.float32 else 3e-2
+
+
 @pytest.mark.parametrize("shape,causal", [
     ((1, 256, 2, 2, 64), True),
     ((2, 256, 4, 2, 64), True),     # GQA
@@ -55,10 +79,37 @@ def test_pallas_kernel_interpret(shape, causal, monkeypatch):
                                np.asarray(ref), atol=2e-3, rtol=2e-3)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_pallas_kernel_grads_interpret(causal, monkeypatch):
+@pytest.mark.parametrize("shape,causal,dtype,budget,pack", PACKED_CASES)
+def test_packed_kernel_interpret(shape, causal, dtype, budget, pack,
+                                 monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    q, k, v = make_qkv(1, 256, 4, 2, 64, seed=3)
+    _budget(monkeypatch, budget)
+    B, T, H, Hkv, D = shape
+    q, k, v = make_qkv(B, T, H, Hkv, D, dtype)
+    assert fa.supported(q, k, v, causal)
+    assert fa._pack(B, H, Hkv, T, T, D, q.dtype.itemsize) == pack
+    out = fa.flash_attention(q, k, v, causal=causal)
+    assert out.dtype == dtype and out.shape == q.shape
+    ref = dense_reference(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), atol=_tol(dtype),
+                               rtol=_tol(dtype))
+
+
+@pytest.mark.parametrize("shape,causal,dtype,budget,block", [
+    ((1, 256, 4, 2, 64), True, jnp.float32, None, None),
+    ((1, 256, 4, 2, 64), False, jnp.float32, None, None),
+    # several blocks a sequence: the tiled kernels' loops over blocks
+    ((1, 256, 4, 2, 64), True, jnp.float32, None, 128),
+    ((2, 256, 2, 2, 128), False, jnp.float32, None, 128),
+] + [case[:4] + (None,) for case in PACKED_CASES])
+def test_pallas_kernel_grads_interpret(shape, causal, dtype, budget, block,
+                                       monkeypatch):
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    _budget(monkeypatch, budget)
+    if block is not None:
+        monkeypatch.setenv("HOROVOD_FLASH_BLOCK", str(block))
+    q, k, v = make_qkv(*shape, dtype, seed=3)
 
     def loss_f(q, k, v):
         o = fa.flash_attention(q, k, v, causal=causal)
@@ -69,9 +120,12 @@ def test_pallas_kernel_grads_interpret(causal, monkeypatch):
 
     gf = jax.grad(loss_f, (0, 1, 2))(q, k, v)
     gr = jax.grad(loss_r, (0, 1, 2))(q, k, v)
+    tol = 5e-3 if dtype == jnp.float32 else 6e-2
     for a, b in zip(gf, gr):
+        assert a.dtype == dtype
         np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b), atol=5e-3, rtol=5e-3)
+                                   np.asarray(b, np.float32), atol=tol,
+                                   rtol=tol)
 
 
 @pytest.mark.parametrize("T,Hkv,blk", [
@@ -129,11 +183,17 @@ def test_flash_attention_lse_interpret(causal, monkeypatch):
                                atol=2e-5)
 
 
-def test_flash_attention_lse_grads_interpret(monkeypatch):
+@pytest.mark.parametrize("shape", [
+    (1, 128, 2, 1, 64),      # tiled: a GQA group at D=64
+    (2, 128, 4, 4, 64),      # packed, two heads a lane tile
+    (1, 128, 4, 2, 128),     # packed, GQA
+])
+def test_flash_attention_lse_grads_interpret(shape, monkeypatch):
     """Gradients flow through BOTH outputs (the lse cotangent folds into
     the backward kernels' delta term)."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    q, k, v = make_qkv(1, 128, 2, 1, 64, seed=3)
+    q, k, v = make_qkv(*shape, seed=3)
+    g = shape[2] // shape[3]
 
     def loss_kernel(q, k, v):
         out, lse = fa.flash_attention_lse(q, k, v, causal=True)
@@ -141,7 +201,7 @@ def test_flash_attention_lse_grads_interpret(monkeypatch):
 
     def loss_dense(q, k, v):
         s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       jnp.repeat(k, 2, 2).astype(jnp.float32)
+                       jnp.repeat(k, g, 2).astype(jnp.float32)
                        ) * (q.shape[-1] ** -0.5)
         T = q.shape[1]
         s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None],
@@ -149,7 +209,7 @@ def test_flash_attention_lse_grads_interpret(monkeypatch):
         lse = jax.scipy.special.logsumexp(s, axis=-1)
         p = jnp.exp(s - lse[..., None])
         out = jnp.einsum("bhqk,bkhd->bqhd", p,
-                         jnp.repeat(v, 2, 2).astype(jnp.float32))
+                         jnp.repeat(v, g, 2).astype(jnp.float32))
         return (out ** 2).sum() + 0.3 * (lse ** 2).sum()
 
     gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
@@ -259,3 +319,148 @@ def test_refusal_on_a_tpu_backend_is_logged_once_per_shape(
     assert len(said) == 2 and all("falling back" in m for m in said)
     assert "flash_attention" in said[0] and "head_dim 48" in said[0]
     assert "fused_xent" in said[1] and "d_model 96" in said[1]
+
+
+# --- the pack rule, and which path a traced call took ------------------------
+
+def _pallas_calls(fn, *args):
+    """[(name, grid, [block shapes])] of every pallas_call ``fn`` traces."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                found.append((
+                    eqn.params["name"], tuple(gm.grid),
+                    [tuple(getattr(d, "block_size", d)
+                           for d in bm.block_shape)
+                     for bm in gm.block_mappings]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _kernel_counts():
+    from horovod_tpu import metrics
+    family = metrics.registry().to_dict().get("hvd_flash_kernel_total", {})
+    return {(s["labels"]["kernel"], s["labels"]["path"]): s["value"]
+            for s in family.get("series", [])}
+
+
+def _grad_all(q, k, v, causal):
+    return jax.grad(lambda q, k, v: (fa.flash_attention(
+        q, k, v, causal=causal).astype(jnp.float32) ** 2).sum(),
+        (0, 1, 2))(q, k, v)
+
+
+def test_pack_rule():
+    # BERT-base as benchmarked: every head of a row in one grid step
+    bb, hb = fa._pack(32, 12, 12, 128, 128, 64, 2)
+    assert hb == 12 and (32 // bb) * (12 // hb) < 64
+    # a long sequence is several blocks: the tiled path
+    assert fa._block_sizes(2048, 2048) == (512, 512)
+    assert fa._pack(2, 16, 16, 2048, 2048, 128, 2) == (1, 1)
+    assert fa._pack(2, 16, 4, 2048, 512, 128, 2) == (1, 1)
+    # a head's scores at T=512 are 1 MB: a smaller pack, not none
+    assert fa._pack(8, 16, 16, 512, 512, 128, 2) == (1, 2)
+    # two heads share a lane tile at D=64: no tile may meet a GQA group
+    assert fa._pack(4, 8, 2, 128, 128, 64, 2) == (1, 1)
+    assert fa._pack(4, 3, 3, 128, 128, 64, 2) == (1, 1)   # odd head count
+    assert fa._pack(2, 4, 4, 128, 128, 192, 2) == (1, 1)  # 192 lanes
+    # nothing chosen is over the budget, and it divides (B, H) in whole
+    # lane tiles and GQA groups
+    for B in (1, 2, 3, 8, 32):
+        for H, Hkv in ((2, 2), (6, 6), (12, 12), (16, 4), (16, 16), (64, 64)):
+            for T in (128, 256, 512):
+                for D in (64, 128, 256):
+                    for itemsize in (2, 4):
+                        bb, hb = fa._pack(B, H, Hkv, T, T, D, itemsize)
+                        g = H // Hkv
+                        assert B % bb == 0 and H % hb == 0
+                        if (bb, hb) == (1, 1):
+                            continue
+                        assert hb % (g * max(1, 128 // D)) == 0
+                        assert fa._packed_resident(
+                            bb, hb, g, T, T, D, itemsize) <= fa._VMEM_BUDGET
+
+
+def test_tiled_path_is_the_parents_spec_for_spec(monkeypatch):
+    """Several blocks a sequence: the three pallas_calls of the parent
+    commit, grid and block shapes pinned here as that commit built them."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    B, T, H, Hkv, D = 1, 2048, 4, 2, 128
+    bq = bk = 512
+    nq = nkv = T // bq
+    g = H // Hkv
+    q, k, v = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16)
+               for h in (H, Hkv, Hkv))
+    calls = _pallas_calls(lambda q, k, v: _grad_all(q, k, v, True), q, k, v)
+    qb, kvb, row = (1, 1, bq, D), (1, 1, T, D), (1, 1, nq, bq)
+    assert calls == [
+        ("hvd_flash_fwd", (B, H, nq), [qb, kvb, kvb, qb, row]),
+        ("hvd_flash_dq", (B, H, nq), [qb, kvb, kvb, qb, row, row, qb]),
+        ("hvd_flash_dkv", (B, Hkv, nkv),
+         [(1, g, T, D), (1, 1, bk, D), (1, 1, bk, D), (1, g, T, D),
+          (1, g, nq, bq), (1, g, nq, bq), (1, 1, bk, D), (1, 1, bk, D)]),
+    ]
+
+
+def test_packed_path_specs_and_counter(monkeypatch):
+    """BERT's block: one forward and ONE backward kernel, all heads of
+    two rows a grid step, in the caller's layout (no transposes); the
+    counter says which path each traced call took."""
+    from horovod_tpu import metrics
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+    B, T, H, D = 32, 128, 12, 64
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16)
+    before = _kernel_counts()
+    calls = _pallas_calls(lambda q, k, v: _grad_all(q, k, v, False), q, q, q)
+    blk, row = (2, T, H * D), (2, H, 1, T)
+    assert calls == [
+        ("hvd_flash_fwd", (16, 1), [blk, blk, blk, blk, row]),
+        ("hvd_flash_bwd", (16, 1), [blk, blk, blk, blk, row, row,
+                                    blk, blk, blk]),
+    ]
+    jaxpr = str(jax.make_jaxpr(
+        lambda q, k, v: _grad_all(q, k, v, False))(q, q, q))
+    assert "transpose[permutation=(0, 2, 1, 3)]" not in jaxpr
+    after = _kernel_counts()
+    grew = {key: after[key] - before.get(key, 0) for key in after
+            if after[key] != before.get(key, 0)}
+    assert set(grew) == {("fwd", "packed"), ("bwd", "packed")}
+
+    long = jax.ShapeDtypeStruct((1, 1024, 2, 128), jnp.bfloat16)
+    jax.make_jaxpr(lambda q, k, v: _grad_all(q, k, v, True))(long, long, long)
+    later = _kernel_counts()
+    grew = {key for key in later if later[key] != after.get(key, 0)}
+    assert grew == {("fwd", "tiled"), ("dq", "tiled"), ("dkv", "tiled")}
+
+
+def test_packed_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the packed kernels at the benchmark's shape and at a
+    causal GQA one: compiled here for a v5e that is described, not
+    attached (interpret mode cannot see tiling or VMEM)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no TPU topology to compile for: {e}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    for (B, T, H, Hkv, D), causal in (((32, 128, 12, 12, 64), False),
+                                      ((2, 256, 8, 2, 128), True)):
+        assert fa._pack(B, H, Hkv, T, T, D, 2) != (1, 1)
+        q, k = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16,
+                                     sharding=one_chip) for h in (H, Hkv))
+        assert fa.supported(q, k, k, causal)
+        text = jax.jit(lambda q, k, v: _grad_all(q, k, v, causal)).lower(
+            q, k, k).compile().as_text()
+        assert "hvd_flash_fwd" in text and "hvd_flash_bwd" in text
+        assert "hvd_flash_dq" not in text

@@ -326,14 +326,22 @@ def test_scopes_change_nothing_but_metadata(build, dp, monkeypatch):
     assert _without_metadata(with_scopes) == _without_metadata(without)
 
 
-def test_flash_kernels_carry_their_names(monkeypatch):
+@pytest.mark.parametrize("seq_len,names", [
+    # one block a sequence: the packed path and its single backward kernel
+    (128, ("hvd_flash_fwd", "hvd_flash_bwd")),
+    # several: the tiled path
+    (1024, ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv")),
+])
+def test_flash_kernels_carry_their_names(seq_len, names, monkeypatch):
     from horovod_tpu.ops import flash_attention as fa
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    q = jax.ShapeDtypeStruct((1, seq_len, 2, 64), jnp.float32)
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=False).sum()
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
-    for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
-        assert name in text, name
+    found = {name for name in ("hvd_flash_fwd", "hvd_flash_bwd",
+                               "hvd_flash_dq", "hvd_flash_dkv")
+             if name in text}
+    assert found == set(names)
