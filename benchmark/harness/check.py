@@ -92,26 +92,35 @@ class Reference:
                 w, opt = update(cfg["optimizer"], w, g, opt, t)
             return w, opt, loss, leaf_norms(g)
 
-        def start(key):
-            w = reference.make_weights(cfg, key)
-            return w, init(w), jax.tree_util.tree_map(jnp.copy, w)
-
-        self._start = jax.jit(start, out_shardings=NamedSharding(mesh, P()))
+        replicated = NamedSharding(mesh, P())
+        self._weights = jax.jit(lambda key: reference.make_weights(cfg, key),
+                                out_shardings=replicated)
+        self._init = jax.jit(init, out_shardings=replicated)
         self._step = jax.jit(step, donate_argnums=(0, 1))
         self._delta = jax.jit(lambda a, b: leaf_norms({k: a[k] - b[k] for k in a}))
+
+    def _start(self, key):
+        w = self._weights(key)
+        return w, self._init(w)
 
     def run(self, weights_key, batches):
         """Python floats: {"losses": [..], "grad_norms": {leaf: n},
         "update_norms": {leaf: n}}."""
-        w, opt, w0 = self._start(weights_key)
+        w, opt = self._start(weights_key)
         losses, grad_norms = [], None
         for t, batch in enumerate(batches[:STEPS], start=1):
             batch = tuple(jax.device_put(a, self._rows) for a in batch)
             w, opt, loss, g_norms = self._step(w, opt, batch, jnp.float32(t))
             losses.append(loss)
             grad_norms = grad_norms or g_norms
-        out = jax.device_get({"losses": losses, "grad_norms": grad_norms,
-                              "update_norms": self._delta(w, w0)})
+        # The first weights are made again from the key for the difference,
+        # as run.py does for the program: a copy kept through the steps
+        # would be 4 of 20 bytes a parameter beside the float32 activations.
+        # By the program that made them, not inside ``_delta``: there they
+        # fuse into the sums and the norms' last bits move.
+        out = jax.device_get({
+            "losses": losses, "grad_norms": grad_norms,
+            "update_norms": self._delta(w, self._weights(weights_key))})
         return jax.tree_util.tree_map(float, out)
 
 

@@ -285,9 +285,10 @@ def run_cell(bench_dir, manifest_path, workload, seed, seconds, trace,
         device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed, "metrics": values, "device": device,
-              "compared": compared, "checks": checks}
+              "checks": checks}
     if reduced is not None and on_chip:
         result["breakdown"] = reduced.breakdown()
+    result["compared"] = compared       # each number beside its limit, last
     return result
 
 
@@ -306,6 +307,10 @@ def main(argv=None):
     result = run_cell(BENCH_DIR, os.path.join(REPO, "BENCHMARK.json"),
                       args.workload, args.seed, args.seconds, bool(args.trace),
                       t0=_T0, keep_trace=args.keep_trace)
+    # each number compared beside its limit, also at the end of standard error
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']:.6g} limit {c['limit']:g}",
+              file=sys.stderr, flush=True)
     say(json.dumps(result))
 
 

@@ -13,14 +13,6 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[2]
 BENCH = REPO / "benchmark"
 DATA_DIRS = ("configs", "traffic", "workloads", "layer_metrics", "end_to_end")
-TINY = {
-    "resnet50-synth": {"block": "basic", "stage_blocks": [2, 2, 2, 2],
-                       "width": 8, "num_classes": 10, "image_size": 32},
-    "bert-base-ft": {"vocab_size": 256, "hidden_size": 64,
-                     "num_hidden_layers": 2, "num_attention_heads": 4,
-                     "head_dim": 16, "intermediate_size": 128,
-                     "max_position_embeddings": 64, "seq_len": 32},
-}
 
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
@@ -30,18 +22,32 @@ def load(path):
     return json.loads(Path(path).read_text())
 
 
-def make_tree(tmp):
-    """``tmp/BENCHMARK.json`` and ``tmp/benchmark/<data dirs>``: the real
-    files, every configuration at a toy size in float32, every batch 8."""
-    tmp = Path(tmp)
-    shutil.copy(REPO / "BENCHMARK.json", tmp / "BENCHMARK.json")
+def copy_files(dest, source=REPO):
+    """``dest/BENCHMARK.json`` and ``dest/benchmark/<data dirs>`` as they
+    stand under ``source``."""
+    dest, source = Path(dest), Path(source)
+    dest.mkdir(parents=True, exist_ok=True)
+    shutil.copy(source / "BENCHMARK.json", dest / "BENCHMARK.json")
     for d in DATA_DIRS:
-        shutil.copytree(BENCH / d, tmp / "benchmark" / d,
+        shutil.copytree(source / "benchmark" / d, dest / "benchmark" / d,
                         ignore=shutil.ignore_patterns("__pycache__"))
-    for name, sizes in TINY.items():
-        path = tmp / "benchmark" / "configs" / name / "config.json"
+    return dest
+
+
+def make_tree(tmp, source=REPO):
+    """A copy of ``source``'s files for a rehearsal: every configuration
+    found there cut to the ``toy`` sizes of its own ``config.json``, in
+    float32, every batch 8.  A configuration that states no ``toy`` is
+    refused: at its published sizes a rehearsal on the CPU would not end."""
+    tmp = copy_files(tmp, source)
+    for path in sorted((tmp / "benchmark" / "configs").glob("*/config.json")):
         cfg = load(path)
-        cfg.update(sizes)
+        if not cfg.get("toy"):
+            raise ValueError(
+                f'configs/{path.parent.name}/config.json has no "toy": the '
+                "sizes a CPU rehearsal lays over the configuration; without "
+                "them it would be rehearsed at full size")
+        cfg.update(cfg["toy"])
         cfg["dtype"]["compute"] = "float32"
         path.write_text(json.dumps(cfg))
     for path in (tmp / "benchmark" / "traffic").glob("*.json"):

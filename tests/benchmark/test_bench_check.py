@@ -67,7 +67,7 @@ def test_bert_on_four_devices_matches_the_whole_batch_reference(tree, tmp_path):
     assert result["correct"] is True
 
 
-@pytest.mark.parametrize("config", ["resnet50-synth", "bert-base-ft"])
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
 def test_control_in_fp8_fails_the_limits_and_float32_passes(tree, config):
     """The reference in the program's place, computed one precision below
     bf16, must come out not correct; computed as itself it is exact."""
@@ -87,3 +87,19 @@ def test_control_in_fp8_fails_the_limits_and_float32_passes(tree, config):
     control = check.compare(check.Reference(
         reference, cfg, quant=check.quant_fp8).run(key, batches), ref)
     assert not check.within(control, reference.LIMITS), control
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
+def test_reference_keeps_weights_and_optimizer_state_and_no_copy(tree, config):
+    """16 bytes a parameter with the gradient, under AdamW: the first
+    weights are made again from the key for the difference at the end."""
+    import jax
+    cfg = bench_tree.load(tree / "benchmark" / "configs" / config / "config.json")
+    reference = registry.load_module(
+        str(bench_tree.BENCH / "configs" / config / "reference.py"))
+    kept = jax.eval_shape(check.Reference(reference, cfg)._start, jax.random.key(3))
+
+    def nbytes(t):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(t))
+
+    assert nbytes(kept) <= 3 * nbytes(kept[0])      # the gradient is the fourth

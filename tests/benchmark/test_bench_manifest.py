@@ -2,6 +2,7 @@
 utilisation metrics rest on."""
 
 import re
+from pathlib import Path
 
 import pytest
 
@@ -10,6 +11,10 @@ from harness import hlo, peaks, registry
 
 MANIFEST = bench_tree.load(bench_tree.REPO / "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+def _one_line(text):
+    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
 def test_every_name_is_a_contract_name():
@@ -21,44 +26,81 @@ def test_every_name_is_a_contract_name():
         == len(MANIFEST["end_to_end"]) + len(MANIFEST["per_layer"])
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_workload_file_agrees_with_manifest(cell):
-    loaded = registry.load_cell(str(bench_tree.BENCH), MANIFEST, cell)
-    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+# What a cell, a configuration and a per-layer metric have to satisfy, as
+# functions of a manifest and the root that holds its files: the tests below
+# hold the repo's own to them, and test_bench_add_cell.py a copy to which a
+# later PR's kind of files and entries have been added.
+
+def workload_file_agrees_with_manifest(manifest, root, cell):
+    bench = Path(root) / "benchmark"
+    loaded = registry.load_cell(str(bench), manifest, cell)
+    entry = next(w for w in manifest["workloads"] if w["name"] == cell)
     assert loaded.chips == entry["chips"] in (1, 4)
     assert len(entry["why"]) <= 200
-    assert bench_tree.load(bench_tree.BENCH / "workloads" / f"{cell}.json")["why"] \
+    assert bench_tree.load(bench / "workloads" / f"{cell}.json")["why"] \
         == entry["why"]
     assert sum(p["chips"] == entry["chips"] for p in loaded.phases) == 1
     assert abs(sum(p["share"] for p in loaded.phases) - 1) < 1e-9
     # a cell reports setup_s, another end-to-end metric and a per-layer one
-    e2e = [m["name"] for m in registry.metrics_for(MANIFEST, "end_to_end", cell)]
+    e2e = [m["name"] for m in registry.metrics_for(manifest, "end_to_end", cell)]
     assert "setup_s" in e2e and len(e2e) >= 2
-    assert registry.metrics_for(MANIFEST, "per_layer", cell)
+    assert registry.metrics_for(manifest, "per_layer", cell)
+
+
+# what the contract never lets ``reduced`` name: a width
+_WIDTH = re.compile(r"_dim$|_rank$|^width$|expansion|per_tok$|"
+                    r"(hidden|intermediate|latent|state|proj|head)\w*_size$")
+
+
+def config_file_states_what_is_run(manifest, root, config):
+    """``config.json`` is the configuration as run.  A cut is stated whole:
+    each key of ``reduced`` is a key of the file, ``published`` holds the
+    source's value for it, and ``deployment`` says in one line over how
+    many chips a layer is divided and how.  ``toy`` is what a rehearsal on
+    the CPU lays over the file (bench_tree.make_tree)."""
+    entry = next(c for c in manifest["configs"] if c["name"] == config)
+    cfg = bench_tree.load(Path(root) / entry["file"])
+    assert cfg["name"] == config and cfg["source"] == entry["source"]
+    assert cfg["reduced"] == entry["reduced"]
+    assert cfg["dtype"]["compute"] == "bfloat16" and cfg["dtype"]["params"] == "float32"
+    assert any(w["config"] == config for w in manifest["workloads"])
+    assert cfg["toy"] and set(cfg["toy"]) <= set(cfg), "toy sizes lie over keys of the file"
+    if cfg["reduced"]:
+        assert set(cfg["published"]) == set(cfg["reduced"]) <= set(cfg)
+        for key in cfg["reduced"]:
+            assert not _WIDTH.search(key), f"{key}: a width is never cut"
+            assert cfg["published"][key] != cfg[key], f"{key} is run as published"
+        assert _one_line(cfg["deployment"]) and re.search(r"\d", cfg["deployment"]), \
+            "over how many chips a layer is divided, and how"
+
+
+def layer_metric_file_agrees_with_manifest(manifest, root, metric):
+    entry = next(m for m in manifest["per_layer"] if m["name"] == metric)
+    mod = registry.load_module(
+        str(Path(root) / "benchmark" / "layer_metrics" / f"{metric}.py"))
+    assert (mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE) == (
+        entry["unit"], entry["layer"], entry["moves"], entry["source"])
+    assert entry["moves"] in {m["name"] for m in manifest["end_to_end"]}
+    # every cell that reads it reports the end-to-end metric it moves
+    moved = next(m for m in manifest["end_to_end"] if m["name"] == entry["moves"])
+    cells = entry.get("workloads") or [w["name"] for w in manifest["workloads"]]
+    assert set(cells) <= set(moved.get("workloads") or
+                             [w["name"] for w in manifest["workloads"]])
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_workload_file_agrees_with_manifest(cell):
+    workload_file_agrees_with_manifest(MANIFEST, bench_tree.REPO, cell)
 
 
 @pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
 def test_config_file_states_what_is_run(config):
-    entry = next(c for c in MANIFEST["configs"] if c["name"] == config)
-    cfg = bench_tree.load(bench_tree.REPO / entry["file"])
-    assert cfg["name"] == config and cfg["source"] == entry["source"]
-    assert cfg["reduced"] == entry["reduced"] == []
-    assert cfg["dtype"]["compute"] == "bfloat16" and cfg["dtype"]["params"] == "float32"
-    assert any(w["config"] == config for w in MANIFEST["workloads"])
+    config_file_states_what_is_run(MANIFEST, bench_tree.REPO, config)
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
 def test_layer_metric_file_agrees_with_manifest(metric):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    mod = registry.load_module(str(bench_tree.BENCH / "layer_metrics" / f"{metric}.py"))
-    assert (mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE) == (
-        entry["unit"], entry["layer"], entry["moves"], entry["source"])
-    assert entry["moves"] in {m["name"] for m in MANIFEST["end_to_end"]}
-    # every cell that reads it reports the end-to-end metric it moves
-    moved = next(m for m in MANIFEST["end_to_end"] if m["name"] == entry["moves"])
-    cells = entry.get("workloads") or [w["name"] for w in MANIFEST["workloads"]]
-    assert set(cells) <= set(moved.get("workloads") or
-                             [w["name"] for w in MANIFEST["workloads"]])
+    layer_metric_file_agrees_with_manifest(MANIFEST, bench_tree.REPO, metric)
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["end_to_end"]])
@@ -122,10 +164,6 @@ def test_all_reduce_bytes_from_hlo_text():
 _METRIC_KEYS = {"name", "unit", "better", "source"}
 _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-
-
-def _one_line(text):
-    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
 def test_manifest_keeps_the_contracts_form():
