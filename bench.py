@@ -232,8 +232,8 @@ def bench_longctx():
     batch, seq, steps = _env_batch(2), 8192, 10
     if on_cpu:
         cfg = dataclasses.replace(cfg, d_model=256, n_layers=2, n_heads=8,
-                                  n_kv_heads=4, d_ff=1024, vocab_size=4096,
-                                  max_seq_len=1024)
+                                  head_dim=0, n_kv_heads=4, d_ff=1024,
+                                  vocab_size=4096, max_seq_len=1024)
         batch, seq, steps = 1, 1024, 2
 
     n_chips = jax.local_device_count()

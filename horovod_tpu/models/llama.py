@@ -20,6 +20,7 @@ surface natively, and is the model the benchmarks drive.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -77,10 +78,30 @@ class LlamaConfig:
     # across shards (pmax + psum) so no full-vocab logits exist on any
     # shard.  Ignored when tp is off.
     vocab_parallel: bool = False
+    # width of a head; 0 = d_model // n_heads, worked out once when the
+    # config is made (so dataclasses.replace with another d_model or
+    # n_heads passes head_dim=0 to have it worked out again)
+    head_dim: int = 0
+    # RMSNorm with a learned weight over each head of q and of k, before
+    # RoPE
+    qk_norm: bool = False
+    # False: an output head ``params["head"] [V, D]`` of its own
+    tie_embeddings: bool = True
+    # experts (models/moe.py): "capacity" = static capacity, overflow
+    # tokens drop; "dropless" = no capacity, on the experts this chip
+    # holds: ``experts_held`` of the ``n_experts`` the router scores
+    # (0 = all), the first of them ``experts_first``
+    moe_dispatch: str = "capacity"
+    experts_held: int = 0
+    experts_first: int = 0
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.moe_dispatch not in ("capacity", "dropless"):
+            raise ValueError("moe_dispatch must be 'capacity' or "
+                             f"'dropless', got {self.moe_dispatch!r}")
 
 
 def llama3_8b() -> LlamaConfig:
@@ -145,21 +166,28 @@ def init_params(cfg: LlamaConfig, key, tp: int = 1) -> Dict:
         "wo": norm(k[4], (L, H * Dh, D), H * Dh),
         "mlp_norm": jnp.ones((L, D), cfg.param_dtype),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, Dh), cfg.param_dtype)
+        layers["k_norm"] = jnp.ones((L, Dh), cfg.param_dtype)
     if cfg.n_experts > 0:
         from .moe import init_moe_layer_params
         layers.update(init_moe_layer_params(
-            k[5], L, D, F, cfg.n_experts, cfg.param_dtype))
+            k[5], L, D, F, cfg.n_experts, cfg.param_dtype,
+            n_held=cfg.experts_held))
     else:
         layers.update({
             "w_gate": norm(k[5], (L, D, F), D),
             "w_up": norm(k[6], (L, D, F), D),
             "w_down": norm(k[7], (L, F, D), F),
         })
-    return {
+    params = {
         "embed": norm(k[0], (V, D), D),
         "layers": layers,
         "final_norm": jnp.ones((D,), cfg.param_dtype),
     }
+    if not cfg.tie_embeddings:
+        params["head"] = norm(jax.random.fold_in(key, 8), (V, D), D)
+    return params
 
 
 def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
@@ -183,6 +211,8 @@ def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
         "wo": P(pp, tp, None),
         "mlp_norm": P(pp, None),
     }
+    if cfg is not None and cfg.qk_norm:
+        layers.update({"q_norm": P(pp, None), "k_norm": P(pp, None)})
     if cfg is not None and cfg.n_experts > 0:
         ep = par.ep_axis
         layers.update({
@@ -197,11 +227,14 @@ def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
             "w_up": P(pp, None, tp),
             "w_down": P(pp, tp, None),
         })
-    return {
+    specs = {
         "embed": embed_spec,
         "layers": layers,
         "final_norm": P(),
     }
+    if cfg is not None and not cfg.tie_embeddings:
+        specs["head"] = embed_spec
+    return specs
 
 
 def _vp_active(cfg: LlamaConfig, par: ParallelSpec) -> bool:
@@ -288,8 +321,11 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-def _attention(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions):
-    """One attention sublayer on tp-local heads and sp-local sequence."""
+def _attention(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
+               mask=None):
+    """One attention sublayer on tp-local heads and sp-local sequence.
+    ``mask``: the key ranges each query row sees (ops/flash_attention.py);
+    None = causal."""
     B, Tl, D = x.shape
     Dh = cfg.head_dim
     # local head counts under tp (weights arrive pre-sharded)
@@ -298,14 +334,20 @@ def _attention(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions):
     q = (x @ lp["wq"].astype(x.dtype)).reshape(B, Tl, Hl, Dh)
     k = (x @ lp["wk"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
     v = (x @ lp["wv"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
+    if cfg.qk_norm:
+        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     # GQA kv heads pass through as-is: ring circulates only the Hkv heads,
     # ulysses repeats to lcm(Hkv, sp) internally only when it must.
     if par.attn == "ulysses":
+        if mask is not None:
+            raise NotImplementedError("ulysses attention is causal only")
         o = ulysses_attention(q, k, v, par.sp_axis, causal=True)
     else:
-        o = ring_attention(q, k, v, par.sp_axis, causal=True)
+        o = ring_attention(q, k, v, par.sp_axis, causal=mask is None,
+                           mask=mask)
     o = o.reshape(B, Tl, Hl * Dh) @ lp["wo"].astype(x.dtype)
     if par.tp_axis is not None:
         o = lax.psum(o, par.tp_axis)  # row-parallel output reduction
@@ -326,30 +368,44 @@ def ffn(pre, lp, cfg: LlamaConfig, par: ParallelSpec):
     Returns (y, aux_loss) — the single dispatch point shared by the
     training block and the KV-cache decode path."""
     if cfg.n_experts > 0:
-        from .moe import moe_layer
-        return moe_layer(pre, lp, cfg, par)
+        from . import moe
+        if cfg.moe_dispatch == "dropless":
+            return moe.dropless_moe_layer(pre, lp, cfg, par)
+        return moe.moe_layer(pre, lp, cfg, par)
     return _mlp(pre, lp, par), jnp.float32(0.0)
 
 
-def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions):
+def _dropless(cfg: LlamaConfig) -> bool:
+    return cfg.n_experts > 0 and cfg.moe_dispatch == "dropless"
+
+
+def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
+          mask=None):
     """One transformer block (shape-preserving — the pipeline stage unit).
-    Returns (x, aux_loss) — aux is 0 for dense MLPs."""
+    Returns (x, aux): the load-balance loss of capacity experts (0 for
+    dense MLPs), or dropless experts' ``[4]`` routing statistics."""
     x = x + _attention(_rmsnorm(x, lp["attn_norm"], cfg.norm_eps),
-                       lp, cfg, par, positions)
+                       lp, cfg, par, positions, mask)
     y, aux = ffn(_rmsnorm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg, par)
     return x + y, aux
 
 
-def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions):
+def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions,
+                 mask=None):
     # Cast the whole stacked weight tree to compute dtype ONCE before the
     # scan: per-layer `.astype` inside the body re-converts every fp32
     # weight slice in both fwd and bwd scans (~16% matmul slowdown
     # measured); one bulk convert amortizes it and the bwd scan reuses
     # the converted stack as a residual.
-    layers = jax.tree_util.tree_map(
-        lambda w: w.astype(cfg.dtype) if w.dtype != cfg.dtype else w,
-        layers)
-    body = block
+    # (a dropless router stays in the parameters' precision: its logits
+    # decide a top-k, and are computed in float32)
+    keep = ("router",) if _dropless(cfg) else ()
+    layers = {n: w if n in keep or w.dtype == cfg.dtype
+              else w.astype(cfg.dtype) for n, w in layers.items()}
+    # a mask is closed over, not an argument: one known here (numpy)
+    # stays so for the kernels' tile tables
+    blk = block if mask is None else functools.partial(block, mask=mask)
+    body = blk
     if cfg.remat:
         if cfg.remat_policy not in ("full", "dots"):
             raise ValueError(
@@ -378,6 +434,8 @@ def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions):
     # axes — a fresh constant would be invariant and fail check_vma's
     # carry-type check once the MoE aux (data-dependent) joins it
     aux0 = (h.astype(jnp.float32) * 0).sum()
+    if _dropless(cfg):
+        aux0 = aux0 + jnp.zeros((4,), jnp.float32)   # routing statistics
     n_local = jax.tree_util.tree_leaves(layers)[0].shape[0]
     k = min(cfg.remat_skip_layers, n_local) if cfg.remat else 0
     if k > 0:
@@ -387,25 +445,33 @@ def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions):
         first = jax.tree_util.tree_map(lambda w: w[:n_local - k], layers)
         last = jax.tree_util.tree_map(lambda w: w[n_local - k:], layers)
         carry = scan_stack(body, (h, aux0), first)
-        h, aux = scan_stack(block, carry, last)
+        h, aux = scan_stack(blk, carry, last)
     else:
         h, aux = scan_stack(body, (h, aux0), layers)
     return h, aux
 
 
 def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
-           n_microbatches: int = 0):
+           n_microbatches: int = 0, positions=None, mask=None):
     """Token ids → final-norm hidden states ``[B, T, D]`` (pre-head).
 
     ``tokens``: ``[B_local, T_local]`` — batch sharded over dp, sequence
     over sp.  With ``par.pp_axis``, ``n_microbatches`` must divide B_local
-    and the layer stack runs through the GPipe scheduler.
+    and the layer stack runs through the GPipe scheduler.  ``positions
+    [B, T]``: each token's position for RoPE (default: its index);
+    ``mask``: the key ranges each query row sees (default: causal).
     """
     Tl = tokens.shape[1]
     sp_idx = (lax.axis_index(par.sp_axis)
               if par.sp_axis is not None else 0)
-    positions = (jnp.arange(Tl)[None, :] + sp_idx * Tl
-                 ).astype(jnp.int32) * jnp.ones_like(tokens)
+    if (positions is not None or mask is not None) and (
+            par.pp_axis is not None or par.sp_axis is not None):
+        raise NotImplementedError(
+            "positions and mask per token are not wired through the "
+            "pipeline or a sequence-parallel axis")
+    if positions is None:
+        positions = (jnp.arange(Tl)[None, :] + sp_idx * Tl
+                     ).astype(jnp.int32) * jnp.ones_like(tokens)
     h = _embed_lookup(params["embed"], tokens, cfg, par)
     aux = jnp.float32(0.0)
 
@@ -433,7 +499,8 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
                                   axis_name=par.pp_axis, with_aux=True)
         h = out.reshape(B, Tl, cfg.d_model)
     else:
-        h, aux = _layer_stack(h, params["layers"], cfg, par, positions)
+        h, aux = _layer_stack(h, params["layers"], cfg, par, positions,
+                              mask)
 
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     return h, aux
@@ -445,7 +512,7 @@ def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     h, aux = hidden(params, tokens, cfg, par, n_microbatches)
     # tied embedding head (Llama-3 unties; tying halves test-model memory
     # and changes no parallel structure — the head matmul stays [D, V])
-    logits = h @ params["embed"].T.astype(h.dtype)
+    logits = h @ _head(params, cfg).T.astype(h.dtype)
     if _vp_active(cfg, par):
         # local [B, T, V/tp] partials → full logits, shard order = vocab
         # order (API contract; the loss path never materializes this)
@@ -453,7 +520,12 @@ def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     return logits, aux
 
 
-def _chunked_xent(h, w_embed, targets, chunk: int):
+def _head(params, cfg: LlamaConfig):
+    """The output head ``[V, D]``: the embedding when tied."""
+    return params["embed" if cfg.tie_embeddings else "head"]
+
+
+def _chunked_xent(h, w_embed, targets, chunk: int, weights=None):
     """Mean cross-entropy without materializing full logits.
 
     Scans the (local) sequence in chunks; each chunk computes its
@@ -462,40 +534,65 @@ def _chunked_xent(h, w_embed, targets, chunk: int):
     backward pass.  The [B, T, V] fp32 logits / log-softmax buffers of
     the one-shot path never exist, at the cost of re-running the head
     matmul once in bwd — the chunked-softmax idea flash attention applies
-    to scores, applied to the vocabulary head.
+    to scores, applied to the vocabulary head.  ``weights [B, T]``
+    multiply each token's term (the divisor stays ``B * T``).
     """
     B, T, D = h.shape
     n = T // chunk
     w = w_embed.astype(h.dtype)
+    if weights is None:
+        weights = jnp.ones((B, T), jnp.float32)
     hs = jnp.moveaxis(h.reshape(B, n, chunk, D), 1, 0)       # [n,B,c,D]
     ts = jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0)    # [n,B,c]
+    ws = jnp.moveaxis(weights.reshape(B, n, chunk), 1, 0)
 
     @jax.checkpoint
     def body(acc, xt):
-        hc, tc = xt
-        logits = (hc @ w.T).astype(jnp.float32)              # [B,c,V]
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-        return acc + (lse - tgt).sum(), None
+        hc, tc, wc = xt
+        return acc + (_token_xent(hc, w, tc) * wc).sum(), None
 
     # the accumulator derives from h (×0) so it carries h's varying mesh
     # axes — a fresh constant would fail check_vma's carry-type check
     acc0 = (h.astype(jnp.float32) * 0).sum()
-    total, _ = lax.scan(body, acc0, (hs, ts))
+    total, _ = lax.scan(body, acc0, (hs, ts, ws))
     return total / (B * T)
 
 
+def _token_xent(h, w, targets):
+    """``lse - target logit`` per token, float32; ``w [V, D]``."""
+    logits = (h @ w.T).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - tgt
+
+
 def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
-            n_microbatches: int = 0):
-    """Mean next-token cross-entropy over local tokens plus the MoE
-    load-balance auxiliary loss (caller pmeans over dp/sp axes)."""
+            n_microbatches: int = 0, *, positions=None, mask=None,
+            weights=None, with_stats: bool = False):
+    """Mean cross-entropy over local tokens plus the MoE load-balance
+    auxiliary loss of capacity experts (caller pmeans over dp/sp axes).
+
+    The objective is the caller's: ``targets [B, Tt]`` are what the
+    logits at the first ``Tt`` positions are scored against (next-token
+    training hands in the shifted tokens at full length), ``weights
+    [B, Tt]`` multiply each position's term (the divisor stays ``B *
+    Tt``), ``positions`` and ``mask`` go to :func:`hidden`.
+    ``with_stats`` also returns dropless experts' routing statistics,
+    summed over the layers (``moe.ROUTING_STATS``; zeros otherwise)."""
     # overlapped dispatch: tap the non-scanned leaves (embed, final_norm)
     # as one group HERE so every use — the lookup AND the tied loss head
     # — contributes to one cotangent before the dispatch fires; the
     # scanned stack is tapped per layer inside the scan body.  No-op
     # outside an overlapped_backprop context.
     params = _overlap.tap_root(params)
-    h, aux = hidden(params, tokens, cfg, par, n_microbatches)
+    h, aux = hidden(params, tokens, cfg, par, n_microbatches, positions,
+                    mask)
+    h = h[:, :targets.shape[1]]
+    head = _head(params, cfg)
+    if weights is not None and (_vp_active(cfg, par) or cfg.fused_xent):
+        raise NotImplementedError(
+            "weights per position go through the chunked or one-shot "
+            "cross-entropy, not the vocab-parallel or fused one")
 
     def warn_unchunked():
         # only on paths that actually materialize the unchunked logits
@@ -512,24 +609,32 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
     loss = None
     if _vp_active(cfg, par):
         warn_unchunked()
-        loss = _vocab_parallel_xent(h, params["embed"], targets, par,
+        loss = _vocab_parallel_xent(h, head, targets, par,
                                     chunk=cfg.loss_chunk)
     if loss is None and cfg.fused_xent:
         from ..ops import fused_xent
-        if fused_xent.supported(h, params["embed"], targets):
-            loss = fused_xent.fused_xent_mean(h, params["embed"], targets)
+        if fused_xent.supported(h, head, targets):
+            loss = fused_xent.fused_xent_mean(h, head, targets)
     if loss is None and cfg.loss_chunk > 0 \
             and h.shape[1] % cfg.loss_chunk == 0:
-        loss = _chunked_xent(h, params["embed"], targets, cfg.loss_chunk)
+        loss = _chunked_xent(h, head, targets, cfg.loss_chunk, weights)
     if loss is None:
         warn_unchunked()
-        logits = h @ params["embed"].T.astype(h.dtype)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        loss = -ll.mean()
-    if cfg.n_experts > 0:
+        if weights is None:
+            logits = h @ head.T.astype(h.dtype)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            loss = -ll.mean()
+        else:
+            loss = (_token_xent(h, head.astype(h.dtype), targets)
+                    * weights).mean()
+    stats = jnp.zeros((4,), jnp.float32)
+    if _dropless(cfg):
+        stats = aux
+    elif cfg.n_experts > 0:
         loss = loss + cfg.aux_loss_coef * aux / cfg.n_layers
-    return loss
+    return (loss, stats) if with_stats else loss
 
 
 def count_params(cfg: LlamaConfig) -> int:
@@ -538,4 +643,4 @@ def count_params(cfg: LlamaConfig) -> int:
                               cfg.vocab_size)
     per_layer = (2 * D + D * H * Dh + 2 * D * Hkv * Dh + H * Dh * D
                  + 3 * D * F)
-    return V * D + L * per_layer + D
+    return (1 if cfg.tie_embeddings else 2) * V * D + L * per_layer + D

@@ -9,6 +9,25 @@ combine as one-hot einsums (MXU-friendly), and expert placement over the
 with two tiled ``all_to_all`` exchanges per layer carrying tokens to their
 experts and back over ICI.
 
+Two dispatch paths, ``cfg.moe_dispatch``:
+
+* ``"capacity"`` (:func:`moe_layer` below, the default): GShard's static
+  capacity and one-hot dispatch; overflow tokens drop; the only path
+  with an ``ep`` axis of more than one chip (its two ``all_to_all``).
+* ``"dropless"`` (:func:`dropless_moe_layer`): top-k over all
+  ``cfg.n_experts`` router outputs in float32; the layer is told which
+  experts it holds (``cfg.experts_first`` and the leading dimension of
+  the expert weights it is given: a chip's share of a deployment) and
+  computes their part of the result.  The (token, expert) pairs whose
+  expert is held are sorted by expert and taken in chunks of a fixed
+  number of rows, as many chunks as the routing needs
+  (``lax.while_loop``), each through three grouped matrix products
+  (``lax.ragged_dot``: on a v5e its time follows the rows in the groups,
+  not the buffer, and it reaches about half the dense product's rate;
+  my chip run, PR 27) and a weighted scatter-add back.  No capacity, no
+  dropped pair, and device work in proportion to the pairs routed here.
+  What absent experts would add is left out.
+
 Gradient calculus note (see training.py): expert weights are *sharded*
 over ep=dp, and the backward all_to_all already sums each expert's
 gradient contributions from every data shard, so expert-weight grads need
@@ -17,26 +36,57 @@ scaling by 1/(dp·sp) instead of the replicated-param pmean.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import metrics as _metrics
+
+SCOPE_ROUTE = "hvd_moe_route"        # router logits, softmax, top-k
+SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, scatter-add
+
+_m_layers = _metrics.counter(
+    "hvd_moe_layer_total",
+    "Expert layers built by dispatch path, one per traced call site",
+    labels=("path",))
+_m_routed = _metrics.counter(
+    "hvd_moe_routed_total",
+    "What steps' outputs said of dropless expert layers, summed over the "
+    "layer-steps recorded (record_routing): pairs routed to held "
+    "experts, rows the grouped products computed, the fullest held "
+    "expert's pairs, and the layer-steps themselves",
+    labels=("what",))
+ROUTING_STATS = ("pairs", "rows", "fullest", "layers")
+
+
+def record_routing(stats) -> None:
+    """Add one step's routing statistics (``[4]``, as ROUTING_STATS
+    orders them, summed over its layers, fetched from the step's
+    output by whoever drives the steps) to ``hvd_moe_routed_total``."""
+    if _metrics.ACTIVE:
+        for what, value in zip(ROUTING_STATS, np.asarray(stats).tolist()):
+            _m_routed.inc(float(value), what=what)
+
 
 def init_moe_layer_params(key, n_layers, d_model, d_ff, n_experts,
-                          param_dtype=jnp.float32):
-    """Stacked per-layer MoE params: router + per-expert SwiGLU weights."""
+                          param_dtype=jnp.float32, n_held=0):
+    """Stacked per-layer MoE params: router + per-expert SwiGLU weights,
+    of ``n_held`` experts where the chip holds a share (0 = all)."""
     k = jax.random.split(key, 4)
 
     def norm(key, shape, fan_in):
         return jax.random.normal(key, shape, param_dtype) * (fan_in ** -0.5)
 
     L, D, F, E = n_layers, d_model, d_ff, n_experts
+    H = n_held or E
     return {
         "router": norm(k[0], (L, D, E), D),
-        "we_gate": norm(k[1], (L, E, D, F), D),
-        "we_up": norm(k[2], (L, E, D, F), D),
-        "we_down": norm(k[3], (L, E, F, D), F),
+        "we_gate": norm(k[1], (L, H, D, F), D),
+        "we_up": norm(k[2], (L, H, D, F), D),
+        "we_down": norm(k[3], (L, H, F, D), F),
     }
 
 
@@ -82,6 +132,8 @@ def _top_k_dispatch(gates, k, capacity):
 def moe_layer(x, lp, cfg, par):
     """One MoE sublayer.  x: [B, Tl, D]; lp: this layer's MoE params with
     expert dim already ep-local ([E_local, D, F] …)."""
+    if _metrics.ACTIVE:
+        _m_layers.inc(path="capacity")
     B, Tl, D = x.shape
     N = B * Tl
     E = cfg.n_experts
@@ -121,3 +173,154 @@ def moe_layer(x, lp, cfg, par):
         # expert FFNs are also tp-column/row sharded → row reduction
         y = lax.psum(y, par.tp_axis)
     return y.reshape(B, Tl, D), aux.astype(jnp.float32)
+
+
+# ------------------------------------------------------- dropless path
+
+def _chunk_rows(n_tokens, k, held, n_experts):
+    """Rows a chunk takes: half over what even routing sends here, so
+    that one chunk is the usual case and a skewed routing takes more
+    chunks, not a larger buffer."""
+    even = n_tokens * k * held / n_experts
+    most = n_tokens * min(k, held)
+    return int(min(most, max(512, -(-int(even * 1.5) // 512) * 512)))
+
+
+def _expert_ffn(xs, wg, wu, wd, wt, sizes):
+    """Rows ``xs [R, D]`` sorted by expert, ``sizes [E]`` rows each (rows
+    past their sum come out zero): SwiGLU by each row's expert, weighted
+    ``wt [R]``, float32 out."""
+    gate = lax.ragged_dot(xs, wg, sizes)
+    up = lax.ragged_dot(xs, wu, sizes)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(xs.dtype)
+    return lax.ragged_dot(h, wd, sizes).astype(jnp.float32) * wt[:, None]
+
+
+def _chunks(order, pair_w, sizes, k, rows):
+    """``(n, chunk)``: how many chunks of ``rows`` sorted pairs the
+    held pairs fill, and ``chunk(c)`` = (token of each row, its weight,
+    which rows are pairs, rows per expert, first sorted position)."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    n = (ends[-1] + rows - 1) // rows
+
+    def chunk(c):
+        lo = c * rows
+        pairs = lax.dynamic_slice(order, (lo,), (rows,))
+        here = jnp.clip(jnp.minimum(ends, lo + rows)
+                        - jnp.maximum(starts, lo), 0, rows)
+        valid = jnp.arange(rows) < here.sum()
+        return pairs // k, pair_w[pairs], valid, here.astype(jnp.int32), lo
+
+    return n, chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(tokens, wg, wu, wd, pair_w, order, sizes, k, rows):
+    """``([N, D] float32, rows)``: for every token the weighted outputs
+    of its held experts, and how many rows the grouped products were
+    given.  ``order``: pair ids sorted by held expert (pairs of experts
+    not held last), padded by ``rows``; ``sizes [E_held]``; ``pair_w
+    [N * k]`` float32."""
+    n, chunk = _chunks(order, pair_w, sizes, k, rows)
+
+    def body(c, carry):
+        out, computed = carry
+        tok, wt, valid, here, _ = chunk(c)
+        ys = _expert_ffn(tokens[tok], wg, wu, wd, wt, here)
+        return (out.at[tok].add(jnp.where(valid[:, None], ys, 0.0)),
+                computed + here.sum())
+
+    return lax.fori_loop(0, n, body, ((tokens * 0).astype(jnp.float32),
+                                      sizes[0] * 0))
+
+
+def _held_experts_fwd(tokens, wg, wu, wd, pair_w, order, sizes, k, rows):
+    out = _held_experts(tokens, wg, wu, wd, pair_w, order, sizes, k, rows)
+    return out, (tokens, wg, wu, wd, pair_w, order, sizes)
+
+
+def _held_experts_bwd(k, rows, res, cotangents):
+    """The same walk over the chunks; each chunk's products are made
+    again from the rows and differentiated there, so nothing of size
+    rows x width is kept from the forward pass."""
+    dout = cotangents[0]
+    tokens, wg, wu, wd, pair_w, order, sizes = res
+    n, chunk = _chunks(order, pair_w, sizes, k, rows)
+    f32 = lambda a: (a * 0).astype(jnp.float32)
+
+    def body(c, carry):
+        dtok, dwg, dwu, dwd, dwt_sorted = carry
+        tok, wt, valid, here, lo = chunk(c)
+        _, vjp = jax.vjp(
+            lambda xs, a, b, d, w: _expert_ffn(xs, a, b, d, w, here),
+            tokens[tok], wg, wu, wd, wt)
+        dxs, da, db, dd, dwt = vjp(
+            jnp.where(valid[:, None], dout[tok].astype(jnp.float32), 0.0))
+        dtok = dtok.at[tok].add(
+            jnp.where(valid[:, None], dxs.astype(jnp.float32), 0.0))
+        dwt_sorted = lax.dynamic_update_slice(
+            dwt_sorted, jnp.where(valid, dwt, 0.0), (lo,))
+        return (dtok, dwg + da.astype(jnp.float32),
+                dwu + db.astype(jnp.float32), dwd + dd.astype(jnp.float32),
+                dwt_sorted)
+
+    dtok, dwg, dwu, dwd, dwt_sorted = lax.fori_loop(
+        0, n, body, (f32(tokens), f32(wg), f32(wu), f32(wd),
+                     jnp.zeros(order.shape, jnp.float32) + f32(pair_w[:1])))
+    # weights' cotangents back from sorted order to pair order
+    # (a sort by pair id: a gather of as many scalars costs ten times it)
+    n_pairs = pair_w.shape[0]
+    _, dpair_w = lax.sort_key_val(order[:n_pairs], dwt_sorted[:n_pairs])
+    return (dtok.astype(tokens.dtype), dwg.astype(wg.dtype),
+            dwu.astype(wu.dtype), dwd.astype(wd.dtype), dpair_w, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def route(tokens, router, k):
+    """``(expert ids [N, k], weights [N, k] float32)``: softmax over all
+    of the router's outputs in float32, the ``k`` largest, renormalised
+    over those ``k`` whether their experts are held here or not."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    top_p, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return top_i, top_p / top_p.sum(axis=-1, keepdims=True)
+
+
+def dropless_moe_layer(x, lp, cfg, par):
+    """One routed expert sublayer that drops no token.  ``x [B, T, D]``;
+    ``lp["router"] [D, cfg.n_experts]``; ``lp["we_*"]`` the experts held
+    here, ``cfg.experts_first`` the id of the first.  Returns the held
+    experts' part of the layer's output and ``[4]`` float32 statistics
+    (ROUTING_STATS: pairs routed here, rows computed, the fullest held
+    expert's pairs, 1)."""
+    if par.tp_axis is not None or par.ep_axis is not None:
+        raise NotImplementedError(
+            "dropless experts run on the experts a chip holds; over a tp or "
+            "ep axis the layer is the capacity path's (moe_dispatch="
+            "'capacity')")
+    if _metrics.ACTIVE:
+        _m_layers.inc(path="dropless")
+    B, T, D = x.shape
+    N, k = B * T, cfg.expert_top_k
+    held = lp["we_gate"].shape[0]
+    tokens = x.reshape(N, D)
+    with jax.named_scope(SCOPE_ROUTE):
+        top_i, top_w = route(tokens, lp["router"], k)
+    with jax.named_scope(SCOPE_EXPERTS):
+        local = (top_i - cfg.experts_first).reshape(-1)
+        e = jnp.where((local >= 0) & (local < held), local, held)
+        rows = _chunk_rows(N, k, held, cfg.n_experts)
+        # held pairs first, by expert; the rest after them, never read
+        order = jnp.pad(jnp.argsort(e, stable=True).astype(jnp.int32),
+                        (0, rows))
+        sizes = (e[:, None] == jnp.arange(held)[None]).sum(0).astype(jnp.int32)
+        ws = [lp[n].astype(x.dtype) for n in ("we_gate", "we_up", "we_down")]
+        y, computed = _held_experts(tokens, *ws, top_w.reshape(-1), order,
+                                    sizes, k, rows)
+        stats = jnp.stack([sizes.sum(), computed, sizes.max(),
+                           sizes[0] * 0 + 1]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, T, D), stats

@@ -38,8 +38,28 @@ Two paths, chosen from the shapes alone (:func:`_pack`; no knob):
   computed once and dq, dk, dv written from them.  Same products in the
   same dtypes as the tiled kernels.
 
+* **masked** — the caller gives the mask as data (``mask=``): for every
+  query row two half-open ranges of key positions, ``[T, 4]`` or
+  ``[B, T, 4]`` int32 ``(lo1, hi1, lo2, hi2)``; a key is seen when it
+  lies in either.  Causal, sliding-window, packed-document and
+  block-diffusion masks are all such ranges (:func:`causal_ranges`,
+  :func:`window_ranges`); no mode per model.  :func:`tile_classes` sorts
+  the (query tile, key tile) pairs into dead (no live pair: skipped, no
+  load and no product), full (every pair live: no mask applied) and
+  mixed (masked inside the tile).  ``hvd_flash_fwd`` and ``hvd_flash_dq``
+  walk each query tile's live key tiles from a table in SMEM;
+  ``hvd_flash_dkv`` has one grid step per live (key tile, query tile)
+  pair and takes the query tiles of its GQA group one at a time, so it
+  holds ``g x bq x D`` of ``q`` and ``do``, not ``g x T x D``: 8 query
+  heads a kv head at 8,192 positions run.  Every query row has to see at
+  least one key.  ``causal=True`` without ``mask`` stays on the tiled or
+  packed kernels above, the same products in the same order.
+
 ``hvd_flash_kernel_total{kernel, path}`` counts the kernels built, once
-per traced call site, so a program says which path its shapes took.
+per traced call site, so a program says which path its shapes took;
+``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
+(``live`` = full, ``masked`` = mixed, ``skipped`` = dead) where the call
+is built, when the mask is known there (a numpy array).
 
 Falls back cleanly: :func:`supported` gates on platform/shape so callers
 (e.g. ``local_attention``) can pick the XLA blockwise path on CPU meshes
@@ -59,6 +79,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+import numpy as np
 
 from .. import metrics as _metrics
 
@@ -75,9 +97,25 @@ _m_kernels = _metrics.counter(
     labels=("kernel", "path"))
 
 
+_m_tiles = _metrics.counter(
+    "hvd_flash_tiles_total",
+    "Tiles of masked flash-attention calls by class, counted where the "
+    "call is built from a mask known there",
+    labels=("kernel", "state"))
+_TILE_STATES = ("skipped", "masked", "live")     # class 0, 1, 2
+
+
 def _count(kernel: str, path: str) -> None:
     if _metrics.ACTIVE:
         _m_kernels.inc(kernel=kernel, path=path)
+
+
+def _count_tiles(kernel: str, classes) -> None:
+    """``classes``: the call's tile classes when known at build time."""
+    if _metrics.ACTIVE and isinstance(classes, np.ndarray):
+        for c, state in enumerate(_TILE_STATES):
+            _m_tiles.inc(int((classes == c).sum()), kernel=kernel,
+                         state=state)
 
 
 def _block_sizes(t_q: int, t_kv: int):
@@ -169,7 +207,7 @@ def _verdict(kernel: str, reason: Optional[str], *operands) -> bool:
     return reason is None
 
 
-def _refusal(q, k, v) -> Optional[str]:
+def _refusal(q, k, v, mask=None) -> Optional[str]:
     """Which test keeps the Pallas kernel off this call; None = it runs."""
     if os.environ.get("HOROVOD_FLASH_ATTENTION", "1") in ("0", "false"):
         return "HOROVOD_FLASH_ATTENTION is off"
@@ -192,17 +230,19 @@ def _refusal(q, k, v) -> Optional[str]:
     if q.dtype not in (jnp.bfloat16, jnp.float32):
         return f"dtype {q.dtype} is neither bfloat16 nor float32"
     g = H // Hkv
-    # fwd holds k+v [Tk, D]; bwd dkv holds q+do [g*T, D] per group
-    resident = max(2 * Tk * D, 2 * g * T * D) * q.dtype.itemsize
+    # fwd holds k+v [Tk, D]; bwd dkv holds q+do [g*T, D] per group, the
+    # masked dkv one query tile of the group at a time
+    rows = g * bq if mask is not None else g * T
+    resident = max(2 * Tk * D, 2 * rows * D) * q.dtype.itemsize
     if resident > _VMEM_BUDGET:
         return (f"resident buffers need {resident} bytes of VMEM, over "
                 f"the {_VMEM_BUDGET} budget")
     return None
 
 
-def supported(q, k, v, causal: bool = True) -> bool:
+def supported(q, k, v, causal: bool = True, mask=None) -> bool:
     """True when the Pallas kernel can run this shape on this backend."""
-    return _verdict("flash_attention", _refusal(q, k, v), q, k, v)
+    return _verdict("flash_attention", _refusal(q, k, v, mask), q, k, v)
 
 
 # ---------------------------------------------------------------- forward
@@ -598,6 +638,394 @@ _packed_attention_lse.defvjp(_packed_attention_lse_fwd,
                              _packed_attention_lse_bwd)
 
 
+# ------------------------------------------------- masked (ranges) path
+# The mask as data: per query row (lo1, hi1, lo2, hi2), key c live when
+# lo1 <= c < hi1 or lo2 <= c < hi2.  Tile classes: 0 dead, 1 mixed, 2 full.
+
+def causal_ranges(T: int):
+    """``[T, 4]``: row i sees keys ``[0, i + 1)``."""
+    r = np.zeros((T, 4), np.int32)
+    r[:, 1] = np.arange(T) + 1
+    return r
+
+
+def window_ranges(T: int, window: int):
+    """``[T, 4]``: row i sees keys ``[max(0, i - window + 1), i + 1)``."""
+    r = causal_ranges(T)
+    r[:, 0] = np.maximum(np.arange(T) - window + 1, 0)
+    return r
+
+
+def dense_mask(ranges, Tk: int):
+    """Boolean ``[..., T, Tk]`` of the ranges (tests and plain paths)."""
+    xp = np if isinstance(ranges, np.ndarray) else jnp
+    cols = xp.arange(Tk)
+    lo1, hi1, lo2, hi2 = (ranges[..., c:c + 1] for c in range(4))
+    return (((cols >= lo1) & (cols < hi1))
+            | ((cols >= lo2) & (cols < hi2)))
+
+
+def tile_classes(ranges, bq: int, bk: int, Tk: int):
+    """``[Bm, nq, nk]`` int32 classes of the (query tile, key tile) pairs
+    of ``ranges [Bm, T, 4]``: 2 where every row has one range covering
+    the whole key tile, 0 where no row's range meets it, else 1.  numpy
+    in, numpy out (known where the call is built); a traced array gives
+    a traced table."""
+    xp = np if isinstance(ranges, np.ndarray) else jnp
+    Bm, T, _ = ranges.shape
+    c0 = (xp.arange(Tk // bk) * bk).astype(xp.int32)      # [nk]
+    c1 = c0 + bk
+    meets = covers = False
+    for lo, hi in ((ranges[..., 0:1], ranges[..., 1:2]),
+                   (ranges[..., 2:3], ranges[..., 3:4])):
+        meets = meets | (xp.minimum(hi, c1) > xp.maximum(lo, c0))
+        covers = covers | ((lo <= c0) & (hi >= c1))
+    meets = meets.reshape(Bm, T // bq, bq, -1).any(axis=2)
+    covers = covers.reshape(Bm, T // bq, bq, -1).all(axis=2)
+    return xp.where(covers, 2, xp.where(meets, 1, 0)).astype(xp.int32)
+
+
+def _row_tables(classes):
+    """For ``hvd_flash_fwd`` / ``hvd_flash_dq``: each query tile's key
+    tiles ordered full, mixed, dead, with the counts of the first and
+    of the first two, flat int32 for SMEM."""
+    xp = np if isinstance(classes, np.ndarray) else jnp
+    nk = classes.shape[-1]
+    key = (2 - classes) * nk + xp.arange(nk)
+    idx = xp.argsort(key, axis=-1).astype(xp.int32)
+    n_full = (classes == 2).sum(-1).astype(xp.int32)
+    n_live = (classes >= 1).sum(-1).astype(xp.int32)
+    return idx.reshape(-1), n_full.reshape(-1), n_live.reshape(-1)
+
+
+def _pair_table(classes):
+    """For ``hvd_flash_dkv``: ``(table, P)``, the (key tile, query tile)
+    pairs to visit, key-tile major so that a key tile's steps are
+    consecutive.  Flat int32 ``[Bm * P * 4]`` of (key tile, query tile,
+    class, flags: 1 first of its key tile, 2 last).  A key tile no query
+    tile sees keeps one dead pair, which writes its zeros.  With classes
+    known at build time ``P`` is the most any batch row needs; traced,
+    every pair has a slot."""
+    xp = np if isinstance(classes, np.ndarray) else jnp
+    Bm, nq, nk = classes.shape
+    cls = xp.swapaxes(classes, 1, 2).reshape(Bm, nk * nq)   # k-major
+    jj = xp.repeat(xp.arange(nk), nq)[None].astype(xp.int32)
+    ii = xp.tile(xp.arange(nq), nk)[None].astype(xp.int32)
+    unseen = (cls.reshape(Bm, nk, nq) >= 1).sum(-1) == 0     # [Bm, nk]
+    needed = (cls >= 1) | (xp.repeat(unseen, nq, axis=1) & (ii == 0))
+    order = xp.argsort(xp.where(needed, 0, 1) * (nk * nq)
+                       + xp.arange(nk * nq), axis=-1)
+    n = needed.sum(-1)                                       # [Bm]
+    P = int(n.max()) if xp is np else nk * nq
+    slot = xp.minimum(xp.arange(P)[None], n[:, None] - 1)    # pads repeat
+    take = lambda a: xp.take_along_axis(
+        xp.broadcast_to(a, cls.shape), xp.take_along_axis(order, slot, 1), 1)
+    j, i = take(jj), take(ii)
+    real = xp.arange(P)[None] < n[:, None]
+    c = xp.where(real, take(cls), 0)
+    prev_j = xp.concatenate([xp.full((Bm, 1), -1, j.dtype), j[:, :-1]], 1)
+    next_j = xp.concatenate([j[:, 1:], xp.full((Bm, 1), -1, j.dtype)], 1)
+    last_real = xp.arange(P)[None] == n[:, None] - 1
+    flags = (xp.where(real & (j != prev_j), 1, 0)
+             + xp.where(real & ((j != next_j) | last_real), 2, 0))
+    table = xp.stack([j, i, c, flags], -1).astype(xp.int32)
+    return table.reshape(-1), P
+
+
+def _live(rng, col0, shape):
+    """Boolean ``shape = (bq, bk)``: the pairs of a tile whose first key
+    is ``col0`` that the rows' ranges ``rng [bq, 4]`` let through."""
+    cols = lax.broadcasted_iota(jnp.int32, shape, 1)
+    r = rng - col0                       # shift the ranges, not the tile
+    return (((cols >= r[:, 0:1]) & (cols < r[:, 1:2]))
+            | ((cols >= r[:, 2:3]) & (cols < r[:, 3:4])))
+
+
+def _masked_scores(q, kj, rng, col0, scale, masked):
+    s = lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    if masked:
+        s = jnp.where(_live(rng, col0, s.shape), s, NEG_INF)
+    return s
+
+
+def _two_loops(n_full, n_live, step, carry):
+    """Full tiles unmasked, then mixed tiles masked: no branch inside a
+    loop body."""
+    carry = lax.fori_loop(0, n_full, functools.partial(step, masked=False),
+                          carry)
+    return lax.fori_loop(n_full, n_live,
+                         functools.partial(step, masked=True), carry)
+
+
+def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
+                 o_ref, lse_ref, *, scale, bk, nq, nk, per_batch):
+    bq, D = q_ref.shape[2], q_ref.shape[3]
+    i = pl.program_id(2)
+    row = (pl.program_id(0) * nq if per_batch else 0) + i
+    q = q_ref[0, 0]
+    rng = r_ref[0]
+
+    def step(n, carry, masked):
+        m, l, acc = carry
+        j = idx_ref[row * nk + n]
+        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        kj, vj = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+        s = _masked_scores(q, kj, rng, j * bk, scale, masked)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(vj.dtype), vj,
+                     preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    m, l, acc = _two_loops(
+        nfull_ref[row], nlive_ref[row], step,
+        (jnp.full((bq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((bq, 1), jnp.float32), jnp.zeros((bq, D), jnp.float32)))
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+    lse_ref[0, 0, i, :] = (m + jnp.log(l)).reshape(bq)
+
+
+def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
+                lse_ref, delta_ref, r_ref, dq_ref, *, scale, bk, nq, nk,
+                per_batch):
+    bq, D = q_ref.shape[2], q_ref.shape[3]
+    i = pl.program_id(2)
+    row = (pl.program_id(0) * nq if per_batch else 0) + i
+    q, do = q_ref[0, 0], do_ref[0, 0]
+    rng = r_ref[0]
+    lse = lse_ref[0, 0, i, :].reshape(bq, 1)
+    delta = delta_ref[0, 0, i, :].reshape(bq, 1)
+
+    def step(n, dq_acc, masked):
+        j = idx_ref[row * nk + n]
+        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        kj, vj = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+        p = jnp.exp(_masked_scores(q, kj, rng, j * bk, scale, masked) - lse)
+        dp = lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
+        return dq_acc + jnp.dot(ds.astype(kj.dtype), kj,
+                                preferred_element_type=jnp.float32)
+
+    dq = _two_loops(nfull_ref[row], nlive_ref[row], step,
+                    jnp.zeros((bq, D), jnp.float32))
+    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+
+
+def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq, P, g,
+                 per_batch):
+    bk, D = k_ref.shape[2], k_ref.shape[3]
+    at = ((pl.program_id(0) * P if per_batch else 0) + pl.program_id(2)) * 4
+    j, i, cls, flags = (tbl_ref[at + c] for c in range(4))
+
+    @pl.when(flags % 2 == 1)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def pair(masked):
+        kb, vb = k_ref[0, 0], v_ref[0, 0]
+        rng = r_ref[0]
+        dk = dv = None
+        for hq in range(g):           # static: the kv head's query heads
+            qi, doi = q_ref[0, hq], do_ref[0, hq]
+            lse = lse_ref[0, hq, i, :].reshape(bq, 1)
+            delta = delta_ref[0, hq, i, :].reshape(bq, 1)
+            p = jnp.exp(_masked_scores(qi, kb, rng, j * bk, scale, masked)
+                        - lse)
+            dv = _add(dv, lax.dot_general(
+                p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            dp = lax.dot_general(doi, vb, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale
+            dk = _add(dk, lax.dot_general(
+                ds.astype(qi.dtype), qi, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    pl.when(cls == 2)(functools.partial(pair, False))
+    pl.when(cls == 1)(functools.partial(pair, True))
+
+    @pl.when(flags >= 2)
+    def _():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _mask_plan(mask, bq, bk, Tk):
+    """(ranges [Bm, T, 4], tile classes, whether the mask is one a batch
+    row, the batch row's index into them) of a caller's mask."""
+    ranges = mask if mask.ndim == 3 else mask[None]
+    if not isinstance(ranges, np.ndarray):
+        ranges = ranges.astype(jnp.int32)
+    per_batch = ranges.shape[0] > 1
+    return (ranges, tile_classes(ranges, bq, bk, Tk), per_batch,
+            (lambda b: b) if per_batch else (lambda b: 0))
+
+
+def _vmem(*block_bytes):
+    """A masked kernel's VMEM limit: its blocks double-buffered, and room
+    for the fp32 tiles in flight."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(2 * sum(block_bytes) + 24 * 1024 * 1024))
+
+
+def _row_specs(bq, D, Tk, nq, g, bm):
+    """Block specs of the kernels that walk a query tile's key tiles
+    (grid ``(B, H, nq)``, three tables prefetched): a query tile, a kv
+    head's whole keys or values, the head's row statistics, the tile's
+    ranges."""
+    tile = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, *_: (b, h, i, 0))
+    whole = pl.BlockSpec((1, 1, Tk, D), lambda b, h, i, *_: (b, h // g, 0, 0))
+    stats = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
+    rng = pl.BlockSpec((1, bq, 4), lambda b, h, i, *_: (bm(b), i, 0))
+    return tile, whole, stats, rng
+
+
+def _masked_fwd_bhtd(q, k, v, mask, scale):
+    """As :func:`_flash_fwd_bhtd`, under ``mask``."""
+    B, H, T, D = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    g = H // Hkv
+    bq, bk = _block_sizes(T, Tk)
+    nq, nk = T // bq, Tk // bk
+    ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
+    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm)
+    _count("fwd", "masked")
+    _count_tiles("fwd", classes)
+    item = q.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_mfwd_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
+                          per_batch=per_batch),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, H, nq),
+            in_specs=[tile, whole, whole, rng], out_specs=[tile, stats]),
+        out_shape=[
+            _sds((B, H, T, D), q.dtype, q, k, v),
+            _sds((B, H, nq, bq), jnp.float32, q, k, v),
+        ],
+        compiler_params=_vmem(2 * Tk * D * item, 2 * bq * D * item,
+                              bq * _LANES * 4),
+        interpret=_INTERPRET,
+        name="hvd_flash_fwd",
+    )(*_row_tables(classes), q, k, v, ranges)
+
+
+def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
+    B, H, T, D = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    g = H // Hkv
+    bq, bk = _block_sizes(T, Tk)
+    nq, nk = T // bq, Tk // bk
+    ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
+    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm)
+    item = q.dtype.itemsize
+
+    delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
+                       out.astype(jnp.float32)).reshape(B, H, nq, bq)
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
+
+    for kernel in ("dq", "dkv"):
+        _count(kernel, "masked")
+        _count_tiles(kernel, classes)
+    dq = pl.pallas_call(
+        functools.partial(_mdq_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
+                          per_batch=per_batch),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, H, nq),
+            in_specs=[tile, whole, whole, tile, stats, stats, rng],
+            out_specs=tile),
+        out_shape=_sds((B, H, T, D), q.dtype, q, k, v, do),
+        compiler_params=_vmem(2 * Tk * D * item, 3 * bq * D * item,
+                              bq * _LANES * 4),
+        interpret=_INTERPRET,
+        name="hvd_flash_dq",
+    )(*_row_tables(classes), q, k, v, do, lse, delta, ranges)
+
+    table, P = _pair_table(classes)
+    # table entry p of batch row b: key tile at [.. + 0], query tile at
+    # [.. + 1]
+    at = (lambda b, p: (b * P + p) * 4) if per_batch else (
+        lambda b, p: p * 4)
+    q_blk = pl.BlockSpec((1, g, bq, D),
+                         lambda b, c, p, t: (b, c, t[at(b, p) + 1], 0))
+    kv_blk = pl.BlockSpec((1, 1, bk, D),
+                          lambda b, c, p, t: (b, c, t[at(b, p)], 0))
+    row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_mdkv_kernel, scale=scale, bq=bq, P=P, g=g,
+                          per_batch=per_batch),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, Hkv, P),
+            in_specs=[
+                q_blk, kv_blk, kv_blk, q_blk, row_blk, row_blk,
+                pl.BlockSpec((1, bq, 4),
+                             lambda b, c, p, t: (bm(b), t[at(b, p) + 1], 0)),
+            ],
+            out_specs=[kv_blk, kv_blk],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)]),
+        out_shape=[
+            _sds((B, Hkv, Tk, D), k.dtype, q, k, v, do),
+            _sds((B, Hkv, Tk, D), v.dtype, q, k, v, do),
+        ],
+        compiler_params=_vmem(2 * g * bq * D * item, 4 * bk * D * item,
+                              2 * g * T * 4, bq * _LANES * 4),
+        interpret=_INTERPRET,
+        name="hvd_flash_dkv",
+    )(table, q, k, v, do, lse, delta, ranges)
+    return dq, dk, dv
+
+
+class _StaticMask:
+    """A mask known where the call is built, hashable so that it rides a
+    ``custom_vjp`` as a static argument and stays numpy in both rules."""
+
+    def __init__(self, ranges):
+        self.ranges = np.ascontiguousarray(ranges, np.int32)
+        self._hash = hash((self.ranges.shape, self.ranges.tobytes()))
+
+    def __hash__(self):
+        return self._hash
+
+    def __eq__(self, other):
+        return (isinstance(other, _StaticMask)
+                and self.ranges.shape == other.ranges.shape
+                and bool((self.ranges == other.ranges).all()))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _masked_attention_lse(q, k, v, mask, static, scale):
+    """``mask``: a traced mask, or None with ``static`` a _StaticMask."""
+    return _masked_fwd_bhtd(q, k, v, static.ranges if static else mask,
+                            scale)
+
+
+def _masked_attention_lse_fwd(q, k, v, mask, static, scale):
+    out, lse = _masked_attention_lse(q, k, v, mask, static, scale)
+    return (out, lse), (q, k, v, mask, out, lse)
+
+
+def _masked_attention_lse_bwd(static, scale, res, cotangents):
+    do, dlse = cotangents
+    q, k, v, mask, out, lse = res
+    dq, dk, dv = _masked_bwd_bhtd(
+        q, k, v, out, lse, do, static.ranges if static else mask, scale,
+        dlse=dlse)
+    return dq, dk, dv, None
+
+
+_masked_attention_lse.defvjp(_masked_attention_lse_fwd,
+                             _masked_attention_lse_bwd)
+
+
 # ------------------------------------------------------------- public op
 # The GQA group reshape in _dkv_kernel's q block assumes query heads of
 # one kv group are contiguous (head h ↔ kv head h // g), matching
@@ -626,13 +1054,22 @@ _flash_attention_lse.defvjp(_flash_attention_lse_fwd,
                             _flash_attention_lse_bwd)
 
 
-def _attention_lse(q, k, v, causal, sm_scale):
+def _attention_lse(q, k, v, causal, sm_scale, mask=None):
     """Both public entry points: ``(out [B,T,H,D], lse [B,H,T])`` by the
-    path :func:`_pack` gives these shapes."""
+    path :func:`_pack` gives these shapes, or the masked path for a
+    ``mask``."""
     scale = float(sm_scale if sm_scale is not None
                   else q.shape[-1] ** -0.5)
     B, T, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
+    if mask is not None:
+        static = (_StaticMask(mask) if isinstance(mask, np.ndarray)
+                  else None)
+        out, lse = _masked_attention_lse(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), None if static else mask, static,
+            scale)
+        return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
     pack = _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize)
     if pack != (1, 1):
         out, lse = _packed_attention_lse(
@@ -647,13 +1084,15 @@ def _attention_lse(q, k, v, causal, sm_scale):
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None):
-    """Fused exact attention.  ``q [B,T,H,D]``, ``k/v [B,Tk,Hkv,D]``."""
-    return _attention_lse(q, k, v, causal, sm_scale)[0]
+                    sm_scale: Optional[float] = None, mask=None):
+    """Fused exact attention.  ``q [B,T,H,D]``, ``k/v [B,Tk,Hkv,D]``.
+    ``mask``: the ranges each query row sees (module docstring); given
+    one, ``causal`` is not looked at."""
+    return _attention_lse(q, k, v, causal, sm_scale, mask)[0]
 
 
 def flash_attention_lse(q, k, v, causal: bool = True,
-                        sm_scale: Optional[float] = None):
+                        sm_scale: Optional[float] = None, mask=None):
     """Fused attention returning ``(out, lse)`` for tile merging.
 
     ``out [B,T,H,D]``, ``lse [B,H,T]`` (logsumexp of the masked scores per
@@ -662,4 +1101,4 @@ def flash_attention_lse(q, k, v, causal: bool = True,
     through both outputs (the lse cotangent folds into the backward
     kernels' delta term).
     """
-    return _attention_lse(q, k, v, causal, sm_scale)
+    return _attention_lse(q, k, v, causal, sm_scale, mask)

@@ -27,7 +27,8 @@ from jax import lax
 NEG_INF = -1e30
 
 
-def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale):
+def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale,
+                  mask=None):
     """One tile: scores q·k with causal masking by global token position,
     folded into the (m, l, o) online-softmax accumulator.  fp32 accumulate
     regardless of input dtype (MXU-native bf16 inputs are fine).
@@ -40,6 +41,9 @@ def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale):
     blocks circulate the ring at 1/(H/Hkv) the bytes of the repeated form.
     Query head h maps to kv head h // (H/Hkv), matching
     ``jnp.repeat(k, H//Hkv, axis=2)`` semantics.
+
+    ``mask [Bm, Tq, 4]``: per query row two half-open ranges of global
+    key positions it sees (ops/flash_attention.py), instead of ``causal``.
     """
     # q: [B, Tq, H, D], k/v: [B, Tk, Hkv, D]
     B, Tq, H, D = q.shape
@@ -53,7 +57,12 @@ def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale):
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                        preferred_element_type=jnp.float32) * scale
         s = s.reshape(B, H, Tq, Tk)
-    if causal:
+    if mask is not None:
+        tk = jnp.arange(Tk) + k_start
+        lo1, hi1, lo2, hi2 = (mask[..., c:c + 1] for c in range(4))
+        live = (((tk >= lo1) & (tk < hi1)) | ((tk >= lo2) & (tk < hi2)))
+        s = jnp.where(live[:, None], s, NEG_INF)      # [Bm, 1, Tq, Tk]
+    elif causal:
         tq = jnp.arange(Tq)[:, None] + q_start
         tk = jnp.arange(Tk)[None, :] + k_start
         s = jnp.where((tk <= tq)[None, None], s, NEG_INF)
@@ -76,7 +85,7 @@ def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale):
 
 
 def blockwise_attend(q, k, v, m, l, o, q_start, k_start, causal: bool,
-                     scale: float, block_size: int = 512):
+                     scale: float, block_size: int = 512, mask=None):
     """Fold one q-shard × kv-shard tile into the ``(m, l, o)`` accumulator
     with O(Tq·block) live memory: an online-softmax sub-scan over
     key/value blocks, each block ``jax.checkpoint``-ed.  ``q_start`` /
@@ -92,7 +101,8 @@ def blockwise_attend(q, k, v, m, l, o, q_start, k_start, causal: bool,
         blk = next((b for b in range(blk, 63, -1) if Tk % b == 0), Tk)
     nblk = Tk // blk
     attend = jax.checkpoint(
-        functools.partial(_block_attend, causal=causal, scale=scale))
+        functools.partial(_block_attend, causal=causal, scale=scale,
+                          mask=mask))
     # kv laid out block-major as scan xs: [nblk, B, blk, Hkv, D]
     # (nblk == 1 degenerates to a length-1 scan over the single tile)
     kb = k.reshape(B, nblk, blk, Hkv, D).swapaxes(0, 1)
@@ -111,7 +121,7 @@ def blockwise_attend(q, k, v, m, l, o, q_start, k_start, causal: bool,
 
 def local_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_size: int = 512):
+                    block_size: int = 512, mask=None):
     """Exact single-shard attention with O(T·block) live memory.
 
     On TPU the fused Pallas kernel path
@@ -121,12 +131,18 @@ def local_attention(q, k, v, causal: bool = True,
     CPU-mesh test path.
 
     q: ``[B, T, H, D]``; k/v: ``[B, Tk, Hkv, D]`` with ``Hkv | H`` (GQA).
+    ``mask``: ``[T, 4]`` or ``[B, T, 4]`` key ranges per query row, in
+    place of ``causal`` (the kernels skip the tiles they leave empty).
     """
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
 
     from ..ops import flash_attention as _fa
-    if _fa.supported(q, k, v, causal):
-        return _fa.flash_attention(q, k, v, causal=causal, sm_scale=scale)
+    if _fa.supported(q, k, v, causal, mask):
+        return _fa.flash_attention(q, k, v, causal=causal, sm_scale=scale,
+                                   mask=mask)
+    if mask is not None:
+        mask = jnp.asarray(mask, jnp.int32)
+        mask = mask if mask.ndim == 3 else mask[None]
 
     # derive accumulators from the operands (×0) so they inherit their
     # varying mesh axes (dp/tp/…) — scan carries must match the body
@@ -140,12 +156,13 @@ def local_attention(q, k, v, causal: bool = True,
     l0 = zero_bht
     o0 = (q * 0).astype(jnp.float32) + opzero
     m, l, o = blockwise_attend(q, k, v, m0, l0, o0, 0, 0, causal, scale,
-                               block_size)
+                               block_size, mask)
     return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
 
 
 def ring_attention(q, k, v, axis_name: Optional[str] = None,
-                   causal: bool = True, sm_scale: Optional[float] = None):
+                   causal: bool = True, sm_scale: Optional[float] = None,
+                   mask=None):
     """Exact attention with sequence sharded over ``axis_name``.
 
     Args:
@@ -164,7 +181,11 @@ def ring_attention(q, k, v, axis_name: Optional[str] = None,
     B, Tl, H, D = q.shape
 
     if n == 1:
-        return local_attention(q, k, v, causal=causal, sm_scale=scale)
+        return local_attention(q, k, v, causal=causal, sm_scale=scale,
+                               mask=mask)
+    if mask is not None:
+        raise NotImplementedError(
+            "a mask by key ranges is not rotated around the ring yet")
 
     my_blk = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]

@@ -101,8 +101,17 @@ def make_llama_train_step(cfg: LlamaConfig, pmesh: ParallelMesh,
                           n_microbatches: int = 0,
                           zero1: bool = False,
                           grad_accum: int = 0,
-                          overlap: bool = False) -> TrainStep:
+                          overlap: bool = False,
+                          objective: Optional[Callable] = None) -> TrainStep:
     """Build the full data/tensor/sequence/pipeline/expert-parallel step.
+
+    ``objective(params, batch, cfg, par) -> (loss, stats)`` replaces
+    next-token cross-entropy: ``batch`` is a tuple of arrays whose first
+    axis is the batch (each sharded as the tokens are), ``stats`` a small
+    float32 array the step returns summed over the data shards (routing
+    statistics of dropless experts, ``llama.loss_fn(with_stats=True)``).
+    The step is then ``step_fn(params, opt_state, batch) -> (params,
+    opt_state, loss, stats)``; plain data parallelism only.
 
     ``zero1=True`` additionally shards the optimizer state over the dp
     axis (ZeRO stage 1): each dp shard keeps 1/dp of every moment buffer,
@@ -327,6 +336,26 @@ def make_llama_train_step(cfg: LlamaConfig, pmesh: ParallelMesh,
                          mesh=mesh, data_spec=data_spec,
                          param_sharding=param_sharding)
 
+    if objective is not None:
+        if (tp > 1 or sp > 1 or pp > 1 or ep_dedicated > 1 or zero1
+                or grad_accum > 1 or overlap):
+            raise ValueError(
+                "objective= composes with plain data parallelism only")
+
+        def objective_step(params, opt_state, batch):
+            with jax.named_scope(SCOPE_FORWARD):
+                (loss, stats), grads = jax.value_and_grad(
+                    lambda p: objective(p, batch, cfg, par),
+                    has_aux=True)(params)
+            with jax.named_scope(SCOPE_REDUCE):
+                grads = reduce_grads(grads)
+                if par.dp_axis is not None:
+                    stats = lax.psum(stats, par.dp_axis)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            return params, opt_state, _mean_loss(loss), stats
+
     def shard_step(params, opt_state, tokens, targets):
         loss, grads = loss_and_grads(params, tokens, targets)
         grads = reduce_grads(grads)
@@ -370,6 +399,12 @@ def make_llama_train_step(cfg: LlamaConfig, pmesh: ParallelMesh,
             return params, opt_state, loss
 
         step_fn = jax.jit(_step, donate_argnums=(0, 1))
+    elif objective is not None:
+        step_fn = jax.jit(jax.shard_map(
+            objective_step, mesh=mesh,
+            in_specs=(pspec_tree, opt_specs, P(par.dp_axis)),
+            out_specs=(pspec_tree, opt_specs, P(), P()),
+            check_vma=True), donate_argnums=(0, 1))
     else:
         step_fn = jax.jit(jax.shard_map(
             shard_step, mesh=mesh,

@@ -464,3 +464,159 @@ def test_packed_kernels_lower_for_the_chip(monkeypatch):
             q, k, k).compile().as_text()
         assert "hvd_flash_fwd" in text and "hvd_flash_bwd" in text
         assert "hvd_flash_dq" not in text
+
+
+# ------------------------------------------------------ the masked path
+
+def _block_diffusion_ranges(L, bk):
+    """[xt ; x0]: xt sees its own block of xt and x0's earlier blocks; x0
+    sees x0's own and earlier blocks."""
+    block = np.arange(L) // bk
+    r = np.zeros((2 * L, 4), np.int32)
+    r[:L, 0], r[:L, 1] = block * bk, (block + 1) * bk
+    r[:L, 2], r[:L, 3] = L, L + block * bk
+    r[L:, 0], r[L:, 1] = L, L + (block + 1) * bk
+    return r
+
+
+MASKS = {
+    "block-diffusion": lambda T: _block_diffusion_ranges(T // 2, 4),
+    "causal": fa.causal_ranges,
+    "window": lambda T: fa.window_ranges(T, 100),
+}
+
+
+def _dense_masked(q, k, v, live):
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(live[:, None] if live.ndim == 3 else live[None, None],
+                  s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("per_batch", [False, True],
+                         ids=["one-mask", "mask-per-row"])
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_masked_kernels_match_dense_masked_attention(mask, per_batch,
+                                                     monkeypatch):
+    """Forward and all three gradients at 8 query heads a kv head, the
+    mask known where the call is built (numpy) or traced per batch row."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    B, T, H, Hkv, D = 2, 512, 8, 1, 64
+    q, k, v = make_qkv(B, T, H, Hkv, D)
+    ranges = MASKS[mask](T)
+    live = jnp.asarray(fa.dense_mask(ranges, T))
+    given = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
+    assert fa.supported(q, k, v, False, given)
+
+    def loss(attend):
+        return lambda q, k, v: (attend(q, k, v) ** 2).sum()
+
+    out = fa.flash_attention(q, k, v, mask=given)
+    np.testing.assert_allclose(out, _dense_masked(q, k, v, live),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, mask=given)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_masked(q, k, v, live)),
+                    (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+def test_causal_ranges_agree_with_the_causal_kernels(monkeypatch):
+    """The mask as data says what ``causal=True`` says."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    q, k, v = make_qkv(1, 256, 4, 2, 64)
+    np.testing.assert_allclose(
+        fa.flash_attention(q, k, v, mask=fa.causal_ranges(256)),
+        fa.flash_attention(q, k, v, causal=True), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("bq,bk", [(128, 128), (64, 256)])
+def test_tile_classes_against_a_brute_force_count(mask, bq, bk):
+    T = 1024
+    ranges = MASKS[mask](T)
+    live = fa.dense_mask(ranges, T)
+    tiles = live.reshape(T // bq, bq, T // bk, bk).transpose(0, 2, 1, 3)
+    want = np.where(tiles.all((2, 3)), 2, np.where(tiles.any((2, 3)), 1, 0))
+    got = fa.tile_classes(ranges[None], bq, bk, T)
+    assert isinstance(got, np.ndarray) and (got[0] == want).all()
+    # the same table from a traced mask
+    traced = jax.jit(lambda r: fa.tile_classes(r, bq, bk, T))(ranges[None])
+    assert (np.asarray(traced)[0] == want).all()
+    # every live tile is walked once by each kernel's table, dead ones never
+    idx, n_full, n_live = (np.asarray(a) for a in fa._row_tables(got))
+    nq, nk = want.shape
+    for i in range(nq):
+        row = idx.reshape(nq, nk)[i]
+        assert set(row[:n_full[i]]) == set(np.flatnonzero(want[i] == 2))
+        assert set(row[n_full[i]:n_live[i]]) == set(np.flatnonzero(want[i] == 1))
+    table, P = fa._pair_table(got)
+    pairs = table.reshape(P, 4)
+    visited = {(j, i) for j, i, c, _ in pairs if c}
+    assert visited == {(j, i) for i, j in zip(*np.nonzero(want))}
+    assert all(want[i, j] == c for j, i, c, _ in pairs if c)
+    firsts = [j for j, _, _, f in pairs if f & 1]
+    lasts = [j for j, _, _, f in pairs if f & 2]
+    assert firsts == lasts == sorted(set(range(nk)))     # each key tile once
+
+
+def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    from horovod_tpu import metrics
+
+    def tiles():
+        fam = metrics.registry().to_dict().get("hvd_flash_tiles_total", {})
+        return {(s["labels"]["kernel"], s["labels"]["state"]): s["value"]
+                for s in fam.get("series", [])}
+
+    before_t, before_k = tiles(), _kernel_counts()
+    x = jax.ShapeDtypeStruct((1, 512, 8, 64), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 512, 1, 64), jnp.float32)
+    ranges = _block_diffusion_ranges(256, 4)
+    jax.make_jaxpr(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, mask=ranges).sum(),
+        (0, 1, 2))(q, k, v))(x, kv, kv)
+    grew = {key: v - before_t.get(key, 0) for key, v in tiles().items()}
+    # 4 x 4 tiles of 128: xt's own 2, xt on x0 1 full + 2 mixed, x0 on x0
+    # 1 full + 2 mixed
+    for kernel in ("fwd", "dq", "dkv"):
+        assert (grew[kernel, "live"], grew[kernel, "masked"],
+                grew[kernel, "skipped"]) == (2, 6, 8)
+    after_k = _kernel_counts()
+    assert {key for key in after_k if after_k[key] != before_k.get(key, 0)} \
+        == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
+
+
+def test_masked_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the three masked kernels at 8 query heads a kv head,
+    8,192 positions and head_dim 128, the shape the causal ``dkv`` kernel
+    is refused for: compiled here for a v5e that is described, not
+    attached."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no TPU topology to compile for: {e}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, k = (jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16,
+                                 sharding=one_chip) for h in (8, 1))
+    assert not fa.supported(q, k, k, True)
+    ranges = _block_diffusion_ranges(4096, 4)
+    assert fa.supported(q, k, k, False, ranges)
+    text = jax.jit(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, mask=ranges).astype(
+            jnp.float32).sum(), (0, 1, 2))(q, k, v)).lower(
+                q, k, k).compile().as_text()
+    for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert name in text

@@ -1,0 +1,137 @@
+"""Routed experts that drop no token (models/moe.py, ``moe_dispatch =
+"dropless"``): the experts a chip holds, whatever the routing."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu import metrics
+from horovod_tpu.models import llama, moe
+
+CFG = llama.LlamaConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
+                        n_kv_heads=2, d_ff=16, n_experts=8, expert_top_k=2,
+                        moe_dispatch="dropless", experts_held=4,
+                        experts_first=2, dtype=jnp.float32)
+PAR = llama.ParallelSpec()
+
+
+def _params(cfg, seed=0):
+    lp = moe.init_moe_layer_params(jax.random.key(seed), 1, cfg.d_model,
+                                   cfg.d_ff, cfg.n_experts,
+                                   n_held=cfg.experts_held)
+    return {n: w[0] for n, w in lp.items()}
+
+
+def _dense(x, lp, cfg):
+    """Every held expert over every token, weighted by the router."""
+    tokens = x.reshape(-1, x.shape[-1])
+    p = jax.nn.softmax(tokens @ lp["router"], axis=-1)
+    top_p, top_i = jax.lax.top_k(p, cfg.expert_top_k)
+    top_w = top_p / top_p.sum(-1, keepdims=True)
+    y = jnp.zeros_like(tokens)
+    for e in range(lp["we_gate"].shape[0]):
+        w_e = jnp.where(top_i == cfg.experts_first + e, top_w, 0.0).sum(-1)
+        h = jax.nn.silu(tokens @ lp["we_gate"][e]) * (tokens @ lp["we_up"][e])
+        y = y + w_e[:, None] * (h @ lp["we_down"][e])
+    return y.reshape(x.shape)
+
+
+def test_dropless_layer_is_the_held_experts_part_of_the_dense_layer():
+    lp = _params(CFG)
+    x = jax.random.normal(jax.random.key(1), (2, 48, CFG.d_model))
+    y, stats = moe.dropless_moe_layer(x, lp, CFG, PAR)
+    np.testing.assert_allclose(y, _dense(x, lp, CFG), atol=1e-5, rtol=1e-5)
+    pairs, rows, fullest, layers = np.asarray(stats)
+    top_i = jax.lax.top_k(x.reshape(-1, 32) @ lp["router"], 2)[1]
+    held = (top_i >= 2) & (top_i < 6)
+    assert pairs == rows == held.sum() and layers == 1
+    assert fullest == max((top_i == e).sum() for e in range(2, 6))
+
+
+def test_every_token_to_one_held_expert_and_none_is_lost():
+    """All N tokens choose held expert 3 first and held expert 4 second:
+    2 N pairs, twice what a chunk takes, every one computed."""
+    lp = _params(CFG)
+    router = np.zeros((CFG.d_model, CFG.n_experts), np.float32)
+    lp["router"] = jnp.asarray(router)
+    x = jax.random.normal(jax.random.key(2), (4, 256, CFG.d_model))
+    x = x.at[..., 0].set(4.0)            # one feature every token shares
+    lp["router"] = lp["router"].at[0, 3].set(2.0).at[0, 4].set(1.0)
+    N = 4 * 256
+    assert moe._chunk_rows(N, 2, 4, 8) < 2 * N      # more than one chunk
+    y, stats = jax.jit(lambda x, lp: moe.dropless_moe_layer(
+        x, lp, CFG, PAR))(x, lp)
+    np.testing.assert_allclose(y, _dense(x, lp, CFG), atol=2e-5, rtol=2e-5)
+    assert np.asarray(stats).tolist() == [2 * N, 2 * N, N, 1]
+
+
+def test_no_token_to_a_held_expert_gives_zeros():
+    lp = _params(CFG)
+    x = jax.random.normal(jax.random.key(3), (1, 32, CFG.d_model))
+    x = x.at[..., 0].set(4.0)
+    lp["router"] = jnp.zeros_like(lp["router"]).at[0, 0].set(2.0).at[0, 7].set(1.0)
+    y, stats = moe.dropless_moe_layer(x, lp, CFG, PAR)
+    assert not np.asarray(y).any() and np.asarray(stats).tolist() == [0, 0, 0, 1]
+
+
+@pytest.mark.parametrize("first,held", [(2, 4), (0, 8), (7, 1)])
+def test_dropless_gradients_match_the_dense_layer(first, held):
+    cfg = dataclasses.replace(CFG, experts_first=first, experts_held=held)
+    lp = _params(cfg, seed=4)
+    x = jax.random.normal(jax.random.key(5), (2, 64, cfg.d_model))
+    t = jax.random.normal(jax.random.key(6), x.shape)
+
+    def loss(f):
+        return lambda x, lp: (f(x, lp) * t).sum()
+
+    got = jax.grad(loss(lambda x, lp: moe.dropless_moe_layer(
+        x, lp, cfg, PAR)[0]), (0, 1))(x, lp)
+    want = jax.grad(loss(lambda x, lp: _dense(x, lp, cfg)), (0, 1))(x, lp)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-4)
+
+
+def test_chunk_rows_follow_even_routing_not_the_worst_case():
+    # the cell: 16,384 positions, 8 of 128 a token, 16 held: 16,384 pairs
+    # even, half as much again a chunk; the worst case is eight times that
+    assert moe._chunk_rows(16384, 8, 16, 128) == 24576
+    assert moe._chunk_rows(16, 8, 16, 128) == 16 * 8        # never past it
+    assert moe._chunk_rows(1024, 2, 8, 8) == 2048           # all held
+
+
+def test_record_routing_adds_to_the_counter():
+    def routed():
+        fam = metrics.registry().to_dict()["hvd_moe_routed_total"]
+        return {s["labels"]["what"]: s["value"] for s in fam["series"]}
+
+    before = routed()
+    moe.record_routing(np.array([10.0, 10.0, 4.0, 2.0], np.float32))
+    after = routed()
+    assert {k: after[k] - before.get(k, 0) for k in after} == {
+        "pairs": 10, "rows": 10, "fullest": 4, "layers": 2}
+
+
+def test_dropless_is_refused_over_tp_or_ep_axes():
+    lp = _params(CFG)
+    x = jnp.zeros((1, 8, CFG.d_model))
+    with pytest.raises(NotImplementedError):
+        moe.dropless_moe_layer(x, lp, CFG, llama.ParallelSpec(tp_axis="tp"))
+
+
+def test_config_head_dim_and_dispatch_fields():
+    assert llama.tiny().head_dim == 16                     # d_model / n_heads
+    cfg = dataclasses.replace(llama.tiny(), head_dim=32, qk_norm=True,
+                              tie_embeddings=False)
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert params["layers"]["wq"].shape == (2, 64, 4 * 32)
+    assert params["layers"]["q_norm"].shape == (2, 32)
+    assert params["head"].shape == params["embed"].shape
+    assert llama.count_params(cfg) == sum(
+        int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) \
+        - 2 * 2 * 32                                       # q/k norms apart
+    with pytest.raises(ValueError):
+        dataclasses.replace(cfg, moe_dispatch="sometimes")
