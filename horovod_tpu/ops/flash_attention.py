@@ -47,7 +47,17 @@ Two paths, chosen from the shapes alone (:func:`_pack`; no knob):
   the (query tile, key tile) pairs into dead (no live pair: skipped, no
   load and no product), full (every pair live: no mask applied) and
   mixed (masked inside the tile).  ``hvd_flash_fwd`` and ``hvd_flash_dq``
-  walk each query tile's live key tiles from a table in SMEM;
+  walk each query tile's live key tiles from a table in SMEM.  A forward
+  grid step takes that query tile of several query heads of one GQA group
+  (they share the resident k and v), unrolled, so that one head's softmax
+  is scheduled under another's products: as many as :func:`_fwd_heads`
+  finds room for from the shapes (a divisor of the group, under
+  ``_MASKED_STEP_VMEM``; 4 of 8 at 512 x 512 tiles and ``head_dim`` 128;
+  one where the group is one head or two do not fit; no knob).  Its
+  running maxima, sums and accumulators live in VMEM scratch, the maxima
+  alike in every lane and the sums as 128 partial sums a row that meet
+  after the last tile, and the rows' ranges are spread over the lanes
+  once a step, so a tile's only cross-lane work is its row maximum.
   ``hvd_flash_dkv`` has one grid step per live (key tile, query tile)
   pair and takes the query tiles of its GQA group one at a time, so it
   holds ``g x bq x D`` of ``q`` and ``do``, not ``g x T x D``: 8 query
@@ -90,6 +100,9 @@ NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
 _LANES = 128
+# what one masked forward grid step may hold: a quarter of a v5e core's
+# 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
+_MASKED_STEP_VMEM = 32 * 1024 * 1024
 
 _m_kernels = _metrics.counter(
     "hvd_flash_kernel_total",
@@ -732,13 +745,16 @@ def _pair_table(classes):
     return table.reshape(-1), P
 
 
+def _in_ranges(cols, lo1, hi1, lo2, hi2):
+    return ((cols >= lo1) & (cols < hi1)) | ((cols >= lo2) & (cols < hi2))
+
+
 def _live(rng, col0, shape):
     """Boolean ``shape = (bq, bk)``: the pairs of a tile whose first key
     is ``col0`` that the rows' ranges ``rng [bq, 4]`` let through."""
     cols = lax.broadcasted_iota(jnp.int32, shape, 1)
     r = rng - col0                       # shift the ranges, not the tile
-    return (((cols >= r[:, 0:1]) & (cols < r[:, 1:2]))
-            | ((cols >= r[:, 2:3]) & (cols < r[:, 3:4])))
+    return _in_ranges(cols, *(r[:, c:c + 1] for c in range(4)))
 
 
 def _masked_scores(q, kj, rng, col0, scale, masked):
@@ -758,34 +774,62 @@ def _two_loops(n_full, n_live, step, carry):
                          functools.partial(step, masked=True), carry)
 
 
+def _lanes(x, n):
+    """``x [rows, 128]`` with every lane of a row alike, as ``[rows, n]``."""
+    if n <= _LANES:
+        return x[:, :n]
+    return jnp.tile(x, (1, n // _LANES))
+
+
 def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
-                 o_ref, lse_ref, *, scale, bk, nq, nk, per_batch):
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+                 o_ref, lse_ref, m_ref, l_ref, acc_ref, rb_ref, *, scale, bk,
+                 nq, nk, per_batch):
+    """One query tile of ``hb`` query heads that share a kv head (static,
+    unrolled: one head's softmax is scheduled under another's products).
+    The running statistics live in VMEM, a head at a time in registers:
+    ``m_ref`` the row maxima and ``l_ref`` the row sums as ``[hb, bq,
+    128]``, the maximum alike in every lane, the sum in 128 partial sums
+    (one a lane, added across the tile's column groups elementwise) that
+    meet once, after the last tile; ``acc_ref [hb, bq, D]``.  ``rb_ref
+    [4, bq, 128]`` holds the rows' ranges spread over the lanes once a
+    step, for every head and mixed tile of it."""
+    hb, bq, D = q_ref.shape[1:]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
-    q = q_ref[0, 0]
-    rng = r_ref[0]
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    for c in range(4):
+        rb_ref[c] = jnp.broadcast_to(r_ref[0][:, c:c + 1], (bq, _LANES))
 
     def step(n, carry, masked):
-        m, l, acc = carry
         j = idx_ref[row * nk + n]
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
         kj, vj = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
-        s = _masked_scores(q, kj, rng, j * bk, scale, masked)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        pv = jnp.dot(p.astype(vj.dtype), vj,
-                     preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr + pv
+        for h in range(hb):
+            s = _scores(q_ref[0, h], kj, scale, False)
+            if masked:           # shift the tile's columns, not the ranges
+                cols = lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bk
+                s = jnp.where(_in_ranges(cols, *(_lanes(rb_ref[c], bk)
+                                                 for c in range(4))),
+                              s, NEG_INF)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, bk))
+            corr = jnp.exp(m - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_ref[h] * corr + functools.reduce(
+                jnp.add, (p[:, c:c + _LANES] for c in range(0, bk, _LANES)))
+            acc_ref[h] = acc_ref[h] * _lanes(corr, D) + jnp.dot(
+                p.astype(vj.dtype), vj, preferred_element_type=jnp.float32)
+        return carry
 
-    m, l, acc = _two_loops(
-        nfull_ref[row], nlive_ref[row], step,
-        (jnp.full((bq, 1), NEG_INF, jnp.float32),
-         jnp.zeros((bq, 1), jnp.float32), jnp.zeros((bq, D), jnp.float32)))
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0, i, :] = (m + jnp.log(l)).reshape(bq)
+    _two_loops(nfull_ref[row], nlive_ref[row], step, 0)
+    for h in range(hb):
+        l = l_ref[h].sum(axis=-1, keepdims=True)
+        o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+        # along the lanes for the row of lse: a transpose, not bq shuffles
+        lse_ref[0, h, pl.ds(i, 1), :] = (m_ref[h] + jnp.log(l)).T[:1]
 
 
 def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
@@ -869,23 +913,51 @@ def _mask_plan(mask, bq, bk, Tk):
             (lambda b: b) if per_batch else (lambda b: 0))
 
 
-def _vmem(*block_bytes):
-    """A masked kernel's VMEM limit: its blocks double-buffered, and room
-    for the fp32 tiles in flight."""
-    return pltpu.CompilerParams(
-        vmem_limit_bytes=int(2 * sum(block_bytes) + 24 * 1024 * 1024))
+def _vmem(*block_bytes, scratch=0):
+    """A masked kernel's VMEM limit: its blocks double-buffered, its
+    scratch, and room for the fp32 tiles in flight."""
+    return pltpu.CompilerParams(vmem_limit_bytes=int(
+        2 * sum(block_bytes) + scratch + 24 * 1024 * 1024))
 
 
-def _row_specs(bq, D, Tk, nq, g, bm):
+def _row_specs(bq, D, Tk, nq, g, bm, hb=1):
     """Block specs of the kernels that walk a query tile's key tiles
-    (grid ``(B, H, nq)``, three tables prefetched): a query tile, a kv
-    head's whole keys or values, the head's row statistics, the tile's
-    ranges."""
-    tile = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, *_: (b, h, i, 0))
-    whole = pl.BlockSpec((1, 1, Tk, D), lambda b, h, i, *_: (b, h // g, 0, 0))
-    stats = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
+    (grid ``(B, H // hb, nq)``, three tables prefetched): a query tile of
+    ``hb`` heads, their kv head's whole keys or values, the heads' row
+    statistics, the tile's ranges."""
+    tile = pl.BlockSpec((1, hb, bq, D), lambda b, h, i, *_: (b, h, i, 0))
+    whole = pl.BlockSpec((1, 1, Tk, D),
+                         lambda b, h, i, *_: (b, h * hb // g, 0, 0))
+    stats = pl.BlockSpec((1, hb, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
     rng = pl.BlockSpec((1, bq, 4), lambda b, h, i, *_: (bm(b), i, 0))
     return tile, whole, stats, rng
+
+
+def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize):
+    """VMEM bytes a masked forward grid step of ``hb`` heads holds:
+    ``(blocks, scratch, tiles)``, the blocks the pipeline double-buffers
+    (whole k and v, ``hb`` tiles of q and of out, their rows of lse, the
+    ranges padded to a lane tile), the kernel's scratch (statistics,
+    accumulators, the ranges over the lanes) and every head's ``[bq, bk]``
+    scores and probabilities in fp32 and the latter cast."""
+    blocks = (2 * Tk * D * itemsize + hb * (2 * bq * D * itemsize
+                                            + nq * bq * 4) + bq * _LANES * 4)
+    scratch = (hb * bq * (2 * _LANES + D) + 4 * bq * _LANES) * 4
+    return blocks, scratch, hb * bq * bk * (8 + itemsize)
+
+
+def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize):
+    """Query heads a masked forward grid step takes: the most of one GQA
+    group (a divisor of ``g``: they share the resident k and v) that
+    :func:`_fwd_step_bytes` keeps under ``_MASKED_STEP_VMEM``, one where
+    not even two fit."""
+    def fits(hb):
+        blocks, scratch, tiles = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
+                                                 itemsize)
+        return 2 * blocks + scratch + tiles <= _MASKED_STEP_VMEM
+
+    return max(hb for hb in range(1, g + 1)
+               if g % hb == 0 and (hb == 1 or fits(hb)))
 
 
 def _masked_fwd_bhtd(q, k, v, mask, scale):
@@ -895,23 +967,28 @@ def _masked_fwd_bhtd(q, k, v, mask, scale):
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
+    hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize)
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm)
+    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm, hb)
     _count("fwd", "masked")
     _count_tiles("fwd", classes)
-    item = q.dtype.itemsize
+    blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
+                                         q.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_mfwd_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
                           per_batch=per_batch),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, H, nq),
-            in_specs=[tile, whole, whole, rng], out_specs=[tile, stats]),
+            num_scalar_prefetch=3, grid=(B, H // hb, nq),
+            in_specs=[tile, whole, whole, rng], out_specs=[tile, stats],
+            scratch_shapes=[pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, bq, D), jnp.float32),
+                            pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
         out_shape=[
             _sds((B, H, T, D), q.dtype, q, k, v),
             _sds((B, H, nq, bq), jnp.float32, q, k, v),
         ],
-        compiler_params=_vmem(2 * Tk * D * item, 2 * bq * D * item,
-                              bq * _LANES * 4),
+        compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_INTERPRET,
         name="hvd_flash_fwd",
     )(*_row_tables(classes), q, k, v, ranges)

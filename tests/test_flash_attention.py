@@ -495,27 +495,66 @@ def _dense_masked(q, k, v, live):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
 
 
-@pytest.mark.parametrize("per_batch", [False, True],
-                         ids=["one-mask", "mask-per-row"])
-@pytest.mark.parametrize("mask", sorted(MASKS))
-def test_masked_kernels_match_dense_masked_attention(mask, per_batch,
+def _dense_masked_lse(q, k, live):
+    k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    return jax.nn.logsumexp(jnp.where(
+        live[:, None] if live.ndim == 3 else live[None, None], s, -jnp.inf),
+        -1)
+
+
+# How many query heads a masked forward grid step takes, at every branch
+# of ``_fwd_heads``: (H, Hkv, D), the step's VMEM budget, the heads.
+HEAD_CASES = {
+    "g8-whole-group": ((8, 1, 64), None, 8),
+    "g8-split-group": ((8, 1, 64), 3 << 20, 4),
+    "g8-one-head": ((8, 1, 64), 1 << 20, 1),      # not even two fit
+    "g2-d128": ((4, 2, 128), None, 2),
+    "g1-d128": ((2, 2, 128), None, 1),
+}
+MASKED_CASES = (
+    [(mask, per_batch, "g8-whole-group") for mask in sorted(MASKS)
+     for per_batch in (False, True)]
+    + [("block-diffusion", per_batch, heads) for heads in HEAD_CASES
+       if heads != "g8-whole-group" for per_batch in (False, True)])
+
+
+@pytest.mark.parametrize(
+    "mask,per_batch,heads", MASKED_CASES,
+    ids=[f"{mask}-{'mask-per-row' if per_batch else 'one-mask'}-{heads}"
+         for mask, per_batch, heads in MASKED_CASES])
+def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
                                                      monkeypatch):
-    """Forward and all three gradients at 8 query heads a kv head, the
-    mask known where the call is built (numpy) or traced per batch row."""
+    """Forward (out and lse, which ``dq`` and ``dkv`` read) and all three
+    gradients, the mask known where the call is built (numpy) or traced
+    per batch row, a forward step taking a whole GQA group, a part of
+    one, or one head."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
-    B, T, H, Hkv, D = 2, 512, 8, 1, 64
+    (H, Hkv, D), budget, hb = HEAD_CASES[heads]
+    if budget is not None:
+        monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
+    B, T = 2, 512
+    assert fa._fwd_heads(H // Hkv, 128, 128, D, T // 128, T, 4) == hb
     q, k, v = make_qkv(B, T, H, Hkv, D)
     ranges = MASKS[mask](T)
     live = jnp.asarray(fa.dense_mask(ranges, T))
     given = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
     assert fa.supported(q, k, v, False, given)
+    if mask == "block-diffusion":
+        # a query tile whose live key tiles are all mixed, and one with a
+        # single live tile
+        classes = fa.tile_classes(ranges[None], 128, 128, T)[0]
+        n_full, n_live = (classes == 2).sum(-1), (classes >= 1).sum(-1)
+        assert ((n_full == 0) & (n_live >= 2)).any() and (n_live == 1).any()
 
     def loss(attend):
         return lambda q, k, v: (attend(q, k, v) ** 2).sum()
 
-    out = fa.flash_attention(q, k, v, mask=given)
+    out, lse = fa.flash_attention_lse(q, k, v, mask=given)
     np.testing.assert_allclose(out, _dense_masked(q, k, v, live),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, _dense_masked_lse(q, k, live),
                                atol=2e-5, rtol=2e-5)
     got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
         q, k, v, mask=given)), (0, 1, 2))(q, k, v)
@@ -524,6 +563,53 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch,
     for a, b, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
                                    err_msg=f"d{name}")
+
+
+def test_forward_heads_a_step_rule():
+    # the benchmark's SDAR cell: four of a group's eight heads a step
+    assert fa._fwd_heads(8, 512, 512, 128, 16, 8192, 2) == 4
+    # one query head a kv head: nothing to share
+    assert fa._fwd_heads(1, 512, 512, 128, 16, 8192, 2) == 1
+    # short sequences: the whole group
+    assert fa._fwd_heads(8, 128, 128, 64, 4, 512, 4) == 8
+    assert fa._fwd_heads(6, 512, 512, 64, 4, 2048, 2) == 6
+    # float32 at head_dim 256: a part of the group
+    assert fa._fwd_heads(8, 512, 512, 256, 8, 4096, 4) == 2
+    # whatever is chosen divides the group and fits, or is one head
+    for g in (1, 2, 3, 4, 6, 8, 16):
+        for bq in (128, 256, 512):
+            for D in (64, 128, 256):
+                for T in (1024, 8192, 32768):
+                    for itemsize in (2, 4):
+                        hb = fa._fwd_heads(g, bq, bq, D, T // bq, T, itemsize)
+                        assert g % hb == 0
+                        blocks, scratch, tiles = fa._fwd_step_bytes(
+                            hb, bq, bq, D, T // bq, T, itemsize)
+                        assert hb == 1 or (2 * blocks + scratch + tiles
+                                           <= fa._MASKED_STEP_VMEM)
+
+
+def test_masked_forward_specs(monkeypatch):
+    """A forward grid step's blocks: four of a group's eight query tiles
+    on their kv head's whole keys and values; ``dq`` keeps one head a
+    step."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    B, T, H, Hkv, D = 2, 2048, 16, 2, 128
+    bq, nq, g = 512, 4, 4
+    q, k = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16)
+            for h in (H, Hkv))
+    ranges = _block_diffusion_ranges(T // 2, 4)
+    calls = dict((name, (grid, blocks)) for name, grid, blocks in
+                 _pallas_calls(lambda q, k, v: jax.grad(
+                     lambda q, k, v: fa.flash_attention(
+                         q, k, v, mask=ranges).astype(jnp.float32).sum(),
+                     (0, 1, 2))(q, k, v), q, k, k))
+    kvb = (1, 1, T, D)
+    assert calls["hvd_flash_fwd"] == ((B, H // g, nq), [
+        (1, g, bq, D), kvb, kvb, (1, bq, 4), (1, g, bq, D), (1, g, nq, bq)])
+    qb, row = (1, 1, bq, D), (1, 1, nq, bq)
+    assert calls["hvd_flash_dq"] == ((B, H, nq), [
+        qb, kvb, kvb, qb, row, row, (1, bq, 4), qb])
 
 
 def test_causal_ranges_agree_with_the_causal_kernels(monkeypatch):
@@ -594,11 +680,14 @@ def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
         == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
 
 
-def test_masked_kernels_lower_for_the_chip(monkeypatch):
+@pytest.mark.parametrize("B,H,Hkv", [(1, 8, 1), (2, 32, 4)],
+                         ids=["one-group", "sdar-cell"])
+def test_masked_kernels_lower_for_the_chip(B, H, Hkv, monkeypatch):
     """Mosaic takes the three masked kernels at 8 query heads a kv head,
     8,192 positions and head_dim 128, the shape the causal ``dkv`` kernel
-    is refused for: compiled here for a v5e that is described, not
-    attached."""
+    is refused for, for one group and at the benchmark's SDAR cell (32
+    query heads over 4, batch 2): compiled here for a v5e that is
+    described, not attached."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     monkeypatch.setenv("TPU_LOG_DIR", "disabled")
@@ -609,8 +698,8 @@ def test_masked_kernels_lower_for_the_chip(monkeypatch):
         pytest.skip(f"no TPU topology to compile for: {e}")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(topo.devices[0])
-    q, k = (jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16,
-                                 sharding=one_chip) for h in (8, 1))
+    q, k = (jax.ShapeDtypeStruct((B, 8192, h, 128), jnp.bfloat16,
+                                 sharding=one_chip) for h in (H, Hkv))
     assert not fa.supported(q, k, k, True)
     ranges = _block_diffusion_ranges(4096, 4)
     assert fa.supported(q, k, k, False, ranges)
