@@ -414,7 +414,6 @@ def main():
     #   HOROVOD_BENCH_LOSS_CHUNK  chunked vocab cross-entropy
     #   HOROVOD_BENCH_REMAT_SKIP  last-k layers un-remat'd
     #   HOROVOD_BENCH_OPT=lp      bf16-moment AdamW
-    #   HOROVOD_BENCH_FUSED_XENT  fused Pallas cross-entropy kernel
     cfg = llama.LlamaConfig(
         vocab_size=32768, d_model=2048, n_layers=16, n_heads=16,
         n_kv_heads=8, d_ff=8192, max_seq_len=1024, remat=True,
@@ -423,8 +422,7 @@ def main():
         remat_policy=os.environ.get("HOROVOD_BENCH_REMAT_POLICY", "full"),
         loss_chunk=int(os.environ.get("HOROVOD_BENCH_LOSS_CHUNK", "2048")),
         remat_skip_layers=int(
-            os.environ.get("HOROVOD_BENCH_REMAT_SKIP", "2")),
-        fused_xent=os.environ.get("HOROVOD_BENCH_FUSED_XENT") == "1")
+            os.environ.get("HOROVOD_BENCH_REMAT_SKIP", "2")))
     batch, seq, steps = _env_batch(8), 1024, 30
     if on_cpu:  # keep the CPU fallback path quick
         cfg = dataclasses.replace(

@@ -185,7 +185,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[str, Any]]:
         for suffix, v in candidates:
             inner = v.jaxpr if hasattr(v, "jaxpr") else v
             label = f"{prim}:{key}{suffix}"
-            if prim == "pjit":
+            if prim in ("pjit", "jit"):
                 name = eqn.params.get("name")
                 if name:
                     label = f"pjit<{name}>"

@@ -66,11 +66,6 @@ class LlamaConfig:
     # skipped recompute.  Spend freed memory here: each skipped layer
     # saves one forward-recompute of itself in the backward pass.
     remat_skip_layers: int = 0
-    # fused Pallas cross-entropy (ops/fused_xent.py): head matmul +
-    # online softmax in one kernel, logits never exist beyond a VMEM
-    # tile.  Opt-in; falls back to loss_chunk / one-shot when the
-    # kernel does not support the shape/backend.
-    fused_xent: bool = False
     # vocab-parallel embedding/head (megatron VocabParallelEmbedding):
     # shards the tied embedding's vocab axis over tp — at Llama-3-8B the
     # 0.53 GB embedding stops being replicated per tp shard.  Lookup
@@ -589,14 +584,12 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
                     mask)
     h = h[:, :targets.shape[1]]
     head = _head(params, cfg)
-    if weights is not None and (_vp_active(cfg, par) or cfg.fused_xent):
+    if weights is not None and _vp_active(cfg, par):
         raise NotImplementedError(
             "weights per position go through the chunked or one-shot "
-            "cross-entropy, not the vocab-parallel or fused one")
+            "cross-entropy, not the vocab-parallel one")
 
     def warn_unchunked():
-        # only on paths that actually materialize the unchunked logits
-        # (the fused kernel never does — it must not trigger this)
         if cfg.loss_chunk > 0 and h.shape[1] % cfg.loss_chunk:
             import logging
             logging.getLogger("horovod_tpu").warning(
@@ -606,19 +599,13 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
                 "materialized", cfg.loss_chunk, h.shape[1],
                 "/tp" if _vp_active(cfg, par) else "")
 
-    loss = None
     if _vp_active(cfg, par):
         warn_unchunked()
         loss = _vocab_parallel_xent(h, head, targets, par,
                                     chunk=cfg.loss_chunk)
-    if loss is None and cfg.fused_xent:
-        from ..ops import fused_xent
-        if fused_xent.supported(h, head, targets):
-            loss = fused_xent.fused_xent_mean(h, head, targets)
-    if loss is None and cfg.loss_chunk > 0 \
-            and h.shape[1] % cfg.loss_chunk == 0:
+    elif cfg.loss_chunk > 0 and h.shape[1] % cfg.loss_chunk == 0:
         loss = _chunked_xent(h, head, targets, cfg.loss_chunk, weights)
-    if loss is None:
+    else:
         warn_unchunked()
         if weights is None:
             logits = h @ head.T.astype(h.dtype)

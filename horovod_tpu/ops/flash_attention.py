@@ -18,52 +18,51 @@ The logsumexp residual is stored blocked as ``[B, H, nq, bq]`` — the
 (nq, bq) trailing dims are full blocks, which satisfies Mosaic's tiling
 rule without the 128-lane padding the naive ``[B, H, T]`` layout needs.
 
-Two paths, chosen from the shapes alone (:func:`_pack`; no knob):
+Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
 
-* **tiled** — the sequence spans several blocks (``nq > 1`` or
-  ``nkv > 1``): one (batch, head, block) per grid step, the
-  online-softmax loop over kv blocks, ``hvd_flash_fwd`` /
-  ``hvd_flash_dq`` / ``hvd_flash_dkv`` in ``[B, H, T, D]`` layout.
-* **packed** — the whole sequence is one block (BERT's 128 tokens; any
-  ``T <= 512``): a grid step costs about 0.4 us on a v5e whatever it
-  does, and one (batch, head) pair of a short sequence is less work
-  than that.  So a step takes a pack of ``bb`` batch rows x ``hb`` heads,
-  as large as ``_VMEM_BUDGET`` holds.  The kernels read the caller's
-  layout as ``[B, T, H*D]`` (a free reshape: no transposes around the
-  call), cut on 128-lane tiles; with ``D = 64`` a tile holds two heads
-  and each head's products run on the whole tile with the other head's
-  lanes zeroed, which costs the MXU nothing (it is 128 wide either way)
-  and keeps every load and store aligned.  With all of S in one block
-  the backward is one kernel, ``hvd_flash_bwd``: S, P, dP and dS are
-  computed once and dq, dk, dv written from them.  Same products in the
-  same dtypes as the tiled kernels.
+* **packed** — no ``mask`` and the whole sequence is one block (BERT's
+  128 tokens; any ``T <= 512``): a grid step costs about 0.4 us on a v5e
+  whatever it does, and one (batch, head) pair of a short sequence is
+  less work than that.  So a step takes a pack of ``bb`` batch rows x
+  ``hb`` heads, as large as ``_VMEM_BUDGET`` holds.  The kernels read the
+  caller's layout as ``[B, T, H*D]`` (a free reshape: no transposes
+  around the call), cut on 128-lane tiles; with ``D = 64`` a tile holds
+  two heads and each head's products run on the whole tile with the
+  other head's lanes zeroed, which costs the MXU nothing (it is 128 wide
+  either way) and keeps every load and store aligned.  With all of S in
+  one block the backward is one kernel, ``hvd_flash_bwd``: S, P, dP and
+  dS are computed once and dq, dk, dv written from them.  Same products
+  in the same dtypes as the masked kernels.
 
-* **masked** — the caller gives the mask as data (``mask=``): for every
-  query row two half-open ranges of key positions, ``[T, 4]`` or
-  ``[B, T, 4]`` int32 ``(lo1, hi1, lo2, hi2)``; a key is seen when it
-  lies in either.  Causal, sliding-window, packed-document and
-  block-diffusion masks are all such ranges (:func:`causal_ranges`,
-  :func:`window_ranges`); no mode per model.  :func:`tile_classes` sorts
-  the (query tile, key tile) pairs into dead (no live pair: skipped, no
-  load and no product), full (every pair live: no mask applied) and
-  mixed (masked inside the tile).  ``hvd_flash_fwd`` and ``hvd_flash_dq``
-  walk each query tile's live key tiles from a table in SMEM.  A forward
-  grid step takes that query tile of several query heads of one GQA group
-  (they share the resident k and v), unrolled, so that one head's softmax
-  is scheduled under another's products: as many as :func:`_fwd_heads`
-  finds room for from the shapes (a divisor of the group, under
-  ``_MASKED_STEP_VMEM``; 4 of 8 at 512 x 512 tiles and ``head_dim`` 128;
-  one where the group is one head or two do not fit; no knob).  Its
-  running maxima, sums and accumulators live in VMEM scratch, the maxima
-  alike in every lane and the sums as 128 partial sums a row that meet
-  after the last tile, and the rows' ranges are spread over the lanes
-  once a step, so a tile's only cross-lane work is its row maximum.
-  ``hvd_flash_dkv`` has one grid step per live (key tile, query tile)
-  pair and takes the query tiles of its GQA group one at a time, so it
-  holds ``g x bq x D`` of ``q`` and ``do``, not ``g x T x D``: 8 query
-  heads a kv head at 8,192 positions run.  Every query row has to see at
-  least one key.  ``causal=True`` without ``mask`` stays on the tiled or
-  packed kernels above, the same products in the same order.
+* **masked** — everything else: several blocks of ``_BLOCK`` positions,
+  or one that does not pack (``hvd_flash_fwd`` / ``hvd_flash_dq`` /
+  ``hvd_flash_dkv`` in ``[B, H, T, D]`` layout), under a mask that is data: for every query
+  row two half-open ranges of key positions, ``[T, 4]`` or ``[B, T, 4]``
+  int32 ``(lo1, hi1, lo2, hi2)``; a key is seen when it lies in either.
+  Causal, sliding-window, packed-document and block-diffusion masks are
+  all such ranges (:func:`causal_ranges`, :func:`window_ranges`); no mode
+  per model.  The caller gives them as ``mask=``; without one,
+  ``causal=True`` is :func:`causal_ranges` and ``causal=False`` is
+  :func:`full_ranges`, made where the call is built.  :func:`tile_classes`
+  sorts the (query tile, key tile) pairs into dead (no live pair:
+  skipped, no load and no product), full (every pair live: no mask
+  applied) and mixed (masked inside the tile).  ``hvd_flash_fwd`` and
+  ``hvd_flash_dq`` walk each query tile's live key tiles from a table in
+  SMEM.  A forward grid step takes that query tile of several query
+  heads of one GQA group (they share the resident k and v), unrolled, so
+  that one head's softmax is scheduled under another's products: as many
+  as :func:`_fwd_heads` finds room for from the shapes (a divisor of the
+  group, under ``_MASKED_STEP_VMEM``; 4 of 8 at 512 x 512 tiles and
+  ``head_dim`` 128; one where the group is one head or two do not fit;
+  no knob).  Its running maxima, sums and accumulators live in VMEM
+  scratch, the maxima alike in every lane and the sums as 128 partial
+  sums a row that meet after the last tile, and the rows' ranges are
+  spread over the lanes once a step, so a tile's only cross-lane work is
+  its row maximum.  ``hvd_flash_dkv`` has one grid step per live (key
+  tile, query tile) pair and takes the query tiles of its GQA group one
+  at a time, so it holds ``g x bq x D`` of ``q`` and ``do``, not
+  ``g x T x D``: 8 query heads a kv head at 8,192 positions run.  Every
+  query row has to see at least one key.
 
 ``hvd_flash_kernel_total{kernel, path}`` counts the kernels built, once
 per traced call site, so a program says which path its shapes took;
@@ -99,6 +98,7 @@ logger = logging.getLogger("horovod_tpu")
 NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
+_BLOCK = 512  # query and key positions a block
 _LANES = 128
 # what one masked forward grid step may hold: a quarter of a v5e core's
 # 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
@@ -106,7 +106,9 @@ _MASKED_STEP_VMEM = 32 * 1024 * 1024
 
 _m_kernels = _metrics.counter(
     "hvd_flash_kernel_total",
-    "Flash-attention Pallas kernels built, one per traced call site",
+    "Flash-attention Pallas kernels built, one per traced call site; "
+    "path is packed or masked (tiled stopped occurring: several blocks "
+    "without mask= count as masked)",
     labels=("kernel", "path"))
 
 
@@ -132,23 +134,10 @@ def _count_tiles(kernel: str, classes) -> None:
 
 
 def _block_sizes(t_q: int, t_kv: int):
-    """Query/key block sizes for the kernel grid.
-
-    ``HOROVOD_FLASH_BLOCK`` overrides the 512 default (the measured
-    best on v5e at the flagship geometry; tools/flash_sweep.py measures
-    candidates — the reference tuned its fusion analogs through the
-    autotuner the same way).  The override is clamped to the sequence
-    lengths; supported() still rejects non-dividing or non-128-multiple
-    results, falling back to the XLA attention path."""
-    try:
-        blk = int(os.environ.get("HOROVOD_FLASH_BLOCK", "512") or 512)
-    except ValueError:
-        blk = 512
-    if blk <= 0:  # 0/negative would crash the divisibility gate; use
-        blk = 512  # HOROVOD_FLASH_ATTENTION=0 to disable the kernel
-    bq = min(blk, t_q)
-    bk = min(blk, t_kv)
-    return bq, bk
+    """Query/key block sizes of the kernel grid: ``_BLOCK``, or the whole
+    of a shorter sequence.  :func:`supported` refuses results that do not
+    divide the lengths or are no multiples of 128."""
+    return min(_BLOCK, t_q), min(_BLOCK, t_kv)
 
 
 def _lane_tile(D):
@@ -172,14 +161,14 @@ def _packed_resident(bb, hb, g, T, Tk, D, itemsize):
 def _pack(B, H, Hkv, T, Tk, D, itemsize):
     """``(bb, hb)``: batch rows and query heads one grid step takes.
 
-    ``(1, 1)`` is the tiled path.  A sequence that is one block
+    ``(1, 1)`` is the masked path.  A sequence that is one block
     (``nq == nkv == 1``) is packed: the largest ``hb`` dividing ``H``,
     then the largest ``bb`` dividing ``B``, that
     :func:`_packed_resident` keeps under ``_VMEM_BUDGET``.  The packed
     kernels cut ``[B, T, H*D]`` on 128-lane tiles, so ``hb`` holds whole
     tiles (two heads at ``D = 64``) and whole GQA groups; where a head
     neither fills nor evenly divides 128 lanes, or a tile of several
-    heads would meet a GQA group, the shape stays on the tiled path."""
+    heads would meet a GQA group, the shape stays on the masked path."""
     g = H // Hkv
     lanes, per_tile = _lane_tile(D)
     if ((T, Tk) != _block_sizes(T, Tk) or lanes % _LANES
@@ -220,7 +209,7 @@ def _verdict(kernel: str, reason: Optional[str], *operands) -> bool:
     return reason is None
 
 
-def _refusal(q, k, v, mask=None) -> Optional[str]:
+def _refusal(q, k, v) -> Optional[str]:
     """Which test keeps the Pallas kernel off this call; None = it runs."""
     if os.environ.get("HOROVOD_FLASH_ATTENTION", "1") in ("0", "false"):
         return "HOROVOD_FLASH_ATTENTION is off"
@@ -243,10 +232,8 @@ def _refusal(q, k, v, mask=None) -> Optional[str]:
     if q.dtype not in (jnp.bfloat16, jnp.float32):
         return f"dtype {q.dtype} is neither bfloat16 nor float32"
     g = H // Hkv
-    # fwd holds k+v [Tk, D]; bwd dkv holds q+do [g*T, D] per group, the
-    # masked dkv one query tile of the group at a time
-    rows = g * bq if mask is not None else g * T
-    resident = max(2 * Tk * D, 2 * rows * D) * q.dtype.itemsize
+    # fwd holds k+v [Tk, D]; dkv holds q+do of one query tile of the group
+    resident = max(2 * Tk * D, 2 * g * bq * D) * q.dtype.itemsize
     if resident > _VMEM_BUDGET:
         return (f"resident buffers need {resident} bytes of VMEM, over "
                 f"the {_VMEM_BUDGET} budget")
@@ -254,225 +241,9 @@ def _refusal(q, k, v, mask=None) -> Optional[str]:
 
 
 def supported(q, k, v, causal: bool = True, mask=None) -> bool:
-    """True when the Pallas kernel can run this shape on this backend."""
-    return _verdict("flash_attention", _refusal(q, k, v, mask), q, k, v)
-
-
-# ---------------------------------------------------------------- forward
-
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                bk, nkv):
-    bq, D = q_ref.shape[2], q_ref.shape[3]
-    i = pl.program_id(2)
-    q = q_ref[0, 0]
-
-    if causal:
-        hi = jnp.minimum(lax.div((i + 1) * bq + bk - 1, bk), nkv)
-    else:
-        hi = nkv
-
-    def body(j, carry):
-        m, l, acc = carry
-        kj = k_ref[0, 0, pl.ds(j * bk, bk), :]
-        vj = v_ref[0, 0, pl.ds(j * bk, bk), :]
-        s = lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            cols = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-            s = jnp.where(cols <= rows, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        pv = jnp.dot(p.astype(vj.dtype), vj,
-                     preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr + pv
-
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, D), jnp.float32)
-    m, l, acc = lax.fori_loop(0, hi, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0, i, :] = (m + jnp.log(l)).reshape(bq)
-
-
-def _flash_fwd_bhtd(q, k, v, causal, scale):
-    """q [B,H,T,D], k/v [B,Hkv,Tk,D] → (out [B,H,T,D], lse [B,H,nq,bq])."""
-    B, H, T, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
-    g = H // Hkv
-    bq, bk = _block_sizes(T, Tk)
-    nq, nkv = T // bq, Tk // bk
-
-    _count("fwd", "tiled")
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bk=bk, nkv=nkv)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            # lse block is per-(b,h): consecutive i steps reuse the same
-            # VMEM buffer, each filling its own row, flushed on (b,h) change
-            pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            _sds((B, H, T, D), q.dtype, q, k, v),
-            _sds((B, H, nq, bq), jnp.float32, q, k, v),
-        ],
-        interpret=_INTERPRET,
-        name="hvd_flash_fwd",
-    )(q, k, v)
-    return out, lse
-
-
-# --------------------------------------------------------------- backward
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               scale, causal, bk, nkv):
-    bq, D = q_ref.shape[2], q_ref.shape[3]
-    i = pl.program_id(2)
-    q = q_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, i, :].reshape(bq, 1)
-    delta = delta_ref[0, 0, i, :].reshape(bq, 1)
-
-    if causal:
-        hi = jnp.minimum(lax.div((i + 1) * bq + bk - 1, bk), nkv)
-    else:
-        hi = nkv
-
-    def body(j, dq_acc):
-        kj = k_ref[0, 0, pl.ds(j * bk, bk), :]
-        vj = v_ref[0, 0, pl.ds(j * bk, bk), :]
-        s = lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            cols = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-            s = jnp.where(cols <= rows, s, NEG_INF)
-        p = jnp.exp(s - lse)                      # [bq, bk]
-        dp = lax.dot_general(do, vj.astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jnp.dot(ds.astype(kj.dtype), kj,
-                                preferred_element_type=jnp.float32)
-
-    dq = lax.fori_loop(0, hi, body, jnp.zeros((bq, D), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, scale, causal, bq, nq, g):
-    bk, D = k_ref.shape[2], k_ref.shape[3]
-    j = pl.program_id(2)
-    kb = k_ref[0, 0]
-    vb = v_ref[0, 0]
-
-    lo = lax.div(j * bk, bq) if causal else 0
-
-    dk_acc = jnp.zeros((bk, D), jnp.float32)
-    dv_acc = jnp.zeros((bk, D), jnp.float32)
-    for hq in range(g):  # static unroll over the GQA group
-        def body(i, carry):
-            dk_acc, dv_acc = carry
-            qi = q_ref[0, hq, pl.ds(i * bq, bq), :]
-            doi = do_ref[0, hq, pl.ds(i * bq, bq), :].astype(jnp.float32)
-            lse = lse_ref[0, hq, i, :].reshape(bq, 1)
-            delta = delta_ref[0, hq, i, :].reshape(bq, 1)
-            s = lax.dot_general(qi, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            if causal:
-                rows = (lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-                        + i * bq)
-                cols = (lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-                        + j * bk)
-                s = jnp.where(cols <= rows, s, NEG_INF)
-            p = jnp.exp(s - lse)                  # [bq, bk]
-            dv_new = dv_acc + lax.dot_general(
-                p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = lax.dot_general(doi, vb.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk_new = dk_acc + lax.dot_general(
-                ds, qi.astype(jnp.float32), (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return dk_new, dv_new
-
-        dk_acc, dv_acc = lax.fori_loop(lo, nq, body, (dk_acc, dv_acc))
-    dk_ref[0, 0] = dk_acc.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv_acc.astype(dv_ref.dtype)
-
-
-def _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale, dlse=None):
-    B, H, T, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
-    g = H // Hkv
-    bq, bk = _block_sizes(T, Tk)
-    nq, nkv = T // bq, Tk // bk
-
-    # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
-    # When the caller differentiates through the exposed lse (ring-step
-    # merging), its cotangent folds in exactly here: dlse/ds = p, so
-    # ds = p·(dp − delta) + p·dlse = p·(dp − (delta − dlse)).
-    delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
-                       out.astype(jnp.float32)).reshape(B, H, nq, bq)
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-
-    _count("dq", "tiled")
-    _count("dkv", "tiled")
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, bk=bk,
-                          nkv=nkv),
-        grid=(B, H, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
-        out_shape=_sds((B, H, T, D), q.dtype, q, k, v, do),
-        interpret=_INTERPRET,
-        name="hvd_flash_dq",
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq,
-                          nq=nq, g=g),
-        grid=(B, Hkv, nkv),
-        in_specs=[
-            pl.BlockSpec((1, g, T, D), lambda b, c, j: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, c, j: (b, c, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, c, j: (b, c, j, 0)),
-            pl.BlockSpec((1, g, T, D), lambda b, c, j: (b, c, 0, 0)),
-            pl.BlockSpec((1, g, nq, bq), lambda b, c, j: (b, c, 0, 0)),
-            pl.BlockSpec((1, g, nq, bq), lambda b, c, j: (b, c, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, c, j: (b, c, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, c, j: (b, c, j, 0)),
-        ],
-        out_shape=[
-            _sds((B, Hkv, Tk, D), k.dtype, q, k, v, do),
-            _sds((B, Hkv, Tk, D), v.dtype, q, k, v, do),
-        ],
-        interpret=_INTERPRET,
-        name="hvd_flash_dkv",
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    """True when the Pallas kernel can run this shape on this backend;
+    the same shapes with or without ``causal`` or a ``mask``."""
+    return _verdict("flash_attention", _refusal(q, k, v), q, k, v)
 
 
 # ------------------------------------------------- packed (one-block) path
@@ -608,7 +379,7 @@ def _packed_fwd(q, k, v, causal, scale, D, pack):
 def _packed_bwd(q, k, v, out, lse, do, dlse, causal, scale, D, pack):
     grid, q_blk, kv_blk, row_blk, g = _packed_specs(q, k, D, pack)
     B, T, HD = q.shape
-    # delta as in the tiled path (rowsum(dO * O), less the lse cotangent),
+    # delta as in the masked path (rowsum(dO * O), less the lse cotangent),
     # per head of the flat layout
     delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
         B, T, HD // D, D).sum(-1).transpose(0, 2, 1)[:, :, None, :]
@@ -659,6 +430,13 @@ def causal_ranges(T: int):
     """``[T, 4]``: row i sees keys ``[0, i + 1)``."""
     r = np.zeros((T, 4), np.int32)
     r[:, 1] = np.arange(T) + 1
+    return r
+
+
+def full_ranges(T: int, Tk: int):
+    """``[T, 4]``: every row sees every key (every tile full)."""
+    r = np.zeros((T, 4), np.int32)
+    r[:, 1] = Tk
     return r
 
 
@@ -961,7 +739,7 @@ def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize):
 
 
 def _masked_fwd_bhtd(q, k, v, mask, scale):
-    """As :func:`_flash_fwd_bhtd`, under ``mask``."""
+    """q [B,H,T,D], k/v [B,Hkv,Tk,D] → (out [B,H,T,D], lse [B,H,nq,bq])."""
     B, H, T, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     g = H // Hkv
@@ -1004,6 +782,10 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
     tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm)
     item = q.dtype.itemsize
 
+    # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
+    # When the caller differentiates through the exposed lse (ring-step
+    # merging), its cotangent folds in exactly here: dlse/ds = p, so
+    # ds = p·(dp − delta) + p·dlse = p·(dp − (delta − dlse)).
     delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
                        out.astype(jnp.float32)).reshape(B, H, nq, bq)
     if dlse is not None:
@@ -1104,59 +886,32 @@ _masked_attention_lse.defvjp(_masked_attention_lse_fwd,
 
 
 # ------------------------------------------------------------- public op
-# The GQA group reshape in _dkv_kernel's q block assumes query heads of
-# one kv group are contiguous (head h ↔ kv head h // g), matching
+# The GQA group in _mdkv_kernel's q block assumes query heads of one kv
+# group are contiguous (head h ↔ kv head h // g), matching
 # jnp.repeat(k, g, axis=head) semantics used across the framework.
-# One custom_vjp serves both entry points: the plain path is the lse path
+# Each custom_vjp serves both entry points: the plain path is the lse path
 # with a zero lse cotangent (folded into delta as a cheap subtract).
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention_lse(q, k, v, causal, scale):
-    return _flash_fwd_bhtd(q, k, v, causal, scale)
-
-
-def _flash_attention_lse_fwd(q, k, v, causal, scale):
-    out, lse = _flash_fwd_bhtd(q, k, v, causal, scale)
-    return (out, lse), (q, k, v, out, lse)
-
-
-def _flash_attention_lse_bwd(causal, scale, res, cotangents):
-    do, dlse = cotangents
-    q, k, v, out, lse = res
-    return _flash_bwd_bhtd(q, k, v, out, lse, do, causal, scale,
-                           dlse=dlse)
-
-
-_flash_attention_lse.defvjp(_flash_attention_lse_fwd,
-                            _flash_attention_lse_bwd)
-
 
 def _attention_lse(q, k, v, causal, sm_scale, mask=None):
     """Both public entry points: ``(out [B,T,H,D], lse [B,H,T])`` by the
-    path :func:`_pack` gives these shapes, or the masked path for a
-    ``mask``."""
+    packed path where there is no ``mask`` and :func:`_pack` takes these
+    shapes, else by the masked path."""
     scale = float(sm_scale if sm_scale is not None
                   else q.shape[-1] ** -0.5)
     B, T, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
-    if mask is not None:
-        static = (_StaticMask(mask) if isinstance(mask, np.ndarray)
-                  else None)
-        out, lse = _masked_attention_lse(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), None if static else mask, static,
-            scale)
-        return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
-    pack = _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize)
-    if pack != (1, 1):
-        out, lse = _packed_attention_lse(
-            q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
-            v.reshape(B, Tk, Hkv * D), bool(causal), scale, D, pack)
-        return out.reshape(B, T, H, D), lse.reshape(B, H, T)
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out, lse = _flash_attention_lse(qt, kt, vt, bool(causal), scale)
+    if mask is None:
+        pack = _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize)
+        if pack != (1, 1):
+            out, lse = _packed_attention_lse(
+                q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
+                v.reshape(B, Tk, Hkv * D), bool(causal), scale, D, pack)
+            return out.reshape(B, T, H, D), lse.reshape(B, H, T)
+        mask = causal_ranges(T) if causal else full_ranges(T, Tk)
+    static = _StaticMask(mask) if isinstance(mask, np.ndarray) else None
+    out, lse = _masked_attention_lse(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), None if static else mask, static, scale)
     return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
 
 

@@ -40,7 +40,7 @@ def make_qkv(B, T, H, Hkv, D, dtype=jnp.float32, seed=0):
 
 
 # One-block shapes and the pack (bb, hb) each must take; (1, 1) is the
-# tiled path.  In the last two the pack the rule would prefer does not
+# masked path.  In the last two the pack the rule would prefer does not
 # fit: all six heads under a smaller budget, all six rows under the real.
 PACKED_CASES = [
     # (B, T, H, Hkv, D), causal, dtype, VMEM budget, pack
@@ -99,8 +99,9 @@ def test_packed_kernel_interpret(shape, causal, dtype, budget, pack,
 @pytest.mark.parametrize("shape,causal,dtype,budget,block", [
     ((1, 256, 4, 2, 64), True, jnp.float32, None, None),
     ((1, 256, 4, 2, 64), False, jnp.float32, None, None),
-    # several blocks a sequence: the tiled kernels' loops over blocks
+    # several blocks a sequence: the masked kernels under ``causal=``
     ((1, 256, 4, 2, 64), True, jnp.float32, None, 128),
+    ((1, 256, 4, 2, 64), True, jnp.bfloat16, None, 128),
     ((2, 256, 2, 2, 128), False, jnp.float32, None, 128),
 ] + [case[:4] + (None,) for case in PACKED_CASES])
 def test_pallas_kernel_grads_interpret(shape, causal, dtype, budget, block,
@@ -108,7 +109,7 @@ def test_pallas_kernel_grads_interpret(shape, causal, dtype, budget, block,
     monkeypatch.setattr(fa, "_INTERPRET", True)
     _budget(monkeypatch, budget)
     if block is not None:
-        monkeypatch.setenv("HOROVOD_FLASH_BLOCK", str(block))
+        monkeypatch.setattr(fa, "_BLOCK", block)
     q, k, v = make_qkv(*shape, dtype, seed=3)
 
     def loss_f(q, k, v):
@@ -184,7 +185,7 @@ def test_flash_attention_lse_interpret(causal, monkeypatch):
 
 
 @pytest.mark.parametrize("shape", [
-    (1, 128, 2, 1, 64),      # tiled: a GQA group at D=64
+    (1, 128, 2, 1, 64),      # masked: a GQA group at D=64
     (2, 128, 4, 4, 64),      # packed, two heads a lane tile
     (1, 128, 4, 2, 128),     # packed, GQA
 ])
@@ -269,14 +270,14 @@ def test_ring_attention_kernel_path_grads_interpret(monkeypatch, hvd):
                                    atol=3e-4, rtol=1e-3)
 
 
-def test_flash_block_env_override(monkeypatch):
-    """HOROVOD_FLASH_BLOCK tunes the kernel grid (tools/flash_sweep.py
-    feeds the measured best back through it); values the sequence
-    length cannot honor make supported() fall back to XLA attention."""
+def test_block_size_is_a_module_constant(monkeypatch):
+    """``_BLOCK`` sets the kernel grid; a value the sequence length cannot
+    honor makes supported() fall back to XLA attention."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     q, k, v = make_qkv(1, 256, 2, 2, 64)
+    assert fa._block_sizes(1024, 1024) == (512, 512)
 
-    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    monkeypatch.setattr(fa, "_BLOCK", 128)
     assert fa._block_sizes(256, 256) == (128, 128)
     assert fa.supported(q, k, v, True)
     out = fa.flash_attention(q, k, v, causal=True)
@@ -285,11 +286,8 @@ def test_flash_block_env_override(monkeypatch):
                                np.asarray(ref), atol=2e-3, rtol=2e-3)
 
     # 192 does not divide T=256 -> kernel unsupported, caller falls back
-    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "192")
+    monkeypatch.setattr(fa, "_BLOCK", 192)
     assert not fa.supported(q, k, v, True)
-
-    monkeypatch.delenv("HOROVOD_FLASH_BLOCK")
-    assert fa._block_sizes(1024, 1024) == (512, 512)
 
 
 def test_refusal_on_a_tpu_backend_is_logged_once_per_shape(
@@ -299,26 +297,18 @@ def test_refusal_on_a_tpu_backend_is_logged_once_per_shape(
     other backend the XLA path is the expected one and nothing is said."""
     import logging
 
-    from horovod_tpu.ops import fused_xent
-
     q = jax.ShapeDtypeStruct((1, 128, 4, 48), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 128, 2, 48), jnp.bfloat16)
-    h = jax.ShapeDtypeStruct((2, 64, 96), jnp.bfloat16)
-    w = jax.ShapeDtypeStruct((512, 96), jnp.float32)
-    y = jax.ShapeDtypeStruct((2, 64), jnp.int32)
     fa._warn_refused.cache_clear()
     with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
         assert not fa.supported(q, kv, kv)
-        assert not fused_xent.supported(h, w, y)
         assert not caplog.records
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         for _ in range(2):
             assert not fa.supported(q, kv, kv)
-            assert not fused_xent.supported(h, w, y)
     said = [r.getMessage() for r in caplog.records]
-    assert len(said) == 2 and all("falling back" in m for m in said)
+    assert len(said) == 1 and "falling back" in said[0]
     assert "flash_attention" in said[0] and "head_dim 48" in said[0]
-    assert "fused_xent" in said[1] and "d_model 96" in said[1]
 
 
 # --- the pack rule, and which path a traced call took ------------------------
@@ -360,7 +350,7 @@ def test_pack_rule():
     # BERT-base as benchmarked: every head of a row in one grid step
     bb, hb = fa._pack(32, 12, 12, 128, 128, 64, 2)
     assert hb == 12 and (32 // bb) * (12 // hb) < 64
-    # a long sequence is several blocks: the tiled path
+    # a long sequence is several blocks: the masked path
     assert fa._block_sizes(2048, 2048) == (512, 512)
     assert fa._pack(2, 16, 16, 2048, 2048, 128, 2) == (1, 1)
     assert fa._pack(2, 16, 4, 2048, 512, 128, 2) == (1, 1)
@@ -387,24 +377,29 @@ def test_pack_rule():
                             bb, hb, g, T, T, D, itemsize) <= fa._VMEM_BUDGET
 
 
-def test_tiled_path_is_the_parents_spec_for_spec(monkeypatch):
-    """Several blocks a sequence: the three pallas_calls of the parent
-    commit, grid and block shapes pinned here as that commit built them."""
+def test_causal_over_several_blocks_builds_the_masked_kernels(monkeypatch):
+    """``causal=True`` without ``mask=``: the masked family's three calls,
+    grids and blocks as ``test_masked_forward_specs`` pins them (both of a
+    group's two heads a forward step; ``dkv`` one step a live pair of
+    tiles, holding one query tile of the group), on the tile classes of
+    :func:`causal_ranges`."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv, D = 1, 2048, 4, 2, 128
     bq = bk = 512
-    nq = nkv = T // bq
+    nq = T // bq
     g = H // Hkv
+    classes = fa.tile_classes(fa.causal_ranges(T)[None], bq, bk, T)[0]
+    assert [int((classes == c).sum()) for c in (1, 2, 0)] == [4, 6, 6]
     q, k, v = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16)
                for h in (H, Hkv, Hkv))
     calls = _pallas_calls(lambda q, k, v: _grad_all(q, k, v, True), q, k, v)
-    qb, kvb, row = (1, 1, bq, D), (1, 1, T, D), (1, 1, nq, bq)
+    qb, kvb, row, rng = (1, 1, bq, D), (1, 1, T, D), (1, 1, nq, bq), (1, bq, 4)
+    grp, tile, rows = (1, g, bq, D), (1, 1, bk, D), (1, g, nq, bq)
     assert calls == [
-        ("hvd_flash_fwd", (B, H, nq), [qb, kvb, kvb, qb, row]),
-        ("hvd_flash_dq", (B, H, nq), [qb, kvb, kvb, qb, row, row, qb]),
-        ("hvd_flash_dkv", (B, Hkv, nkv),
-         [(1, g, T, D), (1, 1, bk, D), (1, 1, bk, D), (1, g, T, D),
-          (1, g, nq, bq), (1, g, nq, bq), (1, 1, bk, D), (1, 1, bk, D)]),
+        ("hvd_flash_fwd", (B, H // g, nq), [grp, kvb, kvb, rng, grp, rows]),
+        ("hvd_flash_dq", (B, H, nq), [qb, kvb, kvb, qb, row, row, rng, qb]),
+        ("hvd_flash_dkv", (B, Hkv, 10),
+         [grp, tile, tile, grp, rows, rows, rng, tile, tile]),
     ]
 
 
@@ -437,7 +432,7 @@ def test_packed_path_specs_and_counter(monkeypatch):
     jax.make_jaxpr(lambda q, k, v: _grad_all(q, k, v, True))(long, long, long)
     later = _kernel_counts()
     grew = {key for key in later if later[key] != after.get(key, 0)}
-    assert grew == {("fwd", "tiled"), ("dq", "tiled"), ("dkv", "tiled")}
+    assert grew == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
 
 
 def test_packed_kernels_lower_for_the_chip(monkeypatch):
@@ -530,7 +525,7 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
     per batch row, a forward step taking a whole GQA group, a part of
     one, or one head."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    monkeypatch.setattr(fa, "_BLOCK", 128)
     (H, Hkv, D), budget, hb = HEAD_CASES[heads]
     if budget is not None:
         monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
@@ -612,14 +607,21 @@ def test_masked_forward_specs(monkeypatch):
         qb, kvb, kvb, qb, row, row, (1, bq, 4), qb])
 
 
-def test_causal_ranges_agree_with_the_causal_kernels(monkeypatch):
-    """The mask as data says what ``causal=True`` says."""
+def test_causal_over_several_blocks_agrees_with_dense(monkeypatch):
+    """``causal=True`` is :func:`causal_ranges`: values and the three
+    gradients against the dense reference, over a GQA group."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    monkeypatch.setattr(fa, "_BLOCK", 128)
     q, k, v = make_qkv(1, 256, 4, 2, 64)
-    np.testing.assert_allclose(
-        fa.flash_attention(q, k, v, mask=fa.causal_ranges(256)),
-        fa.flash_attention(q, k, v, causal=True), atol=2e-6, rtol=2e-6)
+    np.testing.assert_allclose(fa.flash_attention(q, k, v, causal=True),
+                               dense_reference(q, k, v, True),
+                               atol=2e-5, rtol=2e-5)
+    got = _grad_all(q, k, v, True)
+    want = jax.grad(lambda q, k, v: (dense_reference(q, k, v, True)
+                                     ** 2).sum(), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3,
+                                   err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("mask", sorted(MASKS))
@@ -654,7 +656,7 @@ def test_tile_classes_against_a_brute_force_count(mask, bq, bk):
 
 def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setenv("HOROVOD_FLASH_BLOCK", "128")
+    monkeypatch.setattr(fa, "_BLOCK", 128)
     from horovod_tpu import metrics
 
     def tiles():
@@ -680,14 +682,16 @@ def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
         == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
 
 
-@pytest.mark.parametrize("B,H,Hkv", [(1, 8, 1), (2, 32, 4)],
-                         ids=["one-group", "sdar-cell"])
-def test_masked_kernels_lower_for_the_chip(B, H, Hkv, monkeypatch):
-    """Mosaic takes the three masked kernels at 8 query heads a kv head,
-    8,192 positions and head_dim 128, the shape the causal ``dkv`` kernel
-    is refused for, for one group and at the benchmark's SDAR cell (32
-    query heads over 4, batch 2): compiled here for a v5e that is
-    described, not attached."""
+@pytest.mark.parametrize("B,H,Hkv,given", [
+    (1, 8, 1, True), (2, 32, 4, True), (1, 32, 8, False)],
+    ids=["one-group", "sdar-cell", "llama3-8b-causal"])
+def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
+    """Mosaic takes the three masked kernels at 8,192 positions and
+    head_dim 128: 8 query heads a kv head under a block-diffusion mask,
+    for one group and at the benchmark's SDAR cell (32 query heads over 4,
+    batch 2), and Llama-3-8B's heads (32 over 8) through ``causal=True``
+    alone, where a ``dkv`` holding ``g x T x D`` was refused: compiled
+    here for a v5e that is described, not attached."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     monkeypatch.setenv("TPU_LOG_DIR", "disabled")
@@ -700,12 +704,11 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, monkeypatch):
     one_chip = SingleDeviceSharding(topo.devices[0])
     q, k = (jax.ShapeDtypeStruct((B, 8192, h, 128), jnp.bfloat16,
                                  sharding=one_chip) for h in (H, Hkv))
-    assert not fa.supported(q, k, k, True)
-    ranges = _block_diffusion_ranges(4096, 4)
-    assert fa.supported(q, k, k, False, ranges)
+    ranges = _block_diffusion_ranges(4096, 4) if given else None
+    assert fa.supported(q, k, k, True, ranges)
     text = jax.jit(lambda q, k, v: jax.grad(
-        lambda q, k, v: fa.flash_attention(q, k, v, mask=ranges).astype(
-            jnp.float32).sum(), (0, 1, 2))(q, k, v)).lower(
-                q, k, k).compile().as_text()
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, mask=ranges).astype(jnp.float32).sum(),
+        (0, 1, 2))(q, k, v)).lower(q, k, k).compile().as_text()
     for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert name in text

@@ -227,7 +227,7 @@ def test_fsdp_matches_baseline(baseline_sgd, hvd):
 
 
 def test_fsdp_rejects_model_parallel_meshes(hvd):
-    with pytest.raises(ValueError, match="dp only"):
+    with pytest.raises(ValueError, match="does not compose with tp>1"):
         training.make_llama_fsdp_step(CFG, ParallelMesh(MeshConfig(2, 1, 1, 2)))
 
 

@@ -329,7 +329,7 @@ def test_scopes_change_nothing_but_metadata(build, dp, monkeypatch):
 @pytest.mark.parametrize("seq_len,names", [
     # one block a sequence: the packed path and its single backward kernel
     (128, ("hvd_flash_fwd", "hvd_flash_bwd")),
-    # several: the tiled path
+    # several: the masked path
     (1024, ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv")),
 ])
 def test_flash_kernels_carry_their_names(seq_len, names, monkeypatch):

@@ -3,7 +3,7 @@
 Usage (on the chip):
 
     python tools/perf_probe.py [--trace /tmp/hvd_trace] [--steps 10]
-        [--flash-block 512] [--no-flash]
+        [--no-flash]
 
 Runs the same ~1B llama training step as bench.py, prints per-step wall
 time and MFU, and (with --trace) captures a Perfetto trace through
@@ -25,8 +25,6 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("--trace", default=None)
-    p.add_argument("--flash-block", type=int, default=None,
-                   help="override flash kernel block size (bq=bk)")
     p.add_argument("--no-flash", action="store_true")
     p.add_argument("--seq", type=int, default=1024)
     p.add_argument("--batch", type=int, default=8)
@@ -53,11 +51,6 @@ def main():
     from bench import detect_peak
 
     use_compile_cache()
-
-    if args.flash_block:
-        # the supported override mechanism (ops/flash_attention.py
-        # _block_sizes reads it; keeps its <=0 and parse guards)
-        os.environ["HOROVOD_FLASH_BLOCK"] = str(args.flash_block)
 
     cfg = llama.LlamaConfig(
         vocab_size=32768, d_model=2048, n_layers=16, n_heads=16,
