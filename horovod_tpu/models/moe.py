@@ -21,12 +21,18 @@ Two dispatch paths, ``cfg.moe_dispatch``:
   computes their part of the result.  The (token, expert) pairs whose
   expert is held are sorted by expert and taken in chunks of a fixed
   number of rows, as many chunks as the routing needs
-  (``lax.while_loop``), each through three grouped matrix products
-  (``lax.ragged_dot``: on a v5e its time follows the rows in the groups,
-  not the buffer, and it reaches about half the dense product's rate;
-  my chip run, PR 27) and a weighted scatter-add back.  No capacity, no
-  dropped pair, and device work in proportion to the pairs routed here.
-  What absent experts would add is left out.
+  (``lax.while_loop``), each through three grouped matrix products and
+  a weighted scatter-add back.  The products are the Pallas kernels of
+  :mod:`horovod_tpu.ops.grouped_matmul` where the backend, the widths and
+  the dtype allow (``hvd_moe_gmm_*`` forward and for the rows' gradients,
+  ``hvd_moe_tgmm_*`` for the weights'; the activation, the pairs' weights
+  and the sum of the two products behind a row's gradient are applied to
+  the fp32 accumulators, and a tile of rows past the pairs costs no
+  product), ``lax.ragged_dot`` elsewhere (CPU, toy widths) with autodiff's
+  backward: one algorithm, no knob; ``hvd_moe_gmm_kernel_total`` says
+  which a program took.  No capacity, no dropped pair, and device work in
+  proportion to the pairs routed here.  What absent experts would add is
+  left out.
 
 Gradient calculus note (see training.py): expert weights are *sharded*
 over ep=dp, and the backward all_to_all already sums each expert's
@@ -44,6 +50,7 @@ import numpy as np
 from jax import lax
 
 from .. import metrics as _metrics
+from ..ops import grouped_matmul as _gmm
 
 SCOPE_ROUTE = "hvd_moe_route"        # router logits, softmax, top-k
 SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, scatter-add
@@ -186,21 +193,96 @@ def _chunk_rows(n_tokens, k, held, n_experts):
     return int(min(most, max(512, -(-int(even * 1.5) // 512) * 512)))
 
 
+def _swiglu(gate, up):
+    """``silu(gate) * up`` in float32 of gates and ups rounded to the
+    rows' dtype, rounded again: the forward's and the backward's alike."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def _gate_up(xs, wg, wu, sizes, keep=False):
+    """``(h,)``, or ``(gate, up)`` for the backward: both products from
+    one read of a tile of rows."""
+    F, dt = wg.shape[2], xs.dtype
+
+    def body(x, a, b):
+        gate, up = _gmm.dot(x, a).astype(dt), _gmm.dot(x, b).astype(dt)
+        return (gate, up) if keep else (_swiglu(gate, up),)
+
+    return _gmm.gmm(body, (xs,), (wg, wu), sizes,
+                    [(F, dt)] * (2 if keep else 1), "gate_up")
+
+
 def _expert_ffn(xs, wg, wu, wd, wt, sizes):
-    """Rows ``xs [R, D]`` sorted by expert, ``sizes [E]`` rows each (rows
-    past their sum come out zero): SwiGLU by each row's expert, weighted
-    ``wt [R]``, float32 out."""
-    gate = lax.ragged_dot(xs, wg, sizes)
-    up = lax.ragged_dot(xs, wu, sizes)
-    h = (jax.nn.silu(gate.astype(jnp.float32))
-         * up.astype(jnp.float32)).astype(xs.dtype)
-    return lax.ragged_dot(h, wd, sizes).astype(jnp.float32) * wt[:, None]
+    """Rows ``xs [R, D]`` sorted by expert, ``sizes [E]`` rows each:
+    SwiGLU by each row's expert, weighted ``wt [R]``, float32 out, zero
+    on the rows past the sizes' sum."""
+    if _gmm.supported(xs, wg, wu, wd):
+        h, = _gate_up(xs, wg, wu, sizes)
+        ys, = _gmm.gmm(lambda h, w, d: (_gmm.dot(h, d) * w,),
+                       (h, wt[:, None]), (wd,), sizes,
+                       [(wd.shape[2], jnp.float32)], "down")
+        return ys
+    _gmm.count_xla(gmm=3)
+    h = _swiglu(lax.ragged_dot(xs, wg, sizes), lax.ragged_dot(xs, wu, sizes))
+    ys = lax.ragged_dot(h, wd, sizes).astype(jnp.float32) * wt[:, None]
+    return jnp.where(_pairs(sizes, ys), ys, 0.0)
+
+
+def _pairs(sizes, like):
+    """``[R, 1]``: which of ``like``'s rows the sizes cover."""
+    return (jnp.arange(like.shape[0]) < sizes.sum())[:, None]
+
+
+def _expert_ffn_grads(xs, wg, wu, wd, wt, sizes, dys, dwg, dwu, dwd):
+    """The backward of :func:`_expert_ffn` for the cotangent ``dys [R,
+    D]`` (float32): ``(dxs, dwt, dwg, dwu, dwd)``, ``dxs`` float32 and it
+    and ``dwt`` zero on the rows past the sizes' sum, the three weights'
+    gradients added to the float32 ``dwg``, ``dwu``, ``dwd`` given.  With
+    the kernels gate and up are made again and the down product is not:
+    a pair's weight meets ``dys @ wd.T`` on the accumulator, where
+    ``sum(h * that)`` is the weight's own gradient."""
+    if not _gmm.supported(xs, wg, wu, wd):
+        _gmm.count_xla(gmm=3, tgmm=3)          # autodiff's six
+        _, vjp = jax.vjp(
+            lambda xs, a, b, d, w: _expert_ffn(xs, a, b, d, w, sizes),
+            xs, wg, wu, wd, wt)
+        dxs, da, db, dd, dwt = vjp(dys)
+        pairs = _pairs(sizes, dxs)
+        return (jnp.where(pairs, dxs.astype(jnp.float32), 0.0),
+                jnp.where(pairs[:, 0], dwt, 0.0), dwg + da.astype(jnp.float32),
+                dwu + db.astype(jnp.float32), dwd + dd.astype(jnp.float32))
+    dt = xs.dtype
+    D, F = xs.shape[1], wg.shape[2]
+    gate, up = _gate_up(xs, wg, wu, sizes, keep=True)
+    dys = dys.astype(dt)
+
+    def dh_body(dy, gate, up, w, d):
+        acc = _gmm.dot(dy, d, transposed=True)          # dys @ wd.T
+        g, u = gate.astype(jnp.float32), up.astype(jnp.float32)
+        sig = jax.nn.sigmoid(g)
+        act = g * sig
+        h = (act * u).astype(dt).astype(jnp.float32)    # _swiglu's
+        dh = acc * w
+        return (dh * u * sig * (1.0 + g - act), dh * act, h * w,
+                (acc * h).sum(axis=1, keepdims=True))
+
+    dgate, dup, hw, dwt = _gmm.gmm(
+        dh_body, (dys, gate, up, wt[:, None]), (wd,), sizes,
+        [(F, dt), (F, dt), (F, dt), (1, jnp.float32)], "dh")
+    dxs, = _gmm.gmm(
+        lambda dg, du, a, b: (_gmm.dot(dg, a, transposed=True)
+                              + _gmm.dot(du, b, transposed=True),),
+        (dgate, dup), (wg, wu), sizes, [(D, jnp.float32)], "dx")
+    return (dxs, dwt[:, 0], _gmm.tgmm(xs, dgate, sizes, dwg, "gate"),
+            _gmm.tgmm(xs, dup, sizes, dwu, "up"),
+            _gmm.tgmm(hw, dys, sizes, dwd, "down"))
 
 
 def _chunks(order, pair_w, sizes, k, rows):
     """``(n, chunk)``: how many chunks of ``rows`` sorted pairs the
     held pairs fill, and ``chunk(c)`` = (token of each row, its weight,
-    which rows are pairs, rows per expert, first sorted position)."""
+    rows per expert, first sorted position)."""
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     n = (ends[-1] + rows - 1) // rows
@@ -210,8 +292,7 @@ def _chunks(order, pair_w, sizes, k, rows):
         pairs = lax.dynamic_slice(order, (lo,), (rows,))
         here = jnp.clip(jnp.minimum(ends, lo + rows)
                         - jnp.maximum(starts, lo), 0, rows)
-        valid = jnp.arange(rows) < here.sum()
-        return pairs // k, pair_w[pairs], valid, here.astype(jnp.int32), lo
+        return pairs // k, pair_w[pairs], here.astype(jnp.int32), lo
 
     return n, chunk
 
@@ -227,10 +308,9 @@ def _held_experts(tokens, wg, wu, wd, pair_w, order, sizes, k, rows):
 
     def body(c, carry):
         out, computed = carry
-        tok, wt, valid, here, _ = chunk(c)
+        tok, wt, here, _ = chunk(c)
         ys = _expert_ffn(tokens[tok], wg, wu, wd, wt, here)
-        return (out.at[tok].add(jnp.where(valid[:, None], ys, 0.0)),
-                computed + here.sum())
+        return out.at[tok].add(ys), computed + here.sum()
 
     return lax.fori_loop(0, n, body, ((tokens * 0).astype(jnp.float32),
                                       sizes[0] * 0))
@@ -252,19 +332,12 @@ def _held_experts_bwd(k, rows, res, cotangents):
 
     def body(c, carry):
         dtok, dwg, dwu, dwd, dwt_sorted = carry
-        tok, wt, valid, here, lo = chunk(c)
-        _, vjp = jax.vjp(
-            lambda xs, a, b, d, w: _expert_ffn(xs, a, b, d, w, here),
-            tokens[tok], wg, wu, wd, wt)
-        dxs, da, db, dd, dwt = vjp(
-            jnp.where(valid[:, None], dout[tok].astype(jnp.float32), 0.0))
-        dtok = dtok.at[tok].add(
-            jnp.where(valid[:, None], dxs.astype(jnp.float32), 0.0))
-        dwt_sorted = lax.dynamic_update_slice(
-            dwt_sorted, jnp.where(valid, dwt, 0.0), (lo,))
-        return (dtok, dwg + da.astype(jnp.float32),
-                dwu + db.astype(jnp.float32), dwd + dd.astype(jnp.float32),
-                dwt_sorted)
+        tok, wt, here, lo = chunk(c)
+        dxs, dwt, dwg, dwu, dwd = _expert_ffn_grads(
+            tokens[tok], wg, wu, wd, wt, here,
+            dout[tok].astype(jnp.float32), dwg, dwu, dwd)
+        return (dtok.at[tok].add(dxs), dwg, dwu, dwd,
+                lax.dynamic_update_slice(dwt_sorted, dwt, (lo,)))
 
     dtok, dwg, dwu, dwd, dwt_sorted = lax.fori_loop(
         0, n, body, (f32(tokens), f32(wg), f32(wu), f32(wd),

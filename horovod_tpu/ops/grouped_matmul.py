@@ -1,0 +1,312 @@
+"""Grouped matrix products as Pallas kernels for TPU.
+
+What a routed expert layer that drops no token spends its MXU time on
+(:mod:`horovod_tpu.models.moe`, the dropless path): rows ``[R, K]``
+sorted by group, ``sizes [G]`` rows each, and one weight matrix a group.
+XLA's own (``lax.ragged_dot``) held 36.5% of the products' compute bound
+in the benchmark's SDAR cell (ledger, PR 29); these hold their operands
+in VMEM a tile of rows at a time and leave the epilogues to the caller.
+
+Two kernels, one walk.  :func:`_plan` turns ``sizes`` into a table of
+*visits* on the device, read from SMEM (scalar prefetch): the (row tile,
+group) pairs that hold a row, in order, so a tile that straddles groups
+is visited once for each with the other groups' rows masked, a group's
+weights are fetched once (consecutive visits of one group name the same
+block), and tiles past ``sizes.sum()`` cost no product: the visits left
+over write zeros to them, one store a tile, or do nothing.  The grid is
+``tiles + groups - 1`` visits, the most any ``sizes`` can need.
+
+* :func:`gmm` (``hvd_moe_gmm_<name>``) — for every visit ``body`` gets
+  the row tiles and the group's weight matrices and returns the output
+  tiles; rows of other groups keep what their own visit writes, rows of
+  no group come out zero.  The products and whatever follows them on
+  the fp32 accumulators (an activation, a row's weight, a second product
+  into the same accumulator) are the caller's: :func:`dot` is the
+  product, bf16 or float32 operands and fp32 accumulation, with the
+  weights read as stored or transposed.
+* :func:`tgmm` (``hvd_moe_tgmm_<name>``) — rows-transposed x rows, a
+  group at a time, added to a float32 ``[G, K, N]`` accumulator that is
+  the call's input and, aliased, its output: a group's block stays in
+  VMEM over its visits and is written once; a group without rows keeps
+  what it held.
+
+``hvd_moe_gmm_kernel_total{kernel, path}`` counts the calls built, once
+per traced call site: ``path=pallas`` here, ``path=xla`` where the
+caller fell back to ``lax.ragged_dot`` (:func:`count_xla`).
+
+Falls back cleanly: :func:`supported` gates on backend, shapes and dtype
+(no knob); on a TPU backend each refused shape is logged once.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import metrics as _metrics
+from .flash_attention import _sds, _verdict
+
+_INTERPRET = False  # flipped by tests to run kernels on CPU
+# Rows a tile, by the kernels alone at the SDAR cell's shapes (my chip
+# runs, PR 30): gmm is fastest at 256 (a straddling tile costs a whole
+# visit: 99 visits of 84 tiles, where 512 rows make 57 of 42; 128 rows are
+# no faster), tgmm at 512 (it adds a float32 [K, N] block in VMEM a visit:
+# 6.4 us a visit at 256 rows against 4.1 of MXU time, 10.1 against 8.2 at
+# 512; at 1,024, or with two accumulators a call, a visit takes three
+# times as long)
+_TILE = 256
+_TGMM_TILE = 512
+_LANES = 128
+# what one grid step may hold: half of a v5e core's 128 MiB of VMEM (a
+# group's float32 [2048, 768] accumulator, in and out and double-buffered,
+# is 25 MiB)
+_STEP_VMEM = 64 * 1024 * 1024
+
+_m_kernels = _metrics.counter(
+    "hvd_moe_gmm_kernel_total",
+    "Grouped matrix product calls built, one per traced call site; kernel "
+    "is gmm (rows x a group's weights) or tgmm (rows-transposed x rows a "
+    "group), path is pallas (ops/grouped_matmul.py: a gmm call may hold "
+    "two products) or xla (lax.ragged_dot)",
+    labels=("kernel", "path"))
+
+
+def _count(kernel: str, path: str, n: int = 1) -> None:
+    if _metrics.ACTIVE:
+        _m_kernels.inc(n, kernel=kernel, path=path)
+
+
+def count_xla(gmm: int = 0, tgmm: int = 0) -> None:
+    """The caller built that many products as ``lax.ragged_dot`` (or had
+    autodiff build them)."""
+    for kernel, n in (("gmm", gmm), ("tgmm", tgmm)):
+        if n:
+            _count(kernel, "xla", n)
+
+
+def _refusal(rows, *weights) -> Optional[str]:
+    """Which test keeps the Pallas kernels off these products; None =
+    they run.  ``rows [R, K]``; ``weights`` every ``[G, K, N]`` the
+    layer's products read, as stored."""
+    if not _INTERPRET and jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}, not tpu"
+    if rows.ndim != 2 or any(w.ndim != 3 for w in weights):
+        return "rows must be rank 2 and weights rank 3"
+    if rows.shape[0] % max(_TILE, _TGMM_TILE):
+        return (f"{rows.shape[0]} rows are no multiple of "
+                f"{max(_TILE, _TGMM_TILE)}")
+    if rows.dtype not in (jnp.bfloat16, jnp.float32):
+        return f"dtype {rows.dtype} is neither bfloat16 nor float32"
+    for w in weights:
+        if w.dtype != rows.dtype or w.shape[0] != weights[0].shape[0]:
+            return "weights must have the rows' dtype and one group count"
+        if w.shape[1] % _LANES or w.shape[2] % _LANES:
+            return (f"weights {w.shape[1:]} are no multiples of {_LANES} "
+                    "both ways")
+    # the dearest calls: tgmm's float32 accumulator in and out and its two
+    # row tiles; gmm's two weight matrices, a row tile in and a float32 one
+    # out; every buffer twice
+    k = max(w.shape[1] * w.shape[2] for w in weights)
+    wide, item = max(max(w.shape[1:]) for w in weights), rows.dtype.itemsize
+    need = 2 * max(2 * k * 4 + 2 * _TGMM_TILE * wide * item,
+                   2 * k * item + _TILE * wide * (item + 4))
+    if need > _STEP_VMEM:
+        return (f"a grid step needs {need} bytes of VMEM, over the "
+                f"{_STEP_VMEM} a step may hold")
+    return None
+
+
+def supported(rows, *weights) -> bool:
+    """True when the Pallas kernels can run the grouped products of
+    ``rows [R, K]`` with these ``[G, K, N]`` weights on this backend."""
+    return _verdict("grouped_matmul", _refusal(rows, *weights), rows,
+                    *weights)
+
+
+def dot(x, w, transposed: bool = False):
+    """``x [m, k] @ w [k, n]`` (``w [n, k]`` read transposed) on the MXU,
+    fp32 accumulation: the product a :func:`gmm` body makes."""
+    return lax.dot_general(
+        x, w, (((1,), (1 if transposed else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _plan(sizes, rows: int, tm: int):
+    """``(table [4 V], bounds [2 G], V)`` int32, made on the device: for
+    visit ``v`` its group, the row tile it writes, the row tile it reads
+    and flags at ``table[4 v : 4 v + 4]``; group ``g`` holds rows
+    ``bounds[2 g] <= r < bounds[2 g + 1]``.  Flags: ``% 4`` is 1 for a
+    (tile, group) pair that holds a row, 2 for a tile past the rows that
+    this visit zeroes, 0 for nothing to do; ``+ 4`` on a written tile's
+    first visit, ``+ 8`` on a group's first.  Visits past the pairs keep
+    naming the last pair's group and read tile, so they fetch nothing."""
+    G, tiles = sizes.shape[0], rows // tm
+    V = tiles + G - 1
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    n = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    vend = jnp.cumsum(n)
+    active = vend[-1]
+    v = jnp.arange(V, dtype=jnp.int32)
+    va = jnp.minimum(v, jnp.maximum(active - 1, 0))
+    g = jnp.minimum(jnp.searchsorted(vend, va, side="right"),
+                    G - 1).astype(jnp.int32)
+    read = first[g] + va - (vend - n)[g]
+    dead = (ends[-1] + tm - 1) // tm + v - active
+    written = jnp.where(v < active, read, jnp.minimum(dead, tiles - 1))
+    kind = jnp.where(v < active, 1, jnp.where(dead < tiles, 2, 0))
+    before = lambda a: jnp.concatenate([jnp.full((1,), -1, jnp.int32), a[:-1]])
+    flags = kind + 4 * (written != before(written)) + 8 * (g != before(g))
+    table = jnp.stack([g, written, read, flags], axis=1).reshape(-1)
+    bounds = jnp.stack([starts, ends], axis=1).reshape(-1)
+    return table.astype(jnp.int32), bounds.astype(jnp.int32), V
+
+
+def _visit(tbl, bounds, tm):
+    """This grid step's ``(flags, whole, mask)``: ``whole`` when the read
+    tile lies inside the group, ``mask()`` ``[tm, 1]`` the tile's rows
+    that are the group's."""
+    v = pl.program_id(0)
+    g, flags = tbl[4 * v], tbl[4 * v + 3]
+    r0 = tbl[4 * v + 2] * tm
+    lo, hi = bounds[2 * g], bounds[2 * g + 1]
+
+    def mask():
+        row = r0 + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        return (row >= lo) & (row < hi)
+
+    return flags, (lo <= r0) & (hi >= r0 + tm), mask
+
+
+def _gmm_kernel(tbl, bounds, *refs, body, n_rows, n_weights, tm):
+    ins, outs = refs[:n_rows + n_weights], refs[n_rows + n_weights:]
+    flags, whole, mask = _visit(tbl, bounds, tm)
+    pair = flags % 4 == 1
+
+    def tiles():
+        return body(*(r[...] for r in ins[:n_rows]),
+                    *(r[0] for r in ins[n_rows:]))
+
+    @pl.when(pair & whole)
+    def _():
+        for o, y in zip(outs, tiles()):
+            o[...] = y.astype(o.dtype)
+
+    @pl.when(pair & jnp.logical_not(whole))
+    def _():
+        @pl.when(flags % 8 >= 4)       # the tile's first visit
+        def _():
+            for o in outs:
+                o[...] = jnp.zeros_like(o)
+
+        m = mask()
+        for o, y in zip(outs, tiles()):
+            o[...] = jnp.where(m, y.astype(o.dtype), o[...])
+
+    @pl.when(flags % 4 == 2)
+    def _():
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
+
+
+def _limit(blocks: int, temporaries: int):
+    """The call's VMEM limit: its blocks double-buffered, the fp32 tiles
+    in flight, and room."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=int(2 * blocks + temporaries + 16 * 1024 * 1024))
+
+
+def gmm(body, rows, weights, sizes, outs, name: str):
+    """Grouped products with the caller's epilogue.  ``rows``: arrays
+    ``[R, *]`` sorted by group, cut into tiles of ``_TILE`` rows (which
+    divides ``R``: :func:`supported`); ``weights``: arrays ``[G, *, *]``;
+    ``sizes [G]`` int32 rows a group; ``outs``: ``(width, dtype)`` of each
+    output ``[R, width]``.  For every (tile, group) pair that holds a
+    row, ``body(*row_tiles, *weight_matrices)`` returns the output tiles,
+    of which the group's rows are kept; rows past ``sizes.sum()`` come
+    out zero and their tiles are not computed."""
+    R, tm = rows[0].shape[0], _TILE
+    table, bounds, V = _plan(sizes, R, tm)
+    _count("gmm", "pallas")
+    row = lambda width, at: pl.BlockSpec(
+        (tm, width), lambda v, t, b: (t[4 * v + at], 0))
+    held = lambda w: pl.BlockSpec(
+        (1,) + w.shape[1:], lambda v, t, b: (t[4 * v], 0, 0))
+    blocks = (sum(tm * r.shape[1] * r.dtype.itemsize for r in rows)
+              + sum(w[0].size * w.dtype.itemsize for w in weights)
+              + sum(tm * width * jnp.dtype(d).itemsize for width, d in outs))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, body=body, n_rows=len(rows),
+                          n_weights=len(weights), tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(V,),
+            in_specs=[row(r.shape[1], 2) for r in rows]
+            + [held(w) for w in weights],
+            out_specs=[row(width, 1) for width, _ in outs]),
+        out_shape=[_sds((R, width), d, sizes, *rows, *weights)
+                   for width, d in outs],
+        compiler_params=_limit(
+            blocks, 3 * 4 * tm * max(max(width for width, _ in outs),
+                                     max(r.shape[1] for r in rows))),
+        interpret=_INTERPRET,
+        name="hvd_moe_gmm_" + name,
+    )(table, bounds, *rows, *weights)
+
+
+def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm):
+    flags, whole, mask = _visit(tbl, bounds, tm)
+    pair = flags % 4 == 1
+    opens = flags >= 8                 # the group's first visit
+
+    def add(first, masked):
+        # other groups' rows, and whatever lies past the last, reach no
+        # product from either side
+        keep = (lambda a: jnp.where(mask(), a, jnp.zeros_like(a))) \
+            if masked else (lambda a: a)
+        acc[0] = (held if first else acc)[0] + lax.dot_general(
+            keep(x_ref[...]), keep(y_ref[...]), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    for first in (True, False):
+        for masked in (True, False):
+            pl.when(pair & (opens == first) & (whole != masked))(
+                functools.partial(add, first, masked))
+
+    # no pair at all: the one block the pipeline still writes back keeps
+    # what it held
+    @pl.when((pl.program_id(0) == 0) & jnp.logical_not(pair))
+    def _():
+        acc[...] = held[...]
+
+
+def tgmm(x, y, sizes, acc, name: str):
+    """``acc[g] + x[rows of g].T @ y[rows of g]`` for every group ``g``:
+    ``x [R, K]`` and ``y [R, N]`` sorted by group, ``acc [G, K, N]``
+    float32, given up to the call (the output takes its place)."""
+    R, K, N, tm = x.shape[0], x.shape[1], y.shape[1], _TGMM_TILE
+    table, bounds, V = _plan(sizes, R, tm)
+    _count("tgmm", "pallas")
+    row = lambda width: pl.BlockSpec(
+        (tm, width), lambda v, t, b: (t[4 * v + 2], 0))
+    held = pl.BlockSpec((1, K, N), lambda v, t, b: (t[4 * v], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(V,),
+            in_specs=[row(K), row(N), held], out_specs=held),
+        out_shape=_sds(acc.shape, jnp.float32, sizes, x, y, acc),
+        input_output_aliases={4: 0},
+        compiler_params=_limit(tm * (K + N) * x.dtype.itemsize
+                               + 2 * K * N * 4, K * N * 4),
+        interpret=_INTERPRET,
+        name="hvd_moe_tgmm_" + name,
+    )(table, bounds, x, y, acc)

@@ -712,3 +712,52 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
         (0, 1, 2))(q, k, v)).lower(q, k, k).compile().as_text()
     for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert name in text
+
+
+# ------------------------------------- the grouped products' kernels
+# (ops/grouped_matmul.py; their other tests are tests/test_grouped_matmul.py.
+# This one is here because one file describes the chip: a second file can
+# go to another worker, whose process cannot load the TPU's library too.)
+
+@pytest.mark.parametrize("call", ["forward", "backward"])
+def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
+    """Mosaic takes the expert layer's grouped products at the benchmark's
+    SDAR cell: a chunk of 24,576 rows of width 2,048 over 16 held experts
+    of width 768 (gate and up from one read of a tile, 1,536 columns),
+    bf16: ``gmm`` with the weights as stored and transposed, ``tgmm`` with
+    a float32 ``[2048, 768]`` accumulator a group, in and out.  Compiled
+    here for a v5e that is described, not attached."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import moe
+    from horovod_tpu.ops import grouped_matmul as gm
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no TPU topology to compile for: {e}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    R, D, F, E = 24576, 2048, 768, 16
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    xs, wt, sizes = (sds((R, D), jnp.bfloat16), sds((R,), jnp.float32),
+                     sds((E,), jnp.int32))
+    wg, wu, wd = (sds(s, jnp.bfloat16) for s in ((E, D, F), (E, D, F),
+                                                 (E, F, D)))
+    assert gm.supported(xs, wg, wu, wd)
+    if call == "forward":
+        text = jax.jit(moe._expert_ffn).lower(
+            xs, wg, wu, wd, wt, sizes).compile().as_text()
+        names = ("hvd_moe_gmm_gate_up", "hvd_moe_gmm_down")
+    else:
+        held = [sds(w.shape, jnp.float32) for w in (wg, wu, wd)]
+        text = jax.jit(moe._expert_ffn_grads, donate_argnums=(7, 8, 9)).lower(
+            xs, wg, wu, wd, wt, sizes, sds((R, D), jnp.float32),
+            *held).compile().as_text()
+        names = ("hvd_moe_gmm_gate_up", "hvd_moe_gmm_dh", "hvd_moe_gmm_dx",
+                 "hvd_moe_tgmm_gate", "hvd_moe_tgmm_up", "hvd_moe_tgmm_down")
+    for name in names:
+        assert name in text
+    assert "ragged-dot" not in text

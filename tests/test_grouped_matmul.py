@@ -1,0 +1,223 @@
+"""The grouped matrix products' Pallas kernels (ops/grouped_matmul.py)
+in interpret mode on the CPU, against ``lax.ragged_dot`` and a dense
+product a group, for group layouts that break tilings; the expert
+layer's gradients through them against the ``ragged_dot`` path's; which
+path a program's shapes take.  (That Mosaic takes the kernels at the
+benchmark's shapes is tested with the other kernels' compiles, in
+tests/test_flash_attention.py: one file describes the chip.)"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from horovod_tpu import metrics
+from horovod_tpu.models import moe
+from horovod_tpu.ops import grouped_matmul as gm
+
+R, K, N, G = 1536, 256, 128, 5            # three tiles of 512 rows
+LAYOUTS = {
+    "empty-group": [300, 0, 400, 100, 200],
+    "one-row-group": [511, 1, 512, 0, 300],
+    "straddling-tile-edges": [700, 323, 200, 100, 13],
+    "one-group-holds-every-row": [0, 0, 1536, 0, 0],
+    "rows-past-the-sum": [100, 50, 0, 0, 60],
+    "sum-a-multiple-of-the-tile": [512, 256, 256, 0, 0],
+    "sum-one-past-a-tile": [512, 256, 256, 1, 0],
+    "no-row-at-all": [0, 0, 0, 0, 0],
+}
+CASES = [pytest.param(sizes, dtype, id=f"{name}-{jnp.dtype(dtype).name}")
+         for name, sizes in LAYOUTS.items()
+         for dtype in (jnp.float32, jnp.bfloat16)]
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+
+
+def _operands(sizes, dtype, transposed=False):
+    """Rows (NaN past the sizes' sum: nothing may read them into a
+    result), weights, sizes."""
+    kx, kw = jax.random.split(jax.random.key(sum(sizes) + 7))
+    live = (np.arange(R) < sum(sizes))[:, None]
+    x = jnp.where(live, jax.random.normal(kx, (R, K)), jnp.nan).astype(dtype)
+    w = jax.random.normal(kw, (G, N, K) if transposed else (G, K, N))
+    return x, (w * K ** -0.5).astype(dtype), jnp.asarray(sizes, jnp.int32)
+
+
+def _tol(dtype):
+    return dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32 else dict(
+        atol=2e-2, rtol=2e-2)
+
+
+def _check_rows(y, x, w, sizes, dtype):
+    total = int(sizes.sum())
+    ref = lax.ragged_dot(jnp.nan_to_num(x), w, sizes)
+    assert y.dtype == dtype and y.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(y[:total], np.float32),
+                               np.asarray(ref[:total], np.float32),
+                               **_tol(dtype))
+    assert not np.asarray(y[total:], np.float32).any()    # zero, not garbage
+
+
+@pytest.mark.parametrize("sizes,dtype", CASES)
+def test_gmm_is_ragged_dot(sizes, dtype, interpret):
+    x, w, sizes = _operands(sizes, dtype)
+    y, = jax.jit(lambda x, w, s: gm.gmm(
+        lambda a, b: (gm.dot(a, b),), (x,), (w,), s, [(N, dtype)], "t"))(
+            x, w, sizes)
+    _check_rows(y, x, w, sizes, dtype)
+
+
+@pytest.mark.parametrize("sizes,dtype", CASES)
+def test_gmm_reads_weights_transposed(sizes, dtype, interpret):
+    x, w, sizes = _operands(sizes, dtype, transposed=True)
+    y, = jax.jit(lambda x, w, s: gm.gmm(
+        lambda a, b: (gm.dot(a, b, transposed=True),), (x,), (w,), s,
+        [(N, dtype)], "t"))(x, w, sizes)
+    _check_rows(y, x, w.swapaxes(1, 2), sizes, dtype)
+
+
+@pytest.mark.parametrize("sizes,dtype", CASES)
+def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, interpret):
+    x, _, sizes = _operands(sizes, dtype)
+    live = (np.arange(R) < int(sizes.sum()))[:, None]
+    y = jnp.where(live, jax.random.normal(jax.random.key(1), (R, N)),
+                  jnp.nan).astype(dtype)
+    held = jax.random.normal(jax.random.key(3), (G, K, N))
+    out = jax.jit(lambda *a: gm.tgmm(*a, "t"))(x, y, sizes, held)
+    assert out.dtype == jnp.float32
+    ends = np.cumsum(np.asarray(sizes))
+    for g, (lo, hi) in enumerate(zip(ends - np.asarray(sizes), ends)):
+        ref = np.asarray(held[g]) + (np.asarray(x[lo:hi], np.float32).T
+                                     @ np.asarray(y[lo:hi], np.float32))
+        np.testing.assert_allclose(out[g], ref, atol=1e-3, rtol=1e-4)
+        if lo == hi:
+            np.testing.assert_array_equal(out[g], held[g])   # kept to the bit
+
+
+def test_a_body_of_two_products_and_an_epilogue(interpret):
+    """What the expert layer asks of ``gmm``: two weights a group, two
+    outputs, one of them a column, from rows and a column of weights."""
+    x, w, sizes = _operands(LAYOUTS["straddling-tile-edges"], jnp.float32)
+    w2, wt = w[::-1], jnp.arange(R, dtype=jnp.float32)[:, None] / R
+
+    def body(x, wt, a, b):
+        acc = gm.dot(x, a) + gm.dot(x, b)
+        return acc * wt, acc.sum(axis=1, keepdims=True)
+
+    y, col = jax.jit(lambda *a: gm.gmm(
+        body, a[:2], a[2:4], a[4], [(N, jnp.float32), (1, jnp.float32)],
+        "t"))(x, wt, w, w2, sizes)
+    ref = lax.ragged_dot(jnp.nan_to_num(x), w + w2, sizes)
+    np.testing.assert_allclose(y, ref * wt, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(col, ref.sum(axis=1, keepdims=True),
+                               atol=2e-4, rtol=2e-5)
+
+
+def test_the_plan_visits_each_tile_group_pair_once_and_zeroes_the_rest():
+    table, bounds, V = gm._plan(jnp.asarray([700, 0, 300, 24], jnp.int32),
+                                2048, 512)
+    g, written, read, flags = np.asarray(table).reshape(V, 4).T
+    assert V == 4 + 4 - 1
+    # tile 0 whole in group 0; tile 1 shared by groups 0, 2, 3; tile 2 and
+    # 3 hold no row: zeroed; one visit left over
+    assert list(zip(g, written, flags % 4)) == [
+        (0, 0, 1), (0, 1, 1), (2, 1, 1), (3, 1, 1), (3, 2, 2), (3, 3, 2),
+        (3, 3, 0)]
+    assert list(read) == [0, 1, 1, 1, 1, 1, 1]          # nothing fetched past the pairs
+    assert list(flags // 4 % 2) == [1, 1, 0, 0, 1, 1, 0]   # a tile's first visit
+    assert list(flags // 8) == [1, 0, 1, 1, 0, 0, 0]       # a group's first
+    assert list(bounds) == [0, 700, 700, 700, 700, 1000, 1000, 1024]
+
+
+# ------------------------------------------------ the expert layer on them
+
+def _layer_operands(dtype, D, F, E=4, k=2, n_tokens=1024, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 7)
+    tokens = jax.random.normal(ks[0], (n_tokens, D)).astype(dtype)
+    ws = [(jax.random.normal(key, shape) * shape[1] ** -0.5).astype(dtype)
+          for key, shape in zip(ks[1:4], ((E, D, F), (E, D, F), (E, F, D)))]
+    e = jax.random.randint(ks[4], (n_tokens * k,), 0, E + 2)
+    e = jnp.where(e >= E, E, e)                 # a third are not held here
+    pair_w = jax.random.uniform(ks[5], (n_tokens * k,), jnp.float32)
+    rows = 512                                  # three chunks of the pairs
+    order = jnp.pad(jnp.argsort(e, stable=True).astype(jnp.int32), (0, rows))
+    sizes = (e[:, None] == jnp.arange(E)[None]).sum(0).astype(jnp.int32)
+    co = jax.random.normal(ks[6], (n_tokens, D), jnp.float32)
+
+    def loss(tokens, wg, wu, wd, pair_w):
+        y, computed = moe._held_experts(tokens, wg, wu, wd, pair_w, order,
+                                        sizes, k, rows)
+        return (y * co).sum(), computed
+
+    return loss, (tokens, *ws, pair_w), int(sizes.sum())
+
+
+def _kernel_counts():
+    family = metrics.registry().to_dict().get("hvd_moe_gmm_kernel_total", {})
+    return {(s["labels"]["kernel"], s["labels"]["path"]): s["value"]
+            for s in family.get("series", [])}
+
+
+def _grew(before):
+    after = _kernel_counts()
+    return {key: after[key] - before.get(key, 0) for key in after
+            if after[key] != before.get(key, 0)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_held_experts_gradients_through_the_kernels(dtype, monkeypatch):
+    loss, args, pairs = _layer_operands(dtype, D=256, F=128)
+    grad = lambda: jax.jit(jax.value_and_grad(
+        loss, (0, 1, 2, 3, 4), has_aux=True))(*args)
+    before = _kernel_counts()
+    (ref, computed), ref_grads = grad()
+    assert computed == pairs
+    if metrics.ACTIVE:         # forward 3, made again 3, autodiff's 3 + 3
+        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3}
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    before = _kernel_counts()
+    (got, computed), grads = grad()
+    assert computed == pairs
+    if metrics.ACTIVE:         # gate_up, down; gate_up, dh, dx; three tgmm
+        assert _grew(before) == {("gmm", "pallas"): 5, ("tgmm", "pallas"): 3}
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert abs(got - ref) <= tol * abs(ref) + tol
+    for a, b in zip(grads, ref_grads):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+def test_toy_widths_on_the_cpu_take_ragged_dot(interpret):
+    """Widths that are no multiples of 128 run XLA's products even where
+    the kernels could be interpreted: the path is chosen from the shapes."""
+    loss, args, _ = _layer_operands(jnp.float32, D=32, F=16)
+    before = _kernel_counts()
+    jax.make_jaxpr(jax.grad(lambda *a: loss(*a)[0], (0, 1, 2, 3, 4)))(*args)
+    if metrics.ACTIVE:
+        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3}
+
+
+@pytest.mark.parametrize("why,rows,weights,reason", [
+    ("cpu-backend", (1024, 256, jnp.float32), (4, 256, 128), "backend"),
+    ("rows-no-tile-multiple", (768, 256, jnp.float32), (4, 256, 128), "multiple of 512"),
+    ("width-not-128", (1024, 96, jnp.float32), (4, 96, 128), "multiples of 128"),
+    ("half-precision", (1024, 256, jnp.float16), (4, 256, 128), "neither"),
+    ("weights-of-another-dtype", (1024, 256, jnp.bfloat16), (4, 256, 128), "rows' dtype"),
+    ("a-step-past-the-vmem", (1024, 8192, jnp.float32), (4, 8192, 2048), "VMEM"),
+])
+def test_refusals_name_their_reason(why, rows, weights, reason, monkeypatch):
+    monkeypatch.setattr(gm, "_INTERPRET", why != "cpu-backend")
+    x = jax.ShapeDtypeStruct(rows[:2], rows[2])
+    w = jax.ShapeDtypeStruct(
+        weights, jnp.float32 if why == "weights-of-another-dtype" else rows[2])
+    assert reason in gm._refusal(x, w, w) and not gm.supported(x, w, w)
+    ok = jax.ShapeDtypeStruct((1024, 256), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, 256, 128), jnp.bfloat16)
+    assert gm.supported(ok, w, w) == (why != "cpu-backend")
