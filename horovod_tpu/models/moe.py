@@ -22,17 +22,19 @@ Two dispatch paths, ``cfg.moe_dispatch``:
   expert is held are sorted by expert and taken in chunks of a fixed
   number of rows, as many chunks as the routing needs
   (``lax.while_loop``), each through three grouped matrix products and
-  a weighted scatter-add back.  The products are the Pallas kernels of
-  :mod:`horovod_tpu.ops.grouped_matmul` where the backend, the widths and
-  the dtype allow (``hvd_moe_gmm_*`` forward and for the rows' gradients,
-  ``hvd_moe_tgmm_*`` for the weights'; the activation, the pairs' weights
-  and the sum of the two products behind a row's gradient are applied to
-  the fp32 accumulators, and a tile of rows past the pairs costs no
-  product), ``lax.ragged_dot`` elsewhere (CPU, toy widths) with autodiff's
-  backward: one algorithm, no knob; ``hvd_moe_gmm_kernel_total`` says
-  which a program took.  No capacity, no dropped pair, and device work in
-  proportion to the pairs routed here.  What absent experts would add is
-  left out.
+  a combine of the weighted rows into their tokens.  Products and
+  combine are the Pallas kernels of :mod:`horovod_tpu.ops.grouped_matmul`
+  where the backend, the widths and the dtype allow (``hvd_moe_gmm_*``
+  forward and for the rows' gradients, ``hvd_moe_tgmm_*`` for the
+  weights'; the activation, the pairs' weights and the sum of the two
+  products behind a row's gradient are applied to the fp32 accumulators,
+  and a tile of rows past the pairs costs no product;
+  ``hvd_moe_combine_out`` / ``_dtok`` add each float32 row to its token,
+  a tile of tokens in VMEM at a time), ``lax.ragged_dot`` with autodiff's
+  backward and ``.at[].add`` elsewhere (CPU, toy widths): one algorithm,
+  no knob; ``hvd_moe_gmm_kernel_total`` says which a program took.  No
+  capacity, no dropped pair, and device work in proportion to the pairs
+  routed here.  What absent experts would add is left out.
 
 Gradient calculus note (see training.py): expert weights are *sharded*
 over ep=dp, and the backward all_to_all already sums each expert's
@@ -53,7 +55,7 @@ from .. import metrics as _metrics
 from ..ops import grouped_matmul as _gmm
 
 SCOPE_ROUTE = "hvd_moe_route"        # router logits, softmax, top-k
-SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, scatter-add
+SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine
 
 _m_layers = _metrics.counter(
     "hvd_moe_layer_total",
@@ -301,16 +303,19 @@ def _chunks(order, pair_w, sizes, k, rows):
 def _held_experts(tokens, wg, wu, wd, pair_w, order, sizes, k, rows):
     """``([N, D] float32, rows)``: for every token the weighted outputs
     of its held experts, and how many rows the grouped products were
-    given.  ``order``: pair ids sorted by held expert (pairs of experts
-    not held last), padded by ``rows``; ``sizes [E_held]``; ``pair_w
-    [N * k]`` float32."""
+    given.  ``order``: pair ids ``token * k + j`` sorted by held expert
+    and, inside an expert, by pair id (pairs of experts not held last),
+    padded by ``rows``; a token's ``k`` experts are distinct, so its
+    tokens ascend inside an expert's rows, which the combine counts on;
+    ``sizes [E_held]``; ``pair_w [N * k]`` float32."""
     n, chunk = _chunks(order, pair_w, sizes, k, rows)
 
     def body(c, carry):
         out, computed = carry
         tok, wt, here, _ = chunk(c)
         ys = _expert_ffn(tokens[tok], wg, wu, wd, wt, here)
-        return out.at[tok].add(ys), computed + here.sum()
+        return (_gmm.combine(ys, tok, here, out, "out", fresh=c == 0),
+                computed + here.sum())
 
     return lax.fori_loop(0, n, body, ((tokens * 0).astype(jnp.float32),
                                       sizes[0] * 0))
@@ -336,7 +341,8 @@ def _held_experts_bwd(k, rows, res, cotangents):
         dxs, dwt, dwg, dwu, dwd = _expert_ffn_grads(
             tokens[tok], wg, wu, wd, wt, here,
             dout[tok].astype(jnp.float32), dwg, dwu, dwd)
-        return (dtok.at[tok].add(dxs), dwg, dwu, dwd,
+        return (_gmm.combine(dxs, tok, here, dtok, "dtok", fresh=c == 0),
+                dwg, dwu, dwd,
                 lax.dynamic_update_slice(dwt_sorted, dwt, (lo,)))
 
     dtok, dwg, dwu, dwd, dwt_sorted = lax.fori_loop(

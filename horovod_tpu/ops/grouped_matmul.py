@@ -30,12 +30,23 @@ over write zeros to them, one store a tile, or do nothing.  The grid is
   VMEM over its visits and is written once; a group without rows keeps
   what it held.
 
+And what follows the products, :func:`combine`
+(``hvd_moe_combine_<name>``): ``out[tok[r]] += rows[r]``, the rows back
+to their tokens, float32.  XLA's scatter-add took 0.11 us a row whatever
+a row held, 2.62 ms a call at 22% of the bytes' bound (my chip runs, PR
+30).  Inside a group the tokens ascend, so the rows of one group that
+land in one tile of tokens are one run of consecutive rows: the kernel
+walks token tiles, a tile stays in VMEM while its runs come in from HBM,
+and is written once.
+
 ``hvd_moe_gmm_kernel_total{kernel, path}`` counts the calls built, once
 per traced call site: ``path=pallas`` here, ``path=xla`` where the
-caller fell back to ``lax.ragged_dot`` (:func:`count_xla`).
+caller fell back to ``lax.ragged_dot`` (:func:`count_xla`) or the combine
+to ``.at[].add``.
 
-Falls back cleanly: :func:`supported` gates on backend, shapes and dtype
-(no knob); on a TPU backend each refused shape is logged once.
+Falls back cleanly: :func:`supported` and :func:`combine` gate on
+backend, shapes and dtype (no knob); on a TPU backend each refused shape
+is logged once.
 """
 
 from __future__ import annotations
@@ -67,13 +78,21 @@ _LANES = 128
 # group's float32 [2048, 768] accumulator, in and out and double-buffered,
 # is 25 MiB)
 _STEP_VMEM = 64 * 1024 * 1024
+# the combine: tokens a grid step holds, rows a copy brings, copies in flight
+_COMBINE_TILE = 512
+_COMBINE_CHUNK = 32
+_COMBINE_RING = 8
+_COMBINE_UNROLL = 4         # rows whose loads go before their stores
+# scalar memory the combine's token ids may take (one int32 a row)
+_COMBINE_SMEM = 256 * 1024
 
 _m_kernels = _metrics.counter(
     "hvd_moe_gmm_kernel_total",
-    "Grouped matrix product calls built, one per traced call site; kernel "
-    "is gmm (rows x a group's weights) or tgmm (rows-transposed x rows a "
-    "group), path is pallas (ops/grouped_matmul.py: a gmm call may hold "
-    "two products) or xla (lax.ragged_dot)",
+    "Grouped matrix product and combine calls built, one per traced call "
+    "site; kernel is gmm (rows x a group's weights), tgmm "
+    "(rows-transposed x rows a group) or combine (rows added to their "
+    "tokens), path is pallas (ops/grouped_matmul.py: a gmm call may hold "
+    "two products) or xla (lax.ragged_dot; the scatter-add)",
     labels=("kernel", "path"))
 
 
@@ -310,3 +329,166 @@ def tgmm(x, y, sizes, acc, name: str):
         interpret=_INTERPRET,
         name="hvd_moe_tgmm_" + name,
     )(table, bounds, x, y, acc)
+
+
+# ----------------------------------------------------------- the combine
+
+def _combine_refusal(rows, out) -> Optional[str]:
+    """Which test keeps the Pallas combine off ``out[tok] += rows``; None
+    = it runs.  ``rows [R, D]``, ``out [N, D]``."""
+    if not _INTERPRET and jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}, not tpu"
+    if rows.ndim != 2 or out.ndim != 2 or rows.shape[1] != out.shape[1]:
+        return "rows and out must be rank 2 and of one width"
+    if rows.dtype != jnp.float32 or out.dtype != jnp.float32:
+        return f"dtypes {rows.dtype} and {out.dtype} are not both float32"
+    if rows.shape[0] % _COMBINE_CHUNK or out.shape[0] % _COMBINE_TILE:
+        return (f"{rows.shape[0]} rows are no multiple of {_COMBINE_CHUNK} "
+                f"or {out.shape[0]} tokens none of {_COMBINE_TILE}")
+    if rows.shape[1] % _LANES:
+        return f"width {rows.shape[1]} is no multiple of {_LANES}"
+    if rows.shape[0] * 4 > _COMBINE_SMEM:
+        return (f"{rows.shape[0]} token ids are over the {_COMBINE_SMEM} "
+                "bytes of scalar memory they may take")
+    need = (4 * _COMBINE_TILE + _COMBINE_RING * _COMBINE_CHUNK) \
+        * rows.shape[1] * 4
+    if need > _STEP_VMEM:
+        return (f"a grid step needs {need} bytes of VMEM, over the "
+                f"{_STEP_VMEM} a step may hold")
+    return None
+
+
+def _combine_plan(tok, sizes, tokens: int):
+    """``(chunks [3 Q], first [tiles + 1])`` int32, made on the device.
+    Inside a group the tokens ascend, so the rows of one group that land
+    in one tile of ``_COMBINE_TILE`` tokens are one run of consecutive
+    rows; a run is copied in chunks of ``_COMBINE_CHUNK`` rows from the
+    multiple of 8 at or under its first row.  Chunks are listed tile by
+    tile, group by group: for chunk ``q`` the row its copy starts at and
+    the first and one past the last of the copied rows that are the
+    run's, at ``chunks[3 q : 3 q + 3]``; tile ``i`` takes the chunks
+    ``first[i] <= q < first[i + 1]``.  Rows past ``sizes.sum()`` are in no
+    run."""
+    tt, ch = _COMBINE_TILE, _COMBINE_CHUNK
+    rows, G, tiles = tok.shape[0], sizes.shape[0], tokens // tt
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    r = jnp.arange(rows, dtype=jnp.int32)
+    group = (r[:, None] >= ends[None]).sum(1).astype(jnp.int32)
+    # ascending over the rows the sizes cover, past every edge after them
+    key = jnp.where(r < ends[-1], group * tokens + tok, G * tokens)
+    edges = (jnp.arange(tiles + 1, dtype=jnp.int32)[:, None] * tt
+             + jnp.arange(G, dtype=jnp.int32)[None] * tokens).reshape(-1)
+    edge = (key[None] < edges[:, None]).sum(1).astype(jnp.int32).reshape(
+        tiles + 1, G)
+    lo, hi = edge[:-1].reshape(-1), edge[1:].reshape(-1)
+    start = lo // 8 * 8
+    n = jnp.where(hi > lo, (hi - start + ch - 1) // ch, 0)
+    before = jnp.cumsum(n) - n
+    Q = rows // ch + 2 * tiles * G                # the most any sizes need
+    q = jnp.arange(Q, dtype=jnp.int32)
+    # the run a chunk is of: the last that starts at or before it (runs
+    # without a row start where the next one does)
+    run = (q[:, None] >= before[None]).sum(1) - 1
+    start, lo, hi, base = jnp.stack([start, lo, hi, before], axis=1)[run].T
+    s0 = start + (q - base) * ch
+    s = jnp.minimum(s0, rows - ch)
+    chunks = jnp.stack([s, jnp.maximum(lo, s0) - s,
+                        jnp.minimum(hi, s0 + ch) - s], axis=1)
+    first = jnp.concatenate([before[::G], (before[-1] + n[-1])[None]])
+    return chunks.reshape(-1).astype(jnp.int32), first.astype(jnp.int32)
+
+
+def _combine_kernel(tok, chunks, first, fresh, rows, held, out, buf, sem, *,
+                    tt, ch, ring, unroll):
+    i = pl.program_id(0)
+    total = first[pl.num_programs(0)]
+
+    def copy(q):
+        slot = q % ring
+        return pltpu.make_async_copy(
+            rows.at[pl.ds(pl.multiple_of(chunks[3 * q], 8), ch)],
+            buf.at[slot], sem.at[slot])
+
+    def ahead(q):
+        # the ring runs on into the next tile's chunks
+        pl.when(q < total)(lambda: copy(q).start())
+
+    @pl.when(i == 0)
+    def _():
+        for q in range(ring - 1):
+            ahead(q)
+
+    @pl.when(fresh[0] == 0)
+    def _():
+        out[...] = held[...]
+
+    @pl.when(fresh[0] != 0)            # nothing held: its tiles are not read
+    def _():
+        out[...] = jnp.zeros_like(out)
+
+    t0 = i * tt
+
+    def chunk(q, _):
+        ahead(q + ring - 1)
+        copy(q).wait()
+        slot, s = q % ring, chunks[3 * q]
+        lo, hi = chunks[3 * q + 1], chunks[3 * q + 2]
+
+        def add(j, n):
+            # a run's tokens are distinct: n rows' loads before their
+            # stores, so that none waits for another's
+            ts = [tok[s + j + u] - t0 for u in range(n)]
+            sums = [out[pl.ds(t, 1), :] + buf[slot, pl.ds(j + u, 1), :]
+                    for u, t in enumerate(ts)]
+            for t, y in zip(ts, sums):
+                out[pl.ds(t, 1), :] = y
+            return 0
+
+        many = (hi - lo) // unroll
+        lax.fori_loop(0, many, lambda m, _: add(lo + m * unroll, unroll), 0)
+        return lax.fori_loop(lo + many * unroll, hi, lambda j, _: add(j, 1), 0)
+
+    lax.fori_loop(first[i], first[i + 1], chunk, 0)
+
+
+def combine(rows, tok, sizes, out, name: str, fresh=False):
+    """``out`` with ``rows[r]`` added to ``out[tok[r]]`` for the rows the
+    sizes cover (rows past ``sizes.sum()`` add nothing, whatever they
+    hold): ``rows [R, D]`` float32 sorted by group, ``sizes [G]`` rows
+    each, ``tok [R]`` int32 the token of each row, ascending inside a
+    group (a token meets a group once); ``out [N, D]`` float32, given up
+    to the call; ``fresh`` (a bool, traced or not): the caller says
+    ``out`` holds zeros, and the kernel does not read it.  The kernel walks
+    tiles of tokens: a tile stays in VMEM while the runs of rows that
+    land in it are copied in from HBM, a ring of copies ahead, and each
+    row is added to its token, in the rows' order; the tile is written
+    once.  Elsewhere (:func:`_combine_refusal`) XLA's scatter-add."""
+    reason = _combine_refusal(rows, out)
+    if not _verdict("moe_combine", reason, rows, out):
+        _count("combine", "xla")
+        live = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
+        return out.at[tok].add(jnp.where(live, rows, 0.0))
+    _count("combine", "pallas")
+    (R, D), N = rows.shape, out.shape[0]
+    tt, ch, ring = _COMBINE_TILE, _COMBINE_CHUNK, _COMBINE_RING
+    chunks, first = _combine_plan(tok, sizes, N)
+    fresh = jnp.asarray(fresh, jnp.int32).reshape(1)
+    tile = pl.BlockSpec((tt, D), lambda i, *_: (i, 0))
+    # a fresh target's first tile is the only one fetched
+    held = pl.BlockSpec((tt, D), lambda i, t, c, f, fresh: (
+        jnp.where(fresh[0] != 0, 0, i), 0))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, tt=tt, ch=ch, ring=ring,
+                          unroll=_COMBINE_UNROLL),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(N // tt,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), held],
+            out_specs=tile,
+            scratch_shapes=[pltpu.VMEM((ring, ch, D), jnp.float32),
+                            pltpu.SemaphoreType.DMA((ring,))]),
+        out_shape=_sds((N, D), jnp.float32, rows, tok, sizes, out),
+        input_output_aliases={5: 0},
+        compiler_params=_limit(2 * tt * D * 4, ring * ch * D * 4),
+        interpret=_INTERPRET,
+        name="hvd_moe_combine_" + name,
+    )(tok.astype(jnp.int32), chunks, first, fresh, rows, out)

@@ -719,14 +719,17 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
 # This one is here because one file describes the chip: a second file can
 # go to another worker, whose process cannot load the TPU's library too.)
 
-@pytest.mark.parametrize("call", ["forward", "backward"])
+@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
 def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
     """Mosaic takes the expert layer's grouped products at the benchmark's
     SDAR cell: a chunk of 24,576 rows of width 2,048 over 16 held experts
     of width 768 (gate and up from one read of a tile, 1,536 columns),
     bf16: ``gmm`` with the weights as stored and transposed, ``tgmm`` with
-    a float32 ``[2048, 768]`` accumulator a group, in and out.  Compiled
-    here for a v5e that is described, not attached."""
+    a float32 ``[2048, 768]`` accumulator a group, in and out; and the
+    combine of the chunk's float32 rows into 16,384 tokens (24,576 token
+    ids in scalar memory, a ring of copies from HBM), with no scatter
+    left beside it.  Compiled here for a v5e that is described, not
+    attached."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from horovod_tpu.models import moe
@@ -751,6 +754,13 @@ def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
         text = jax.jit(moe._expert_ffn).lower(
             xs, wg, wu, wd, wt, sizes).compile().as_text()
         names = ("hvd_moe_gmm_gate_up", "hvd_moe_gmm_down")
+    elif call == "combine":
+        text = jax.jit(lambda *a: gm.combine(*a, "out"),
+                       donate_argnums=3).lower(
+            sds((R, D), jnp.float32), sds((R,), jnp.int32), sizes,
+            sds((16384, D), jnp.float32)).compile().as_text()
+        names = ("hvd_moe_combine_out",)
+        assert "scatter" not in text
     else:
         held = [sds(w.shape, jnp.float32) for w in (wg, wu, wd)]
         text = jax.jit(moe._expert_ffn_grads, donate_argnums=(7, 8, 9)).lower(

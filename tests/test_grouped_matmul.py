@@ -1,8 +1,8 @@
 """The grouped matrix products' Pallas kernels (ops/grouped_matmul.py)
 in interpret mode on the CPU, against ``lax.ragged_dot`` and a dense
-product a group, for group layouts that break tilings; the expert
-layer's gradients through them against the ``ragged_dot`` path's; which
-path a program's shapes take.  (That Mosaic takes the kernels at the
+product a group, for group layouts that break tilings; the combine
+against ``.at[].add``; the expert layer's gradients through them against
+the ``ragged_dot`` path's; which path a program's shapes take.  (That Mosaic takes the kernels at the
 benchmark's shapes is tested with the other kernels' compiles, in
 tests/test_flash_attention.py: one file describes the chip.)"""
 
@@ -133,6 +133,124 @@ def test_the_plan_visits_each_tile_group_pair_once_and_zeroes_the_rest():
     assert list(bounds) == [0, 700, 700, 700, 700, 1000, 1000, 1024]
 
 
+# ------------------------------------------------------------ the combine
+
+TOKENS, WIDTH = 1024, 256                  # two tiles of 512 tokens
+_ALL = list(range(TOKENS))
+# for each group the tokens of its rows, ascending (a token meets a group
+# once); group 0 first in the sorted rows
+COMBINE_LAYOUTS = {
+    "an-empty-expert": [_ALL[3::7], [], _ALL[::5], _ALL[500:530], [1023]],
+    "every-pair-on-one-expert": [[], [], _ALL, [], []],
+    # token 9 has no held expert, token 600 all five
+    "a-token-without-and-one-with-all-k": [
+        [t for t in _ALL[::3] if t != 9], [1, 600, 900], [600],
+        [2, 3, 511, 512, 600, 1022], [600, 601]],
+    "a-run-straddling-a-tile-edge": [
+        _ALL[480:560], _ALL[505:519], [511], [512], _ALL[0:1024:2]],
+    "no-row-at-all": [[], [], [], [], []],
+    "rows-to-the-last": [_ALL[:700], _ALL[100:500], _ALL[600:], [5], [6, 7]],
+}
+
+
+def _combine_operands(layout, carry):
+    """Rows (garbage past the groups' rows), their tokens (garbage there
+    too), sizes, the carry, and how many rows the sizes cover."""
+    rng = np.random.default_rng(len(str(layout)))
+    tok = np.concatenate([np.sort(np.asarray(g, np.int64)) for g in layout]
+                         + [np.zeros(0, np.int64)])
+    p = len(tok)
+    assert p <= R and all(len(set(g)) == len(g) for g in layout)
+    tok = np.concatenate([tok, rng.integers(0, TOKENS, R - p)]).astype(np.int32)
+    rows = rng.standard_normal((R, WIDTH)).astype(np.float32)
+    rows[p:] = np.where(rng.random((R - p, WIDTH)) < 0.5, np.nan, 1e30)
+    held = (rng.standard_normal((TOKENS, WIDTH)).astype(np.float32)
+            if carry else np.zeros((TOKENS, WIDTH), np.float32))
+    sizes = np.asarray([len(g) for g in layout], np.int32)
+    return rows, tok, sizes, held, p
+
+
+@pytest.mark.parametrize("carry", [False, True, "fresh"],
+                         ids=["zero", "a-carry", "fresh"])
+@pytest.mark.parametrize("layout", COMBINE_LAYOUTS.values(),
+                         ids=COMBINE_LAYOUTS.keys())
+def test_combine_is_scatter_add(layout, carry, interpret):
+    """To the last bit against the rows added in their order (the
+    kernel's order), within 2 ulp of the sum's magnitude against
+    ``.at[].add`` whatever order XLA takes; rows past the sizes' sum add
+    nothing; a token with no row keeps what it held, or zero where the
+    caller said the target was fresh."""
+    rows, tok, sizes, held, p = _combine_operands(layout, carry is True)
+    before = _kernel_counts()
+    fresh = carry == "fresh"       # said fresh, what is held is never read
+    got = np.asarray(jax.jit(lambda *a: gm.combine(*a[:4], "t", fresh=a[4]))(
+        rows, tok, sizes, held + np.float32("nan") if fresh else held, fresh))
+    if metrics.ACTIVE:
+        assert _grew(before) == {("combine", "pallas"): 1}
+    ordered = held.copy()
+    np.add.at(ordered, tok[:p], rows[:p])
+    np.testing.assert_array_equal(got, ordered)
+    xla = np.asarray(jnp.asarray(held).at[tok[:p]].add(rows[:p]))
+    size = np.abs(held)
+    np.add.at(size, tok[:p], np.abs(rows[:p]))
+    assert (np.abs(got - xla) <= 2 * np.spacing(size)).all()
+    untouched = np.setdiff1d(np.arange(TOKENS), tok[:p])
+    np.testing.assert_array_equal(got[untouched], held[untouched])
+
+
+def test_the_combines_plan_lists_each_run_in_chunks():
+    """Two groups over two tiles of 512 tokens, chunks of 32 rows from
+    the multiple of 8 under a run's first row."""
+    tok = np.concatenate([np.arange(480, 560), [3, 600, 601], np.zeros(45)])
+    chunks, first = gm._combine_plan(
+        jnp.asarray(tok, jnp.int32), jnp.asarray([80, 3], jnp.int32), 1024)
+    first = np.asarray(first)
+    chunks = np.asarray(chunks).reshape(-1, 3)[:first[-1]]
+    # tile 0: group 0's rows 0..31, then group 1's row 80 (copied from 80);
+    # tile 1: group 0's rows 32..79 from row 32, 64; group 1's rows 81, 82
+    assert first.tolist() == [0, 2, 5]
+    assert chunks.tolist() == [[0, 0, 32], [80, 0, 1],
+                               [32, 0, 32], [64, 0, 16], [80, 1, 3]]
+
+
+@pytest.mark.parametrize("why,rows,out,reason", [
+    ("cpu-backend", (1536, 256, jnp.float32), (1024, 256), "backend"),
+    ("rows-of-bf16", (1536, 256, jnp.bfloat16), (1024, 256), "float32"),
+    ("rows-no-chunk-multiple", (1000, 256, jnp.float32), (1024, 256), "multiple of 32"),
+    ("tokens-no-tile-multiple", (1536, 256, jnp.float32), (1000, 256), "none of 512"),
+    ("width-not-128", (1536, 96, jnp.float32), (1024, 96), "multiple of 128"),
+    ("another-width", (1536, 256, jnp.float32), (1024, 128), "one width"),
+    ("token-ids-past-the-smem", (131072, 128, jnp.float32), (1024, 128), "scalar memory"),
+    ("a-step-past-the-vmem", (1536, 16384, jnp.float32), (1024, 16384), "VMEM"),
+])
+def test_combine_refusals_fall_back_to_scatter_add(why, rows, out, reason,
+                                                   monkeypatch):
+    monkeypatch.setattr(gm, "_INTERPRET", why != "cpu-backend")
+    x = jax.ShapeDtypeStruct(rows[:2], rows[2])
+    o = jax.ShapeDtypeStruct(out, jnp.float32)
+    assert reason in gm._combine_refusal(x, o)
+    if why == "another-width":          # nothing to add such rows to
+        return
+    before = _kernel_counts()
+    tok, sizes = (jax.ShapeDtypeStruct((rows[0],), jnp.int32),
+                  jax.ShapeDtypeStruct((4,), jnp.int32))
+    text = str(jax.make_jaxpr(lambda *a: gm.combine(*a, "t"))(
+        x, tok, sizes, o))
+    assert "scatter-add" in text and "pallas_call" not in text
+    if metrics.ACTIVE:
+        assert _grew(before) == {("combine", "xla"): 1}
+
+
+def test_combine_fallback_adds_nothing_past_the_sizes():
+    rows, tok, sizes, held, p = _combine_operands(
+        COMBINE_LAYOUTS["an-empty-expert"], True)
+    got = np.asarray(gm.combine(jnp.nan_to_num(rows, nan=7.0), tok, sizes,
+                                jnp.asarray(held), "t"))
+    ordered = held.copy()
+    np.add.at(ordered, tok[:p], rows[:p])
+    np.testing.assert_array_equal(got, ordered)
+
+
 # ------------------------------------------------ the expert layer on them
 
 def _layer_operands(dtype, D, F, E=4, k=2, n_tokens=1024, seed=0):
@@ -140,7 +258,11 @@ def _layer_operands(dtype, D, F, E=4, k=2, n_tokens=1024, seed=0):
     tokens = jax.random.normal(ks[0], (n_tokens, D)).astype(dtype)
     ws = [(jax.random.normal(key, shape) * shape[1] ** -0.5).astype(dtype)
           for key, shape in zip(ks[1:4], ((E, D, F), (E, D, F), (E, F, D)))]
-    e = jax.random.randint(ks[4], (n_tokens * k,), 0, E + 2)
+    # a token's k experts are distinct, as top_k's are: a token meets a
+    # group once, which the combine counts on
+    e = (jax.random.randint(ks[4], (n_tokens, 1), 0, E + 2) + jnp.cumsum(
+        jax.random.randint(ks[0], (n_tokens, k), 1, (E + 2) // k + 1),
+        axis=1) - 1).reshape(-1) % (E + 2)
     e = jnp.where(e >= E, E, e)                 # a third are not held here
     pair_w = jax.random.uniform(ks[5], (n_tokens * k,), jnp.float32)
     rows = 512                                  # three chunks of the pairs
@@ -178,13 +300,15 @@ def test_held_experts_gradients_through_the_kernels(dtype, monkeypatch):
     (ref, computed), ref_grads = grad()
     assert computed == pairs
     if metrics.ACTIVE:         # forward 3, made again 3, autodiff's 3 + 3
-        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3}
+        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3,
+                                 ("combine", "xla"): 2}
     monkeypatch.setattr(gm, "_INTERPRET", True)
     before = _kernel_counts()
     (got, computed), grads = grad()
     assert computed == pairs
     if metrics.ACTIVE:         # gate_up, down; gate_up, dh, dx; three tgmm
-        assert _grew(before) == {("gmm", "pallas"): 5, ("tgmm", "pallas"): 3}
+        assert _grew(before) == {("gmm", "pallas"): 5, ("tgmm", "pallas"): 3,
+                                 ("combine", "pallas"): 2}
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert abs(got - ref) <= tol * abs(ref) + tol
     for a, b in zip(grads, ref_grads):
@@ -201,7 +325,8 @@ def test_toy_widths_on_the_cpu_take_ragged_dot(interpret):
     before = _kernel_counts()
     jax.make_jaxpr(jax.grad(lambda *a: loss(*a)[0], (0, 1, 2, 3, 4)))(*args)
     if metrics.ACTIVE:
-        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3}
+        assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3,
+                                 ("combine", "xla"): 2}
 
 
 @pytest.mark.parametrize("why,rows,weights,reason", [

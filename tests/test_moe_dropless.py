@@ -10,6 +10,7 @@ import pytest
 
 from horovod_tpu import metrics
 from horovod_tpu.models import llama, moe
+from horovod_tpu.ops import grouped_matmul
 
 CFG = llama.LlamaConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
                         n_kv_heads=2, d_ff=16, n_experts=8, expert_top_k=2,
@@ -93,6 +94,57 @@ def test_dropless_gradients_match_the_dense_layer(first, held):
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-4)
+
+
+@pytest.mark.parametrize("chunks", [1, 2], ids=["one-chunk", "two-chunks"])
+def test_layer_through_the_combine_kernel_is_the_scatter_adds(chunks,
+                                                               monkeypatch):
+    """The layer's output and its five gradients with the combine as the
+    Pallas kernel (interpreted; the model width a multiple of 128, 1,024
+    tokens) against ``.at[].add``, to the last bit: both add a token's
+    rows in the sorted rows' order.  Two chunks: every token sends both
+    its pairs to held experts, 2,048 pairs for chunks of 1,536 rows, so
+    the second chunk adds to what the first left."""
+    cfg = dataclasses.replace(CFG, d_model=128)
+    lp = _params(cfg, seed=7)
+    x = jax.random.normal(jax.random.key(8), (2, 512, cfg.d_model))
+    if chunks == 2:
+        x = x.at[..., 0].set(6.0)
+        lp["router"] = jnp.zeros_like(lp["router"]).at[0, 3].set(
+            2.0).at[0, 4].set(1.0).at[1, 2:6].set(0.05)
+    t = jax.random.normal(jax.random.key(9), x.shape)
+
+    def run():
+        def loss(x, lp):
+            y, stats = moe.dropless_moe_layer(x, lp, cfg, PAR)
+            return (y * t).sum(), (y, stats)
+
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True))(x, lp)
+        return y, stats, grads
+
+    def combines():
+        fam = metrics.registry().to_dict().get("hvd_moe_gmm_kernel_total", {})
+        return {s["labels"]["path"]: s["value"] for s in fam.get("series", [])
+                if s["labels"]["kernel"] == "combine"}
+
+    y, stats, grads = run()
+    before = combines()
+    monkeypatch.setattr(grouped_matmul, "_INTERPRET", True)
+    y2, stats2, grads2 = run()
+    if metrics.ACTIVE:           # out and dtok, and no scatter-add beside them
+        after = combines()
+        assert after.get("pallas", 0) - before.get("pallas", 0) == 2
+        assert after.get("xla", 0) == before.get("xla", 0)
+    rows = moe._chunk_rows(1024, 2, 4, 8)
+    assert (np.asarray(stats)[0] > rows) == (chunks == 2)
+    np.testing.assert_array_equal(stats, stats2)
+    np.testing.assert_array_equal(y, y2)
+    assert len(jax.tree_util.tree_leaves(grads)) == 5
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(grads2)):
+        assert np.asarray(a).any()
+        np.testing.assert_array_equal(a, b)
 
 
 def test_chunk_rows_follow_even_routing_not_the_worst_case():
