@@ -28,7 +28,7 @@ from jax import lax
 
 from ..optim import overlap as _overlap
 from ..parallel.ring_attention import local_attention, ring_attention
-from .llama import ParallelSpec
+from .llama import ParallelSpec, remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,9 +233,10 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
         params["layers"])
     body = block
     if cfg.remat:
-        body = jax.checkpoint(
-            body, static_argnums=(2, 3),
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        # dots, and the packed flash kernel's named output and row
+        # statistics: the kernel is not rerun in the backward pass
+        body = jax.checkpoint(body, static_argnums=(2, 3),
+                              policy=remat_policy("dots"))
 
     def scan_body(h, lp):
         # overlapped dispatch tap (identity unless an overlapped_backprop

@@ -28,9 +28,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import metrics as _metrics
 from ..optim import overlap as _overlap
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
+
+_m_remat = _metrics.counter(
+    "hvd_remat_policy_total",
+    "Remat'd layer stacks built by policy, one per traced stack; saves "
+    "says which named residuals the policy keeps beside the layer's "
+    "input (flash: the attention kernels' output and row statistics)",
+    labels=("policy", "saves"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,9 +55,14 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16     # activation / compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # remat granularity: "full" recomputes everything (max memory savings),
-    # "dots" saves matmul outputs without batch dims (cheap recompute of
-    # elementwise/norm only — the right default when memory allows)
+    # remat granularity: "full" recomputes every op XLA makes (max memory
+    # savings), "dots" saves matmul outputs without batch dims (cheap
+    # recompute of elementwise/norm only — the right default when memory
+    # allows).  Under either a hand-written attention kernel is never
+    # rerun: its output and row statistics (ops/flash_attention.py's
+    # OUT_NAME, LSE_NAME) are a layer's saved residuals beside the
+    # layer's input, L x B x T x H x head_dim x 2 + L x B x H x T x 4
+    # bytes a chip (bf16 out, float32 lse; docs/models.md has the sums)
     remat_policy: str = "dots"
     # Mixture-of-Experts (0 experts = dense SwiGLU MLP)
     n_experts: int = 0
@@ -385,6 +398,28 @@ def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
     return x + y, aux
 
 
+def remat_policy(name: str):
+    """What a remat'd layer keeps beside its input, by
+    ``LlamaConfig.remat_policy``'s names; counted, once per traced stack
+    (:func:`_layer_stack`, and ``models/bert.py``'s encoder under
+    ``"dots"``).  Either policy keeps what the flash kernels name: a
+    hand-written kernel is never rerun to make its own residuals again."""
+    if name not in ("full", "dots"):
+        raise ValueError(
+            f"remat_policy must be 'full' or 'dots', got {name!r}")
+    # imported here, as the kernels are where they are called: a program
+    # without attention never loads Pallas
+    from ..ops import flash_attention as _flash
+    if _metrics.ACTIVE:
+        _m_remat.inc(policy=name, saves="flash")
+    cp = jax.checkpoint_policies
+    named = cp.save_only_these_names(_flash.OUT_NAME, _flash.LSE_NAME)
+    if name == "full":
+        return named
+    return cp.save_from_both_policies(
+        cp.dots_with_no_batch_dims_saveable, named)
+
+
 def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions,
                  mask=None):
     # Cast the whole stacked weight tree to compute dtype ONCE before the
@@ -402,14 +437,8 @@ def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions,
     blk = block if mask is None else functools.partial(block, mask=mask)
     body = blk
     if cfg.remat:
-        if cfg.remat_policy not in ("full", "dots"):
-            raise ValueError(
-                f"remat_policy must be 'full' or 'dots', got "
-                f"{cfg.remat_policy!r}")
-        policy = (jax.checkpoint_policies.nothing_saveable
-                  if cfg.remat_policy == "full" else
-                  jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        body = jax.checkpoint(body, static_argnums=(2, 3), policy=policy)
+        body = jax.checkpoint(body, static_argnums=(2, 3),
+                              policy=remat_policy(cfg.remat_policy))
 
     def scan_stack(body_fn, carry, ls):
         def scan_body(carry, lp):

@@ -64,6 +64,22 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   ``g x T x D``: 8 query heads a kv head at 8,192 positions run.  Every
   query row has to see at least one key.
 
+Residuals are named.  Both paths' ``custom_vjp`` keep ``(q, k, v, out,
+lse)`` for the backward kernels, and the forward rules pass ``out`` and
+``lse`` through ``jax.ad_checkpoint.checkpoint_name`` as ``OUT_NAME``
+(``hvd_flash_out``) and ``LSE_NAME`` (``hvd_flash_lse``), in the kernels'
+own layout (``[B, H, T, D]`` and ``[B, H, nq, bq]``; packed: ``[B, T,
+H*D]`` and ``[B, H, 1, T]``), ``out`` in the operands' dtype and ``lse``
+in float32 as the kernel wrote them.  A caller that rematerializes
+around the op and saves these names
+(``jax.checkpoint_policies.save_only_these_names``, as
+``models/llama.py::remat_policy`` does for the decoder trunk's layer
+stack and for ``models/bert.py``'s encoder) gets the backward's
+residuals from memory, ``B x T x H x D x itemsize + B x H x T x 4``
+bytes a call; one that does not reruns the forward kernel in its
+backward pass to make them again, and the names change nothing in its
+program.
+
 ``hvd_flash_kernel_total{kernel, path}`` counts the kernels built, once
 per traced call site, so a program says which path its shapes took;
 ``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
@@ -87,6 +103,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import numpy as np
@@ -103,6 +120,10 @@ _LANES = 128
 # what one masked forward grid step may hold: a quarter of a v5e core's
 # 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
 _MASKED_STEP_VMEM = 32 * 1024 * 1024
+# checkpoint names of the forward kernels' two results ("Residuals are
+# named", above)
+OUT_NAME = "hvd_flash_out"
+LSE_NAME = "hvd_flash_lse"
 
 _m_kernels = _metrics.counter(
     "hvd_flash_kernel_total",
@@ -406,8 +427,18 @@ def _packed_attention_lse(q, k, v, causal, scale, D, pack):
     return _packed_fwd(q, k, v, causal, scale, D, pack)
 
 
+def _named_residuals(out, lse):
+    """The forward kernel's two results under their checkpoint names.
+    A forward rule returns THESE as primal outputs and as residuals: a
+    name put on what the public op returns would be another variable
+    than the residual, and a remat'd caller would still rerun the
+    kernel to make the residual again."""
+    return (checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME))
+
+
 def _packed_attention_lse_fwd(q, k, v, causal, scale, D, pack):
-    out, lse = _packed_fwd(q, k, v, causal, scale, D, pack)
+    out, lse = _named_residuals(*_packed_fwd(q, k, v, causal, scale, D,
+                                             pack))
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -868,7 +899,8 @@ def _masked_attention_lse(q, k, v, mask, static, scale):
 
 
 def _masked_attention_lse_fwd(q, k, v, mask, static, scale):
-    out, lse = _masked_attention_lse(q, k, v, mask, static, scale)
+    out, lse = _named_residuals(
+        *_masked_attention_lse(q, k, v, mask, static, scale))
     return (out, lse), (q, k, v, mask, out, lse)
 
 
