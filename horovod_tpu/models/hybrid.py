@@ -1,0 +1,346 @@
+"""A decoder trunk whose layers are of several kinds and hand memory to
+one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash).
+
+``LlamaConfig.layer_kinds`` names each layer's kind; :mod:`.llama`'s
+``init_params``, ``param_specs``, ``count_params`` and ``hidden`` come
+here when it is set.  Every layer is
+
+    h = x + Mixer(LN1(x));  y = h + W2 (silu(g) * v),  [g ; v] = W1 LN2(h)
+
+with LayerNorm (weight and bias), no bias in a product, no positional
+encoding anywhere.  The kinds are their mixers:
+
+* ``mamba`` — Mamba-1: ``[xs ; z] = W_in u``; ``xs = silu(conv(xs) +
+  b)``, depthwise, causal, ``ssm_conv`` wide; ``[r ; B ; C] = W_x xs``;
+  ``delta = softplus(W_dt r + b_dt)``; ``A = -exp(A_log)``; ``s`` the
+  selective scan (:mod:`horovod_tpu.ops.selective_scan`); the mixer gives
+  ``W_out (s * silu(z))``.  The last one before a ``gmu`` also emits ``m
+  = s``, the memory.
+* ``window``, ``full`` — differential attention (arXiv 2410.05258):
+  adjacent heads pair, ``A1 = softmax(q1 k1^T / sqrt(Dh) + M)``, ``A2``
+  of the pair's second heads, values the pair's ``[v ; v']``; ``o =
+  RMSNorm((A1 - lambda A2) [v ; v']) (1 - lambda_init)`` with ``lambda =
+  exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` and ``lambda_init =
+  0.8 - 0.6 exp(-0.3 i)``, ``i`` the layer's published index
+  (``layer_ids``).  ``M`` is causal within the last ``sliding_window``
+  keys, or causal; a ``full`` layer that a ``cross`` layer follows keeps
+  its ``k`` and ``v``.
+* ``cross`` — the same with ``W_q`` and ``W_o`` only, attending
+  causally to the ``full`` layer's ``k`` and ``v``.
+* ``gmu`` — the gated memory unit, ``W_out (silu(W_in u) * m)``.
+
+Attention goes through ``ring_attention.local_attention`` with the mask
+as key ranges (``window_ranges``, ``causal_ranges``), two calls a layer:
+``(q1, k1, [v ; v'])`` and ``(q2, k2, [v ; v'])``, the masked flash
+kernels taking values twice as wide as queries and keys.
+
+Parameters are a tree per kind, each leaf stacked over the kind's layers;
+:func:`layer_stack` runs the kinds in order, scanning runs of equal
+layers, and carries ``(h, m, kv)``.  Under ``cfg.remat`` each layer (a
+run's scan body) is a ``jax.checkpoint`` under the config's policy: the
+flash kernels' ``out`` and ``lse`` are kept by name as in the llama
+trunk, and ``m`` and the ``full`` layer's ``k``, ``v`` are saved, being
+what the remat'd ``gmu`` and ``cross`` layers take as inputs: ``B x T x
+ssm_inner x 2 + 2 x B x T x Hkv x Dh x 2`` bytes in bf16 (126 MB at
+8,192 positions of the published widths); the emitting layers are made
+again in the backward pass like any other, their scan included.
+
+``hvd_layer_kind_total{kind}`` counts the layers traced; the mixers run
+under the scopes ``hvd_ssm_mixer``, ``hvd_gmu`` and
+``hvd_diff_attention``.  Plain data parallelism only: nothing here is
+sharded over a tensor-, sequence- or pipeline-parallel axis yet.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .. import metrics as _metrics
+from ..ops import flash_attention as _fa
+from ..ops.selective_scan import selective_scan
+from ..parallel.ring_attention import local_attention
+from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
+
+KINDS = ("mamba", "window", "full", "gmu", "cross")
+_MATRICES = ("w1", "w2", "in_proj", "x_proj", "dt_proj", "out_proj", "wqkv",
+             "wq", "wo")
+
+_m_kinds = _metrics.counter(
+    "hvd_layer_kind_total",
+    "Layers of a trunk of several kinds traced, by kind "
+    "(models/hybrid.py)", labels=("kind",))
+
+
+def check(cfg) -> None:
+    """The config's kinds make a trunk: known names, every ``gmu`` after a
+    ``mamba`` and every ``cross`` after a ``full``."""
+    kinds = cfg.layer_kinds
+    if len(kinds) != cfg.n_layers or set(kinds) - set(KINDS):
+        raise ValueError(f"layer_kinds must name n_layers={cfg.n_layers} "
+                         f"layers from {KINDS}, got {kinds!r}")
+    if cfg.layer_ids and len(cfg.layer_ids) != len(kinds):
+        raise ValueError("layer_ids must give every layer's published index")
+    for kind, source in (("gmu", "mamba"), ("cross", "full")):
+        if kind in kinds and source not in kinds[:kinds.index(kind)]:
+            raise ValueError(f"a {kind} layer needs a {source} layer "
+                             "before it")
+    if cfg.n_heads % 2 or cfg.n_kv_heads % 2:
+        raise ValueError("differential attention pairs adjacent heads: "
+                         "n_heads and n_kv_heads must be even")
+
+
+def published_kinds(n_layers: int):
+    """Each layer's kind by the model's rule: even layers carry a mixer of
+    the Mamba family, odd ones of the attention family; the first half
+    (the self-decoder) and layer ``n/2`` are Mamba and window attention,
+    layer ``n/2 + 1`` is the one full attention, and the cross-decoder
+    after it alternates gated memory units and cross attention."""
+    half = n_layers // 2
+    return tuple(
+        ("mamba" if i <= half else "gmu") if i % 2 == 0
+        else "window" if i < half else "full" if i == half + 1 else "cross"
+        for i in range(n_layers))
+
+
+def lambda_init(i):
+    return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+
+# --------------------------------------------------------------- shapes
+
+def layer_shapes(cfg, kind):
+    """{leaf: shape} of one layer of ``kind``."""
+    D, F, Dh = cfg.d_model, cfg.d_ff, cfg.head_dim
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    Di, N, Kc, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank
+    shapes = {"norm1_w": (D,), "norm1_b": (D,), "norm2_w": (D,),
+              "norm2_b": (D,), "w1": (D, 2 * F), "w2": (F, D)}
+    if kind == "mamba":
+        shapes.update({
+            "in_proj": (D, 2 * Di), "conv_w": (Kc, Di), "conv_b": (Di,),
+            "x_proj": (Di, R + 2 * N), "dt_proj": (R, Di), "dt_bias": (Di,),
+            "A_log": (Di, N), "D": (Di,), "out_proj": (Di, D)})
+    elif kind == "gmu":
+        shapes.update({"in_proj": (D, Di), "out_proj": (Di, D)})
+    else:
+        width = H * Dh if kind == "cross" else (H + 2 * Hkv) * Dh
+        shapes.update({
+            "wq" if kind == "cross" else "wqkv": (D, width),
+            "wo": (H * Dh, D), "lambda_q1": (Dh,), "lambda_k1": (Dh,),
+            "lambda_q2": (Dh,), "lambda_k2": (Dh,), "subln": (2 * Dh,)})
+    return shapes
+
+
+def _counts(cfg):
+    return {k: cfg.layer_kinds.count(k) for k in KINDS
+            if k in cfg.layer_kinds}
+
+
+def count_params(cfg) -> int:
+    layers = sum(n * sum(int(np.prod(s))
+                         for s in layer_shapes(cfg, kind).values())
+                 for kind, n in _counts(cfg).items())
+    return ((1 if cfg.tie_embeddings else 2) * cfg.vocab_size * cfg.d_model
+            + layers + 2 * cfg.d_model)
+
+
+def init_layers(cfg, key):
+    """{kind: {leaf: [layers of the kind, ...]}}: matrices normal at
+    ``fan_in ** -0.5``, norms at 1 and 0, and Mamba's own: ``A_log =
+    log(1..N)``, ``D = 1``, ``softplus(dt_bias)`` log-uniform on 1e-3 ..
+    1e-1; ``lambda``'s vectors normal(0, 0.1)."""
+    check(cfg)
+    dt = cfg.param_dtype
+    out = {}
+    for a, (kind, n) in enumerate(_counts(cfg).items()):
+        tree = {}
+        for b, (name, shape) in enumerate(layer_shapes(cfg, kind).items()):
+            k = jax.random.fold_in(jax.random.fold_in(key, a), b)
+            full = (n,) + shape
+            if name in ("norm1_w", "norm2_w", "subln", "D"):
+                leaf = jnp.ones(full, dt)
+            elif name in ("norm1_b", "norm2_b", "conv_b"):
+                leaf = jnp.zeros(full, dt)
+            elif name == "A_log":
+                leaf = jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, shape[1] + 1, dtype=dt)), full)
+            elif name == "dt_bias":
+                step = jnp.exp(jax.random.uniform(
+                    k, full, dt, math.log(1e-3), math.log(1e-1)))
+                leaf = step + jnp.log(-jnp.expm1(-step))
+            elif name.startswith("lambda_"):
+                leaf = jax.random.normal(k, full, dt) * 0.1
+            else:
+                leaf = jax.random.normal(k, full, dt) * shape[0] ** -0.5
+            tree[name] = leaf
+        out[kind] = tree
+    return out
+
+
+def layer_specs(cfg):
+    """PartitionSpecs of :func:`init_layers`' tree: every leaf replicated."""
+    from jax.sharding import PartitionSpec as P
+    return {kind: {name: P() for name in layer_shapes(cfg, kind)}
+            for kind in _counts(cfg)}
+
+
+# --------------------------------------------------------------- layers
+
+def _mlp(u, lp):
+    g, v = jnp.split(u @ lp["w1"], 2, axis=-1)
+    return (jax.nn.silu(g) * v) @ lp["w2"]
+
+
+def _mamba(u, lp, cfg):
+    """-> (the mixer's output ``[B, T, D]``, the scan's output ``s [B, T,
+    ssm_inner]`` before the gate)."""
+    N, R, Kc = cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    f32 = jnp.float32
+    T = u.shape[1]
+    xs, z = jnp.split(u @ lp["in_proj"], 2, axis=-1)
+    padded = jnp.pad(xs.astype(f32), ((0, 0), (Kc - 1, 0), (0, 0)))
+    xs = jax.nn.silu(sum(padded[:, j:j + T] * lp["conv_w"][j].astype(f32)
+                         for j in range(Kc))
+                     + lp["conv_b"].astype(f32)).astype(u.dtype)
+    r, Bm, Cm = jnp.split(xs @ lp["x_proj"], (R, R + N), axis=-1)
+    delta = jax.nn.softplus(
+        jnp.dot(r, lp["dt_proj"], preferred_element_type=f32)
+        + lp["dt_bias"].astype(f32))
+    s = selective_scan(xs, delta, -jnp.exp(lp["A_log"].astype(f32)), Bm, Cm,
+                       lp["D"].astype(f32))
+    return (s * jax.nn.silu(z)) @ lp["out_proj"], s
+
+
+def _pairs(x):
+    """``[B, T, H, Dh]`` -> the pairs' first and second heads, ``[B, T,
+    H / 2, Dh]`` each."""
+    B, T, H, Dh = x.shape
+    x = x.reshape(B, T, H // 2, 2, Dh)
+    return x[:, :, :, 0], x[:, :, :, 1]
+
+
+def _diff_attention(q, k, v, lp, lam0, mask, cfg):
+    """q ``[B, T, H, Dh]``; k, v ``[B, Tk, Hkv, Dh]`` -> ``[B, T, D]``."""
+    f32 = jnp.float32
+    B, T, H, Dh = q.shape
+    (q1, q2), (k1, k2) = _pairs(q), _pairs(k)
+    vv = v.reshape(B, v.shape[1], v.shape[2] // 2, 2 * Dh)
+    a1 = local_attention(q1, k1, vv, mask=mask).astype(f32)
+    a2 = local_attention(q2, k2, vv, mask=mask).astype(f32)
+    dot = lambda a, b: jnp.sum(lp[a].astype(f32) * lp[b].astype(f32))
+    lam = (jnp.exp(dot("lambda_q1", "lambda_k1"))
+           - jnp.exp(dot("lambda_q2", "lambda_k2")) + lam0)
+    o = a1 - lam * a2
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+    o = o * lp["subln"].astype(f32) * (1.0 - lam0)
+    return o.astype(q.dtype).reshape(B, T, H * Dh) @ lp["wo"]
+
+
+def key_ranges(kind, T, cfg):
+    """The key ranges ``[T, 4]`` a layer of ``kind`` sees: causal within
+    the last ``sliding_window`` keys, or causal."""
+    if kind == "window":
+        return _fa.window_ranges(T, cfg.sliding_window)
+    return _fa.causal_ranges(T)
+
+
+def _layer(kind, emits, cfg):
+    """One layer of ``kind`` as ``f(h, lp, lam0, memory) -> (h, emitted)``:
+    ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer, else
+    None; ``emitted`` is what an emitting mamba (``s``) or full layer
+    (``(k, v)``) hands on, else None."""
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def f(h, lp, lam0, memory):
+        # the products' matrices in the compute dtype; norms, the
+        # convolution, the scan's A, D and step bias and lambda's vectors
+        # stay in the parameters'
+        lp = {n: w.astype(cfg.dtype) if n in _MATRICES else w
+              for n, w in lp.items()}
+        B, T, _ = h.shape
+        u = layer_norm(h, lp["norm1_w"], lp["norm1_b"], cfg.norm_eps)
+        emitted = None
+        if kind == "mamba":
+            with jax.named_scope("hvd_ssm_mixer"):
+                y, s = _mamba(u, lp, cfg)
+            emitted = s if emits else None
+        elif kind == "gmu":
+            with jax.named_scope("hvd_gmu"):
+                y = (jax.nn.silu(u @ lp["in_proj"]) * memory) @ lp["out_proj"]
+        else:
+            with jax.named_scope("hvd_diff_attention"):
+                if kind == "cross":
+                    q = (u @ lp["wq"]).reshape(B, T, H, Dh)
+                    k, v = memory
+                else:
+                    q, k, v = jnp.split(u @ lp["wqkv"],
+                                        (H * Dh, (H + Hkv) * Dh), axis=-1)
+                    q = q.reshape(B, T, H, Dh)
+                    k, v = (a.reshape(B, T, Hkv, Dh) for a in (k, v))
+                    emitted = (k, v) if emits else None
+                y = _diff_attention(q, k, v, lp, lam0, key_ranges(kind, T, cfg),
+                                    cfg)
+        h = h + y
+        return h + _mlp(layer_norm(h, lp["norm2_w"], lp["norm2_b"],
+                                   cfg.norm_eps), lp), emitted
+
+    return f
+
+
+def _runs(cfg):
+    """[(kind, first of the kind's stack, layers' published ids, emits)]:
+    runs of equal layers in order; an emitting layer is a run of its
+    own."""
+    kinds = cfg.layer_kinds
+    ids = cfg.layer_ids or tuple(range(len(kinds)))
+    last_mamba = (max(i for i, k in enumerate(kinds) if k == "mamba"
+                      and i < kinds.index("gmu")) if "gmu" in kinds else -1)
+    last_full = (max(i for i, k in enumerate(kinds) if k == "full"
+                     and i < kinds.index("cross")) if "cross" in kinds
+                 else -1)
+    runs, seen = [], {}
+    for i, kind in enumerate(kinds):
+        emits = i in (last_mamba, last_full)
+        at = seen.get(kind, 0)
+        seen[kind] = at + 1
+        if (runs and runs[-1][0] == kind and not emits
+                and not runs[-1][3]):
+            runs[-1][2].append(ids[i])
+        else:
+            runs.append([kind, at, [ids[i]], emits])
+    return runs
+
+
+def layer_stack(h, layers, cfg, policy=None):
+    """The trunk: ``h [B, T, D]`` through every layer.  ``policy``: the
+    remat policy of ``cfg.remat`` (``llama.remat_policy``)."""
+    check(cfg)
+    m = kv = None
+    for kind, first, ids, emits in _runs(cfg):
+        n = len(ids)
+        if _metrics.ACTIVE:
+            _m_kinds.inc(n, kind=kind)
+        f = _layer(kind, emits, cfg)
+        if cfg.remat:
+            f = jax.checkpoint(f, policy=policy)
+        memory = m if kind == "gmu" else kv if kind == "cross" else None
+        lam0 = jnp.asarray([lambda_init(i) for i in ids], jnp.float32)
+        lps = jax.tree_util.tree_map(lambda w: w[first:first + n],
+                                     layers[kind])
+        if n == 1:
+            h, emitted = f(h, jax.tree_util.tree_map(lambda w: w[0], lps),
+                           lam0[0], memory)
+            if kind == "mamba" and emits:
+                m = emitted
+            elif emits:
+                kv = emitted
+        else:
+            h, _ = lax.scan(
+                lambda h_, at: (f(h_, at[0], at[1], memory)[0], None),
+                h, (lps, lam0))
+    return h

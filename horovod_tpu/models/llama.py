@@ -102,6 +102,21 @@ class LlamaConfig:
     moe_dispatch: str = "capacity"
     experts_held: int = 0
     experts_first: int = 0
+    # a trunk whose layers are of several kinds (models/hybrid.py): each
+    # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" (() = the
+    # trunk of identical layers here), and its index in the published
+    # model (() = its place in the trunk); the window of the "window"
+    # kind's attention; the state-space mixer's inner width, states a
+    # channel, convolution width and step rank.  Such a trunk has
+    # LayerNorm, a fused gate/up MLP, differential attention and no
+    # positional encoding.
+    layer_kinds: tuple = ()
+    layer_ids: tuple = ()
+    sliding_window: int = 0
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -154,6 +169,16 @@ class ParallelSpec:
 def init_params(cfg: LlamaConfig, key, tp: int = 1) -> Dict:
     """Initialize parameters; with ``tp > 1`` returns the FULL stacked
     params — shard them over the mesh with :func:`param_specs`."""
+    if cfg.layer_kinds:
+        from . import hybrid
+        k = jax.random.split(key, 2)
+        return {
+            "embed": jax.random.normal(
+                k[0], (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            * cfg.d_model ** -0.5,
+            "layers": hybrid.init_layers(cfg, k[1]),
+            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+            "final_norm_bias": jnp.zeros((cfg.d_model,), cfg.param_dtype)}
     k = jax.random.split(key, 8)
     D, H, Hkv, Dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                               cfg.head_dim, cfg.d_ff, cfg.n_layers,
@@ -207,6 +232,10 @@ def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
     expert weights shard their expert dim over ep.
     """
     from jax.sharding import PartitionSpec as P
+    if cfg is not None and cfg.layer_kinds:
+        from . import hybrid
+        return {"embed": P(), "layers": hybrid.layer_specs(cfg),
+                "final_norm": P(), "final_norm_bias": P()}
     tp = par.tp_axis
     pp = par.pp_axis
     embed_spec = (P(tp, None) if cfg is not None and cfg.vocab_parallel
@@ -485,6 +514,8 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     [B, T]``: each token's position for RoPE (default: its index);
     ``mask``: the key ranges each query row sees (default: causal).
     """
+    if cfg.layer_kinds:
+        return _hybrid_hidden(params, tokens, cfg, par, positions, mask)
     Tl = tokens.shape[1]
     sp_idx = (lax.axis_index(par.sp_axis)
               if par.sp_axis is not None else 0)
@@ -528,6 +559,26 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
 
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     return h, aux
+
+
+def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
+                   positions, mask):
+    """:func:`hidden` of a trunk of several kinds (models/hybrid.py): no
+    positions at all, each kind's own mask, a final LayerNorm."""
+    from . import hybrid
+    if (positions is not None or mask is not None
+            or any(a is not None for a in (par.tp_axis, par.sp_axis,
+                                           par.pp_axis))):
+        raise NotImplementedError(
+            "a trunk of several kinds takes no positions and no mask and "
+            "runs under plain data parallelism only")
+    h = _embed_lookup(params["embed"], tokens, cfg, par)
+    h = hybrid.layer_stack(
+        h, params["layers"], cfg,
+        remat_policy(cfg.remat_policy) if cfg.remat else None)
+    h = hybrid.layer_norm(h, params["final_norm"],
+                          params["final_norm_bias"], cfg.norm_eps)
+    return h, jnp.float32(0.0)
 
 
 def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
@@ -654,6 +705,9 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
 
 
 def count_params(cfg: LlamaConfig) -> int:
+    if cfg.layer_kinds:
+        from . import hybrid
+        return hybrid.count_params(cfg)
     D, H, Hkv, Dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                               cfg.head_dim, cfg.d_ff, cfg.n_layers,
                               cfg.vocab_size)

@@ -12,7 +12,11 @@ them.
 Public layout contract (matches :mod:`horovod_tpu.parallel.ring_attention`):
   q: ``[B, T, H, D]``   k/v: ``[B, Tk, Hkv, D]`` with ``Hkv | H`` (GQA —
   query head h reads kv head ``h // (H//Hkv)``; the kernels run in
-  ``[B, H, T, D]`` layout internally for TPU tiling).
+  ``[B, H, T, D]`` layout internally for TPU tiling).  The values may
+  have a width of their own, ``v [B, Tk, Hkv, Dv]`` (differential
+  attention's 128 beside 64-wide queries and keys): such a call takes the
+  masked path and ``out`` is ``Dv`` wide; at ``Dv = D`` every kernel is
+  built as it was.
 
 The logsumexp residual is stored blocked as ``[B, H, nq, bq]`` — the
 (nq, bq) trailing dims are full blocks, which satisfies Mosaic's tiling
@@ -240,12 +244,16 @@ def _refusal(q, k, v) -> Optional[str]:
         return "q and k must be rank 4"
     B, T, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
-    if v.shape != k.shape or q.shape[0] != k.shape[0] or k.shape[3] != D:
-        return "v must match k, and k must match q in batch and head_dim"
+    if (v.ndim != 4 or v.shape[:3] != k.shape[:3]
+            or q.shape[0] != k.shape[0] or k.shape[3] != D):
+        return ("v must match k but in its width, and k must match q in "
+                "batch and head_dim")
+    Dv = v.shape[3]
     if H % Hkv:
         return f"kv heads {Hkv} do not divide query heads {H}"
-    if D % 64 or D > 256:
-        return f"head_dim {D} is not a multiple of 64 up to 256"
+    if D % 64 or D > 256 or Dv % 64 or Dv > 256:
+        return (f"head_dim {D} or the values' {Dv} is not a multiple of "
+                "64 up to 256")
     bq, bk = _block_sizes(T, Tk)
     if T % bq or Tk % bk or bq % 128 or bk % 128:
         return (f"blocks ({bq}, {bk}) must divide the sequence lengths "
@@ -253,8 +261,9 @@ def _refusal(q, k, v) -> Optional[str]:
     if q.dtype not in (jnp.bfloat16, jnp.float32):
         return f"dtype {q.dtype} is neither bfloat16 nor float32"
     g = H // Hkv
-    # fwd holds k+v [Tk, D]; dkv holds q+do of one query tile of the group
-    resident = max(2 * Tk * D, 2 * g * bq * D) * q.dtype.itemsize
+    # fwd holds k+v [Tk, D + Dv]; dkv holds q+do of one query tile of the
+    # group
+    resident = max(Tk, g * bq) * (D + Dv) * q.dtype.itemsize
     if resident > _VMEM_BUDGET:
         return (f"resident buffers need {resident} bytes of VMEM, over "
                 f"the {_VMEM_BUDGET} budget")
@@ -602,7 +611,8 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     meet once, after the last tile; ``acc_ref [hb, bq, D]``.  ``rb_ref
     [4, bq, 128]`` holds the rows' ranges spread over the lanes once a
     step, for every head and mixed tile of it."""
-    hb, bq, D = q_ref.shape[1:]
+    hb, bq = q_ref.shape[1:3]
+    Dv = acc_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -629,7 +639,7 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
             m_ref[h] = m_new
             l_ref[h] = l_ref[h] * corr + functools.reduce(
                 jnp.add, (p[:, c:c + _LANES] for c in range(0, bk, _LANES)))
-            acc_ref[h] = acc_ref[h] * _lanes(corr, D) + jnp.dot(
+            acc_ref[h] = acc_ref[h] * _lanes(corr, Dv) + jnp.dot(
                 p.astype(vj.dtype), vj, preferred_element_type=jnp.float32)
         return carry
 
@@ -729,40 +739,45 @@ def _vmem(*block_bytes, scratch=0):
         2 * sum(block_bytes) + scratch + 24 * 1024 * 1024))
 
 
-def _row_specs(bq, D, Tk, nq, g, bm, hb=1):
+def _row_specs(bq, D, Dv, Tk, nq, g, bm, hb=1):
     """Block specs of the kernels that walk a query tile's key tiles
     (grid ``(B, H // hb, nq)``, three tables prefetched): a query tile of
-    ``hb`` heads, their kv head's whole keys or values, the heads' row
-    statistics, the tile's ranges."""
-    tile = pl.BlockSpec((1, hb, bq, D), lambda b, h, i, *_: (b, h, i, 0))
-    whole = pl.BlockSpec((1, 1, Tk, D),
-                         lambda b, h, i, *_: (b, h * hb // g, 0, 0))
+    ``hb`` heads ``D`` wide (q, dq) and ``Dv`` wide (out, do), their kv
+    head's whole keys and whole values, the heads' row statistics, the
+    tile's ranges."""
+    tile = lambda d: pl.BlockSpec((1, hb, bq, d),
+                                  lambda b, h, i, *_: (b, h, i, 0))
+    whole = lambda d: pl.BlockSpec(
+        (1, 1, Tk, d), lambda b, h, i, *_: (b, h * hb // g, 0, 0))
     stats = pl.BlockSpec((1, hb, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
     rng = pl.BlockSpec((1, bq, 4), lambda b, h, i, *_: (bm(b), i, 0))
-    return tile, whole, stats, rng
+    return tile(D), tile(Dv), whole(D), whole(Dv), stats, rng
 
 
-def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize):
+def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None):
     """VMEM bytes a masked forward grid step of ``hb`` heads holds:
     ``(blocks, scratch, tiles)``, the blocks the pipeline double-buffers
     (whole k and v, ``hb`` tiles of q and of out, their rows of lse, the
     ranges padded to a lane tile), the kernel's scratch (statistics,
     accumulators, the ranges over the lanes) and every head's ``[bq, bk]``
-    scores and probabilities in fp32 and the latter cast."""
-    blocks = (2 * Tk * D * itemsize + hb * (2 * bq * D * itemsize
-                                            + nq * bq * 4) + bq * _LANES * 4)
-    scratch = (hb * bq * (2 * _LANES + D) + 4 * bq * _LANES) * 4
+    scores and probabilities in fp32 and the latter cast.  ``Dv``: the
+    values' width where it is not ``D``."""
+    Dv = D if Dv is None else Dv
+    blocks = (Tk * (D + Dv) * itemsize
+              + hb * (bq * (D + Dv) * itemsize + nq * bq * 4)
+              + bq * _LANES * 4)
+    scratch = (hb * bq * (2 * _LANES + Dv) + 4 * bq * _LANES) * 4
     return blocks, scratch, hb * bq * bk * (8 + itemsize)
 
 
-def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize):
+def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize, Dv=None):
     """Query heads a masked forward grid step takes: the most of one GQA
     group (a divisor of ``g``: they share the resident k and v) that
     :func:`_fwd_step_bytes` keeps under ``_MASKED_STEP_VMEM``, one where
     not even two fit."""
     def fits(hb):
         blocks, scratch, tiles = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
-                                                 itemsize)
+                                                 itemsize, Dv)
         return 2 * blocks + scratch + tiles <= _MASKED_STEP_VMEM
 
     return max(hb for hb in range(1, g + 1)
@@ -770,31 +785,33 @@ def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize):
 
 
 def _masked_fwd_bhtd(q, k, v, mask, scale):
-    """q [B,H,T,D], k/v [B,Hkv,Tk,D] → (out [B,H,T,D], lse [B,H,nq,bq])."""
+    """q [B,H,T,D], k [B,Hkv,Tk,D], v [B,Hkv,Tk,Dv] → (out [B,H,T,Dv],
+    lse [B,H,nq,bq])."""
     B, H, T, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
-    hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize)
+    hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize, Dv)
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm, hb)
+    tile, otile, whole, vwhole, stats, rng = _row_specs(bq, D, Dv, Tk, nq,
+                                                        g, bm, hb)
     _count("fwd", "masked")
     _count_tiles("fwd", classes)
     blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
-                                         q.dtype.itemsize)
+                                         q.dtype.itemsize, Dv)
     return pl.pallas_call(
         functools.partial(_mfwd_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
                           per_batch=per_batch),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, H // hb, nq),
-            in_specs=[tile, whole, whole, rng], out_specs=[tile, stats],
+            in_specs=[tile, whole, vwhole, rng], out_specs=[otile, stats],
             scratch_shapes=[pltpu.VMEM((hb, bq, _LANES), jnp.float32),
                             pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-                            pltpu.VMEM((hb, bq, D), jnp.float32),
+                            pltpu.VMEM((hb, bq, Dv), jnp.float32),
                             pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
         out_shape=[
-            _sds((B, H, T, D), q.dtype, q, k, v),
+            _sds((B, H, T, Dv), q.dtype, q, k, v),
             _sds((B, H, nq, bq), jnp.float32, q, k, v),
         ],
         compiler_params=_vmem(blocks, scratch=scratch),
@@ -805,12 +822,13 @@ def _masked_fwd_bhtd(q, k, v, mask, scale):
 
 def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
     B, H, T, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, whole, stats, rng = _row_specs(bq, D, Tk, nq, g, bm)
+    tile, otile, whole, vwhole, stats, rng = _row_specs(bq, D, Dv, Tk, nq,
+                                                        g, bm)
     item = q.dtype.itemsize
 
     # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
@@ -830,11 +848,11 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
                           per_batch=per_batch),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, H, nq),
-            in_specs=[tile, whole, whole, tile, stats, stats, rng],
+            in_specs=[tile, whole, vwhole, otile, stats, stats, rng],
             out_specs=tile),
         out_shape=_sds((B, H, T, D), q.dtype, q, k, v, do),
-        compiler_params=_vmem(2 * Tk * D * item, 3 * bq * D * item,
-                              bq * _LANES * 4),
+        compiler_params=_vmem(Tk * (D + Dv) * item,
+                              bq * (2 * D + Dv) * item, bq * _LANES * 4),
         interpret=_INTERPRET,
         name="hvd_flash_dq",
     )(*_row_tables(classes), q, k, v, do, lse, delta, ranges)
@@ -844,10 +862,10 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
     # [.. + 1]
     at = (lambda b, p: (b * P + p) * 4) if per_batch else (
         lambda b, p: p * 4)
-    q_blk = pl.BlockSpec((1, g, bq, D),
-                         lambda b, c, p, t: (b, c, t[at(b, p) + 1], 0))
-    kv_blk = pl.BlockSpec((1, 1, bk, D),
-                          lambda b, c, p, t: (b, c, t[at(b, p)], 0))
+    q_blk = lambda d: pl.BlockSpec(
+        (1, g, bq, d), lambda b, c, p, t: (b, c, t[at(b, p) + 1], 0))
+    kv_blk = lambda d: pl.BlockSpec(
+        (1, 1, bk, d), lambda b, c, p, t: (b, c, t[at(b, p)], 0))
     row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_mdkv_kernel, scale=scale, bq=bq, P=P, g=g,
@@ -855,18 +873,20 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hkv, P),
             in_specs=[
-                q_blk, kv_blk, kv_blk, q_blk, row_blk, row_blk,
+                q_blk(D), kv_blk(D), kv_blk(Dv), q_blk(Dv), row_blk,
+                row_blk,
                 pl.BlockSpec((1, bq, 4),
                              lambda b, c, p, t: (bm(b), t[at(b, p) + 1], 0)),
             ],
-            out_specs=[kv_blk, kv_blk],
+            out_specs=[kv_blk(D), kv_blk(Dv)],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)]),
+                            pltpu.VMEM((bk, Dv), jnp.float32)]),
         out_shape=[
             _sds((B, Hkv, Tk, D), k.dtype, q, k, v, do),
-            _sds((B, Hkv, Tk, D), v.dtype, q, k, v, do),
+            _sds((B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
         ],
-        compiler_params=_vmem(2 * g * bq * D * item, 4 * bk * D * item,
+        compiler_params=_vmem(g * bq * (D + Dv) * item,
+                              2 * bk * (D + Dv) * item,
                               2 * g * T * 4, bq * _LANES * 4),
         interpret=_INTERPRET,
         name="hvd_flash_dkv",
@@ -933,7 +953,8 @@ def _attention_lse(q, k, v, causal, sm_scale, mask=None):
     B, T, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
     if mask is None:
-        pack = _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize)
+        pack = ((1, 1) if v.shape[-1] != D else
+                _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize))
         if pack != (1, 1):
             out, lse = _packed_attention_lse(
                 q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
@@ -949,7 +970,8 @@ def _attention_lse(q, k, v, causal, sm_scale, mask=None):
 
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None, mask=None):
-    """Fused exact attention.  ``q [B,T,H,D]``, ``k/v [B,Tk,Hkv,D]``.
+    """Fused exact attention.  ``q [B,T,H,D]``, ``k [B,Tk,Hkv,D]``, ``v
+    [B,Tk,Hkv,Dv]``; ``out [B,T,H,Dv]``.
     ``mask``: the ranges each query row sees (module docstring); given
     one, ``causal`` is not looked at."""
     return _attention_lse(q, k, v, causal, sm_scale, mask)[0]

@@ -79,7 +79,7 @@ def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale,
         pg = p.reshape(B, Hkv, g, Tq, Tk)
         pv = jnp.einsum("bhgqk,bkhd->bqhgd", pg, vf,
                         preferred_element_type=jnp.float32)
-        pv = pv.reshape(B, Tq, H, D)
+        pv = pv.reshape(B, Tq, H, v.shape[-1])
     o_new = o * corr.transpose(0, 2, 1)[..., None] + pv
     return m_new, l_new, o_new
 
@@ -106,7 +106,7 @@ def blockwise_attend(q, k, v, m, l, o, q_start, k_start, causal: bool,
     # kv laid out block-major as scan xs: [nblk, B, blk, Hkv, D]
     # (nblk == 1 degenerates to a length-1 scan over the single tile)
     kb = k.reshape(B, nblk, blk, Hkv, D).swapaxes(0, 1)
-    vb = v.reshape(B, nblk, blk, Hkv, D).swapaxes(0, 1)
+    vb = v.reshape(B, nblk, blk, Hkv, v.shape[-1]).swapaxes(0, 1)
 
     def step(carry, xs):
         m, l, o = carry
@@ -130,7 +130,8 @@ def local_attention(q, k, v, causal: bool = True,
     recurrence expressed in XLA) is the portable fallback and the
     CPU-mesh test path.
 
-    q: ``[B, T, H, D]``; k/v: ``[B, Tk, Hkv, D]`` with ``Hkv | H`` (GQA).
+    q: ``[B, T, H, D]``; k/v: ``[B, Tk, Hkv, D]`` with ``Hkv | H`` (GQA);
+    v may be ``[B, Tk, Hkv, Dv]``, the result then ``[B, T, H, Dv]``.
     ``mask``: ``[T, 4]`` or ``[B, T, 4]`` key ranges per query row, in
     place of ``causal`` (the kernels skip the tiles they leave empty).
     """
@@ -155,6 +156,8 @@ def local_attention(q, k, v, causal: bool = True,
     m0 = zero_bht + NEG_INF
     l0 = zero_bht
     o0 = (q * 0).astype(jnp.float32) + opzero
+    if v.shape[-1] != q.shape[-1]:      # values of a width of their own
+        o0 = jnp.broadcast_to(o0[..., :1], q.shape[:3] + v.shape[-1:])
     m, l, o = blockwise_attend(q, k, v, m0, l0, o0, 0, 0, causal, scale,
                                block_size, mask)
     return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)

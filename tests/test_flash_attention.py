@@ -437,10 +437,10 @@ def test_packed_path_specs_and_counter(monkeypatch):
     assert grew == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
 
 
-def test_packed_kernels_lower_for_the_chip(monkeypatch):
-    """Mosaic takes the packed kernels at the benchmark's shape and at a
-    causal GQA one: compiled here for a v5e that is described, not
-    attached (interpret mode cannot see tiling or VMEM)."""
+def _described_chip(monkeypatch):
+    """The sharding of one chip of a v5e that is described, not attached,
+    with jax told its backend is a TPU: what the ``*_lower_for_the_chip``
+    tests compile for (interpret mode cannot see tiling or VMEM)."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     monkeypatch.setenv("TPU_LOG_DIR", "disabled")
@@ -450,7 +450,14 @@ def test_packed_kernels_lower_for_the_chip(monkeypatch):
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no TPU topology to compile for: {e}")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_packed_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the packed kernels at the benchmark's shape and at a
+    causal GQA one: compiled here for a v5e that is described, not
+    attached (interpret mode cannot see tiling or VMEM)."""
+    one_chip = _described_chip(monkeypatch)
     for (B, T, H, Hkv, D), causal in (((32, 128, 12, 12, 64), False),
                                       ((2, 256, 8, 2, 128), True)):
         assert fa._pack(B, H, Hkv, T, T, D, 2) != (1, 1)
@@ -694,16 +701,7 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
     batch 2), and Llama-3-8B's heads (32 over 8) through ``causal=True``
     alone, where a ``dkv`` holding ``g x T x D`` was refused: compiled
     here for a v5e that is described, not attached."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"no TPU topology to compile for: {e}")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
+    one_chip = _described_chip(monkeypatch)
     q, k = (jax.ShapeDtypeStruct((B, 8192, h, 128), jnp.bfloat16,
                                  sharding=one_chip) for h in (H, Hkv))
     ranges = _block_diffusion_ranges(4096, 4) if given else None
@@ -732,18 +730,9 @@ def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
     ids in scalar memory, a ring of copies from HBM), with no scatter
     left beside it.  Compiled here for a v5e that is described, not
     attached."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     from horovod_tpu.models import moe
     from horovod_tpu.ops import grouped_matmul as gm
-    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"no TPU topology to compile for: {e}")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
+    one_chip = _described_chip(monkeypatch)
     R, D, F, E = 24576, 2048, 768, 16
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
@@ -995,3 +984,51 @@ def test_layer_stack_counts_its_remat_policy(monkeypatch):
         ("full", "flash"): 1, ("dots", "flash"): 2}
     with pytest.raises(ValueError, match="remat_policy"):
         trace(remat_policy="some")
+
+
+# ------------------------- the hybrid trunk's kernels at published widths
+# (models/hybrid.py: differential attention through the masked kernels with
+# values twice as wide as queries and keys, and ops/selective_scan.py's two
+# kernels; their other tests are tests/test_hybrid.py and
+# tests/test_selective_scan.py.  Here for the same reason as the grouped
+# products': one file describes the chip.)
+
+@pytest.mark.parametrize("kind", ["window", "causal"])
+def test_differential_attentions_kernels_lower_for_the_chip(kind, monkeypatch):
+    """Mosaic takes the three masked kernels at the benchmark's
+    phi4-mini-flash cell: 8,192 positions, 20 first heads of the query
+    pairs over 10 of the key pairs at head_dim 64, the pairs' values 128
+    wide, under the window of 512 and under the causal ranges."""
+    one_chip = _described_chip(monkeypatch)
+    T = 8192
+    sds = lambda h, d: jax.ShapeDtypeStruct((1, T, h, d), jnp.bfloat16,
+                                            sharding=one_chip)
+    q, k, v = sds(20, 64), sds(10, 64), sds(10, 128)
+    ranges = fa.window_ranges(T, 512) if kind == "window" else \
+        fa.causal_ranges(T)
+    assert fa.supported(q, k, v, True, ranges)
+    text = jax.jit(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, mask=ranges).astype(jnp.float32).sum(),
+        (0, 1, 2))(q, k, v)).lower(q, k, v).compile().as_text()
+    for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert name in text
+
+
+def test_selective_scan_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the selective scan forward and backward at the
+    benchmark's phi4-mini-flash cell: 8,192 positions of 5,120 channels
+    of 16 states, bf16 ``xs``, ``B`` and ``C`` beside a float32 step."""
+    from horovod_tpu.ops import selective_scan as ss
+    one_chip = _described_chip(monkeypatch)
+    T, Ch, N = 8192, 5120, 16
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    operands = (sds((1, T, Ch), jnp.bfloat16), sds((1, T, Ch), jnp.float32),
+                sds((Ch, N), jnp.float32), sds((1, T, N), jnp.bfloat16),
+                sds((1, T, N), jnp.bfloat16), sds((Ch,), jnp.float32))
+    assert ss.supported(*operands)
+    text = jax.jit(jax.grad(
+        lambda *a: ss.selective_scan(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(6)))).lower(*operands).compile().as_text()
+    assert "hvd_ssm_scan_fwd" in text and "hvd_ssm_scan_bwd" in text

@@ -1,0 +1,158 @@
+"""models/hybrid.py: differential attention in its three forms against
+its dense formula (through the masked flash kernels in interpret mode and
+through the XLA path) and a trunk of several kinds with runs of equal
+layers.  (The llama and SDAR programs' lowered text, which the new kinds
+leave as it was: tests/benchmark/test_bench_phi4flash.py.)"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu import metrics
+from horovod_tpu.models import hybrid, llama
+from horovod_tpu.ops import flash_attention as fa
+
+H, HKV, DH = 8, 4, 64          # groups of two, head_dim 64, values of 128
+
+
+def _cfg(**kw):
+    base = dict(vocab_size=256, d_model=H * DH, n_layers=1, n_heads=H,
+                n_kv_heads=HKV, d_ff=128, norm_eps=1e-5, dtype=jnp.float32,
+                layer_kinds=("full",), sliding_window=100, ssm_inner=128,
+                ssm_dt_rank=8, remat=False)
+    return llama.LlamaConfig(**{**base, **kw})
+
+
+def _dense(q, k, v, lp, lam0, live):
+    """The formula, written out: two dense softmaxes a head pair."""
+    B, T, _, _ = q.shape
+    pairs = lambda x: (x[:, :, 0::2], x[:, :, 1::2])
+    (q1, q2), (k1, k2), (va, vb) = pairs(q), pairs(k), pairs(v)
+    vv = jnp.concatenate([va, vb], -1)                  # [B, Tk, Hkv/2, 2Dh]
+    rep = lambda x: jnp.repeat(x, 2, axis=2)            # two query pairs a kv pair
+
+    def soft(q_, k_):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_, rep(k_)) / 8.0
+        return jax.nn.softmax(jnp.where(live, s, -jnp.inf), -1)
+
+    lam = (jnp.exp(lp["lambda_q1"] @ lp["lambda_k1"])
+           - jnp.exp(lp["lambda_q2"] @ lp["lambda_k2"]) + lam0)
+    o = jnp.einsum("bhqk,bkhd->bqhd", soft(q1, k1) - lam * soft(q2, k2), rep(vv))
+    o = o / jnp.sqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-5)
+    o = o * lp["subln"] * (1 - lam0)
+    return o.reshape(B, T, H * DH) @ lp["wo"]
+
+
+@pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+@pytest.mark.parametrize("kind", ["window", "full", "cross"])
+def test_differential_attention_follows_its_dense_formula(kind, interpret,
+                                                          monkeypatch):
+    """Window, causal and cross; forward and every gradient; through
+    ``hvd_flash_fwd``/``dq``/``dkv`` with values twice as wide as queries
+    and keys, and through the XLA path."""
+    monkeypatch.setattr(fa, "_INTERPRET", interpret)
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    cfg, T, B = _cfg(layer_kinds=(kind if kind != "cross" else "full",)), 256, 2
+    ks = jax.random.split(jax.random.key(4), 10)
+    q = jax.random.normal(ks[0], (B, T, H, DH))
+    k, v = (jax.random.normal(ks[i], (B, T, HKV, DH)) for i in (1, 2))
+    lp = {n: jax.random.normal(ks[3 + i], (DH,)) * 0.3 for i, n in enumerate(
+        ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))}
+    lp["subln"] = 1 + 0.1 * jax.random.normal(ks[7], (2 * DH,))
+    lp["wo"] = jax.random.normal(ks[8], (H * DH, H * DH)) * (H * DH) ** -0.5
+    w = jax.random.normal(ks[9], (B, T, H * DH))
+    lam0 = hybrid.lambda_init(17)
+    ranges = hybrid.key_ranges(kind, T, cfg)
+    live = fa.dense_mask(ranges, T)
+    before = metrics.registry().to_dict().get("hvd_flash_kernel_total", {})
+    got = jax.value_and_grad(lambda q, k, v, lp: (hybrid._diff_attention(
+        q, k, v, lp, lam0, ranges, cfg) * w).sum(), (0, 1, 2, 3))(q, k, v, lp)
+    want = jax.value_and_grad(lambda q, k, v, lp: (_dense(
+        q, k, v, lp, lam0, live) * w).sum(), (0, 1, 2, 3))(q, k, v, lp)
+    scale = float(jnp.abs(want[0]))
+    assert abs(float(got[0] - want[0])) < 1e-4 * max(scale, 1.0)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()),
+                                   rtol=2e-3)
+    if interpret and metrics.ACTIVE:       # two calls a layer, each of three
+        after = metrics.registry().to_dict()["hvd_flash_kernel_total"]
+        count = lambda fam: {s["labels"]["kernel"]: s["value"] for s in
+                             fam.get("series", []) if s["labels"]["path"] == "masked"}
+        grew = {k: n - count(before).get(k, 0) for k, n in count(after).items()}
+        assert grew == {"fwd": 2, "dq": 2, "dkv": 2}
+
+
+def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    q = jnp.zeros((1, 128, 2, 64))
+    k = jnp.zeros((1, 128, 1, 64))
+    assert fa._refusal(q, k, jnp.zeros((1, 128, 1, 128))) is None
+    assert "width" in fa._refusal(q, k, jnp.zeros((1, 128, 1, 96))) or \
+        "multiple of 64" in fa._refusal(q, k, jnp.zeros((1, 128, 1, 96)))
+    assert "v must match k" in fa._refusal(q, k, jnp.zeros((1, 256, 1, 64)))
+    # values of a width of their own never take the packed path
+    out = fa.flash_attention(q, k, jnp.ones((1, 128, 1, 128)), causal=True)
+    assert out.shape == (1, 128, 2, 128)
+    np.testing.assert_allclose(out, 1.0, rtol=1e-6)
+
+
+def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
+    """Runs of equal layers are one scan; the emitting mamba and full
+    layers are runs of their own; the stack equals the layers applied one
+    by one."""
+    kinds = ("mamba", "mamba", "window", "window", "mamba", "full", "gmu",
+             "gmu", "cross", "cross")
+    cfg = _cfg(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, n_layers=10,
+               layer_kinds=kinds, layer_ids=(0, 2, 3, 5, 16, 17, 18, 20, 21, 23),
+               sliding_window=16)
+    assert [(r[0], r[1], len(r[2]), r[3]) for r in hybrid._runs(cfg)] == [
+        ("mamba", 0, 2, False), ("window", 0, 2, False), ("mamba", 2, 1, True),
+        ("full", 0, 1, True), ("gmu", 0, 2, False), ("cross", 0, 2, False)]
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert llama.count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    h = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    before = metrics.registry().to_dict().get("hvd_layer_kind_total", {})
+    got = hybrid.layer_stack(h, params["layers"], cfg)
+    if metrics.ACTIVE:
+        count = lambda fam: {s["labels"]["kind"]: s["value"]
+                             for s in fam.get("series", [])}
+        after = count(metrics.registry().to_dict()["hvd_layer_kind_total"])
+        assert {k: n - count(before).get(k, 0) for k, n in after.items()} == {
+            "mamba": 3, "window": 2, "full": 1, "gmu": 2, "cross": 2}
+    want, m, kv, seen = h, None, None, {}
+    for i, kind in enumerate(kinds):
+        at = seen.get(kind, 0)
+        seen[kind] = at + 1
+        lp = jax.tree_util.tree_map(lambda w_: w_[at], params["layers"][kind])
+        memory = m if kind == "gmu" else kv if kind == "cross" else None
+        want, out = hybrid._layer(kind, i in (4, 5), cfg)(
+            want, lp, hybrid.lambda_init(cfg.layer_ids[i]), memory)
+        m, kv = (out, kv) if i == 4 else (m, out) if i == 5 else (m, kv)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # remat changes no number
+    remat = hybrid.layer_stack(
+        h, params["layers"], _cfg(**{**vars(cfg), "remat": True}),
+        llama.remat_policy("full"))
+    np.testing.assert_allclose(remat, got, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kinds,error", [
+    (("gmu", "mamba"), "needs a mamba"), (("cross", "full"), "needs a full"),
+    (("mamba", "attention"), "layer_kinds must name"), (("mamba",), "n_layers")])
+def test_kinds_that_make_no_trunk_are_refused(kinds, error):
+    with pytest.raises(ValueError, match=error):
+        hybrid.check(_cfg(n_layers=2, layer_kinds=kinds))
+
+
+def test_trunk_of_kinds_takes_no_positions_mask_or_model_parallel_axis():
+    cfg = _cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    for kw in (dict(positions=tokens), dict(mask=fa.causal_ranges(128))):
+        with pytest.raises(NotImplementedError, match="no positions"):
+            llama.hidden(params, tokens, cfg, llama.ParallelSpec(), **kw)
+    with pytest.raises(NotImplementedError, match="data parallelism"):
+        llama.hidden(params, tokens, cfg, llama.ParallelSpec(tp_axis="tp"))
