@@ -11,8 +11,7 @@ them.
 
 Public layout contract (matches :mod:`horovod_tpu.parallel.ring_attention`):
   q: ``[B, T, H, D]``   k/v: ``[B, Tk, Hkv, D]`` with ``Hkv | H`` (GQA —
-  query head h reads kv head ``h // (H//Hkv)``; the kernels run in
-  ``[B, H, T, D]`` layout internally for TPU tiling).  The values may
+  query head h reads kv head ``h // (H//Hkv)``).  The values may
   have a width of their own, ``v [B, Tk, Hkv, Dv]`` (differential
   attention's 128 beside 64-wide queries and keys): such a call takes the
   masked path and ``out`` is ``Dv`` wide; at ``Dv = D`` every kernel is
@@ -40,7 +39,7 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
 
 * **masked** — everything else: several blocks of ``_BLOCK`` positions,
   or one that does not pack (``hvd_flash_fwd`` / ``hvd_flash_dq`` /
-  ``hvd_flash_dkv`` in ``[B, H, T, D]`` layout), under a mask that is data: for every query
+  ``hvd_flash_dkv``), under a mask that is data: for every query
   row two half-open ranges of key positions, ``[T, 4]`` or ``[B, T, 4]``
   int32 ``(lo1, hi1, lo2, hi2)``; a key is seen when it lies in either.
   Causal, sliding-window, packed-document and block-diffusion masks are
@@ -66,16 +65,26 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   tile, query tile) pair and takes the query tiles of its GQA group one
   at a time, so it holds ``g x bq x D`` of ``q`` and ``do``, not
   ``g x T x D``: 8 query heads a kv head at 8,192 positions run.  Every
-  query row has to see at least one key.
+  query row has to see at least one key.  Two layouts, chosen from the
+  widths where the call is built (no knob): where ``D`` and ``Dv`` are
+  whole lane tiles (multiples of 128) the kernels read ``q``, ``k``,
+  ``v``, ``do`` and write ``out``, ``dq``, ``dk``, ``dv`` as the caller
+  holds them, ``[B, T, H*D]`` (a free reshape), a head being ``D`` lanes
+  at a static, aligned offset of its block (``rows``: nothing is
+  transposed around the call, forward or backward); a 64-wide head is
+  half a tile and cannot be a block of ``[B, T, H*64]``, so such a call
+  is transposed to ``[B, H, T, D]`` around the kernels (``heads``).  One
+  set of kernel bodies; the block specs and :func:`_head` differ.
 
 Residuals are named.  Both paths' ``custom_vjp`` keep ``(q, k, v, out,
 lse)`` for the backward kernels, and the forward rules pass ``out`` and
 ``lse`` through ``jax.ad_checkpoint.checkpoint_name`` as ``OUT_NAME``
 (``hvd_flash_out``) and ``LSE_NAME`` (``hvd_flash_lse``), in the kernels'
-own layout (``[B, H, T, D]`` and ``[B, H, nq, bq]``; packed: ``[B, T,
-H*D]`` and ``[B, H, 1, T]``), ``out`` in the operands' dtype and ``lse``
-in float32 as the kernel wrote them.  A caller that rematerializes
-around the op and saves these names
+own layout (masked: ``[B, T, H*Dv]``, what the caller's next product
+reads, or on the ``heads`` route ``[B, H, T, Dv]``, and ``[B, H, nq,
+bq]``; packed: ``[B, T, H*D]`` and ``[B, H, 1, T]``), ``out`` in the
+operands' dtype and ``lse`` in float32 as the kernel wrote them.  A
+caller that rematerializes around the op and saves these names
 (``jax.checkpoint_policies.save_only_these_names``, as
 ``models/llama.py::remat_policy`` does for the decoder trunk's layer
 stack and for ``models/bert.py``'s encoder) gets the backward's
@@ -84,8 +93,9 @@ bytes a call; one that does not reruns the forward kernel in its
 backward pass to make them again, and the names change nothing in its
 program.
 
-``hvd_flash_kernel_total{kernel, path}`` counts the kernels built, once
-per traced call site, so a program says which path its shapes took;
+``hvd_flash_kernel_total{kernel, path, layout}`` counts the kernels
+built, once per traced call site, so a program says which path and which
+layout (``rows`` or ``heads``; packed calls are ``rows``) its shapes took;
 ``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
 (``live`` = full, ``masked`` = mixed, ``skipped`` = dead) where the call
 is built, when the mask is known there (a numpy array).
@@ -133,8 +143,10 @@ _m_kernels = _metrics.counter(
     "hvd_flash_kernel_total",
     "Flash-attention Pallas kernels built, one per traced call site; "
     "path is packed or masked (tiled stopped occurring: several blocks "
-    "without mask= count as masked)",
-    labels=("kernel", "path"))
+    "without mask= count as masked); layout is rows where the kernels "
+    "read and write the caller's [B, T, H*D], heads where the call is "
+    "transposed to [B, H, T, D] around them",
+    labels=("kernel", "path", "layout"))
 
 
 _m_tiles = _metrics.counter(
@@ -145,9 +157,10 @@ _m_tiles = _metrics.counter(
 _TILE_STATES = ("skipped", "masked", "live")     # class 0, 1, 2
 
 
-def _count(kernel: str, path: str) -> None:
+def _count(kernel: str, path: str, rows: bool = True) -> None:
     if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path)
+        _m_kernels.inc(kernel=kernel, path=path,
+                       layout="rows" if rows else "heads")
 
 
 def _count_tiles(kernel: str, classes) -> None:
@@ -209,6 +222,16 @@ def _pack(B, H, Hkv, T, Tk, D, itemsize):
             return max(b for b in range(1, B + 1)
                        if B % b == 0 and fits(b, hb)), hb
     return 1, 1
+
+
+def _row_widths(D, Dv):
+    """``(D, Dv)`` where the masked kernels read the caller's ``[B, T,
+    H*D]``: heads that are whole lane tiles are blocks of it at aligned
+    lane offsets.  None where a head is half a tile (64): that call is
+    transposed to ``[B, H, T, D]`` around the kernels (the packed path's
+    zeroed lanes need query and key heads at one lane offset, which the
+    pairs of a GQA group are not)."""
+    return (D, Dv) if D % _LANES == 0 and Dv % _LANES == 0 else None
 
 
 def _sds(shape, dtype, *operands):
@@ -599,6 +622,16 @@ def _lanes(x, n):
     return jnp.tile(x, (1, n // _LANES))
 
 
+def _head(ref, h, d, at=slice(None)):
+    """Index of positions ``at`` of head ``h`` in a block of heads ``d``
+    wide: ``[1, heads, positions, d]`` of the transposed layout, or
+    ``[1, positions, heads * d]`` of the caller's, where a head is ``d``
+    lanes from a 128 boundary, known where the kernel is built."""
+    if len(ref.shape) == 4:
+        return 0, h, at
+    return 0, at, slice(h * d, (h + 1) * d)
+
+
 def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
                  o_ref, lse_ref, m_ref, l_ref, acc_ref, rb_ref, *, scale, bk,
                  nq, nk, per_batch):
@@ -611,8 +644,8 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     meet once, after the last tile; ``acc_ref [hb, bq, D]``.  ``rb_ref
     [4, bq, 128]`` holds the rows' ranges spread over the lanes once a
     step, for every head and mixed tile of it."""
-    hb, bq = q_ref.shape[1:3]
-    Dv = acc_ref.shape[-1]
+    hb, bq, Dv = acc_ref.shape
+    D = k_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -624,9 +657,9 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     def step(n, carry, masked):
         j = idx_ref[row * nk + n]
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-        kj, vj = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+        kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
         for h in range(hb):
-            s = _scores(q_ref[0, h], kj, scale, False)
+            s = _scores(q_ref[_head(q_ref, h, D)], kj, scale, False)
             if masked:           # shift the tile's columns, not the ranges
                 cols = lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bk
                 s = jnp.where(_in_ranges(cols, *(_lanes(rb_ref[c], bk)
@@ -646,7 +679,7 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     _two_loops(nfull_ref[row], nlive_ref[row], step, 0)
     for h in range(hb):
         l = l_ref[h].sum(axis=-1, keepdims=True)
-        o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+        o_ref[_head(o_ref, h, Dv)] = (acc_ref[h] / l).astype(o_ref.dtype)
         # along the lanes for the row of lse: a transpose, not bq shuffles
         lse_ref[0, h, pl.ds(i, 1), :] = (m_ref[h] + jnp.log(l)).T[:1]
 
@@ -654,10 +687,10 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
 def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, r_ref, dq_ref, *, scale, bk, nq, nk,
                 per_batch):
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+    (bq, D), Dv = q_ref.shape[-2:], v_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
-    q, do = q_ref[0, 0], do_ref[0, 0]
+    q, do = q_ref[_head(q_ref, 0, D)], do_ref[_head(do_ref, 0, Dv)]
     rng = r_ref[0]
     lse = lse_ref[0, 0, i, :].reshape(bq, 1)
     delta = delta_ref[0, 0, i, :].reshape(bq, 1)
@@ -665,7 +698,7 @@ def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
     def step(n, dq_acc, masked):
         j = idx_ref[row * nk + n]
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-        kj, vj = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+        kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
         p = jnp.exp(_masked_scores(q, kj, rng, j * bk, scale, masked) - lse)
         dp = lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -675,13 +708,13 @@ def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
 
     dq = _two_loops(nfull_ref[row], nlive_ref[row], step,
                     jnp.zeros((bq, D), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    dq_ref[_head(dq_ref, 0, D)] = dq.astype(dq_ref.dtype)
 
 
 def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq, P, g,
                  per_batch):
-    bk, D = k_ref.shape[2], k_ref.shape[3]
+    (bk, D), Dv = k_ref.shape[-2:], v_ref.shape[-1]
     at = ((pl.program_id(0) * P if per_batch else 0) + pl.program_id(2)) * 4
     j, i, cls, flags = (tbl_ref[at + c] for c in range(4))
 
@@ -691,11 +724,11 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def pair(masked):
-        kb, vb = k_ref[0, 0], v_ref[0, 0]
+        kb, vb = k_ref[_head(k_ref, 0, D)], v_ref[_head(v_ref, 0, Dv)]
         rng = r_ref[0]
         dk = dv = None
         for hq in range(g):           # static: the kv head's query heads
-            qi, doi = q_ref[0, hq], do_ref[0, hq]
+            qi, doi = q_ref[_head(q_ref, hq, D)], do_ref[_head(do_ref, hq, Dv)]
             lse = lse_ref[0, hq, i, :].reshape(bq, 1)
             delta = delta_ref[0, hq, i, :].reshape(bq, 1)
             p = jnp.exp(_masked_scores(qi, kb, rng, j * bk, scale, masked)
@@ -717,8 +750,8 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(flags >= 2)
     def _():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[_head(dk_ref, 0, D)] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[_head(dv_ref, 0, Dv)] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _mask_plan(mask, bq, bk, Tk):
@@ -739,16 +772,34 @@ def _vmem(*block_bytes, scratch=0):
         2 * sum(block_bytes) + scratch + 24 * 1024 * 1024))
 
 
-def _row_specs(bq, D, Dv, Tk, nq, g, bm, hb=1):
+def _heads_shape(rows, B, heads, n, d):
+    """``B`` batch rows of ``heads`` heads ``d`` wide by ``n`` positions:
+    ``[B, heads, n, d]``, or with ``rows`` the caller's ``[B, n, heads *
+    d]``."""
+    return (B, n, heads * d) if rows else (B, heads, n, d)
+
+
+def _heads_block(rows, heads, n, d, index):
+    """Block of such an array, one batch row, at ``index(*grid) -> (batch,
+    block of heads, block of positions)``; with ``rows`` the heads are
+    the block's lanes."""
+    def at(*grid):
+        b, h, i = index(*grid)
+        return (b, i, h) if rows else (b, h, i, 0)
+
+    return pl.BlockSpec(_heads_shape(rows, 1, heads, n, d), at)
+
+
+def _row_specs(rows, bq, D, Dv, Tk, nq, g, bm, hb=1):
     """Block specs of the kernels that walk a query tile's key tiles
     (grid ``(B, H // hb, nq)``, three tables prefetched): a query tile of
     ``hb`` heads ``D`` wide (q, dq) and ``Dv`` wide (out, do), their kv
     head's whole keys and whole values, the heads' row statistics, the
     tile's ranges."""
-    tile = lambda d: pl.BlockSpec((1, hb, bq, d),
-                                  lambda b, h, i, *_: (b, h, i, 0))
-    whole = lambda d: pl.BlockSpec(
-        (1, 1, Tk, d), lambda b, h, i, *_: (b, h * hb // g, 0, 0))
+    tile = lambda d: _heads_block(rows, hb, bq, d,
+                                  lambda b, h, i, *_: (b, h, i))
+    whole = lambda d: _heads_block(rows, 1, Tk, d,
+                                   lambda b, h, i, *_: (b, h * hb // g, 0))
     stats = pl.BlockSpec((1, hb, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
     rng = pl.BlockSpec((1, bq, 4), lambda b, h, i, *_: (bm(b), i, 0))
     return tile(D), tile(Dv), whole(D), whole(Dv), stats, rng
@@ -784,19 +835,31 @@ def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize, Dv=None):
                if g % hb == 0 and (hb == 1 or fits(hb)))
 
 
-def _masked_fwd_bhtd(q, k, v, mask, scale):
+def _masked_dims(q, k, v, widths):
+    """``(B, H, Hkv, T, Tk, D, Dv)`` of a masked call's operands: ``[B, H,
+    T, D]``, or with ``widths = (D, Dv)`` the caller's ``[B, T, H * D]``."""
+    if widths is None:
+        (B, H, T, D), (_, Hkv, Tk, Dv) = q.shape, v.shape
+    else:
+        (B, T, HD), (_, Tk, HkvDv), (D, Dv) = q.shape, v.shape, widths
+        H, Hkv = HD // D, HkvDv // Dv
+    return B, H, Hkv, T, Tk, D, Dv
+
+
+def _masked_fwd(q, k, v, mask, scale, widths):
     """q [B,H,T,D], k [B,Hkv,Tk,D], v [B,Hkv,Tk,Dv] → (out [B,H,T,Dv],
-    lse [B,H,nq,bq])."""
-    B, H, T, D = q.shape
-    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    lse [B,H,nq,bq]); with ``widths = (D, Dv)``, q [B,T,H*D], k
+    [B,Tk,Hkv*D], v [B,Tk,Hkv*Dv] → out [B,T,H*Dv] and the same lse."""
+    B, H, Hkv, T, Tk, D, Dv = _masked_dims(q, k, v, widths)
+    rows = widths is not None
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
     hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize, Dv)
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, otile, whole, vwhole, stats, rng = _row_specs(bq, D, Dv, Tk, nq,
-                                                        g, bm, hb)
-    _count("fwd", "masked")
+    tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
+                                                        nq, g, bm, hb)
+    _count("fwd", "masked", rows)
     _count_tiles("fwd", classes)
     blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
                                          q.dtype.itemsize, Dv)
@@ -811,7 +874,7 @@ def _masked_fwd_bhtd(q, k, v, mask, scale):
                             pltpu.VMEM((hb, bq, Dv), jnp.float32),
                             pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
         out_shape=[
-            _sds((B, H, T, Dv), q.dtype, q, k, v),
+            _sds(_heads_shape(rows, B, H, T, Dv), q.dtype, q, k, v),
             _sds((B, H, nq, bq), jnp.float32, q, k, v),
         ],
         compiler_params=_vmem(blocks, scratch=scratch),
@@ -820,28 +883,31 @@ def _masked_fwd_bhtd(q, k, v, mask, scale):
     )(*_row_tables(classes), q, k, v, ranges)
 
 
-def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
-    B, H, T, D = q.shape
-    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
+    """``(dq, dk, dv)`` in the operands' own layout (:func:`_masked_fwd`)."""
+    B, H, Hkv, T, Tk, D, Dv = _masked_dims(q, k, v, widths)
+    rows = widths is not None
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, otile, whole, vwhole, stats, rng = _row_specs(bq, D, Dv, Tk, nq,
-                                                        g, bm)
+    tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
+                                                        nq, g, bm)
     item = q.dtype.itemsize
 
     # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
     # When the caller differentiates through the exposed lse (ring-step
     # merging), its cotangent folds in exactly here: dlse/ds = p, so
     # ds = p·(dp − delta) + p·dlse = p·(dp − (delta − dlse)).
-    delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
-                       out.astype(jnp.float32)).reshape(B, H, nq, bq)
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
+    lay = "bthd" if rows else "bhtd"     # rows: [B, T, H*Dv] by its heads
+    heads = (B, T, H, Dv) if rows else (B, H, T, Dv)
+    delta = jnp.einsum(f"{lay},{lay}->bht",
+                       do.astype(jnp.float32).reshape(heads),
+                       out.astype(jnp.float32).reshape(heads))
+    delta = delta.reshape(B, H, nq, bq) - dlse.astype(jnp.float32)
 
     for kernel in ("dq", "dkv"):
-        _count(kernel, "masked")
+        _count(kernel, "masked", rows)
         _count_tiles(kernel, classes)
     dq = pl.pallas_call(
         functools.partial(_mdq_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
@@ -850,7 +916,7 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
             num_scalar_prefetch=3, grid=(B, H, nq),
             in_specs=[tile, whole, vwhole, otile, stats, stats, rng],
             out_specs=tile),
-        out_shape=_sds((B, H, T, D), q.dtype, q, k, v, do),
+        out_shape=_sds(_heads_shape(rows, B, H, T, D), q.dtype, q, k, v, do),
         compiler_params=_vmem(Tk * (D + Dv) * item,
                               bq * (2 * D + Dv) * item, bq * _LANES * 4),
         interpret=_INTERPRET,
@@ -862,10 +928,10 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
     # [.. + 1]
     at = (lambda b, p: (b * P + p) * 4) if per_batch else (
         lambda b, p: p * 4)
-    q_blk = lambda d: pl.BlockSpec(
-        (1, g, bq, d), lambda b, c, p, t: (b, c, t[at(b, p) + 1], 0))
-    kv_blk = lambda d: pl.BlockSpec(
-        (1, 1, bk, d), lambda b, c, p, t: (b, c, t[at(b, p)], 0))
+    q_blk = lambda d: _heads_block(
+        rows, g, bq, d, lambda b, c, p, t: (b, c, t[at(b, p) + 1]))
+    kv_blk = lambda d: _heads_block(
+        rows, 1, bk, d, lambda b, c, p, t: (b, c, t[at(b, p)]))
     row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_mdkv_kernel, scale=scale, bq=bq, P=P, g=g,
@@ -882,8 +948,8 @@ def _masked_bwd_bhtd(q, k, v, out, lse, do, mask, scale, dlse=None):
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, Dv), jnp.float32)]),
         out_shape=[
-            _sds((B, Hkv, Tk, D), k.dtype, q, k, v, do),
-            _sds((B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
+            _sds(_heads_shape(rows, B, Hkv, Tk, D), k.dtype, q, k, v, do),
+            _sds(_heads_shape(rows, B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
         ],
         compiler_params=_vmem(g * bq * (D + Dv) * item,
                               2 * bk * (D + Dv) * item,
@@ -911,25 +977,27 @@ class _StaticMask:
                 and bool((self.ranges == other.ranges).all()))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _masked_attention_lse(q, k, v, mask, static, scale):
-    """``mask``: a traced mask, or None with ``static`` a _StaticMask."""
-    return _masked_fwd_bhtd(q, k, v, static.ranges if static else mask,
-                            scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _masked_attention_lse(q, k, v, mask, static, scale, widths):
+    """``mask``: a traced mask, or None with ``static`` a _StaticMask;
+    ``widths``: None, or ``(D, Dv)`` of operands in the caller's layout
+    (:func:`_masked_fwd`)."""
+    return _masked_fwd(q, k, v, static.ranges if static else mask, scale,
+                       widths)
 
 
-def _masked_attention_lse_fwd(q, k, v, mask, static, scale):
+def _masked_attention_lse_fwd(q, k, v, mask, static, scale, widths):
     out, lse = _named_residuals(
-        *_masked_attention_lse(q, k, v, mask, static, scale))
+        *_masked_attention_lse(q, k, v, mask, static, scale, widths))
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _masked_attention_lse_bwd(static, scale, res, cotangents):
+def _masked_attention_lse_bwd(static, scale, widths, res, cotangents):
     do, dlse = cotangents
     q, k, v, mask, out, lse = res
-    dq, dk, dv = _masked_bwd_bhtd(
+    dq, dk, dv = _masked_bwd(
         q, k, v, out, lse, do, static.ranges if static else mask, scale,
-        dlse=dlse)
+        dlse, widths)
     return dq, dk, dv, None
 
 
@@ -939,7 +1007,8 @@ _masked_attention_lse.defvjp(_masked_attention_lse_fwd,
 
 # ------------------------------------------------------------- public op
 # The GQA group in _mdkv_kernel's q block assumes query heads of one kv
-# group are contiguous (head h ↔ kv head h // g), matching
+# group are contiguous (head h ↔ kv head h // g; in the caller's layout
+# the group is g * D adjacent lanes), matching
 # jnp.repeat(k, g, axis=head) semantics used across the framework.
 # Each custom_vjp serves both entry points: the plain path is the lse path
 # with a zero lse cotangent (folded into delta as a cheap subtract).
@@ -962,10 +1031,17 @@ def _attention_lse(q, k, v, causal, sm_scale, mask=None):
             return out.reshape(B, T, H, D), lse.reshape(B, H, T)
         mask = causal_ranges(T) if causal else full_ranges(T, Tk)
     static = _StaticMask(mask) if isinstance(mask, np.ndarray) else None
-    out, lse = _masked_attention_lse(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), None if static else mask, static, scale)
-    return out.transpose(0, 2, 1, 3), lse.reshape(B, H, T)
+    Dv = v.shape[-1]
+    widths = _row_widths(D, Dv)
+    if widths:
+        operands = (q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
+                    v.reshape(B, Tk, Hkv * Dv))
+    else:
+        operands = tuple(x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = _masked_attention_lse(*operands, None if static else mask,
+                                     static, scale, widths)
+    out = out.reshape(B, T, H, Dv) if widths else out.transpose(0, 2, 1, 3)
+    return out, lse.reshape(B, H, T)
 
 
 def flash_attention(q, k, v, causal: bool = True,

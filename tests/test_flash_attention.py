@@ -338,8 +338,16 @@ def _pallas_calls(fn, *args):
 def _kernel_counts():
     from horovod_tpu import metrics
     family = metrics.registry().to_dict().get("hvd_flash_kernel_total", {})
-    return {(s["labels"]["kernel"], s["labels"]["path"]): s["value"]
+    return {(s["labels"]["kernel"], s["labels"]["path"],
+             s["labels"]["layout"]): s["value"]
             for s in family.get("series", [])}
+
+
+def _grew(before):
+    """The series of ``hvd_flash_kernel_total`` that moved since
+    ``before = _kernel_counts()``."""
+    after = _kernel_counts()
+    return {key for key in after if after[key] != before.get(key, 0)}
 
 
 def _grad_all(q, k, v, causal):
@@ -384,7 +392,8 @@ def test_causal_over_several_blocks_builds_the_masked_kernels(monkeypatch):
     grids and blocks as ``test_masked_forward_specs`` pins them (both of a
     group's two heads a forward step; ``dkv`` one step a live pair of
     tiles, holding one query tile of the group), on the tile classes of
-    :func:`causal_ranges`."""
+    :func:`causal_ranges`; at ``head_dim`` 128 the blocks are cut from the
+    caller's ``[B, T, H*D]``, a head a lane block."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv, D = 1, 2048, 4, 2, 128
     bq = bk = 512
@@ -395,8 +404,8 @@ def test_causal_over_several_blocks_builds_the_masked_kernels(monkeypatch):
     q, k, v = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16)
                for h in (H, Hkv, Hkv))
     calls = _pallas_calls(lambda q, k, v: _grad_all(q, k, v, True), q, k, v)
-    qb, kvb, row, rng = (1, 1, bq, D), (1, 1, T, D), (1, 1, nq, bq), (1, bq, 4)
-    grp, tile, rows = (1, g, bq, D), (1, 1, bk, D), (1, g, nq, bq)
+    qb, kvb, row, rng = (1, bq, D), (1, T, D), (1, 1, nq, bq), (1, bq, 4)
+    grp, tile, rows = (1, bq, g * D), (1, bk, D), (1, g, nq, bq)
     assert calls == [
         ("hvd_flash_fwd", (B, H // g, nq), [grp, kvb, kvb, rng, grp, rows]),
         ("hvd_flash_dq", (B, H, nq), [qb, kvb, kvb, qb, row, row, rng, qb]),
@@ -425,16 +434,18 @@ def test_packed_path_specs_and_counter(monkeypatch):
     jaxpr = str(jax.make_jaxpr(
         lambda q, k, v: _grad_all(q, k, v, False))(q, q, q))
     assert "transpose[permutation=(0, 2, 1, 3)]" not in jaxpr
-    after = _kernel_counts()
-    grew = {key: after[key] - before.get(key, 0) for key in after
-            if after[key] != before.get(key, 0)}
-    assert set(grew) == {("fwd", "packed"), ("bwd", "packed")}
+    assert _grew(before) == {("fwd", "packed", "rows"),
+                             ("bwd", "packed", "rows")}
 
-    long = jax.ShapeDtypeStruct((1, 1024, 2, 128), jnp.bfloat16)
-    jax.make_jaxpr(lambda q, k, v: _grad_all(q, k, v, True))(long, long, long)
-    later = _kernel_counts()
-    grew = {key for key in later if later[key] != after.get(key, 0)}
-    assert grew == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
+    # several blocks: the masked kernels, at head_dim 128 on the caller's
+    # layout too, at 64 (half a lane tile a head) transposed around them
+    for D, layout in ((128, "rows"), (64, "heads")):
+        before = _kernel_counts()
+        long = jax.ShapeDtypeStruct((1, 1024, 2, D), jnp.bfloat16)
+        jax.make_jaxpr(lambda q, k, v: _grad_all(q, k, v, True))(
+            long, long, long)
+        assert _grew(before) == {(kernel, "masked", layout)
+                                 for kernel in ("fwd", "dq", "dkv")}
 
 
 def _described_chip(monkeypatch):
@@ -515,6 +526,7 @@ HEAD_CASES = {
     "g8-one-head": ((8, 1, 64), 1 << 20, 1),      # not even two fit
     "g2-d128": ((4, 2, 128), None, 2),
     "g1-d128": ((2, 2, 128), None, 1),
+    "g8-d128": ((8, 1, 128), None, 8),
 }
 MASKED_CASES = (
     [(mask, per_batch, "g8-whole-group") for mask in sorted(MASKS)
@@ -532,10 +544,15 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
     """Forward (out and lse, which ``dq`` and ``dkv`` read) and all three
     gradients, the mask known where the call is built (numpy) or traced
     per batch row, a forward step taking a whole GQA group, a part of
-    one, or one head."""
+    one, or one head; at ``head_dim`` 64 transposed around the kernels
+    (``heads``), at 128 on the caller's layout (``rows``: groups of 1, 2
+    and 8)."""
+    from horovod_tpu import metrics
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_BLOCK", 128)
+    monkeypatch.setattr(metrics, "ACTIVE", True)
     (H, Hkv, D), budget, hb = HEAD_CASES[heads]
+    before = _kernel_counts()
     if budget is not None:
         monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
     B, T = 2, 512
@@ -567,6 +584,82 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
     for a, b, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
                                    err_msg=f"d{name}")
+    assert _grew(before) == {
+        (kernel, "masked", "rows" if D % 128 == 0 else "heads")
+        for kernel in ("fwd", "dq", "dkv")}
+
+
+def _masked_routes(q, k, v, mask, scale, w_out, w_lse):
+    """``(out, lse, dq, dk, dv)`` of the masked kernels on the caller's
+    layout (``rows``) and transposed around them (``heads``), each brought
+    back to ``[B, T, H, D]`` / ``[B, H, nq, bq]``."""
+    D, Dv = q.shape[3], v.shape[3]
+    static = fa._StaticMask(mask) if isinstance(mask, np.ndarray) else None
+
+    def run(widths, put, back):
+        (out, lse), vjp = jax.vjp(
+            lambda q, k, v: fa._masked_attention_lse(
+                q, k, v, None if static else mask, static, scale, widths),
+            put(q), put(k), put(v))
+        dq, dk, dv = vjp((put(w_out), w_lse))
+        return (back(out, Dv), lse, back(dq, D), back(dk, D), back(dv, Dv))
+
+    swap = lambda x, d=None: x.transpose(0, 2, 1, 3)
+    rows = run((D, Dv), lambda x: x.reshape(*x.shape[:2], -1),
+               lambda x, d: x.reshape(*x.shape[:2], -1, d))
+    return rows, run(None, swap, swap)
+
+
+@pytest.mark.parametrize("H,Hkv,Dv,per_batch", [
+    (4, 2, 128, False), (8, 1, 128, True), (2, 2, 256, False)],
+    ids=["g2", "g8-mask-per-row", "g1-values-256"])
+def test_rows_and_heads_routes_are_equal_to_the_bit(H, Hkv, Dv, per_batch,
+                                                    monkeypatch):
+    """One set of kernel bodies, two ways of building specs and slicing
+    refs: for equal inputs ``out``, ``lse``, ``dq``, ``dk``, ``dv`` (the
+    ``lse`` cotangent folded in) are the same bits on either route."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    B, T, D = 2, 512, 128
+    rng = np.random.RandomState(5)
+    q, k, v, w_out = (jnp.asarray(rng.randn(B, T, h, d), jnp.bfloat16)
+                      for h, d in ((H, D), (Hkv, D), (Hkv, Dv), (H, Dv)))
+    w_lse = jnp.asarray(rng.randn(B, H, T // 128, 128), jnp.float32)
+    ranges = _block_diffusion_ranges(T // 2, 4)
+    mask = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
+    rows, heads = _masked_routes(q, k, v, mask, D ** -0.5, w_out, w_lse)
+    for a, b, name in zip(rows, heads, ("out", "lse", "dq", "dk", "dv")):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32),
+                                      err_msg=name)
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+
+
+def _rank4_transposes(text):
+    import re
+    return re.findall(r"stablehlo\.transpose[^\n]*: \(tensor<(?:\d+x){4}",
+                      text)
+
+
+def test_rows_route_lowers_with_no_transpose_around_the_kernels(monkeypatch):
+    """The jitted forward and backward at ``head_dim`` 128: the operands
+    reach the kernels by reshapes, which are free, and no rank-4
+    ``transpose`` is left in the lowered module; at 64 the transposed
+    route has them (what the pattern finds)."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    ranges = _block_diffusion_ranges(512, 4)
+
+    def lowered(D):
+        q, k = (jax.ShapeDtypeStruct((2, 1024, h, D), jnp.bfloat16)
+                for h in (8, 2))
+        return jax.jit(lambda q, k, v: jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, mask=ranges).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)).lower(q, k, k).as_text()
+
+    assert not _rank4_transposes(lowered(128))
+    assert _rank4_transposes(lowered(64))
 
 
 def test_forward_heads_a_step_rule():
@@ -596,7 +689,9 @@ def test_forward_heads_a_step_rule():
 def test_masked_forward_specs(monkeypatch):
     """A forward grid step's blocks: four of a group's eight query tiles
     on their kv head's whole keys and values; ``dq`` keeps one head a
-    step."""
+    step.  At ``head_dim`` 128 every block is cut from the caller's
+    layout: four heads are 512 lanes of ``[B, T, H*D]``, a kv head's keys
+    128 lanes of ``[B, T, Hkv*D]``."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv, D = 2, 2048, 16, 2, 128
     bq, nq, g = 512, 4, 4
@@ -608,10 +703,11 @@ def test_masked_forward_specs(monkeypatch):
                      lambda q, k, v: fa.flash_attention(
                          q, k, v, mask=ranges).astype(jnp.float32).sum(),
                      (0, 1, 2))(q, k, v), q, k, k))
-    kvb = (1, 1, T, D)
+    kvb = (1, T, D)
     assert calls["hvd_flash_fwd"] == ((B, H // g, nq), [
-        (1, g, bq, D), kvb, kvb, (1, bq, 4), (1, g, bq, D), (1, g, nq, bq)])
-    qb, row = (1, 1, bq, D), (1, 1, nq, bq)
+        (1, bq, g * D), kvb, kvb, (1, bq, 4), (1, bq, g * D),
+        (1, g, nq, bq)])
+    qb, row = (1, bq, D), (1, 1, nq, bq)
     assert calls["hvd_flash_dq"] == ((B, H, nq), [
         qb, kvb, kvb, qb, row, row, (1, bq, 4), qb])
 
@@ -686,9 +782,9 @@ def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
     for kernel in ("fwd", "dq", "dkv"):
         assert (grew[kernel, "live"], grew[kernel, "masked"],
                 grew[kernel, "skipped"]) == (2, 6, 8)
-    after_k = _kernel_counts()
-    assert {key for key in after_k if after_k[key] != before_k.get(key, 0)} \
-        == {("fwd", "masked"), ("dq", "masked"), ("dkv", "masked")}
+    assert _grew(before_k) == {("fwd", "masked", "heads"),
+                               ("dq", "masked", "heads"),
+                               ("dkv", "masked", "heads")}
 
 
 @pytest.mark.parametrize("B,H,Hkv,given", [
@@ -700,7 +796,10 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
     for one group and at the benchmark's SDAR cell (32 query heads over 4,
     batch 2), and Llama-3-8B's heads (32 over 8) through ``causal=True``
     alone, where a ``dkv`` holding ``g x T x D`` was refused: compiled
-    here for a v5e that is described, not attached."""
+    here for a v5e that is described, not attached.  Their blocks are cut
+    from the caller's ``[B, T, H*D]``: XLA puts no rank-4 ``transpose``
+    or ``copy`` beside them."""
+    import re
     one_chip = _described_chip(monkeypatch)
     q, k = (jax.ShapeDtypeStruct((B, 8192, h, 128), jnp.bfloat16,
                                  sharding=one_chip) for h in (H, Hkv))
@@ -712,6 +811,10 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
         (0, 1, 2))(q, k, v)).lower(q, k, k).compile().as_text()
     for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert name in text
+    shapes = "|".join(f"{B},{a},{b},128" for h in (H, Hkv)
+                      for a, b in ((8192, h), (h, 8192)))
+    assert not re.findall(
+        rf"= \w+\[(?:{shapes})\]\S* (?:transpose|copy)\(", text)
 
 
 # ------------------------------------- the grouped products' kernels
@@ -777,12 +880,14 @@ def _kernels_by_place(fn, *args):
             if eqn.primitive.name == "pallas_call"]
 
 
-# attention path -> (heads, kv heads, tokens, the mask of a [T] sequence)
+# attention path -> (heads, kv heads, head_dim, tokens, the mask of a [T]
+# sequence); ``rows``: the masked kernels on the caller's layout
 REMAT_PATHS = {
-    "masked-numpy-mask": (2, 1, 256, lambda T: fa.window_ranges(T, 100)),
-    "masked-traced-mask": (2, 1, 256, lambda T: jnp.asarray(
+    "masked-numpy-mask": (2, 1, 64, 256, lambda T: fa.window_ranges(T, 100)),
+    "masked-traced-mask": (2, 1, 64, 256, lambda T: jnp.asarray(
         fa.window_ranges(T, 100))),
-    "packed": (2, 2, 128, lambda T: None),
+    "masked-rows": (2, 1, 128, 256, lambda T: fa.window_ranges(T, 100)),
+    "packed": (2, 2, 64, 128, lambda T: None),
 }
 
 
@@ -792,11 +897,11 @@ def _remat_stack(policy, path):
     ``grads`` a FRESH function of the three (jax caches a traced function
     by identity, and two policies must not share a trace)."""
     from horovod_tpu.models import llama
-    H, Hkv, T, make_mask = REMAT_PATHS[path]
+    H, Hkv, Dh, T, make_mask = REMAT_PATHS[path]
     cfg = llama.LlamaConfig(
         vocab_size=64, d_model=128, n_layers=2, n_heads=H, n_kv_heads=Hkv,
-        d_ff=128, max_seq_len=T, dtype=jnp.bfloat16, remat=True,
-        remat_policy=policy)
+        head_dim=Dh, d_ff=128, max_seq_len=T, dtype=jnp.bfloat16,
+        remat=True, remat_policy=policy)
     par = llama.ParallelSpec()
     layers = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]
     B = 2
@@ -823,7 +928,9 @@ def test_remat_policies_save_the_named_residuals(policy, path, monkeypatch):
     the forward scan alone and the backward scan's remat body holds only
     the backward kernels; the layer's saved residuals are the two named
     values, ``out`` in the compute dtype and ``lse`` in float32, as the
-    kernel wrote them; loss and every gradient equal, to the bit, those
+    kernel wrote them (``out`` as ``[B, T, H*D]`` on the packed path and
+    on the masked one at ``head_dim`` 128, what the ``wo`` product reads;
+    ``[B, H, T, D]`` at 64); loss and every gradient equal, to the bit, those
     of the same stack under the policy without the names (the program
     before the kernels' residuals were kept), which runs the forward
     kernel twice."""
@@ -855,7 +962,8 @@ def test_remat_policies_save_the_named_residuals(policy, path, monkeypatch):
     kept = [(aval, why) for aval, why in saved_residuals(layer, h, one)
             if "flash_attention.py" in why]
     D = cfg.head_dim
-    out_shape = (2, T, H * D) if path == "packed" else (2, H, T, D)
+    out_shape = ((2, T, H * D) if path in ("packed", "masked-rows")
+                 else (2, H, T, D))
     assert sorted((a.shape, str(a.dtype)) for a, _ in kept)[-1] == (
         out_shape, "bfloat16")
     assert [(a.shape, str(a.dtype)) for a, why in kept

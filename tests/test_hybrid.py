@@ -78,10 +78,35 @@ def test_differential_attention_follows_its_dense_formula(kind, interpret,
                                    rtol=2e-3)
     if interpret and metrics.ACTIVE:       # two calls a layer, each of three
         after = metrics.registry().to_dict()["hvd_flash_kernel_total"]
+        # head_dim 64 is half a lane tile: transposed around the kernels
         count = lambda fam: {s["labels"]["kernel"]: s["value"] for s in
-                             fam.get("series", []) if s["labels"]["path"] == "masked"}
+                             fam.get("series", [])
+                             if (s["labels"]["path"], s["labels"]["layout"])
+                             == ("masked", "heads")}
         grew = {k: n - count(before).get(k, 0) for k, n in count(after).items()}
         assert grew == {"fwd": 2, "dq": 2, "dkv": 2}
+
+
+def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
+        monkeypatch):
+    """The lowered text (``lower().as_text()``) of a remat'd trunk of the
+    three attention kinds at the phi widths (``head_dim`` 64, values of
+    128, groups of two), kernels interpreted, forward and backward, as it
+    was at the commit before the masked kernels learnt to read the
+    caller's layout (PR 33's tree, jax 0.9.0): half a lane tile a head
+    stays on the transposed route, built as it was.  A later change to
+    this program changes the hash and states it here."""
+    import hashlib
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    cfg = _cfg(n_layers=3, layer_kinds=("window", "full", "cross"),
+               layer_ids=(1, 17, 19), dtype=jnp.bfloat16, remat=True)
+    layers = llama.init_params(cfg, jax.random.key(0))["layers"]
+    h = jax.ShapeDtypeStruct((2, 256, H * DH), cfg.dtype)
+    text = jax.jit(jax.value_and_grad(lambda h, ls: hybrid.layer_stack(
+        h, ls, cfg, llama.remat_policy("full")).astype(jnp.float32).sum(),
+        (0, 1))).lower(h, layers).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "d1661b6db1d6495d"
 
 
 def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
