@@ -11,8 +11,16 @@ loop's ``bench_feed`` / ``bench_dispatch`` / ``bench_wait`` annotations
 sit on the same clock.  Pallas kernels are the events whose text has
 ``custom_call_target="tpu_custom_call"`` (they carry no name of their
 own today).
+
+An event carries no ``op_name`` (its stats are ``device_offset_ps`` and
+``device_duration_ps`` only): the program's scopes are in the compiled
+text, under the same instruction names, and ``harness/scopes`` joins the
+two.  Names are unique within a module, not across the programs of a
+run, so ``Reduced.instructions`` is kept by the module that the event
+lies in on ``XLA Modules`` (``jit_step(<fingerprint>)``: ``jit_step``).
 """
 
+import bisect
 import glob
 import os
 import re
@@ -88,6 +96,8 @@ class Reduced:
         self.kernel_s = 0.0
         self.collective_s = self.collective_exposed_s = None
         self.device_ops, self.idle_gaps = [], []
+        # first chip: {module: {instruction: [opcode, self seconds, calls]}}
+        self.instructions = {}
 
     def breakdown(self):
         return {"device_ops": self.device_ops[:10], "idle_gaps": self.idle_gaps[:10]}
@@ -137,11 +147,20 @@ def reduce_profile(profile, chips):
             exposed += (_length(reduces) - _overlap(reduces, others)) / 1e9
         if index:
             continue               # the breakdown is of the first chip
-        for inst, opcode, self_ns in _self_times(ops):
+        spans = sorted((s, s + d, re.sub(r"\(\d+\)$", "", n))
+                       for s, d, n in _line(plane, "XLA Modules"))
+        ops.sort(key=lambda e: (e[0], -e[1]))       # as _self_times orders them
+        for (start, _, _), (inst, opcode, self_ns) in zip(ops, _self_times(ops)):
             kind = re.sub(r"[.\d]+$", "", inst)
             if "tpu_custom_call" in opcode or opcode == "custom-call":
                 kind += " (custom-call)"
             by_kind[kind] = by_kind.get(kind, 0.0) + self_ns / 1e9
+            i = bisect.bisect_right(spans, (start, float("inf"))) - 1
+            module = spans[i][2] if i >= 0 and start < spans[i][1] else ""
+            kept = r.instructions.setdefault(module, {}).setdefault(
+                inst, [opcode, 0.0, 0])
+            kept[1] += self_ns / 1e9
+            kept[2] += 1
         edges = [lo] + [t for pair in busy for t in pair] + [hi]
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 <= g0:

@@ -234,6 +234,9 @@ def run_cell(bench_dir, manifest_path, workload, seed, seconds, trace,
     say(f"memory: counter's peak {device['memory_peak_bytes']} + step "
         f"temporaries {temporaries}")
     device["memory_peak_bytes"] += temporaries
+    if trace and keep_trace:        # the text that the trace's names are of
+        with open(os.path.join(os.path.dirname(out), "step.hlo.txt"), "w") as f:
+            f.write(compiled.as_text())
 
     ctx = types.SimpleNamespace(
         cell=cell, phases={p.name: p.window for p in phases}, main=main.window,
@@ -300,9 +303,10 @@ def main(argv=None):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--keep-trace", nargs="?", const=True, default=False,
                     metavar="DIR",
-                    help="leave the .xplane.pb under DIR/<workload>/trace, DIR "
-                         ".bench_out unless given (how the tests' recorded "
-                         "trace was made)")
+                    help="leave the .xplane.pb under DIR/<workload>/trace and "
+                         "the compiled step's text beside it as step.hlo.txt, "
+                         "DIR .bench_out unless given (how the tests' recorded "
+                         "traces were made)")
     args = ap.parse_args(argv)
     result = run_cell(BENCH_DIR, os.path.join(REPO, "BENCHMARK.json"),
                       args.workload, args.seed, args.seconds, bool(args.trace),

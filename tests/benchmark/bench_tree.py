@@ -8,6 +8,7 @@ import os
 import shutil
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
@@ -20,6 +21,16 @@ if str(BENCH) not in sys.path:
 
 def load(path):
     return json.loads(Path(path).read_text())
+
+
+def plane(name, **lines):
+    """A made-up plane of a profile as harness/xplane reads one: each line
+    a list of (start ns, duration ns, event name)."""
+    return types.SimpleNamespace(name=name, lines=[
+        types.SimpleNamespace(name=n, events=[
+            types.SimpleNamespace(start_ns=s, duration_ns=d, name=e)
+            for s, d, e in events])
+        for n, events in lines.items()])
 
 
 def copy_files(dest, source=REPO):

@@ -188,6 +188,21 @@ def test_new_readers_read_what_is_there_and_nothing_otherwise(metric):
     ctx.trace.device_ops += [["hvd_flash_fwd (custom-call)", 0.2],
                              ["hvd_flash_dkv (custom-call)", 0.8],
                              ["ragged-dot-none (custom-call)", 0.02]]
+    if metric == "moe_experts_ms":
+        # since PR 35 the time under the experts' scope, whoever computes
+        # it: the kinds alone no longer read, the instructions' names do
+        assert read(ctx) is None
+        stack = "jit(objective_step)/hvd_forward/transpose(jvp())/while/body"
+        text = "HloModule jit_objective_step\n\nENTRY %main.1 () -> f32[] {\n" + "".join(
+            f'  %{name} = f32[8]{{0}} custom-call(), metadata={{op_name="{stack}/{path}"}}\n'
+            for name, path in [("hvd_moe_gmm_dh.3", "hvd_moe_experts/hvd_moe_gmm_dh/pallas_call"),
+                               ("sort.2", "hvd_moe_experts/sort"),
+                               ("fusion.9", "hvd_moe_route/top_k")]) + "}\n"
+        ctx = types.SimpleNamespace(traced=stretch, say=said.append, trace=ctx.trace,
+                                    hlo_text=lambda: text)
+        ctx.trace.instructions = {"jit_objective_step": {
+            "hvd_moe_gmm_dh.3": ["custom-call", 0.015, 24], "sort.2": ["sort", 0.005, 4],
+            "fusion.9": ["fusion", 0.03, 24]}}
     value = read(ctx)
     assert value is not None and value > 0
     if metric == "mask_flash_roofline":

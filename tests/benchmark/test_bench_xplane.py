@@ -28,25 +28,15 @@ def test_union_overlap_and_self_time():
     ) == ("all-reduce.4", "all-reduce")
 
 
-def _event(start, dur, name):
-    return types.SimpleNamespace(start_ns=start, duration_ns=dur, name=name)
-
-
-def _plane(name, **lines):
-    return types.SimpleNamespace(name=name, lines=[
-        types.SimpleNamespace(name=n, events=[_event(*e) for e in evs])
-        for n, evs in lines.items()])
-
-
 def test_reduce_a_made_up_two_chip_trace():
     def chip(i):
-        return _plane(f"/device:TPU:{i}", **{
+        return bench_tree.plane(f"/device:TPU:{i}", **{
             "XLA Modules": [(0, 1000, "jit_step(1)")],
             "XLA Ops": [(0, 400, "%fusion.1 = f32[8] fusion(%a)"),
                         (400, 200, "%all-reduce.1 = f32[8] all-reduce(%g)"),
                         (700, 100, '%k.1 = f32[8] custom-call(%q), custom_call_target="tpu_custom_call"')],
             "Async XLA Ops": [(300, 200, "%all-reduce-start.2 = f32[8] all-reduce-start(%h)")]})
-    host = _plane("/host:CPU", python=[(590, 50, "bench_dispatch"), (640, 400, "bench_wait"),
+    host = bench_tree.plane("/host:CPU", python=[(590, 50, "bench_dispatch"), (640, 400, "bench_wait"),
                                        (0, 5, "$builtins len")])
     r = xplane.reduce_profile(types.SimpleNamespace(planes=[chip(0), chip(1), host]), chips=2)
     assert (r.busy_s, r.window_s) == (700e-9, 1000e-9)
