@@ -1,10 +1,18 @@
-"""Model zoo: the benchmark-config model families (SURVEY.md §2.3).
+"""The models the steps of ``training.py`` and the benchmark's cells run.
 
-MNIST MLP/CNN (config 1), ResNet-50 (config 2), BERT (config 3),
-Llama-3-style decoder (config 4, flagship) and a Mixtral-style MoE variant
-(expert parallelism).  All are written TPU-first: bf16 compute / fp32
-params, stacked-layer ``lax.scan`` bodies, explicit mesh-axis hooks.
+``mnist`` (MLP / CNN), ``resnet`` (ResNet-18/50 v1.5 with SyncBN),
+``bert`` (a post-LN encoder with a classification head and its own
+data-parallel fine-tune step), ``llama`` (a Llama-style decoder trunk:
+GQA, RoPE, SwiGLU, optional q/k norm, untied head, chunked or
+vocabulary-parallel loss), ``moe`` (the trunk's routed experts: a capacity
+path over an ``ep`` axis and a dropless path on the experts a chip holds),
+``hybrid`` (a trunk whose layers are of several kinds: Mamba mixers,
+differential attention as window, full and cross, gated memory units) and
+``generate`` (KV-cache decoding).  bf16 compute over fp32 parameters,
+stacked layers under ``lax.scan`` where the layers are equal, mesh axes
+as hooks (``llama.ParallelSpec``).  Each model names its own parts for
+the device trace (``training.SCOPE_EMBED`` .. ``SCOPE_STAGE``).
 """
 
-from . import generate, llama, mnist, resnet  # noqa: F401  (bert/moe
-#                                                 import on demand)
+from . import generate, llama, mnist, resnet  # noqa: F401  (bert, moe and
+#                                        hybrid are imported where they are used)

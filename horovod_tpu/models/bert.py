@@ -195,13 +195,17 @@ def _mlp(x, lp, par: ParallelSpec):
 
 
 def block(x, lp, cfg: BertConfig, par: ParallelSpec, mask):
-    """One post-LN encoder block (BERT layout: residual then LayerNorm)."""
-    a = _attention(x, lp, cfg, par, mask)
-    x = _layernorm(x + a, lp["attn_norm_w"], lp["attn_norm_b"],
-                   cfg.norm_eps)
-    m = _mlp(x, lp, par)
-    return _layernorm(x + m, lp["mlp_norm_w"], lp["mlp_norm_b"],
-                      cfg.norm_eps)
+    """One post-LN encoder block (BERT layout: residual then LayerNorm);
+    each sublayer lies with its residual's LayerNorm under its scope."""
+    from ..training import SCOPE_ATTENTION, SCOPE_MLP
+    with jax.named_scope(SCOPE_ATTENTION):
+        a = _attention(x, lp, cfg, par, mask)
+        x = _layernorm(x + a, lp["attn_norm_w"], lp["attn_norm_b"],
+                       cfg.norm_eps)
+    with jax.named_scope(SCOPE_MLP):
+        m = _mlp(x, lp, par)
+        return _layernorm(x + m, lp["mlp_norm_w"], lp["mlp_norm_b"],
+                          cfg.norm_eps)
 
 
 def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
@@ -213,6 +217,7 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
     0/1 attention mask for padded batches (forces the dense path and is
     incompatible with sp sharding).
     """
+    from ..training import SCOPE_EMBED
     if mask is not None and par.sp_axis is not None:
         raise ValueError("attention masks require unsharded sequence "
                          "(pad-free batches for the sp path)")
@@ -220,13 +225,14 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
     sp_idx = (lax.axis_index(par.sp_axis)
               if par.sp_axis is not None else 0)
     positions = jnp.arange(Tl, dtype=jnp.int32)[None, :] + sp_idx * Tl
-    h = params["word_embed"].astype(cfg.dtype)[tokens]
-    h = h + params["pos_embed"].astype(cfg.dtype)[positions]
-    tt = (token_types if token_types is not None
-          else jnp.zeros_like(tokens))
-    h = h + params["type_embed"].astype(cfg.dtype)[tt]
-    h = _layernorm(h, params["embed_norm_w"], params["embed_norm_b"],
-                   cfg.norm_eps)
+    with jax.named_scope(SCOPE_EMBED):
+        h = params["word_embed"].astype(cfg.dtype)[tokens]
+        h = h + params["pos_embed"].astype(cfg.dtype)[positions]
+        tt = (token_types if token_types is not None
+              else jnp.zeros_like(tokens))
+        h = h + params["type_embed"].astype(cfg.dtype)[tt]
+        h = _layernorm(h, params["embed_norm_w"], params["embed_norm_b"],
+                       cfg.norm_eps)
 
     layers = jax.tree_util.tree_map(
         lambda w: w.astype(cfg.dtype) if w.dtype != cfg.dtype else w,
@@ -252,26 +258,30 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
 def classify(params, tokens, cfg: BertConfig, par: ParallelSpec,
              token_types=None, mask=None):
     """Sequence classification logits ``[B, num_labels]`` (pooled [CLS])."""
+    from ..training import SCOPE_HEAD
     h = encode(params, tokens, cfg, par, token_types, mask)
-    cls = h[:, 0, :]  # [CLS] position
-    pooled = jnp.tanh(cls @ params["pooler_w"].astype(cls.dtype)
-                      + params["pooler_b"].astype(cls.dtype))
-    return (pooled @ params["cls_w"].astype(pooled.dtype)
-            + params["cls_b"].astype(pooled.dtype)).astype(jnp.float32)
+    with jax.named_scope(SCOPE_HEAD):
+        cls = h[:, 0, :]  # [CLS] position
+        pooled = jnp.tanh(cls @ params["pooler_w"].astype(cls.dtype)
+                          + params["pooler_b"].astype(cls.dtype))
+        return (pooled @ params["cls_w"].astype(pooled.dtype)
+                + params["cls_b"].astype(pooled.dtype)).astype(jnp.float32)
 
 
 def loss_fn(params, tokens, labels, cfg: BertConfig, par: ParallelSpec,
             token_types=None, mask=None):
     """Mean classification cross-entropy over the local batch (caller
     pmeans over dp)."""
+    from ..training import SCOPE_HEAD
     # overlapped dispatch: tap the non-scanned leaves (embeddings,
     # pooler, classification head) as one group; the scanned stack is
     # tapped per layer inside encode()'s scan body.  No-op outside an
     # overlapped_backprop context.
     params = _overlap.tap_root(params)
     logits = classify(params, tokens, cfg, par, token_types, mask)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
+    with jax.named_scope(SCOPE_HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
 
 
 def make_dp_finetune_step(cfg: BertConfig, mesh, axis: str, optimizer,

@@ -254,6 +254,8 @@ def _layer(kind, emits, cfg):
     ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer, else
     None; ``emitted`` is what an emitting mamba (``s``) or full layer
     (``(k, v)``) hands on, else None."""
+    from ..training import (SCOPE_DIFF_ATTENTION, SCOPE_GMU, SCOPE_MLP,
+                            SCOPE_SSM_MIXER)
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def f(h, lp, lam0, memory):
@@ -266,14 +268,14 @@ def _layer(kind, emits, cfg):
         u = layer_norm(h, lp["norm1_w"], lp["norm1_b"], cfg.norm_eps)
         emitted = None
         if kind == "mamba":
-            with jax.named_scope("hvd_ssm_mixer"):
+            with jax.named_scope(SCOPE_SSM_MIXER):
                 y, s = _mamba(u, lp, cfg)
             emitted = s if emits else None
         elif kind == "gmu":
-            with jax.named_scope("hvd_gmu"):
+            with jax.named_scope(SCOPE_GMU):
                 y = (jax.nn.silu(u @ lp["in_proj"]) * memory) @ lp["out_proj"]
         else:
-            with jax.named_scope("hvd_diff_attention"):
+            with jax.named_scope(SCOPE_DIFF_ATTENTION):
                 if kind == "cross":
                     q = (u @ lp["wq"]).reshape(B, T, H, Dh)
                     k, v = memory
@@ -286,8 +288,10 @@ def _layer(kind, emits, cfg):
                 y = _diff_attention(q, k, v, lp, lam0, key_ranges(kind, T, cfg),
                                     cfg)
         h = h + y
-        return h + _mlp(layer_norm(h, lp["norm2_w"], lp["norm2_b"],
-                                   cfg.norm_eps), lp), emitted
+        with jax.named_scope(SCOPE_MLP):
+            y = _mlp(layer_norm(h, lp["norm2_w"], lp["norm2_b"],
+                                cfg.norm_eps), lp)
+        return h + y, emitted
 
     return f
 

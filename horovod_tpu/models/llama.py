@@ -420,10 +420,16 @@ def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
           mask=None):
     """One transformer block (shape-preserving — the pipeline stage unit).
     Returns (x, aux): the load-balance loss of capacity experts (0 for
-    dense MLPs), or dropless experts' ``[4]`` routing statistics."""
-    x = x + _attention(_rmsnorm(x, lp["attn_norm"], cfg.norm_eps),
+    dense MLPs), or dropless experts' ``[4]`` routing statistics.  Each
+    sublayer with its norm lies under a scope of its own; the residual
+    adds are the layer's."""
+    from ..training import SCOPE_ATTENTION, SCOPE_MLP
+    with jax.named_scope(SCOPE_ATTENTION):
+        a = _attention(_rmsnorm(x, lp["attn_norm"], cfg.norm_eps),
                        lp, cfg, par, positions, mask)
-    y, aux = ffn(_rmsnorm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg, par)
+    x = x + a
+    with jax.named_scope(SCOPE_MLP):
+        y, aux = ffn(_rmsnorm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg, par)
     return x + y, aux
 
 
@@ -516,6 +522,7 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     """
     if cfg.layer_kinds:
         return _hybrid_hidden(params, tokens, cfg, par, positions, mask)
+    from ..training import SCOPE_EMBED, SCOPE_HEAD
     Tl = tokens.shape[1]
     sp_idx = (lax.axis_index(par.sp_axis)
               if par.sp_axis is not None else 0)
@@ -527,7 +534,8 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     if positions is None:
         positions = (jnp.arange(Tl)[None, :] + sp_idx * Tl
                      ).astype(jnp.int32) * jnp.ones_like(tokens)
-    h = _embed_lookup(params["embed"], tokens, cfg, par)
+    with jax.named_scope(SCOPE_EMBED):
+        h = _embed_lookup(params["embed"], tokens, cfg, par)
     aux = jnp.float32(0.0)
 
     if par.pp_axis is not None:
@@ -557,7 +565,8 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
         h, aux = _layer_stack(h, params["layers"], cfg, par, positions,
                               mask)
 
-    h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(SCOPE_HEAD):
+        h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     return h, aux
 
 
@@ -572,26 +581,34 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
         raise NotImplementedError(
             "a trunk of several kinds takes no positions and no mask and "
             "runs under plain data parallelism only")
-    h = _embed_lookup(params["embed"], tokens, cfg, par)
+    from ..training import SCOPE_EMBED, SCOPE_HEAD
+    with jax.named_scope(SCOPE_EMBED):
+        h = _embed_lookup(params["embed"], tokens, cfg, par)
     h = hybrid.layer_stack(
         h, params["layers"], cfg,
         remat_policy(cfg.remat_policy) if cfg.remat else None)
-    h = hybrid.layer_norm(h, params["final_norm"],
-                          params["final_norm_bias"], cfg.norm_eps)
+    with jax.named_scope(SCOPE_HEAD):
+        h = hybrid.layer_norm(h, params["final_norm"],
+                              params["final_norm_bias"], cfg.norm_eps)
     return h, jnp.float32(0.0)
 
 
 def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
             n_microbatches: int = 0):
     """Token ids → logits.  Call inside shard_map over the parallel mesh."""
+    from ..training import SCOPE_HEAD
     h, aux = hidden(params, tokens, cfg, par, n_microbatches)
-    # tied embedding head (Llama-3 unties; tying halves test-model memory
-    # and changes no parallel structure — the head matmul stays [D, V])
-    logits = h @ _head(params, cfg).T.astype(h.dtype)
-    if _vp_active(cfg, par):
-        # local [B, T, V/tp] partials → full logits, shard order = vocab
-        # order (API contract; the loss path never materializes this)
-        logits = lax.all_gather(logits, par.tp_axis, axis=-1, tiled=True)
+    with jax.named_scope(SCOPE_HEAD):
+        # tied embedding head (Llama-3 unties; tying halves test-model
+        # memory and changes no parallel structure — the head matmul
+        # stays [D, V])
+        logits = h @ _head(params, cfg).T.astype(h.dtype)
+        if _vp_active(cfg, par):
+            # local [B, T, V/tp] partials → full logits, shard order =
+            # vocab order (API contract; the loss path never materializes
+            # this)
+            logits = lax.all_gather(logits, par.tp_axis, axis=-1,
+                                    tiled=True)
     return logits, aux
 
 
@@ -654,6 +671,7 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
     Tt``), ``positions`` and ``mask`` go to :func:`hidden`.
     ``with_stats`` also returns dropless experts' routing statistics,
     summed over the layers (``moe.ROUTING_STATS``; zeros otherwise)."""
+    from ..training import SCOPE_HEAD
     # overlapped dispatch: tap the non-scanned leaves (embed, final_norm)
     # as one group HERE so every use — the lookup AND the tied loss head
     # — contributes to one cotangent before the dispatch fires; the
@@ -662,8 +680,22 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
     params = _overlap.tap_root(params)
     h, aux = hidden(params, tokens, cfg, par, n_microbatches, positions,
                     mask)
-    h = h[:, :targets.shape[1]]
-    head = _head(params, cfg)
+    with jax.named_scope(SCOPE_HEAD):
+        loss = _head_loss(h[:, :targets.shape[1]], _head(params, cfg),
+                          targets, cfg, par, weights)
+    stats = jnp.zeros((4,), jnp.float32)
+    if _dropless(cfg):
+        stats = aux
+    elif cfg.n_experts > 0:
+        loss = loss + cfg.aux_loss_coef * aux / cfg.n_layers
+    return (loss, stats) if with_stats else loss
+
+
+def _head_loss(h, head, targets, cfg: LlamaConfig, par: ParallelSpec,
+               weights):
+    """Mean cross-entropy of ``h [B, Tt, D]`` through ``head [V, D]``
+    against ``targets [B, Tt]``: vocabulary-parallel, in chunks of
+    ``cfg.loss_chunk`` rows, or whole."""
     if weights is not None and _vp_active(cfg, par):
         raise NotImplementedError(
             "weights per position go through the chunked or one-shot "
@@ -681,27 +713,18 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
 
     if _vp_active(cfg, par):
         warn_unchunked()
-        loss = _vocab_parallel_xent(h, head, targets, par,
+        return _vocab_parallel_xent(h, head, targets, par,
                                     chunk=cfg.loss_chunk)
-    elif cfg.loss_chunk > 0 and h.shape[1] % cfg.loss_chunk == 0:
-        loss = _chunked_xent(h, head, targets, cfg.loss_chunk, weights)
-    else:
-        warn_unchunked()
-        if weights is None:
-            logits = h @ head.T.astype(h.dtype)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None],
-                                     axis=-1)[..., 0]
-            loss = -ll.mean()
-        else:
-            loss = (_token_xent(h, head.astype(h.dtype), targets)
-                    * weights).mean()
-    stats = jnp.zeros((4,), jnp.float32)
-    if _dropless(cfg):
-        stats = aux
-    elif cfg.n_experts > 0:
-        loss = loss + cfg.aux_loss_coef * aux / cfg.n_layers
-    return (loss, stats) if with_stats else loss
+    if cfg.loss_chunk > 0 and h.shape[1] % cfg.loss_chunk == 0:
+        return _chunked_xent(h, head, targets, cfg.loss_chunk, weights)
+    warn_unchunked()
+    if weights is not None:
+        return (_token_xent(h, head.astype(h.dtype), targets)
+                * weights).mean()
+    logits = h @ head.T.astype(h.dtype)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    return -ll.mean()
 
 
 def count_params(cfg: LlamaConfig) -> int:

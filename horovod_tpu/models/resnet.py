@@ -158,24 +158,28 @@ def forward(params, state, images, cfg: ResNetConfig, train: bool = True,
             axis_name: Optional[str] = None):
     """images: [B, H, W, 3] (any float dtype) → (logits fp32 [B, classes],
     new_state).  ``axis_name``: dp axis for synchronized batch norm."""
-    x = images.astype(cfg.dtype)
-    x = _conv(x, params["stem"], 2)
+    from ..training import SCOPE_HEAD, SCOPE_STAGE, SCOPE_STEM
     new_state = {}
-    x, new_state["stem_bn"] = _bn(x, params["stem_bn"], state["stem_bn"],
-                                  cfg, train, axis_name)
-    x = jax.nn.relu(x)
-    x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-                          "SAME")
+    with jax.named_scope(SCOPE_STEM):
+        x = images.astype(cfg.dtype)
+        x = _conv(x, params["stem"], 2)
+        x, new_state["stem_bn"] = _bn(x, params["stem_bn"], state["stem_bn"],
+                                      cfg, train, axis_name)
+        x = jax.nn.relu(x)
+        x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
     for i in range(len(cfg.stage_blocks)):
         blocks_ns = []
-        for b, (bp, bs) in enumerate(zip(params[f"stage{i}"],
-                                         state[f"stage{i}"])):
-            stride = 2 if (b == 0 and i > 0) else 1
-            x, bns = _block(x, bp, bs, cfg, stride, train, axis_name)
-            blocks_ns.append(bns)
+        with jax.named_scope(SCOPE_STAGE.format(i)):
+            for b, (bp, bs) in enumerate(zip(params[f"stage{i}"],
+                                             state[f"stage{i}"])):
+                stride = 2 if (b == 0 and i > 0) else 1
+                x, bns = _block(x, bp, bs, cfg, stride, train, axis_name)
+                blocks_ns.append(bns)
         new_state[f"stage{i}"] = blocks_ns
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
-    logits = x @ params["fc"]["w"] + params["fc"]["b"]
+    with jax.named_scope(SCOPE_HEAD):
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
+        logits = x @ params["fc"]["w"] + params["fc"]["b"]
     return logits, new_state
 
 

@@ -38,6 +38,19 @@ SCOPE_FORWARD = "hvd_forward"      # model and loss, inside the differentiated f
 SCOPE_REDUCE = "hvd_reduce"        # explicit gradient scaling / pmeans
 SCOPE_OPTIMIZER = "hvd_optimizer"  # optimizer.update + apply_updates
 SCOPE_SYNC_BN = "hvd_sync_bn"      # SyncBN's psum of the batch statistics
+# The model's own parts, opened in ``models/`` where the work is and so
+# under whichever step builder's ``hvd_forward``: a layer is the union of
+# its sublayers' scopes, and what is left under ``hvd_forward`` alone is
+# the residual adds and what an ``objective=`` from outside computes.
+SCOPE_EMBED = "hvd_embed"          # the lookups (and BERT's embedding norm)
+SCOPE_ATTENTION = "hvd_attention"  # norm, projections, RoPE, kernels, tp's psum
+SCOPE_MLP = "hvd_mlp"              # norm + feed-forward, dense or routed experts
+SCOPE_HEAD = "hvd_head"            # final norm, head product, loss; ResNet's pool + fc
+SCOPE_STEM = "hvd_stem"            # ResNet: conv1 + BN + max-pool
+SCOPE_STAGE = "hvd_stage{}"        # ResNet: a stage's blocks, ``.format(i)``
+SCOPE_SSM_MIXER = "hvd_ssm_mixer"  # models/hybrid.py's three mixers, after norm1
+SCOPE_GMU = "hvd_gmu"
+SCOPE_DIFF_ATTENTION = "hvd_diff_attention"
 
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec

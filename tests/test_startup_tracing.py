@@ -4,6 +4,8 @@ function, the scope names inside the two step builders and the kernel
 names (docs/observability.md "Start-up and the jitted step")."""
 
 import contextlib
+import dataclasses
+import functools
 import json
 import os
 import re
@@ -19,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu import tracing, training
-from horovod_tpu.models import bert, resnet
+from horovod_tpu.models import bert, llama, resnet
 from horovod_tpu.parallel.mesh import MeshConfig, ParallelMesh
 from horovod_tpu.tracing.span import SpanBuffer
 
@@ -237,7 +239,7 @@ def test_start_profiler_leaves_the_python_tracer_off(hvd, monkeypatch, tmp_path)
 
 # ---- names inside the step ---------------------------------------------------
 
-def _resnet_step(dp, sync_bn=True):
+def _resnet_lowered(dp, sync_bn=True):
     cfg = resnet.ResNetConfig(variant=18, num_classes=10, width=8,
                               dtype=jnp.float32)
     pmesh = ParallelMesh(MeshConfig(dp=dp), devices=jax.devices()[:dp])
@@ -250,10 +252,14 @@ def _resnet_step(dp, sync_bn=True):
     opt_state = jax.eval_shape(optax.sgd(0.1, momentum=0.9).init, params)
     x = jax.ShapeDtypeStruct((4 * dp, 32, 32, 3), jnp.float32)
     y = jax.ShapeDtypeStruct((4 * dp,), jnp.int32)
-    return ts.step_fn.lower(params, state, opt_state, x, y).compile().as_text()
+    return ts.step_fn.lower(params, state, opt_state, x, y)
 
 
-def _bert_step(dp, reduce_grads=True):
+def _resnet_step(dp, sync_bn=True):
+    return _resnet_lowered(dp, sync_bn).compile().as_text()
+
+
+def _bert_lowered(dp, reduce_grads=True):
     cfg = bert.tiny(vocab=64, seq=32, num_labels=3)
     mesh = Mesh(np.array(jax.devices()[:dp]), ("dp",))
     opt = optax.adamw(1e-3)
@@ -264,7 +270,11 @@ def _bert_step(dp, reduce_grads=True):
     opt_state = jax.eval_shape(opt.init, params)
     tokens = jax.ShapeDtypeStruct((2 * dp, 32), jnp.int32)
     labels = jax.ShapeDtypeStruct((2 * dp,), jnp.int32)
-    return step.lower(params, opt_state, tokens, labels).compile().as_text()
+    return step.lower(params, opt_state, tokens, labels)
+
+
+def _bert_step(dp, reduce_grads=True):
+    return _bert_lowered(dp, reduce_grads).compile().as_text()
 
 
 def _op_names(hlo_text):
@@ -324,6 +334,126 @@ def test_scopes_change_nothing_but_metadata(build, dp, monkeypatch):
     assert training.SCOPE_FORWARD in with_scopes
     assert training.SCOPE_FORWARD not in without
     assert _without_metadata(with_scopes) == _without_metadata(without)
+
+
+# ---- the model's own names ----------------------------------------------------
+
+_HYBRID_KINDS = ("mamba", "window", "mamba", "full", "gmu", "cross")
+_LLAMA_TINIES = {
+    "llama": llama.tiny(),
+    "dropless": dataclasses.replace(
+        llama.tiny(), n_experts=4, expert_top_k=2, moe_dispatch="dropless"),
+    "hybrid": llama.LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=len(_HYBRID_KINDS), n_heads=4,
+        n_kv_heads=2, head_dim=16, d_ff=128, dtype=jnp.float32,
+        remat_policy="full", layer_kinds=_HYBRID_KINDS, sliding_window=16,
+        ssm_inner=128, ssm_dt_rank=8),
+}
+
+
+def _llama_lowered(which):
+    """The llama tiny through the plain step, which opens no scope of its
+    own; the other two through ``objective=``, as their cells' adapters
+    build them, under ``hvd_forward``."""
+    pmesh = ParallelMesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    objective = None if which == "llama" else (
+        lambda params, batch, cfg, par: llama.loss_fn(
+            params, *batch, cfg, par, with_stats=True))
+    ts = training.make_llama_train_step(_LLAMA_TINIES[which], pmesh,
+                                        optax.adamw(1e-3), objective=objective)
+    state = jax.eval_shape(ts.init_fn, jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    batch = (tokens, tokens) if objective is None else ((tokens, tokens),)
+    return ts.step_fn.lower(*state, *batch)
+
+
+def _model_lowered(model):
+    if model == "bert":
+        return _bert_lowered(1)
+    if model == "resnet":
+        return _resnet_lowered(2)       # two devices: SyncBN's psum is there
+    return _llama_lowered(model)
+
+
+# scopes every pass has, and those of them inside a remat'd layer (the
+# embedding and the head lie outside the stack)
+_EMBED, _ATTN, _MLP, _HEAD = (training.SCOPE_EMBED, training.SCOPE_ATTENTION,
+                              training.SCOPE_MLP, training.SCOPE_HEAD)
+_MIXERS = (training.SCOPE_SSM_MIXER, training.SCOPE_GMU,
+           training.SCOPE_DIFF_ATTENTION)
+_MODEL_SCOPES = {
+    "llama": ((_EMBED, _ATTN, _MLP, _HEAD), (_ATTN, _MLP)),
+    "dropless": ((_EMBED, _ATTN, _MLP, _HEAD, "hvd_moe_route",
+                  "hvd_moe_experts"), (_ATTN, _MLP, "hvd_moe_route")),
+    "hybrid": ((_EMBED, _MLP, _HEAD) + _MIXERS, (_MLP,) + _MIXERS),
+    "bert": ((_EMBED, _ATTN, _MLP, _HEAD), (_ATTN, _MLP)),
+    "resnet": ((training.SCOPE_STEM, _HEAD)
+               + tuple(training.SCOPE_STAGE.format(i) for i in range(4)), ()),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _model_op_names(model):
+    return frozenset(_op_names(_model_lowered(model).compile().as_text()))
+
+
+@pytest.mark.parametrize("model", sorted(_MODEL_SCOPES))
+def test_compiled_step_carries_the_models_scopes_in_every_pass(model):
+    """The sublayers' names are opened in ``models/``, so the step of any
+    builder carries them (under ``hvd_forward`` where the builder opens
+    it): in the forward pass, under autodiff's transpose in the backward
+    pass, and under remat's second forward where the layer stack is
+    remat'd."""
+    names = _model_op_names(model)
+    every_pass, rematted = _MODEL_SCOPES[model]
+    fwd = training.SCOPE_FORWARD
+    opens_forward = model != "llama"
+    assert any(fwd in n for n in names) == opens_forward
+    for scope in every_pass:
+        # ``jvp(hvd_embed)/gather`` where the scope is the outermost name
+        under = [n for n in names if re.search(rf"[/(]{scope}[/)]", n)
+                 and (fwd in n) == opens_forward]
+        assert any("transpose(" not in n and "rematted_computation" not in n
+                   for n in under), f"{scope}: forward"
+        assert any("transpose(" in n for n in under), f"{scope}: backward"
+        if scope in rematted:
+            assert any("rematted_computation" in n for n in under), \
+                f"{scope}: recompute"
+    # a part of the model is named once: the trunk's attention scope is not
+    # opened around hybrid's (a reader that adds the two would count twice)
+    assert not any(_ATTN in n and training.SCOPE_DIFF_ATTENTION in n
+                   for n in names)
+    assert not any(fwd in n and training.SCOPE_OPTIMIZER in n for n in names)
+
+
+def test_routed_experts_lie_inside_the_mlp_scope():
+    names = [n for n in _model_op_names("dropless")
+             if "hvd_moe_experts" in n or "hvd_moe_route" in n]
+    assert names
+    for n in names:
+        assert re.search(rf"\b{_MLP}/(.*/)?hvd_moe_(experts|route)/", n), n
+    # and the attention's kernels' call under the attention's
+    assert not any(_ATTN in n for n in names)
+
+
+def test_resnet_blocks_sync_bn_lies_inside_its_stage():
+    names = [n for n in _model_op_names("resnet")
+             if f"/{training.SCOPE_SYNC_BN}/" in n]
+    assert names
+    assert all(re.search(r"/hvd_(stem|stage\d)/(.*/)?hvd_sync_bn/", n)
+               for n in names)
+
+
+@pytest.mark.parametrize("model", sorted(_MODEL_SCOPES))
+def test_model_scopes_leave_the_lowered_text_as_it_was(model, monkeypatch):
+    """``lower().as_text()`` carries no debug info: with the scopes and
+    with ``jax.named_scope`` switched off it is the same text, byte for
+    byte (a scope is a string in the lowered module's locations)."""
+    with_scopes = _model_lowered(model).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert _model_lowered(model).as_text() == with_scopes
+    assert "hvd_" not in re.sub(r"hvd_flash_(out|lse)", "", with_scopes)
 
 
 @pytest.mark.parametrize("seq_len,names", [
