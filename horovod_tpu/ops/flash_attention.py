@@ -61,10 +61,27 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   scratch, the maxima alike in every lane and the sums as 128 partial
   sums a row that meet after the last tile, and the rows' ranges are
   spread over the lanes once a step, so a tile's only cross-lane work is
-  its row maximum.  ``hvd_flash_dkv`` has one grid step per live (key
+  its row maximum.  A ``hvd_flash_dq`` grid step is laid out the same
+  way: the query tile of as many heads of a group as :func:`_dq_heads`
+  finds room for (4 of 8 at the same shape; its step also holds ``do``
+  and ``dq``, so never more than the forward's), unrolled
+  inside a key tile's step so that k and v tiles are loaded once for all
+  of them; ``dq`` accumulates in float32 VMEM scratch ``[hb, bq, D]`` and
+  is cast out after the last tile; the heads' rows of ``lse`` and of
+  ``delta`` become columns alike in every lane by one transpose each and
+  the rows' ranges are spread over the lanes, once a step, and a mixed
+  tile's mask is made once for all the step's heads.
+  ``hvd_flash_dkv`` has one grid step per live (key
   tile, query tile) pair and takes the query tiles of its GQA group one
   at a time, so it holds ``g x bq x D`` of ``q`` and ``do``, not
-  ``g x T x D``: 8 query heads a kv head at 8,192 positions run.  Every
+  ``g x T x D``: 8 query heads a kv head at 8,192 positions run.  It
+  forms its tile keys by queries, ``S^T = K Q^T [bk, bq]``: a query's
+  ``lse``, ``delta`` and ranges (handed over as ``[Bm, 4, T]``, a row a
+  bound) lie along the lanes as they are stored and go down the sublanes
+  for nothing, one mask serves the pair's ``g`` heads, and ``dv = P^T do``
+  and ``dk = dS^T q`` are plain ``[bk, bq] x [bq, D]`` products.  The
+  backward is still two kernels and 7 products a live pair of a head (S
+  and dP made again in each), bf16 operands, float32 accumulation.  Every
   query row has to see at least one key.  Two layouts, chosen from the
   widths where the call is built (no knob): where ``D`` and ``Dv`` are
   whole lane tiles (multiples of 128) the kernels read ``q``, ``k``,
@@ -131,8 +148,8 @@ _INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
 _BLOCK = 512  # query and key positions a block
 _LANES = 128
-# what one masked forward grid step may hold: a quarter of a v5e core's
-# 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
+# what one grid step of the masked forward or of dq may hold: a quarter of
+# a v5e core's 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
 _MASKED_STEP_VMEM = 32 * 1024 * 1024
 # checkpoint names of the forward kernels' two results ("Residuals are
 # named", above)
@@ -590,22 +607,6 @@ def _in_ranges(cols, lo1, hi1, lo2, hi2):
     return ((cols >= lo1) & (cols < hi1)) | ((cols >= lo2) & (cols < hi2))
 
 
-def _live(rng, col0, shape):
-    """Boolean ``shape = (bq, bk)``: the pairs of a tile whose first key
-    is ``col0`` that the rows' ranges ``rng [bq, 4]`` let through."""
-    cols = lax.broadcasted_iota(jnp.int32, shape, 1)
-    r = rng - col0                       # shift the ranges, not the tile
-    return _in_ranges(cols, *(r[:, c:c + 1] for c in range(4)))
-
-
-def _masked_scores(q, kj, rng, col0, scale, masked):
-    s = lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
-    if masked:
-        s = jnp.where(_live(rng, col0, s.shape), s, NEG_INF)
-    return s
-
-
 def _two_loops(n_full, n_live, step, carry):
     """Full tiles unmasked, then mixed tiles masked: no branch inside a
     loop body."""
@@ -620,6 +621,29 @@ def _lanes(x, n):
     if n <= _LANES:
         return x[:, :n]
     return jnp.tile(x, (1, n // _LANES))
+
+
+def _column(row):
+    """``row [1, n]`` as a column alike in every lane, ``[n, 128]``: the
+    row down the sublanes and one transpose, not ``n`` lane shuffles."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _spread_ranges(r_ref, rb_ref):
+    """The rows' four range columns of ``r_ref [1, bq, 4]`` over the lanes
+    of ``rb_ref [4, bq, 128]``: once a grid step, for every head and
+    mixed tile of it."""
+    for c in range(4):
+        rb_ref[c] = jnp.broadcast_to(r_ref[0][:, c:c + 1], rb_ref.shape[1:])
+
+
+def _rows_live(rb_ref, col0, bk):
+    """Boolean ``[bq, bk]``: the pairs of a tile whose first key is
+    ``col0`` that the rows' spread ranges let through (the tile's columns
+    are shifted, not the ranges)."""
+    bq = rb_ref.shape[1]
+    cols = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + col0
+    return _in_ranges(cols, *(_lanes(rb_ref[c], bk) for c in range(4)))
 
 
 def _head(ref, h, d, at=slice(None)):
@@ -644,15 +668,14 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     meet once, after the last tile; ``acc_ref [hb, bq, D]``.  ``rb_ref
     [4, bq, 128]`` holds the rows' ranges spread over the lanes once a
     step, for every head and mixed tile of it."""
-    hb, bq, Dv = acc_ref.shape
+    hb, _, Dv = acc_ref.shape
     D = k_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-    for c in range(4):
-        rb_ref[c] = jnp.broadcast_to(r_ref[0][:, c:c + 1], (bq, _LANES))
+    _spread_ranges(r_ref, rb_ref)
 
     def step(n, carry, masked):
         j = idx_ref[row * nk + n]
@@ -660,11 +683,8 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
         for h in range(hb):
             s = _scores(q_ref[_head(q_ref, h, D)], kj, scale, False)
-            if masked:           # shift the tile's columns, not the ranges
-                cols = lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bk
-                s = jnp.where(_in_ranges(cols, *(_lanes(rb_ref[c], bk)
-                                                 for c in range(4))),
-                              s, NEG_INF)
+            if masked:
+                s = jnp.where(_rows_live(rb_ref, j * bk, bk), s, NEG_INF)
             m = m_ref[h]
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - _lanes(m_new, bk))
@@ -685,38 +705,63 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
 
 
 def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, r_ref, dq_ref, *, scale, bk, nq, nk,
-                per_batch):
-    (bq, D), Dv = q_ref.shape[-2:], v_ref.shape[-1]
+                lse_ref, delta_ref, r_ref, dq_ref, acc_ref, lse_b, delta_b,
+                rb_ref, *, scale, bk, nq, nk, per_batch):
+    """One query tile of ``hb`` query heads that share a kv head, as the
+    forward takes them (static, unrolled: a key tile is loaded once for
+    all of them and one head's elementwise work is scheduled under
+    another's products).  ``acc_ref [hb, bq, D]`` holds ``dq`` in float32
+    until the last tile; ``lse_b`` and ``delta_b [hb, bq, 128]`` the heads'
+    rows of ``lse`` and ``delta`` as columns alike in every lane and
+    ``rb_ref [4, bq, 128]`` the rows' ranges over the lanes, each laid out
+    once a step."""
+    (hb, _, D), Dv = acc_ref.shape, v_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
-    q, do = q_ref[_head(q_ref, 0, D)], do_ref[_head(do_ref, 0, Dv)]
-    rng = r_ref[0]
-    lse = lse_ref[0, 0, i, :].reshape(bq, 1)
-    delta = delta_ref[0, 0, i, :].reshape(bq, 1)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    for h in range(hb):
+        lse_b[h] = _column(lse_ref[0, h, pl.ds(i, 1), :])
+        delta_b[h] = _column(delta_ref[0, h, pl.ds(i, 1), :])
+    _spread_ranges(r_ref, rb_ref)
 
-    def step(n, dq_acc, masked):
+    def step(n, carry, masked):
         j = idx_ref[row * nk + n]
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
-        p = jnp.exp(_masked_scores(q, kj, rng, j * bk, scale, masked) - lse)
-        dp = lax.dot_general(do, vj, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jnp.dot(ds.astype(kj.dtype), kj,
-                                preferred_element_type=jnp.float32)
+        if masked:                  # one mask a tile, added by every head
+            dead = jnp.where(_rows_live(rb_ref, j * bk, bk), 0.0, NEG_INF)
+        for h in range(hb):
+            s = _scores(q_ref[_head(q_ref, h, D)], kj, scale, False)
+            if masked:
+                s = s + dead
+            p = jnp.exp(s - _lanes(lse_b[h], bk))
+            dp = lax.dot_general(do_ref[_head(do_ref, h, Dv)], vj,
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(delta_b[h], bk)) * scale
+            acc_ref[h] += jnp.dot(ds.astype(kj.dtype), kj,
+                                  preferred_element_type=jnp.float32)
+        return carry
 
-    dq = _two_loops(nfull_ref[row], nlive_ref[row], step,
-                    jnp.zeros((bq, D), jnp.float32))
-    dq_ref[_head(dq_ref, 0, D)] = dq.astype(dq_ref.dtype)
+    _two_loops(nfull_ref[row], nlive_ref[row], step, 0)
+    for h in range(hb):
+        dq_ref[_head(dq_ref, h, D)] = acc_ref[h].astype(dq_ref.dtype)
 
 
 def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq, P, g,
+                 r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, P, g,
                  per_batch):
+    """One live (key tile, query tile) pair of a kv head's ``g`` query
+    heads (static, unrolled).  The tile is formed keys by queries, ``S^T =
+    K Q^T [bk, bq]``: a query's ``lse``, ``delta`` and ranges (``r_ref [1,
+    4, bq]``, a row a bound) then lie along the lanes as they are stored
+    and go down the sublanes for nothing, and ``dv = P^T do`` and ``dk =
+    dS^T q`` are plain ``[bk, bq] x [bq, D]`` products."""
     (bk, D), Dv = k_ref.shape[-2:], v_ref.shape[-1]
+    bq = r_ref.shape[-1]
     at = ((pl.program_id(0) * P if per_batch else 0) + pl.program_id(2)) * 4
     j, i, cls, flags = (tbl_ref[at + c] for c in range(4))
+    keys_by_queries = (((1,), (1,)), ((), ()))
 
     @pl.when(flags % 2 == 1)
     def _():
@@ -725,23 +770,24 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def pair(masked):
         kb, vb = k_ref[_head(k_ref, 0, D)], v_ref[_head(v_ref, 0, Dv)]
-        rng = r_ref[0]
+        if masked:
+            keys = lax.broadcasted_iota(jnp.int32, (bk, bq), 0) + j * bk
+            live = _in_ranges(keys, *(r_ref[0, c:c + 1, :]
+                                      for c in range(4)))
         dk = dv = None
         for hq in range(g):           # static: the kv head's query heads
             qi, doi = q_ref[_head(q_ref, hq, D)], do_ref[_head(do_ref, hq, Dv)]
-            lse = lse_ref[0, hq, i, :].reshape(bq, 1)
-            delta = delta_ref[0, hq, i, :].reshape(bq, 1)
-            p = jnp.exp(_masked_scores(qi, kb, rng, j * bk, scale, masked)
-                        - lse)
-            dv = _add(dv, lax.dot_general(
-                p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            dp = lax.dot_general(doi, vb, (((1,), (1,)), ((), ())),
+            s = _scores(kb, qi, scale, False)
+            if masked:
+                s = jnp.where(live, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[0, hq, pl.ds(i, 1), :])
+            dv = _add(dv, jnp.dot(p.astype(doi.dtype), doi,
+                                  preferred_element_type=jnp.float32))
+            dp = lax.dot_general(vb, doi, keys_by_queries,
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk = _add(dk, lax.dot_general(
-                ds.astype(qi.dtype), qi, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            ds = p * (dp - delta_ref[0, hq, pl.ds(i, 1), :]) * scale
+            dk = _add(dk, jnp.dot(ds.astype(qi.dtype), qi,
+                                  preferred_element_type=jnp.float32))
         dk_acc[...] += dk
         dv_acc[...] += dv
 
@@ -821,18 +867,38 @@ def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None):
     return blocks, scratch, hb * bq * bk * (8 + itemsize)
 
 
-def _fwd_heads(g, bq, bk, D, nq, Tk, itemsize, Dv=None):
-    """Query heads a masked forward grid step takes: the most of one GQA
-    group (a divisor of ``g``: they share the resident k and v) that
-    :func:`_fwd_step_bytes` keeps under ``_MASKED_STEP_VMEM``, one where
-    not even two fit."""
+def _dq_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None):
+    """The same for a ``dq`` grid step of ``hb`` heads: whole k and v,
+    ``hb`` tiles of q, ``do`` and ``dq`` with their rows of lse and of
+    delta, the ranges; the accumulators, the two statistics and the ranges
+    over the lanes; and a head's two ``[bq, bk]`` fp32 tiles in flight
+    (the probabilities take the scores' place and ``ds`` takes ``dp``'s)
+    with ``ds`` cast."""
+    Dv = D if Dv is None else Dv
+    blocks = (Tk * (D + Dv) * itemsize
+              + hb * (bq * (2 * D + Dv) * itemsize + 2 * nq * bq * 4)
+              + bq * _LANES * 4)
+    scratch = (hb * bq * (2 * _LANES + D) + 4 * bq * _LANES) * 4
+    return blocks, scratch, hb * bq * bk * (8 + itemsize)
+
+
+def _heads_a_step(step_bytes, g, *shapes):
+    """Query heads a grid step of ``hvd_flash_fwd`` or ``hvd_flash_dq``
+    takes: the most of one GQA group (a divisor of ``g``: they share the
+    resident k and v) whose ``step_bytes(hb, *shapes)`` stay under
+    ``_MASKED_STEP_VMEM``, one where not even two fit."""
     def fits(hb):
-        blocks, scratch, tiles = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
-                                                 itemsize, Dv)
+        blocks, scratch, tiles = step_bytes(hb, *shapes)
         return 2 * blocks + scratch + tiles <= _MASKED_STEP_VMEM
 
     return max(hb for hb in range(1, g + 1)
                if g % hb == 0 and (hb == 1 or fits(hb)))
+
+
+# (g, bq, bk, D, nq, Tk, itemsize, Dv=None) -> heads a step; dq never more
+# than the forward, whose step holds less
+_fwd_heads = functools.partial(_heads_a_step, _fwd_step_bytes)
+_dq_heads = functools.partial(_heads_a_step, _dq_step_bytes)
 
 
 def _masked_dims(q, k, v, widths):
@@ -890,10 +956,11 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     g = H // Hkv
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
+    item = q.dtype.itemsize
+    hb = _dq_heads(g, bq, bk, D, nq, Tk, item, Dv)
     ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
     tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
-                                                        nq, g, bm)
-    item = q.dtype.itemsize
+                                                        nq, g, bm, hb)
 
     # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
     # When the caller differentiates through the exposed lse (ring-step
@@ -909,16 +976,20 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     for kernel in ("dq", "dkv"):
         _count(kernel, "masked", rows)
         _count_tiles(kernel, classes)
+    blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv)
     dq = pl.pallas_call(
         functools.partial(_mdq_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
                           per_batch=per_batch),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, H, nq),
+            num_scalar_prefetch=3, grid=(B, H // hb, nq),
             in_specs=[tile, whole, vwhole, otile, stats, stats, rng],
-            out_specs=tile),
+            out_specs=tile,
+            scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32),
+                            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
         out_shape=_sds(_heads_shape(rows, B, H, T, D), q.dtype, q, k, v, do),
-        compiler_params=_vmem(Tk * (D + Dv) * item,
-                              bq * (2 * D + Dv) * item, bq * _LANES * 4),
+        compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_INTERPRET,
         name="hvd_flash_dq",
     )(*_row_tables(classes), q, k, v, do, lse, delta, ranges)
@@ -934,15 +1005,16 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
         rows, 1, bk, d, lambda b, c, p, t: (b, c, t[at(b, p)]))
     row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_mdkv_kernel, scale=scale, bq=bq, P=P, g=g,
+        functools.partial(_mdkv_kernel, scale=scale, P=P, g=g,
                           per_batch=per_batch),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hkv, P),
             in_specs=[
                 q_blk(D), kv_blk(D), kv_blk(Dv), q_blk(Dv), row_blk,
                 row_blk,
-                pl.BlockSpec((1, bq, 4),
-                             lambda b, c, p, t: (bm(b), t[at(b, p) + 1], 0)),
+                # the query tile's ranges, a row a bound: [Bm, 4, T]
+                pl.BlockSpec((1, 4, bq),
+                             lambda b, c, p, t: (bm(b), 0, t[at(b, p) + 1])),
             ],
             out_specs=[kv_blk(D), kv_blk(Dv)],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
@@ -953,10 +1025,11 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
         ],
         compiler_params=_vmem(g * bq * (D + Dv) * item,
                               2 * bk * (D + Dv) * item,
-                              2 * g * T * 4, bq * _LANES * 4),
+                              2 * g * T * 4, 8 * bq * 4,
+                              scratch=bk * (D + Dv) * 4),
         interpret=_INTERPRET,
         name="hvd_flash_dkv",
-    )(table, q, k, v, do, lse, delta, ranges)
+    )(table, q, k, v, do, lse, delta, ranges.transpose(0, 2, 1))
     return dq, dk, dv
 
 

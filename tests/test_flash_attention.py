@@ -322,16 +322,22 @@ def _eqns(jaxpr, path=()):
             yield from _eqns(sub, path + (eqn.primitive.name,))
 
 
-def _pallas_calls(fn, *args):
-    """[(name, grid, [block shapes])] of every pallas_call ``fn`` traces."""
+def _pallas_calls(fn, *args, scratch=False):
+    """[(name, grid, [block shapes])] of every pallas_call ``fn`` traces;
+    with ``scratch`` a fourth entry, [(scratch shape, dtype)]."""
     found = []
     for _, eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
         if eqn.primitive.name == "pallas_call":
             gm = eqn.params["grid_mapping"]
-            found.append((
+            call = (
                 eqn.params["name"], tuple(gm.grid),
                 [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
-                 for bm in gm.block_mappings]))
+                 for bm in gm.block_mappings])
+            if scratch:
+                invars = eqn.params["jaxpr"].invars
+                held = invars[len(invars) - gm.num_scratch_operands:]
+                call += ([(v.aval.shape, str(v.aval.dtype)) for v in held],)
+            found.append(call)
     return found
 
 
@@ -389,11 +395,13 @@ def test_pack_rule():
 
 def test_causal_over_several_blocks_builds_the_masked_kernels(monkeypatch):
     """``causal=True`` without ``mask=``: the masked family's three calls,
-    grids and blocks as ``test_masked_forward_specs`` pins them (both of a
-    group's two heads a forward step; ``dkv`` one step a live pair of
-    tiles, holding one query tile of the group), on the tile classes of
-    :func:`causal_ranges`; at ``head_dim`` 128 the blocks are cut from the
-    caller's ``[B, T, H*D]``, a head a lane block."""
+    grids and blocks as ``test_masked_forward_specs`` and
+    ``test_masked_backward_specs`` pin them (both of a group's two heads a
+    forward step and a ``dq`` step; ``dkv`` one step a live pair of
+    tiles, holding one query tile of the group and its ranges a row a
+    bound), on the tile classes of :func:`causal_ranges`; at ``head_dim``
+    128 the blocks are cut from the caller's ``[B, T, H*D]``, a head a
+    lane block."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv, D = 1, 2048, 4, 2, 128
     bq = bk = 512
@@ -404,13 +412,14 @@ def test_causal_over_several_blocks_builds_the_masked_kernels(monkeypatch):
     q, k, v = (jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16)
                for h in (H, Hkv, Hkv))
     calls = _pallas_calls(lambda q, k, v: _grad_all(q, k, v, True), q, k, v)
-    qb, kvb, row, rng = (1, bq, D), (1, T, D), (1, 1, nq, bq), (1, bq, 4)
+    kvb, rng = (1, T, D), (1, bq, 4)
     grp, tile, rows = (1, bq, g * D), (1, bk, D), (1, g, nq, bq)
     assert calls == [
         ("hvd_flash_fwd", (B, H // g, nq), [grp, kvb, kvb, rng, grp, rows]),
-        ("hvd_flash_dq", (B, H, nq), [qb, kvb, kvb, qb, row, row, rng, qb]),
+        ("hvd_flash_dq", (B, H // g, nq),
+         [grp, kvb, kvb, grp, rows, rows, rng, grp]),
         ("hvd_flash_dkv", (B, Hkv, 10),
-         [grp, tile, tile, grp, rows, rows, rng, tile, tile]),
+         [grp, tile, tile, grp, rows, rows, (1, 4, bq), tile, tile]),
     ]
 
 
@@ -589,6 +598,75 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
         for kernel in ("fwd", "dq", "dkv")}
 
 
+# How many query heads a ``dq`` grid step takes, at every branch of
+# ``_dq_heads``, on both layouts: (H, Hkv, D, Dv), the step's VMEM budget,
+# the heads.  ``dkv`` takes the whole group a step whatever that says.
+BACKWARD_CASES = {
+    "g8-whole-group": ((8, 1, 64, 64), None, 8),
+    "g8-split-group": ((8, 1, 64, 64), 4 << 20, 4),
+    "g8-one-head": ((8, 1, 64, 64), 1 << 20, 1),      # not even two fit
+    "g2-values-twice-as-wide": ((4, 2, 64, 128), None, 2),   # the Phi call
+    "g1-d128": ((2, 2, 128, 128), None, 1),
+    "g8-d128": ((8, 1, 128, 128), None, 8),
+    "g4-d128-split-group": ((8, 2, 128, 128), 4 << 20, 2),
+}
+BACKWARD_MASKS = (
+    [("block-diffusion", per_batch, heads) for heads in BACKWARD_CASES
+     for per_batch in (False, True)]
+    + [(mask, False, heads) for mask in ("causal", "window")
+       for heads in ("g2-values-twice-as-wide", "g8-d128")])
+
+
+@pytest.mark.parametrize(
+    "mask,per_batch,heads", BACKWARD_MASKS,
+    ids=[f"{mask}-{'mask-per-row' if per_batch else 'one-mask'}-{heads}"
+         for mask, per_batch, heads in BACKWARD_MASKS])
+def test_masked_backward_matches_dense_masked_attention(mask, per_batch,
+                                                        heads, monkeypatch):
+    """``dq``, ``dk`` and ``dv`` of a loss on ``out`` AND on ``lse`` (whose
+    cotangent folds into ``delta`` before the kernels) against dense masked
+    attention: a ``dq`` step taking a whole GQA group, a part of one, or
+    one head; values twice as wide as keys; transposed around the kernels
+    at ``head_dim`` 64, on the caller's layout at 128; the mask known where
+    the call is built or traced a batch row; a query tile whose live tiles
+    are all mixed and one with a single live tile."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    (H, Hkv, D, Dv), budget, hb = BACKWARD_CASES[heads]
+    if budget is not None:
+        monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
+    B, T = 2, 512
+    assert fa._dq_heads(H // Hkv, 128, 128, D, T // 128, T, 4, Dv) == hb
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(B, T, h, d), jnp.float32)
+               for h, d in ((H, D), (Hkv, D), (Hkv, Dv)))
+    w_out = jnp.asarray(rng.randn(B, T, H, Dv), jnp.float32)
+    w_lse = jnp.asarray(rng.randn(B, H, T), jnp.float32)
+    ranges = MASKS[mask](T)
+    live = jnp.asarray(fa.dense_mask(ranges, T))
+    given = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
+    if mask == "block-diffusion":
+        classes = fa.tile_classes(ranges[None], 128, 128, T)[0]
+        n_full, n_live = (classes == 2).sum(-1), (classes >= 1).sum(-1)
+        assert ((n_full == 0) & (n_live >= 2)).any() and (n_live == 1).any()
+
+    def loss(attend):
+        def of(q, k, v):
+            out, lse = attend(q, k, v)
+            return (out * w_out).sum() + (lse * w_lse).sum()
+        return of
+
+    got = jax.grad(loss(lambda q, k, v: fa.flash_attention_lse(
+        q, k, v, mask=given)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: (
+        _dense_masked(q, k, v, live), _dense_masked_lse(q, k, live))),
+        (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+
+
 def _masked_routes(q, k, v, mask, scale, w_out, w_lse):
     """``(out, lse, dq, dk, dv)`` of the masked kernels on the caller's
     layout (``rows``) and transposed around them (``heads``), each brought
@@ -686,12 +764,45 @@ def test_forward_heads_a_step_rule():
                                            <= fa._MASKED_STEP_VMEM)
 
 
+def test_backward_heads_a_step_rule():
+    # the benchmark's SDAR cell: four of a group's eight heads a step
+    assert fa._dq_heads(8, 512, 512, 128, 16, 8192, 2) == 4
+    # the Phi cell's calls: both heads of a pair, values twice as wide
+    assert fa._dq_heads(2, 512, 512, 64, 16, 8192, 2, 128) == 2
+    # Llama-3-8B's heads under causal training at 4,096 positions
+    assert fa._dq_heads(4, 512, 512, 128, 8, 4096, 2) == 4
+    # one query head a kv head: nothing to share
+    assert fa._dq_heads(1, 512, 512, 128, 16, 8192, 2) == 1
+    # short sequences: the whole group
+    assert fa._dq_heads(8, 128, 128, 64, 4, 512, 4) == 8
+    # float32 at head_dim 256: a part of the group, as the forward
+    assert fa._dq_heads(8, 512, 512, 256, 8, 4096, 4) == 2
+    # its step holds do and dq too: at 32,768 positions of head_dim 64 two
+    # heads where the forward takes four
+    assert fa._dq_heads(8, 512, 512, 64, 64, 32768, 2) == 2
+    assert fa._fwd_heads(8, 512, 512, 64, 64, 32768, 2) == 4
+    # whatever is chosen divides the group and fits, or is one head
+    for g in (1, 2, 3, 4, 6, 8, 16):
+        for bq in (128, 256, 512):
+            for D, Dv in ((64, 64), (64, 128), (128, 128), (256, 256)):
+                for T in (1024, 8192, 32768):
+                    for itemsize in (2, 4):
+                        shapes = (bq, bq, D, T // bq, T, itemsize, Dv)
+                        hb = fa._dq_heads(g, *shapes)
+                        assert g % hb == 0
+                        blocks, scratch, tiles = fa._dq_step_bytes(
+                            hb, *shapes)
+                        assert hb == 1 or (2 * blocks + scratch + tiles
+                                           <= fa._MASKED_STEP_VMEM)
+                        # never more than the forward, which holds less
+                        assert hb <= fa._fwd_heads(g, *shapes)
+
+
 def test_masked_forward_specs(monkeypatch):
     """A forward grid step's blocks: four of a group's eight query tiles
-    on their kv head's whole keys and values; ``dq`` keeps one head a
-    step.  At ``head_dim`` 128 every block is cut from the caller's
-    layout: four heads are 512 lanes of ``[B, T, H*D]``, a kv head's keys
-    128 lanes of ``[B, T, Hkv*D]``."""
+    on their kv head's whole keys and values.  At ``head_dim`` 128 every
+    block is cut from the caller's layout: four heads are 512 lanes of
+    ``[B, T, H*D]``, a kv head's keys 128 lanes of ``[B, T, Hkv*D]``."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv, D = 2, 2048, 16, 2, 128
     bq, nq, g = 512, 4, 4
@@ -707,9 +818,51 @@ def test_masked_forward_specs(monkeypatch):
     assert calls["hvd_flash_fwd"] == ((B, H // g, nq), [
         (1, bq, g * D), kvb, kvb, (1, bq, 4), (1, bq, g * D),
         (1, g, nq, bq)])
-    qb, row = (1, bq, D), (1, 1, nq, bq)
-    assert calls["hvd_flash_dq"] == ((B, H, nq), [
-        qb, kvb, kvb, qb, row, row, (1, bq, 4), qb])
+
+
+@pytest.mark.parametrize("D,Dv", [(128, 128), (64, 128)],
+                         ids=["rows", "heads-values-128"])
+def test_masked_backward_specs(D, Dv, monkeypatch):
+    """The backward's two calls.  ``dq``: the forward's grid, ``hb`` heads
+    of a group a step on their kv head's whole keys and values, with
+    ``do``, their rows of ``lse`` and of ``delta`` and the tile's ranges;
+    in scratch the float32 accumulator ``[hb, bq, D]``, ``lse`` and
+    ``delta`` as columns over the lanes and the ranges over the lanes.
+    ``dkv``: a step a live pair of tiles, the group's query tiles on one
+    key tile, the ranges a row a bound ``[1, 4, bq]``, two float32
+    accumulators.  On the caller's layout at ``head_dim`` 128, transposed
+    around the kernels at 64 (values 128 wide: the Phi call)."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    B, T, H, Hkv = 2, 2048, 16, 4
+    bq, nq, g = 512, 4, 4
+    hb = fa._dq_heads(g, bq, bq, D, nq, T, 2, Dv)
+    assert hb == 4
+    q, k, v = (jax.ShapeDtypeStruct((B, T, h, d), jnp.bfloat16)
+               for h, d in ((H, D), (Hkv, D), (Hkv, Dv)))
+    ranges = _block_diffusion_ranges(T // 2, 4)
+    classes = fa.tile_classes(ranges[None], bq, bq, T)
+    P = fa._pair_table(classes)[1]
+    assert P == int((classes >= 1).sum())
+    calls = {name: rest for name, *rest in _pallas_calls(
+        lambda q, k, v: jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, mask=ranges).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v), q, k, v, scratch=True)}
+    if D % 128 == 0:
+        blk = lambda heads, n, d: (1, n, heads * d)
+    else:
+        blk = lambda heads, n, d: (1, heads, n, d)
+    stats = lambda heads: (1, heads, nq, bq)
+    f32 = lambda *shape: (shape, "float32")
+    assert calls["hvd_flash_dq"] == [(B, H // hb, nq), [
+        blk(hb, bq, D), blk(1, T, D), blk(1, T, Dv), blk(hb, bq, Dv),
+        stats(hb), stats(hb), (1, bq, 4), blk(hb, bq, D)],
+        [f32(hb, bq, D), f32(hb, bq, 128), f32(hb, bq, 128),
+         ((4, bq, 128), "int32")]]
+    assert calls["hvd_flash_dkv"] == [(B, Hkv, P), [
+        blk(g, bq, D), blk(1, bq, D), blk(1, bq, Dv), blk(g, bq, Dv),
+        stats(g), stats(g), (1, 4, bq), blk(1, bq, D), blk(1, bq, Dv)],
+        [f32(bq, D), f32(bq, Dv)]]
 
 
 def test_causal_over_several_blocks_agrees_with_dense(monkeypatch):

@@ -95,7 +95,11 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     was at the commit before the masked kernels learnt to read the
     caller's layout (PR 33's tree, jax 0.9.0): half a lane tile a head
     stays on the transposed route, built as it was.  A later change to
-    this program changes the hash and states it here."""
+    this program changes the hash and states it here: PR 37, the masked
+    backward's kernel bodies (``dq`` both heads of a pair a step with its
+    accumulator, ``lse`` / ``delta`` columns and spread ranges in scratch;
+    ``dkv``'s tile keys by queries on ranges handed over ``[Bm, 4, T]``);
+    the block specs and everything around the kernels as they were."""
     import hashlib
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_BLOCK", 128)
@@ -106,7 +110,7 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     text = jax.jit(jax.value_and_grad(lambda h, ls: hybrid.layer_stack(
         h, ls, cfg, llama.remat_policy("full")).astype(jnp.float32).sum(),
         (0, 1))).lower(h, layers).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "d1661b6db1d6495d"
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "f9c9e5ce95fd15d3"
 
 
 def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
