@@ -1,24 +1,34 @@
 """A decoder trunk whose layers are of several kinds and hand memory to
-one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash).
+one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash) and
+the Mamba-2 hybrids (GraniteMoeHybrid; the mixer from arXiv 2405.21060).
 
 ``LlamaConfig.layer_kinds`` names each layer's kind; :mod:`.llama`'s
 ``init_params``, ``param_specs``, ``count_params`` and ``hidden`` come
-here when it is set.  Every layer is
+here when it is set.
 
-    h = x + Mixer(LN1(x));  y = h + W2 (silu(g) * v),  [g ; v] = W1 LN2(h)
+**The frame, which is the config's.**  Every layer is
 
-with LayerNorm (weight and bias), no bias in a product, no positional
-encoding anywhere.  The kinds are their mixers:
+    h = x + r Mixer(Norm1(x));  y = h + r W2 (silu(g) * v),  [g ; v] = W1 Norm2(h)
 
-* ``mamba`` — Mamba-1: ``[xs ; z] = W_in u``; ``xs = silu(conv(xs) +
-  b)``, depthwise, causal, ``ssm_conv`` wide; ``[r ; B ; C] = W_x xs``;
+with no bias in a product and no positional encoding anywhere.
+``cfg.trunk_norm`` says which norm (``layernorm``: weight and bias;
+``rmsnorm``: weight), for the layers' two and the final one;
+``cfg.residual_multiplier`` is ``r``; the embedding's and the logits'
+multipliers are applied in :mod:`.llama`, the softmax's scale
+(``cfg.attention_multiplier``) in the ``attention`` kind.  Each at its
+default computes what the trunk computed before the config carried it.
+
+**The kinds, which are a family's**: their mixers.
+
+* ``mamba`` — Mamba-1 (SambaY): ``[xs ; z] = W_in u``; ``xs = silu(conv(xs)
+  + b)``, depthwise, causal, ``ssm_conv`` wide; ``[r ; B ; C] = W_x xs``;
   ``delta = softplus(W_dt r + b_dt)``; ``A = -exp(A_log)``; ``s`` the
   selective scan (:mod:`horovod_tpu.ops.selective_scan`); the mixer gives
   ``W_out (s * silu(z))``.  The last one before a ``gmu`` also emits ``m
   = s``, the memory.
-* ``window``, ``full`` — differential attention (arXiv 2410.05258):
-  adjacent heads pair, ``A1 = softmax(q1 k1^T / sqrt(Dh) + M)``, ``A2``
-  of the pair's second heads, values the pair's ``[v ; v']``; ``o =
+* ``window``, ``full`` — differential attention (arXiv 2410.05258;
+  SambaY): adjacent heads pair, ``A1 = softmax(q1 k1^T / sqrt(Dh) + M)``,
+  ``A2`` of the pair's second heads, values the pair's ``[v ; v']``; ``o =
   RMSNorm((A1 - lambda A2) [v ; v']) (1 - lambda_init)`` with ``lambda =
   exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` and ``lambda_init =
   0.8 - 0.6 exp(-0.3 i)``, ``i`` the layer's published index
@@ -28,11 +38,22 @@ encoding anywhere.  The kinds are their mixers:
 * ``cross`` — the same with ``W_q`` and ``W_o`` only, attending
   causally to the ``full`` layer's ``k`` and ``v``.
 * ``gmu`` — the gated memory unit, ``W_out (silu(W_in u) * m)``.
+* ``mamba2`` — Mamba-2 (Granite): ``[z ; xBC ; dt] = W_in u``; ``xBC =
+  silu(conv(xBC) + b)`` over all its channels; ``[x ; B ; C] = xBC``, ``x``
+  in ``ssm_heads`` heads, ``B`` and ``C`` of ``ssm_state`` columns shared
+  by a group's heads; ``delta = softplus(dt + dt_bias)`` and ``A =
+  -exp(A_log)`` a head; ``y`` the chunked scan of a matrix state a head
+  (:mod:`horovod_tpu.ops.ssd_scan`); the mixer gives ``W_out (RMSNorm(y *
+  silu(z)) * w)``, the gate before the norm, over all channels at once.
+* ``attention`` — plain grouped-query attention (Granite): ``softmax(s q
+  k^T + causal) v`` through ``W_o``, ``s = cfg.attention_multiplier`` (0 =
+  ``1 / sqrt(Dh)``).
 
 Attention goes through ``ring_attention.local_attention`` with the mask
-as key ranges (``window_ranges``, ``causal_ranges``), two calls a layer:
-``(q1, k1, [v ; v'])`` and ``(q2, k2, [v ; v'])``, the masked flash
-kernels taking values twice as wide as queries and keys.
+as key ranges (``window_ranges``, ``causal_ranges``); differential
+attention in two calls a layer, ``(q1, k1, [v ; v'])`` and ``(q2, k2, [v
+; v'])``, the masked flash kernels taking values twice as wide as queries
+and keys.
 
 Parameters are a tree per kind, each leaf stacked over the kind's layers;
 :func:`layer_stack` runs the kinds in order, scanning runs of equal
@@ -46,9 +67,10 @@ ssm_inner x 2 + 2 x B x T x Hkv x Dh x 2`` bytes in bf16 (126 MB at
 again in the backward pass like any other, their scan included.
 
 ``hvd_layer_kind_total{kind}`` counts the layers traced; the mixers run
-under the scopes ``hvd_ssm_mixer``, ``hvd_gmu`` and
-``hvd_diff_attention``.  Plain data parallelism only: nothing here is
-sharded over a tensor-, sequence- or pipeline-parallel axis yet.
+under the scopes ``hvd_ssm_mixer``, ``hvd_gmu``, ``hvd_diff_attention``,
+``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``) and
+``hvd_attention``.  Plain data parallelism only: nothing here is sharded
+over a tensor-, sequence- or pipeline-parallel axis yet.
 """
 
 from __future__ import annotations
@@ -63,10 +85,13 @@ from jax import lax
 from .. import metrics as _metrics
 from ..ops import flash_attention as _fa
 from ..ops.selective_scan import selective_scan
+from ..ops.ssd_scan import ssd_scan
 from ..parallel.ring_attention import local_attention
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
+from .llama import _rmsnorm
 
-KINDS = ("mamba", "window", "full", "gmu", "cross")
+KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention")
+_DIFFERENTIAL = ("window", "full", "cross")
 _MATRICES = ("w1", "w2", "in_proj", "x_proj", "dt_proj", "out_proj", "wqkv",
              "wq", "wo")
 
@@ -89,9 +114,17 @@ def check(cfg) -> None:
         if kind in kinds and source not in kinds[:kinds.index(kind)]:
             raise ValueError(f"a {kind} layer needs a {source} layer "
                              "before it")
-    if cfg.n_heads % 2 or cfg.n_kv_heads % 2:
+    if set(kinds) & set(_DIFFERENTIAL) and (cfg.n_heads % 2
+                                            or cfg.n_kv_heads % 2):
         raise ValueError("differential attention pairs adjacent heads: "
                          "n_heads and n_kv_heads must be even")
+    if "mamba2" in kinds and (
+            cfg.ssm_heads <= 0 or cfg.ssm_inner % cfg.ssm_heads
+            or cfg.ssm_groups <= 0 or cfg.ssm_heads % cfg.ssm_groups):
+        raise ValueError(
+            "a mamba2 layer needs ssm_heads dividing ssm_inner and "
+            f"ssm_groups dividing ssm_heads, got {cfg.ssm_heads} heads of "
+            f"{cfg.ssm_inner} channels in {cfg.ssm_groups} groups")
 
 
 def published_kinds(n_layers: int):
@@ -120,7 +153,17 @@ def layer_shapes(cfg, kind):
     Di, N, Kc, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank
     shapes = {"norm1_w": (D,), "norm1_b": (D,), "norm2_w": (D,),
               "norm2_b": (D,), "w1": (D, 2 * F), "w2": (F, D)}
-    if kind == "mamba":
+    if cfg.trunk_norm == "rmsnorm":
+        del shapes["norm1_b"], shapes["norm2_b"]
+    if kind == "mamba2":
+        Hs, conv = cfg.ssm_heads, Di + 2 * cfg.ssm_groups * N
+        shapes.update({
+            "in_proj": (D, Di + conv + Hs), "conv_w": (Kc, conv),
+            "conv_b": (conv,), "dt_bias": (Hs,), "A_log": (Hs,), "D": (Hs,),
+            "gate_norm": (Di,), "out_proj": (Di, D)})
+    elif kind == "attention":
+        shapes.update({"wqkv": (D, (H + 2 * Hkv) * Dh), "wo": (H * Dh, D)})
+    elif kind == "mamba":
         shapes.update({
             "in_proj": (D, 2 * Di), "conv_w": (Kc, Di), "conv_b": (Di,),
             "x_proj": (Di, R + 2 * N), "dt_proj": (R, Di), "dt_bias": (Di,),
@@ -146,14 +189,16 @@ def count_params(cfg) -> int:
                          for s in layer_shapes(cfg, kind).values())
                  for kind, n in _counts(cfg).items())
     return ((1 if cfg.tie_embeddings else 2) * cfg.vocab_size * cfg.d_model
-            + layers + 2 * cfg.d_model)
+            + layers + (2 if cfg.trunk_norm == "layernorm" else 1)
+            * cfg.d_model)
 
 
 def init_layers(cfg, key):
     """{kind: {leaf: [layers of the kind, ...]}}: matrices normal at
     ``fan_in ** -0.5``, norms at 1 and 0, and Mamba's own: ``A_log =
     log(1..N)``, ``D = 1``, ``softplus(dt_bias)`` log-uniform on 1e-3 ..
-    1e-1; ``lambda``'s vectors normal(0, 0.1)."""
+    1e-1; ``lambda``'s vectors normal(0, 0.1).  Mamba-2's ``A_log =
+    log(uniform(1, 16))`` a head."""
     check(cfg)
     dt = cfg.param_dtype
     out = {}
@@ -162,10 +207,12 @@ def init_layers(cfg, key):
         for b, (name, shape) in enumerate(layer_shapes(cfg, kind).items()):
             k = jax.random.fold_in(jax.random.fold_in(key, a), b)
             full = (n,) + shape
-            if name in ("norm1_w", "norm2_w", "subln", "D"):
+            if name in ("norm1_w", "norm2_w", "subln", "D", "gate_norm"):
                 leaf = jnp.ones(full, dt)
             elif name in ("norm1_b", "norm2_b", "conv_b"):
                 leaf = jnp.zeros(full, dt)
+            elif name == "A_log" and kind == "mamba2":
+                leaf = jnp.log(jax.random.uniform(k, full, dt, 1.0, 16.0))
             elif name == "A_log":
                 leaf = jnp.broadcast_to(
                     jnp.log(jnp.arange(1, shape[1] + 1, dtype=dt)), full)
@@ -196,17 +243,24 @@ def _mlp(u, lp):
     return (jax.nn.silu(g) * v) @ lp["w2"]
 
 
+def _conv_silu(xs, lp, Kc):
+    """``silu(conv(xs) + b)``: depthwise, causal, ``Kc`` wide, in float32;
+    back in ``xs``'s dtype."""
+    f32 = jnp.float32
+    T = xs.shape[1]
+    padded = jnp.pad(xs.astype(f32), ((0, 0), (Kc - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(padded[:, j:j + T] * lp["conv_w"][j].astype(f32)
+                           for j in range(Kc))
+                       + lp["conv_b"].astype(f32)).astype(xs.dtype)
+
+
 def _mamba(u, lp, cfg):
     """-> (the mixer's output ``[B, T, D]``, the scan's output ``s [B, T,
     ssm_inner]`` before the gate)."""
-    N, R, Kc = cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    N, R = cfg.ssm_state, cfg.ssm_dt_rank
     f32 = jnp.float32
-    T = u.shape[1]
     xs, z = jnp.split(u @ lp["in_proj"], 2, axis=-1)
-    padded = jnp.pad(xs.astype(f32), ((0, 0), (Kc - 1, 0), (0, 0)))
-    xs = jax.nn.silu(sum(padded[:, j:j + T] * lp["conv_w"][j].astype(f32)
-                         for j in range(Kc))
-                     + lp["conv_b"].astype(f32)).astype(u.dtype)
+    xs = _conv_silu(xs, lp, cfg.ssm_conv)
     r, Bm, Cm = jnp.split(xs @ lp["x_proj"], (R, R + N), axis=-1)
     delta = jax.nn.softplus(
         jnp.dot(r, lp["dt_proj"], preferred_element_type=f32)
@@ -214,6 +268,30 @@ def _mamba(u, lp, cfg):
     s = selective_scan(xs, delta, -jnp.exp(lp["A_log"].astype(f32)), Bm, Cm,
                        lp["D"].astype(f32))
     return (s * jax.nn.silu(z)) @ lp["out_proj"], s
+
+
+def _mamba2(u, lp, cfg):
+    """The Mamba-2 mixer's output ``[B, T, D]``."""
+    from ..training import SCOPE_SSD_SCAN
+    f32 = jnp.float32
+    B, T, _ = u.shape
+    Di, Hs, G, N = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
+    conv = Di + 2 * G * N
+    # the step's 64 columns come out of their product in float32: a decay
+    # is the exponential of up to a chunk's sum of them
+    z, xBC = jnp.split(u @ lp["in_proj"][:, :Di + conv], (Di,), axis=-1)
+    dt = jnp.dot(u, lp["in_proj"][:, Di + conv:], preferred_element_type=f32)
+    x, Bm, Cm = jnp.split(_conv_silu(xBC, lp, cfg.ssm_conv),
+                          (Di, Di + G * N), axis=-1)
+    delta = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
+    with jax.named_scope(SCOPE_SSD_SCAN):
+        y = ssd_scan(x.reshape(B, T, Hs, Di // Hs), delta,
+                     -jnp.exp(lp["A_log"].astype(f32)),
+                     Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N),
+                     lp["D"].astype(f32), cfg.ssm_chunk)
+    y = y.reshape(B, T, Di).astype(f32) * jax.nn.silu(z.astype(f32))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    return (y * lp["gate_norm"].astype(f32)).astype(u.dtype) @ lp["out_proj"]
 
 
 def _pairs(x):
@@ -249,13 +327,30 @@ def key_ranges(kind, T, cfg):
     return _fa.causal_ranges(T)
 
 
+def norm(x, w, b, cfg):
+    """The trunk's norm, by ``cfg.trunk_norm``; ``b`` is None for
+    ``rmsnorm``."""
+    if cfg.trunk_norm == "rmsnorm":
+        return _rmsnorm(x, w, cfg.norm_eps)
+    return layer_norm(x, w, b, cfg.norm_eps)
+
+
+def join(x, y, cfg):
+    """The residual stream takes a sublayer's output, times
+    ``cfg.residual_multiplier`` (in float32, rounded once)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + y
+    return (x.astype(jnp.float32) + cfg.residual_multiplier
+            * y.astype(jnp.float32)).astype(x.dtype)
+
+
 def _layer(kind, emits, cfg):
     """One layer of ``kind`` as ``f(h, lp, lam0, memory) -> (h, emitted)``:
     ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer, else
     None; ``emitted`` is what an emitting mamba (``s``) or full layer
     (``(k, v)``) hands on, else None."""
-    from ..training import (SCOPE_DIFF_ATTENTION, SCOPE_GMU, SCOPE_MLP,
-                            SCOPE_SSM_MIXER)
+    from ..training import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
+                            SCOPE_MLP, SCOPE_SSD_MIXER, SCOPE_SSM_MIXER)
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def f(h, lp, lam0, memory):
@@ -265,16 +360,35 @@ def _layer(kind, emits, cfg):
         lp = {n: w.astype(cfg.dtype) if n in _MATRICES else w
               for n, w in lp.items()}
         B, T, _ = h.shape
-        u = layer_norm(h, lp["norm1_w"], lp["norm1_b"], cfg.norm_eps)
+        norm1 = lambda: norm(h, lp["norm1_w"], lp.get("norm1_b"), cfg)
         emitted = None
-        if kind == "mamba":
+        # the two kinds of PR 40 open their scope around the sublayer with
+        # its norm, as ``llama.block`` and ``hvd_mlp`` do; the five of PR 33
+        # keep ``norm1`` before theirs, where the accepted metrics read them
+        if kind == "mamba2":
+            with jax.named_scope(SCOPE_SSD_MIXER):
+                y = _mamba2(norm1(), lp, cfg)
+        elif kind == "attention":
+            with jax.named_scope(SCOPE_ATTENTION):
+                q, k, v = jnp.split(norm1() @ lp["wqkv"],
+                                    (H * Dh, (H + Hkv) * Dh), axis=-1)
+                o = local_attention(
+                    q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh),
+                    v.reshape(B, T, Hkv, Dh),
+                    sm_scale=cfg.attention_multiplier or None,
+                    mask=key_ranges(kind, T, cfg))
+                y = o.reshape(B, T, H * Dh) @ lp["wo"]
+        elif kind == "mamba":
+            u = norm1()
             with jax.named_scope(SCOPE_SSM_MIXER):
                 y, s = _mamba(u, lp, cfg)
             emitted = s if emits else None
         elif kind == "gmu":
+            u = norm1()
             with jax.named_scope(SCOPE_GMU):
                 y = (jax.nn.silu(u @ lp["in_proj"]) * memory) @ lp["out_proj"]
         else:
+            u = norm1()
             with jax.named_scope(SCOPE_DIFF_ATTENTION):
                 if kind == "cross":
                     q = (u @ lp["wq"]).reshape(B, T, H, Dh)
@@ -287,11 +401,10 @@ def _layer(kind, emits, cfg):
                     emitted = (k, v) if emits else None
                 y = _diff_attention(q, k, v, lp, lam0, key_ranges(kind, T, cfg),
                                     cfg)
-        h = h + y
+        h = join(h, y, cfg)
         with jax.named_scope(SCOPE_MLP):
-            y = _mlp(layer_norm(h, lp["norm2_w"], lp["norm2_b"],
-                                cfg.norm_eps), lp)
-        return h + y, emitted
+            y = _mlp(norm(h, lp["norm2_w"], lp.get("norm2_b"), cfg), lp)
+        return join(h, y, cfg), emitted
 
     return f
 
@@ -299,7 +412,13 @@ def _layer(kind, emits, cfg):
 def _runs(cfg):
     """[(kind, first of the kind's stack, layers' published ids, emits)]:
     runs of equal layers in order; an emitting layer is a run of its
-    own."""
+    own.  A run is several layers (one scan) only where it is its kind's
+    whole stack: a scan over a part of a stack takes a slice, whose
+    gradient comes back padded to the whole stack and is added to the
+    other parts' (three copies of every leaf's gradient, and an optimizer
+    pass of its own: 9.3 GB of temporaries at nine Mamba-2 layers in runs
+    of five and four, 3.5 GB layer by layer, where each weight gradient's
+    product is fused with that weight's update)."""
     kinds = cfg.layer_kinds
     ids = cfg.layer_ids or tuple(range(len(kinds)))
     last_mamba = (max(i for i, k in enumerate(kinds) if k == "mamba"
@@ -317,7 +436,12 @@ def _runs(cfg):
             runs[-1][2].append(ids[i])
         else:
             runs.append([kind, at, [ids[i]], emits])
-    return runs
+    shared = {kind for kind in set(kinds)
+              if sum(r[0] == kind for r in runs) > 1}
+    return [run for kind, at, ids_, emits in runs
+            for run in ([[kind, at + j, [i], emits]
+                         for j, i in enumerate(ids_)] if kind in shared
+                        else [[kind, at, ids_, emits]])]
 
 
 def layer_stack(h, layers, cfg, policy=None):
@@ -325,13 +449,16 @@ def layer_stack(h, layers, cfg, policy=None):
     remat policy of ``cfg.remat`` (``llama.remat_policy``)."""
     check(cfg)
     m = kv = None
+    made = {}       # one function a (kind, emits): equal layers trace once
     for kind, first, ids, emits in _runs(cfg):
         n = len(ids)
         if _metrics.ACTIVE:
             _m_kinds.inc(n, kind=kind)
-        f = _layer(kind, emits, cfg)
-        if cfg.remat:
-            f = jax.checkpoint(f, policy=policy)
+        if (kind, emits) not in made:
+            f = _layer(kind, emits, cfg)
+            made[kind, emits] = (jax.checkpoint(f, policy=policy)
+                                 if cfg.remat else f)
+        f = made[kind, emits]
         memory = m if kind == "gmu" else kv if kind == "cross" else None
         lam0 = jnp.asarray([lambda_init(i) for i in ids], jnp.float32)
         lps = jax.tree_util.tree_map(lambda w: w[first:first + n],

@@ -103,13 +103,17 @@ class LlamaConfig:
     experts_held: int = 0
     experts_first: int = 0
     # a trunk whose layers are of several kinds (models/hybrid.py): each
-    # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" (() = the
-    # trunk of identical layers here), and its index in the published
-    # model (() = its place in the trunk); the window of the "window"
-    # kind's attention; the state-space mixer's inner width, states a
-    # channel, convolution width and step rank.  Such a trunk has
-    # LayerNorm, a fused gate/up MLP, differential attention and no
-    # positional encoding.
+    # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" |
+    # "mamba2" | "attention" (() = the trunk of identical layers here),
+    # and its index in the published model (() = its place in the trunk);
+    # the window of the "window" kind's attention; the state-space
+    # mixers' inner width, states (a channel for "mamba", a head's
+    # columns for "mamba2"), convolution width, and "mamba"'s step rank;
+    # "mamba2"'s heads (of ssm_inner / ssm_heads channels), the groups
+    # that share B and C, and the positions a chunk of its scan takes.
+    # Such a trunk has a fused gate/up MLP and no positional encoding;
+    # its layers' norm is ``trunk_norm``: "layernorm" (weight and bias)
+    # or "rmsnorm" (weight).
     layer_kinds: tuple = ()
     layer_ids: tuple = ()
     sliding_window: int = 0
@@ -117,6 +121,21 @@ class LlamaConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    ssm_heads: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    trunk_norm: str = "layernorm"
+    # Granite's four multipliers, of the trunk of several kinds alone
+    # (the trunk of identical layers refuses them), each at what a trunk
+    # computes without it: the embedding's rows times
+    # ``embedding_multiplier``; each sublayer's output times
+    # ``residual_multiplier`` before it joins the stream; the softmax's
+    # scale (0 = head_dim ** -0.5); the logits divided by
+    # ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -125,6 +144,16 @@ class LlamaConfig:
         if self.moe_dispatch not in ("capacity", "dropless"):
             raise ValueError("moe_dispatch must be 'capacity' or "
                              f"'dropless', got {self.moe_dispatch!r}")
+        if self.trunk_norm not in ("layernorm", "rmsnorm"):
+            raise ValueError("trunk_norm must be 'layernorm' or 'rmsnorm', "
+                             f"got {self.trunk_norm!r}")
+        multipliers = (self.embedding_multiplier, self.residual_multiplier,
+                       self.attention_multiplier, self.logits_scaling)
+        if not self.layer_kinds and multipliers != (1.0, 1.0, 0.0, 1.0):
+            raise ValueError(
+                "embedding_multiplier, residual_multiplier, "
+                "attention_multiplier and logits_scaling are wired through "
+                "the trunk of several kinds (layer_kinds) alone")
 
 
 def llama3_8b() -> LlamaConfig:
@@ -172,13 +201,16 @@ def init_params(cfg: LlamaConfig, key, tp: int = 1) -> Dict:
     if cfg.layer_kinds:
         from . import hybrid
         k = jax.random.split(key, 2)
-        return {
+        params = {
             "embed": jax.random.normal(
                 k[0], (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
             * cfg.d_model ** -0.5,
             "layers": hybrid.init_layers(cfg, k[1]),
-            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
-            "final_norm_bias": jnp.zeros((cfg.d_model,), cfg.param_dtype)}
+            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype)}
+        if cfg.trunk_norm == "layernorm":
+            params["final_norm_bias"] = jnp.zeros((cfg.d_model,),
+                                                  cfg.param_dtype)
+        return params
     k = jax.random.split(key, 8)
     D, H, Hkv, Dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                               cfg.head_dim, cfg.d_ff, cfg.n_layers,
@@ -234,8 +266,11 @@ def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
     from jax.sharding import PartitionSpec as P
     if cfg is not None and cfg.layer_kinds:
         from . import hybrid
-        return {"embed": P(), "layers": hybrid.layer_specs(cfg),
-                "final_norm": P(), "final_norm_bias": P()}
+        specs = {"embed": P(), "layers": hybrid.layer_specs(cfg),
+                 "final_norm": P()}
+        if cfg.trunk_norm == "layernorm":
+            specs["final_norm_bias"] = P()
+        return specs
     tp = par.tp_axis
     pp = par.pp_axis
     embed_spec = (P(tp, None) if cfg is not None and cfg.vocab_parallel
@@ -285,7 +320,7 @@ def _embed_lookup(embed, tokens, cfg: LlamaConfig, par: ParallelSpec):
     VocabParallelEmbedding forward)."""
     w = embed.astype(cfg.dtype)
     if not _vp_active(cfg, par):
-        return w[tokens]
+        return _times_embedding_multiplier(w[tokens], cfg)
     Vl = w.shape[0]
     off = lax.axis_index(par.tp_axis) * Vl
     local = tokens - off
@@ -293,6 +328,13 @@ def _embed_lookup(embed, tokens, cfg: LlamaConfig, par: ParallelSpec):
     rows = w[jnp.clip(local, 0, Vl - 1)]
     rows = rows * inside[..., None].astype(w.dtype)
     return lax.psum(rows, par.tp_axis)
+
+
+def _times_embedding_multiplier(rows, cfg: LlamaConfig):
+    if cfg.embedding_multiplier == 1.0:
+        return rows
+    return (rows.astype(jnp.float32) * cfg.embedding_multiplier
+            ).astype(rows.dtype)
 
 
 def _vp_chunk_losses(h, w, targets, par: ParallelSpec):
@@ -573,7 +615,8 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
 def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
                    positions, mask):
     """:func:`hidden` of a trunk of several kinds (models/hybrid.py): no
-    positions at all, each kind's own mask, a final LayerNorm."""
+    positions at all, each kind's own mask, a final norm of the trunk's
+    kind."""
     from . import hybrid
     if (positions is not None or mask is not None
             or any(a is not None for a in (par.tp_axis, par.sp_axis,
@@ -588,8 +631,8 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
         h, params["layers"], cfg,
         remat_policy(cfg.remat_policy) if cfg.remat else None)
     with jax.named_scope(SCOPE_HEAD):
-        h = hybrid.layer_norm(h, params["final_norm"],
-                              params["final_norm_bias"], cfg.norm_eps)
+        h = hybrid.norm(h, params["final_norm"],
+                        params.get("final_norm_bias"), cfg)
     return h, jnp.float32(0.0)
 
 
@@ -603,6 +646,8 @@ def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
         # memory and changes no parallel structure — the head matmul
         # stays [D, V])
         logits = h @ _head(params, cfg).T.astype(h.dtype)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if _vp_active(cfg, par):
             # local [B, T, V/tp] partials → full logits, shard order =
             # vocab order (API contract; the loss path never materializes
@@ -617,7 +662,8 @@ def _head(params, cfg: LlamaConfig):
     return params["embed" if cfg.tie_embeddings else "head"]
 
 
-def _chunked_xent(h, w_embed, targets, chunk: int, weights=None):
+def _chunked_xent(h, w_embed, targets, chunk: int, weights=None,
+                  scaling: float = 1.0):
     """Mean cross-entropy without materializing full logits.
 
     Scans the (local) sequence in chunks; each chunk computes its
@@ -627,7 +673,8 @@ def _chunked_xent(h, w_embed, targets, chunk: int, weights=None):
     the one-shot path never exist, at the cost of re-running the head
     matmul once in bwd — the chunked-softmax idea flash attention applies
     to scores, applied to the vocabulary head.  ``weights [B, T]``
-    multiply each token's term (the divisor stays ``B * T``).
+    multiply each token's term (the divisor stays ``B * T``); the logits
+    are divided by ``scaling``.
     """
     B, T, D = h.shape
     n = T // chunk
@@ -641,7 +688,7 @@ def _chunked_xent(h, w_embed, targets, chunk: int, weights=None):
     @jax.checkpoint
     def body(acc, xt):
         hc, tc, wc = xt
-        return acc + (_token_xent(hc, w, tc) * wc).sum(), None
+        return acc + (_token_xent(hc, w, tc, scaling) * wc).sum(), None
 
     # the accumulator derives from h (×0) so it carries h's varying mesh
     # axes — a fresh constant would fail check_vma's carry-type check
@@ -650,9 +697,12 @@ def _chunked_xent(h, w_embed, targets, chunk: int, weights=None):
     return total / (B * T)
 
 
-def _token_xent(h, w, targets):
-    """``lse - target logit`` per token, float32; ``w [V, D]``."""
+def _token_xent(h, w, targets, scaling: float = 1.0):
+    """``lse - target logit`` per token, float32; ``w [V, D]``; the logits
+    divided by ``scaling``."""
     logits = (h @ w.T).astype(jnp.float32)
+    if scaling != 1.0:
+        logits = logits / scaling
     lse = jax.nn.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return lse - tgt
@@ -711,16 +761,18 @@ def _head_loss(h, head, targets, cfg: LlamaConfig, par: ParallelSpec,
                 "materialized", cfg.loss_chunk, h.shape[1],
                 "/tp" if _vp_active(cfg, par) else "")
 
+    scaling = cfg.logits_scaling
     if _vp_active(cfg, par):
         warn_unchunked()
         return _vocab_parallel_xent(h, head, targets, par,
                                     chunk=cfg.loss_chunk)
     if cfg.loss_chunk > 0 and h.shape[1] % cfg.loss_chunk == 0:
-        return _chunked_xent(h, head, targets, cfg.loss_chunk, weights)
+        return _chunked_xent(h, head, targets, cfg.loss_chunk, weights,
+                             scaling)
     warn_unchunked()
-    if weights is not None:
-        return (_token_xent(h, head.astype(h.dtype), targets)
-                * weights).mean()
+    if weights is not None or scaling != 1.0:
+        per_token = _token_xent(h, head.astype(h.dtype), targets, scaling)
+        return (per_token if weights is None else per_token * weights).mean()
     logits = h @ head.T.astype(h.dtype)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

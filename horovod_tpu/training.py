@@ -48,9 +48,11 @@ SCOPE_MLP = "hvd_mlp"              # norm + feed-forward, dense or routed expert
 SCOPE_HEAD = "hvd_head"            # final norm, head product, loss; ResNet's pool + fc
 SCOPE_STEM = "hvd_stem"            # ResNet: conv1 + BN + max-pool
 SCOPE_STAGE = "hvd_stage{}"        # ResNet: a stage's blocks, ``.format(i)``
-SCOPE_SSM_MIXER = "hvd_ssm_mixer"  # models/hybrid.py's three mixers, after norm1
+SCOPE_SSM_MIXER = "hvd_ssm_mixer"  # models/hybrid.py's mixers, after norm1
 SCOPE_GMU = "hvd_gmu"
 SCOPE_DIFF_ATTENTION = "hvd_diff_attention"
+SCOPE_SSD_MIXER = "hvd_ssd_mixer"  # the Mamba-2 mixer: norm1, projections, conv, scan, gated norm
+SCOPE_SSD_SCAN = "hvd_ssd_scan"    # inside it: ops/ssd_scan.py's call, whatever computes it
 
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec

@@ -1293,3 +1293,25 @@ def test_selective_scan_kernels_lower_for_the_chip(monkeypatch):
         lambda *a: ss.selective_scan(*a).astype(jnp.float32).sum(),
         argnums=tuple(range(6)))).lower(*operands).compile().as_text()
     assert "hvd_ssm_scan_fwd" in text and "hvd_ssm_scan_bwd" in text
+
+
+def test_ssd_scan_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the chunked state-space scan forward and backward at
+    the benchmark's granite-4.0-h-micro cell: 8,192 positions of 64 heads
+    of 64 channels over one group of 128 states in chunks of 256, bf16
+    ``x``, ``B`` and ``C`` beside a float32 step; no ``[T, H, P, N]``
+    array and no ``[Q, Q]`` tile a head in the compiled program."""
+    from horovod_tpu.ops import ssd_scan as sd
+    one_chip = _described_chip(monkeypatch)
+    T, H, P, N = 8192, 64, 64, 128
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    operands = (sds((1, T, H, P), jnp.bfloat16), sds((1, T, H), jnp.float32),
+                sds((H,), jnp.float32), sds((1, T, 1, N), jnp.bfloat16),
+                sds((1, T, 1, N), jnp.bfloat16), sds((H,), jnp.float32))
+    assert sd.supported(*operands, 256)
+    text = jax.jit(jax.grad(
+        lambda *a: sd.ssd_scan(*a, 256).astype(jnp.float32).sum(),
+        argnums=tuple(range(6)))).lower(*operands).compile().as_text()
+    assert "hvd_ssd_chunk_fwd" in text and "hvd_ssd_chunk_bwd" in text
+    assert f"{T},{H},{P},{N}]" not in text and "64,256,256]" not in text

@@ -128,17 +128,19 @@ def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
 
 
 def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
-    """Runs of equal layers are one scan; the emitting mamba and full
-    layers are runs of their own; the stack equals the layers applied one
-    by one."""
+    """A run of equal layers that is its kind's whole stack is one scan;
+    a kind whose stack several runs share goes layer by layer (no slice
+    of a stack is scanned); the emitting mamba and full layers are runs
+    of their own; the stack equals the layers applied one by one."""
     kinds = ("mamba", "mamba", "window", "window", "mamba", "full", "gmu",
              "gmu", "cross", "cross")
     cfg = _cfg(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, n_layers=10,
                layer_kinds=kinds, layer_ids=(0, 2, 3, 5, 16, 17, 18, 20, 21, 23),
                sliding_window=16)
     assert [(r[0], r[1], len(r[2]), r[3]) for r in hybrid._runs(cfg)] == [
-        ("mamba", 0, 2, False), ("window", 0, 2, False), ("mamba", 2, 1, True),
-        ("full", 0, 1, True), ("gmu", 0, 2, False), ("cross", 0, 2, False)]
+        ("mamba", 0, 1, False), ("mamba", 1, 1, False), ("window", 0, 2, False),
+        ("mamba", 2, 1, True), ("full", 0, 1, True), ("gmu", 0, 2, False),
+        ("cross", 0, 2, False)]
     params = llama.init_params(cfg, jax.random.key(0))
     assert llama.count_params(cfg) == sum(
         x.size for x in jax.tree_util.tree_leaves(params))
@@ -170,7 +172,7 @@ def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
 
 @pytest.mark.parametrize("kinds,error", [
     (("gmu", "mamba"), "needs a mamba"), (("cross", "full"), "needs a full"),
-    (("mamba", "attention"), "layer_kinds must name"), (("mamba",), "n_layers")])
+    (("mamba", "linear"), "layer_kinds must name"), (("mamba",), "n_layers")])
 def test_kinds_that_make_no_trunk_are_refused(kinds, error):
     with pytest.raises(ValueError, match=error):
         hybrid.check(_cfg(n_layers=2, layer_kinds=kinds))
@@ -185,3 +187,133 @@ def test_trunk_of_kinds_takes_no_positions_mask_or_model_parallel_axis():
             llama.hidden(params, tokens, cfg, llama.ParallelSpec(), **kw)
     with pytest.raises(NotImplementedError, match="data parallelism"):
         llama.hidden(params, tokens, cfg, llama.ParallelSpec(tp_axis="tp"))
+
+
+# ------------------------------- the Mamba-2 hybrids (granite-4.0-h-micro)
+# The kinds ``mamba2`` and ``attention`` under the config's frame (RMSNorm,
+# the four multipliers) against the configuration's plain reference, which
+# walks the recurrence position by position: benchmark/configs/
+# granite-4.0-h-micro/reference.py, at its toy sizes.
+
+def _granite():
+    import importlib.util
+    import json
+    import pathlib
+    cdir = (pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+            / "configs" / "granite-4.0-h-micro")
+    cfg = json.loads((cdir / "config.json").read_text())
+    cfg.update(cfg["toy"])
+    cfg["dtype"]["compute"] = "float32"
+    mods = []
+    for name in ("reference", "adapter"):
+        spec = importlib.util.spec_from_file_location(
+            f"granite_{name}", cdir / f"{name}.py")
+        mods.append(importlib.util.module_from_spec(spec))
+        spec.loader.exec_module(mods[-1])
+    return cfg, mods[0], mods[1]
+
+
+def _granite_losses(cfg, ref, adapter, lcfg=None, grads=False):
+    """(the program's, the reference's) loss on seeded weights and rows,
+    with the gradients leaf by leaf under the reference's names where
+    asked."""
+    import dataclasses
+    key = jax.random.key(7)
+    w = ref.make_weights(cfg, key)
+    batch = ref.make_samples(cfg, jax.random.fold_in(key, 1), 2)
+    lcfg = lcfg or adapter.program_config(cfg)
+    assert dataclasses.is_dataclass(lcfg)
+    fn = lambda p: llama.loss_fn(p, *batch, lcfg, llama.ParallelSpec())
+    with jax.default_matmul_precision("highest"):
+        want = jax.value_and_grad(lambda w_: ref.loss(cfg, w_, batch))(w)
+        got = jax.value_and_grad(fn)(adapter._to_program(w, cfg))
+    if not grads:
+        return got[0], want[0]
+    return (got[0], adapter._to_flat(got[1], cfg)), want
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernels"])
+def test_mamba2_and_attention_trunk_follows_the_plain_reference(interpret,
+                                                                monkeypatch):
+    """Loss and every leaf's gradient: mamba2, attention, mamba2 under
+    RMSNorm and the four multipliers; the chunked scan in jax.numpy and
+    through ``hvd_ssd_chunk_fwd`` / ``hvd_ssd_chunk_bwd`` in interpret
+    mode, four chunks a row."""
+    from horovod_tpu.ops import ssd_scan as sd
+    monkeypatch.setattr(sd, "_INTERPRET", interpret)
+    cfg, ref, adapter = _granite()
+    lcfg = adapter.program_config(cfg)
+    assert lcfg.layer_kinds == ("mamba2", "attention", "mamba2")
+    assert [(r[0], r[1], len(r[2])) for r in hybrid._runs(lcfg)] == [
+        ("mamba2", 0, 1), ("attention", 0, 1), ("mamba2", 1, 1)]
+    before = metrics.registry().to_dict().get("hvd_layer_kind_total", {})
+    (loss, grads), (want_loss, want) = _granite_losses(cfg, ref, adapter,
+                                                       grads=True)
+    if metrics.ACTIVE:
+        count = lambda fam: {s["labels"]["kind"]: s["value"]
+                             for s in fam.get("series", [])}
+        after = count(metrics.registry().to_dict()["hvd_layer_kind_total"])
+        grew = {k: n - count(before).get(k, 0) for k, n in after.items()}
+        assert {k: n for k, n in grew.items() if n} == {"mamba2": 2,
+                                                        "attention": 1}
+    assert abs(float(loss - want_loss)) < 2e-6 * float(want_loss)
+    assert set(grads) == set(want) == set(ref.weight_shapes(cfg))
+    for name in want:
+        np.testing.assert_allclose(
+            grads[name], want[name], rtol=2e-3,
+            atol=2e-4 * float(jnp.abs(want[name]).max()), err_msg=name)
+    assert llama.count_params(lcfg) == sum(
+        int(np.prod(s)) for s in ref.weight_shapes(cfg).values())
+
+
+@pytest.mark.parametrize("field,family", [
+    ("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
+    ("attention_multiplier", 0.0), ("logits_scaling", 1.0)])
+def test_each_multiplier_is_the_configs_and_not_the_familys(field, family):
+    """A multiplier dropped, or left at what the other trunks compute,
+    moves the loss away from the reference's; each as published keeps it
+    there."""
+    import dataclasses
+    cfg, ref, adapter = _granite()
+    # weights large enough for the logits to say something: at the
+    # configuration's ranges a toy's loss is log(vocabulary) whatever the
+    # trunk computes
+    cfg.update(initializer_range=0.3, residual_out_range=0.3)
+    lcfg = adapter.program_config(cfg)
+    assert getattr(llama.LlamaConfig(), field) == family
+    assert getattr(lcfg, field) == cfg[field] != family
+    got, want = _granite_losses(cfg, ref, adapter)
+    assert abs(float(got - want)) < 1e-5 * float(want)
+    dropped, _ = _granite_losses(
+        cfg, ref, adapter, dataclasses.replace(lcfg, **{field: family}))
+    assert abs(float(dropped - want)) > 1e-4 * float(want), field
+    # the trunk of identical layers computes none of them, and says so
+    with pytest.raises(ValueError, match="trunk of several kinds"):
+        llama.LlamaConfig(**{field: cfg[field]})
+
+
+def test_the_frame_is_the_configs_rmsnorm_has_no_bias():
+    cfg = _cfg(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, n_layers=2,
+               layer_kinds=("mamba2", "attention"), ssm_heads=8, ssm_state=16,
+               ssm_chunk=16, trunk_norm="rmsnorm")
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert "final_norm_bias" not in params
+    assert set(params["layers"]["attention"]) == {
+        "norm1_w", "norm2_w", "w1", "w2", "wqkv", "wo"}
+    assert set(params["layers"]["mamba2"]) == {
+        "norm1_w", "norm2_w", "w1", "w2", "in_proj", "conv_w", "conv_b",
+        "dt_bias", "A_log", "D", "gate_norm", "out_proj"}
+    assert params["layers"]["mamba2"]["in_proj"].shape == (
+        1, 64, 128 + 128 + 2 * 16 + 8)
+    A = np.exp(np.asarray(params["layers"]["mamba2"]["A_log"]))
+    assert (1 <= A).all() and (A <= 16).all() and A.std() > 0
+    assert llama.count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    # the same kinds under LayerNorm carry its biases: the frame is a field
+    biased = llama.init_params(_cfg(**{**vars(cfg), "trunk_norm": "layernorm"}),
+                               jax.random.key(0))
+    assert "final_norm_bias" in biased and "norm1_b" in biased["layers"]["mamba2"]
+    with pytest.raises(ValueError, match="trunk_norm"):
+        _cfg(trunk_norm="batchnorm")
+    with pytest.raises(ValueError, match="ssm_heads"):
+        hybrid.check(_cfg(n_layers=1, layer_kinds=("mamba2",), ssm_heads=3))
