@@ -45,6 +45,10 @@ default computes what the trunk computed before the config carried it.
   -exp(A_log)`` a head; ``y`` the chunked scan of a matrix state a head
   (:mod:`horovod_tpu.ops.ssd_scan`); the mixer gives ``W_out (RMSNorm(y *
   silu(z)) * w)``, the gate before the norm, over all channels at once.
+  The two elementwise chains, convolution + SiLU + split and gate + norm,
+  are :mod:`horovod_tpu.ops.mamba2_mixer`'s (float32 inside, the operands'
+  dtype out); where its kernels run, ``z`` and ``xBC`` are a product each
+  over their columns of ``W_in``, since a kernel reads no slice.
 * ``attention`` — plain grouped-query attention (Granite): ``softmax(s q
   k^T + causal) v`` through ``W_o``, ``s = cfg.attention_multiplier`` (0 =
   ``1 / sqrt(Dh)``).
@@ -68,9 +72,11 @@ again in the backward pass like any other, their scan included.
 
 ``hvd_layer_kind_total{kind}`` counts the layers traced; the mixers run
 under the scopes ``hvd_ssm_mixer``, ``hvd_gmu``, ``hvd_diff_attention``,
-``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``) and
-``hvd_attention``.  Plain data parallelism only: nothing here is sharded
-over a tensor-, sequence- or pipeline-parallel axis yet.
+``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``; the
+mixer's own kernels ``hvd_conv_silu_fwd`` / ``_bwd`` and
+``hvd_gated_norm_fwd`` / ``_bwd`` under no scope of their own, rows of the
+mixer's) and ``hvd_attention``.  Plain data parallelism only: nothing here
+is sharded over a tensor-, sequence- or pipeline-parallel axis yet.
 """
 
 from __future__ import annotations
@@ -84,8 +90,10 @@ from jax import lax
 
 from .. import metrics as _metrics
 from ..ops import flash_attention as _fa
+from ..ops import mamba2_mixer as _mixer
 from ..ops.selective_scan import selective_scan
-from ..ops.ssd_scan import ssd_scan
+from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
+from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
 from .llama import _rmsnorm
@@ -276,22 +284,36 @@ def _mamba2(u, lp, cfg):
     f32 = jnp.float32
     B, T, _ = u.shape
     Di, Hs, G, N = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
-    conv = Di + 2 * G * N
+    P, conv = Di // Hs, Di + 2 * G * N
+    sizes = (Di, G * N, G * N)
+    W = lp["in_proj"]
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, u.dtype)
+    kernels = _mixer.supported(sds(B, T, conv), sizes)
+    # the three kernels hand x and y on in the scan's own layout, the
+    # positions on the lanes, where all of them run
+    turned = (kernels and _mixer.supported(sds(B, T, conv), sizes, True)
+              and ssd_scan_supported(
+                  sds(B, T, Hs, P), sds(B, T, Hs), lp["A_log"],
+                  sds(B, T, G, N), sds(B, T, G, N), lp["D"], cfg.ssm_chunk))
+    if kernels:
+        # a kernel reads no slice of a joint array: a product each
+        z, xBC = u @ W[:, :Di], u @ W[:, Di:Di + conv]
+    else:
+        z, xBC = jnp.split(u @ W[:, :Di + conv], (Di,), axis=-1)
     # the step's 64 columns come out of their product in float32: a decay
     # is the exponential of up to a chunk's sum of them
-    z, xBC = jnp.split(u @ lp["in_proj"][:, :Di + conv], (Di,), axis=-1)
-    dt = jnp.dot(u, lp["in_proj"][:, Di + conv:], preferred_element_type=f32)
-    x, Bm, Cm = jnp.split(_conv_silu(xBC, lp, cfg.ssm_conv),
-                          (Di, Di + G * N), axis=-1)
+    dt = jnp.dot(u, W[:, Di + conv:], preferred_element_type=f32)
+    x, Bm, Cm = _mixer.conv_silu_split(xBC, lp["conv_w"], lp["conv_b"], sizes,
+                                       turned)
     delta = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
+    scan, heads = ((ssd_scan_turned, (B, Hs, P, T)) if turned
+                   else (ssd_scan, (B, T, Hs, P)))
     with jax.named_scope(SCOPE_SSD_SCAN):
-        y = ssd_scan(x.reshape(B, T, Hs, Di // Hs), delta,
-                     -jnp.exp(lp["A_log"].astype(f32)),
-                     Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N),
-                     lp["D"].astype(f32), cfg.ssm_chunk)
-    y = y.reshape(B, T, Di).astype(f32) * jax.nn.silu(z.astype(f32))
-    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
-    return (y * lp["gate_norm"].astype(f32)).astype(u.dtype) @ lp["out_proj"]
+        y = scan(x.reshape(heads), delta, -jnp.exp(lp["A_log"].astype(f32)),
+                 Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N),
+                 lp["D"].astype(f32), cfg.ssm_chunk)
+    return _mixer.gated_rmsnorm(y.reshape(x.shape), z, lp["gate_norm"],
+                                cfg.norm_eps, turned) @ lp["out_proj"]
 
 
 def _pairs(x):

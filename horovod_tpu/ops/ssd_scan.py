@@ -139,10 +139,12 @@ def _running_sums(delta, A, chunk):
         Bt, T, H)
 
 
-def _finish_backward(x, delta, A, D, dy, dx, dXx, dcs, chunk):
+def _finish_backward(x, delta, A, D, dy, dx, dXx, dcs, chunk, heads=2):
     """What the chunks' backward leaves to elementwise work: ``d cs``
     summed back over each chunk's later positions into ``d a``, ``d
-    delta``, ``dA``, ``dD`` and ``D``'s part of ``dx``."""
+    delta``, ``dA``, ``dD`` and ``D``'s part of ``dx``.  ``heads``: the
+    axis of ``x``, ``dy`` and ``dx`` that counts heads (2 of ``[Bt, T, H,
+    P]``, 1 of the kernels' ``[Bt, H, P, T]``)."""
     f32 = jnp.float32
     Bt, T, H = delta.shape
     da = lax.cumsum(dcs.reshape(Bt, T // chunk, chunk, H), axis=2,
@@ -150,10 +152,16 @@ def _finish_backward(x, delta, A, D, dy, dx, dXx, dcs, chunk):
     dl32, dy32, x32 = delta.astype(f32), dy.astype(f32), x.astype(f32)
     ddelta = da * A.astype(f32) + dXx
     dA = (da * dl32).sum((0, 1))
-    dD = (dy32 * x32).sum((0, 1, 3))
-    dx = dx.astype(f32) + D.astype(f32)[:, None] * dy32
+    dD = (dy32 * x32).sum(tuple(a for a in range(4) if a != heads))
+    dx = dx.astype(f32) + _a_head(D, heads) * dy32
     return (dx.astype(x.dtype), ddelta.astype(delta.dtype),
             dA.astype(A.dtype), dD.astype(D.dtype))
+
+
+def _a_head(D, heads):
+    """``D [H]`` float32 against a rank-4 array whose axis ``heads`` counts
+    heads."""
+    return jnp.expand_dims(D.astype(jnp.float32), tuple(range(1, 4 - heads)))
 
 
 # ------------------------------------------------------------ plain path
@@ -388,17 +396,26 @@ def _bwd_kernel(xt_ref, dyt_ref, dl_ref, csr_ref, csc_ref, b_ref, c_ref,
         dc_ref[0] += _dot(d, Bm, _TN)
 
 
-def _kernel_operands(x, delta, A, B, C, chunk, hb):
-    """The call's arrays in the kernels' layout: x^T [Bt, H, P, T], delta
-    and cs as rows [Bt, H, T], cs as columns [Bt, H / hb, T, hb], B and C
-    [Bt, T, G N]."""
+def _kernel_operands(xt, delta, A, B, C, chunk, hb):
+    """The call's arrays in the kernels' layout: x^T [Bt, H, P, T] as it
+    comes, delta and cs as rows [Bt, H, T], cs as columns [Bt, H / hb, T,
+    hb], B and C [Bt, T, G N]."""
     Bt, T, H = delta.shape
     cs = _running_sums(delta, A, chunk)
     rows = lambda a: jnp.transpose(a, (0, 2, 1))
     cols = jnp.transpose(cs.reshape(Bt, T, H // hb, hb), (0, 2, 1, 3))
     flat = lambda a: a.reshape(Bt, T, -1)
-    return (jnp.transpose(x, (0, 2, 3, 1)), rows(delta.astype(jnp.float32)),
-            rows(cs), cols, flat(B), flat(C))
+    return (xt, rows(delta.astype(jnp.float32)), rows(cs), cols, flat(B),
+            flat(C))
+
+
+def _turn(x):
+    """``[Bt, T, H, P]`` -> the kernels' ``[Bt, H, P, T]``."""
+    return jnp.transpose(x, (0, 2, 3, 1))
+
+
+def _back(xt):
+    return jnp.transpose(xt, (0, 3, 1, 2))
 
 
 def _specs(H, P, G, N, chunk, nk, hb, reverse):
@@ -420,20 +437,21 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _scan_fwd_pallas(x, delta, A, B, C, chunk):
-    Bt, T, H, P = x.shape
+def _chunks_fwd_pallas(xt, delta, A, B, C, chunk):
+    """-> (y^T without ``D x`` [Bt, H, P, T], the chunks' first states)."""
+    Bt, H, P, T = xt.shape
     G, N = B.shape[2:]
     nk, hb = T // chunk, _head_block(H, G)
     per_group, tile, row, col, grp, bound = _specs(H, P, G, N, chunk, nk, hb,
                                                    False)
     _count("fwd", "pallas")
-    operands = _kernel_operands(x, delta, A, B, C, chunk, hb)
-    yt, states = pl.pallas_call(
+    operands = _kernel_operands(xt, delta, A, B, C, chunk, hb)
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, hb=hb, per_group=per_group),
         grid=(Bt, nk, H // hb),
         in_specs=[tile, row, row, col, grp, grp],
         out_specs=[tile, bound],
-        out_shape=[_sds((Bt, H, P, T), x.dtype, *operands),
+        out_shape=[_sds((Bt, H, P, T), xt.dtype, *operands),
                    _sds((Bt, nk, H, P, N), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((H // hb, hb, P, N), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
@@ -441,26 +459,31 @@ def _scan_fwd_pallas(x, delta, A, B, C, chunk):
         interpret=_INTERPRET,
         name="hvd_ssd_chunk_fwd",
     )(*operands)
-    return jnp.transpose(yt, (0, 3, 1, 2)), states
 
 
-def _scan_bwd_pallas(x, delta, A, B, C, states, dy, chunk):
+def _scan_fwd_pallas(x, delta, A, B, C, chunk):
+    yt, states = _chunks_fwd_pallas(_turn(x), delta, A, B, C, chunk)
+    return _back(yt), states
+
+
+def _chunks_bwd_pallas(xt, delta, A, B, C, states, dyt, chunk):
+    """-> (dx^T [Bt, H, P, T], the two ``[Bt, T, H]`` float32 arrays ``d
+    delta`` is made of, dB, dC)."""
     f32 = jnp.float32
-    Bt, T, H, P = x.shape
+    Bt, H, P, T = xt.shape
     G, N = B.shape[2:]
     nk, hb = T // chunk, _head_block(H, G)
     per_group, tile, row, col, grp, bound = _specs(H, P, G, N, chunk, nk, hb,
                                                    True)
     _count("bwd", "pallas")
-    xt, dl, csr, csc, Bf, Cf = _kernel_operands(x, delta, A, B, C, chunk, hb)
-    dyt = jnp.transpose(dy.astype(x.dtype), (0, 2, 3, 1))
-    operands = (xt, dyt, dl, csr, csc, Bf, Cf, states)
+    xt, dl, csr, csc, Bf, Cf = _kernel_operands(xt, delta, A, B, C, chunk, hb)
+    operands = (xt, dyt.astype(xt.dtype), dl, csr, csc, Bf, Cf, states)
     dxt, dxx, dcs, dB, dC = pl.pallas_call(
         functools.partial(_bwd_kernel, hb=hb, per_group=per_group),
         grid=(Bt, nk, H // hb),
         in_specs=[tile, tile, row, row, col, grp, grp, bound],
         out_specs=[tile, row, row, grp, grp],
-        out_shape=[_sds((Bt, H, P, T), x.dtype, *operands),
+        out_shape=[_sds((Bt, H, P, T), xt.dtype, *operands),
                    _sds((Bt, H, T), f32, *operands),
                    _sds((Bt, H, T), f32, *operands),
                    _sds((Bt, T, G * N), f32, *operands),
@@ -473,8 +496,13 @@ def _scan_bwd_pallas(x, delta, A, B, C, states, dy, chunk):
         name="hvd_ssd_chunk_bwd",
     )(*operands)
     rows = lambda a: jnp.transpose(a, (0, 2, 1))
-    return (jnp.transpose(dxt, (0, 3, 1, 2)), rows(dxx), rows(dcs),
-            dB.reshape(B.shape), dC.reshape(C.shape))
+    return dxt, rows(dxx), rows(dcs), dB.reshape(B.shape), dC.reshape(C.shape)
+
+
+def _scan_bwd_pallas(x, delta, A, B, C, states, dy, chunk):
+    dxt, *rest = _chunks_bwd_pallas(_turn(x), delta, A, B, C, states,
+                                    _turn(dy), chunk)
+    return (_back(dxt), *rest)
 
 
 # ------------------------------------------------------------- public op
@@ -515,3 +543,43 @@ def _ssd_scan_bwd(chunk, res, dy):
 
 
 ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
+
+
+# ------------------------------------------- the same in the kernels' layout
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan_turned(xt, delta, A, B, C, D, chunk):
+    return _scan_turned_fwd(xt, delta, A, B, C, D, chunk)[0]
+
+
+def _scan_turned_fwd(xt, delta, A, B, C, D, chunk):
+    f32 = jnp.float32
+    yt, states = _chunks_fwd_pallas(xt, delta, A, B, C, chunk)
+    yt = yt.astype(f32) + _a_head(D, 1) * xt.astype(f32)
+    return yt.astype(xt.dtype), (xt, delta, A, B, C, D, states)
+
+
+def _scan_turned_bwd(chunk, res, dyt):
+    xt, delta, A, B, C, D, states = res
+    dxt, dXx, dcs, dB, dC = _chunks_bwd_pallas(xt, delta, A, B, C, states,
+                                               dyt, chunk)
+    dxt, ddelta, dA, dD = _finish_backward(xt, delta, A, D, dyt, dxt, dXx,
+                                           dcs, chunk, heads=1)
+    return dxt, ddelta, dA, dB.astype(B.dtype), dC.astype(C.dtype), dD
+
+
+_scan_turned.defvjp(_scan_turned_fwd, _scan_turned_bwd)
+
+
+def ssd_scan_turned(xt, delta, A, B, C, D, chunk=256):
+    """:func:`ssd_scan` on ``x^T [Bt, H, P, T]``, giving ``y^T [Bt, H, P,
+    T]``: the kernels' own layout taken and returned, for a caller whose
+    neighbouring kernels write and read it (``ops/mamba2_mixer.py``), so
+    that nothing is transposed in HBM around the call; the other operands
+    as :func:`ssd_scan` takes them.  Where the kernels do not run, that
+    function between two transposes."""
+    Bt, H, P, T = xt.shape
+    x = jax.ShapeDtypeStruct((Bt, T, H, P), xt.dtype)
+    if supported(x, delta, A, B, C, D, chunk):
+        return _scan_turned(xt, delta, A, B, C, D, chunk)
+    return _turn(ssd_scan(_back(xt), delta, A, B, C, D, chunk))

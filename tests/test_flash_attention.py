@@ -6,6 +6,8 @@ the XLA blockwise fallback is validated directly.  Reference is dense
 softmax attention in fp32.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1315,3 +1317,45 @@ def test_ssd_scan_kernels_lower_for_the_chip(monkeypatch):
         argnums=tuple(range(6)))).lower(*operands).compile().as_text()
     assert "hvd_ssd_chunk_fwd" in text and "hvd_ssd_chunk_bwd" in text
     assert f"{T},{H},{P},{N}]" not in text and "64,256,256]" not in text
+
+
+@pytest.mark.parametrize("turned", [False, True], ids=["rows", "turned"])
+def test_mamba2_mixer_kernels_lower_for_the_chip(turned, monkeypatch):
+    """Mosaic takes the Mamba-2 mixer's four elementwise kernels at the
+    benchmark's granite-4.0-h-micro cell: 8,192 positions, 4,352 convolved
+    channels cut 4,096 / 128 / 128 under four taps, 4,096 gated ones, bf16
+    operands beside float32 parameters; ``turned``, as the cell runs them,
+    ``x`` written and ``y`` read ``[1, 4096, 8192]`` with the chunked scan
+    on that layout between them and no transpose in the compiled chain."""
+    from horovod_tpu.ops import mamba2_mixer as mm
+    from horovod_tpu.ops import ssd_scan as sd
+    one_chip = _described_chip(monkeypatch)
+    T, sizes, H, N = 8192, (4096, 128, 128), 64, 128
+    C, Di = sum(sizes), sizes[0]
+    bf, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    assert mm.supported(sds((1, T, C), bf), sizes, turned)
+
+    def chain(xBC, conv_w, conv_b, z, gate_w, delta, A, D):
+        x, B, Cm = mm.conv_silu_split(xBC, conv_w, conv_b, sizes, turned)
+        scan, heads = ((sd.ssd_scan_turned, (1, H, Di // H, T)) if turned
+                       else (sd.ssd_scan, (1, T, H, Di // H)))
+        y = scan(x.reshape(heads), delta, A, B.reshape(1, T, 1, N),
+                 Cm.reshape(1, T, 1, N), D, 256)
+        return mm.gated_rmsnorm(y.reshape(x.shape), z, gate_w, 1e-5,
+                                turned).astype(f32).sum()
+
+    text = jax.jit(jax.value_and_grad(chain, argnums=tuple(range(8)))).lower(
+        sds((1, T, C), bf), sds((4, C), f32), sds((C,), f32),
+        sds((1, T, Di), bf), sds((Di,), f32), sds((1, T, H), f32),
+        sds((H,), f32), sds((H,), f32)).compile().as_text()
+    for name in ("hvd_conv_silu_fwd", "hvd_conv_silu_bwd",
+                 "hvd_gated_norm_fwd", "hvd_gated_norm_bwd",
+                 "hvd_ssd_chunk_fwd", "hvd_ssd_chunk_bwd"):
+        assert name in text
+    if turned:
+        # x, y and their cotangents never change layout in HBM
+        assert f"bf16[1,{T},{H},{Di // H}]" not in text
+        assert not re.search(
+            rf"bf16\[1,{T},{Di}\]\S* (copy|transpose)\(", text)

@@ -113,6 +113,45 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == "f9c9e5ce95fd15d3"
 
 
+def test_mamba_trunk_is_untouched_by_the_mamba2_mixers_kernels(monkeypatch):
+    """``_mamba`` keeps ``_conv_silu`` and its own gate: with the Mamba-2
+    mixer's kernels switched on (interpret), tracing a remat'd trunk of
+    ``mamba`` and ``gmu`` layers forward and backward counts nothing in
+    ``hvd_mixer_kernel_total`` and calls neither ``hvd_conv_silu_*`` nor
+    ``hvd_gated_norm_*``, in the jaxpr and in the lowered text."""
+    from horovod_tpu.ops import mamba2_mixer as mm
+    monkeypatch.setattr(mm, "_INTERPRET", True)
+    monkeypatch.setattr(mm, "_BLOCK", 32)
+    cfg = _cfg(d_model=128, n_heads=2, n_kv_heads=2, n_layers=3,
+               layer_kinds=("mamba", "mamba", "gmu"), layer_ids=(0, 2, 4),
+               ssm_inner=256, dtype=jnp.bfloat16, remat=True)
+    layers = llama.init_params(cfg, jax.random.key(0))["layers"]
+    h = jax.ShapeDtypeStruct((2, 64, 128), cfg.dtype)
+    step = jax.jit(jax.value_and_grad(lambda h, ls: hybrid.layer_stack(
+        h, ls, cfg, llama.remat_policy("full")).astype(jnp.float32).sum(),
+        (0, 1)))
+    before = _mixer_counts()
+    jaxpr = str(jax.make_jaxpr(step)(h, layers))
+    text = step.lower(h, layers).as_text()
+    assert _mixer_grew(before) == {}
+    for name in ("hvd_conv_silu", "hvd_gated_norm"):
+        assert name not in jaxpr and name not in text
+    # the same widths through a mamba2 layer do reach them
+    cfg2 = _cfg(d_model=128, n_heads=2, n_kv_heads=2, n_layers=1,
+                layer_kinds=("mamba2",), ssm_inner=256, ssm_heads=4,
+                ssm_groups=1, ssm_state=128, ssm_chunk=32, dtype=jnp.bfloat16,
+                trunk_norm="rmsnorm")
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0].astype(cfg2.dtype),
+        llama.init_params(cfg2, jax.random.key(0))["layers"]["mamba2"])
+    jaxpr2 = str(jax.make_jaxpr(lambda h, lp: hybrid._mamba2(h, lp, cfg2))(
+        h, lp))
+    assert "hvd_conv_silu_fwd" in jaxpr2 and "hvd_gated_norm_fwd" in jaxpr2
+    if metrics.ACTIVE:
+        assert _mixer_grew(before) == {("conv_fwd", "pallas"): 1,
+                                       ("norm_fwd", "pallas"): 1}
+
+
 def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
     q = jnp.zeros((1, 128, 2, 64))
@@ -213,6 +252,18 @@ def _granite():
     return cfg, mods[0], mods[1]
 
 
+def _mixer_counts():
+    family = metrics.registry().to_dict().get("hvd_mixer_kernel_total", {})
+    return {(s["labels"]["kernel"], s["labels"]["path"]): s["value"]
+            for s in family.get("series", [])}
+
+
+def _mixer_grew(before):
+    after = _mixer_counts()
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
 def _granite_losses(cfg, ref, adapter, lcfg=None, grads=False):
     """(the program's, the reference's) loss on seeded weights and rows,
     with the gradients leaf by leaf under the reference's names where
@@ -232,15 +283,26 @@ def _granite_losses(cfg, ref, adapter, lcfg=None, grads=False):
     return (got[0], adapter._to_flat(got[1], cfg)), want
 
 
-@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("interpret,mixer", [
+    (False, False), (True, False), (True, True)],
+    ids=["xla", "kernels", "mixer-kernels"])
 def test_mamba2_and_attention_trunk_follows_the_plain_reference(interpret,
+                                                                mixer,
                                                                 monkeypatch):
     """Loss and every leaf's gradient: mamba2, attention, mamba2 under
     RMSNorm and the four multipliers; the chunked scan in jax.numpy and
     through ``hvd_ssd_chunk_fwd`` / ``hvd_ssd_chunk_bwd`` in interpret
-    mode, four chunks a row."""
+    mode, four chunks a row; with ``mixer`` the convolution and the gate
+    through ``ops/mamba2_mixer.py``'s four kernels too, two blocks of
+    positions a row (160 convolved channels cut 128, 16, 16), and ``x``
+    and ``y`` handed on turned through ``ssd_scan_turned``."""
+    from horovod_tpu.ops import mamba2_mixer as mm
     from horovod_tpu.ops import ssd_scan as sd
     monkeypatch.setattr(sd, "_INTERPRET", interpret)
+    monkeypatch.setattr(mm, "_INTERPRET", mixer)
+    for name, size in (("_BLOCK", 32), ("_ROWS", 16), ("_TURN", (16, 64))):
+        monkeypatch.setattr(mm, name, size)
+    mixer_before = _mixer_counts()
     cfg, ref, adapter = _granite()
     lcfg = adapter.program_config(cfg)
     assert lcfg.layer_kinds == ("mamba2", "attention", "mamba2")
@@ -256,6 +318,13 @@ def test_mamba2_and_attention_trunk_follows_the_plain_reference(interpret,
         grew = {k: n - count(before).get(k, 0) for k, n in after.items()}
         assert {k: n for k, n in grew.items() if n} == {"mamba2": 2,
                                                         "attention": 1}
+        # every chain of the mamba2 layers on the one path (a traced
+        # call site counts; the plain form's backward is autodiff's)
+        path = "pallas" if mixer else "xla"
+        assert set(_mixer_grew(mixer_before)) == {
+            (k, path) for k in (("conv_fwd", "conv_bwd", "norm_fwd",
+                                 "norm_bwd") if mixer
+                                else ("conv_fwd", "norm_fwd"))}
     assert abs(float(loss - want_loss)) < 2e-6 * float(want_loss)
     assert set(grads) == set(want) == set(ref.weight_shapes(cfg))
     for name in want:

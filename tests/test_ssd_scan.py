@@ -191,3 +191,36 @@ def test_off_the_chip_the_plain_path_runs_without_being_asked():
     jax.jit(lambda *a: sd.ssd_scan(*a, Q))(*operands)
     if metrics.ACTIVE:
         assert _counts().get(("fwd", "xla"), 0) == before.get(("fwd", "xla"), 0) + 1
+
+
+
+@pytest.mark.parametrize("path", ["pallas", "xla"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_kernels_layout_taken_and_returned_is_the_same_scan(path, dtype,
+                                                                monkeypatch):
+    """``ssd_scan_turned`` on ``x^T [Bt, H, P, T]`` is ``ssd_scan`` between
+    two transposes: ``y^T`` to the bit (the same kernels, ``D x`` added
+    element by element in the other layout) and the six gradients, ``dD``'s
+    sum in another order; off the kernels it is that function itself."""
+    monkeypatch.setattr(sd, "_INTERPRET", path == "pallas")
+    operands, w = _operands(dtype, 2, 4 * Q, G=2)
+    turn = lambda a: jnp.transpose(a, (0, 2, 3, 1))
+    turned = (turn(operands[0]),) + operands[1:]
+    before = _counts()
+    value, grads = _value_and_grads(
+        lambda *a: sd.ssd_scan_turned(*a, Q), turned, turn(w))
+    if metrics.ACTIVE:
+        after = _counts()
+        assert {k: after[k] - before.get(k, 0) for k in after
+                if after[k] != before.get(k, 0)} == {("fwd", path): 1,
+                                                     ("bwd", path): 1}
+    want_value, want = _value_and_grads(lambda *a: sd.ssd_scan(*a, Q),
+                                        operands, w)
+    y = sd.ssd_scan(*operands, Q).astype(jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(sd.ssd_scan_turned(*turned, Q).astype(jnp.float32)),
+        np.asarray(turn(y)))
+    # the same terms added up in another order
+    assert abs(float(value - want_value)) <= 1e-6 * float(jnp.abs(y * w).sum())
+    _close((jnp.transpose(grads[0], (0, 3, 1, 2)),) + grads[1:], want, 1e-5)
