@@ -1,6 +1,8 @@
 """A decoder trunk whose layers are of several kinds and hand memory to
-one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash) and
-the Mamba-2 hybrids (GraniteMoeHybrid; the mixer from arXiv 2405.21060).
+one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash), the
+Mamba-2 hybrids (GraniteMoeHybrid; the mixer from arXiv 2405.21060) and
+the delta-rule hybrids (Kimi Linear, arXiv 2510.26692: linear attention
+layers between gated softmax ones, routed experts in every layer).
 
 ``LlamaConfig.layer_kinds`` names each layer's kind; :mod:`.llama`'s
 ``init_params``, ``param_specs``, ``count_params`` and ``hidden`` come
@@ -8,9 +10,15 @@ here when it is set.
 
 **The frame, which is the config's.**  Every layer is
 
-    h = x + r Mixer(Norm1(x));  y = h + r W2 (silu(g) * v),  [g ; v] = W1 Norm2(h)
+    h = x + r Mixer(Norm1(x));  y = h + r FF(Norm2(h))
 
-with no bias in a product and no positional encoding anywhere.
+with no bias in a product and no positional encoding anywhere.  ``FF`` is
+the config's: ``W2 (silu(g) * v)``, ``[g ; v] = W1 z``, or, with
+``cfg.n_experts``, routed experts that drop no token
+(``moe.dropless_moe_layer``: the experts this chip holds, scored by
+``cfg.router_score``, chosen with the layer's ``router_bias``) plus, with
+``cfg.n_shared_experts``, that same ``W1`` / ``W2`` as the expert every
+token passes through (``moe.shared_expert``), added once.
 ``cfg.trunk_norm`` says which norm (``layernorm``: weight and bias;
 ``rmsnorm``: weight), for the layers' two and the final one;
 ``cfg.residual_multiplier`` is ``r``; the embedding's and the logits'
@@ -51,7 +59,19 @@ default computes what the trunk computed before the config carried it.
   over their columns of ``W_in``, since a kernel reads no slice.
 * ``attention`` — plain grouped-query attention (Granite): ``softmax(s q
   k^T + causal) v`` through ``W_o``, ``s = cfg.attention_multiplier`` (0 =
-  ``1 / sqrt(Dh)``).
+  ``1 / sqrt(Dh)``).  With ``cfg.attn_gate`` the heads' output is gated
+  before ``W_o``: ``o * sigmoid(W_gate u)``, elementwise (the form G1 of
+  arXiv 2505.06708).
+* ``kda`` — Kimi Delta Attention: ``[q ; k ; v] = silu(conv(W_qkv u))``,
+  depthwise, causal, ``ssm_conv`` wide, no bias, in ``ssm_heads`` heads,
+  keys ``ssm_state`` and values ``ssm_inner / ssm_heads`` wide; ``q`` and
+  ``k`` of unit length a head; ``g = -exp(A_log) softplus(W_fb (W_fa u) +
+  dt_bias)`` a key channel (``A_log`` a head) and ``beta = 2 sigmoid(W_b
+  u)`` a head, both float32; ``o`` the chunked gated delta rule
+  (:mod:`horovod_tpu.ops.kda_scan`); the mixer gives ``W_o (RMSNorm(o) w *
+  sigmoid(W_gb (W_ga u)))``, the norm over a head's values.  The
+  convolution with its SiLU and split is
+  :func:`horovod_tpu.ops.mamba2_mixer.conv_silu_split`.
 
 Attention goes through ``ring_attention.local_attention`` with the mask
 as key ranges (``window_ranges``, ``causal_ranges``); differential
@@ -75,8 +95,12 @@ under the scopes ``hvd_ssm_mixer``, ``hvd_gmu``, ``hvd_diff_attention``,
 ``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``; the
 mixer's own kernels ``hvd_conv_silu_fwd`` / ``_bwd`` and
 ``hvd_gated_norm_fwd`` / ``_bwd`` under no scope of their own, rows of the
-mixer's) and ``hvd_attention``.  Plain data parallelism only: nothing here
-is sharded over a tensor-, sequence- or pipeline-parallel axis yet.
+mixer's), ``hvd_kda_mixer`` (the scan's call under ``hvd_kda_scan``) and
+``hvd_attention``; the feed-forward under ``hvd_mlp``, routed experts'
+``hvd_moe_route`` / ``hvd_moe_experts`` and the shared expert's
+``hvd_moe_shared`` inside it.  Plain data parallelism only: nothing here
+is sharded over a tensor-, sequence-, pipeline- or expert-parallel axis
+yet.
 """
 
 from __future__ import annotations
@@ -91,17 +115,26 @@ from jax import lax
 from .. import metrics as _metrics
 from ..ops import flash_attention as _fa
 from ..ops import mamba2_mixer as _mixer
+from ..ops.kda_scan import kda_scan
 from ..ops.selective_scan import selective_scan
 from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
 from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
-from .llama import _rmsnorm
+from . import moe
+from .llama import ParallelSpec, _rmsnorm
 
-KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention")
+# the five of PR 33 (SambaY), the two of PR 40 (Granite: ``mamba2`` under
+# ``hvd_ssd_mixer``, ``attention`` under ``hvd_attention``), and ``kda``
+# (PR 42: Kimi Delta Attention under ``hvd_kda_mixer``; ``attention`` takes
+# ``cfg.attn_gate``)
+KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention",
+         "kda")
 _DIFFERENTIAL = ("window", "full", "cross")
 _MATRICES = ("w1", "w2", "in_proj", "x_proj", "dt_proj", "out_proj", "wqkv",
-             "wq", "wo")
+             "wq", "wo", "wgate", "f_a", "f_b", "g_a", "g_b", "b_proj",
+             "we_gate", "we_up", "we_down")
+_L2_EPS = 1e-6      # under the root of a head's squared length (fla's)
 
 _m_kinds = _metrics.counter(
     "hvd_layer_kind_total",
@@ -133,6 +166,15 @@ def check(cfg) -> None:
             "a mamba2 layer needs ssm_heads dividing ssm_inner and "
             f"ssm_groups dividing ssm_heads, got {cfg.ssm_heads} heads of "
             f"{cfg.ssm_inner} channels in {cfg.ssm_groups} groups")
+    if "kda" in kinds and (cfg.ssm_heads <= 0 or cfg.ssm_state <= 0
+                           or cfg.ssm_inner % cfg.ssm_heads):
+        raise ValueError(
+            "a kda layer needs ssm_heads heads of ssm_state key channels "
+            f"dividing ssm_inner, got {cfg.ssm_heads} heads, keys "
+            f"{cfg.ssm_state} wide, {cfg.ssm_inner} value channels")
+    if cfg.n_experts > 0 and cfg.moe_dispatch != "dropless":
+        raise ValueError("routed experts in a trunk of several kinds are "
+                         "the dropless ones (moe_dispatch='dropless')")
 
 
 def published_kinds(n_layers: int):
@@ -171,6 +213,16 @@ def layer_shapes(cfg, kind):
             "gate_norm": (Di,), "out_proj": (Di, D)})
     elif kind == "attention":
         shapes.update({"wqkv": (D, (H + 2 * Hkv) * Dh), "wo": (H * Dh, D)})
+        if cfg.attn_gate:
+            shapes["wgate"] = (D, H * Dh)
+    elif kind == "kda":
+        Hs, Vd = cfg.ssm_heads, cfg.ssm_inner // cfg.ssm_heads
+        conv = 2 * Hs * N + Di
+        shapes.update({
+            "wqkv": (D, conv), "conv_w": (Kc, conv), "f_a": (D, N),
+            "f_b": (N, Hs * N), "dt_bias": (Hs * N,), "A_log": (Hs,),
+            "b_proj": (D, Hs), "g_a": (D, Vd), "g_b": (Vd, Di),
+            "o_norm": (Vd,), "wo": (Di, D)})
     elif kind == "mamba":
         shapes.update({
             "in_proj": (D, 2 * Di), "conv_w": (Kc, Di), "conv_b": (Di,),
@@ -184,6 +236,15 @@ def layer_shapes(cfg, kind):
             "wq" if kind == "cross" else "wqkv": (D, width),
             "wo": (H * Dh, D), "lambda_q1": (Dh,), "lambda_k1": (Dh,),
             "lambda_q2": (Dh,), "lambda_k2": (Dh,), "subln": (2 * Dh,)})
+    if cfg.n_experts > 0:
+        held, S = cfg.experts_held or cfg.n_experts, cfg.n_shared_experts
+        del shapes["w1"], shapes["w2"]
+        shapes.update({
+            "router": (D, cfg.n_experts), "router_bias": (cfg.n_experts,),
+            "we_gate": (held, D, F), "we_up": (held, D, F),
+            "we_down": (held, F, D)})
+        if S:
+            shapes.update({"w1": (D, 2 * S * F), "w2": (S * F, D)})
     return shapes
 
 
@@ -205,8 +266,8 @@ def init_layers(cfg, key):
     """{kind: {leaf: [layers of the kind, ...]}}: matrices normal at
     ``fan_in ** -0.5``, norms at 1 and 0, and Mamba's own: ``A_log =
     log(1..N)``, ``D = 1``, ``softplus(dt_bias)`` log-uniform on 1e-3 ..
-    1e-1; ``lambda``'s vectors normal(0, 0.1).  Mamba-2's ``A_log =
-    log(uniform(1, 16))`` a head."""
+    1e-1; ``lambda``'s vectors normal(0, 0.1).  Mamba-2's and KDA's ``A_log
+    = log(uniform(1, 16))`` a head; a router's selection bias 0."""
     check(cfg)
     dt = cfg.param_dtype
     out = {}
@@ -215,11 +276,12 @@ def init_layers(cfg, key):
         for b, (name, shape) in enumerate(layer_shapes(cfg, kind).items()):
             k = jax.random.fold_in(jax.random.fold_in(key, a), b)
             full = (n,) + shape
-            if name in ("norm1_w", "norm2_w", "subln", "D", "gate_norm"):
+            if name in ("norm1_w", "norm2_w", "subln", "D", "gate_norm",
+                        "o_norm"):
                 leaf = jnp.ones(full, dt)
-            elif name in ("norm1_b", "norm2_b", "conv_b"):
+            elif name in ("norm1_b", "norm2_b", "conv_b", "router_bias"):
                 leaf = jnp.zeros(full, dt)
-            elif name == "A_log" and kind == "mamba2":
+            elif name == "A_log" and kind in ("mamba2", "kda"):
                 leaf = jnp.log(jax.random.uniform(k, full, dt, 1.0, 16.0))
             elif name == "A_log":
                 leaf = jnp.broadcast_to(
@@ -230,8 +292,9 @@ def init_layers(cfg, key):
                 leaf = step + jnp.log(-jnp.expm1(-step))
             elif name.startswith("lambda_"):
                 leaf = jax.random.normal(k, full, dt) * 0.1
-            else:
-                leaf = jax.random.normal(k, full, dt) * shape[0] ** -0.5
+            else:       # fan-in: the rows, an expert's rows
+                fan_in = shape[1] if name.startswith("we_") else shape[0]
+                leaf = jax.random.normal(k, full, dt) * fan_in ** -0.5
             tree[name] = leaf
         out[kind] = tree
     return out
@@ -247,8 +310,7 @@ def layer_specs(cfg):
 # --------------------------------------------------------------- layers
 
 def _mlp(u, lp):
-    g, v = jnp.split(u @ lp["w1"], 2, axis=-1)
-    return (jax.nn.silu(g) * v) @ lp["w2"]
+    return moe.shared_expert(u, lp["w1"], lp["w2"])
 
 
 def _conv_silu(xs, lp, Kc):
@@ -316,6 +378,51 @@ def _mamba2(u, lp, cfg):
                                 cfg.norm_eps, turned) @ lp["out_proj"]
 
 
+def _kda(u, lp, cfg):
+    """The Kimi Delta Attention mixer's output ``[B, T, D]``."""
+    from ..training import SCOPE_KDA_SCAN
+    f32 = jnp.float32
+    B, T, _ = u.shape
+    Hs, K = cfg.ssm_heads, cfg.ssm_state
+    Vd = cfg.ssm_inner // Hs
+    q, k, v = _mixer.conv_silu_split(
+        u @ lp["wqkv"], lp["conv_w"], jnp.zeros_like(lp["conv_w"][0]),
+        (Hs * K, Hs * K, Hs * Vd))
+
+    def unit(a):            # a head's keys or queries at length 1
+        a32 = a.reshape(B, T, Hs, K).astype(f32)
+        return (a32 * lax.rsqrt(jnp.sum(a32 * a32, -1, keepdims=True)
+                                + _L2_EPS)).astype(a.dtype)
+
+    # decays and write strengths come out of their products in float32: a
+    # decay is the exponential of up to a chunk's sum of them
+    f = jnp.dot(u @ lp["f_a"], lp["f_b"], preferred_element_type=f32)
+    g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        f + lp["dt_bias"].astype(f32)).reshape(B, T, Hs, K)
+    beta = 2.0 * jax.nn.sigmoid(
+        jnp.dot(u, lp["b_proj"], preferred_element_type=f32))
+    with jax.named_scope(SCOPE_KDA_SCAN):
+        o = kda_scan(unit(q), unit(k), v.reshape(B, T, Hs, Vd), g, beta,
+                     cfg.ssm_chunk)
+    o32 = o.astype(f32)
+    o32 = o32 * lax.rsqrt(jnp.mean(o32 * o32, -1, keepdims=True)
+                          + cfg.norm_eps) * lp["o_norm"].astype(f32)
+    gate = jax.nn.sigmoid(((u @ lp["g_a"]) @ lp["g_b"]).astype(f32))
+    return (o32.reshape(gate.shape) * gate).astype(u.dtype) @ lp["wo"]
+
+
+def _feed_forward(z, lp, cfg):
+    """The config's feed-forward of the normed stream ``z`` -> (its output,
+    routed experts' ``[4]`` statistics or None)."""
+    if cfg.n_experts == 0:
+        return _mlp(z, lp), None
+    y, stats = moe.dropless_moe_layer(z, lp, cfg, ParallelSpec())
+    if cfg.n_shared_experts:
+        with jax.named_scope(moe.SCOPE_SHARED):
+            y = y + _mlp(z, lp)
+    return y, stats
+
+
 def _pairs(x):
     """``[B, T, H, Dh]`` -> the pairs' first and second heads, ``[B, T,
     H / 2, Dh]`` each."""
@@ -367,12 +474,14 @@ def join(x, y, cfg):
 
 
 def _layer(kind, emits, cfg):
-    """One layer of ``kind`` as ``f(h, lp, lam0, memory) -> (h, emitted)``:
-    ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer, else
-    None; ``emitted`` is what an emitting mamba (``s``) or full layer
-    (``(k, v)``) hands on, else None."""
+    """One layer of ``kind`` as ``f(h, lp, lam0, memory) -> (h, emitted,
+    stats)``: ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer,
+    else None; ``emitted`` is what an emitting mamba (``s``) or full layer
+    (``(k, v)``) hands on, else None; ``stats`` routed experts' ``[4]``
+    statistics, None of a dense feed-forward."""
     from ..training import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
-                            SCOPE_MLP, SCOPE_SSD_MIXER, SCOPE_SSM_MIXER)
+                            SCOPE_KDA_MIXER, SCOPE_MLP, SCOPE_SSD_MIXER,
+                            SCOPE_SSM_MIXER)
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def f(h, lp, lam0, memory):
@@ -384,22 +493,31 @@ def _layer(kind, emits, cfg):
         B, T, _ = h.shape
         norm1 = lambda: norm(h, lp["norm1_w"], lp.get("norm1_b"), cfg)
         emitted = None
-        # the two kinds of PR 40 open their scope around the sublayer with
-        # its norm, as ``llama.block`` and ``hvd_mlp`` do; the five of PR 33
-        # keep ``norm1`` before theirs, where the accepted metrics read them
+        # the kinds of PR 40 and 42 open their scope around the sublayer
+        # with its norm, as ``llama.block`` and ``hvd_mlp`` do; the five of
+        # PR 33 keep ``norm1`` before theirs, where the accepted metrics
+        # read them
         if kind == "mamba2":
             with jax.named_scope(SCOPE_SSD_MIXER):
                 y = _mamba2(norm1(), lp, cfg)
+        elif kind == "kda":
+            with jax.named_scope(SCOPE_KDA_MIXER):
+                y = _kda(norm1(), lp, cfg)
         elif kind == "attention":
             with jax.named_scope(SCOPE_ATTENTION):
-                q, k, v = jnp.split(norm1() @ lp["wqkv"],
+                u = norm1()
+                q, k, v = jnp.split(u @ lp["wqkv"],
                                     (H * Dh, (H + Hkv) * Dh), axis=-1)
                 o = local_attention(
                     q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh),
                     v.reshape(B, T, Hkv, Dh),
                     sm_scale=cfg.attention_multiplier or None,
-                    mask=key_ranges(kind, T, cfg))
-                y = o.reshape(B, T, H * Dh) @ lp["wo"]
+                    mask=key_ranges(kind, T, cfg)).reshape(B, T, H * Dh)
+                if cfg.attn_gate:
+                    gate = jax.nn.sigmoid(
+                        (u @ lp["wgate"]).astype(jnp.float32))
+                    o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+                y = o @ lp["wo"]
         elif kind == "mamba":
             u = norm1()
             with jax.named_scope(SCOPE_SSM_MIXER):
@@ -425,8 +543,9 @@ def _layer(kind, emits, cfg):
                                     cfg)
         h = join(h, y, cfg)
         with jax.named_scope(SCOPE_MLP):
-            y = _mlp(norm(h, lp["norm2_w"], lp.get("norm2_b"), cfg), lp)
-        return join(h, y, cfg), emitted
+            y, stats = _feed_forward(
+                norm(h, lp["norm2_w"], lp.get("norm2_b"), cfg), lp, cfg)
+        return join(h, y, cfg), emitted, stats
 
     return f
 
@@ -458,19 +577,25 @@ def _runs(cfg):
             runs[-1][2].append(ids[i])
         else:
             runs.append([kind, at, [ids[i]], emits])
+    # (with routed experts every layer is a run of its own too: a layer
+    # then holds 160 M parameters at the solar-open2-250b cell's sizes, and
+    # a scan's stacked gradient and its stack of weights cast for the
+    # products were 3.3 GB of the step's temporaries)
     shared = {kind for kind in set(kinds)
-              if sum(r[0] == kind for r in runs) > 1}
+              if sum(r[0] == kind for r in runs) > 1 or cfg.n_experts > 0}
     return [run for kind, at, ids_, emits in runs
             for run in ([[kind, at + j, [i], emits]
                          for j, i in enumerate(ids_)] if kind in shared
                         else [[kind, at, ids_, emits]])]
 
 
-def layer_stack(h, layers, cfg, policy=None):
+def layer_stack(h, layers, cfg, policy=None, with_stats=False):
     """The trunk: ``h [B, T, D]`` through every layer.  ``policy``: the
-    remat policy of ``cfg.remat`` (``llama.remat_policy``)."""
+    remat policy of ``cfg.remat`` (``llama.remat_policy``).
+    ``with_stats`` also returns routed experts' ``[4]`` statistics summed
+    over the layers (``moe.ROUTING_STATS``), None of a dense trunk."""
     check(cfg)
-    m = kv = None
+    m = kv = stats = None
     made = {}       # one function a (kind, emits): equal layers trace once
     for kind, first, ids, emits in _runs(cfg):
         n = len(ids)
@@ -486,14 +611,16 @@ def layer_stack(h, layers, cfg, policy=None):
         lps = jax.tree_util.tree_map(lambda w: w[first:first + n],
                                      layers[kind])
         if n == 1:
-            h, emitted = f(h, jax.tree_util.tree_map(lambda w: w[0], lps),
-                           lam0[0], memory)
+            h, emitted, new = f(h, jax.tree_util.tree_map(lambda w: w[0], lps),
+                                lam0[0], memory)
             if kind == "mamba" and emits:
                 m = emitted
             elif emits:
                 kv = emitted
-        else:
+            if new is not None:
+                stats = new if stats is None else stats + new
+        else:       # never a routed layer: ``_runs`` gives each its own run
             h, _ = lax.scan(
                 lambda h_, at: (f(h_, at[0], at[1], memory)[0], None),
                 h, (lps, lam0))
-    return h
+    return (h, stats) if with_stats else h

@@ -102,16 +102,29 @@ class LlamaConfig:
     moe_dispatch: str = "capacity"
     experts_held: int = 0
     experts_first: int = 0
+    # how dropless experts' router scores its outputs (``moe.route``):
+    # "softmax" over all of them, or "sigmoid" of each (then a layer also
+    # carries a selection bias, ``router_bias``, that moves the choice
+    # alone); and how many shared experts of width ``d_ff`` every token
+    # passes through beside the routed ones (as one of that many times the
+    # width; the trunk of several kinds alone)
+    router_score: str = "softmax"
+    n_shared_experts: int = 0
     # a trunk whose layers are of several kinds (models/hybrid.py): each
     # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" |
-    # "mamba2" | "attention" (() = the trunk of identical layers here),
+    # "mamba2" | "attention" | "kda" (() = the trunk of identical layers here),
     # and its index in the published model (() = its place in the trunk);
     # the window of the "window" kind's attention; the state-space
     # mixers' inner width, states (a channel for "mamba", a head's
     # columns for "mamba2"), convolution width, and "mamba"'s step rank;
     # "mamba2"'s heads (of ssm_inner / ssm_heads channels), the groups
     # that share B and C, and the positions a chunk of its scan takes.
-    # Such a trunk has a fused gate/up MLP and no positional encoding;
+    # "kda" (Kimi Delta Attention) has ssm_heads heads whose keys are
+    # ssm_state and whose values ssm_inner / ssm_heads wide, a convolution
+    # ssm_conv wide and chunks of ssm_chunk positions; ``attn_gate`` puts a
+    # sigmoid gate of the layer's input on the "attention" kind's output,
+    # before ``wo``.  Such a trunk has a fused gate/up MLP (or, with
+    # ``n_experts``, dropless routed experts) and no positional encoding;
     # its layers' norm is ``trunk_norm``: "layernorm" (weight and bias)
     # or "rmsnorm" (weight).
     layer_kinds: tuple = ()
@@ -124,6 +137,7 @@ class LlamaConfig:
     ssm_heads: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 256
+    attn_gate: bool = False
     trunk_norm: str = "layernorm"
     # Granite's four multipliers, of the trunk of several kinds alone
     # (the trunk of identical layers refuses them), each at what a trunk
@@ -147,6 +161,14 @@ class LlamaConfig:
         if self.trunk_norm not in ("layernorm", "rmsnorm"):
             raise ValueError("trunk_norm must be 'layernorm' or 'rmsnorm', "
                              f"got {self.trunk_norm!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError("router_score must be 'softmax' or 'sigmoid', "
+                             f"got {self.router_score!r}")
+        if not self.layer_kinds and (self.n_shared_experts
+                                     or self.attn_gate):
+            raise ValueError(
+                "n_shared_experts and attn_gate are wired through the trunk "
+                "of several kinds (layer_kinds) alone")
         multipliers = (self.embedding_multiplier, self.residual_multiplier,
                        self.attention_multiplier, self.logits_scaling)
         if not self.layer_kinds and multipliers != (1.0, 1.0, 0.0, 1.0):
@@ -210,6 +232,10 @@ def init_params(cfg: LlamaConfig, key, tp: int = 1) -> Dict:
         if cfg.trunk_norm == "layernorm":
             params["final_norm_bias"] = jnp.zeros((cfg.d_model,),
                                                   cfg.param_dtype)
+        if not cfg.tie_embeddings:
+            params["head"] = jax.random.normal(
+                jax.random.fold_in(key, 8), (cfg.vocab_size, cfg.d_model),
+                cfg.param_dtype) * cfg.d_model ** -0.5
         return params
     k = jax.random.split(key, 8)
     D, H, Hkv, Dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
@@ -270,6 +296,8 @@ def param_specs(par: ParallelSpec, cfg: Optional[LlamaConfig] = None):
                  "final_norm": P()}
         if cfg.trunk_norm == "layernorm":
             specs["final_norm_bias"] = P()
+        if not cfg.tie_embeddings:
+            specs["head"] = P()
         return specs
     tp = par.tp_axis
     pp = par.pp_axis
@@ -616,7 +644,8 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
                    positions, mask):
     """:func:`hidden` of a trunk of several kinds (models/hybrid.py): no
     positions at all, each kind's own mask, a final norm of the trunk's
-    kind."""
+    kind; with routed experts, their routing statistics summed over the
+    layers in place of the zero."""
     from . import hybrid
     if (positions is not None or mask is not None
             or any(a is not None for a in (par.tp_axis, par.sp_axis,
@@ -627,13 +656,14 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     from ..training import SCOPE_EMBED, SCOPE_HEAD
     with jax.named_scope(SCOPE_EMBED):
         h = _embed_lookup(params["embed"], tokens, cfg, par)
-    h = hybrid.layer_stack(
+    h, stats = hybrid.layer_stack(
         h, params["layers"], cfg,
-        remat_policy(cfg.remat_policy) if cfg.remat else None)
+        remat_policy(cfg.remat_policy) if cfg.remat else None,
+        with_stats=True)
     with jax.named_scope(SCOPE_HEAD):
         h = hybrid.norm(h, params["final_norm"],
                         params.get("final_norm_bias"), cfg)
-    return h, jnp.float32(0.0)
+    return h, jnp.float32(0.0) if stats is None else stats
 
 
 def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,

@@ -15,7 +15,10 @@ Two dispatch paths, ``cfg.moe_dispatch``:
   capacity and one-hot dispatch; overflow tokens drop; the only path
   with an ``ep`` axis of more than one chip (its two ``all_to_all``).
 * ``"dropless"`` (:func:`dropless_moe_layer`): top-k over all
-  ``cfg.n_experts`` router outputs in float32; the layer is told which
+  ``cfg.n_experts`` router outputs in float32 (:func:`route`: a softmax
+  over them or a sigmoid of each, with or without a selection bias; a
+  shared expert, :func:`shared_expert`, is added by the trunk that has
+  one); the layer is told which
   experts it holds (``cfg.experts_first`` and the leading dimension of
   the expert weights it is given: a chip's share of a deployment) and
   computes their part of the result.  The (token, expert) pairs whose
@@ -54,8 +57,9 @@ from jax import lax
 from .. import metrics as _metrics
 from ..ops import grouped_matmul as _gmm
 
-SCOPE_ROUTE = "hvd_moe_route"        # router logits, softmax, top-k
+SCOPE_ROUTE = "hvd_moe_route"        # router logits, scores, top-k
 SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine
+SCOPE_SHARED = "hvd_moe_shared"      # the expert every token passes through
 
 _m_layers = _metrics.counter(
     "hvd_moe_layer_total",
@@ -359,28 +363,56 @@ def _held_experts_bwd(k, rows, res, cotangents):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def route(tokens, router, k):
-    """``(expert ids [N, k], weights [N, k] float32)``: softmax over all
-    of the router's outputs in float32, the ``k`` largest, renormalised
-    over those ``k`` whether their experts are held here or not."""
+SCORES = ("softmax", "sigmoid")
+
+
+def route(tokens, router, k, score="softmax", bias=None):
+    """``(expert ids [N, k], weights [N, k] float32)``: every one of the
+    router's outputs scored in float32, by ``score``: ``"softmax"`` over
+    all of them, or ``"sigmoid"`` of each alone (DeepSeek-V3's); the ``k``
+    with the largest score are chosen, or, given a selection ``bias [E]``,
+    the largest ``score + bias``; the weights are the chosen experts'
+    scores (without the bias: it moves the choice and nothing else),
+    renormalised over those ``k`` whether their experts are held here or
+    not.  The bias gets no gradient."""
+    if score not in SCORES:
+        raise ValueError(f"score must be one of {SCORES}, got {score!r}")
     logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    top_p, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if bias is None:
+        top_p, top_i = lax.top_k(scores, k)
+    else:
+        _, top_i = lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
     return top_i, top_p / top_p.sum(axis=-1, keepdims=True)
+
+
+def shared_expert(x, w_gate_up, w_down):
+    """The expert every token passes through, ``W_down (silu(g) * u)`` with
+    ``[g ; u] = W_gate_up x``: gate and up as one product, no routing, no
+    grouped kernel.  It is added to the routed experts' result once, on
+    every chip alike (replicated; never over an ``ep`` axis)."""
+    gate, up = jnp.split(x @ w_gate_up.astype(x.dtype), 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ w_down.astype(x.dtype)
 
 
 def dropless_moe_layer(x, lp, cfg, par):
     """One routed expert sublayer that drops no token.  ``x [B, T, D]``;
-    ``lp["router"] [D, cfg.n_experts]``; ``lp["we_*"]`` the experts held
-    here, ``cfg.experts_first`` the id of the first.  Returns the held
-    experts' part of the layer's output and ``[4]`` float32 statistics
-    (ROUTING_STATS: pairs routed here, rows computed, the fullest held
-    expert's pairs, 1)."""
+    ``lp["router"] [D, cfg.n_experts]``, scored by ``cfg.router_score``
+    and chosen with ``lp["router_bias"] [cfg.n_experts]`` where the layer
+    has one; ``lp["we_*"]`` the experts held here, ``cfg.experts_first``
+    the id of the first.  Returns the held experts' part of the layer's
+    output and ``[4]`` float32 statistics (ROUTING_STATS: pairs routed
+    here, rows computed, the fullest held expert's pairs, 1).  A shared
+    expert is the caller's to add (:func:`shared_expert`)."""
     if par.tp_axis is not None or par.ep_axis is not None:
         raise NotImplementedError(
-            "dropless experts run on the experts a chip holds; over a tp or "
-            "ep axis the layer is the capacity path's (moe_dispatch="
-            "'capacity')")
+            "dropless experts run on the experts a chip holds, and a shared "
+            "expert beside them whole on every chip; over a tp or ep axis "
+            "the layer is the capacity path's (moe_dispatch='capacity'), "
+            "which has no shared expert")
     if _metrics.ACTIVE:
         _m_layers.inc(path="dropless")
     B, T, D = x.shape
@@ -388,7 +420,8 @@ def dropless_moe_layer(x, lp, cfg, par):
     held = lp["we_gate"].shape[0]
     tokens = x.reshape(N, D)
     with jax.named_scope(SCOPE_ROUTE):
-        top_i, top_w = route(tokens, lp["router"], k)
+        top_i, top_w = route(tokens, lp["router"], k, cfg.router_score,
+                             lp.get("router_bias"))
     with jax.named_scope(SCOPE_EXPERTS):
         local = (top_i - cfg.experts_first).reshape(-1)
         e = jnp.where((local >= 0) & (local < held), local, held)

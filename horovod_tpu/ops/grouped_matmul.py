@@ -128,17 +128,32 @@ def _refusal(rows, *weights) -> Optional[str]:
         if w.shape[1] % _LANES or w.shape[2] % _LANES:
             return (f"weights {w.shape[1:]} are no multiples of {_LANES} "
                     "both ways")
-    # the dearest calls: tgmm's float32 accumulator in and out and its two
-    # row tiles; gmm's two weight matrices, a row tile in and a float32 one
-    # out; every buffer twice
+    # the dearest calls: gmm's two weight matrices, a row tile in and a
+    # float32 one out, every buffer twice; tgmm's float32 accumulator in
+    # and out and its two row tiles, in as many blocks of the accumulator's
+    # rows as make them fit (:func:`_tgmm_split`)
     k = max(w.shape[1] * w.shape[2] for w in weights)
     wide, item = max(max(w.shape[1:]) for w in weights), rows.dtype.itemsize
-    need = 2 * max(2 * k * 4 + 2 * _TGMM_TILE * wide * item,
-                   2 * k * item + _TILE * wide * (item + 4))
-    if need > _STEP_VMEM:
-        return (f"a grid step needs {need} bytes of VMEM, over the "
-                f"{_STEP_VMEM} a step may hold")
+    need = 2 * (2 * k * item + _TILE * wide * (item + 4))
+    if need > _STEP_VMEM or not all(
+            _tgmm_split(w.shape[1], w.shape[2], item) for w in weights):
+        return (f"a grid step needs over the {_STEP_VMEM} bytes of VMEM a "
+                f"step may hold (weights {weights[0].shape[1:]})")
     return None
+
+
+def _tgmm_split(K: int, N: int, item: int) -> int:
+    """In how many blocks of its rows :func:`tgmm` walks a ``[K, N]``
+    accumulator, so that a block in and out and the two row tiles, every
+    buffer twice, fit a grid step: 1 at the SDAR cell's 2,048 x 768, 2 at
+    4,096 x 1,280; 0 = no whole number of lanes does."""
+    for split in (1, 2, 4, 8):
+        kb = K // split
+        if K % (split * _LANES) == 0 and 2 * (
+                2 * kb * N * 4 + 2 * _TGMM_TILE * max(kb, N) * item
+                ) <= _STEP_VMEM:
+            return split
+    return 0
 
 
 def supported(rows, *weights) -> bool:
@@ -189,11 +204,11 @@ def _plan(sizes, rows: int, tm: int):
     return table.astype(jnp.int32), bounds.astype(jnp.int32), V
 
 
-def _visit(tbl, bounds, tm):
+def _visit(tbl, bounds, tm, axis=0):
     """This grid step's ``(flags, whole, mask)``: ``whole`` when the read
     tile lies inside the group, ``mask()`` ``[tm, 1]`` the tile's rows
-    that are the group's."""
-    v = pl.program_id(0)
+    that are the group's.  ``axis``: the grid's axis that counts visits."""
+    v = pl.program_id(axis)
     g, flags = tbl[4 * v], tbl[4 * v + 3]
     r0 = tbl[4 * v + 2] * tm
     lo, hi = bounds[2 * g], bounds[2 * g + 1]
@@ -236,11 +251,11 @@ def _gmm_kernel(tbl, bounds, *refs, body, n_rows, n_weights, tm):
             o[...] = jnp.zeros_like(o)
 
 
-def _limit(blocks: int, temporaries: int):
+def _limit(blocks: int, temporaries: int, axes: int = 1):
     """The call's VMEM limit: its blocks double-buffered, the fp32 tiles
     in flight, and room."""
     return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
+        dimension_semantics=("arbitrary",) * axes,
         vmem_limit_bytes=int(2 * blocks + temporaries + 16 * 1024 * 1024))
 
 
@@ -281,8 +296,8 @@ def gmm(body, rows, weights, sizes, outs, name: str):
     )(table, bounds, *rows, *weights)
 
 
-def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm):
-    flags, whole, mask = _visit(tbl, bounds, tm)
+def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm, axis):
+    flags, whole, mask = _visit(tbl, bounds, tm, axis)
     pair = flags % 4 == 1
     opens = flags >= 8                 # the group's first visit
 
@@ -302,7 +317,7 @@ def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm):
 
     # no pair at all: the one block the pipeline still writes back keeps
     # what it held
-    @pl.when((pl.program_id(0) == 0) & jnp.logical_not(pair))
+    @pl.when((pl.program_id(axis) == 0) & jnp.logical_not(pair))
     def _():
         acc[...] = held[...]
 
@@ -310,22 +325,40 @@ def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm):
 def tgmm(x, y, sizes, acc, name: str):
     """``acc[g] + x[rows of g].T @ y[rows of g]`` for every group ``g``:
     ``x [R, K]`` and ``y [R, N]`` sorted by group, ``acc [G, K, N]``
-    float32, given up to the call (the output takes its place)."""
+    float32, given up to the call (the output takes its place).  An
+    accumulator too large for a grid step is walked in blocks of its rows
+    (``x``'s columns), the visits once a block: a grid ``(blocks,
+    visits)``."""
     R, K, N, tm = x.shape[0], x.shape[1], y.shape[1], _TGMM_TILE
     table, bounds, V = _plan(sizes, R, tm)
     _count("tgmm", "pallas")
-    row = lambda width: pl.BlockSpec(
-        (tm, width), lambda v, t, b: (t[4 * v + 2], 0))
-    held = pl.BlockSpec((1, K, N), lambda v, t, b: (t[4 * v], 0, 0))
+    split = _tgmm_split(K, N, x.dtype.itemsize) or 1
+    kb = K // split
+    # an index map's arguments: the grid's ids, then table and bounds
+    at = (lambda a: (0, a[0], a[-2])) if split == 1 else (
+        lambda a: (a[0], a[1], a[-2]))
+
+    def row(width, column):
+        def index(*a):
+            j, v, t = at(a)
+            return t[4 * v + 2], j if column else 0
+        return pl.BlockSpec((tm, width), index)
+
+    def group(*a):
+        j, v, t = at(a)
+        return t[4 * v], j, 0
+
+    held = pl.BlockSpec((1, kb, N), group)
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm),
+        functools.partial(_tgmm_kernel, tm=tm, axis=int(split > 1)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(V,),
-            in_specs=[row(K), row(N), held], out_specs=held),
+            num_scalar_prefetch=2, grid=(V,) if split == 1 else (split, V),
+            in_specs=[row(kb, True), row(N, False), held], out_specs=held),
         out_shape=_sds(acc.shape, jnp.float32, sizes, x, y, acc),
         input_output_aliases={4: 0},
-        compiler_params=_limit(tm * (K + N) * x.dtype.itemsize
-                               + 2 * K * N * 4, K * N * 4),
+        compiler_params=_limit(tm * (kb + N) * x.dtype.itemsize
+                               + 2 * kb * N * 4, kb * N * 4,
+                               1 + int(split > 1)),
         interpret=_INTERPRET,
         name="hvd_moe_tgmm_" + name,
     )(table, bounds, x, y, acc)

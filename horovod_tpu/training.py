@@ -53,6 +53,8 @@ SCOPE_GMU = "hvd_gmu"
 SCOPE_DIFF_ATTENTION = "hvd_diff_attention"
 SCOPE_SSD_MIXER = "hvd_ssd_mixer"  # the Mamba-2 mixer: norm1, projections, conv, scan, gated norm
 SCOPE_SSD_SCAN = "hvd_ssd_scan"    # inside it: ops/ssd_scan.py's call, whatever computes it
+SCOPE_KDA_MIXER = "hvd_kda_mixer"  # Kimi Delta Attention: norm1, projections, conv, scan, gated norm
+SCOPE_KDA_SCAN = "hvd_kda_scan"    # inside it: ops/kda_scan.py's call, whatever computes it
 
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec

@@ -988,10 +988,24 @@ def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
     ids in scalar memory, a ring of copies from HBM), with no scatter
     left beside it.  Compiled here for a v5e that is described, not
     attached."""
+    _grouped_kernels_lower(call, monkeypatch, 24576, 2048, 768, 16, 16384)
+
+
+@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+def test_grouped_matmul_kernels_lower_at_a_hidden_size_of_4096(call, monkeypatch):
+    """The same at the solar-open2-250b cell: a chunk of 2,560 rows of width
+    4,096 over 8 held experts of width 1,280; ``tgmm`` walks its float32
+    ``[4096, 1280]`` accumulator in two blocks of rows, since in and out and
+    twice it is 84 MB and a grid step may hold 64."""
+    from horovod_tpu.ops import grouped_matmul as gm
+    assert gm._tgmm_split(4096, 1280, 2) == 2
+    _grouped_kernels_lower(call, monkeypatch, 2560, 4096, 1280, 8, 8192)
+
+
+def _grouped_kernels_lower(call, monkeypatch, R, D, F, E, tokens):
     from horovod_tpu.models import moe
     from horovod_tpu.ops import grouped_matmul as gm
     one_chip = _described_chip(monkeypatch)
-    R, D, F, E = 24576, 2048, 768, 16
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
     xs, wt, sizes = (sds((R, D), jnp.bfloat16), sds((R,), jnp.float32),
@@ -1007,7 +1021,7 @@ def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
         text = jax.jit(lambda *a: gm.combine(*a, "out"),
                        donate_argnums=3).lower(
             sds((R, D), jnp.float32), sds((R,), jnp.int32), sizes,
-            sds((16384, D), jnp.float32)).compile().as_text()
+            sds((tokens, D), jnp.float32)).compile().as_text()
         names = ("hvd_moe_combine_out",)
         assert "scatter" not in text
     else:
@@ -1317,6 +1331,31 @@ def test_ssd_scan_kernels_lower_for_the_chip(monkeypatch):
         argnums=tuple(range(6)))).lower(*operands).compile().as_text()
     assert "hvd_ssd_chunk_fwd" in text and "hvd_ssd_chunk_bwd" in text
     assert f"{T},{H},{P},{N}]" not in text and "64,256,256]" not in text
+
+
+def test_kda_scan_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the chunked gated delta rule forward and backward at
+    the benchmark's solar-open2-250b cell: 8,192 positions of 8 heads whose
+    keys and values are 128 wide in chunks of 64, bf16 ``q``, ``k``, ``v``
+    beside float32 decays and ``beta``; the per-channel decay of the
+    diagonal blocks (``[.., 16, 16, 128]``: 537 MB in float32, one array)
+    stays inside its fusions: forward and backward together take 0.65 GB of
+    temporaries."""
+    from horovod_tpu.ops import kda_scan as kd
+    one_chip = _described_chip(monkeypatch)
+    T, H, K = 8192, 8, 128
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    wide = sds((1, T, H, K), jnp.bfloat16)
+    operands = (wide, wide, wide, sds((1, T, H, K), jnp.float32),
+                sds((1, T, H), jnp.float32))
+    assert kd.supported(*operands, 64)
+    compiled = jax.jit(jax.grad(
+        lambda *a: kd.kda_scan(*a, 64).astype(jnp.float32).sum(),
+        argnums=tuple(range(5)))).lower(*operands).compile()
+    text = compiled.as_text()
+    assert "hvd_kda_chunk_fwd" in text and "hvd_kda_chunk_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
 @pytest.mark.parametrize("turned", [False, True], ids=["rows", "turned"])

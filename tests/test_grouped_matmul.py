@@ -98,6 +98,31 @@ def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, interpret):
             np.testing.assert_array_equal(out[g], held[g])   # kept to the bit
 
 
+@pytest.mark.parametrize("sizes,dtype", CASES)
+def test_tgmm_walks_a_large_accumulator_in_blocks_of_its_rows(
+        sizes, dtype, interpret, monkeypatch):
+    """An accumulator that, in and out and twice, does not fit a grid step
+    (4,096 x 1,280 on the chip; here the step's room is cut to force it) is
+    walked in two blocks of its rows over a grid (blocks, visits), to the
+    same sums."""
+    x, _, sizes = _operands(sizes, dtype)
+    y = jax.random.normal(jax.random.key(1), (R, N)).astype(dtype)
+    held = jax.random.normal(jax.random.key(3), (G, K, N))
+    whole = jax.jit(lambda *a: gm.tgmm(*a, "t"))(x, y, sizes, held)
+    item = jnp.dtype(dtype).itemsize
+    assert gm._tgmm_split(K, N, item) == 1
+    room = 2 * (2 * K // 2 * N * 4 + 2 * gm._TGMM_TILE * max(K // 2, N) * item)
+    monkeypatch.setattr(gm, "_STEP_VMEM", room)
+    assert gm._tgmm_split(K, N, item) == 2
+    halves = jax.jit(lambda *a: gm.tgmm(*a, "t2"))(x, y, sizes, held)
+    np.testing.assert_allclose(halves, whole, atol=1e-3, rtol=1e-4)
+    # the benchmark's two shapes: SDAR's whole, Solar's in two blocks
+    monkeypatch.undo()
+    assert gm._tgmm_split(2048, 768, 2) == gm._tgmm_split(768, 2048, 2) == 1
+    assert gm._tgmm_split(4096, 1280, 2) == gm._tgmm_split(1280, 4096, 2) == 2
+    assert gm._tgmm_split(8192, 2048, 4) == 8 and gm._tgmm_split(16384, 4096, 4) == 0
+
+
 def test_a_body_of_two_products_and_an_epilogue(interpret):
     """What the expert layer asks of ``gmm``: two weights a group, two
     outputs, one of them a column, from rows and a column of weights."""

@@ -198,7 +198,7 @@ def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
         seen[kind] = at + 1
         lp = jax.tree_util.tree_map(lambda w_: w_[at], params["layers"][kind])
         memory = m if kind == "gmu" else kv if kind == "cross" else None
-        want, out = hybrid._layer(kind, i in (4, 5), cfg)(
+        want, out, _ = hybrid._layer(kind, i in (4, 5), cfg)(
             want, lp, hybrid.lambda_init(cfg.layer_ids[i]), memory)
         m, kv = (out, kv) if i == 4 else (m, out) if i == 5 else (m, kv)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
@@ -386,3 +386,114 @@ def test_the_frame_is_the_configs_rmsnorm_has_no_bias():
         _cfg(trunk_norm="batchnorm")
     with pytest.raises(ValueError, match="ssm_heads"):
         hybrid.check(_cfg(n_layers=1, layer_kinds=("mamba2",), ssm_heads=3))
+
+
+# --------------------------- the delta-rule hybrids (solar-open2-250b)
+# The kind ``kda``, the gate on ``attention`` and routed experts beside a
+# shared one as the config's feed-forward.  The plain reference is the
+# benchmark's, benchmark/configs/solar-open2-250b/reference.py, at its toy
+# sizes (tests/benchmark/test_bench_solar.py runs the train step against it).
+
+def _solar_cfg(**kw):
+    base = dict(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, n_layers=3,
+                d_ff=32, layer_kinds=("attention", "kda", "kda"), ssm_heads=4,
+                ssm_state=16, ssm_inner=64, ssm_conv=4, ssm_chunk=16,
+                trunk_norm="rmsnorm", attn_gate=True, tie_embeddings=False,
+                n_experts=16, expert_top_k=4, experts_held=4, experts_first=4,
+                moe_dispatch="dropless", router_score="sigmoid",
+                n_shared_experts=1)
+    return _cfg(**{**base, **kw})
+
+
+def test_kda_gate_and_routed_feed_forward_are_the_configs_leaves():
+    cfg = _solar_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    routed = {"norm1_w", "norm2_w", "router", "router_bias", "we_gate", "we_up",
+              "we_down", "w1", "w2"}
+    assert set(params["layers"]["attention"]) == routed | {"wqkv", "wgate", "wo"}
+    assert set(params["layers"]["kda"]) == routed | {
+        "wqkv", "conv_w", "f_a", "f_b", "dt_bias", "A_log", "b_proj", "g_a",
+        "g_b", "o_norm", "wo"}
+    kda, att = params["layers"]["kda"], params["layers"]["attention"]
+    assert kda["wqkv"].shape == (2, 64, 3 * 64) and kda["conv_w"].shape == (2, 4, 192)
+    assert kda["f_a"].shape == (2, 64, 16) and kda["f_b"].shape == (2, 16, 64)
+    assert kda["dt_bias"].shape == (2, 64) and kda["A_log"].shape == (2, 4)
+    assert kda["b_proj"].shape == (2, 64, 4) and kda["o_norm"].shape == (2, 16)
+    assert att["wgate"].shape == (1, 64, 64) and att["w1"].shape == (1, 64, 64)
+    assert att["router"].shape == (1, 64, 16) and att["we_down"].shape == (1, 4, 32, 64)
+    assert not np.asarray(kda["router_bias"]).any() and (np.asarray(kda["o_norm"]) == 1).all()
+    A = np.exp(np.asarray(kda["A_log"]))
+    assert (1 <= A).all() and (A <= 16).all()
+    step = np.asarray(jax.nn.softplus(kda["dt_bias"]))
+    assert 0.999e-3 <= step.min() and step.max() <= 0.1001
+    assert abs(float(att["we_gate"].std()) - 64 ** -0.5) < 0.01     # an expert's rows
+    assert params["head"].shape == params["embed"].shape == (256, 64)
+    assert llama.count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    specs = llama.param_specs(llama.ParallelSpec(), cfg)
+    assert jax.tree_util.tree_structure(specs) == jax.tree_util.tree_structure(
+        jax.tree_util.tree_map(lambda _: 0, params)) or set(specs) == set(params)
+    # without the flags the kinds' leaves are Granite's, to the name
+    plain = llama.init_params(_solar_cfg(attn_gate=False, n_experts=0,
+                                         n_shared_experts=0), jax.random.key(0))
+    assert set(plain["layers"]["attention"]) == {
+        "norm1_w", "norm2_w", "w1", "w2", "wqkv", "wo"}
+    assert plain["layers"]["attention"]["w1"].shape == (1, 64, 64)
+    # every routed layer is a run of its own (no scan's stacked gradient)
+    assert [(k, at, ids) for k, at, ids, _ in hybrid._runs(cfg)] == [
+        ("attention", 0, [0]), ("kda", 0, [1]), ("kda", 1, [2])]
+    assert [(k, ids) for k, _, ids, _ in hybrid._runs(_solar_cfg(
+        n_experts=0, n_shared_experts=0))] == [("attention", [0]), ("kda", [1, 2])]
+
+
+@pytest.mark.parametrize("kw,error", [
+    (dict(ssm_heads=3), "kda layer needs"), (dict(ssm_state=0), "kda layer needs"),
+    (dict(moe_dispatch="capacity"), "dropless")])
+def test_kda_and_routed_trunks_that_cannot_be_are_refused(kw, error):
+    with pytest.raises(ValueError, match=error):
+        hybrid.check(_solar_cfg(**kw))
+
+
+def test_routed_trunk_hands_on_its_statistics_and_a_dense_one_none():
+    cfg = _solar_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    h = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    out, stats = hybrid.layer_stack(h, params["layers"], cfg, with_stats=True)
+    pairs, rows, fullest, layers = np.asarray(stats)
+    assert layers == 3 and pairs == rows and 0 < fullest <= pairs
+    assert 0 < pairs < 3 * 2 * 32 * 4
+    np.testing.assert_array_equal(out, hybrid.layer_stack(h, params["layers"], cfg))
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    _, got = llama.loss_fn(params, tokens, tokens, cfg, llama.ParallelSpec(),
+                           with_stats=True)
+    assert got.shape == (4,) and got[3] == 3
+    dense = _solar_cfg(n_experts=0, n_shared_experts=0)
+    dparams = llama.init_params(dense, jax.random.key(0))
+    _, none = hybrid.layer_stack(h, dparams["layers"], dense, with_stats=True)
+    assert none is None
+    # a layer's own statistics count one layer
+    f = hybrid._layer("kda", False, cfg)
+    one = jax.tree_util.tree_map(lambda w: w[0], params["layers"]["kda"])
+    _, _, s = f(h, one, 0.0, None)
+    assert s.shape == (4,) and s[3] == 1
+
+
+def test_the_attention_gate_is_a_sigmoid_of_the_input_before_wo():
+    cfg = _solar_cfg(layer_kinds=("attention",), n_layers=1, n_experts=0,
+                     n_shared_experts=0)
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0], llama.init_params(cfg, jax.random.key(0))["layers"]["attention"])
+    h = jax.random.normal(jax.random.key(1), (1, 32, 64))
+    got, _, _ = hybrid._layer("attention", False, cfg)(h, lp, 0.0, None)
+    u = hybrid.norm(h, lp["norm1_w"], None, cfg)
+    q, k, v = jnp.split(u @ lp["wqkv"], (64, 96), axis=-1)
+    q, k, v = q.reshape(1, 32, 4, 16), k.reshape(1, 32, 2, 16), v.reshape(1, 32, 2, 16)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, 2)) / 4.0
+    s = jnp.where(jnp.tri(32, dtype=bool), s, -jnp.inf)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), jnp.repeat(v, 2, 2))
+    a = h + (o.reshape(1, 32, 64) * jax.nn.sigmoid(u @ lp["wgate"])) @ lp["wo"]
+    want = a + hybrid._mlp(hybrid.norm(a, lp["norm2_w"], None, cfg), lp)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    ungated, _, _ = hybrid._layer("attention", False, _solar_cfg(
+        **{**vars(cfg), "attn_gate": False}))(h, lp, 0.0, None)
+    assert float(jnp.abs(ungated - got).max()) > 1e-3

@@ -187,3 +187,111 @@ def test_config_head_dim_and_dispatch_fields():
         - 2 * 2 * 32                                       # q/k norms apart
     with pytest.raises(ValueError):
         dataclasses.replace(cfg, moe_dispatch="sometimes")
+
+
+# ------------------------- the router's scores, its bias, a shared expert
+
+SIGMOID = dataclasses.replace(CFG, router_score="sigmoid")
+
+
+def test_route_scores_by_softmax_or_sigmoid_and_weighs_over_the_chosen():
+    tokens = jax.random.normal(jax.random.key(0), (40, 32))
+    router = jax.random.normal(jax.random.key(1), (32, 8))
+    logits = np.asarray(tokens @ router, np.float64)
+    for score, s in (("softmax", np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)),
+                     ("sigmoid", 1 / (1 + np.exp(-logits)))):
+        top_i, top_w = moe.route(tokens, router, 3, score)
+        want_i = np.argsort(-s, axis=-1)[:, :3]
+        np.testing.assert_array_equal(np.sort(top_i, -1), np.sort(want_i, -1))
+        chosen = np.take_along_axis(s, np.asarray(top_i), -1)
+        np.testing.assert_allclose(top_w, chosen / chosen.sum(-1, keepdims=True),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, rtol=1e-6)
+    # the default is what the softmax-top-k trunk always computed
+    for a, b in zip(moe.route(tokens, router, 3), moe.route(tokens, router, 3, "softmax")):
+        np.testing.assert_array_equal(a, b)
+    with pytest.raises(ValueError, match="score must be one of"):
+        moe.route(tokens, router, 3, "tanh")
+    with pytest.raises(ValueError, match="router_score"):
+        dataclasses.replace(CFG, router_score="tanh")
+
+
+@pytest.mark.parametrize("score", moe.SCORES)
+def test_a_selection_bias_moves_the_choice_and_not_the_weights(score):
+    """Chosen by ``s + b``, weighed by ``s``: a large bias on one expert
+    puts it among every token's choices at its own small score."""
+    tokens = jax.random.normal(jax.random.key(0), (64, 32))
+    router = jax.random.normal(jax.random.key(1), (32, 8))
+    plain_i, _ = moe.route(tokens, router, 2, score)
+    bias = jnp.zeros((8,)).at[5].set(10.0).at[0].set(-10.0)
+    top_i, top_w = moe.route(tokens, router, 2, score, bias)
+    assert (np.asarray(top_i) == 5).any(-1).all() and not (np.asarray(top_i) == 0).any()
+    assert not (np.asarray(plain_i) == 5).any(-1).all()
+    logits = np.asarray(tokens @ router, np.float64)
+    s = (np.exp(logits) / np.exp(logits).sum(-1, keepdims=True) if score == "softmax"
+         else 1 / (1 + np.exp(-logits)))
+    want_i = np.argsort(-(s + np.asarray(bias)), axis=-1)[:, :2]
+    np.testing.assert_array_equal(np.sort(top_i, -1), np.sort(want_i, -1))
+    chosen = np.take_along_axis(s, np.asarray(top_i), -1)     # without the bias
+    np.testing.assert_allclose(top_w, chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
+    # a zero bias is no bias; and the bias takes no gradient
+    for a, b in zip(moe.route(tokens, router, 2, score, jnp.zeros((8,))),
+                    moe.route(tokens, router, 2, score)):
+        np.testing.assert_allclose(a, b, rtol=1e-6)
+    g = jax.grad(lambda b: moe.route(tokens, router, 2, score, b)[1].sum())(bias)
+    assert not np.asarray(g).any()
+
+
+def _dense_sigmoid(x, lp, cfg):
+    tokens = x.reshape(-1, x.shape[-1])
+    s = jax.nn.sigmoid(tokens @ lp["router"])
+    _, top_i = jax.lax.top_k(s + lp["router_bias"], cfg.expert_top_k)
+    top_s = jnp.take_along_axis(s, top_i, -1)
+    top_w = top_s / top_s.sum(-1, keepdims=True)
+    y = jnp.zeros_like(tokens)
+    for e in range(lp["we_gate"].shape[0]):
+        w_e = jnp.where(top_i == cfg.experts_first + e, top_w, 0.0).sum(-1)
+        h = jax.nn.silu(tokens @ lp["we_gate"][e]) * (tokens @ lp["we_up"][e])
+        y = y + w_e[:, None] * (h @ lp["we_down"][e])
+    return y.reshape(x.shape)
+
+
+def test_dropless_layer_with_a_sigmoid_router_and_a_bias_and_its_gradients():
+    lp = {**_params(SIGMOID),
+          "router_bias": 0.5 * jax.random.normal(jax.random.key(3), (8,))}
+    x = jax.random.normal(jax.random.key(1), (2, 48, CFG.d_model))
+    y, stats = moe.dropless_moe_layer(x, lp, SIGMOID, PAR)
+    np.testing.assert_allclose(y, _dense_sigmoid(x, lp, SIGMOID), atol=1e-5, rtol=1e-5)
+    assert float(jnp.abs(y - moe.dropless_moe_layer(
+        x, {k: v for k, v in lp.items() if k != "router_bias"}, SIGMOID, PAR)[0]).max()) > 1e-3
+    w = jax.random.normal(jax.random.key(2), x.shape)
+    got = jax.grad(lambda x_, lp_: (moe.dropless_moe_layer(x_, lp_, SIGMOID, PAR)[0] * w).sum(),
+                   (0, 1))(x, lp)
+    want = jax.grad(lambda x_, lp_: (_dense_sigmoid(x_, lp_, SIGMOID) * w).sum(), (0, 1))(x, lp)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
+    assert not np.asarray(got[1]["router_bias"]).any()
+
+
+def test_shared_expert_is_a_fused_gate_up_swiglu():
+    x = jax.random.normal(jax.random.key(0), (2, 24, 32)).astype(jnp.bfloat16)
+    w1 = jax.random.normal(jax.random.key(1), (32, 2 * 16)) * 0.2
+    w2 = jax.random.normal(jax.random.key(2), (16, 32)) * 0.2
+    y = moe.shared_expert(x, w1, w2)
+    assert y.dtype == x.dtype and y.shape == x.shape
+    x32 = x.astype(jnp.float32)
+    want = (jax.nn.silu(x32 @ w1[:, :16]) * (x32 @ w1[:, 16:])) @ w2
+    np.testing.assert_allclose(y.astype(jnp.float32), want, atol=0.05, rtol=0.05)
+    assert moe.SCOPE_SHARED == "hvd_moe_shared"
+
+
+def test_refusal_over_an_expert_axis_speaks_of_the_shared_expert_too():
+    with pytest.raises(NotImplementedError, match="shared expert beside them whole"):
+        moe.dropless_moe_layer(jnp.zeros((1, 8, 32)), _params(CFG), CFG,
+                               llama.ParallelSpec(ep_axis="ep"))
+
+
+def test_shared_experts_and_the_gate_belong_to_the_trunk_of_several_kinds():
+    for field in ({"n_shared_experts": 1}, {"attn_gate": True}):
+        with pytest.raises(ValueError, match="trunk of several kinds"):
+            dataclasses.replace(CFG, **field)
