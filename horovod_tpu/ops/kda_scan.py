@@ -32,14 +32,38 @@ the diagonal through a reference row, the first of the block's rows,
 on the column side, a product on the MXU; a block on the diagonal
 entry by entry, ``exp(G_i - G_j)`` for ``j <= i`` alone, in float32.  The
 state's products see ``exp(G)``, ``exp(G_last - G)`` and ``exp(G_last)``
-only.  Running sums, every exponential, the triangular inverse (forward
-substitution, row by row) and the carried state are float32; the
-products take the operands' dtype and accumulate in float32.
+only.  Running sums, every exponential, the triangular inverse and the
+carried state are float32; the products take the operands' dtype and
+accumulate in float32.
 
-Two passes.  The tiles ``T`` and ``Aqk`` of every chunk at once are
-elementwise work and small batched products and stay in XLA
-(:func:`_tiles`; their backward is autodiff's, the inverse's its own
-rule).  Only the ``T / C`` chunk states are walked one after another:
+Two passes, two kernels each and a backward of their own; only the running
+sums ``G`` and their transpose stay in XLA.
+
+The tiles ``T`` and ``Aqk`` of every chunk have no carry, so every grid
+step is its own:
+
+* ``hvd_kda_tiles_fwd`` takes a chunk's ``q, k, G`` and ``beta`` for a
+  block of heads and makes both tiles in VMEM.  A block row below the
+  diagonal is one product, q's and k's row sides stacked against the
+  columns before it; a pass over the diagonal blocks makes one
+  sub-diagonal of all of them at once, row ``i`` against row ``i - d``
+  brought beside it by a turn of the sublanes, ``exp(G_i - G_j)`` for ``j
+  <= i`` alone (16 passes over ``[C, K]``, not a ``[16, 16, K]`` array a
+  block).  The inverse (:func:`_blocked_inverse`): the diagonal blocks by
+  forward substitution side by side along the lanes, 15 steps deep, then
+  merged pair by pair by products at float32 precision.  (The power series
+  ``sum (-L)^n`` is the same matrix and cancels catastrophically at
+  ``beta`` near 2.)
+* ``hvd_kda_tiles_bwd`` takes the two tiles' cotangents and the same
+  operands, makes ``A``, its inverse and every decay again and writes
+  ``dq, dk``, the running sum's cotangent (all float32, to be added to the
+  chunk kernel's) and ``dbeta``: ``dN = dT Diag(beta)``, ``dL = -tril(N^T
+  tril(dN) N^T, -1)``, ``dA = Diag(beta) dL``, then the tiles' transposes
+  block by block as the forward made them.  ``G_i`` raises row ``i``'s
+  entries and lowers column ``i``'s, so its cotangent needs no pass of its
+  own: ``q_i dq_i + k_i (dk_i^row - dk_i^col)``.
+
+The ``T / C`` chunk states are walked one after another:
 
 * ``hvd_kda_chunk_fwd`` takes a chunk's ``q, k, v, G, T, Aqk`` for a
   block of heads, keeps every head's state in VMEM scratch while the
@@ -50,19 +74,25 @@ rule).  Only the ``T / C`` chunk states are walked one after another:
   cotangent as its carry, makes a chunk's ``U`` again and writes ``dq,
   dk, dv``, the running sum's cotangent and the two tiles' cotangents.
 
+The backward makes the tiles again with the forward kernel (what is kept
+is the operands and the chunks' first states, nothing else).
+
 The grid is ``(batch, blocks of heads, chunks)``; the arrays are read as
 ``[Bt, T, H K]`` with a head's channels a block of lanes, so nothing is
-transposed in HBM.  ``hvd_kda_scan_total{kernel, path}`` counts the calls
-built, once per traced call site: ``kernel`` is ``fwd`` or ``bwd``,
-``path`` is ``pallas`` or ``xla``.
+transposed in HBM.  ``hvd_kda_scan_total{kernel, path}`` counts the scans
+built and ``hvd_kda_tiles_total{kernel, path}`` their first pass, once per
+traced call site: ``kernel`` is ``fwd`` or ``bwd``, ``path`` is ``pallas``
+or ``xla``.
 
-Falls back cleanly: on another backend than a TPU and at shapes
-:func:`supported` refuses, the same chunk functions (:func:`_chunk_fwd`,
-:func:`_chunk_bwd`: the kernels call them on what they load) under a
-``lax.scan`` over the chunks, every head at once, with the same
-residuals; the choice is from shapes and backend, no knob.  A length that
-is no multiple of the chunk is padded with positions that leave the state
-as it is (``k = v = g = beta = 0``).
+Falls back cleanly, both passes together: on another backend than a TPU
+and at shapes :func:`supported` refuses, the tiles of every chunk at once
+in ``jax.numpy`` (:func:`_tiles`, unchanged since PR 42: its backward is
+autodiff's, the row-by-row inverse's its own rule) and the same chunk
+functions (:func:`_chunk_fwd`, :func:`_chunk_bwd`: the kernels call them
+on what they load) under a ``lax.scan`` over the chunks, every head at
+once, with the same residuals; the choice is from shapes and backend, no
+knob.  A length that is no multiple of the chunk is padded with positions
+that leave the state as it is (``k = v = g = beta = 0``).
 """
 
 from __future__ import annotations
@@ -85,6 +115,7 @@ _SUB = 16           # positions a sub-chunk: a diagonal block's side
 # heads a grid step takes: their chains of small products are independent
 # and fill one another's latencies
 _HEADS = (8, 4, 2, 1)
+_TOGETHER = 2      # heads a tile kernel's loop over a block's heads unrolls
 # a backward grid step at 8 heads of 128 x 128 and a chunk of 64: fourteen
 # blocks double-buffered (5 MB), every head's state (0.5 MB) and the
 # unrolled heads' temporaries
@@ -99,9 +130,25 @@ _m_kernels = _metrics.counter(
     labels=("kernel", "path"))
 
 
+_m_tiles = _metrics.counter(
+    "hvd_kda_tiles_total",
+    "The chunked gated-delta-rule scan's first pass, every chunk's tiles T "
+    "and Aqk with the triangular inverse, built, one per traced call site; "
+    "kernel is fwd or bwd (a differentiated scan builds fwd twice: its "
+    "backward makes the tiles again), path is pallas (ops/kda_scan.py's "
+    "hvd_kda_tiles_fwd / hvd_kda_tiles_bwd) or xla (the same tiles in "
+    "jax.numpy, their backward autodiff's)",
+    labels=("kernel", "path"))
+
+
 def _count(kernel: str, path: str) -> None:
     if _metrics.ACTIVE:
         _m_kernels.inc(kernel=kernel, path=path)
+
+
+def _count_tiles(kernel: str, path: str) -> None:
+    if _metrics.ACTIVE:
+        _m_tiles.inc(kernel=kernel, path=path)
 
 
 def _acc(dtype):
@@ -238,8 +285,9 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b^T
 _TN = (((0,), (0,)), ((), ()))      # a^T @ b
 
 
-def _dot(a, b, dims=(((1,), (0,)), ((), ())), acc=jnp.float32):
-    return lax.dot_general(a, b, dims, preferred_element_type=acc)
+def _dot(a, b, dims=(((1,), (0,)), ((), ())), acc=jnp.float32, precision=None):
+    return lax.dot_general(a, b, dims, precision=precision,
+                           preferred_element_type=acc)
 
 
 def _decayed(q, k, G):
@@ -294,6 +342,185 @@ def _chunk_bwd(q, k, v, G, Tm, Aqk, St, do, dSt, scale):
     at_last = lax.broadcasted_iota(jnp.int32, G.shape, 0) == G.shape[0] - 1
     dG = dG + jnp.where(at_last, dlast, 0.0)
     return dqh * e, dkh * e + dkt * w, dR, dG, dT, dAqk, dS0
+
+
+# ------------------------------------------ the tiles of one chunk of one head
+# Plain functions of arrays that the tile kernels call on what they load: the
+# scores and their transposes a head (q, k, G [C, K]; the tiles [C, C]), the
+# inverse and its cotangent every head of a grid step at once ([n, C, C], beta
+# down the rows [n, C, 1] and along the columns [n, 1, C]); s the side of a
+# diagonal block.  The same mathematics as :func:`_tiles`, which stays the
+# plain path's and the tests' reference.
+
+_HI = lax.Precision.HIGHEST
+
+
+def _roll(a, d):
+    """Rows ``d`` later, around the end: ``out[i] = a[i - d]``."""
+    return pltpu.roll(a, d % a.shape[0], 0)
+
+
+def _at(n, m, axis):
+    return lax.broadcasted_iota(jnp.int32, (n, m), axis)
+
+
+def _block_row(q32, k32, G, s, I):
+    """Block row ``I``'s factors through its first row: the row factor
+    ``exp(G_i - G_ref) [s, K]``, q's and k's row sides stacked ``[2 s, K]``
+    and the column side ``[C, K]`` in the operands' dtype, the column
+    factor ``exp(min(G_ref - G_j, 0)) [C, K]``; only the columns before the
+    block row are live."""
+    lo = s * I
+    ref = G[lo:lo + 1]
+    rows = jnp.exp(G[lo:lo + s] - ref)
+    cols = jnp.exp(jnp.minimum(ref - G, 0.0))
+    side = jnp.concatenate([q32[lo:lo + s] * rows, k32[lo:lo + s] * rows], 0)
+    return rows, cols, side, k32 * cols
+
+
+def _diagonal(k32, G, s, d, local):
+    """Pass ``d`` over the diagonal blocks: row ``i`` meets row ``i - d`` of
+    its own block.  -> (``E [C, K]``: ``exp(G_i - G_{i-d})``, 0 where ``i -
+    d`` lies in another block; ``k_{i-d} E``)."""
+    if d == 0:
+        return None, k32
+    E = jnp.exp(jnp.where(local >= d, G - _roll(G, d), -jnp.inf))
+    return E, _roll(k32, d) * E
+
+
+def _scores(q, k, G, s, with_q=True):
+    """-> (``A``, ``Aqk`` or None) ``[C, C]`` in ``G``'s dtype."""
+    acc, dt = G.dtype, q.dtype
+    C, K = G.shape
+    q32, k32 = q.astype(acc), k.astype(acc)
+    row, col = _at(C, C, 0), _at(C, C, 1)
+    # blocks below the diagonal: one product a block row
+    below = [jnp.zeros((2 * s, C), acc)]
+    for I in range(1, C // s):
+        _, _, side, kr = _block_row(q32, k32, G, s, I)
+        both = _dot(side.astype(dt), kr.astype(dt), _NT, acc)
+        below.append(jnp.where(_at(2 * s, C, 1) < s * I, both, 0.0))
+    A = jnp.concatenate([b[s:] for b in below], 0)
+    Aqk = jnp.concatenate([b[:s] for b in below], 0) if with_q else None
+    # blocks on the diagonal: entry by entry, a sub-diagonal of all a pass
+    local = _at(C, K, 0) % s
+    for d in range(s):
+        _, M = _diagonal(k32, G, s, d, local)
+        on = col == row - d
+        if with_q:
+            Aqk = Aqk + jnp.where(on, (q32 * M).sum(1, keepdims=True), 0.0)
+        if d:
+            A = A + jnp.where(on, (k32 * M).sum(1, keepdims=True), 0.0)
+    return A, Aqk
+
+
+def _bdot(a, b):
+    """``a @ b`` at float32 precision, a head at a time: ``[n, i, j] x [n,
+    j, k]``."""
+    return lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))), precision=_HI,
+                           preferred_element_type=a.dtype)
+
+
+def _blocked_inverse(L, s):
+    """``(I + L)^-1`` for ``L [n, C, C]`` (or ``[C, C]``) strictly lower
+    triangular, every matrix at once.  The diagonal blocks of side ``s`` by
+    forward substitution, all of them side by side along the lanes of one
+    ``[s, C]`` array a matrix (``s - 1`` steps deep, not ``C - 1``: step
+    ``m`` takes row ``m``, final by then, off every later row); then merged
+    pair by pair, ``[[P, 0], [B, Q]]^-1 = [[P^-1, 0], [-Q^-1 B P^-1,
+    Q^-1]]``, by products at float32 precision of the rows that change.
+    The same matrix as :func:`_unit_lower_inverse`'s and as well
+    conditioned: no power of ``L`` is formed."""
+    C = L.shape[-1]
+    L3 = L.reshape(-1, C, C)
+    n = L3.shape[0]
+    # a lane's block's first lane, for one matrix's rows and for all (two
+    # iotas: Mosaic aborts on a slice of one)
+    first, firsts = (_at(r, C, 1) // s * s for r in (s, n * s))
+    side = lambda a, lo: jnp.where(first == lo, a, 0.0)
+    Ls = sum(side(L3[:, lo:lo + s], lo) for lo in range(0, C, s))
+    Ls = Ls.reshape(n * s, C)
+    N = jnp.broadcast_to(
+        (_at(s, C, 1) - first == _at(s, C, 0)).astype(L.dtype), (n, s, C))
+    for m in range(s - 1):      # every block's column m along its lanes
+        col = jnp.take_along_axis(Ls, firsts + m, axis=1,
+                                  mode="promise_in_bounds")
+        N = N - col.reshape(n, s, C) * N[:, m:m + 1]
+    N = jnp.concatenate([side(N, lo) for lo in range(0, C, s)], 1)
+    row, col = _at(C, C, 0), _at(C, C, 1)
+    w = s
+    while w < C:
+        pair = (row // (2 * w) == col // (2 * w)) & (row // w != col // w)
+        later = [(lo, min(lo + w, C)) for lo in range(w, C, 2 * w)]
+        X = _bdot(_bdot(jnp.concatenate([N[:, a:b] for a, b in later], 1),
+                        jnp.where(pair, L3, 0.0)), N)
+        rows, at = [], 0
+        for a, b in later:
+            rows += [N[:, a - w:a], N[:, a:b] - X[:, at:at + b - a]]
+            at += b - a
+        if later[-1][1] < C:                 # a last block without a pair
+            rows.append(N[:, later[-1][1]:])
+        N = jnp.concatenate(rows, 1)
+        w *= 2
+    return N.reshape(L.shape)
+
+
+def _inverse_bwd(A, bcol, brow, dT, s):
+    """``T = (I + Diag(beta) A)^-1 Diag(beta)``'s cotangent back to ``A``'s
+    ``[n, C, C]`` and to beta's, in two parts: down the rows ``[n, C, 1]``
+    and along the columns ``[n, 1, C]``; the inverse made again."""
+    C = A.shape[-1]
+    row, col = _at(C, C, 0), _at(C, C, 1)
+    N = _blocked_inverse(bcol * A, s)
+    Nt = jnp.swapaxes(N, 1, 2)
+    dN = jnp.where(col <= row, dT * brow, 0.0)
+    dL = jnp.where(col < row, -_bdot(_bdot(Nt, dN), Nt), 0.0)
+    return (bcol * dL, (dL * A).sum(2, keepdims=True),
+            (dT * N).sum(1, keepdims=True))
+
+
+def _scores_bwd(q, k, G, dA, dAqk, s):
+    """The two tiles' cotangents back to (dq, dk, dG) ``[C, K]`` in ``G``'s
+    dtype, block by block as :func:`_scores` made them, every decay made
+    again; ``dA`` strictly lower triangular."""
+    acc, dt = G.dtype, q.dtype
+    C, K = G.shape
+    q32, k32 = q.astype(acc), k.astype(acc)
+    row, col = _at(C, C, 0), _at(C, C, 1)
+    dQK = jnp.where(col <= row, dAqk, 0.0)
+    # blocks below the diagonal
+    dq_rows, dk_rows = [jnp.zeros((s, K), acc)], [jnp.zeros((s, K), acc)]
+    dk_cols = jnp.zeros((C, K), acc)
+    for I in range(1, C // s):
+        lo = s * I
+        rows, cols, side, kr = _block_row(q32, k32, G, s, I)
+        cot = jnp.where(
+            _at(2 * s, C, 1) < lo,
+            jnp.concatenate([dQK[lo:lo + s], dA[lo:lo + s]], 0), 0.0).astype(dt)
+        dside = _dot(cot, kr.astype(dt), acc=acc)
+        dq_rows.append(dside[:s] * rows)
+        dk_rows.append(dside[s:] * rows)
+        dk_cols = dk_cols + _dot(cot, side.astype(dt), _TN, acc) * cols
+    dq, dk_row = jnp.concatenate(dq_rows, 0), jnp.concatenate(dk_rows, 0)
+    # blocks on the diagonal: a pass reads its sub-diagonal of both
+    # cotangents off the MXU, a row's entry along all its lanes
+    local = _at(C, K, 0) % s
+    ones = jnp.ones((C, K), dt)
+    spread = functools.partial(
+        _dot, acc=acc, precision=_HI if dt == jnp.float32 else None)
+    for d in range(s):
+        E, M = _diagonal(k32, G, s, d, local)
+        on = col == row - d
+        a = spread(jnp.where(on, dQK, 0.0).astype(dt), ones)
+        dq = dq + a * M
+        if d == 0:
+            dk_cols = dk_cols + a * q32
+            continue
+        b = spread(jnp.where(on, dA, 0.0).astype(dt), ones)
+        dk_row = dk_row + b * M
+        dk_cols = dk_cols + _roll((a * q32 + b * k32) * E, -d)
+    # G_i raises row i's entries and lowers column i's
+    return dq, dk_row + dk_cols, q32 * dq + k32 * (dk_row - dk_cols)
 
 
 # ------------------------------------------------------------ plain path
@@ -413,9 +640,11 @@ def _specs(K, V, chunk, nk, hb, reverse):
     return keys, vals, tile, bound
 
 
-def _params():
+def _params(carried=True):
+    """``carried``: the chunks hand a state on and run in order."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel",
+                             "arbitrary" if carried else "parallel"),
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
@@ -474,6 +703,147 @@ def _states_bwd_pallas(q, k, v, G, Tm, Aqk, states, do, chunk, scale):
             dG.reshape(q.shape), dT, dA)
 
 
+def _over_heads(hb, K, head):
+    """``head(h, lanes)`` over a grid step's heads, ``lanes`` the head's
+    channels of a ``[1, C, hb K]`` block: ``_TOGETHER`` heads an iteration
+    fill one another's latencies, and the program stays a loop's size (with
+    all eight unrolled a kernel lowers more than twice as long and runs no
+    faster: my chip runs, PR 43)."""
+    u = math.gcd(hb, _TOGETHER)
+
+    def some(i, carry):
+        for j in range(u):
+            h = i * u + j
+            head(h, pl.ds(pl.multiple_of(h * K, K), K))
+        return carry
+
+    lax.fori_loop(0, hb // u, some, None)
+
+
+def _betas(bcol_ref, brow_ref, hb):
+    """A grid step's beta a head: down the rows ``[hb, C, 1]``, along the
+    columns ``[hb, 1, C]``."""
+    return (jnp.stack([bcol_ref[0, 0, 0, :, h:h + 1] for h in range(hb)]),
+            jnp.stack([brow_ref[0, 0, 0, h:h + 1, :] for h in range(hb)]))
+
+
+def _tiles_fwd_kernel(q_ref, k_ref, g_ref, bcol_ref, brow_ref, t_ref, a_ref,
+                      *, hb, K, s):
+    """One chunk of one block of heads: no carry, every grid step its own.
+    The scores head by head, ``A`` kept where ``T`` will lie; then every
+    head's inverse at once: each a chain of small steps and products, and
+    eight of them fill one another's latencies."""
+    def scores(h, ks):
+        t_ref[0, h, 0], a_ref[0, h, 0] = _scores(
+            q_ref[0, :, ks], k_ref[0, :, ks], g_ref[0, :, ks], s)
+
+    _over_heads(hb, K, scores)
+    bcol, brow = _betas(bcol_ref, brow_ref, hb)
+    t_ref[0, :, 0] = _blocked_inverse(bcol * t_ref[0, :, 0], s) * brow
+
+
+def _tiles_bwd_kernel(q_ref, k_ref, g_ref, bcol_ref, brow_ref, dt_ref, da_ref,
+                      dq_ref, dk_ref, dg_ref, dbcol_ref, dbrow_ref, a_scr,
+                      *, hb, K, s):
+    """The forward's stretches and back: ``A`` head by head into scratch;
+    every head's inverse and its cotangent at once, ``dA`` left where ``A``
+    lay; the two tiles' transposes head by head."""
+    def scores(h, ks):
+        a_scr[h], _ = _scores(q_ref[0, :, ks], k_ref[0, :, ks],
+                              g_ref[0, :, ks], s, with_q=False)
+
+    def transposes(h, ks):
+        dq_ref[0, :, ks], dk_ref[0, :, ks], dg_ref[0, :, ks] = _scores_bwd(
+            q_ref[0, :, ks], k_ref[0, :, ks], g_ref[0, :, ks], a_scr[h],
+            da_ref[0, h, 0], s)
+
+    _over_heads(hb, K, scores)
+    a_scr[...], db_rows, db_cols = _inverse_bwd(
+        a_scr[...], *_betas(bcol_ref, brow_ref, hb), dt_ref[0, :, 0], s)
+    for h in range(hb):
+        dbcol_ref[0, 0, 0, :, h:h + 1] = db_rows[h]
+        dbrow_ref[0, 0, 0, h:h + 1, :] = db_cols[h]
+    _over_heads(hb, K, transposes)
+
+
+def _beta_blocks(beta, chunk, hb):
+    """``beta [Bt, T, H]`` in float32 as the tile kernels read it: a chunk's
+    down the rows ``[Bt, H / hb, T / C, C, hb]`` and along the columns
+    ``[Bt, H / hb, T / C, hb, C]``, with the two blocks' specs."""
+    Bt, T, H = beta.shape
+    b = beta.astype(jnp.float32).reshape(Bt, T // chunk, chunk, H // hb, hb)
+    at = lambda b_, j, c: (b_, j, c, 0, 0)
+    return (jnp.transpose(b, (0, 3, 1, 2, 4)), jnp.transpose(b, (0, 3, 1, 4, 2)),
+            pl.BlockSpec((1, 1, 1, chunk, hb), at),
+            pl.BlockSpec((1, 1, 1, hb, chunk), at))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _tiles_fwd_call(q, k, G, beta, *, chunk, interpret):
+    Bt, T, H, K = q.shape
+    nk, hb = T // chunk, _head_block(H)
+    keys, _, tile, _ = _specs(K, K, chunk, nk, hb, False)
+    bcol, brow, cols, rows = _beta_blocks(beta, chunk, hb)
+    operands = (_flat(q), _flat(k), _flat(G), bcol, brow)
+    shape = _sds((Bt, H, nk, chunk, chunk), jnp.float32, *operands)
+    return pl.pallas_call(
+        functools.partial(_tiles_fwd_kernel, hb=hb, K=K,
+                          s=math.gcd(chunk, _SUB)),
+        grid=(Bt, H // hb, nk),
+        in_specs=[keys, keys, keys, cols, rows],
+        out_specs=[tile, tile],
+        out_shape=[shape, shape],
+        compiler_params=_params(carried=False),
+        interpret=interpret,
+        name="hvd_kda_tiles_fwd",
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _tiles_bwd_call(q, k, G, beta, dT, dAqk, *, chunk, interpret):
+    f32 = jnp.float32
+    Bt, T, H, K = q.shape
+    nk, hb = T // chunk, _head_block(H)
+    keys, _, tile, _ = _specs(K, K, chunk, nk, hb, False)
+    bcol, brow, cols, rows = _beta_blocks(beta, chunk, hb)
+    operands = (_flat(q), _flat(k), _flat(G), bcol, brow, dT, dAqk)
+    wide = _sds((Bt, T, H * K), f32, *operands)
+    dq, dk, dG, db_rows, db_cols = pl.pallas_call(
+        functools.partial(_tiles_bwd_kernel, hb=hb, K=K,
+                          s=math.gcd(chunk, _SUB)),
+        grid=(Bt, H // hb, nk),
+        in_specs=[keys, keys, keys, cols, rows, tile, tile],
+        out_specs=[keys, keys, keys, cols, rows],
+        out_shape=[wide, wide, wide, _sds(bcol.shape, f32, *operands),
+                   _sds(brow.shape, f32, *operands)],
+        scratch_shapes=[pltpu.VMEM((hb, chunk, chunk), f32)],
+        compiler_params=_params(carried=False),
+        interpret=interpret,
+        name="hvd_kda_tiles_bwd",
+    )(*operands)
+    dbeta = (jnp.transpose(db_rows, (0, 2, 3, 1, 4))
+             + jnp.transpose(db_cols, (0, 2, 4, 1, 3))).reshape(Bt, T, H)
+    return dq.reshape(q.shape), dk.reshape(q.shape), dG.reshape(q.shape), dbeta
+
+
+# The two calls are nested ``jit``s: a program traces and lowers each kernel
+# once, however many layers and passes call it (at every call site anew the
+# Solar cell's ``lower_s`` read 58.8 s for the parent's 19.7, my chip run,
+# PR 43).
+
+def _tiles_fwd_pallas(q, k, G, beta, chunk):
+    """:func:`_tiles` as a kernel: the same tiles, made in VMEM."""
+    _count_tiles("fwd", "pallas")
+    return _tiles_fwd_call(q, k, G, beta, chunk=chunk, interpret=_INTERPRET)
+
+
+def _tiles_bwd_pallas(q, k, G, beta, dT, dAqk, chunk):
+    """-> (dq, dk, dG ``[Bt, T, H, K]``, dbeta ``[Bt, T, H]``), float32."""
+    _count_tiles("bwd", "pallas")
+    return _tiles_bwd_call(q, k, G, beta, dT, dAqk, chunk=chunk,
+                           interpret=_INTERPRET)
+
+
 # ------------------------------------------------------------- public op
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -484,11 +854,13 @@ def _scan(q, k, v, g, beta, chunk):
 def _scan_fwd(q, k, v, g, beta, chunk):
     scale = q.shape[3] ** -0.5
     G = _running_sums(g.astype(_acc(q.dtype)), chunk)
-    Tm, Aqk = _tiles(q, k, G, beta, chunk)
     if supported(q, k, v, g, beta, chunk):
+        Tm, Aqk = _tiles_fwd_pallas(q, k, G, beta, chunk)
         o, states = _states_fwd_pallas(q, k, v, G, Tm, Aqk, chunk, scale)
     else:
         _count("fwd", "xla")
+        _count_tiles("fwd", "xla")
+        Tm, Aqk = _tiles(q, k, G, beta, chunk)
         o, states = _states_fwd_xla(q, k, v, G, Tm, Aqk, chunk, scale)
     return o.astype(v.dtype), (q, k, v, g, beta, states)
 
@@ -498,16 +870,20 @@ def _scan_bwd(chunk, res, do):
     scale = q.shape[3] ** -0.5
     acc = _acc(q.dtype)
     G = _running_sums(g.astype(acc), chunk)
-    (Tm, Aqk), tiles_vjp = jax.vjp(
-        lambda q_, k_, G_, b_: _tiles(q_, k_, G_, b_, chunk), q, k, G, beta)
     if supported(q, k, v, g, beta, chunk):        # as the forward found
-        back = _states_bwd_pallas
+        Tm, Aqk = _tiles_fwd_pallas(q, k, G, beta, chunk)
+        dq, dk, dv, dG, dT, dAqk = _states_bwd_pallas(
+            q, k, v, G, Tm, Aqk, states, do, chunk, scale)
+        dq2, dk2, dG2, dbeta = _tiles_bwd_pallas(q, k, G, beta, dT, dAqk, chunk)
     else:
         _count("bwd", "xla")
-        back = _states_bwd_xla
-    dq, dk, dv, dG, dT, dAqk = back(q, k, v, G, Tm, Aqk, states, do, chunk,
-                                    scale)
-    dq2, dk2, dG2, dbeta = tiles_vjp((dT.astype(acc), dAqk.astype(acc)))
+        _count_tiles("fwd", "xla")
+        _count_tiles("bwd", "xla")
+        (Tm, Aqk), tiles_vjp = jax.vjp(
+            lambda q_, k_, G_, b_: _tiles(q_, k_, G_, b_, chunk), q, k, G, beta)
+        dq, dk, dv, dG, dT, dAqk = _states_bwd_xla(
+            q, k, v, G, Tm, Aqk, states, do, chunk, scale)
+        dq2, dk2, dG2, dbeta = tiles_vjp((dT.astype(acc), dAqk.astype(acc)))
     return ((dq + dq2.astype(acc)).astype(q.dtype),
             (dk + dk2.astype(acc)).astype(k.dtype), dv.astype(v.dtype),
             _sum_back(dG + dG2, chunk).astype(g.dtype),
