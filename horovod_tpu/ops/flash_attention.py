@@ -49,7 +49,31 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   :func:`full_ranges`, made where the call is built.  :func:`tile_classes`
   sorts the (query tile, key tile) pairs into dead (no live pair:
   skipped, no load and no product), full (every pair live: no mask
-  applied) and mixed (masked inside the tile).  ``hvd_flash_fwd`` and
+  applied) and mixed (cut by the mask).  **A mixed tile is walked by its
+  live sub-tiles** of ``_SUB`` = 256 positions a side, not visited whole:
+  where the classes are made (:func:`_mask_plan`) the same
+  :func:`tile_classes` classes every mixed tile's sub-tiles
+  (:func:`_sub_words`; numpy where the mask is numpy, traced where it is
+  traced) and the kernels get a word of bits a tile in SMEM beside the
+  tables they had.  ``hvd_flash_dkv`` visits a mixed pair's sub-tiles
+  that are not dead one by one, ``dk`` and ``dv`` accumulating by the
+  key sub-tile's rows; ``hvd_flash_fwd`` and ``hvd_flash_dq`` take each
+  band of 256 query rows on the span from its first live key sub-tile to
+  its last in one visit, so that what a visit costs beside its products
+  (the rows' maxima, sums and accumulators read and written) is paid once
+  a band, as a tile taken whole pays it.  Every such visit is masked from
+  the ranges spread once a step (the mask is exact to the pair, so a full
+  sub-tile inside a span loses nothing but the mask's few percent); a
+  row that sees no key in a visit keeps its maximum, sum and accumulator
+  as a mixed tile's fully masked rows always did.  The diagonal tile of a
+  causal mask, and a tile of a window as wide as a tile, so cost three
+  quarters of a tile, the 24 mixed tiles of the block-diffusion mask of 2
+  x 4,096 positions two thirds.  A call whose mask cuts no tile (every
+  tile full or dead, the mask known where the call is built), or whose
+  tile is no larger than a sub-tile, builds no such table and the loops
+  as they were.  The width is one number for every call, chosen on the
+  chip between 256 and 128 (the runs stand beside ``_SUB``; 128 visits
+  less and costs more in every call measured).  ``hvd_flash_fwd`` and
   ``hvd_flash_dq`` walk each query tile's live key tiles from a table in
   SMEM.  A forward grid step takes that query tile of several query
   heads of one GQA group (they share the resident k and v), unrolled, so
@@ -69,8 +93,9 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   of them; ``dq`` accumulates in float32 VMEM scratch ``[hb, bq, D]`` and
   is cast out after the last tile; the heads' rows of ``lse`` and of
   ``delta`` become columns alike in every lane by one transpose each and
-  the rows' ranges are spread over the lanes, once a step, and a mixed
-  tile's mask is made once for all the step's heads.
+  the rows' ranges are spread over the lanes, once a step, and a masked
+  visit's mask is made once for all the step's heads, in the forward
+  too.
   ``hvd_flash_dkv`` has one grid step per live (key
   tile, query tile) pair and takes the query tiles of its GQA group one
   at a time, so it holds ``g x bq x D`` of ``q`` and ``do``, not
@@ -115,7 +140,10 @@ built, once per traced call site, so a program says which path and which
 layout (``rows`` or ``heads``; packed calls are ``rows``) its shapes took;
 ``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
 (``live`` = full, ``masked`` = mixed, ``skipped`` = dead) where the call
-is built, when the mask is known there (a numpy array).
+is built, when the mask is known there (a numpy array), and
+``hvd_flash_subtiles_total{kernel, state}`` the sub-tiles of its mixed
+tiles in the same three states: no series where no tile is walked by
+sub-tiles.
 
 Falls back cleanly: :func:`supported` gates on platform/shape so callers
 (e.g. ``local_attention``) can pick the XLA blockwise path on CPU meshes
@@ -128,8 +156,9 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +176,17 @@ NEG_INF = -1e30
 _INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
 _BLOCK = 512  # query and key positions a block
+# positions a side of the sub-tiles by which a tile that the mask cuts is
+# walked (module docstring, "a mixed tile").  Chosen on a v5e between 256
+# and 128, a lane tile (PERF.md, PR 44; the kernels alone, ms a call of
+# fwd + dq + dkv, a mixed tile whole / by 256 / by 128): the SDAR call
+# (block diffusion, 32 heads over 4 at head_dim 128) 17.66 / 16.46 /
+# 17.04, a Phi window call (20 heads over 10 at 64, values 128) 2.77 /
+# 2.60 / 3.41, a Phi causal call 9.90 / 9.80 / 10.23, a causal call of 8
+# heads over 1 at 128 3.64 / 3.58 / 3.65: 128 visits less (0.85 of 256's
+# area under block diffusion) and pays more for it, a visit's rows of
+# statistics and accumulators being read and written whatever its width.
+_SUB = 256
 _LANES = 128
 # what one grid step of the masked forward or of dq may hold: a quarter of
 # a v5e core's 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
@@ -171,6 +211,12 @@ _m_tiles = _metrics.counter(
     "Tiles of masked flash-attention calls by class, counted where the "
     "call is built from a mask known there",
     labels=("kernel", "state"))
+_m_subtiles = _metrics.counter(
+    "hvd_flash_subtiles_total",
+    "Sub-tiles (_SUB positions a side) of the mixed tiles of masked "
+    "flash-attention calls by class, counted where the call is built from "
+    "a mask known there; no series where no tile is walked by sub-tiles",
+    labels=("kernel", "state"))
 _TILE_STATES = ("skipped", "masked", "live")     # class 0, 1, 2
 
 
@@ -180,12 +226,18 @@ def _count(kernel: str, path: str, rows: bool = True) -> None:
                        layout="rows" if rows else "heads")
 
 
-def _count_tiles(kernel: str, classes) -> None:
-    """``classes``: the call's tile classes when known at build time."""
-    if _metrics.ACTIVE and isinstance(classes, np.ndarray):
+def _count_tiles(kernel: str, classes, sub=None) -> None:
+    """``classes``: the call's tile classes when known at build time;
+    ``sub``: its mixed tiles' sub-tile classes (:func:`_sub_words`)."""
+    if not (_metrics.ACTIVE and isinstance(classes, np.ndarray)):
+        return
+    for c, state in enumerate(_TILE_STATES):
+        _m_tiles.inc(int((classes == c).sum()), kernel=kernel, state=state)
+    if sub is not None:
+        codes = sub_codes(sub.words[classes == 1], math.prod(sub.grid))
         for c, state in enumerate(_TILE_STATES):
-            _m_tiles.inc(int((classes == c).sum()), kernel=kernel,
-                         state=state)
+            _m_subtiles.inc(int((codes == c).sum()), kernel=kernel,
+                            state=state)
 
 
 def _block_sizes(t_q: int, t_kv: int):
@@ -556,24 +608,77 @@ def tile_classes(ranges, bq: int, bk: int, Tk: int):
     return xp.where(covers, 2, xp.where(meets, 1, 0)).astype(xp.int32)
 
 
-def _row_tables(classes):
+class _Sub(NamedTuple):
+    """The mixed tiles' ``sq x sk`` sub-tiles, ``[Bm, nq, nk]`` int32 words
+    a tile, zero for a tile that is not mixed.  ``words``: the sub-tiles'
+    classes at two bits each (query sub-tile ``r`` on key sub-tile ``c`` at
+    bits ``2 * (r * sk + c)``).  ``spans``: for each band of query
+    sub-tiles ``r`` (bits ``8 * r`` on) the span from its first live key
+    sub-tile to its last: their number (3 bits; 0, no live one) and the
+    first (2 bits).  ``grid``: ``(sq, sk)``."""
+    words: object
+    spans: object
+    grid: tuple
+
+
+def _sub_words(ranges, classes, bq: int, bk: int, Tk: int):
+    """:class:`_Sub` of ``ranges [Bm, T, 4]`` whose tiles of ``bq x bk``
+    have ``classes``, by :func:`tile_classes` at ``_SUB`` positions a side
+    (numpy in, numpy out; traced, traced tables).  None where a tile is
+    no more than one sub-tile, or the mask is known here and cuts no
+    tile: such a call builds no table and walks no sub-tile."""
+    xp = np if isinstance(ranges, np.ndarray) else jnp
+    if (bq % _SUB or bk % _SUB or bq * bk == _SUB * _SUB
+            or (xp is np and not (classes == 1).any())):
+        return None
+    sq, sk = bq // _SUB, bk // _SUB
+    assert sq <= 4 and sk <= 4, (bq, bk, _SUB)      # the words' fields
+    Bm, nq, nk = classes.shape
+    fine = tile_classes(ranges, _SUB, _SUB, Tk).reshape(Bm, nq, sq, nk, sk)
+    fine = fine.transpose(0, 1, 3, 2, 4)            # [Bm, nq, nk, sq, sk]
+    live = fine >= 1
+    first = xp.argmax(live, -1)
+    last = sk - 1 - xp.argmax(live[..., ::-1], -1)
+    fields = xp.where(live.any(-1), last - first + 1, 0) + (first << 3)
+
+    def pack(values, bits):      # disjoint bits: the sum is the union
+        n = values.shape[-1]
+        shifts = (bits * xp.arange(n)).astype(xp.uint32)
+        word = (values.astype(xp.uint32) << shifts).sum(-1, dtype=xp.uint32)
+        return xp.where(classes == 1, word, xp.uint32(0)).view(xp.int32)
+
+    return _Sub(pack(fine.reshape(Bm, nq, nk, sq * sk), 2), pack(fields, 8),
+                (sq, sk))
+
+
+def sub_codes(words, n: int):
+    """``[..., n]`` classes of the ``n`` sub-tiles held in ``words``."""
+    xp = np if isinstance(words, np.ndarray) else jnp
+    return (words[..., None] >> (2 * xp.arange(n)).astype(words.dtype)) & 3
+
+
+def _row_tables(classes, sub=None):
     """For ``hvd_flash_fwd`` / ``hvd_flash_dq``: each query tile's key
     tiles ordered full, mixed, dead, with the counts of the first and
-    of the first two, flat int32 for SMEM."""
+    of the first two, flat int32 for SMEM; with ``sub`` (:class:`_Sub`)
+    its ``spans`` as a fourth, by query tile and key tile."""
     xp = np if isinstance(classes, np.ndarray) else jnp
     nk = classes.shape[-1]
     key = (2 - classes) * nk + xp.arange(nk)
     idx = xp.argsort(key, axis=-1).astype(xp.int32)
     n_full = (classes == 2).sum(-1).astype(xp.int32)
     n_live = (classes >= 1).sum(-1).astype(xp.int32)
-    return idx.reshape(-1), n_full.reshape(-1), n_live.reshape(-1)
+    tables = idx.reshape(-1), n_full.reshape(-1), n_live.reshape(-1)
+    return tables if sub is None else tables + (sub.spans.reshape(-1),)
 
 
-def _pair_table(classes):
+def _pair_table(classes, sub=None):
     """For ``hvd_flash_dkv``: ``(table, P)``, the (key tile, query tile)
     pairs to visit, key-tile major so that a key tile's steps are
     consecutive.  Flat int32 ``[Bm * P * 4]`` of (key tile, query tile,
-    class, flags: 1 first of its key tile, 2 last).  A key tile no query
+    class, flags: 1 first of its key tile, 2 last), ``[Bm * P * 5]`` with
+    ``sub`` (:class:`_Sub`): the pair's word of sub-tile classes
+    (``words``) the fifth.  A key tile no query
     tile sees keeps one dead pair, which writes its zeros.  With classes
     known at build time ``P`` is the most any batch row needs; traced,
     every pair has a slot."""
@@ -599,7 +704,10 @@ def _pair_table(classes):
     last_real = xp.arange(P)[None] == n[:, None] - 1
     flags = (xp.where(real & (j != prev_j), 1, 0)
              + xp.where(real & ((j != next_j) | last_real), 2, 0))
-    table = xp.stack([j, i, c, flags], -1).astype(xp.int32)
+    columns = [j, i, c, flags]
+    if sub is not None:
+        columns.append(take(xp.swapaxes(sub.words, 1, 2).reshape(cls.shape)))
+    table = xp.stack(columns, -1).astype(xp.int32)
     return table.reshape(-1), P
 
 
@@ -607,13 +715,71 @@ def _in_ranges(cols, lo1, hi1, lo2, hi2):
     return ((cols >= lo1) & (cols < hi1)) | ((cols >= lo2) & (cols < hi2))
 
 
-def _two_loops(n_full, n_live, step, carry):
-    """Full tiles unmasked, then mixed tiles masked: no branch inside a
-    loop body."""
-    carry = lax.fori_loop(0, n_full, functools.partial(step, masked=False),
-                          carry)
-    return lax.fori_loop(n_full, n_live,
-                         functools.partial(step, masked=True), carry)
+def _walk(word, sub, visit):
+    """A mixed pair of ``hvd_flash_dkv`` by its live sub-tiles:
+    ``visit(c, r)`` for key sub-tile ``c`` (traced: a loop) on query
+    sub-tile ``r`` (static) of the ``sub = (sq, sk)`` that the two bits of
+    ``word`` (SMEM; :class:`_Sub`'s ``words``) do not call dead; the
+    caller unrolls its heads inside ``visit``."""
+    sq, sk = sub
+
+    def keys(c, carry):
+        for r in range(sq):
+            pl.when((word >> (2 * (r * sk + c))) & 3 != 0)(
+                functools.partial(visit, c, r))
+        return carry
+
+    lax.fori_loop(0, sk, keys, 0)
+
+
+def _walk_bands(word, sub, visit):
+    """A mixed tile of ``hvd_flash_fwd`` / ``hvd_flash_dq`` by its bands of
+    query sub-tiles, each on the span of its live key sub-tiles at once
+    (what a visit costs beside its products, the rows' statistics and
+    accumulators read and written, it then pays once a band, as a tile
+    taken whole pays it): ``visit(r, first, n)`` for band ``r`` (traced: a
+    loop) on ``n`` (static) key sub-tiles from the ``first`` on (traced),
+    as ``word`` says (SMEM; :class:`_Sub`'s ``spans``); a band with no
+    live sub-tile not at all."""
+    sq, sk = sub
+
+    def band(r, carry):
+        field = (word >> (8 * r)) & 0xff
+        for n in range(1, sk + 1):
+            pl.when(field & 7 == n)(functools.partial(
+                visit, r, (field >> 3) & 3, n))
+        return carry
+
+    lax.fori_loop(0, sq, band, 0)
+
+
+def _key_tiles(tables, row, nk, bq, bk, sub, visit):
+    """A query tile's live key tiles, from ``tables`` (:func:`_row_tables`
+    in SMEM; the tile's entries are ``row``'s ``nk``): full tiles
+    unmasked, then mixed tiles masked, whole or, with ``sub = (sq, sk)``,
+    by their live sub-tiles (:func:`_walk_bands`).  ``visit(rows, col0,
+    width, masked)`` takes the tile's query ``rows`` (a slice) on
+    ``width`` keys from ``col0`` on.  No branch inside a loop body but
+    :func:`_walk_bands`'s."""
+    idx_ref, nfull_ref, nlive_ref, *sub_ref = tables
+
+    def whole(n, carry, masked):
+        j = idx_ref[row * nk + n]
+        visit(slice(None), pl.multiple_of(j * bk, bk), bk, masked)
+        return carry
+
+    def by_sub_tiles(n, carry):
+        j = idx_ref[row * nk + n]
+        wq, wk = bq // sub[0], bk // sub[1]
+        _walk_bands(sub_ref[0][row * nk + j], sub, lambda r, first, n: visit(
+            pl.ds(pl.multiple_of(r * wq, wq), wq),
+            pl.multiple_of(j * bk + first * wk, wk), n * wk, True))
+        return carry
+
+    lax.fori_loop(0, nfull_ref[row], functools.partial(whole, masked=False),
+                  0)
+    lax.fori_loop(nfull_ref[row], nlive_ref[row], by_sub_tiles if sub else
+                  functools.partial(whole, masked=True), 0)
 
 
 def _lanes(x, n):
@@ -637,13 +803,13 @@ def _spread_ranges(r_ref, rb_ref):
         rb_ref[c] = jnp.broadcast_to(r_ref[0][:, c:c + 1], rb_ref.shape[1:])
 
 
-def _rows_live(rb_ref, col0, bk):
-    """Boolean ``[bq, bk]``: the pairs of a tile whose first key is
-    ``col0`` that the rows' spread ranges let through (the tile's columns
-    are shifted, not the ranges)."""
-    bq = rb_ref.shape[1]
-    cols = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + col0
-    return _in_ranges(cols, *(_lanes(rb_ref[c], bk) for c in range(4)))
+def _rows_live(rb_ref, col0, bk, rows=slice(None)):
+    """Boolean ``[rows, bk]``: the pairs of the tile's ``rows`` with the
+    ``bk`` keys from ``col0`` on that the rows' spread ranges let through
+    (the tile's columns are shifted, not the ranges)."""
+    lo1, hi1, lo2, hi2 = (_lanes(rb_ref[c, rows], bk) for c in range(4))
+    cols = lax.broadcasted_iota(jnp.int32, lo1.shape, 1) + col0
+    return _in_ranges(cols, lo1, hi1, lo2, hi2)
 
 
 def _head(ref, h, d, at=slice(None)):
@@ -656,9 +822,9 @@ def _head(ref, h, d, at=slice(None)):
     return 0, at, slice(h * d, (h + 1) * d)
 
 
-def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
-                 o_ref, lse_ref, m_ref, l_ref, acc_ref, rb_ref, *, scale, bk,
-                 nq, nk, per_batch):
+def _mfwd_kernel(tables, q_ref, k_ref, v_ref, r_ref, o_ref, lse_ref, m_ref,
+                 l_ref, acc_ref, rb_ref, *, scale, bk, nq, nk, per_batch,
+                 sub):
     """One query tile of ``hb`` query heads that share a kv head (static,
     unrolled: one head's softmax is scheduled under another's products).
     The running statistics live in VMEM, a head at a time in registers:
@@ -667,8 +833,11 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     (one a lane, added across the tile's column groups elementwise) that
     meet once, after the last tile; ``acc_ref [hb, bq, D]``.  ``rb_ref
     [4, bq, 128]`` holds the rows' ranges spread over the lanes once a
-    step, for every head and mixed tile of it."""
-    hb, _, Dv = acc_ref.shape
+    step, for every head and mixed tile of it.  ``tables``: the query
+    tiles' key tiles (:func:`_row_tables`); with ``sub = (sq, sk)`` a mixed
+    tile is walked by its live sub-tiles, with None it is taken whole
+    (:func:`_key_tiles`)."""
+    hb, bq, Dv = acc_ref.shape
     D = k_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
@@ -677,26 +846,32 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
     _spread_ranges(r_ref, rb_ref)
 
-    def step(n, carry, masked):
-        j = idx_ref[row * nk + n]
-        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+    def visit(rows, col0, width, masked):
+        """The tile's query ``rows`` (a slice) on ``width`` keys from
+        ``col0`` on.  Rows that see none of them keep what they have: with
+        a maximum of their own ``p`` is 0, and what rows without one add
+        is wiped by ``corr`` = 0 at their first live key."""
+        at = pl.ds(col0, width)
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
+        if masked:                  # one mask a visit, added by every head
+            dead = jnp.where(_rows_live(rb_ref, col0, width, rows), 0.0,
+                             NEG_INF)
         for h in range(hb):
-            s = _scores(q_ref[_head(q_ref, h, D)], kj, scale, False)
+            s = _scores(q_ref[_head(q_ref, h, D, rows)], kj, scale, False)
             if masked:
-                s = jnp.where(_rows_live(rb_ref, j * bk, bk), s, NEG_INF)
-            m = m_ref[h]
+                s = s + dead
+            m = m_ref[h, rows]
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - _lanes(m_new, bk))
+            p = jnp.exp(s - _lanes(m_new, width))
             corr = jnp.exp(m - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_ref[h] * corr + functools.reduce(
-                jnp.add, (p[:, c:c + _LANES] for c in range(0, bk, _LANES)))
-            acc_ref[h] = acc_ref[h] * _lanes(corr, Dv) + jnp.dot(
+            m_ref[h, rows] = m_new
+            l_ref[h, rows] = l_ref[h, rows] * corr + functools.reduce(
+                jnp.add,
+                (p[:, c:c + _LANES] for c in range(0, width, _LANES)))
+            acc_ref[h, rows] = acc_ref[h, rows] * _lanes(corr, Dv) + jnp.dot(
                 p.astype(vj.dtype), vj, preferred_element_type=jnp.float32)
-        return carry
 
-    _two_loops(nfull_ref[row], nlive_ref[row], step, 0)
+    _key_tiles(tables, row, nk, bq, bk, sub, visit)
     for h in range(hb):
         l = l_ref[h].sum(axis=-1, keepdims=True)
         o_ref[_head(o_ref, h, Dv)] = (acc_ref[h] / l).astype(o_ref.dtype)
@@ -704,18 +879,19 @@ def _mfwd_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, r_ref,
         lse_ref[0, h, pl.ds(i, 1), :] = (m_ref[h] + jnp.log(l)).T[:1]
 
 
-def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, r_ref, dq_ref, acc_ref, lse_b, delta_b,
-                rb_ref, *, scale, bk, nq, nk, per_batch):
+def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                r_ref, dq_ref, acc_ref, lse_b, delta_b, rb_ref, *, scale, bk,
+                nq, nk, per_batch, sub):
     """One query tile of ``hb`` query heads that share a kv head, as the
     forward takes them (static, unrolled: a key tile is loaded once for
     all of them and one head's elementwise work is scheduled under
-    another's products).  ``acc_ref [hb, bq, D]`` holds ``dq`` in float32
+    another's products), a mixed tile by its live sub-tiles as there
+    (``tables``, ``sub``).  ``acc_ref [hb, bq, D]`` holds ``dq`` in float32
     until the last tile; ``lse_b`` and ``delta_b [hb, bq, 128]`` the heads'
     rows of ``lse`` and ``delta`` as columns alike in every lane and
     ``rb_ref [4, bq, 128]`` the rows' ranges over the lanes, each laid out
     once a step."""
-    (hb, _, D), Dv = acc_ref.shape, v_ref.shape[-1]
+    (hb, bq, D), Dv = acc_ref.shape, v_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -724,42 +900,51 @@ def _mdq_kernel(idx_ref, nfull_ref, nlive_ref, q_ref, k_ref, v_ref, do_ref,
         delta_b[h] = _column(delta_ref[0, h, pl.ds(i, 1), :])
     _spread_ranges(r_ref, rb_ref)
 
-    def step(n, carry, masked):
-        j = idx_ref[row * nk + n]
-        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+    def visit(rows, col0, width, masked):
+        """The tile's query ``rows`` (a slice) on ``width`` keys from
+        ``col0`` on."""
+        at = pl.ds(col0, width)
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
         if masked:                  # one mask a tile, added by every head
-            dead = jnp.where(_rows_live(rb_ref, j * bk, bk), 0.0, NEG_INF)
+            dead = jnp.where(_rows_live(rb_ref, col0, width, rows), 0.0,
+                             NEG_INF)
         for h in range(hb):
-            s = _scores(q_ref[_head(q_ref, h, D)], kj, scale, False)
+            s = _scores(q_ref[_head(q_ref, h, D, rows)], kj, scale, False)
             if masked:
                 s = s + dead
-            p = jnp.exp(s - _lanes(lse_b[h], bk))
-            dp = lax.dot_general(do_ref[_head(do_ref, h, Dv)], vj,
+            p = jnp.exp(s - _lanes(lse_b[h, rows], width))
+            dp = lax.dot_general(do_ref[_head(do_ref, h, Dv, rows)], vj,
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - _lanes(delta_b[h], bk)) * scale
-            acc_ref[h] += jnp.dot(ds.astype(kj.dtype), kj,
-                                  preferred_element_type=jnp.float32)
-        return carry
+            ds = p * (dp - _lanes(delta_b[h, rows], width)) * scale
+            acc_ref[h, rows] += jnp.dot(ds.astype(kj.dtype), kj,
+                                        preferred_element_type=jnp.float32)
 
-    _two_loops(nfull_ref[row], nlive_ref[row], step, 0)
+    _key_tiles(tables, row, nk, bq, bk, sub, visit)
     for h in range(hb):
         dq_ref[_head(dq_ref, h, D)] = acc_ref[h].astype(dq_ref.dtype)
 
 
 def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, P, g,
-                 per_batch):
+                 r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *st_ref, scale, P, g,
+                 per_batch, sub):
     """One live (key tile, query tile) pair of a kv head's ``g`` query
     heads (static, unrolled).  The tile is formed keys by queries, ``S^T =
     K Q^T [bk, bq]``: a query's ``lse``, ``delta`` and ranges (``r_ref [1,
     4, bq]``, a row a bound) then lie along the lanes as they are stored
     and go down the sublanes for nothing, and ``dv = P^T do`` and ``dk =
-    dS^T q`` are plain ``[bk, bq] x [bq, D]`` products."""
+    dS^T q`` are plain ``[bk, bq] x [bq, D]`` products.  With ``sub = (sq,
+    sk)`` a mixed pair is walked by its live sub-tiles (the table's fifth
+    column their classes), ``dk`` and ``dv`` accumulating by the key
+    sub-tile's rows; with None it is taken whole.  The sub-tiles read the
+    query tile's rows of ``lse`` and ``delta`` from ``st_ref [2, g, 1,
+    bq]``, laid there once a mixed pair: Mosaic loads a row at a dynamic
+    index whole, not one lane tile of it."""
     (bk, D), Dv = k_ref.shape[-2:], v_ref.shape[-1]
     bq = r_ref.shape[-1]
-    at = ((pl.program_id(0) * P if per_batch else 0) + pl.program_id(2)) * 4
+    width = 5 if sub else 4
+    at = ((pl.program_id(0) * P if per_batch else 0)
+          + pl.program_id(2)) * width
     j, i, cls, flags = (tbl_ref[at + c] for c in range(4))
     keys_by_queries = (((1,), (1,)), ((), ()))
 
@@ -768,31 +953,57 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def pair(masked):
-        kb, vb = k_ref[_head(k_ref, 0, D)], v_ref[_head(v_ref, 0, Dv)]
+    def whole(n, hq):
+        return (lse_ref, delta_ref)[n][0, hq, pl.ds(i, 1), :]
+
+    def pair(masked, keys=slice(None), key0=0, queries=slice(None),
+             stat=whole):
+        """The pair's ``keys`` (a slice from the tile's ``key0`` on) by its
+        ``queries`` (a static slice); ``stat(0, head)`` the queries' row of
+        ``lse``, ``stat(1, head)`` of ``delta``."""
+        kb = k_ref[_head(k_ref, 0, D, keys)]
+        vb = v_ref[_head(v_ref, 0, Dv, keys)]
         if masked:
-            keys = lax.broadcasted_iota(jnp.int32, (bk, bq), 0) + j * bk
-            live = _in_ranges(keys, *(r_ref[0, c:c + 1, :]
-                                      for c in range(4)))
+            bounds = [r_ref[0, c:c + 1, queries] for c in range(4)]
+            at_key = lax.broadcasted_iota(
+                jnp.int32, (kb.shape[0], bounds[0].shape[1]), 0)
+            live = _in_ranges(at_key + (j * bk + key0), *bounds)
         dk = dv = None
         for hq in range(g):           # static: the kv head's query heads
-            qi, doi = q_ref[_head(q_ref, hq, D)], do_ref[_head(do_ref, hq, Dv)]
+            qi = q_ref[_head(q_ref, hq, D, queries)]
+            doi = do_ref[_head(do_ref, hq, Dv, queries)]
             s = _scores(kb, qi, scale, False)
             if masked:
                 s = jnp.where(live, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[0, hq, pl.ds(i, 1), :])
+            p = jnp.exp(s - stat(0, hq))
             dv = _add(dv, jnp.dot(p.astype(doi.dtype), doi,
                                   preferred_element_type=jnp.float32))
             dp = lax.dot_general(vb, doi, keys_by_queries,
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, hq, pl.ds(i, 1), :]) * scale
+            ds = p * (dp - stat(1, hq)) * scale
             dk = _add(dk, jnp.dot(ds.astype(qi.dtype), qi,
                                   preferred_element_type=jnp.float32))
-        dk_acc[...] += dk
-        dv_acc[...] += dv
+        dk_acc[keys] += dk
+        dv_acc[keys] += dv
 
     pl.when(cls == 2)(functools.partial(pair, False))
-    pl.when(cls == 1)(functools.partial(pair, True))
+    if sub:
+        @pl.when(cls == 1)
+        def _():
+            for hq in range(g):
+                for n in range(2):
+                    st_ref[0][n, hq] = whole(n, hq)
+            wq, wk = bq // sub[0], bk // sub[1]
+
+            def visit(c, r):
+                key0, queries = pl.multiple_of(c * wk, wk), slice(
+                    r * wq, (r + 1) * wq)
+                pair(True, pl.ds(key0, wk), key0, queries,
+                     lambda n, hq: st_ref[0][n, hq, :, queries])
+
+            _walk(tbl_ref[at + 4], sub, visit)
+    else:
+        pl.when(cls == 1)(functools.partial(pair, True))
 
     @pl.when(flags >= 2)
     def _():
@@ -801,14 +1012,22 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _mask_plan(mask, bq, bk, Tk):
-    """(ranges [Bm, T, 4], tile classes, whether the mask is one a batch
-    row, the batch row's index into them) of a caller's mask."""
+    """(ranges [Bm, T, 4], tile classes, the mixed tiles' sub-tile classes
+    (:func:`_sub_words`), whether the mask is one a batch row, the batch
+    row's index into them) of a caller's mask."""
     ranges = mask if mask.ndim == 3 else mask[None]
     if not isinstance(ranges, np.ndarray):
         ranges = ranges.astype(jnp.int32)
     per_batch = ranges.shape[0] > 1
-    return (ranges, tile_classes(ranges, bq, bk, Tk), per_batch,
-            (lambda b: b) if per_batch else (lambda b: 0))
+    classes = tile_classes(ranges, bq, bk, Tk)
+    return (ranges, classes, _sub_words(ranges, classes, bq, bk, Tk),
+            per_batch, (lambda b: b) if per_batch else (lambda b: 0))
+
+
+def _tables_first(kernel, n, **static):
+    """``kernel`` taking its first ``n`` refs, the tables in SMEM, as one
+    tuple."""
+    return lambda *refs: kernel(refs[:n], *refs[n:], **static)
 
 
 def _vmem(*block_bytes, scratch=0):
@@ -922,18 +1141,19 @@ def _masked_fwd(q, k, v, mask, scale, widths):
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
     hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize, Dv)
-    ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
+    ranges, classes, sub, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
     tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
                                                         nq, g, bm, hb)
     _count("fwd", "masked", rows)
-    _count_tiles("fwd", classes)
+    _count_tiles("fwd", classes, sub)
     blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
                                          q.dtype.itemsize, Dv)
+    tables = _row_tables(classes, sub)
     return pl.pallas_call(
-        functools.partial(_mfwd_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
-                          per_batch=per_batch),
+        _tables_first(_mfwd_kernel, len(tables), scale=scale, bk=bk, nq=nq,
+                      nk=nk, per_batch=per_batch, sub=sub and sub.grid),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, H // hb, nq),
+            num_scalar_prefetch=len(tables), grid=(B, H // hb, nq),
             in_specs=[tile, whole, vwhole, rng], out_specs=[otile, stats],
             scratch_shapes=[pltpu.VMEM((hb, bq, _LANES), jnp.float32),
                             pltpu.VMEM((hb, bq, _LANES), jnp.float32),
@@ -946,7 +1166,7 @@ def _masked_fwd(q, k, v, mask, scale, widths):
         compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_INTERPRET,
         name="hvd_flash_fwd",
-    )(*_row_tables(classes), q, k, v, ranges)
+    )(*tables, q, k, v, ranges)
 
 
 def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
@@ -958,7 +1178,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     nq, nk = T // bq, Tk // bk
     item = q.dtype.itemsize
     hb = _dq_heads(g, bq, bk, D, nq, Tk, item, Dv)
-    ranges, classes, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
+    ranges, classes, sub, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
     tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
                                                         nq, g, bm, hb)
 
@@ -975,13 +1195,14 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
 
     for kernel in ("dq", "dkv"):
         _count(kernel, "masked", rows)
-        _count_tiles(kernel, classes)
+        _count_tiles(kernel, classes, sub)
     blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv)
+    tables = _row_tables(classes, sub)
     dq = pl.pallas_call(
-        functools.partial(_mdq_kernel, scale=scale, bk=bk, nq=nq, nk=nk,
-                          per_batch=per_batch),
+        _tables_first(_mdq_kernel, len(tables), scale=scale, bk=bk, nq=nq,
+                      nk=nk, per_batch=per_batch, sub=sub and sub.grid),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, H // hb, nq),
+            num_scalar_prefetch=len(tables), grid=(B, H // hb, nq),
             in_specs=[tile, whole, vwhole, otile, stats, stats, rng],
             out_specs=tile,
             scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32),
@@ -992,13 +1213,14 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
         compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_INTERPRET,
         name="hvd_flash_dq",
-    )(*_row_tables(classes), q, k, v, do, lse, delta, ranges)
+    )(*tables, q, k, v, do, lse, delta, ranges)
 
-    table, P = _pair_table(classes)
+    table, P = _pair_table(classes, sub)
     # table entry p of batch row b: key tile at [.. + 0], query tile at
     # [.. + 1]
-    at = (lambda b, p: (b * P + p) * 4) if per_batch else (
-        lambda b, p: p * 4)
+    width = 5 if sub else 4
+    at = (lambda b, p: (b * P + p) * width) if per_batch else (
+        lambda b, p: p * width)
     q_blk = lambda d: _heads_block(
         rows, g, bq, d, lambda b, c, p, t: (b, c, t[at(b, p) + 1]))
     kv_blk = lambda d: _heads_block(
@@ -1006,7 +1228,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_mdkv_kernel, scale=scale, P=P, g=g,
-                          per_batch=per_batch),
+                          per_batch=per_batch, sub=sub and sub.grid),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hkv, P),
             in_specs=[
@@ -1018,7 +1240,8 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
             ],
             out_specs=[kv_blk(D), kv_blk(Dv)],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, Dv), jnp.float32)]),
+                            pltpu.VMEM((bk, Dv), jnp.float32)]
+            + ([pltpu.VMEM((2, g, 1, bq), jnp.float32)] if sub else [])),
         out_shape=[
             _sds(_heads_shape(rows, B, Hkv, Tk, D), k.dtype, q, k, v, do),
             _sds(_heads_shape(rows, B, Hkv, Tk, Dv), v.dtype, q, k, v, do),

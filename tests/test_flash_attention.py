@@ -6,6 +6,7 @@ the XLA blockwise fallback is validated directly.  Reference is dense
 softmax attention in fp32.
 """
 
+import functools
 import re
 
 import jax
@@ -512,6 +513,47 @@ MASKS = {
 }
 
 
+def _packed_documents(T):
+    """``[2, T, 4]``: causal inside documents, cut elsewhere in each batch
+    row, off every tile's and sub-tile's edge."""
+    rows = []
+    for cuts in ((0, T // 3 + 7, T // 2 + 90, T), (0, T // 4 - 11, T)):
+        r = fa.causal_ranges(T)
+        for lo, hi in zip(cuts, cuts[1:]):
+            r[lo:hi, 0] = lo
+        rows.append(r)
+    return np.stack(rows)
+
+
+def _first_or_last(T):
+    """``[T, 4]``: a row in three sees only a few of the first keys, so
+    none in any later sub-tile its tile visits; the next only a few of
+    the last, none before the last sub-tile visited; the third a stretch
+    across every sub-tile, so that all of them are visited, masked."""
+    i = np.arange(T)
+    r = np.zeros((T, 4), np.int32)
+    r[:, 0] = np.select([i % 3 == 0, i % 3 == 1], [0, T - 1 - i % 7], i % 50)
+    r[:, 1] = np.select([i % 3 == 0, i % 3 == 1], [1 + i % 7, T], T - i % 60)
+    return r
+
+
+# the masks a tile of which is walked by sub-tiles (``tiles`` below)
+SUB_MASKS = dict(MASKS, **{
+    "window-of-a-tile": lambda T: fa.window_ranges(T, 256),   # as Phi's
+    "packed-documents": _packed_documents,
+    "first-or-last": _first_or_last})
+
+
+def _tiles(monkeypatch, tiles):
+    """``tiles = (block, sub)``: positions a tile and a sub-tile; ``sub``
+    None leaves ``_SUB``, which no tile of 128 holds twice."""
+    block, sub = tiles
+    monkeypatch.setattr(fa, "_BLOCK", block)
+    if sub:
+        monkeypatch.setattr(fa, "_SUB", sub)
+    return block, max(512, 2 * block)
+
+
 def _dense_masked(q, k, v, live):
     g = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
@@ -539,46 +581,67 @@ HEAD_CASES = {
     "g1-d128": ((2, 2, 128), None, 1),
     "g8-d128": ((8, 1, 128), None, 8),
 }
+WHOLE = (128, None)          # tiles of 128: a mixed tile is taken whole
 MASKED_CASES = (
-    [(mask, per_batch, "g8-whole-group") for mask in sorted(MASKS)
+    [(mask, per_batch, "g8-whole-group", WHOLE) for mask in sorted(MASKS)
      for per_batch in (False, True)]
-    + [("block-diffusion", per_batch, heads) for heads in HEAD_CASES
-       if heads != "g8-whole-group" for per_batch in (False, True)])
+    + [("block-diffusion", per_batch, heads, WHOLE) for heads in HEAD_CASES
+       if heads != "g8-whole-group" for per_batch in (False, True)]
+    # a mixed tile by its sub-tiles: 2 x 2 of them, and the chip's 4 x 4
+    + [(mask, False, "g2-d128", (256, 128))
+       for mask in sorted(set(SUB_MASKS) - {"window"})]
+    + [(mask, False, "g8-whole-group", (256, 128))
+       for mask in ("causal", "first-or-last")]
+    + [("block-diffusion", False, "g2-d128", (512, 128))])
 
 
-@pytest.mark.parametrize(
-    "mask,per_batch,heads", MASKED_CASES,
-    ids=[f"{mask}-{'mask-per-row' if per_batch else 'one-mask'}-{heads}"
-         for mask, per_batch, heads in MASKED_CASES])
+def _case_id(mask, per_batch, heads, tiles):
+    return (f"{mask}-{'mask-per-row' if per_batch else 'one-mask'}-{heads}"
+            + ("" if tiles == WHOLE else "-tiles-of-%d-by-%d" % tiles))
+
+
+@pytest.mark.parametrize("mask,per_batch,heads,tiles", MASKED_CASES,
+                         ids=[_case_id(*case) for case in MASKED_CASES])
 def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
-                                                     monkeypatch):
+                                                     tiles, monkeypatch):
     """Forward (out and lse, which ``dq`` and ``dkv`` read) and all three
     gradients, the mask known where the call is built (numpy) or traced
     per batch row, a forward step taking a whole GQA group, a part of
     one, or one head; at ``head_dim`` 64 transposed around the kernels
     (``heads``), at 128 on the caller's layout (``rows``: groups of 1, 2
-    and 8)."""
+    and 8); a mixed tile taken whole, or walked by its live sub-tiles
+    (``tiles``) under every mask, two and eight heads a step."""
     from horovod_tpu import metrics
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_BLOCK", 128)
+    blk, T = _tiles(monkeypatch, tiles)
     monkeypatch.setattr(metrics, "ACTIVE", True)
     (H, Hkv, D), budget, hb = HEAD_CASES[heads]
     before = _kernel_counts()
     if budget is not None:
         monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
-    B, T = 2, 512
-    assert fa._fwd_heads(H // Hkv, 128, 128, D, T // 128, T, 4) == hb
+    B = 2
+    assert fa._fwd_heads(H // Hkv, blk, blk, D, T // blk, T, 4) == hb
     q, k, v = make_qkv(B, T, H, Hkv, D)
-    ranges = MASKS[mask](T)
+    ranges = SUB_MASKS[mask](T)
     live = jnp.asarray(fa.dense_mask(ranges, T))
-    given = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
+    given = (jnp.asarray(np.stack([ranges] * B)) if per_batch else
+             jnp.asarray(ranges) if ranges.ndim == 3 else ranges)
     assert fa.supported(q, k, v, False, given)
-    if mask == "block-diffusion":
+    if mask == "block-diffusion" and tiles == WHOLE:
         # a query tile whose live key tiles are all mixed, and one with a
         # single live tile
         classes = fa.tile_classes(ranges[None], 128, 128, T)[0]
         n_full, n_live = (classes == 2).sum(-1), (classes >= 1).sum(-1)
         assert ((n_full == 0) & (n_live >= 2)).any() and (n_live == 1).any()
+    if tiles != WHOLE:
+        _, classes, sub, *_ = fa._mask_plan(given, blk, blk, T)
+        codes = np.asarray(fa.sub_codes(sub.words, np.prod(sub.grid)))
+        mixed = codes[np.asarray(classes) == 1]
+        assert (mixed == 1).any() and len(mixed)
+        if mask == "first-or-last":        # every sub-tile visited, masked
+            assert (codes == 1).all()
+        else:                              # some skipped, some unmasked
+            assert (mixed == 0).any() and (mixed == 2).any()
 
     def loss(attend):
         return lambda q, k, v: (attend(q, k, v) ** 2).sum()
@@ -613,41 +676,47 @@ BACKWARD_CASES = {
     "g4-d128-split-group": ((8, 2, 128, 128), 4 << 20, 2),
 }
 BACKWARD_MASKS = (
-    [("block-diffusion", per_batch, heads) for heads in BACKWARD_CASES
+    [("block-diffusion", per_batch, heads, WHOLE) for heads in BACKWARD_CASES
      for per_batch in (False, True)]
-    + [(mask, False, heads) for mask in ("causal", "window")
-       for heads in ("g2-values-twice-as-wide", "g8-d128")])
+    + [(mask, False, heads, WHOLE) for mask in ("causal", "window")
+       for heads in ("g2-values-twice-as-wide", "g8-d128")]
+    # a mixed tile by its sub-tiles, the ``lse`` cotangent folded in
+    + [(mask, False, "g2-values-twice-as-wide", (256, 128))
+       for mask in ("window-of-a-tile", "packed-documents", "first-or-last")]
+    + [(mask, False, "g8-d128", (256, 128))
+       for mask in ("causal", "block-diffusion")])
 
 
-@pytest.mark.parametrize(
-    "mask,per_batch,heads", BACKWARD_MASKS,
-    ids=[f"{mask}-{'mask-per-row' if per_batch else 'one-mask'}-{heads}"
-         for mask, per_batch, heads in BACKWARD_MASKS])
+@pytest.mark.parametrize("mask,per_batch,heads,tiles", BACKWARD_MASKS,
+                         ids=[_case_id(*case) for case in BACKWARD_MASKS])
 def test_masked_backward_matches_dense_masked_attention(mask, per_batch,
-                                                        heads, monkeypatch):
+                                                        heads, tiles,
+                                                        monkeypatch):
     """``dq``, ``dk`` and ``dv`` of a loss on ``out`` AND on ``lse`` (whose
     cotangent folds into ``delta`` before the kernels) against dense masked
     attention: a ``dq`` step taking a whole GQA group, a part of one, or
     one head; values twice as wide as keys; transposed around the kernels
     at ``head_dim`` 64, on the caller's layout at 128; the mask known where
     the call is built or traced a batch row; a query tile whose live tiles
-    are all mixed and one with a single live tile."""
+    are all mixed and one with a single live tile; a mixed tile taken
+    whole, or walked by its live sub-tiles (``tiles``)."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_BLOCK", 128)
+    blk, T = _tiles(monkeypatch, tiles)
     (H, Hkv, D, Dv), budget, hb = BACKWARD_CASES[heads]
     if budget is not None:
         monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
-    B, T = 2, 512
-    assert fa._dq_heads(H // Hkv, 128, 128, D, T // 128, T, 4, Dv) == hb
+    B = 2
+    assert fa._dq_heads(H // Hkv, blk, blk, D, T // blk, T, 4, Dv) == hb
     rng = np.random.RandomState(11)
     q, k, v = (jnp.asarray(rng.randn(B, T, h, d), jnp.float32)
                for h, d in ((H, D), (Hkv, D), (Hkv, Dv)))
     w_out = jnp.asarray(rng.randn(B, T, H, Dv), jnp.float32)
     w_lse = jnp.asarray(rng.randn(B, H, T), jnp.float32)
-    ranges = MASKS[mask](T)
+    ranges = SUB_MASKS[mask](T)
     live = jnp.asarray(fa.dense_mask(ranges, T))
-    given = jnp.asarray(np.stack([ranges] * B)) if per_batch else ranges
-    if mask == "block-diffusion":
+    given = (jnp.asarray(np.stack([ranges] * B)) if per_batch else
+             jnp.asarray(ranges) if ranges.ndim == 3 else ranges)
+    if mask == "block-diffusion" and tiles == WHOLE:
         classes = fa.tile_classes(ranges[None], 128, 128, T)[0]
         n_full, n_live = (classes == 2).sum(-1), (classes >= 1).sum(-1)
         assert ((n_full == 0) & (n_live >= 2)).any() and (n_live == 1).any()
@@ -832,7 +901,9 @@ def test_masked_backward_specs(D, Dv, monkeypatch):
     ``delta`` as columns over the lanes and the ranges over the lanes.
     ``dkv``: a step a live pair of tiles, the group's query tiles on one
     key tile, the ranges a row a bound ``[1, 4, bq]``, two float32
-    accumulators.  On the caller's layout at ``head_dim`` 128, transposed
+    accumulators and, the mask cutting tiles that are walked by sub-tiles,
+    the query tile's rows of ``lse`` and ``delta`` ``[2, g, 1, bq]``.  On
+    the caller's layout at ``head_dim`` 128, transposed
     around the kernels at 64 (values 128 wide: the Phi call)."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
     B, T, H, Hkv = 2, 2048, 16, 4
@@ -864,7 +935,7 @@ def test_masked_backward_specs(D, Dv, monkeypatch):
     assert calls["hvd_flash_dkv"] == [(B, Hkv, P), [
         blk(g, bq, D), blk(1, bq, D), blk(1, bq, Dv), blk(g, bq, Dv),
         stats(g), stats(g), (1, 4, bq), blk(1, bq, D), blk(1, bq, Dv)],
-        [f32(bq, D), f32(bq, Dv)]]
+        [f32(bq, D), f32(bq, Dv), f32(2, g, 1, bq)]]
 
 
 def test_causal_over_several_blocks_agrees_with_dense(monkeypatch):
@@ -914,16 +985,163 @@ def test_tile_classes_against_a_brute_force_count(mask, bq, bk):
     assert firsts == lasts == sorted(set(range(nk)))     # each key tile once
 
 
+# the masks of the benchmark's cells at a quarter of their 8,192 positions
+# (tiles of 512 as there), and documents packed otherwise in each batch row
+SUB_CLASS_MASKS = {
+    "causal": fa.causal_ranges,
+    "window-512": lambda T: fa.window_ranges(T, 512),
+    "block-diffusion": lambda T: _block_diffusion_ranges(T // 2, 4),
+    "packed-documents-traced": _packed_documents,
+}
+
+
+@pytest.mark.parametrize("mask", sorted(SUB_CLASS_MASKS))
+@pytest.mark.parametrize("sub", [256, 128])
+def test_sub_tile_classes_against_the_dense_mask(mask, sub, monkeypatch):
+    """The words of a mixed tile's sub-tile classes, from a mask known
+    where the call is built and from a traced one: no live pair in a dead
+    sub-tile, no masked pair in a full one, each of a mixed tile's ``sq x
+    sk`` sub-tiles classed, and nothing but zeros for a tile that is not
+    mixed; the same words ride both kernels' tables."""
+    monkeypatch.setattr(fa, "_SUB", sub)
+    T, blk = 2048, 512
+    ranges = SUB_CLASS_MASKS[mask](T)
+    ranges = ranges if ranges.ndim == 3 else ranges[None]
+    traced = mask.endswith("traced")
+    plan = jax.jit(lambda r: fa._mask_plan(r, blk, blk, T)[1:3]) if traced \
+        else (lambda r: fa._mask_plan(r, blk, blk, T)[1:3])
+    classes, found = plan(ranges)
+    assert isinstance(found.words, jax.Array if traced else np.ndarray)
+    classes, words, spans = (np.asarray(a) for a in
+                             (classes, found.words, found.spans))
+    n, S = T // blk, blk // sub
+    assert found.grid == (S, S)
+    assert words.dtype == spans.dtype == np.int32
+    live = fa.dense_mask(ranges, T)
+    for b in range(ranges.shape[0]):
+        # [query tile, key tile, query sub-tile, key sub-tile, rows, keys]
+        pairs = live[b].reshape(n, S, sub, n, S, sub).transpose(0, 3, 1, 4, 2, 5)
+        want = np.where(pairs.all((4, 5)), 2,
+                        np.where(pairs.any((4, 5)), 1, 0))
+        got = fa.sub_codes(words[b], S * S).reshape(n, n, S, S)
+        mixed = classes[b] == 1
+        assert mixed.any() and (got[mixed] == want[mixed]).all()
+        assert (want[mixed] == 1).any((-1, -2)).all()   # why a tile is mixed
+        assert (words[b][~mixed] == 0).all() and (spans[b][~mixed] == 0).all()
+        # a band's span: from its first live sub-tile to its last, nothing
+        # live outside it
+        for i, j in zip(*np.nonzero(mixed)):
+            for r in range(S):
+                field = (spans[b, i, j] >> (8 * r)) & 0xff
+                count, first = field & 7, field >> 3
+                at = np.flatnonzero(want[i, j, r])
+                assert count == (at[-1] - at[0] + 1 if len(at) else 0)
+                assert not count or first == at[0]
+    # the tables: the spans a fourth for fwd / dq by (query tile, key
+    # tile), the classes a fifth column of the pairs' for dkv
+    tables = fa._row_tables(classes, found)
+    assert len(tables) == 4 and (np.asarray(tables[3])
+                                 == spans.reshape(-1)).all()
+    table, P = fa._pair_table(classes, found)
+    for b, rows in enumerate(np.asarray(table).reshape(-1, P, 5)):
+        for j, i, c, _, word in rows:
+            assert word == (words[b, i, j] if c == 1 else word)
+
+
+@pytest.mark.parametrize("mask", ["full", "causal-by-whole-tiles", "one-tile"])
+def test_a_mask_that_cuts_no_tile_builds_no_sub_tile_table(mask,
+                                                           monkeypatch):
+    """Every tile full or dead (or a tile no larger than a sub-tile): no
+    words, three tables in SMEM as before the sub-tiles, the pairs' table
+    four wide, no scratch for the sub-tiles' statistics and no series of
+    ``hvd_flash_subtiles_total``."""
+    from horovod_tpu import metrics
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+    T = 1024
+    if mask == "one-tile":
+        monkeypatch.setattr(fa, "_BLOCK", fa._SUB)
+        ranges = fa.causal_ranges(T)          # cuts tiles of one sub-tile
+    elif mask == "full":
+        ranges = fa.full_ranges(T, T)
+    else:
+        ranges = fa.causal_ranges(T)
+        ranges[:, 1] = (np.arange(T) // 512 + 1) * 512
+    blk = fa._BLOCK
+    ranges_b, classes, sub, *_ = fa._mask_plan(ranges, blk, blk, T)
+    assert sub is None and ((classes == 1).any() == (mask == "one-tile"))
+    # traced, a mask may cut a tile: the table is built unless a tile is
+    # one sub-tile
+    traced = jax.eval_shape(
+        lambda r: fa._mask_plan(r, blk, blk, T)[2], jnp.asarray(ranges_b))
+    assert (traced is None) == (mask == "one-tile")
+    before = _subtile_counts()
+    x = jax.ShapeDtypeStruct((1, T, 2, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, mask=ranges).sum(),
+        (0, 1, 2))(q, k, v))(x, x, x)
+    calls = {eqn.params["name"]: eqn.params["grid_mapping"]
+             for _, eqn in _eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"}
+    assert {name: (gm.num_index_operands, gm.num_scratch_operands)
+            for name, gm in calls.items()} == {
+        "hvd_flash_fwd": (3, 4), "hvd_flash_dq": (3, 4),
+        "hvd_flash_dkv": (1, 2)}
+    assert _subtile_counts() == before
+
+
+def _state_counts(family):
+    """``{(kernel, state): value}`` of ``hvd_flash_tiles_total`` or
+    ``hvd_flash_subtiles_total``."""
+    from horovod_tpu import metrics
+    fam = metrics.registry().to_dict().get(family, {})
+    return {(s["labels"]["kernel"], s["labels"]["state"]): s["value"]
+            for s in fam.get("series", [])}
+
+
+def _subtile_counts():
+    return _state_counts("hvd_flash_subtiles_total")
+
+
+# the issue's count (numpy, ``tile_classes`` at 512 and at the sub-tile's
+# width): sub-tiles of the SDAR cell's 24 mixed tiles, full / mixed / dead
+SDAR_SUB_TILES = {256: (16, 48, 32), 128: (96, 96, 192)}
+
+
+@pytest.mark.parametrize("sub", sorted(SDAR_SUB_TILES))
+def test_sdar_call_counts_its_tiles_and_sub_tiles(sub, monkeypatch):
+    """At the SDAR cell's ranges (``[xt ; x0]`` of 8,192 positions, blocks
+    of 4, tiles of 512) ``hvd_flash_tiles_total`` reads what it read
+    before the sub-tiles, 56 / 24 / 176 a kernel, and
+    ``hvd_flash_subtiles_total`` the sub-tiles of the 24 mixed tiles in
+    its three states, for each of the three kernels."""
+    from horovod_tpu import metrics
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_SUB", sub)
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+
+    tiles = functools.partial(_state_counts, "hvd_flash_tiles_total")
+    before_t, before_s = tiles(), _subtile_counts()
+    ranges = _block_diffusion_ranges(4096, 4)
+    q, k = (jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16)
+            for h in (8, 1))
+    jax.make_jaxpr(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, mask=ranges).astype(jnp.float32).sum(),
+        (0, 1, 2))(q, k, v))(q, k, k)
+    states = ("live", "masked", "skipped")
+    for kernel in ("fwd", "dq", "dkv"):
+        assert tuple(tiles()[kernel, s] - before_t.get((kernel, s), 0)
+                     for s in states) == (56, 24, 176)
+        assert tuple(_subtile_counts()[kernel, s]
+                     - before_s.get((kernel, s), 0)
+                     for s in states) == SDAR_SUB_TILES[sub]
+
+
 def test_masked_call_counts_its_tiles_and_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_BLOCK", 128)
-    from horovod_tpu import metrics
-
-    def tiles():
-        fam = metrics.registry().to_dict().get("hvd_flash_tiles_total", {})
-        return {(s["labels"]["kernel"], s["labels"]["state"]): s["value"]
-                for s in fam.get("series", [])}
-
+    tiles = functools.partial(_state_counts, "hvd_flash_tiles_total")
     before_t, before_k = tiles(), _kernel_counts()
     x = jax.ShapeDtypeStruct((1, 512, 8, 64), jnp.float32)
     kv = jax.ShapeDtypeStruct((1, 512, 1, 64), jnp.float32)

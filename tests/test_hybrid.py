@@ -99,7 +99,11 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     backward's kernel bodies (``dq`` both heads of a pair a step with its
     accumulator, ``lse`` / ``delta`` columns and spread ranges in scratch;
     ``dkv``'s tile keys by queries on ranges handed over ``[Bm, 4, T]``);
-    the block specs and everything around the kernels as they were."""
+    PR 44, the kernel bodies again (one ``visit`` serves a whole tile and
+    a sub-tile; the forward's mask made once a visit and added by every
+    head, as ``dq`` made it; at these tiles of 128 no tile is walked by
+    sub-tiles); the block specs and everything around the kernels as they
+    were."""
     import hashlib
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_BLOCK", 128)
@@ -110,7 +114,7 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     text = jax.jit(jax.value_and_grad(lambda h, ls: hybrid.layer_stack(
         h, ls, cfg, llama.remat_policy("full")).astype(jnp.float32).sum(),
         (0, 1))).lower(h, layers).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "f9c9e5ce95fd15d3"
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "85265b68d64b884c"
 
 
 def test_mamba_trunk_is_untouched_by_the_mamba2_mixers_kernels(monkeypatch):
