@@ -35,7 +35,7 @@ import horovod_tpu as hvd
 from horovod_tpu import training
 from horovod_tpu.models import llama
 from horovod_tpu.native import loader as native_loader
-from horovod_tpu.ops import flash_attention
+from horovod_tpu.ops import _pallas, flash_attention
 from horovod_tpu.optim.precision import adamw_lp
 from horovod_tpu.parallel.mesh import MeshConfig, ParallelMesh
 from horovod_tpu.runtime import use_compile_cache
@@ -201,7 +201,7 @@ def trainer_phase(cfg: SmokeConfig) -> dict:
         (cfg.per_chip_batch, cfg.seq, m.n_kv_heads, m.head_dim), m.dtype)
     facts = {
         "flash_supported": flash_attention.supported(q, kv, kv),
-        "interpret": flash_attention._INTERPRET,
+        "interpret": _pallas.INTERPRET,
     }
 
     logger = logging.getLogger("horovod_tpu")

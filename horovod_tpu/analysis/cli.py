@@ -17,7 +17,9 @@ from .report import (Finding, RULES, apply_suppressions,
                      file_skipped, iter_suppressions)
 
 _SKIP_DIRS = {"__pycache__", ".git", "build", "dist", "node_modules",
-              ".pytest_cache", ".hypothesis"}
+              ".pytest_cache", ".hypothesis",
+              # what chip runs and parent copies leave in a builder's tree
+              "_scratch", "_export", "chiprun_out", ".bench_out"}
 
 #: All engines, in run order.  "guards" is the HVD110–115 guarded-by
 #: race detector (guarded_by.py); "divergence" is the HVD200–HVD205

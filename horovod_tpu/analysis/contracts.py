@@ -331,7 +331,7 @@ class _Extractor(ast.NodeVisitor):
         # metric family declaration outside an assignment (assignment
         # forms were already captured, with the target var, from
         # visit_Assign — the _hvd_decl_done marker prevents doubles)
-        if name in ("counter", "gauge", "histogram") \
+        if name in ("counter", "gauge", "histogram", "kernel_counter") \
                 and not getattr(node, "_hvd_decl_done", False):
             self._maybe_metric_decl(node, None)
         # metric mutators
@@ -368,6 +368,9 @@ class _Extractor(ast.NodeVisitor):
 
     def _maybe_metric_decl(self, call: ast.Call, var: Optional[str]) -> None:
         kind = _call_name(call.func)
+        labels: Optional[Tuple[str, ...]] = ()
+        if kind == "kernel_counter":    # ops/_pallas.py: a counter family
+            kind, labels = "counter", ("kernel", "path")   # and its count()
         if kind not in ("counter", "gauge", "histogram"):
             return
         if not call.args:
@@ -376,7 +379,6 @@ class _Extractor(ast.NodeVisitor):
         if not fam:
             return
         call._hvd_decl_done = True  # type: ignore[attr-defined]
-        labels: Optional[Tuple[str, ...]] = ()
         lo, hi = _HIST_LO, _HIST_HI
         # positional: (name, help, labels, lo, hi)
         if len(call.args) >= 3:
@@ -511,7 +513,9 @@ def find_repo_root(paths: Sequence[str]) -> Optional[str]:
 
 
 _SKIP_DIRS = {"__pycache__", ".git", "build", "dist", "node_modules",
-              ".pytest_cache", ".hypothesis", "related"}
+              ".pytest_cache", ".hypothesis", "related",
+              # what chip runs and parent copies leave in a builder's tree
+              "_scratch", "_export", "chiprun_out", ".bench_out"}
 
 
 def _scan_files(root: str) -> List[str]:

@@ -12,7 +12,7 @@ units, plain attention without positions; the frame from the config) and
 ``generate`` (KV-cache decoding).  bf16 compute over fp32 parameters,
 stacked layers under ``lax.scan`` where the layers are equal, mesh axes
 as hooks (``llama.ParallelSpec``).  Each model names its own parts for
-the device trace (``training.SCOPE_EMBED`` .. ``SCOPE_STAGE``).
+the device trace (``scopes.SCOPE_EMBED`` .. ``SCOPE_STAGE``).
 """
 
 from . import generate, llama, mnist, resnet  # noqa: F401  (bert, moe and

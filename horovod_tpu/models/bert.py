@@ -28,6 +28,8 @@ from jax import lax
 
 from ..optim import overlap as _overlap
 from ..parallel.ring_attention import local_attention, ring_attention
+from ..scopes import (SCOPE_ATTENTION, SCOPE_EMBED, SCOPE_FORWARD, SCOPE_HEAD,
+                      SCOPE_MLP, SCOPE_OPTIMIZER, SCOPE_REDUCE)
 from .llama import ParallelSpec, remat_policy
 
 
@@ -197,7 +199,6 @@ def _mlp(x, lp, par: ParallelSpec):
 def block(x, lp, cfg: BertConfig, par: ParallelSpec, mask):
     """One post-LN encoder block (BERT layout: residual then LayerNorm);
     each sublayer lies with its residual's LayerNorm under its scope."""
-    from ..training import SCOPE_ATTENTION, SCOPE_MLP
     with jax.named_scope(SCOPE_ATTENTION):
         a = _attention(x, lp, cfg, par, mask)
         x = _layernorm(x + a, lp["attn_norm_w"], lp["attn_norm_b"],
@@ -217,7 +218,6 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
     0/1 attention mask for padded batches (forces the dense path and is
     incompatible with sp sharding).
     """
-    from ..training import SCOPE_EMBED
     if mask is not None and par.sp_axis is not None:
         raise ValueError("attention masks require unsharded sequence "
                          "(pad-free batches for the sp path)")
@@ -258,7 +258,6 @@ def encode(params, tokens, cfg: BertConfig, par: ParallelSpec,
 def classify(params, tokens, cfg: BertConfig, par: ParallelSpec,
              token_types=None, mask=None):
     """Sequence classification logits ``[B, num_labels]`` (pooled [CLS])."""
-    from ..training import SCOPE_HEAD
     h = encode(params, tokens, cfg, par, token_types, mask)
     with jax.named_scope(SCOPE_HEAD):
         cls = h[:, 0, :]  # [CLS] position
@@ -272,7 +271,6 @@ def loss_fn(params, tokens, labels, cfg: BertConfig, par: ParallelSpec,
             token_types=None, mask=None):
     """Mean classification cross-entropy over the local batch (caller
     pmeans over dp)."""
-    from ..training import SCOPE_HEAD
     # overlapped dispatch: tap the non-scanned leaves (embeddings,
     # pooler, classification head) as one group; the scanned stack is
     # tapped per layer inside encode()'s scan body.  No-op outside an
@@ -296,7 +294,6 @@ def make_dp_finetune_step(cfg: BertConfig, mesh, axis: str, optimizer,
     """
     import optax
     from jax.sharding import PartitionSpec as P
-    from ..training import SCOPE_FORWARD, SCOPE_OPTIMIZER, SCOPE_REDUCE
     par = ParallelSpec(dp_axis=axis)
 
     def forward(params, tokens, labels):

@@ -120,6 +120,10 @@ from ..ops.selective_scan import selective_scan
 from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
 from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
+from ..scopes import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
+                      SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLP,
+                      SCOPE_SHARED, SCOPE_SSD_MIXER, SCOPE_SSD_SCAN,
+                      SCOPE_SSM_MIXER)
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
 from . import moe
 from .llama import ParallelSpec, _rmsnorm
@@ -342,7 +346,6 @@ def _mamba(u, lp, cfg):
 
 def _mamba2(u, lp, cfg):
     """The Mamba-2 mixer's output ``[B, T, D]``."""
-    from ..training import SCOPE_SSD_SCAN
     f32 = jnp.float32
     B, T, _ = u.shape
     Di, Hs, G, N = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
@@ -380,7 +383,6 @@ def _mamba2(u, lp, cfg):
 
 def _kda(u, lp, cfg):
     """The Kimi Delta Attention mixer's output ``[B, T, D]``."""
-    from ..training import SCOPE_KDA_SCAN
     f32 = jnp.float32
     B, T, _ = u.shape
     Hs, K = cfg.ssm_heads, cfg.ssm_state
@@ -418,7 +420,7 @@ def _feed_forward(z, lp, cfg):
         return _mlp(z, lp), None
     y, stats = moe.dropless_moe_layer(z, lp, cfg, ParallelSpec())
     if cfg.n_shared_experts:
-        with jax.named_scope(moe.SCOPE_SHARED):
+        with jax.named_scope(SCOPE_SHARED):
             y = y + _mlp(z, lp)
     return y, stats
 
@@ -479,9 +481,6 @@ def _layer(kind, emits, cfg):
     else None; ``emitted`` is what an emitting mamba (``s``) or full layer
     (``(k, v)``) hands on, else None; ``stats`` routed experts' ``[4]``
     statistics, None of a dense feed-forward."""
-    from ..training import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
-                            SCOPE_KDA_MIXER, SCOPE_MLP, SCOPE_SSD_MIXER,
-                            SCOPE_SSM_MIXER)
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def f(h, lp, lam0, memory):

@@ -32,6 +32,7 @@ from .. import metrics as _metrics
 from ..optim import overlap as _overlap
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
+from ..scopes import SCOPE_ATTENTION, SCOPE_EMBED, SCOPE_HEAD, SCOPE_MLP
 
 _m_remat = _metrics.counter(
     "hvd_remat_policy_total",
@@ -493,7 +494,6 @@ def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
     dense MLPs), or dropless experts' ``[4]`` routing statistics.  Each
     sublayer with its norm lies under a scope of its own; the residual
     adds are the layer's."""
-    from ..training import SCOPE_ATTENTION, SCOPE_MLP
     with jax.named_scope(SCOPE_ATTENTION):
         a = _attention(_rmsnorm(x, lp["attn_norm"], cfg.norm_eps),
                        lp, cfg, par, positions, mask)
@@ -592,7 +592,6 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
     """
     if cfg.layer_kinds:
         return _hybrid_hidden(params, tokens, cfg, par, positions, mask)
-    from ..training import SCOPE_EMBED, SCOPE_HEAD
     Tl = tokens.shape[1]
     sp_idx = (lax.axis_index(par.sp_axis)
               if par.sp_axis is not None else 0)
@@ -653,7 +652,6 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
         raise NotImplementedError(
             "a trunk of several kinds takes no positions and no mask and "
             "runs under plain data parallelism only")
-    from ..training import SCOPE_EMBED, SCOPE_HEAD
     with jax.named_scope(SCOPE_EMBED):
         h = _embed_lookup(params["embed"], tokens, cfg, par)
     h, stats = hybrid.layer_stack(
@@ -669,7 +667,6 @@ def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
 def forward(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
             n_microbatches: int = 0):
     """Token ids → logits.  Call inside shard_map over the parallel mesh."""
-    from ..training import SCOPE_HEAD
     h, aux = hidden(params, tokens, cfg, par, n_microbatches)
     with jax.named_scope(SCOPE_HEAD):
         # tied embedding head (Llama-3 unties; tying halves test-model
@@ -751,7 +748,6 @@ def loss_fn(params, tokens, targets, cfg: LlamaConfig, par: ParallelSpec,
     Tt``), ``positions`` and ``mask`` go to :func:`hidden`.
     ``with_stats`` also returns dropless experts' routing statistics,
     summed over the layers (``moe.ROUTING_STATS``; zeros otherwise)."""
-    from ..training import SCOPE_HEAD
     # overlapped dispatch: tap the non-scanned leaves (embed, final_norm)
     # as one group HERE so every use — the lookup AND the tied loss head
     # — contributes to one cotangent before the dispatch fires; the

@@ -56,10 +56,8 @@ from jax import lax
 
 from .. import metrics as _metrics
 from ..ops import grouped_matmul as _gmm
+from ..scopes import SCOPE_EXPERTS, SCOPE_ROUTE, SCOPE_SHARED  # noqa: F401
 
-SCOPE_ROUTE = "hvd_moe_route"        # router logits, scores, top-k
-SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine
-SCOPE_SHARED = "hvd_moe_shared"      # the expert every token passes through
 
 _m_layers = _metrics.counter(
     "hvd_moe_layer_total",

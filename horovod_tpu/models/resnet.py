@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.sync_batch_norm import sync_batch_norm
+from ..scopes import SCOPE_HEAD, SCOPE_STAGE, SCOPE_STEM
 
 # variant → (block counts per stage, bottleneck?)
 VARIANTS = {
@@ -158,7 +159,6 @@ def forward(params, state, images, cfg: ResNetConfig, train: bool = True,
             axis_name: Optional[str] = None):
     """images: [B, H, W, 3] (any float dtype) → (logits fp32 [B, classes],
     new_state).  ``axis_name``: dp axis for synchronized batch norm."""
-    from ..training import SCOPE_HEAD, SCOPE_STAGE, SCOPE_STEM
     new_state = {}
     with jax.named_scope(SCOPE_STEM):
         x = images.astype(cfg.dtype)

@@ -148,16 +148,13 @@ sub-tiles.
 Falls back cleanly: :func:`supported` gates on platform/shape so callers
 (e.g. ``local_attention``) can pick the XLA blockwise path on CPU meshes
 or odd shapes — on a TPU backend each refused shape is logged once, at
-WARNING, with the test that refused it.  ``HOROVOD_FLASH_ATTENTION=0``
-disables the kernel.
+WARNING, with the test that refused it.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 import math
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -169,11 +166,10 @@ from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from .. import metrics as _metrics
-
-logger = logging.getLogger("horovod_tpu")
+from . import _pallas
+from ._pallas import LANES as _LANES, sds as _sds, verdict as _verdict
 
 NEG_INF = -1e30
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft cap for resident kernel buffers
 _BLOCK = 512  # query and key positions a block
 # positions a side of the sub-tiles by which a tile that the mask cuts is
@@ -187,7 +183,6 @@ _BLOCK = 512  # query and key positions a block
 # area under block diffusion) and pays more for it, a visit's rows of
 # statistics and accumulators being read and written whatever its width.
 _SUB = 256
-_LANES = 128
 # what one grid step of the masked forward or of dq may hold: a quarter of
 # a v5e core's 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
 _MASKED_STEP_VMEM = 32 * 1024 * 1024
@@ -196,7 +191,7 @@ _MASKED_STEP_VMEM = 32 * 1024 * 1024
 OUT_NAME = "hvd_flash_out"
 LSE_NAME = "hvd_flash_lse"
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_flash_kernel_total",
     "Flash-attention Pallas kernels built, one per traced call site; "
     "path is packed or masked (tiled stopped occurring: several blocks "
@@ -206,12 +201,12 @@ _m_kernels = _metrics.counter(
     labels=("kernel", "path", "layout"))
 
 
-_m_tiles = _metrics.counter(
+_count_tile = _pallas.kernel_counter(
     "hvd_flash_tiles_total",
     "Tiles of masked flash-attention calls by class, counted where the "
     "call is built from a mask known there",
     labels=("kernel", "state"))
-_m_subtiles = _metrics.counter(
+_count_subtile = _pallas.kernel_counter(
     "hvd_flash_subtiles_total",
     "Sub-tiles (_SUB positions a side) of the mixed tiles of masked "
     "flash-attention calls by class, counted where the call is built from "
@@ -220,24 +215,17 @@ _m_subtiles = _metrics.counter(
 _TILE_STATES = ("skipped", "masked", "live")     # class 0, 1, 2
 
 
-def _count(kernel: str, path: str, rows: bool = True) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path,
-                       layout="rows" if rows else "heads")
-
-
 def _count_tiles(kernel: str, classes, sub=None) -> None:
     """``classes``: the call's tile classes when known at build time;
     ``sub``: its mixed tiles' sub-tile classes (:func:`_sub_words`)."""
     if not (_metrics.ACTIVE and isinstance(classes, np.ndarray)):
         return
     for c, state in enumerate(_TILE_STATES):
-        _m_tiles.inc(int((classes == c).sum()), kernel=kernel, state=state)
+        _count_tile(kernel, state, n=int((classes == c).sum()))
     if sub is not None:
         codes = sub_codes(sub.words[classes == 1], math.prod(sub.grid))
         for c, state in enumerate(_TILE_STATES):
-            _m_subtiles.inc(int((codes == c).sum()), kernel=kernel,
-                            state=state)
+            _count_subtile(kernel, state, n=int((codes == c).sum()))
 
 
 def _block_sizes(t_q: int, t_kv: int):
@@ -303,35 +291,10 @@ def _row_widths(D, Dv):
     return (D, Dv) if D % _LANES == 0 and Dv % _LANES == 0 else None
 
 
-def _sds(shape, dtype, *operands):
-    """ShapeDtypeStruct carrying the union of the operands' varying mesh
-    axes — required for pallas_call outputs under shard_map check_vma."""
-    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-
-
-@functools.lru_cache(maxsize=None)
-def _warn_refused(kernel: str, shapes: tuple, reason: str) -> None:
-    logger.warning("%s kernel refused shapes %s (%s); falling back to "
-                   "the XLA path", kernel, shapes, reason)
-
-
-def _verdict(kernel: str, reason: Optional[str], *operands) -> bool:
-    """``reason is None``, said aloud where it matters: on a TPU the XLA
-    path is a slower program than the one the caller named, so each
-    refused (kernel, shapes, reason) is logged once, at WARNING."""
-    if reason is not None and jax.default_backend() == "tpu":
-        _warn_refused(kernel, tuple(tuple(x.shape) for x in operands),
-                      reason)
-    return reason is None
-
-
 def _refusal(q, k, v) -> Optional[str]:
     """Which test keeps the Pallas kernel off this call; None = it runs."""
-    if os.environ.get("HOROVOD_FLASH_ATTENTION", "1") in ("0", "false"):
-        return "HOROVOD_FLASH_ATTENTION is off"
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if q.ndim != 4 or k.ndim != 4:
         return "q and k must be rank 4"
     B, T, H, D = q.shape
@@ -350,8 +313,8 @@ def _refusal(q, k, v) -> Optional[str]:
     if T % bq or Tk % bk or bq % 128 or bk % 128:
         return (f"blocks ({bq}, {bk}) must divide the sequence lengths "
                 f"({T}, {Tk}) and be multiples of 128")
-    if q.dtype not in (jnp.bfloat16, jnp.float32):
-        return f"dtype {q.dtype} is neither bfloat16 nor float32"
+    if (why := _pallas.dtype_refusal(q.dtype)):
+        return why
     g = H // Hkv
     # fwd holds k+v [Tk, D + Dv]; dkv holds q+do of one query tile of the
     # group
@@ -482,7 +445,7 @@ def _packed_fwd(q, k, v, causal, scale, D, pack):
     """q [B,T,H*D], k/v [B,Tk,Hkv*D] → (out [B,T,H*D], lse [B,H,1,T])."""
     grid, q_blk, kv_blk, row_blk, g = _packed_specs(q, k, D, pack)
     B, T, HD = q.shape
-    _count("fwd", "packed")
+    _count("fwd", "packed", "rows")
     return pl.pallas_call(
         functools.partial(_packed_fwd_kernel, scale=scale, causal=causal,
                           D=D, g=g),
@@ -493,7 +456,7 @@ def _packed_fwd(q, k, v, causal, scale, D, pack):
             _sds(q.shape, q.dtype, q, k, v),
             _sds((B, HD // D, 1, T), jnp.float32, q, k, v),
         ],
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_flash_fwd",
     )(q, k, v)
 
@@ -506,7 +469,7 @@ def _packed_bwd(q, k, v, out, lse, do, dlse, causal, scale, D, pack):
     delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
         B, T, HD // D, D).sum(-1).transpose(0, 2, 1)[:, :, None, :]
     delta = delta - dlse.astype(jnp.float32)
-    _count("bwd", "packed")
+    _count("bwd", "packed", "rows")
     return pl.pallas_call(
         functools.partial(_packed_bwd_kernel, scale=scale, causal=causal,
                           D=D, g=g),
@@ -518,7 +481,7 @@ def _packed_bwd(q, k, v, out, lse, do, dlse, causal, scale, D, pack):
             _sds(k.shape, k.dtype, q, k, v, do),
             _sds(v.shape, v.dtype, q, k, v, do),
         ],
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_flash_bwd",
     )(q, k, v, do, lse, delta)
 
@@ -1033,8 +996,8 @@ def _tables_first(kernel, n, **static):
 def _vmem(*block_bytes, scratch=0):
     """A masked kernel's VMEM limit: its blocks double-buffered, its
     scratch, and room for the fp32 tiles in flight."""
-    return pltpu.CompilerParams(vmem_limit_bytes=int(
-        2 * sum(block_bytes) + scratch + 24 * 1024 * 1024))
+    return _pallas.params(
+        vmem=2 * sum(block_bytes) + scratch + 24 * 1024 * 1024)
 
 
 def _heads_shape(rows, B, heads, n, d):
@@ -1144,7 +1107,7 @@ def _masked_fwd(q, k, v, mask, scale, widths):
     ranges, classes, sub, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
     tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
                                                         nq, g, bm, hb)
-    _count("fwd", "masked", rows)
+    _count("fwd", "masked", "rows" if rows else "heads")
     _count_tiles("fwd", classes, sub)
     blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
                                          q.dtype.itemsize, Dv)
@@ -1164,7 +1127,7 @@ def _masked_fwd(q, k, v, mask, scale, widths):
             _sds((B, H, nq, bq), jnp.float32, q, k, v),
         ],
         compiler_params=_vmem(blocks, scratch=scratch),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_flash_fwd",
     )(*tables, q, k, v, ranges)
 
@@ -1194,7 +1157,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     delta = delta.reshape(B, H, nq, bq) - dlse.astype(jnp.float32)
 
     for kernel in ("dq", "dkv"):
-        _count(kernel, "masked", rows)
+        _count(kernel, "masked", "rows" if rows else "heads")
         _count_tiles(kernel, classes, sub)
     blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv)
     tables = _row_tables(classes, sub)
@@ -1211,7 +1174,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
                             pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
         out_shape=_sds(_heads_shape(rows, B, H, T, D), q.dtype, q, k, v, do),
         compiler_params=_vmem(blocks, scratch=scratch),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_flash_dq",
     )(*tables, q, k, v, do, lse, delta, ranges)
 
@@ -1250,7 +1213,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
                               2 * bk * (D + Dv) * item,
                               2 * g * T * 4, 8 * bq * 4,
                               scratch=bk * (D + Dv) * 4),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_flash_dkv",
     )(table, q, k, v, do, lse, delta, ranges.transpose(0, 2, 1))
     return dq, dk, dv

@@ -54,16 +54,17 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import metrics as _metrics
-from .flash_attention import _sds, _verdict
+from . import _pallas
+# a grid step may hold _STEP_VMEM: a group's float32 [2048, 768]
+# accumulator, in and out and double-buffered, is 25 MiB of it
+from ._pallas import (LANES as _LANES, STEP_VMEM as _STEP_VMEM, sds as _sds,
+                      verdict as _verdict)
 
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 # Rows a tile, by the kernels alone at the SDAR cell's shapes (my chip
 # runs, PR 30): gmm is fastest at 256 (a straddling tile costs a whole
 # visit: 99 visits of 84 tiles, where 512 rows make 57 of 42; 128 rows are
@@ -73,11 +74,6 @@ _INTERPRET = False  # flipped by tests to run kernels on CPU
 # times as long)
 _TILE = 256
 _TGMM_TILE = 512
-_LANES = 128
-# what one grid step may hold: half of a v5e core's 128 MiB of VMEM (a
-# group's float32 [2048, 768] accumulator, in and out and double-buffered,
-# is 25 MiB)
-_STEP_VMEM = 64 * 1024 * 1024
 # the combine: tokens a grid step holds, rows a copy brings, copies in flight
 _COMBINE_TILE = 512
 _COMBINE_CHUNK = 32
@@ -86,19 +82,13 @@ _COMBINE_UNROLL = 4         # rows whose loads go before their stores
 # scalar memory the combine's token ids may take (one int32 a row)
 _COMBINE_SMEM = 256 * 1024
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_moe_gmm_kernel_total",
     "Grouped matrix product and combine calls built, one per traced call "
     "site; kernel is gmm (rows x a group's weights), tgmm "
     "(rows-transposed x rows a group) or combine (rows added to their "
     "tokens), path is pallas (ops/grouped_matmul.py: a gmm call may hold "
-    "two products) or xla (lax.ragged_dot; the scatter-add)",
-    labels=("kernel", "path"))
-
-
-def _count(kernel: str, path: str, n: int = 1) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(n, kernel=kernel, path=path)
+    "two products) or xla (lax.ragged_dot; the scatter-add)")
 
 
 def count_xla(gmm: int = 0, tgmm: int = 0) -> None:
@@ -106,22 +96,22 @@ def count_xla(gmm: int = 0, tgmm: int = 0) -> None:
     autodiff build them)."""
     for kernel, n in (("gmm", gmm), ("tgmm", tgmm)):
         if n:
-            _count(kernel, "xla", n)
+            _count(kernel, "xla", n=n)
 
 
 def _refusal(rows, *weights) -> Optional[str]:
     """Which test keeps the Pallas kernels off these products; None =
     they run.  ``rows [R, K]``; ``weights`` every ``[G, K, N]`` the
     layer's products read, as stored."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if rows.ndim != 2 or any(w.ndim != 3 for w in weights):
         return "rows must be rank 2 and weights rank 3"
     if rows.shape[0] % max(_TILE, _TGMM_TILE):
         return (f"{rows.shape[0]} rows are no multiple of "
                 f"{max(_TILE, _TGMM_TILE)}")
-    if rows.dtype not in (jnp.bfloat16, jnp.float32):
-        return f"dtype {rows.dtype} is neither bfloat16 nor float32"
+    if (why := _pallas.dtype_refusal(rows.dtype)):
+        return why
     for w in weights:
         if w.dtype != rows.dtype or w.shape[0] != weights[0].shape[0]:
             return "weights must have the rows' dtype and one group count"
@@ -254,9 +244,9 @@ def _gmm_kernel(tbl, bounds, *refs, body, n_rows, n_weights, tm):
 def _limit(blocks: int, temporaries: int, axes: int = 1):
     """The call's VMEM limit: its blocks double-buffered, the fp32 tiles
     in flight, and room."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",) * axes,
-        vmem_limit_bytes=int(2 * blocks + temporaries + 16 * 1024 * 1024))
+    return _pallas.params(
+        *("arbitrary",) * axes,
+        vmem=2 * blocks + temporaries + 16 * 1024 * 1024)
 
 
 def gmm(body, rows, weights, sizes, outs, name: str):
@@ -291,7 +281,7 @@ def gmm(body, rows, weights, sizes, outs, name: str):
         compiler_params=_limit(
             blocks, 3 * 4 * tm * max(max(width for width, _ in outs),
                                      max(r.shape[1] for r in rows))),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_moe_gmm_" + name,
     )(table, bounds, *rows, *weights)
 
@@ -359,7 +349,7 @@ def tgmm(x, y, sizes, acc, name: str):
         compiler_params=_limit(tm * (kb + N) * x.dtype.itemsize
                                + 2 * kb * N * 4, kb * N * 4,
                                1 + int(split > 1)),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_moe_tgmm_" + name,
     )(table, bounds, x, y, acc)
 
@@ -369,8 +359,8 @@ def tgmm(x, y, sizes, acc, name: str):
 def _combine_refusal(rows, out) -> Optional[str]:
     """Which test keeps the Pallas combine off ``out[tok] += rows``; None
     = it runs.  ``rows [R, D]``, ``out [N, D]``."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if rows.ndim != 2 or out.ndim != 2 or rows.shape[1] != out.shape[1]:
         return "rows and out must be rank 2 and of one width"
     if rows.dtype != jnp.float32 or out.dtype != jnp.float32:
@@ -522,6 +512,6 @@ def combine(rows, tok, sizes, out, name: str, fresh=False):
         out_shape=_sds((N, D), jnp.float32, rows, tok, sizes, out),
         input_output_aliases={5: 0},
         compiler_params=_limit(2 * tt * D * 4, ring * ch * D * 4),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_moe_combine_" + name,
     )(tok.astype(jnp.int32), chunks, first, fresh, rows, out)

@@ -107,48 +107,30 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import metrics as _metrics
-from .flash_attention import _sds, _verdict
+from . import _pallas
+from ._pallas import (NT as _NT, TN as _TN, sds as _sds,
+                      verdict as _verdict)
 
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 _SUB = 16           # positions a sub-chunk: a diagonal block's side
 # heads a grid step takes: their chains of small products are independent
 # and fill one another's latencies
 _HEADS = (8, 4, 2, 1)
 _TOGETHER = 2      # heads a tile kernel's loop over a block's heads unrolls
-# a backward grid step at 8 heads of 128 x 128 and a chunk of 64: fourteen
-# blocks double-buffered (5 MB), every head's state (0.5 MB) and the
-# unrolled heads' temporaries
-_VMEM_LIMIT = 64 * 1024 * 1024
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_kda_scan_total",
     "Chunked gated-delta-rule (Kimi Delta Attention) scan calls built, one "
     "per traced call site; kernel is fwd or bwd, path is pallas "
     "(ops/kda_scan.py's kernels) or xla (the same chunked form in "
-    "jax.numpy)",
-    labels=("kernel", "path"))
-
-
-_m_tiles = _metrics.counter(
+    "jax.numpy)")
+_count_tiles = _pallas.kernel_counter(
     "hvd_kda_tiles_total",
     "The chunked gated-delta-rule scan's first pass, every chunk's tiles T "
     "and Aqk with the triangular inverse, built, one per traced call site; "
     "kernel is fwd or bwd (a differentiated scan builds fwd twice: its "
     "backward makes the tiles again), path is pallas (ops/kda_scan.py's "
     "hvd_kda_tiles_fwd / hvd_kda_tiles_bwd) or xla (the same tiles in "
-    "jax.numpy, their backward autodiff's)",
-    labels=("kernel", "path"))
-
-
-def _count(kernel: str, path: str) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path)
-
-
-def _count_tiles(kernel: str, path: str) -> None:
-    if _metrics.ACTIVE:
-        _m_tiles.inc(kernel=kernel, path=path)
+    "jax.numpy, their backward autodiff's)")
 
 
 def _acc(dtype):
@@ -164,8 +146,8 @@ def _head_block(H: int) -> int:
 def _refusal(q, k, v, g, beta, chunk) -> Optional[str]:
     """Which test keeps the Pallas kernels off this call; None = they
     run."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if q.ndim != 4 or v.ndim != 4:
         return "q, k must be [batch, T, heads, K] and v [batch, T, heads, V]"
     Bt, T, H, K = q.shape
@@ -175,12 +157,10 @@ def _refusal(q, k, v, g, beta, chunk) -> Optional[str]:
         return "operands disagree on batch, T, heads or K"
     if T % chunk:
         return f"{T} positions are no multiple of the chunk {chunk}"
-    if not _INTERPRET and (chunk % 16 or K % 128 or V % 128):
+    if not _pallas.INTERPRET and (chunk % 16 or K % 128 or V % 128):
         return (f"chunk {chunk} must be a multiple of 16, {K} key and {V} "
                 "value channels a head of 128")
-    if q.dtype not in (jnp.bfloat16, jnp.float32) or v.dtype != q.dtype:
-        return f"dtype {q.dtype} is neither bfloat16 nor float32"
-    return None
+    return _pallas.dtype_refusal(q.dtype, v.dtype)
 
 
 def supported(q, k, v, g, beta, chunk=64) -> bool:
@@ -280,10 +260,6 @@ def _sum_back(dG, chunk):
 # Plain functions of two-dimensional arrays: the kernels call them on what
 # they load, the plain path under vmap.  q, k, G [C, K]; v, o [C, V]; Tm,
 # Aqk [C, C]; the state transposed, St [V, K].
-
-_NT = (((1,), (1,)), ((), ()))      # a @ b^T
-_TN = (((0,), (0,)), ((), ()))      # a^T @ b
-
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ())), acc=jnp.float32, precision=None):
     return lax.dot_general(a, b, dims, precision=precision,
@@ -641,11 +617,12 @@ def _specs(K, V, chunk, nk, hb, reverse):
 
 
 def _params(carried=True):
-    """``carried``: the chunks hand a state on and run in order."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel",
-                             "arbitrary" if carried else "parallel"),
-        vmem_limit_bytes=_VMEM_LIMIT)
+    """``carried``: the chunks hand a state on and run in order.  (A
+    backward step at 8 heads of 128 x 128 and a chunk of 64: fourteen
+    blocks double-buffered, 5 MB, every head's state, 0.5 MB, and the
+    unrolled heads' temporaries.)"""
+    return _pallas.params("parallel", "parallel",
+                          "arbitrary" if carried else "parallel")
 
 
 def _flat(a):
@@ -668,7 +645,7 @@ def _states_fwd_pallas(q, k, v, G, Tm, Aqk, chunk, scale):
                    _sds((Bt, nk, H, V, K), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((hb, V, K), jnp.float32)],
         compiler_params=_params(),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_kda_chunk_fwd",
     )(*operands)
     return o.reshape(Bt, T, H, V), states
@@ -696,7 +673,7 @@ def _states_bwd_pallas(q, k, v, G, Tm, Aqk, states, do, chunk, scale):
                    _sds(Tm.shape, f32, *operands)],
         scratch_shapes=[pltpu.VMEM((hb, V, K), f32)],
         compiler_params=_params(),
-        interpret=_INTERPRET,
+        interpret=_pallas.INTERPRET,
         name="hvd_kda_chunk_bwd",
     )(*operands)
     return (dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(v.shape),
@@ -834,14 +811,15 @@ def _tiles_bwd_call(q, k, G, beta, dT, dAqk, *, chunk, interpret):
 def _tiles_fwd_pallas(q, k, G, beta, chunk):
     """:func:`_tiles` as a kernel: the same tiles, made in VMEM."""
     _count_tiles("fwd", "pallas")
-    return _tiles_fwd_call(q, k, G, beta, chunk=chunk, interpret=_INTERPRET)
+    return _tiles_fwd_call(q, k, G, beta, chunk=chunk,
+                           interpret=_pallas.INTERPRET)
 
 
 def _tiles_bwd_pallas(q, k, G, beta, dT, dAqk, chunk):
     """-> (dq, dk, dG ``[Bt, T, H, K]``, dbeta ``[Bt, T, H]``), float32."""
     _count_tiles("bwd", "pallas")
     return _tiles_bwd_call(q, k, G, beta, dT, dAqk, chunk=chunk,
-                           interpret=_INTERPRET)
+                           interpret=_pallas.INTERPRET)
 
 
 # ------------------------------------------------------------- public op

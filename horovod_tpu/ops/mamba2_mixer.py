@@ -25,7 +25,7 @@ float32 only in VMEM.
   inverse norm again and writes ``dy``, ``dz`` and ``dw`` (a float32 sum
   over the blocks, as above).  Residuals: the operands.
 
-A kernel walks its block tile by tile, ``_ROWS`` positions by ``_LANES``
+A kernel walks its block tile by tile, ``_ROWS`` positions by ``_CHANNELS``
 channels: few enough vector registers a value that a chain stays in them
 (the whole block's width at once spilled every value: the convolution's
 backward 0.87 ms a call for 0.48, my chip runs, PR 41).  The tiles of
@@ -66,29 +66,22 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import metrics as _metrics
-from .flash_attention import _sds, _verdict
+from . import _pallas
+from ._pallas import sds as _sds, verdict as _verdict
 
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 _BLOCK = 256        # positions a grid step: 2.2 MB of bf16 at 4,352 channels
 _ROWS = 32          # positions a tile of the walk inside a block
-_LANES = 256        # channels a tile
+_CHANNELS = 256     # channels a tile of the walk (two registers' width)
 _TURN = (128, 256)  # positions by channels a tile turned in VMEM
 _HALO = 16          # positions of the second view: one packed bf16 tile
-_VMEM_LIMIT = 64 * 1024 * 1024
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_mixer_kernel_total",
     "Mamba-2 mixer elementwise chains built, one per traced call site; "
     "kernel is conv_fwd, conv_bwd (convolution + SiLU + split), norm_fwd "
     "or norm_bwd (gate + RMSNorm), path is pallas (ops/mamba2_mixer.py's "
     "kernels) or xla (the plain form; its backward is autodiff's and is "
-    "not counted)", labels=("kernel", "path"))
-
-
-def _count(kernel: str, path: str) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path)
+    "not counted)")
 
 
 def _blocks(T: int):
@@ -101,32 +94,25 @@ def _refusal(a, widths, turned=False) -> Optional[str]:
     """Which test keeps the Pallas kernels off an operand ``[Bt, T,
     channels]`` (an array or its shape and dtype) cut into ``widths``, the
     first of them ``turned`` or not; None = they run."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if len(a.shape) != 3 or sum(widths) != a.shape[2]:
         return f"operand must be [batch, T, {sum(widths)} channels]"
-    if not _INTERPRET and any(w % 128 for w in widths):
+    if not _pallas.INTERPRET and any(w % 128 for w in widths):
         return f"channels {tuple(widths)} are no multiples of the 128 lanes"
     bt, rows = _blocks(a.shape[1])
     if a.shape[1] % bt or bt % rows or rows % _HALO:
         return (f"{a.shape[1]} positions are no multiple of the block "
                 f"{_BLOCK} (or of {_HALO})")
-    if turned and not _INTERPRET and bt % 128:
+    if turned and not _pallas.INTERPRET and bt % 128:
         return f"a block of {bt} positions turns into no whole lanes"
-    if a.dtype not in (jnp.bfloat16, jnp.float32):
-        return f"dtype {a.dtype} is neither bfloat16 nor float32"
-    return None
+    return _pallas.dtype_refusal(a.dtype)
 
 
 def supported(a, widths, turned=False) -> bool:
     """True when the Pallas kernels can run an operand of ``a``'s shape
     ``[Bt, T, channels]`` and dtype, cut into ``widths``, on this backend."""
     return _verdict("mamba2_mixer", _refusal(a, tuple(widths), turned), a)
-
-
-def _params(*semantics):
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _partials(a):
@@ -350,8 +336,8 @@ def _statics(T, turned):
     every call site lowering its kernel anew the cell's ``lower_s`` read
     24.1 s for the parent's 17.3, my chip runs, PR 41)."""
     bt, rows = _blocks(T)
-    return dict(bt=bt, rows=rows, lanes=_LANES,
-                turn=_TURN if turned else None, interpret=_INTERPRET)
+    return dict(bt=bt, rows=rows, lanes=_CHANNELS,
+                turn=_TURN if turned else None, interpret=_pallas.INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -371,7 +357,7 @@ def _conv_fwd_call(xBC, conv_w, conv_b, *, sizes, bt, rows, lanes, turn,
         out_shape=[_sds(shape, xBC.dtype, xBC, conv_w, conv_b)
                    for shape in [first[1]] + [(Bt, T, s) for s in sizes[1:]]],
         scratch_shapes=[pltpu.VMEM((1, bt, sizes[0]), xBC.dtype)] * bool(turn),
-        compiler_params=_params("parallel", "parallel"),
+        compiler_params=_pallas.params("parallel", "parallel"),
         interpret=interpret,
         name="hvd_conv_silu_fwd",
     )(xBC, xBC, *_conv_operands(conv_w, conv_b))
@@ -399,7 +385,7 @@ def _conv_bwd_call(xBC, conv_w, conv_b, douts, *, sizes, bt, rows, lanes,
                    _sds((Bt, 8 * (Kc + 1), C), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((8, C), jnp.float32)]
         + [pltpu.VMEM((1, bt, sizes[0]), xBC.dtype)] * bool(turn),
-        compiler_params=_params("parallel", "arbitrary"),
+        compiler_params=_pallas.params("parallel", "arbitrary"),
         interpret=interpret,
         name="hvd_conv_silu_bwd",
     )(*operands)
@@ -569,7 +555,7 @@ def _norm_fwd_call(y, z, w, *, eps, bt, rows, lanes, turn, interpret):
         out_shape=_sds(z.shape, y.dtype, y, z, w),
         scratch_shapes=[pltpu.VMEM((rows, C), jnp.float32)]
         + [pltpu.VMEM((1, bt, C), y.dtype)] * bool(turn),
-        compiler_params=_params("parallel", "parallel"),
+        compiler_params=_pallas.params("parallel", "parallel"),
         interpret=interpret,
         name="hvd_gated_norm_fwd",
     )(y, z, w.astype(jnp.float32)[None])
@@ -594,7 +580,7 @@ def _norm_bwd_call(y, z, w, do, *, eps, bt, rows, lanes, turn, interpret):
                    _sds((Bt, 8, C), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((rows, C), jnp.float32)] * 2
         + [pltpu.VMEM((1, bt, C), y.dtype)] * (2 * bool(turn)),
-        compiler_params=_params("parallel", "arbitrary"),
+        compiler_params=_pallas.params("parallel", "arbitrary"),
         interpret=interpret,
         name="hvd_gated_norm_bwd",
     )(*operands)

@@ -62,27 +62,19 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import metrics as _metrics
-from .flash_attention import _sds, _verdict
+from . import _pallas
+from ._pallas import LANES as _LANES, sds as _sds, verdict as _verdict
 
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 _CHUNK = 128        # positions between two saved states
-_LANES = 128
 _UNROLL = 8         # steps a loop iteration takes: one aligned tile of rows
-# what a backward grid step holds at 512 channels a block and 16 states:
-# a chunk's states (4.2 MB), eleven blocks double-buffered (13 MB)
-_VMEM_LIMIT = 64 * 1024 * 1024
+# the grid (batch, chunks of the sequence, channel blocks): a batch row's
+# chunks hand the state on, its channel blocks share scratch
+_GRID = ("parallel", "arbitrary", "arbitrary")
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_ssm_scan_kernel_total",
     "Selective-scan calls built, one per traced call site; kernel is fwd "
-    "or bwd, path is pallas (ops/selective_scan.py) or xla (lax.scan)",
-    labels=("kernel", "path"))
-
-
-def _count(kernel: str, path: str) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path)
+    "or bwd, path is pallas (ops/selective_scan.py) or xla (lax.scan)")
 
 
 def _channel_block(C: int) -> int:
@@ -92,8 +84,8 @@ def _channel_block(C: int) -> int:
 def _refusal(xs, delta, A, B, C, D) -> Optional[str]:
     """Which test keeps the Pallas kernels off this call; None = they
     run."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if xs.ndim != 3 or B.ndim != 3:
         return "xs must be [batch, T, channels] and B [batch, T, states]"
     Bt, T, Ch = xs.shape
@@ -108,9 +100,7 @@ def _refusal(xs, delta, A, B, C, D) -> Optional[str]:
     if T % min(_CHUNK, T) or min(_CHUNK, T) % _UNROLL:
         return (f"{T} positions are no multiple of the chunk "
                 f"{min(_CHUNK, T)}, or it of {_UNROLL}")
-    if xs.dtype not in (jnp.bfloat16, jnp.float32):
-        return f"dtype {xs.dtype} is neither bfloat16 nor float32"
-    return None
+    return _pallas.dtype_refusal(xs.dtype)
 
 
 def supported(xs, delta, A, B, C, D) -> bool:
@@ -275,12 +265,6 @@ def _specs(Ch, N, chunk, nk, reverse):
     return cb, row, mat, col, vec, bound
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
-
-
 def _scan_fwd_pallas(xs, delta, A, B, C, D):
     """-> (s [Bt, T, Ch] in xs's dtype, the chunks' first states [Bt,
     T / chunk, N, Ch] float32)."""
@@ -299,8 +283,8 @@ def _scan_fwd_pallas(xs, delta, A, B, C, D):
         out_shape=[_sds((Bt, T, Ch), jnp.float32, *operands),
                    _sds((Bt, nk, N, Ch), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((Ch // cb, N, cb), jnp.float32)],
-        compiler_params=_params(),
-        interpret=_INTERPRET,
+        compiler_params=_pallas.params(*_GRID),
+        interpret=_pallas.INTERPRET,
         name="hvd_ssm_scan_fwd",
     )(*operands)
     return s.astype(xs.dtype), bounds
@@ -329,8 +313,10 @@ def _scan_bwd_pallas(xs, delta, A, B, C, D, bounds, ds):
                    _sds((Bt, T, N, _LANES), jnp.float32, *operands, ds)],
         scratch_shapes=[pltpu.VMEM((chunk + 1, N, cb), jnp.float32),
                         pltpu.VMEM((Ch // cb, N, cb), jnp.float32)],
-        compiler_params=_params(),
-        interpret=_INTERPRET,
+        # a step at 512 channels a block and 16 states: a chunk's states
+        # (4.2 MB), eleven blocks double-buffered (13 MB)
+        compiler_params=_pallas.params(*_GRID),
+        interpret=_pallas.INTERPRET,
         name="hvd_ssm_scan_bwd",
     )(xs32, dl32, ds32, at, bb, cc, drow, bounds)
     dD = (ds32 * xs32).sum((0, 1))

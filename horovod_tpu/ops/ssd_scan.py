@@ -65,44 +65,37 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import metrics as _metrics
-from .flash_attention import _sds, _verdict
+from . import _pallas
+from ._pallas import (NT as _NT, TN as _TN, sds as _sds,
+                      verdict as _verdict)
 
-_INTERPRET = False  # flipped by tests to run kernels on CPU
 # heads a grid step takes: whole sublane tiles of float32 rows, 16 where
 # they divide a group (a forward call 0.70 ms for 0.83 at 8, forward and
 # backward 2.71 for 2.91, transposes included: my chip run, PR 40)
 _HEADS = (16, 8)
-# a backward grid step at 16 heads of 64 x 128 and a chunk of 256: every
-# head's state (2 MB), three [Q, Q] float32 tiles, eleven blocks
-# double-buffered (9 MB) and the unrolled heads' temporaries
-_VMEM_LIMIT = 64 * 1024 * 1024
+# the grid (batch, chunks of the sequence, head blocks): a batch row's
+# chunks hand the states on, its head blocks share scratch
+_GRID = ("parallel", "arbitrary", "arbitrary")
 
-_m_kernels = _metrics.counter(
+_count = _pallas.kernel_counter(
     "hvd_ssd_kernel_total",
     "Chunked state-space (Mamba-2) scan calls built, one per traced call "
     "site; kernel is fwd or bwd, path is pallas (ops/ssd_scan.py's "
-    "kernels) or xla (the same chunked form in jax.numpy)",
-    labels=("kernel", "path"))
-
-
-def _count(kernel: str, path: str) -> None:
-    if _metrics.ACTIVE:
-        _m_kernels.inc(kernel=kernel, path=path)
+    "kernels) or xla (the same chunked form in jax.numpy)")
 
 
 def _head_block(H: int, G: int) -> int:
     """Heads a grid step takes: they share a group's ``B`` and ``C``."""
     R = H // G
-    sizes = _HEADS + (4, 2, 1) if _INTERPRET else _HEADS
+    sizes = _HEADS + (4, 2, 1) if _pallas.INTERPRET else _HEADS
     return next((hb for hb in sizes if R % hb == 0), 0)
 
 
 def _refusal(x, delta, A, B, C, D, chunk) -> Optional[str]:
     """Which test keeps the Pallas kernels off this call; None = they
     run."""
-    if not _INTERPRET and jax.default_backend() != "tpu":
-        return f"backend is {jax.default_backend()}, not tpu"
+    if (why := _pallas.off_chip()):
+        return why
     if x.ndim != 4 or B.ndim != 4:
         return ("x must be [batch, T, heads, head channels] and B [batch, "
                 "T, groups, states]")
@@ -115,12 +108,10 @@ def _refusal(x, delta, A, B, C, D, chunk) -> Optional[str]:
         return f"{T} positions are no multiple of the chunk {chunk}"
     if not _head_block(H, G):
         return f"{H // G} heads a group are no multiple of {_HEADS[-1]}"
-    if not _INTERPRET and (chunk % 128 or N % 128 or P % 8):
+    if not _pallas.INTERPRET and (chunk % 128 or N % 128 or P % 8):
         return (f"chunk {chunk} and {N} states must be multiples of 128, "
                 f"{P} channels a head of 8")
-    if x.dtype not in (jnp.bfloat16, jnp.float32):
-        return f"dtype {x.dtype} is neither bfloat16 nor float32"
-    return None
+    return _pallas.dtype_refusal(x.dtype)
 
 
 def supported(x, delta, A, B, C, D, chunk=256) -> bool:
@@ -280,10 +271,6 @@ def _scan_bwd_xla(x, delta, A, B, C, states, dy, chunk):
 # columns (1, 1, Q, hb) of [Bt, H / hb, T, hb]; B and C (1, Q, N) of
 # [Bt, T, G N]; the saved states (1, 1, hb, P, N) of [Bt, T / Q, H, P, N].
 
-_NT = (((1,), (1,)), ((), ()))      # a @ b^T
-_TN = (((0,), (0,)), ((), ()))      # a^T @ b
-
-
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
@@ -431,12 +418,6 @@ def _specs(H, P, G, N, chunk, nk, hb, reverse):
     return per_group, tile, row, col, grp, bound
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
-
-
 def _chunks_fwd_pallas(xt, delta, A, B, C, chunk):
     """-> (y^T without ``D x`` [Bt, H, P, T], the chunks' first states)."""
     Bt, H, P, T = xt.shape
@@ -455,8 +436,8 @@ def _chunks_fwd_pallas(xt, delta, A, B, C, chunk):
                    _sds((Bt, nk, H, P, N), jnp.float32, *operands)],
         scratch_shapes=[pltpu.VMEM((H // hb, hb, P, N), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
-        compiler_params=_params(),
-        interpret=_INTERPRET,
+        compiler_params=_pallas.params(*_GRID),
+        interpret=_pallas.INTERPRET,
         name="hvd_ssd_chunk_fwd",
     )(*operands)
 
@@ -491,8 +472,11 @@ def _chunks_bwd_pallas(xt, delta, A, B, C, states, dyt, chunk):
         scratch_shapes=[pltpu.VMEM((H // hb, hb, P, N), f32),
                         pltpu.VMEM((chunk, chunk), f32),
                         pltpu.VMEM((chunk, chunk), f32)],
-        compiler_params=_params(),
-        interpret=_INTERPRET,
+        # a step at 16 heads of 64 x 128 and a chunk of 256: every head's
+        # state (2 MB), three [Q, Q] float32 tiles, eleven blocks
+        # double-buffered (9 MB) and the unrolled heads' temporaries
+        compiler_params=_pallas.params(*_GRID),
+        interpret=_pallas.INTERPRET,
         name="hvd_ssd_chunk_bwd",
     )(*operands)
     rows = lambda a: jnp.transpose(a, (0, 2, 1))

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..scopes import SCOPE_SYNC_BN
+
 
 def sync_batch_stats(x, axes: Sequence[int] = (0, 1, 2),
                      axis_name: Optional[str] = None
@@ -31,8 +33,6 @@ def sync_batch_stats(x, axes: Sequence[int] = (0, 1, 2),
     local = jnp.stack([jnp.sum(x32, axes), jnp.sum(x32 * x32, axes)])
     count = x.size / local[0].size
     if axis_name is not None:
-        # training imports the models, which import this module
-        from ..training import SCOPE_SYNC_BN
         with jax.named_scope(SCOPE_SYNC_BN):
             local = lax.psum(local, axis_name)
         count = count * lax.axis_size(axis_name)

@@ -29,36 +29,15 @@ import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# Names the jitted data-parallel steps put on their parts
-# (``jax.named_scope``: metadata only, the compiled program is the same).
-# They reach ``compiled.as_text()`` as ``op_name="jit(..)/../<name>/.."``
-# and xprof's op names: the forward pass reads ``jvp(hvd_forward)``, the
-# backward pass ``transpose(jvp(hvd_forward))``.
-SCOPE_FORWARD = "hvd_forward"      # model and loss, inside the differentiated fn
-SCOPE_REDUCE = "hvd_reduce"        # explicit gradient scaling / pmeans
-SCOPE_OPTIMIZER = "hvd_optimizer"  # optimizer.update + apply_updates
-SCOPE_SYNC_BN = "hvd_sync_bn"      # SyncBN's psum of the batch statistics
-# The model's own parts, opened in ``models/`` where the work is and so
-# under whichever step builder's ``hvd_forward``: a layer is the union of
-# its sublayers' scopes, and what is left under ``hvd_forward`` alone is
-# the residual adds and what an ``objective=`` from outside computes.
-SCOPE_EMBED = "hvd_embed"          # the lookups (and BERT's embedding norm)
-SCOPE_ATTENTION = "hvd_attention"  # norm, projections, RoPE, kernels, tp's psum
-SCOPE_MLP = "hvd_mlp"              # norm + feed-forward, dense or routed experts
-SCOPE_HEAD = "hvd_head"            # final norm, head product, loss; ResNet's pool + fc
-SCOPE_STEM = "hvd_stem"            # ResNet: conv1 + BN + max-pool
-SCOPE_STAGE = "hvd_stage{}"        # ResNet: a stage's blocks, ``.format(i)``
-SCOPE_SSM_MIXER = "hvd_ssm_mixer"  # models/hybrid.py's mixers, after norm1
-SCOPE_GMU = "hvd_gmu"
-SCOPE_DIFF_ATTENTION = "hvd_diff_attention"
-SCOPE_SSD_MIXER = "hvd_ssd_mixer"  # the Mamba-2 mixer: norm1, projections, conv, scan, gated norm
-SCOPE_SSD_SCAN = "hvd_ssd_scan"    # inside it: ops/ssd_scan.py's call, whatever computes it
-SCOPE_KDA_MIXER = "hvd_kda_mixer"  # Kimi Delta Attention: norm1, projections, conv, scan, gated norm
-SCOPE_KDA_SCAN = "hvd_kda_scan"    # inside it: ops/kda_scan.py's call, whatever computes it
-
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec
 from .parallel.mesh import ParallelMesh
+# the names the steps put on their parts, handed on under this module's name
+from .scopes import (  # noqa: F401
+    SCOPE_FORWARD, SCOPE_REDUCE, SCOPE_OPTIMIZER, SCOPE_SYNC_BN, SCOPE_EMBED,
+    SCOPE_ATTENTION, SCOPE_MLP, SCOPE_HEAD, SCOPE_STEM, SCOPE_STAGE,
+    SCOPE_SSM_MIXER, SCOPE_GMU, SCOPE_DIFF_ATTENTION, SCOPE_SSD_MIXER,
+    SCOPE_SSD_SCAN, SCOPE_KDA_MIXER, SCOPE_KDA_SCAN)
 
 
 @dataclasses.dataclass

@@ -61,3 +61,18 @@ def thvd(hvd):
     """Torch adapter over the initialized engine."""
     import horovod_tpu.torch as thvd
     return thvd
+
+
+@pytest.fixture
+def pallas_interpret(monkeypatch):
+    """The Pallas kernels of every file under ``ops/`` run here, on the
+    CPU, interpreted: the one flag they all read, on for the test that
+    asks for the fixture.  What it gives is the switch, for a case that
+    compares both paths: ``pallas_interpret(path == "pallas")``."""
+    from horovod_tpu.ops import _pallas
+
+    def switch(on=True):
+        monkeypatch.setattr(_pallas, "INTERPRET", bool(on))
+
+    switch()
+    return switch

@@ -12,6 +12,7 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from _helpers import kernels_by_place as _kernels_by_place
 from horovod_tpu.models import bert
 
 
@@ -126,3 +127,38 @@ def test_dp_finetune_loss_drops(cfg, hvd):
         if first is None:
             first = float(loss)
     assert float(loss) < first * 0.7, (first, float(loss))
+
+
+def test_bert_stack_saves_the_packed_kernels_residuals(monkeypatch,
+                                                       pallas_interpret):
+    """``models/bert.py``'s remat'd encoder keeps dots and the packed
+    kernel's two named values: one forward kernel, in the forward scan,
+    and the same loss and gradients, to the bit, as under dots alone
+    (which reruns the kernel in the remat body)."""
+    from horovod_tpu.models import llama
+    cfg = bert.BertConfig(vocab_size=64, d_model=128, n_layers=2, n_heads=2,
+                          d_ff=128, max_seq_len=128)
+    params = bert.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 128)))
+    labels = jnp.asarray([0, 1])
+
+    def grads():
+        return jax.jit(jax.value_and_grad(lambda p: bert.loss_fn(
+            p, tokens, labels, cfg, llama.ParallelSpec())))
+
+    kept = grads()
+    placed = _kernels_by_place(kept, params)
+    assert sorted((name, "remat2" in p) for p, name in placed) == [
+        ("hvd_flash_bwd", True), ("hvd_flash_fwd", False)]
+    got = kept(params)
+    cp = jax.checkpoint_policies
+    monkeypatch.setattr(cp, "save_only_these_names",
+                        lambda *names: cp.nothing_saveable)
+    dots_only = grads()
+    assert sorted((name, "remat2" in p) for p, name in _kernels_by_place(
+        dots_only, params)) == [("hvd_flash_bwd", True),
+                                ("hvd_flash_fwd", False),
+                                ("hvd_flash_fwd", True)]
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(dots_only(params))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

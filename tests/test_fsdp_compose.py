@@ -19,6 +19,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from _helpers import mesh_map
 from horovod_tpu.ops.engine import TensorTableEntry
 from horovod_tpu.ops.fusion import (EntrySig, canonicalize_spec,
                                     plan_fusion, spec_axes, spec_shift)
@@ -71,6 +72,12 @@ def test_make_spec_plan_infers_model_axes_and_env(monkeypatch):
     # all-replicated spec trees can still name the mesh's model axes
     # via the validated env knob
     monkeypatch.setenv("HOROVOD_MODEL_AXES", MODEL)
+    from horovod_tpu import runtime
+    state = runtime._state()
+    if state.config is not None:    # an earlier test of this worker called
+        import dataclasses          # hvd.init(): the knob is its config's
+        monkeypatch.setattr(state, "config", dataclasses.replace(
+            state.config, model_axes=MODEL))
     plan2 = make_spec_plan({"n": P()}, DATA)
     assert plan2.model_axes == (MODEL,)
     with pytest.raises(ValueError, match="data axis"):
@@ -244,7 +251,7 @@ def _full_grads(n_steps, n_dev):
 
 
 def _run_spec(D, sharded, grads_steps, k=2):
-    """Nested-pmap (data=D, model=M) spec-aware trajectory; returns
+    """Spec-aware trajectory on a (data=D, model=M) mesh; returns
     (params at replica (0,0), per-chip inner-state bytes)."""
     tx = DistributedOptimizer(adamw_lp(1e-2),
                               axis_name=DATA, threshold_bytes=64,
@@ -271,10 +278,9 @@ def _run_spec(D, sharded, grads_steps, k=2):
     stacked = [
         {kk: g[kk].reshape((D, M) + g[kk].shape[1:]) for kk in g}
         for g in grads_steps]
-    f = jax.pmap(jax.pmap(prog, axis_name=MODEL, in_axes=(0,)),
-                 axis_name=DATA, in_axes=(0,))
+    f = mesh_map(prog, (D, M), (DATA, MODEL), in_axes=(0,))
     p_out, nb = f(stacked)
-    return (jax.tree_util.tree_map(lambda a: a[0, 0], p_out),
+    return (jax.tree_util.tree_map(lambda a: np.asarray(a)[0, 0], p_out),
             int(np.asarray(nb)[0, 0]))
 
 

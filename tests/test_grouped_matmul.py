@@ -2,9 +2,9 @@
 in interpret mode on the CPU, against ``lax.ragged_dot`` and a dense
 product a group, for group layouts that break tilings; the combine
 against ``.at[].add``; the expert layer's gradients through them against
-the ``ragged_dot`` path's; which path a program's shapes take.  (That Mosaic takes the kernels at the
-benchmark's shapes is tested with the other kernels' compiles, in
-tests/test_flash_attention.py: one file describes the chip.)"""
+the ``ragged_dot`` path's; which path a program's shapes take; and that
+Mosaic takes the kernels at the benchmark's shapes, compiled for a v5e
+that is described, not attached."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 from jax import lax
 
+from _helpers import described_chip as _described_chip
 from horovod_tpu import metrics
 from horovod_tpu.models import moe
 from horovod_tpu.ops import grouped_matmul as gm
@@ -30,11 +31,6 @@ LAYOUTS = {
 CASES = [pytest.param(sizes, dtype, id=f"{name}-{jnp.dtype(dtype).name}")
          for name, sizes in LAYOUTS.items()
          for dtype in (jnp.float32, jnp.bfloat16)]
-
-
-@pytest.fixture
-def interpret(monkeypatch):
-    monkeypatch.setattr(gm, "_INTERPRET", True)
 
 
 def _operands(sizes, dtype, transposed=False):
@@ -63,7 +59,7 @@ def _check_rows(y, x, w, sizes, dtype):
 
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
-def test_gmm_is_ragged_dot(sizes, dtype, interpret):
+def test_gmm_is_ragged_dot(sizes, dtype, pallas_interpret):
     x, w, sizes = _operands(sizes, dtype)
     y, = jax.jit(lambda x, w, s: gm.gmm(
         lambda a, b: (gm.dot(a, b),), (x,), (w,), s, [(N, dtype)], "t"))(
@@ -72,7 +68,7 @@ def test_gmm_is_ragged_dot(sizes, dtype, interpret):
 
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
-def test_gmm_reads_weights_transposed(sizes, dtype, interpret):
+def test_gmm_reads_weights_transposed(sizes, dtype, pallas_interpret):
     x, w, sizes = _operands(sizes, dtype, transposed=True)
     y, = jax.jit(lambda x, w, s: gm.gmm(
         lambda a, b: (gm.dot(a, b, transposed=True),), (x,), (w,), s,
@@ -81,7 +77,7 @@ def test_gmm_reads_weights_transposed(sizes, dtype, interpret):
 
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
-def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, interpret):
+def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, pallas_interpret):
     x, _, sizes = _operands(sizes, dtype)
     live = (np.arange(R) < int(sizes.sum()))[:, None]
     y = jnp.where(live, jax.random.normal(jax.random.key(1), (R, N)),
@@ -100,7 +96,7 @@ def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, interpret):
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
 def test_tgmm_walks_a_large_accumulator_in_blocks_of_its_rows(
-        sizes, dtype, interpret, monkeypatch):
+        sizes, dtype, pallas_interpret, monkeypatch):
     """An accumulator that, in and out and twice, does not fit a grid step
     (4,096 x 1,280 on the chip; here the step's room is cut to force it) is
     walked in two blocks of its rows over a grid (blocks, visits), to the
@@ -123,7 +119,7 @@ def test_tgmm_walks_a_large_accumulator_in_blocks_of_its_rows(
     assert gm._tgmm_split(8192, 2048, 4) == 8 and gm._tgmm_split(16384, 4096, 4) == 0
 
 
-def test_a_body_of_two_products_and_an_epilogue(interpret):
+def test_a_body_of_two_products_and_an_epilogue(pallas_interpret):
     """What the expert layer asks of ``gmm``: two weights a group, two
     outputs, one of them a column, from rows and a column of weights."""
     x, w, sizes = _operands(LAYOUTS["straddling-tile-edges"], jnp.float32)
@@ -199,7 +195,7 @@ def _combine_operands(layout, carry):
                          ids=["zero", "a-carry", "fresh"])
 @pytest.mark.parametrize("layout", COMBINE_LAYOUTS.values(),
                          ids=COMBINE_LAYOUTS.keys())
-def test_combine_is_scatter_add(layout, carry, interpret):
+def test_combine_is_scatter_add(layout, carry, pallas_interpret):
     """To the last bit against the rows added in their order (the
     kernel's order), within 2 ulp of the sum's magnitude against
     ``.at[].add`` whatever order XLA takes; rows past the sizes' sum add
@@ -249,8 +245,8 @@ def test_the_combines_plan_lists_each_run_in_chunks():
     ("a-step-past-the-vmem", (1536, 16384, jnp.float32), (1024, 16384), "VMEM"),
 ])
 def test_combine_refusals_fall_back_to_scatter_add(why, rows, out, reason,
-                                                   monkeypatch):
-    monkeypatch.setattr(gm, "_INTERPRET", why != "cpu-backend")
+                                                   pallas_interpret):
+    pallas_interpret(why != "cpu-backend")
     x = jax.ShapeDtypeStruct(rows[:2], rows[2])
     o = jax.ShapeDtypeStruct(out, jnp.float32)
     assert reason in gm._combine_refusal(x, o)
@@ -317,7 +313,8 @@ def _grew(before):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-def test_held_experts_gradients_through_the_kernels(dtype, monkeypatch):
+def test_held_experts_gradients_through_the_kernels(dtype, pallas_interpret):
+    pallas_interpret(False)
     loss, args, pairs = _layer_operands(dtype, D=256, F=128)
     grad = lambda: jax.jit(jax.value_and_grad(
         loss, (0, 1, 2, 3, 4), has_aux=True))(*args)
@@ -327,8 +324,8 @@ def test_held_experts_gradients_through_the_kernels(dtype, monkeypatch):
     if metrics.ACTIVE:         # forward 3, made again 3, autodiff's 3 + 3
         assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3,
                                  ("combine", "xla"): 2}
-    monkeypatch.setattr(gm, "_INTERPRET", True)
     before = _kernel_counts()
+    pallas_interpret()
     (got, computed), grads = grad()
     assert computed == pairs
     if metrics.ACTIVE:         # gate_up, down; gate_up, dh, dx; three tgmm
@@ -343,7 +340,7 @@ def test_held_experts_gradients_through_the_kernels(dtype, monkeypatch):
         assert np.abs(a - b).max() <= tol * np.abs(b).max()
 
 
-def test_toy_widths_on_the_cpu_take_ragged_dot(interpret):
+def test_toy_widths_on_the_cpu_take_ragged_dot(pallas_interpret):
     """Widths that are no multiples of 128 run XLA's products even where
     the kernels could be interpreted: the path is chosen from the shapes."""
     loss, args, _ = _layer_operands(jnp.float32, D=32, F=16)
@@ -362,8 +359,9 @@ def test_toy_widths_on_the_cpu_take_ragged_dot(interpret):
     ("weights-of-another-dtype", (1024, 256, jnp.bfloat16), (4, 256, 128), "rows' dtype"),
     ("a-step-past-the-vmem", (1024, 8192, jnp.float32), (4, 8192, 2048), "VMEM"),
 ])
-def test_refusals_name_their_reason(why, rows, weights, reason, monkeypatch):
-    monkeypatch.setattr(gm, "_INTERPRET", why != "cpu-backend")
+def test_refusals_name_their_reason(why, rows, weights, reason,
+                                    pallas_interpret):
+    pallas_interpret(why != "cpu-backend")
     x = jax.ShapeDtypeStruct(rows[:2], rows[2])
     w = jax.ShapeDtypeStruct(
         weights, jnp.float32 if why == "weights-of-another-dtype" else rows[2])
@@ -371,3 +369,61 @@ def test_refusals_name_their_reason(why, rows, weights, reason, monkeypatch):
     ok = jax.ShapeDtypeStruct((1024, 256), jnp.bfloat16)
     w = jax.ShapeDtypeStruct((4, 256, 128), jnp.bfloat16)
     assert gm.supported(ok, w, w) == (why != "cpu-backend")
+
+
+# ----------------------------------------------------- for the chip
+
+@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
+    """Mosaic takes the expert layer's grouped products at the benchmark's
+    SDAR cell: a chunk of 24,576 rows of width 2,048 over 16 held experts
+    of width 768 (gate and up from one read of a tile, 1,536 columns),
+    bf16: ``gmm`` with the weights as stored and transposed, ``tgmm`` with
+    a float32 ``[2048, 768]`` accumulator a group, in and out; and the
+    combine of the chunk's float32 rows into 16,384 tokens (24,576 token
+    ids in scalar memory, a ring of copies from HBM), with no scatter
+    left beside it.  Compiled here for a v5e that is described, not
+    attached."""
+    _grouped_kernels_lower(call, monkeypatch, 24576, 2048, 768, 16, 16384)
+
+
+@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+def test_grouped_matmul_kernels_lower_at_a_hidden_size_of_4096(call, monkeypatch):
+    """The same at the solar-open2-250b cell: a chunk of 2,560 rows of width
+    4,096 over 8 held experts of width 1,280; ``tgmm`` walks its float32
+    ``[4096, 1280]`` accumulator in two blocks of rows, since in and out and
+    twice it is 84 MB and a grid step may hold 64."""
+    assert gm._tgmm_split(4096, 1280, 2) == 2
+    _grouped_kernels_lower(call, monkeypatch, 2560, 4096, 1280, 8, 8192)
+
+
+def _grouped_kernels_lower(call, monkeypatch, R, D, F, E, tokens):
+    one_chip = _described_chip(monkeypatch)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    xs, wt, sizes = (sds((R, D), jnp.bfloat16), sds((R,), jnp.float32),
+                     sds((E,), jnp.int32))
+    wg, wu, wd = (sds(s, jnp.bfloat16) for s in ((E, D, F), (E, D, F),
+                                                 (E, F, D)))
+    assert gm.supported(xs, wg, wu, wd)
+    if call == "forward":
+        text = jax.jit(moe._expert_ffn).lower(
+            xs, wg, wu, wd, wt, sizes).compile().as_text()
+        names = ("hvd_moe_gmm_gate_up", "hvd_moe_gmm_down")
+    elif call == "combine":
+        text = jax.jit(lambda *a: gm.combine(*a, "out"),
+                       donate_argnums=3).lower(
+            sds((R, D), jnp.float32), sds((R,), jnp.int32), sizes,
+            sds((tokens, D), jnp.float32)).compile().as_text()
+        names = ("hvd_moe_combine_out",)
+        assert "scatter" not in text
+    else:
+        held = [sds(w.shape, jnp.float32) for w in (wg, wu, wd)]
+        text = jax.jit(moe._expert_ffn_grads, donate_argnums=(7, 8, 9)).lower(
+            xs, wg, wu, wd, wt, sizes, sds((R, D), jnp.float32),
+            *held).compile().as_text()
+        names = ("hvd_moe_gmm_gate_up", "hvd_moe_gmm_dh", "hvd_moe_gmm_dx",
+                 "hvd_moe_tgmm_gate", "hvd_moe_tgmm_up", "hvd_moe_tgmm_down")
+    for name in names:
+        assert name in text
+    assert "ragged-dot" not in text

@@ -47,11 +47,12 @@ def _dense(q, k, v, lp, lam0, live):
 @pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
 @pytest.mark.parametrize("kind", ["window", "full", "cross"])
 def test_differential_attention_follows_its_dense_formula(kind, interpret,
-                                                          monkeypatch):
+                                                          monkeypatch,
+                                                          pallas_interpret):
     """Window, causal and cross; forward and every gradient; through
     ``hvd_flash_fwd``/``dq``/``dkv`` with values twice as wide as queries
     and keys, and through the XLA path."""
-    monkeypatch.setattr(fa, "_INTERPRET", interpret)
+    pallas_interpret(interpret)
     monkeypatch.setattr(fa, "_BLOCK", 128)
     cfg, T, B = _cfg(layer_kinds=(kind if kind != "cross" else "full",)), 256, 2
     ks = jax.random.split(jax.random.key(4), 10)
@@ -88,7 +89,7 @@ def test_differential_attention_follows_its_dense_formula(kind, interpret,
 
 
 def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
-        monkeypatch):
+        monkeypatch, pallas_interpret):
     """The lowered text (``lower().as_text()``) of a remat'd trunk of the
     three attention kinds at the phi widths (``head_dim`` 64, values of
     128, groups of two), kernels interpreted, forward and backward, as it
@@ -105,7 +106,6 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     sub-tiles); the block specs and everything around the kernels as they
     were."""
     import hashlib
-    monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_BLOCK", 128)
     cfg = _cfg(n_layers=3, layer_kinds=("window", "full", "cross"),
                layer_ids=(1, 17, 19), dtype=jnp.bfloat16, remat=True)
@@ -117,14 +117,14 @@ def test_lowered_trunk_text_is_unchanged_by_the_kernels_second_layout(
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == "85265b68d64b884c"
 
 
-def test_mamba_trunk_is_untouched_by_the_mamba2_mixers_kernels(monkeypatch):
+def test_mamba_trunk_is_untouched_by_the_mamba2_mixers_kernels(
+        monkeypatch, pallas_interpret):
     """``_mamba`` keeps ``_conv_silu`` and its own gate: with the Mamba-2
     mixer's kernels switched on (interpret), tracing a remat'd trunk of
     ``mamba`` and ``gmu`` layers forward and backward counts nothing in
     ``hvd_mixer_kernel_total`` and calls neither ``hvd_conv_silu_*`` nor
     ``hvd_gated_norm_*``, in the jaxpr and in the lowered text."""
     from horovod_tpu.ops import mamba2_mixer as mm
-    monkeypatch.setattr(mm, "_INTERPRET", True)
     monkeypatch.setattr(mm, "_BLOCK", 32)
     cfg = _cfg(d_model=128, n_heads=2, n_kv_heads=2, n_layers=3,
                layer_kinds=("mamba", "mamba", "gmu"), layer_ids=(0, 2, 4),
@@ -156,8 +156,7 @@ def test_mamba_trunk_is_untouched_by_the_mamba2_mixers_kernels(monkeypatch):
                                        ("norm_fwd", "pallas"): 1}
 
 
-def test_masked_kernels_refuse_what_they_cannot_hold(monkeypatch):
-    monkeypatch.setattr(fa, "_INTERPRET", True)
+def test_masked_kernels_refuse_what_they_cannot_hold(pallas_interpret):
     q = jnp.zeros((1, 128, 2, 64))
     k = jnp.zeros((1, 128, 1, 64))
     assert fa._refusal(q, k, jnp.zeros((1, 128, 1, 128))) is None
@@ -232,29 +231,9 @@ def test_trunk_of_kinds_takes_no_positions_mask_or_model_parallel_axis():
         llama.hidden(params, tokens, cfg, llama.ParallelSpec(tp_axis="tp"))
 
 
-# ------------------------------- the Mamba-2 hybrids (granite-4.0-h-micro)
-# The kinds ``mamba2`` and ``attention`` under the config's frame (RMSNorm,
-# the four multipliers) against the configuration's plain reference, which
-# walks the recurrence position by position: benchmark/configs/
-# granite-4.0-h-micro/reference.py, at its toy sizes.
-
-def _granite():
-    import importlib.util
-    import json
-    import pathlib
-    cdir = (pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-            / "configs" / "granite-4.0-h-micro")
-    cfg = json.loads((cdir / "config.json").read_text())
-    cfg.update(cfg["toy"])
-    cfg["dtype"]["compute"] = "float32"
-    mods = []
-    for name in ("reference", "adapter"):
-        spec = importlib.util.spec_from_file_location(
-            f"granite_{name}", cdir / f"{name}.py")
-        mods.append(importlib.util.module_from_spec(spec))
-        spec.loader.exec_module(mods[-1])
-    return cfg, mods[0], mods[1]
-
+# (The Mamba-2 hybrids, granite-4.0-h-micro's kinds against its plain
+# reference, are tests/test_hybrid_granite.py; the two helpers below count
+# the mixer's kernels for that file and this one.)
 
 def _mixer_counts():
     family = metrics.registry().to_dict().get("hvd_mixer_kernel_total", {})
@@ -266,130 +245,6 @@ def _mixer_grew(before):
     after = _mixer_counts()
     return {k: after[k] - before.get(k, 0) for k in after
             if after[k] != before.get(k, 0)}
-
-
-def _granite_losses(cfg, ref, adapter, lcfg=None, grads=False):
-    """(the program's, the reference's) loss on seeded weights and rows,
-    with the gradients leaf by leaf under the reference's names where
-    asked."""
-    import dataclasses
-    key = jax.random.key(7)
-    w = ref.make_weights(cfg, key)
-    batch = ref.make_samples(cfg, jax.random.fold_in(key, 1), 2)
-    lcfg = lcfg or adapter.program_config(cfg)
-    assert dataclasses.is_dataclass(lcfg)
-    fn = lambda p: llama.loss_fn(p, *batch, lcfg, llama.ParallelSpec())
-    with jax.default_matmul_precision("highest"):
-        want = jax.value_and_grad(lambda w_: ref.loss(cfg, w_, batch))(w)
-        got = jax.value_and_grad(fn)(adapter._to_program(w, cfg))
-    if not grads:
-        return got[0], want[0]
-    return (got[0], adapter._to_flat(got[1], cfg)), want
-
-
-@pytest.mark.parametrize("interpret,mixer", [
-    (False, False), (True, False), (True, True)],
-    ids=["xla", "kernels", "mixer-kernels"])
-def test_mamba2_and_attention_trunk_follows_the_plain_reference(interpret,
-                                                                mixer,
-                                                                monkeypatch):
-    """Loss and every leaf's gradient: mamba2, attention, mamba2 under
-    RMSNorm and the four multipliers; the chunked scan in jax.numpy and
-    through ``hvd_ssd_chunk_fwd`` / ``hvd_ssd_chunk_bwd`` in interpret
-    mode, four chunks a row; with ``mixer`` the convolution and the gate
-    through ``ops/mamba2_mixer.py``'s four kernels too, two blocks of
-    positions a row (160 convolved channels cut 128, 16, 16), and ``x``
-    and ``y`` handed on turned through ``ssd_scan_turned``."""
-    from horovod_tpu.ops import mamba2_mixer as mm
-    from horovod_tpu.ops import ssd_scan as sd
-    monkeypatch.setattr(sd, "_INTERPRET", interpret)
-    monkeypatch.setattr(mm, "_INTERPRET", mixer)
-    for name, size in (("_BLOCK", 32), ("_ROWS", 16), ("_TURN", (16, 64))):
-        monkeypatch.setattr(mm, name, size)
-    mixer_before = _mixer_counts()
-    cfg, ref, adapter = _granite()
-    lcfg = adapter.program_config(cfg)
-    assert lcfg.layer_kinds == ("mamba2", "attention", "mamba2")
-    assert [(r[0], r[1], len(r[2])) for r in hybrid._runs(lcfg)] == [
-        ("mamba2", 0, 1), ("attention", 0, 1), ("mamba2", 1, 1)]
-    before = metrics.registry().to_dict().get("hvd_layer_kind_total", {})
-    (loss, grads), (want_loss, want) = _granite_losses(cfg, ref, adapter,
-                                                       grads=True)
-    if metrics.ACTIVE:
-        count = lambda fam: {s["labels"]["kind"]: s["value"]
-                             for s in fam.get("series", [])}
-        after = count(metrics.registry().to_dict()["hvd_layer_kind_total"])
-        grew = {k: n - count(before).get(k, 0) for k, n in after.items()}
-        assert {k: n for k, n in grew.items() if n} == {"mamba2": 2,
-                                                        "attention": 1}
-        # every chain of the mamba2 layers on the one path (a traced
-        # call site counts; the plain form's backward is autodiff's)
-        path = "pallas" if mixer else "xla"
-        assert set(_mixer_grew(mixer_before)) == {
-            (k, path) for k in (("conv_fwd", "conv_bwd", "norm_fwd",
-                                 "norm_bwd") if mixer
-                                else ("conv_fwd", "norm_fwd"))}
-    assert abs(float(loss - want_loss)) < 2e-6 * float(want_loss)
-    assert set(grads) == set(want) == set(ref.weight_shapes(cfg))
-    for name in want:
-        np.testing.assert_allclose(
-            grads[name], want[name], rtol=2e-3,
-            atol=2e-4 * float(jnp.abs(want[name]).max()), err_msg=name)
-    assert llama.count_params(lcfg) == sum(
-        int(np.prod(s)) for s in ref.weight_shapes(cfg).values())
-
-
-@pytest.mark.parametrize("field,family", [
-    ("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
-    ("attention_multiplier", 0.0), ("logits_scaling", 1.0)])
-def test_each_multiplier_is_the_configs_and_not_the_familys(field, family):
-    """A multiplier dropped, or left at what the other trunks compute,
-    moves the loss away from the reference's; each as published keeps it
-    there."""
-    import dataclasses
-    cfg, ref, adapter = _granite()
-    # weights large enough for the logits to say something: at the
-    # configuration's ranges a toy's loss is log(vocabulary) whatever the
-    # trunk computes
-    cfg.update(initializer_range=0.3, residual_out_range=0.3)
-    lcfg = adapter.program_config(cfg)
-    assert getattr(llama.LlamaConfig(), field) == family
-    assert getattr(lcfg, field) == cfg[field] != family
-    got, want = _granite_losses(cfg, ref, adapter)
-    assert abs(float(got - want)) < 1e-5 * float(want)
-    dropped, _ = _granite_losses(
-        cfg, ref, adapter, dataclasses.replace(lcfg, **{field: family}))
-    assert abs(float(dropped - want)) > 1e-4 * float(want), field
-    # the trunk of identical layers computes none of them, and says so
-    with pytest.raises(ValueError, match="trunk of several kinds"):
-        llama.LlamaConfig(**{field: cfg[field]})
-
-
-def test_the_frame_is_the_configs_rmsnorm_has_no_bias():
-    cfg = _cfg(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, n_layers=2,
-               layer_kinds=("mamba2", "attention"), ssm_heads=8, ssm_state=16,
-               ssm_chunk=16, trunk_norm="rmsnorm")
-    params = llama.init_params(cfg, jax.random.key(0))
-    assert "final_norm_bias" not in params
-    assert set(params["layers"]["attention"]) == {
-        "norm1_w", "norm2_w", "w1", "w2", "wqkv", "wo"}
-    assert set(params["layers"]["mamba2"]) == {
-        "norm1_w", "norm2_w", "w1", "w2", "in_proj", "conv_w", "conv_b",
-        "dt_bias", "A_log", "D", "gate_norm", "out_proj"}
-    assert params["layers"]["mamba2"]["in_proj"].shape == (
-        1, 64, 128 + 128 + 2 * 16 + 8)
-    A = np.exp(np.asarray(params["layers"]["mamba2"]["A_log"]))
-    assert (1 <= A).all() and (A <= 16).all() and A.std() > 0
-    assert llama.count_params(cfg) == sum(
-        x.size for x in jax.tree_util.tree_leaves(params))
-    # the same kinds under LayerNorm carry its biases: the frame is a field
-    biased = llama.init_params(_cfg(**{**vars(cfg), "trunk_norm": "layernorm"}),
-                               jax.random.key(0))
-    assert "final_norm_bias" in biased and "norm1_b" in biased["layers"]["mamba2"]
-    with pytest.raises(ValueError, match="trunk_norm"):
-        _cfg(trunk_norm="batchnorm")
-    with pytest.raises(ValueError, match="ssm_heads"):
-        hybrid.check(_cfg(n_layers=1, layer_kinds=("mamba2",), ssm_heads=3))
 
 
 # --------------------------- the delta-rule hybrids (solar-open2-250b)

@@ -9,8 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _helpers import (eqns as _eqns, kernels_by_place as _kernels_by_place,
+                      make_qkv)
 from horovod_tpu import training
 from horovod_tpu.models import llama
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel.mesh import MeshConfig, ParallelMesh
 
 CFG = llama.tiny(vocab=64, seq=32)
@@ -428,3 +431,185 @@ def test_bench_llama8b_dp_mode_rehearsal_fallback():
     assert r["rehearsal"]["ok"] is True
     assert r["rehearsal"]["mesh"]["chips"] == 64
     assert r["rehearsal"]["n_params"] > 7e9
+
+
+# ------------------------------------------ the residuals under remat
+# The forward rules name ``out`` and ``lse`` (fa.OUT_NAME, fa.LSE_NAME) and
+# models/llama.py::remat_policy saves what is named: a remat'd layer
+# stack (the decoder trunk's, BERT's encoder) runs the forward kernel once
+# a layer, not twice.
+
+# attention path -> (heads, kv heads, head_dim, tokens, the mask of a [T]
+# sequence); ``rows``: the masked kernels on the caller's layout
+REMAT_PATHS = {
+    "masked-numpy-mask": (2, 1, 64, 256, lambda T: fa.window_ranges(T, 100)),
+    "masked-traced-mask": (2, 1, 64, 256, lambda T: jnp.asarray(
+        fa.window_ranges(T, 100))),
+    "masked-rows": (2, 1, 128, 256, lambda T: fa.window_ranges(T, 100)),
+    "packed": (2, 2, 64, 128, lambda T: None),
+}
+
+
+def _remat_stack(policy, path):
+    """A two-layer remat'd stack at toy widths, bf16, through the
+    interpreted kernels: ``(grads, (h, layers, mask), cfg, pos)`` with
+    ``grads`` a FRESH function of the three (jax caches a traced function
+    by identity, and two policies must not share a trace)."""
+    H, Hkv, Dh, T, make_mask = REMAT_PATHS[path]
+    cfg = llama.LlamaConfig(
+        vocab_size=64, d_model=128, n_layers=2, n_heads=H, n_kv_heads=Hkv,
+        head_dim=Dh, d_ff=128, max_seq_len=T, dtype=jnp.bfloat16,
+        remat=True, remat_policy=policy)
+    par = llama.ParallelSpec()
+    layers = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    B = 2
+    h = jnp.asarray(np.random.RandomState(1).randn(B, T, cfg.d_model),
+                    cfg.dtype)
+    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+    mask = make_mask(T)
+
+    def loss(h, layers, mask):
+        out, _ = llama._layer_stack(h, layers, cfg, par, pos, mask)
+        return (out.astype(jnp.float32) ** 2).mean()
+
+    grads = jax.value_and_grad(loss, argnums=(0, 1))
+    if isinstance(mask, jax.Array):           # traced: a jit argument
+        return jax.jit(grads), (h, layers, mask), cfg, pos
+    return (jax.jit(lambda h, layers, _: grads(h, layers, mask)),
+            (h, layers, 0), cfg, pos)
+
+
+@pytest.mark.parametrize("path", sorted(REMAT_PATHS))
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_remat_policies_save_the_named_residuals(policy, path, monkeypatch,
+                                                 pallas_interpret):
+    """Under either policy of ``_layer_stack`` the forward kernel stands in
+    the forward scan alone and the backward scan's remat body holds only
+    the backward kernels; the layer's saved residuals are the two named
+    values, ``out`` in the compute dtype and ``lse`` in float32, as the
+    kernel wrote them (``out`` as ``[B, T, H*D]`` on the packed path and
+    on the masked one at ``head_dim`` 128, what the ``wo`` product reads;
+    ``[B, H, T, D]`` at 64); loss and every gradient equal, to the bit, those
+    of the same stack under the policy without the names (the program
+    before the kernels' residuals were kept), which runs the forward
+    kernel twice."""
+    from jax._src.ad_checkpoint import saved_residuals
+    grads, args, cfg, pos = _remat_stack(policy, path)
+    backward = ({"hvd_flash_bwd"} if path == "packed"
+                else {"hvd_flash_dq", "hvd_flash_dkv"})
+    placed = _kernels_by_place(grads, *args)
+    forward = [p for p, name in placed if name == "hvd_flash_fwd"]
+    assert len(forward) == 1 and "scan" in forward[0]
+    assert "remat2" not in forward[0]
+    assert {name for p, name in placed if "remat2" in p} == backward
+    assert len(placed) == 1 + len(backward)
+
+    # one layer under the stack's own policy: what it keeps beside its
+    # arguments are the two named values (jax puts a reduce_precision of
+    # the value's own precision, a no-op, on a residual that the forward
+    # also uses: ``out`` shows under that, ``lse`` under its name)
+    h, layers, _ = args
+    H, T = cfg.n_heads, h.shape[1]
+    one = jax.tree_util.tree_map(lambda w: w[0].astype(cfg.dtype), layers)
+    static = None if path == "packed" else fa.window_ranges(T, 100)
+    layer = jax.checkpoint(
+        lambda h, lp: llama.block(h, lp, cfg, llama.ParallelSpec(), pos,
+                                  static)[0],
+        policy=llama.remat_policy(policy))
+    kept = [(aval, why) for aval, why in saved_residuals(layer, h, one)
+            if "flash_attention.py" in why]
+    D = cfg.head_dim
+    out_shape = ((2, T, H * D) if path in ("packed", "masked-rows")
+                 else (2, H, T, D))
+    assert sorted((a.shape, str(a.dtype)) for a, _ in kept)[-1] == (
+        out_shape, "bfloat16")
+    assert [(a.shape, str(a.dtype)) for a, why in kept
+            if f"named '{fa.LSE_NAME}'" in why] == [
+        ((2, H, 1, T), "float32")]
+    if policy == "full":
+        assert len(kept) == 2
+    traced = jax.make_jaxpr(jax.grad(lambda h: layer(h, one).astype(
+        jnp.float32).sum()))(h)
+    assert {eqn.params["name"] for _, eqn in _eqns(traced.jaxpr)
+            if eqn.primitive.name == "name"} == {fa.OUT_NAME, fa.LSE_NAME}
+
+    # the same stack, the names saved by no policy: the forward kernel a
+    # second time in the remat body, and the same numbers to the bit
+    got = grads(*args)
+    cp = jax.checkpoint_policies
+    monkeypatch.setattr(cp, "save_only_these_names",
+                        lambda *names: cp.nothing_saveable)
+    before, args, _, _ = _remat_stack(policy, path)
+    placed = _kernels_by_place(before, *args)
+    assert sorted(name for p, name in placed if "remat2" in p) == sorted(
+        backward | {"hvd_flash_fwd"})
+    want = before(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    assert np.isfinite(float(got[0])) and float(got[0]) > 0
+
+
+def test_a_name_that_no_policy_saves_changes_nothing(monkeypatch,
+                                                     pallas_interpret):
+    """A caller that remats around the op and saves dots only (BERT's
+    stack, before it took ``remat_policy("dots")``): with the names in
+    the forward rule and no policy that keeps them, the remat body still
+    holds the forward kernel and every number is what it was without the
+    names."""
+    q, k, v = make_qkv(4, 128, 12, 12, 64, jnp.bfloat16)    # BERT's block
+
+    def grads():
+        layer = jax.checkpoint(
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=False),
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        return jax.jit(jax.grad(lambda q, k, v: (layer(q, k, v).astype(
+            jnp.float32) ** 2).sum(), (0, 1, 2)))
+
+    named = grads()
+    placed = _kernels_by_place(named, q, k, v)
+    assert sorted(name for p, name in placed) == [
+        "hvd_flash_bwd", "hvd_flash_fwd", "hvd_flash_fwd"]
+    got = named(q, k, v)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    plain = grads()
+    assert _kernels_by_place(plain, q, k, v) == placed
+    for a, b in zip(got, plain(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_layer_stack_counts_its_remat_policy(monkeypatch):
+    """``hvd_remat_policy_total{policy, saves}``: one count per traced
+    remat'd stack, none where ``remat`` is off."""
+    from horovod_tpu import metrics
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+
+    def counts():
+        family = metrics.registry().to_dict().get(
+            "hvd_remat_policy_total", {})
+        return {(s["labels"]["policy"], s["labels"]["saves"]): s["value"]
+                for s in family.get("series", [])}
+
+    def trace(**kw):
+        cfg = llama.LlamaConfig(vocab_size=64, d_model=64, n_layers=2,
+                                n_heads=2, n_kv_heads=2, d_ff=64,
+                                max_seq_len=16, **kw)
+        layers = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+        h = jax.ShapeDtypeStruct((1, 16, 64), cfg.dtype)
+        pos = jnp.arange(16)[None]
+        jax.make_jaxpr(lambda h, ls: llama._layer_stack(
+            h, ls, cfg, llama.ParallelSpec(), pos))(h, layers)
+
+    before = counts()
+    trace(remat_policy="full")
+    trace(remat_policy="dots")
+    trace(remat_policy="dots")
+    trace(remat=False)
+    after = counts()
+    assert {key: after[key] - before.get(key, 0) for key in after} == {
+        ("full", "flash"): 1, ("dots", "flash"): 2}
+    with pytest.raises(ValueError, match="remat_policy"):
+        trace(remat_policy="some")

@@ -4,15 +4,17 @@ and against a convolution written as a loop: results and every gradient in
 float32 and in bf16, several blocks of positions so that the halo crosses a
 block's edge in both directions, splits of one, two and three parts, the
 first part and the gate's ``y`` turned (positions on the lanes) or not, the
-refusals (each to the bit the plain form) and the counter that says which
-path ran.  (Their lowering for the chip is in tests/test_flash_attention.py,
-the one file that describes the chip.)"""
+refusals (each to the bit the plain form), the counter that says which
+path ran, and their lowering for the chip."""
+
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _helpers import described_chip as _described_chip
 from horovod_tpu import metrics
 from horovod_tpu.ops import mamba2_mixer as mm
 
@@ -24,12 +26,12 @@ def _sizes(monkeypatch):
     two tiles of positions a block and two of channels at 160 or 256."""
     monkeypatch.setattr(mm, "_BLOCK", BLOCK)
     monkeypatch.setattr(mm, "_ROWS", ROWS)
-    monkeypatch.setattr(mm, "_LANES", LANES)
+    monkeypatch.setattr(mm, "_CHANNELS", LANES)
     monkeypatch.setattr(mm, "_TURN", (ROWS, LANES))
 
 
-def _kernels(monkeypatch, on=True):
-    monkeypatch.setattr(mm, "_INTERPRET", on)
+def _kernels(monkeypatch, pallas_interpret, on=True):
+    pallas_interpret(on)
     _sizes(monkeypatch)
 
 
@@ -111,13 +113,14 @@ _TOL = {jnp.float32: (1e-5, 1e-5, 1e-5), jnp.bfloat16: (2 ** -8, 1e-5, 1e-5)}
                          ids=["no-split", "two-parts", "three-parts"])
 def test_convolution_and_its_gradients_follow_the_plain_form(dtype, sizes,
                                                              turned,
-                                                             monkeypatch):
+                                                             monkeypatch,
+                                                             pallas_interpret):
     """A batch of two rows of three blocks of positions, each walked in two
     tiles of positions: the forward's halo is the block before, the
     backward's the block after; the first part's channels are a tile, or a
     tile and a narrower one.  ``turned``: the first part comes ``[Bt, size,
     T]`` and its cotangent goes in so, two tiles of positions a block."""
-    _kernels(monkeypatch)
+    _kernels(monkeypatch, pallas_interpret)
     operands, cts = _conv_operands(dtype, 2, 3 * BLOCK, sizes)
     if turned:
         cts[0] = jnp.swapaxes(cts[0], 1, 2)
@@ -139,12 +142,13 @@ def test_convolution_and_its_gradients_follow_the_plain_form(dtype, sizes,
 
 
 @pytest.mark.parametrize("path", ["pallas", "xla"])
-def test_convolution_is_causal_from_position_zero(path, monkeypatch):
+def test_convolution_is_causal_from_position_zero(path, monkeypatch,
+                                                  pallas_interpret):
     """Against the convolution written as a loop: positions 0..2 see zeros
     and not the row before (a batch of two: the second row's first
     positions must not read the first row's last), and every block's first
     positions see the block before."""
-    _kernels(monkeypatch, path == "pallas")
+    _kernels(monkeypatch, pallas_interpret, path == "pallas")
     (xBC, w, b), _ = _conv_operands(jnp.float32, 2, 3 * BLOCK, (128,), seed=3)
     (got,) = mm.conv_silu_split(xBC, w, b, (128,))
     want = _conv_loop(xBC, w, b)
@@ -170,12 +174,12 @@ def test_convolution_is_causal_from_position_zero(path, monkeypatch):
                                       (1e-5, True)],
                          ids=["eps-1e-5", "eps-0.5", "unit-w"])
 def test_gate_and_norm_and_their_gradients_follow_the_plain_form(
-        dtype, eps, unit, turned, monkeypatch):
+        dtype, eps, unit, turned, monkeypatch, pallas_interpret):
     """``eps`` 0.5 is as large as the mean square itself, so a kernel that
     dropped it or put it outside the root would read far off; ``w`` away
     from 1 tells ``dw`` and the scaling of ``dy``, ``dz`` apart.
     ``turned``: ``y`` comes ``[Bt, C, T]`` and ``dy`` goes out so."""
-    _kernels(monkeypatch)
+    _kernels(monkeypatch, pallas_interpret)
     (y, z, w), ct = _norm_operands(dtype, 2, 3 * BLOCK, 256, unit=unit)
     operands = (jnp.swapaxes(y, 1, 2) if turned else y, z, w)
     assert mm.supported(z, (256,), turned)
@@ -199,16 +203,16 @@ def test_gate_and_norm_and_their_gradients_follow_the_plain_form(
         ).max()) > (1e-6 if eps < 0.1 else 1e-2)
 
 
-def _refused(monkeypatch, why):
+def _refused(monkeypatch, pallas_interpret, why):
     """Operands' shapes ``(T, sizes)`` under the refusal ``why``."""
+    pallas_interpret(why == "positions")
     if why == "backend":                  # the CPU, nothing flipped
         return 2 * BLOCK, (128, 16, 16)
+    _sizes(monkeypatch)
     if why == "lanes":                    # a TPU, channels off the lanes
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        _sizes(monkeypatch)
         return 2 * BLOCK, (128, 16, 16)
-    _kernels(monkeypatch)                 # a T the block does not divide
-    return BLOCK + ROWS, (128, 16, 16)
+    return BLOCK + ROWS, (128, 16, 16)    # a T the block does not divide
 
 
 @pytest.mark.parametrize("chain", ["conv", "norm"])
@@ -216,10 +220,11 @@ def _refused(monkeypatch, why):
                                         ("lanes", "128 lanes"),
                                         ("positions", "positions")])
 def test_refused_shapes_take_the_plain_form_to_the_bit(chain, why, reason,
-                                                       monkeypatch):
+                                                       monkeypatch,
+                                                       pallas_interpret):
     """The branch lies outside the ``custom_vjp``: result and gradients are
     the plain form's own, bit for bit, and the counter says ``xla``."""
-    T, sizes = _refused(monkeypatch, why)
+    T, sizes = _refused(monkeypatch, pallas_interpret, why)
     if chain == "conv":
         operands, cts = _conv_operands(jnp.bfloat16, 1, T, sizes, seed=1)
         assert reason in mm._refusal(operands[0], sizes)
@@ -248,8 +253,9 @@ def test_refused_shapes_take_the_plain_form_to_the_bit(chain, why, reason,
     assert "hvd_conv_silu" not in text and "hvd_gated_norm" not in text
 
 
-def test_each_call_site_is_counted_once_with_its_kernel_and_path(monkeypatch):
-    _kernels(monkeypatch)
+def test_each_call_site_is_counted_once_with_its_kernel_and_path(
+        monkeypatch, pallas_interpret):
+    _kernels(monkeypatch, pallas_interpret)
     sizes = (128, 16, 16)
     operands, cts = _conv_operands(jnp.float32, 1, 2 * BLOCK, sizes)
     noperands, ct = _norm_operands(jnp.float32, 1, 2 * BLOCK, 128)
@@ -297,3 +303,44 @@ def test_on_the_chip_the_kernels_want_whole_tiles(monkeypatch):
     mm.conv_silu_split(xBC, jnp.zeros((9, 128)), b, (128,))
     mm.gated_rmsnorm(xBC, xBC.astype(jnp.float32), b, 1e-5)
     assert seen == [("conv_fwd", "xla"), ("norm_fwd", "xla")]
+
+
+@pytest.mark.parametrize("turned", [False, True], ids=["rows", "turned"])
+def test_mamba2_mixer_kernels_lower_for_the_chip(turned, monkeypatch):
+    """Mosaic takes the Mamba-2 mixer's four elementwise kernels at the
+    benchmark's granite-4.0-h-micro cell: 8,192 positions, 4,352 convolved
+    channels cut 4,096 / 128 / 128 under four taps, 4,096 gated ones, bf16
+    operands beside float32 parameters; ``turned``, as the cell runs them,
+    ``x`` written and ``y`` read ``[1, 4096, 8192]`` with the chunked scan
+    on that layout between them and no transpose in the compiled chain."""
+    from horovod_tpu.ops import ssd_scan as sd
+    one_chip = _described_chip(monkeypatch)
+    T, sizes, H, N = 8192, (4096, 128, 128), 64, 128
+    C, Di = sum(sizes), sizes[0]
+    bf, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    assert mm.supported(sds((1, T, C), bf), sizes, turned)
+
+    def chain(xBC, conv_w, conv_b, z, gate_w, delta, A, D):
+        x, B, Cm = mm.conv_silu_split(xBC, conv_w, conv_b, sizes, turned)
+        scan, heads = ((sd.ssd_scan_turned, (1, H, Di // H, T)) if turned
+                       else (sd.ssd_scan, (1, T, H, Di // H)))
+        y = scan(x.reshape(heads), delta, A, B.reshape(1, T, 1, N),
+                 Cm.reshape(1, T, 1, N), D, 256)
+        return mm.gated_rmsnorm(y.reshape(x.shape), z, gate_w, 1e-5,
+                                turned).astype(f32).sum()
+
+    text = jax.jit(jax.value_and_grad(chain, argnums=tuple(range(8)))).lower(
+        sds((1, T, C), bf), sds((4, C), f32), sds((C,), f32),
+        sds((1, T, Di), bf), sds((Di,), f32), sds((1, T, H), f32),
+        sds((H,), f32), sds((H,), f32)).compile().as_text()
+    for name in ("hvd_conv_silu_fwd", "hvd_conv_silu_bwd",
+                 "hvd_gated_norm_fwd", "hvd_gated_norm_bwd",
+                 "hvd_ssd_chunk_fwd", "hvd_ssd_chunk_bwd"):
+        assert name in text
+    if turned:
+        # x, y and their cotangents never change layout in HBM
+        assert f"bf16[1,{T},{H},{Di // H}]" not in text
+        assert not re.search(
+            rf"bf16\[1,{T},{Di}\]\S* (copy|transpose)\(", text)

@@ -97,14 +97,15 @@ def test_dropless_gradients_match_the_dense_layer(first, held):
 
 
 @pytest.mark.parametrize("chunks", [1, 2], ids=["one-chunk", "two-chunks"])
-def test_layer_through_the_combine_kernel_is_the_scatter_adds(chunks,
-                                                               monkeypatch):
+def test_layer_through_the_combine_kernel_is_the_scatter_adds(
+        chunks, pallas_interpret):
     """The layer's output and its five gradients with the combine as the
     Pallas kernel (interpreted; the model width a multiple of 128, 1,024
     tokens) against ``.at[].add``, to the last bit: both add a token's
     rows in the sorted rows' order.  Two chunks: every token sends both
     its pairs to held experts, 2,048 pairs for chunks of 1,536 rows, so
     the second chunk adds to what the first left."""
+    pallas_interpret(False)
     cfg = dataclasses.replace(CFG, d_model=128)
     lp = _params(cfg, seed=7)
     x = jax.random.normal(jax.random.key(8), (2, 512, cfg.d_model))
@@ -130,7 +131,7 @@ def test_layer_through_the_combine_kernel_is_the_scatter_adds(chunks,
 
     y, stats, grads = run()
     before = combines()
-    monkeypatch.setattr(grouped_matmul, "_INTERPRET", True)
+    pallas_interpret()
     y2, stats2, grads2 = run()
     if metrics.ACTIVE:           # out and dtok, and no scatter-add beside them
         after = combines()

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from _helpers import sp_sharded as _sharded
+from _helpers import dense_reference, make_qkv, sp_sharded as _sharded
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel.mesh import MeshConfig, ParallelMesh, factor_mesh
 from horovod_tpu.parallel.pipeline import pipeline_apply
 from horovod_tpu.parallel.ring_attention import ring_attention
@@ -228,3 +229,51 @@ def test_ring_attention_memory_scales_linearly(sp_mesh):
     # cap) must cost ~4x temp memory, not ~16x
     ratio = temp_bytes(16384) / temp_bytes(4096)
     assert ratio < 6.0, ratio
+
+
+# --- flash kernel inside the ring (VERDICT r2 #7) ---------------------------
+
+@pytest.mark.parametrize("causal,Hkv", [(True, 2), (False, 2), (True, 1)])
+def test_ring_attention_kernel_path_interpret(causal, Hkv, pallas_interpret,
+                                              hvd):
+    """The ring path routes each per-step tile through the Pallas kernel
+    when shapes fit (O(Tl·blk) per step instead of a [B,H,Tl,Tl] tile);
+    Hkv=1 exercises the GQA grouped tiles through the merge."""
+    from horovod_tpu.parallel.ring_attention import ring_attention
+    mesh = jax.make_mesh((2,), ("sp",))
+    q, k, v = make_qkv(1, 256, 2, Hkv, 64, seed=5)  # 128 per shard
+
+    # confirm the kernel path is taken per shard (supported in interpret)
+    assert fa.supported(q[:, :128], k[:, :128], v[:, :128], causal)
+
+    out = _sharded(mesh, lambda q, k, v: ring_attention(
+        q, k, v, axis_name="sp", causal=causal))(q, k, v)
+    ref = dense_reference(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=3e-5)
+
+
+def test_ring_attention_kernel_path_grads_interpret(pallas_interpret, hvd):
+    from jax.sharding import PartitionSpec as P
+    from horovod_tpu.parallel.ring_attention import ring_attention
+    mesh = jax.make_mesh((2,), ("sp",))
+    q, k, v = make_qkv(1, 256, 2, 2, 64, seed=7)
+
+    def ring_loss(q, k, v):
+        # local loss per shard: the reverse ring delivers every shard's
+        # cotangents to each k/v block (see test_parallel.py rationale)
+        o = ring_attention(q, k, v, "sp", causal=True)
+        return (o ** 2).sum()
+
+    gr = jax.jit(jax.shard_map(
+        jax.grad(ring_loss, argnums=(0, 1, 2)), mesh=mesh,
+        in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
+        check_vma=False))(q, k, v)
+
+    def loss_dense(q, k, v):
+        return (dense_reference(q, k, v, True) ** 2).sum()
+
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=3e-4, rtol=1e-3)

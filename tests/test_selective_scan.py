@@ -1,14 +1,14 @@
 """ops/selective_scan.py: the Pallas kernels (interpret mode on the CPU)
 and the ``lax.scan`` fallback against a float32 recurrence written here:
-the output and all six gradients, the states kept at chunk boundaries, and
-the counter that says which path ran.  (Their lowering for the chip is in
-tests/test_flash_attention.py, the one file that describes the chip.)"""
+the output and all six gradients, the states kept at chunk boundaries, the
+counter that says which path ran, and their lowering for the chip."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _helpers import described_chip as _described_chip
 from horovod_tpu import metrics
 from horovod_tpu.ops import selective_scan as ss
 
@@ -72,10 +72,11 @@ def _close(got, want, dtype):
                          ids=["three-chunks-three-blocks", "one-chunk",
                               "two-chunks-two-blocks"])
 def test_scan_and_its_six_gradients_follow_the_recurrence(path, dtype, T, Ch,
-                                                          monkeypatch):
+                                                          monkeypatch,
+                                                          pallas_interpret):
     """A batch of two; a sequence of several chunks and of one; channel
     blocks of 128 and of 512."""
-    monkeypatch.setattr(ss, "_INTERPRET", path == "pallas")
+    pallas_interpret(path == "pallas")
     monkeypatch.setattr(ss, "_CHUNK", 16)
     operands, w = _operands(dtype, 2, T, Ch)
     assert ss.supported(*operands) == (path == "pallas")
@@ -96,8 +97,8 @@ def test_scan_and_its_six_gradients_follow_the_recurrence(path, dtype, T, Ch,
     _close(grads, want, dtype)
 
 
-def test_state_kept_at_a_chunk_boundary_is_the_sequential_state(monkeypatch):
-    monkeypatch.setattr(ss, "_INTERPRET", True)
+def test_state_kept_at_a_chunk_boundary_is_the_sequential_state(monkeypatch,
+        pallas_interpret):
     monkeypatch.setattr(ss, "_CHUNK", 8)
     operands, _ = _operands(jnp.float32, 2, 32, 256, seed=3)
     s, bounds = ss._scan_fwd_pallas(*operands)
@@ -113,8 +114,7 @@ def test_state_kept_at_a_chunk_boundary_is_the_sequential_state(monkeypatch):
 @pytest.mark.parametrize("change,reason", [
     (dict(Ch=200), "channels"), (dict(T=20), "positions"),
     (dict(dtype=jnp.float16), "dtype")])
-def test_refused_shapes_take_the_plain_path(change, reason, monkeypatch):
-    monkeypatch.setattr(ss, "_INTERPRET", True)
+def test_refused_shapes_take_the_plain_path(change, reason, pallas_interpret):
     kw = {"dtype": jnp.float32, "Bt": 1, "T": 16, "Ch": 128, **change}
     operands, w = _operands(**kw)
     assert reason in ss._refusal(*operands)
@@ -131,3 +131,21 @@ def test_off_the_chip_the_plain_path_runs_without_being_asked():
     jax.jit(ss.selective_scan)(*operands)
     if metrics.ACTIVE:
         assert _counts().get(("fwd", "xla"), 0) == before.get(("fwd", "xla"), 0) + 1
+
+
+def test_selective_scan_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the selective scan forward and backward at the
+    benchmark's phi4-mini-flash cell: 8,192 positions of 5,120 channels
+    of 16 states, bf16 ``xs``, ``B`` and ``C`` beside a float32 step."""
+    one_chip = _described_chip(monkeypatch)
+    T, Ch, N = 8192, 5120, 16
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    operands = (sds((1, T, Ch), jnp.bfloat16), sds((1, T, Ch), jnp.float32),
+                sds((Ch, N), jnp.float32), sds((1, T, N), jnp.bfloat16),
+                sds((1, T, N), jnp.bfloat16), sds((Ch,), jnp.float32))
+    assert ss.supported(*operands)
+    text = jax.jit(jax.grad(
+        lambda *a: ss.selective_scan(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(6)))).lower(*operands).compile().as_text()
+    assert "hvd_ssm_scan_fwd" in text and "hvd_ssm_scan_bwd" in text

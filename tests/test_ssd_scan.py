@@ -1,16 +1,17 @@
 """ops/ssd_scan.py: the chunked form in jax.numpy and the Pallas kernels
 (interpret mode on the CPU) against the recurrence walked position by
 position, written here in float32: the output and all six gradients over
-one, two and eight chunks and over two groups, a strong decay, the states
-kept at chunk boundaries, the refusals and the counter that says which path
-ran.  (Their lowering for the chip is in tests/test_flash_attention.py, the
-one file that describes the chip.)"""
+one, two and eight chunks and over two groups, the refusals, the counter
+that says which path ran, and their lowering for the chip.  (A strong
+decay, the states kept at chunk boundaries and the kernels' own layout:
+tests/test_ssd_states.py.)"""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _helpers import described_chip as _described_chip
 from horovod_tpu import metrics
 from horovod_tpu.ops import ssd_scan as sd
 
@@ -79,10 +80,10 @@ def _close(got, want, tol):
                          ids=["one-chunk", "two-chunks", "eight-chunks",
                               "two-chunks-two-groups"])
 def test_scan_and_its_six_gradients_follow_the_recurrence(path, dtype, chunks,
-                                                          G, monkeypatch):
+                                                          G, pallas_interpret):
     """A batch of two; one chunk, two and eight; one group of ``B`` and
     ``C`` for all heads and two groups of four."""
-    monkeypatch.setattr(sd, "_INTERPRET", path == "pallas")
+    pallas_interpret(path == "pallas")
     operands, w = _operands(dtype, 2, chunks * Q, G)
     assert sd.supported(*operands, Q) == (path == "pallas")
     before = _counts()
@@ -102,59 +103,11 @@ def test_scan_and_its_six_gradients_follow_the_recurrence(path, dtype, chunks,
     _close(grads, want, 2e-5 if dtype == jnp.float32 else 2.5e-2)
 
 
-def test_blocks_of_heads_share_a_groups_products(monkeypatch):
-    """Two groups of eight heads, four heads a grid step: ``B C^T`` made at
-    a group's first block, ``dB`` and ``dC`` added up over its two."""
-    monkeypatch.setattr(sd, "_INTERPRET", True)
-    monkeypatch.setattr(sd, "_HEADS", (4,))
-    operands, w = _operands(jnp.float32, 1, 2 * Q, G=2, heads=16, seed=5)
-    assert sd._head_block(16, 2) == 4
-    value, grads = _value_and_grads(lambda *a: sd.ssd_scan(*a, Q), operands, w)
-    want_value, want = _value_and_grads(_plain, operands, w)
-    assert abs(float(value - want_value)) <= 1e-5 * abs(float(want_value))
-    _close(grads, want, 2e-5)
-
-
-@pytest.mark.parametrize("path", ["pallas", "xla"])
-def test_a_strong_decay_neither_overflows_nor_loses_the_state(path,
-                                                              monkeypatch):
-    """``delta A`` near -20 a position: a chunk's running sum passes -300,
-    whose exponential is 0 in float32 and whose inverse would be inf;
-    every exponent is a difference that is never positive, so nothing
-    overflows and nothing is NaN, forward or backward."""
-    monkeypatch.setattr(sd, "_INTERPRET", path == "pallas")
-    operands, w = _operands(jnp.float32, 1, 4 * Q, seed=2, decay=40.0)
-    x, delta, A = operands[:3]
-    assert float((delta * A).min()) < -20
-    assert float(jnp.cumsum((delta * A)[0, :Q], 0).min()) < -100
-    value, grads = _value_and_grads(lambda *a: sd.ssd_scan(*a, Q), operands, w)
-    want_value, want = _value_and_grads(_plain, operands, w)
-    assert np.isfinite(float(value))
-    assert abs(float(value - want_value)) <= 1e-5 * abs(float(want_value))
-    _close(grads, want, 1e-3)
-
-
-def test_state_kept_at_a_chunk_boundary_is_the_sequential_state(monkeypatch):
-    monkeypatch.setattr(sd, "_INTERPRET", True)
-    operands, _ = _operands(jnp.float32, 2, 4 * Q, seed=3)
-    y, bounds = sd._scan_fwd_pallas(*operands[:5], Q)
-    want_y, states = _states(*operands[:5], jnp.zeros((H,)))
-    np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
-    assert bounds.shape == (2, 4, H, P, N) and not np.asarray(bounds[:, 0]).any()
-    _, plain_bounds = sd._scan_fwd_xla(*operands[:5], Q)
-    for k in range(1, 4):               # chunk k starts from step Q k - 1's state
-        np.testing.assert_allclose(bounds[:, k], states[Q * k - 1],
-                                   rtol=2e-5, atol=2e-6)
-        np.testing.assert_allclose(plain_bounds[:, k], states[Q * k - 1],
-                                   rtol=2e-5, atol=2e-6)
-
-
 @pytest.mark.parametrize("change,reason", [
     (dict(T=3 * Q // 2), "positions"), (dict(dtype=jnp.float16), "dtype")])
-def test_refused_shapes_take_the_plain_path(change, reason, monkeypatch):
+def test_refused_shapes_take_the_plain_path(change, reason, pallas_interpret):
     """The plain path takes what the kernels refuse: positions that are no
     multiple of the chunk in chunks of their common divisor."""
-    monkeypatch.setattr(sd, "_INTERPRET", True)
     kw = {"dtype": jnp.float32, "Bt": 1, "T": Q, **change}
     operands, w = _operands(**kw)
     assert reason in sd._refusal(*operands, Q)
@@ -193,34 +146,22 @@ def test_off_the_chip_the_plain_path_runs_without_being_asked():
         assert _counts().get(("fwd", "xla"), 0) == before.get(("fwd", "xla"), 0) + 1
 
 
-
-@pytest.mark.parametrize("path", ["pallas", "xla"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-def test_the_kernels_layout_taken_and_returned_is_the_same_scan(path, dtype,
-                                                                monkeypatch):
-    """``ssd_scan_turned`` on ``x^T [Bt, H, P, T]`` is ``ssd_scan`` between
-    two transposes: ``y^T`` to the bit (the same kernels, ``D x`` added
-    element by element in the other layout) and the six gradients, ``dD``'s
-    sum in another order; off the kernels it is that function itself."""
-    monkeypatch.setattr(sd, "_INTERPRET", path == "pallas")
-    operands, w = _operands(dtype, 2, 4 * Q, G=2)
-    turn = lambda a: jnp.transpose(a, (0, 2, 3, 1))
-    turned = (turn(operands[0]),) + operands[1:]
-    before = _counts()
-    value, grads = _value_and_grads(
-        lambda *a: sd.ssd_scan_turned(*a, Q), turned, turn(w))
-    if metrics.ACTIVE:
-        after = _counts()
-        assert {k: after[k] - before.get(k, 0) for k in after
-                if after[k] != before.get(k, 0)} == {("fwd", path): 1,
-                                                     ("bwd", path): 1}
-    want_value, want = _value_and_grads(lambda *a: sd.ssd_scan(*a, Q),
-                                        operands, w)
-    y = sd.ssd_scan(*operands, Q).astype(jnp.float32)
-    np.testing.assert_array_equal(
-        np.asarray(sd.ssd_scan_turned(*turned, Q).astype(jnp.float32)),
-        np.asarray(turn(y)))
-    # the same terms added up in another order
-    assert abs(float(value - want_value)) <= 1e-6 * float(jnp.abs(y * w).sum())
-    _close((jnp.transpose(grads[0], (0, 3, 1, 2)),) + grads[1:], want, 1e-5)
+def test_ssd_scan_kernels_lower_for_the_chip(monkeypatch):
+    """Mosaic takes the chunked state-space scan forward and backward at
+    the benchmark's granite-4.0-h-micro cell: 8,192 positions of 64 heads
+    of 64 channels over one group of 128 states in chunks of 256, bf16
+    ``x``, ``B`` and ``C`` beside a float32 step; no ``[T, H, P, N]``
+    array and no ``[Q, Q]`` tile a head in the compiled program."""
+    one_chip = _described_chip(monkeypatch)
+    T, H, P, N = 8192, 64, 64, 128
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    operands = (sds((1, T, H, P), jnp.bfloat16), sds((1, T, H), jnp.float32),
+                sds((H,), jnp.float32), sds((1, T, 1, N), jnp.bfloat16),
+                sds((1, T, 1, N), jnp.bfloat16), sds((H,), jnp.float32))
+    assert sd.supported(*operands, 256)
+    text = jax.jit(jax.grad(
+        lambda *a: sd.ssd_scan(*a, 256).astype(jnp.float32).sum(),
+        argnums=tuple(range(6)))).lower(*operands).compile().as_text()
+    assert "hvd_ssd_chunk_fwd" in text and "hvd_ssd_chunk_bwd" in text
+    assert f"{T},{H},{P},{N}]" not in text and "64,256,256]" not in text

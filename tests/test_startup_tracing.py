@@ -444,6 +444,34 @@ def test_resnet_blocks_sync_bn_lies_inside_its_stage():
                for n in names)
 
 
+def test_models_take_their_scope_names_from_a_leaf_not_from_training(tmp_path):
+    """``horovod_tpu/scopes.py`` holds the names and imports nothing of
+    the package: the package and the four model files that open scopes
+    import without ``training.py``, which imports the models and hands
+    the names on under its own."""
+    child = (
+        "import sys\n"
+        "import horovod_tpu\n"
+        "from horovod_tpu.models import llama, hybrid, bert, resnet\n"
+        "assert 'horovod_tpu.training' not in sys.modules\n"
+        "from horovod_tpu import scopes, training\n"
+        "from horovod_tpu.models import moe\n"
+        "names = [n for n in dir(scopes) if n.startswith('SCOPE_')]\n"
+        "assert len(names) == 20, names\n"
+        "for n in names:\n"
+        "    home = moe if 'moe' in getattr(scopes, n) else training\n"
+        "    assert getattr(home, n) is getattr(scopes, n), n\n"
+        "print('ok')\n")
+    done = subprocess.run(
+        [sys.executable, "-c", child], cwd=tmp_path, capture_output=True,
+        text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO))
+    assert done.returncode == 0, done.stderr[-3000:]
+    assert done.stdout.strip().splitlines()[-1] == "ok"
+    with open(os.path.join(REPO, "horovod_tpu", "scopes.py")) as f:
+        assert not re.search(r"^\s*(import|from)\s", f.read(), re.M)
+
+
 @pytest.mark.parametrize("model", sorted(_MODEL_SCOPES))
 def test_model_scopes_leave_the_lowered_text_as_it_was(model, monkeypatch):
     """``lower().as_text()`` carries no debug info: with the scopes and
@@ -462,9 +490,8 @@ def test_model_scopes_leave_the_lowered_text_as_it_was(model, monkeypatch):
     # several: the masked path
     (1024, ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv")),
 ])
-def test_flash_kernels_carry_their_names(seq_len, names, monkeypatch):
+def test_flash_kernels_carry_their_names(seq_len, names, pallas_interpret):
     from horovod_tpu.ops import flash_attention as fa
-    monkeypatch.setattr(fa, "_INTERPRET", True)
     q = jax.ShapeDtypeStruct((1, seq_len, 2, 64), jnp.float32)
 
     def loss(q, k, v):

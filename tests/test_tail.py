@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _helpers import free_port
+from _helpers import free_port, mesh_map
 
 import horovod_tpu.chaos as chaos
 from horovod_tpu.ops import collectives
@@ -39,9 +39,7 @@ def _sig(name, tail="strict", dtype="float32", **kw):
 
 
 def _pmap2(fn, G, L, in_axes):
-    inner = jax.pmap(fn, axis_name=LOCAL, in_axes=in_axes)
-    outer = tuple(0 if a is not None else None for a in in_axes)
-    return jax.pmap(inner, axis_name=CROSS, in_axes=outer)
+    return mesh_map(fn, (G, L), (CROSS, LOCAL), in_axes)
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +162,7 @@ def test_tail_state_required_for_stale():
 
 
 # ---------------------------------------------------------------------------
-# in-jit policy arithmetic (nested pmap over the virtual 8-device mesh)
+# in-jit policy arithmetic (a 2-D mesh of the virtual devices)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("G,L", [(2, 4), (4, 2)])

@@ -3,8 +3,8 @@
 composition of ZeRO tiles, quantized wire, and overlap taps (ISSUE 14).
 
 Measures what the spec-aware refactor changes on a virtual 2-D CPU mesh
-(nested ``pmap`` over ``--xla_force_host_platform_device_count``
-devices: outer axis ``data``, inner axis ``model``).  Params are
+(``shard_map`` over ``--xla_force_host_platform_device_count``
+devices on a mesh of axes ``data`` and ``model``).  Params are
 model-sharded (`PartitionSpec("model")` on the stacked layer weights,
 replicated norms/embed); gradients w.r.t. the LOCAL shards arrive
 pre-reduced over the model axis (the in-program gather's transpose),
@@ -155,10 +155,22 @@ def _run_ab(jax, tx, params, D, M, steps):
             p = optax.apply_updates(p, u)
         return p, s
 
-    f = jax.pmap(jax.pmap(prog, axis_name="model", in_axes=(0, None)),
-                 axis_name="data", in_axes=(0, None))
-    p_on, s_on = f(X, jax.numpy.asarray(True))
-    p_off, _ = f(X, jax.numpy.asarray(False))
+    from jax.sharding import PartitionSpec as P
+    piece = P("data", "model")
+
+    def on_a_device(x, fire):       # a leaf [1, 1, ...] in, and out
+        return jax.tree_util.tree_map(
+            lambda r: jax.numpy.asarray(r)[None, None], prog(x[0, 0], fire))
+
+    f = jax.jit(jax.shard_map(
+        on_a_device, mesh=jax.make_mesh((D, M), ("data", "model")),
+        in_specs=(piece, P()), out_specs=piece, check_vma=False))
+    # to the host: a caller indexes replicas, which an array laid out on
+    # the mesh's named axes does not let it do
+    run = lambda fire: jax.tree_util.tree_map(
+        np.asarray, f(X, jax.numpy.asarray(fire)))
+    p_on, s_on = run(True)
+    p_off, _ = run(False)
     for a, b in zip(jax.tree_util.tree_leaves(p_on),
                     jax.tree_util.tree_leaves(p_off)):
         a, b = np.asarray(a), np.asarray(b)

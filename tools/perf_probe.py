@@ -3,7 +3,6 @@
 Usage (on the chip):
 
     python tools/perf_probe.py [--trace /tmp/hvd_trace] [--steps 10]
-        [--no-flash]
 
 Runs the same ~1B llama training step as bench.py, prints per-step wall
 time and MFU, and (with --trace) captures a Perfetto trace through
@@ -25,7 +24,6 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("--trace", default=None)
-    p.add_argument("--no-flash", action="store_true")
     p.add_argument("--seq", type=int, default=1024)
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--remat", default="full", choices=["full", "dots"])
@@ -35,9 +33,6 @@ def main():
                    help="time like bench.py: sync once at the end")
     p.add_argument("--opt", default="adamw", choices=["adamw", "adamw_lp"])
     args = p.parse_args()
-
-    if args.no_flash:
-        os.environ["HOROVOD_FLASH_ATTENTION"] = "0"
 
     import jax
     import jax.numpy as jnp
