@@ -12,11 +12,13 @@ here when it is set.
 
     h = x + r Mixer(Norm1(x));  y = h + r FF(Norm2(h))
 
-with no bias in a product and no positional encoding anywhere.  ``FF`` is
+with no bias in a product; positions are a kind's (``attention`` and
+``swa`` with a rotary table, below) and no other kind has any.  ``FF`` is
 the config's: ``W2 (silu(g) * v)``, ``[g ; v] = W1 z``, or, with
 ``cfg.n_experts``, routed experts that drop no token
 (``moe.dropless_moe_layer``: the experts this chip holds, scored by
-``cfg.router_score``, chosen with the layer's ``router_bias``) plus, with
+``cfg.router_score``, a sigmoid's chosen with the layer's ``router_bias``)
+plus, with
 ``cfg.n_shared_experts``, that same ``W1`` / ``W2`` as the expert every
 token passes through (``moe.shared_expert``), added once.
 ``cfg.trunk_norm`` says which norm (``layernorm``: weight and bias;
@@ -62,6 +64,18 @@ default computes what the trunk computed before the config carried it.
   ``1 / sqrt(Dh)``).  With ``cfg.attn_gate`` the heads' output is gated
   before ``W_o``: ``o * sigmoid(W_gate u)``, elementwise (the form G1 of
   arXiv 2505.06708).
+* ``swa`` — the same layer under a window (Mellum 2's
+  ``sliding_attention``): row ``i`` sees the last ``sliding_window`` keys
+  with its own, ``[max(0, i - w + 1), i + 1)``.  One body serves both;
+  they differ in their key ranges and in their rotary table.  **Positions
+  are these two kinds', a table a kind** (``cfg.rope_tables``, Hugging
+  Face's ``rope_parameters`` a layer type): ``q`` and ``k`` are rotated
+  (rotate-half, float32) by ``cos`` and ``sin`` of ``p * inv_freq[j]``,
+  ``p = arange(T)`` a row, before the scores; ``inv_freq`` is plain RoPE's
+  or YaRN's with its ``attention_factor`` on cos and sin both
+  (``llama.rope_inv_freq`` has the equations), made once where the step
+  is traced and handed to the kind's layers.  A kind without a table has
+  no positions (Granite's and Solar's ``attention``).
 * ``kda`` — Kimi Delta Attention: ``[q ; k ; v] = silu(conv(W_qkv u))``,
   depthwise, causal, ``ssm_conv`` wide, no bias, in ``ssm_heads`` heads,
   keys ``ssm_state`` and values ``ssm_inner / ssm_heads`` wide; ``q`` and
@@ -95,8 +109,11 @@ under the scopes ``hvd_ssm_mixer``, ``hvd_gmu``, ``hvd_diff_attention``,
 ``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``; the
 mixer's own kernels ``hvd_conv_silu_fwd`` / ``_bwd`` and
 ``hvd_gated_norm_fwd`` / ``_bwd`` under no scope of their own, rows of the
-mixer's), ``hvd_kda_mixer`` (the scan's call under ``hvd_kda_scan``) and
-``hvd_attention``; the feed-forward under ``hvd_mlp``, routed experts'
+mixer's), ``hvd_kda_mixer`` (the scan's call under ``hvd_kda_scan``),
+``hvd_attention`` and ``hvd_window_attention`` (``swa``; either's rotary
+table and products under ``hvd_rope`` inside it, and
+``hvd_rope_tables_total{kind, type}`` counts the tables traced); the
+feed-forward under ``hvd_mlp``, routed experts'
 ``hvd_moe_route`` / ``hvd_moe_experts`` and the shared expert's
 ``hvd_moe_shared`` inside it.  Plain data parallelism only: nothing here
 is sharded over a tensor-, sequence-, pipeline- or expert-parallel axis
@@ -121,20 +138,22 @@ from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
 from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
 from ..scopes import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
-                      SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLP,
+                      SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLP, SCOPE_ROPE,
                       SCOPE_SHARED, SCOPE_SSD_MIXER, SCOPE_SSD_SCAN,
-                      SCOPE_SSM_MIXER)
+                      SCOPE_SSM_MIXER, SCOPE_WINDOW_ATTENTION)
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
 from . import moe
-from .llama import ParallelSpec, _rmsnorm
+from .llama import ParallelSpec, _rmsnorm, rope_table, rotate
 
 # the five of PR 33 (SambaY), the two of PR 40 (Granite: ``mamba2`` under
 # ``hvd_ssd_mixer``, ``attention`` under ``hvd_attention``), and ``kda``
 # (PR 42: Kimi Delta Attention under ``hvd_kda_mixer``; ``attention`` takes
-# ``cfg.attn_gate``)
+# ``cfg.attn_gate``), and ``swa`` (PR 46: ``attention``'s layer under the
+# window and ``hvd_window_attention``; either takes a rotary table)
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention",
-         "kda")
+         "kda", "swa")
 _DIFFERENTIAL = ("window", "full", "cross")
+_PLAIN = {"attention": SCOPE_ATTENTION, "swa": SCOPE_WINDOW_ATTENTION}
 _MATRICES = ("w1", "w2", "in_proj", "x_proj", "dt_proj", "out_proj", "wqkv",
              "wq", "wo", "wgate", "f_a", "f_b", "g_a", "g_b", "b_proj",
              "we_gate", "we_up", "we_down")
@@ -144,6 +163,11 @@ _m_kinds = _metrics.counter(
     "hvd_layer_kind_total",
     "Layers of a trunk of several kinds traced, by kind "
     "(models/hybrid.py)", labels=("kind",))
+_m_ropes = _metrics.counter(
+    "hvd_rope_tables_total",
+    "Rotary tables of a trunk of several kinds traced, one a layer kind "
+    "that has one, by kind and rope_type (models/hybrid.py)",
+    labels=("kind", "type"))
 
 
 def check(cfg) -> None:
@@ -176,6 +200,12 @@ def check(cfg) -> None:
             "a kda layer needs ssm_heads heads of ssm_state key channels "
             f"dividing ssm_inner, got {cfg.ssm_heads} heads, keys "
             f"{cfg.ssm_state} wide, {cfg.ssm_inner} value channels")
+    if "swa" in kinds and cfg.sliding_window <= 0:
+        raise ValueError("a swa layer needs sliding_window > 0")
+    tabled = [kind for kind, _ in cfg.rope_tables]
+    if set(tabled) - set(_PLAIN) or len(set(tabled)) != len(tabled):
+        raise ValueError(f"rope_tables gives each of {tuple(_PLAIN)} one "
+                         f"table at most, got {tabled!r}")
     if cfg.n_experts > 0 and cfg.moe_dispatch != "dropless":
         raise ValueError("routed experts in a trunk of several kinds are "
                          "the dropless ones (moe_dispatch='dropless')")
@@ -215,7 +245,7 @@ def layer_shapes(cfg, kind):
             "in_proj": (D, Di + conv + Hs), "conv_w": (Kc, conv),
             "conv_b": (conv,), "dt_bias": (Hs,), "A_log": (Hs,), "D": (Hs,),
             "gate_norm": (Di,), "out_proj": (Di, D)})
-    elif kind == "attention":
+    elif kind in _PLAIN:
         shapes.update({"wqkv": (D, (H + 2 * Hkv) * Dh), "wo": (H * Dh, D)})
         if cfg.attn_gate:
             shapes["wgate"] = (D, H * Dh)
@@ -243,10 +273,11 @@ def layer_shapes(cfg, kind):
     if cfg.n_experts > 0:
         held, S = cfg.experts_held or cfg.n_experts, cfg.n_shared_experts
         del shapes["w1"], shapes["w2"]
-        shapes.update({
-            "router": (D, cfg.n_experts), "router_bias": (cfg.n_experts,),
-            "we_gate": (held, D, F), "we_up": (held, D, F),
-            "we_down": (held, F, D)})
+        shapes["router"] = (D, cfg.n_experts)
+        if cfg.router_score == "sigmoid":   # the selection bias is a sigmoid's
+            shapes["router_bias"] = (cfg.n_experts,)
+        shapes.update({"we_gate": (held, D, F), "we_up": (held, D, F),
+                       "we_down": (held, F, D)})
         if S:
             shapes.update({"w1": (D, 2 * S * F), "w2": (S * F, D)})
     return shapes
@@ -453,7 +484,7 @@ def _diff_attention(q, k, v, lp, lam0, mask, cfg):
 def key_ranges(kind, T, cfg):
     """The key ranges ``[T, 4]`` a layer of ``kind`` sees: causal within
     the last ``sliding_window`` keys, or causal."""
-    if kind == "window":
+    if kind in ("window", "swa"):
         return _fa.window_ranges(T, cfg.sliding_window)
     return _fa.causal_ranges(T)
 
@@ -476,14 +507,16 @@ def join(x, y, cfg):
 
 
 def _layer(kind, emits, cfg):
-    """One layer of ``kind`` as ``f(h, lp, lam0, memory) -> (h, emitted,
-    stats)``: ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross layer,
-    else None; ``emitted`` is what an emitting mamba (``s``) or full layer
-    (``(k, v)``) hands on, else None; ``stats`` routed experts' ``[4]``
-    statistics, None of a dense feed-forward."""
+    """One layer of ``kind`` as ``f(h, lp, lam0, memory, rope=None) -> (h,
+    emitted, stats)``: ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross
+    layer, else None; ``rope`` the kind's ``(cos, sin)``
+    (``llama.rope_table``), None where it has no positions; ``emitted`` is
+    what an emitting mamba (``s``) or full layer (``(k, v)``) hands on, else
+    None; ``stats`` routed experts' ``[4]`` statistics, None of a dense
+    feed-forward."""
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    def f(h, lp, lam0, memory):
+    def f(h, lp, lam0, memory, rope=None):
         # the products' matrices in the compute dtype; norms, the
         # convolution, the scan's A, D and step bias and lambda's vectors
         # stay in the parameters'
@@ -502,14 +535,17 @@ def _layer(kind, emits, cfg):
         elif kind == "kda":
             with jax.named_scope(SCOPE_KDA_MIXER):
                 y = _kda(norm1(), lp, cfg)
-        elif kind == "attention":
-            with jax.named_scope(SCOPE_ATTENTION):
+        elif kind in _PLAIN:
+            with jax.named_scope(_PLAIN[kind]):
                 u = norm1()
                 q, k, v = jnp.split(u @ lp["wqkv"],
                                     (H * Dh, (H + Hkv) * Dh), axis=-1)
+                q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh)
+                if rope is not None:
+                    with jax.named_scope(SCOPE_ROPE):
+                        q, k = rotate(q, *rope), rotate(k, *rope)
                 o = local_attention(
-                    q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh),
-                    v.reshape(B, T, Hkv, Dh),
+                    q, k, v.reshape(B, T, Hkv, Dh),
                     sm_scale=cfg.attention_multiplier or None,
                     mask=key_ranges(kind, T, cfg)).reshape(B, T, H * Dh)
                 if cfg.attn_gate:
@@ -595,6 +631,13 @@ def layer_stack(h, layers, cfg, policy=None, with_stats=False):
     over the layers (``moe.ROUTING_STATS``), None of a dense trunk."""
     check(cfg)
     m = kv = stats = None
+    ropes = {}      # a kind's (cos, sin), made once for all its layers
+    for kind, table in cfg.rope_tables:
+        if kind in cfg.layer_kinds:
+            if _metrics.ACTIVE:
+                _m_ropes.inc(kind=kind, type=table.rope_type)
+            with jax.named_scope(_PLAIN[kind]), jax.named_scope(SCOPE_ROPE):
+                ropes[kind] = rope_table(table, cfg.head_dim, h.shape[1])
     made = {}       # one function a (kind, emits): equal layers trace once
     for kind, first, ids, emits in _runs(cfg):
         n = len(ids)
@@ -611,7 +654,7 @@ def layer_stack(h, layers, cfg, policy=None, with_stats=False):
                                      layers[kind])
         if n == 1:
             h, emitted, new = f(h, jax.tree_util.tree_map(lambda w: w[0], lps),
-                                lam0[0], memory)
+                                lam0[0], memory, ropes.get(kind))
             if kind == "mamba" and emits:
                 m = emitted
             elif emits:
@@ -620,6 +663,7 @@ def layer_stack(h, layers, cfg, policy=None, with_stats=False):
                 stats = new if stats is None else stats + new
         else:       # never a routed layer: ``_runs`` gives each its own run
             h, _ = lax.scan(
-                lambda h_, at: (f(h_, at[0], at[1], memory)[0], None),
+                lambda h_, at: (f(h_, at[0], at[1], memory,
+                                  ropes.get(kind))[0], None),
                 h, (lps, lam0))
     return (h, stats) if with_stats else h

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -40,6 +41,34 @@ _m_remat = _metrics.counter(
     "says which named residuals the policy keeps beside the layer's "
     "input (flash: the attention kernels' output and row statistics)",
     labels=("policy", "saves"))
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeTable:
+    """One layer kind's rotary table: Hugging Face's ``rope_parameters`` of
+    a layer type.  ``rope_type`` "default" is plain RoPE at ``theta``;
+    "yarn" (arXiv 2309.00071, ``transformers``' ``_compute_yarn_parameters``)
+    stretches it by ``factor`` from ``original_max_position_embeddings``
+    positions (:func:`rope_inv_freq`, ``truncate`` at its default);
+    ``attention_factor`` multiplies cos and sin both (0 = YaRN's ``0.1
+    ln(factor) + 1``)."""
+    theta: float = 500000.0
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+
+    def __post_init__(self):
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError("rope_type must be 'default' or 'yarn', got "
+                             f"{self.rope_type!r}")
+        if self.rope_type == "yarn" and (
+                self.factor < 1.0 or self.original_max_position_embeddings <= 0):
+            raise ValueError(
+                "a yarn table needs factor >= 1 and the "
+                "original_max_position_embeddings it stretches from")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +142,8 @@ class LlamaConfig:
     n_shared_experts: int = 0
     # a trunk whose layers are of several kinds (models/hybrid.py): each
     # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" |
-    # "mamba2" | "attention" | "kda" (() = the trunk of identical layers here),
+    # "mamba2" | "attention" | "kda" | "swa" (() = the trunk of identical layers
+    # here),
     # and its index in the published model (() = its place in the trunk);
     # the window of the "window" kind's attention; the state-space
     # mixers' inner width, states (a channel for "mamba", a head's
@@ -124,10 +154,13 @@ class LlamaConfig:
     # ssm_state and whose values ssm_inner / ssm_heads wide, a convolution
     # ssm_conv wide and chunks of ssm_chunk positions; ``attn_gate`` puts a
     # sigmoid gate of the layer's input on the "attention" kind's output,
-    # before ``wo``.  Such a trunk has a fused gate/up MLP (or, with
-    # ``n_experts``, dropless routed experts) and no positional encoding;
-    # its layers' norm is ``trunk_norm``: "layernorm" (weight and bias)
-    # or "rmsnorm" (weight).
+    # before ``wo``.  "swa" is the "attention" kind's plain grouped-query
+    # attention under the window.  Such a trunk has a fused gate/up MLP
+    # (or, with ``n_experts``, dropless routed experts); its layers' norm
+    # is ``trunk_norm``: "layernorm" (weight and bias) or "rmsnorm"
+    # (weight).  Positions are a kind's: ``rope_tables`` pairs "attention"
+    # or "swa" with its :class:`RopeTable`, ``((kind, table), ...)``, and
+    # a kind without one (every other kind; () = all) has none.
     layer_kinds: tuple = ()
     layer_ids: tuple = ()
     sliding_window: int = 0
@@ -139,6 +172,7 @@ class LlamaConfig:
     ssm_groups: int = 1
     ssm_chunk: int = 256
     attn_gate: bool = False
+    rope_tables: tuple = ()
     trunk_norm: str = "layernorm"
     # Granite's four multipliers, of the trunk of several kinds alone
     # (the trunk of identical layers refuses them), each at what a trunk
@@ -165,11 +199,11 @@ class LlamaConfig:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError("router_score must be 'softmax' or 'sigmoid', "
                              f"got {self.router_score!r}")
-        if not self.layer_kinds and (self.n_shared_experts
-                                     or self.attn_gate):
+        if not self.layer_kinds and (self.n_shared_experts or self.attn_gate
+                                     or self.rope_tables):
             raise ValueError(
-                "n_shared_experts and attn_gate are wired through the trunk "
-                "of several kinds (layer_kinds) alone")
+                "n_shared_experts, attn_gate and rope_tables are wired "
+                "through the trunk of several kinds (layer_kinds) alone")
         multipliers = (self.embedding_multiplier, self.residual_multiplier,
                        self.attention_multiplier, self.logits_scaling)
         if not self.layer_kinds and multipliers != (1.0, 1.0, 0.0, 1.0):
@@ -429,6 +463,56 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+def rope_inv_freq(table: RopeTable, head_dim: int):
+    """``(inverse frequencies [head_dim / 2], the factor on cos and sin)``
+    of one kind's table, in numpy (float64, rounded once to float32).
+    Plain: ``e[j] = theta ** (-2j / head_dim)``, factor 1.  YaRN: ``n[j] =
+    e[j] / factor``; a dimension that turns ``r`` times within the original
+    length is ``c(r) = head_dim ln(L / (2 pi r)) / (2 ln theta)``; ``low =
+    floor(c(beta_fast))``, ``high = ceil(c(beta_slow))``, clipped to ``[0,
+    head_dim - 1]``; ``ramp[j] =
+    clip((j - low) / (high - low), 0, 1)``; ``inv_freq[j] = n[j] ramp[j] +
+    e[j] (1 - ramp[j])``: the fast dimensions keep their frequency, the
+    slow ones are interpolated."""
+    half = head_dim // 2
+    j = np.arange(half, dtype=np.float64)
+    e = float(table.theta) ** (-j / half)
+    if table.rope_type == "default":
+        return e.astype(np.float32), 1.0
+
+    def turns(r):
+        return (head_dim * math.log(table.original_max_position_embeddings
+                                    / (r * 2 * math.pi))
+                / (2 * math.log(table.theta)))
+
+    low = max(math.floor(turns(table.beta_fast)), 0)
+    high = min(math.ceil(turns(table.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001       # transformers': no division by zero
+    ramp = np.clip((j - low) / (high - low), 0.0, 1.0)
+    inv_freq = e / table.factor * ramp + e * (1.0 - ramp)
+    return (inv_freq.astype(np.float32),
+            table.attention_factor or 0.1 * math.log(table.factor) + 1.0)
+
+
+def rope_table(table: RopeTable, head_dim: int, T: int):
+    """``(cos, sin) [T, head_dim / 2]`` float32 of the positions
+    ``arange(T)``, the table's factor on both."""
+    inv_freq, factor = rope_inv_freq(table, head_dim)
+    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * inv_freq
+    return (jnp.cos(angles) * jnp.float32(factor),
+            jnp.sin(angles) * jnp.float32(factor))
+
+
+def rotate(x, cos, sin):
+    """:func:`_rope`'s rotation (rotate-half) by a table made beforehand:
+    x ``[B, T, H, D]``, cos and sin ``[T, D / 2]``; float32 inside."""
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return out.astype(x.dtype)
+
+
 def _attention(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
                mask=None):
     """One attention sublayer on tp-local heads and sp-local sequence.
@@ -641,17 +725,19 @@ def hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
 
 def _hybrid_hidden(params, tokens, cfg: LlamaConfig, par: ParallelSpec,
                    positions, mask):
-    """:func:`hidden` of a trunk of several kinds (models/hybrid.py): no
-    positions at all, each kind's own mask, a final norm of the trunk's
-    kind; with routed experts, their routing statistics summed over the
-    layers in place of the zero."""
+    """:func:`hidden` of a trunk of several kinds (models/hybrid.py): each
+    kind's own mask and, where the config gives the kind a rotary table,
+    its own positions (``arange(T)`` a row; none handed in), a final norm
+    of the trunk's kind; with routed experts, their routing statistics
+    summed over the layers in place of the zero."""
     from . import hybrid
     if (positions is not None or mask is not None
             or any(a is not None for a in (par.tp_axis, par.sp_axis,
                                            par.pp_axis))):
         raise NotImplementedError(
-            "a trunk of several kinds takes no positions and no mask and "
-            "runs under plain data parallelism only")
+            "a trunk of several kinds takes no positions and no mask from "
+            "outside (a kind's rotary table counts arange(T) a row, its "
+            "mask is its own) and runs under plain data parallelism only")
     with jax.named_scope(SCOPE_EMBED):
         h = _embed_lookup(params["embed"], tokens, cfg, par)
     h, stats = hybrid.layer_stack(

@@ -32,6 +32,8 @@ SCOPE_SSD_MIXER = "hvd_ssd_mixer"  # the Mamba-2 mixer: norm1, projections, conv
 SCOPE_SSD_SCAN = "hvd_ssd_scan"    # inside it: ops/ssd_scan.py's call, whatever computes it
 SCOPE_KDA_MIXER = "hvd_kda_mixer"  # Kimi Delta Attention: norm1, projections, conv, scan, gated norm
 SCOPE_KDA_SCAN = "hvd_kda_scan"    # inside it: ops/kda_scan.py's call, whatever computes it
+SCOPE_WINDOW_ATTENTION = "hvd_window_attention"  # plain GQA under the window ("swa"), as hvd_attention
+SCOPE_ROPE = "hvd_rope"            # inside either: a kind's rotary table and its products with q and k
 # Inside ``hvd_mlp`` where the feed-forward is routed (``models/moe.py``)
 SCOPE_ROUTE = "hvd_moe_route"        # router logits, scores, top-k
 SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine

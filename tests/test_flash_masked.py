@@ -475,6 +475,38 @@ def test_masked_kernels_lower_for_the_chip(B, H, Hkv, given, monkeypatch):
         rf"= \w+\[(?:{shapes})\]\S* (?:transpose|copy)\(", text)
 
 
+@pytest.mark.parametrize("kind,dead,mixed,full", [
+    ("window", 931, 62, 31), ("causal", 496, 32, 496)])
+def test_plain_gqa_kernels_lower_at_16384_positions(kind, dead, mixed, full,
+                                                    monkeypatch):
+    """Mosaic takes the three masked kernels at the benchmark's
+    mellum2-12b-a2.5b cell, the longest row they have had: 16,384
+    positions (32 x 32 tiles of 512), 32 query heads over 4 at head_dim
+    128 on the caller's ``[B, T, H*D]``, under the window of 1,024 (93
+    tiles visited: the diagonal's 32 and the tile two back's 30 mixed, 31
+    full) and under the causal ranges (528 visited, 32 mixed); the SMEM
+    tables, the heads a step and the step's VMEM hold at that length."""
+    T = 16384
+    ranges = (fa.window_ranges(T, 1024) if kind == "window"
+              else fa.causal_ranges(T))
+    classes = fa.tile_classes(ranges[None], 512, 512, T)
+    assert [int((classes == c).sum()) for c in (0, 1, 2)] == [dead, mixed, full]
+    assert np.array_equal(
+        fa.dense_mask(ranges[:2048], 2048),
+        (lambda i, j: (j <= i) & ((i - j < 1024) | (kind == "causal")))(
+            np.arange(2048)[:, None], np.arange(2048)[None, :]))
+    one_chip = _described_chip(monkeypatch)
+    q, k = (jax.ShapeDtypeStruct((1, T, h, 128), jnp.bfloat16,
+                                 sharding=one_chip) for h in (32, 4))
+    assert fa.supported(q, k, k, True, ranges)
+    text = jax.jit(lambda q, k, v: jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, mask=ranges).astype(jnp.float32).sum(),
+        (0, 1, 2))(q, k, v)).lower(q, k, k).compile().as_text()
+    for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert name in text
+
+
 # ----------------- differential attention's calls at published widths
 # (models/hybrid.py: values twice as wide as queries and keys; the trunk's
 # other tests are tests/test_hybrid.py)

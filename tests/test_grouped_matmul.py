@@ -397,6 +397,18 @@ def test_grouped_matmul_kernels_lower_at_a_hidden_size_of_4096(call, monkeypatch
     _grouped_kernels_lower(call, monkeypatch, 2560, 4096, 1280, 8, 8192)
 
 
+@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+def test_grouped_matmul_kernels_lower_at_the_most_rows_they_have_had(call, monkeypatch):
+    """The same at the mellum2-12b-a2.5b cell: a chunk of 49,152 rows (half
+    over the 32,768 pairs that even routing sends a layer) of width 2,304
+    over 16 held experts of width 896, combined into 16,384 tokens;
+    ``tgmm``'s float32 ``[2304, 896]`` accumulator, 8.3 MB, is walked whole
+    like SDAR's ``[2048, 768]``, not in two blocks like Solar's."""
+    assert moe._chunk_rows(16384, 8, 16, 64) == 49152
+    assert gm._tgmm_split(2304, 896, 2) == gm._tgmm_split(896, 2304, 2) == 1
+    _grouped_kernels_lower(call, monkeypatch, 49152, 2304, 896, 16, 16384)
+
+
 def _grouped_kernels_lower(call, monkeypatch, R, D, F, E, tokens):
     one_chip = _described_chip(monkeypatch)
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,

@@ -356,3 +356,156 @@ def test_the_attention_gate_is_a_sigmoid_of_the_input_before_wo():
     ungated, _, _ = hybrid._layer("attention", False, _solar_cfg(
         **{**vars(cfg), "attn_gate": False}))(h, lp, 0.0, None)
     assert float(jnp.abs(ungated - got).max()) > 1e-3
+
+
+# ------------------------------ plain GQA under a window, a table a kind
+# (Mellum 2's ``sliding_attention`` and ``full_attention``: the ``swa`` and
+# ``attention`` kinds with ``cfg.rope_tables``; the tables themselves are
+# held to the equations in tests/test_llama.py)
+
+YARN = llama.RopeTable(theta=10000.0, rope_type="yarn", factor=4,
+                       original_max_position_embeddings=64, beta_fast=2,
+                       beta_slow=0.125)
+PLAIN = llama.RopeTable(theta=10000.0)
+
+
+def _mellum_cfg(**kw):
+    base = dict(layer_kinds=("swa",), trunk_norm="rmsnorm", sliding_window=100,
+                rope_tables=(("attention", YARN), ("swa", PLAIN)))
+    return _cfg(**{**base, **kw})
+
+
+@pytest.mark.parametrize("kind", ["swa", "attention"])
+def test_the_two_plain_kinds_masks_are_the_dense_ones(kind):
+    """``swa``: Hugging Face's ``sliding_window`` semantics, ``i - j <
+    window`` and ``j <= i``; ``attention``: causal."""
+    T, cfg = 384, _mellum_cfg()
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    want = (j <= i) & ((i - j < cfg.sliding_window) | (kind == "attention"))
+    np.testing.assert_array_equal(
+        fa.dense_mask(hybrid.key_ranges(kind, T, cfg), T), want)
+    assert want[300].sum() == (100 if kind == "swa" else 301)
+
+
+@pytest.mark.parametrize("interpret", [True, False], ids=["kernels", "xla"])
+@pytest.mark.parametrize("kind", ["swa", "attention"])
+def test_plain_gqa_with_a_rotary_table_follows_its_dense_formula(
+        kind, interpret, monkeypatch, pallas_interpret):
+    """One layer of either kind, forward and every gradient, through the
+    masked flash kernels and through the XLA path: ``q`` and ``k`` rotated
+    by the kind's own table (float64 here), the window or the causal mask,
+    no q/k norm, ``wo``; then the frame's feed-forward."""
+    pallas_interpret(interpret)
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    cfg, T, B = _mellum_cfg(layer_kinds=(kind,)), 256, 2
+    table = dict(cfg.rope_tables)[kind]
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0], llama.init_params(cfg, jax.random.key(0))["layers"][kind])
+    assert set(lp) == {"norm1_w", "norm2_w", "w1", "w2", "wqkv", "wo"}
+    h = jax.random.normal(jax.random.key(1), (B, T, H * DH))
+    w = jax.random.normal(jax.random.key(2), (B, T, H * DH))
+    inv, factor = llama.rope_inv_freq(table, DH)
+    assert (factor > 1.1) == (kind == "attention")
+    angles = np.arange(T)[:, None] * inv.astype(np.float64)
+    cos, sin = (jnp.asarray(f(angles) * factor, jnp.float32)[None, :, None]
+                for f in (np.cos, np.sin))
+    live = fa.dense_mask(hybrid.key_ranges(kind, T, cfg), T)
+
+    def rot(x):
+        x1, x2 = x[..., :DH // 2], x[..., DH // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+    def dense(h, lp):
+        u = hybrid.norm(h, lp["norm1_w"], None, cfg)
+        q, k, v = jnp.split(u @ lp["wqkv"], (H * DH, (H + HKV) * DH), axis=-1)
+        q, k = rot(q.reshape(B, T, H, DH)), rot(k.reshape(B, T, HKV, DH))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, 2)) / 8.0
+        p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), -1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v.reshape(B, T, HKV, DH), 2, 2))
+        a = h + o.reshape(B, T, H * DH) @ lp["wo"]
+        return a + hybrid._mlp(hybrid.norm(a, lp["norm2_w"], None, cfg), lp)
+
+    layer = hybrid._layer(kind, False, cfg)
+    rope = llama.rope_table(table, DH, T)
+    got = jax.value_and_grad(lambda h, lp: (layer(h, lp, 0.0, None, rope)[0]
+                                            * w).sum(), (0, 1))(h, lp)
+    want = jax.value_and_grad(lambda h, lp: (dense(h, lp) * w).sum(), (0, 1))(h, lp)
+    assert abs(float(got[0] - want[0])) < 1e-4 * max(float(jnp.abs(want[0])), 1.0)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()),
+                                   rtol=2e-3)
+    # without its table the layer is another one; the other kind's too
+    bare = layer(h, lp, 0.0, None)[0]
+    other = layer(h, lp, 0.0, None, llama.rope_table(
+        YARN if kind == "swa" else PLAIN, DH, T))[0]
+    here = layer(h, lp, 0.0, None, rope)[0]
+    assert float(jnp.abs(bare - here).max()) > 1e-3
+    assert float(jnp.abs(other - here).max()) > 1e-3
+
+
+def _rope_counts():
+    family = metrics.registry().to_dict().get("hvd_rope_tables_total", {})
+    return {(s["labels"]["kind"], s["labels"]["type"]): s["value"]
+            for s in family.get("series", [])}
+
+
+def test_a_trunks_rotary_tables_are_made_once_a_kind_and_counted(monkeypatch):
+    """Three ``swa`` layers and one ``attention``: two tables (two ``cos``)
+    whatever the layers, ``hvd_rope_tables_total`` grows by one a kind,
+    both scopes and ``hvd_rope`` are in the lowered names; a trunk without
+    tables makes none and is position-free (Granite's and Solar's)."""
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+    kinds = ("swa", "swa", "swa", "attention")
+    cfg = _mellum_cfg(n_layers=4, layer_kinds=kinds, remat=True)
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert {k: v["wqkv"].shape[0] for k, v in params["layers"].items()} == {
+        "swa": 3, "attention": 1}
+    h = jax.random.normal(jax.random.key(1), (1, 128, H * DH))
+    run = lambda c: (lambda h, ls: hybrid.layer_stack(
+        h, ls, c, llama.remat_policy("full")))
+    before = _rope_counts()
+    jaxpr = jax.make_jaxpr(run(cfg))(h, params["layers"])
+    grew = {k: v - before.get(k, 0) for k, v in _rope_counts().items()}
+    assert {k: v for k, v in grew.items() if v} == {
+        ("swa", "default"): 1, ("attention", "yarn"): 1}
+    outer = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert outer.count("cos") == outer.count("sin") == 2
+    text = jax.jit(run(cfg)).lower(h, params["layers"]).as_text(debug_info=True)
+    for scope in ("hvd_window_attention/hvd_rope", "hvd_attention/hvd_rope"):
+        assert scope in text, scope
+    bare = _mellum_cfg(n_layers=4, layer_kinds=kinds, rope_tables=())
+    before = _rope_counts()
+    plain = jax.make_jaxpr(run(bare))(h, params["layers"])
+    assert _rope_counts() == before and " cos " not in str(plain)
+    # a shuffled row moves a position-free trunk's outputs with it, and not
+    # one that counts positions
+    perm = jax.random.permutation(jax.random.key(2), 128)
+    full = _mellum_cfg(n_layers=1, layer_kinds=("attention",), rope_tables=())
+    lp = llama.init_params(full, jax.random.key(3))["layers"]
+    last = lambda c, x: hybrid.layer_stack(x, lp, c)[:, -1]
+    moved = h[:, jnp.concatenate([perm[perm != 127], jnp.array([127])])]
+    np.testing.assert_allclose(last(full, moved), last(full, h), atol=1e-5)
+    tabled = _mellum_cfg(n_layers=1, layer_kinds=("attention",))
+    assert float(jnp.abs(last(tabled, moved) - last(tabled, h)).max()) > 1e-3
+
+
+@pytest.mark.parametrize("kw,error", [
+    (dict(sliding_window=0), "sliding_window"),
+    (dict(rope_tables=(("full", PLAIN),)), "one table at most"),
+    (dict(rope_tables=(("swa", PLAIN), ("swa", YARN))), "one table at most")])
+def test_plain_kinds_that_cannot_be_are_refused(kw, error):
+    with pytest.raises(ValueError, match=error):
+        hybrid.check(_mellum_cfg(**kw))
+
+
+def test_a_softmax_routers_layer_carries_no_selection_bias():
+    """The bias moves a sigmoid router's choice; a softmax router has none
+    (Mellum 2's, SDAR's), so its layer has no such leaf."""
+    cfg = _solar_cfg(layer_kinds=("swa",), n_layers=1, router_score="softmax",
+                     sliding_window=8, attn_gate=False, n_shared_experts=0)
+    assert "router_bias" not in hybrid.layer_shapes(cfg, "swa")
+    assert "router_bias" in hybrid.layer_shapes(
+        _solar_cfg(layer_kinds=("kda",), n_layers=1), "kda")
+    assert set(hybrid.layer_shapes(cfg, "swa")) == {
+        "norm1_w", "norm2_w", "wqkv", "wo", "router", "we_gate", "we_up", "we_down"}

@@ -613,3 +613,98 @@ def test_layer_stack_counts_its_remat_policy(monkeypatch):
         ("full", "flash"): 1, ("dots", "flash"): 2}
     with pytest.raises(ValueError, match="remat_policy"):
         trace(remat_policy="some")
+
+
+# --------------------------------------------- a kind's rotary table
+# (models/hybrid.py hands one to its ``attention`` and ``swa`` layers; the
+# trunk of identical layers keeps ``_rope``, whose lowered text is pinned in
+# tests/benchmark/test_bench_phi4flash.py)
+
+MELLUM_YARN = llama.RopeTable(
+    theta=500000, rope_type="yarn", factor=16,
+    original_max_position_embeddings=8192, beta_fast=32, beta_slow=1,
+    attention_factor=1.2772588722239782)
+
+
+def _yarn_in_float64(t, dim):
+    """``transformers``' ``_compute_yarn_parameters``, written out."""
+    import math
+    j = np.arange(dim // 2, dtype=np.float64)
+    e = float(t.theta) ** (-2 * j / dim)
+    c = lambda r: (dim * math.log(t.original_max_position_embeddings
+                                  / (r * 2 * math.pi)) / (2 * math.log(t.theta)))
+    low, high = max(math.floor(c(t.beta_fast)), 0), min(math.ceil(c(t.beta_slow)), dim - 1)
+    ramp = np.clip((j - low) / (high - low), 0, 1)
+    return low, high, e / t.factor * ramp + e * (1 - ramp)
+
+
+def test_yarn_table_at_the_published_numbers_by_hand():
+    """Mellum 2's ``full_attention`` table: ``c(32) = 18.08``, ``c(1) =
+    34.98``, so ``low`` 18 and ``high`` 35; five frequencies worked out by
+    hand; ``attention_factor`` = 0.1 ln 16 + 1, given or not."""
+    low, high, want = _yarn_in_float64(MELLUM_YARN, 128)
+    assert (low, high) == (18, 35)
+    inv_freq, factor = llama.rope_inv_freq(MELLUM_YARN, 128)
+    assert inv_freq.dtype == np.float32 and inv_freq.shape == (64,)
+    by_hand = {0: 1.0, 18: 0.024955408670558694,        # fast: as they were
+               26: 0.004839421345719893 * (8 / 17 / 16 + 9 / 17),
+               35: 0.0007644969883171747 / 16,          # slow: interpolated
+               63: 2.455140791131609e-06 / 16}
+    for j, value in by_hand.items():
+        assert inv_freq[j] == np.float32(value), j
+    np.testing.assert_array_equal(inv_freq, want.astype(np.float32))
+    assert factor == 1.2772588722239782
+    assert llama.rope_inv_freq(dataclasses.replace(
+        MELLUM_YARN, attention_factor=0.0), 128)[1] == pytest.approx(
+            1.2772588722239782, rel=1e-15)
+    plain, one = llama.rope_inv_freq(llama.RopeTable(theta=500000), 128)
+    assert one == 1.0
+    np.testing.assert_array_equal(
+        plain, (500000.0 ** (-np.arange(64) / 64)).astype(np.float32))
+    # the ramp rises between whole dimensions (``truncate``'s default):
+    # 18 keeps its frequency, 19 is a seventeenth of the way
+    assert inv_freq[19] == np.float32(
+        500000.0 ** (-19 / 64) * (1 / 17 / 16 + 16 / 17))
+
+
+@pytest.mark.parametrize("table", [llama.RopeTable(theta=10000.0), llama.RopeTable(
+    theta=10000.0, rope_type="yarn", factor=4, original_max_position_embeddings=16,
+    beta_fast=2, beta_slow=0.125)], ids=["default", "yarn"])
+def test_rotary_table_and_rotation_against_float64(table):
+    """cos and sin of ``p * inv_freq[j]``, both times the table's factor,
+    and the rotate-half products, against the equations in float64; the
+    plain table's rotation is ``_rope``'s."""
+    T, dim = 64, 32
+    cos, sin = llama.rope_table(table, dim, T)
+    if table.rope_type == "yarn":
+        low, high, inv = _yarn_in_float64(table, dim)
+        assert (low, high) == (0, 6) and 0 < ((np.arange(16) - low) / 6)[3] < 1
+        factor = 0.1 * np.log(4.0) + 1.0
+    else:
+        inv, factor = 10000.0 ** (-np.arange(16) / 16), 1.0
+    angles = np.arange(T)[:, None] * inv.astype(np.float32).astype(np.float64)
+    np.testing.assert_allclose(cos, np.cos(angles) * factor, atol=2e-5)
+    np.testing.assert_allclose(sin, np.sin(angles) * factor, atol=2e-5)
+    x = np.asarray(jax.random.normal(jax.random.key(0), (2, T, 3, dim)), np.float64)
+    x1, x2 = x[..., :16], x[..., 16:]
+    c, s = (a[None, :, None] * factor for a in (np.cos(angles), np.sin(angles)))
+    want = np.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], -1)
+    got = llama.rotate(jnp.asarray(x, jnp.float32), cos, sin)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    if table.rope_type == "default":
+        pos = jnp.broadcast_to(jnp.arange(T)[None], (2, T))
+        np.testing.assert_allclose(
+            got, llama._rope(jnp.asarray(x, jnp.float32), pos, 10000.0), atol=1e-5)
+    else:   # both halves of every pair carry the factor: a score its square
+        plain = llama.rotate(jnp.asarray(x, jnp.float32), cos / factor, sin / factor)
+        np.testing.assert_allclose(got, plain * factor, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kw,error", [
+    (dict(rope_type="linear"), "rope_type"), (dict(rope_type="yarn"), "yarn table"),
+    (dict(rope_type="yarn", factor=0.5, original_max_position_embeddings=8), "yarn table")])
+def test_rotary_tables_that_cannot_be_are_refused(kw, error):
+    with pytest.raises(ValueError, match=error):
+        llama.RopeTable(**kw)
+    with pytest.raises(ValueError, match="layer_kinds"):
+        llama.LlamaConfig(rope_tables=(("attention", llama.RopeTable()),))
