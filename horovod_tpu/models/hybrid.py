@@ -132,6 +132,7 @@ from jax import lax
 from .. import metrics as _metrics
 from ..ops import flash_attention as _fa
 from ..ops import mamba2_mixer as _mixer
+from ..ops import rope as _rotary
 from ..ops.kda_scan import kda_scan
 from ..ops.selective_scan import selective_scan
 from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
@@ -538,10 +539,23 @@ def _layer(kind, emits, cfg):
         elif kind in _PLAIN:
             with jax.named_scope(_PLAIN[kind]):
                 u = norm1()
-                q, k, v = jnp.split(u @ lp["wqkv"],
-                                    (H * Dh, (H + Hkv) * Dh), axis=-1)
+                qkv = u @ lp["wqkv"]
+                widths = (H * Dh, Hkv * Dh, Hkv * Dh)
+                joined = rope is not None and _rotary.supported(
+                    qkv, *rope, widths)
+                if joined:
+                    # the kernel reads the product's columns where they
+                    # lie and writes q, k (rotated) and v each whole:
+                    # no slice before it, and backward no concatenate
+                    with jax.named_scope(SCOPE_ROPE):
+                        q, k, v = _rotary.split_rotate(
+                            qkv, *rope, widths, (True, True, False))
+                else:
+                    q, k, v = jnp.split(qkv, (H * Dh, (H + Hkv) * Dh),
+                                        axis=-1)
                 q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh)
-                if rope is not None:
+                if rope is not None and not joined:
+                    _rotary.count_xla()
                     with jax.named_scope(SCOPE_ROPE):
                         q, k = rotate(q, *rope), rotate(k, *rope)
                 o = local_attention(

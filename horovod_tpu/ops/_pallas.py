@@ -1,8 +1,8 @@
 """What a Pallas file of this package needs that is not its kernel.
 
-The kernel files (``flash_attention``, ``grouped_matmul``,
-``selective_scan``, ``ssd_scan``, ``mamba2_mixer``, ``kda_scan``) keep
-their kernels, their shape rules and their numbers; this holds what they
+The seven kernel files (``flash_attention``, ``grouped_matmul``,
+``selective_scan``, ``ssd_scan``, ``mamba2_mixer``, ``kda_scan``, ``rope``)
+keep their kernels, their shape rules and their numbers; this holds what they
 all said alike: whether a kernel can run here at all (:func:`off_chip`,
 the test every ``_refusal`` opens with) and on which dtypes
 (:func:`dtype_refusal`), how a refusal is said aloud (:func:`verdict`),

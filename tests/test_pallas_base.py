@@ -1,4 +1,4 @@
-"""What ``ops/_pallas.py`` hands to Mosaic for the six kernel files, call
+"""What ``ops/_pallas.py`` hands to Mosaic for the seven kernel files, call
 for call: the one thing moving their scaffolding behind one module could
 change in silence.  The table below was written out from the tree before
 that move (PR 44's) and passes there as it stands."""
@@ -84,7 +84,15 @@ def _mixer(turned):
         sds((1, T, Di), BF), sds((Di,), F32))
 
 
-# the shapes of the six files' ``*_lower_for_the_chip`` cases
+def _rope(shape, widths, turned, table):
+    from horovod_tpu.ops import rope
+    operands = (sds(shape, BF), sds(table, F32), sds(table, F32))
+    return jax.grad(lambda a, cos, sin: sum(
+        part.astype(F32).sum() for part in rope.split_rotate(
+            a, cos, sin, widths, turned))), operands
+
+
+# the shapes of the seven files' ``*_lower_for_the_chip`` cases
 CALLS = {
     "flash-packed-bert": lambda: _flash(32, 128, 12, 12, 64, causal=False),
     "flash-packed-gqa": lambda: _flash(2, 256, 8, 2, 128),
@@ -114,6 +122,8 @@ CALLS = {
     "kda-scan": _kda_scan,
     "mixer-rows": lambda: _mixer(False),
     "mixer-turned": lambda: _mixer(True),
+    "rope-mellum-qkv": lambda: _rope(
+        (1, 16384, 5120), (4096, 512, 512), (True, True, False), (16384, 64)),
 }
 
 # (kernel, dimension_semantics, vmem_limit_bytes) of every pallas_call the
@@ -208,6 +218,10 @@ HANDED = {
         ("hvd_gated_norm_fwd", (PA, PA), 64 * MiB),
         ("hvd_gated_norm_bwd", (PA, AR), 64 * MiB),
         ("hvd_conv_silu_bwd", (PA, AR), 64 * MiB),
+    ],
+    "rope-mellum-qkv": [
+        ("hvd_rope_fwd", (PA, PA), 64 * MiB),
+        ("hvd_rope_bwd", (PA, PA), 64 * MiB),
     ],
 }
 
