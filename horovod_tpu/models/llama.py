@@ -33,7 +33,8 @@ from .. import metrics as _metrics
 from ..optim import overlap as _overlap
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
-from ..scopes import SCOPE_ATTENTION, SCOPE_EMBED, SCOPE_HEAD, SCOPE_MLP
+from ..scopes import (SCOPE_ATTENTION, SCOPE_EMBED, SCOPE_HEAD, SCOPE_MLP,
+                      SCOPE_ROPE)
 
 _m_remat = _metrics.counter(
     "hvd_remat_policy_total",
@@ -513,24 +514,65 @@ def rotate(x, cos, sin):
     return out.astype(x.dtype)
 
 
+def _qk_norm_tables(h, layers, cfg: LlamaConfig, positions):
+    """``(cos, sin)`` of ``positions`` for ``ops/rope.py``'s pass of q/k
+    norm and rotation, made once for every layer of a stack, where the
+    trunk norms ``q`` and ``k`` and the kernel takes the products' rows
+    (a TPU, heads of 128, ``T`` a multiple of its block); else None, and
+    :func:`_attention` keeps the standing form."""
+    if not cfg.qk_norm:
+        return None
+    # imported here, as in ``remat_policy``
+    from ..ops import rope as _rotary
+    table = jax.ShapeDtypeStruct(
+        positions.shape + (cfg.head_dim // 2,), jnp.float32)
+    for wn, nn in (("wq", "q_norm"), ("wk", "k_norm")):
+        rows = jax.ShapeDtypeStruct(
+            h.shape[:2] + layers[wn].shape[-1:], h.dtype)
+        # (a leaf of the stack is [layers, ...])
+        weight = jax.ShapeDtypeStruct(layers[nn].shape[1:], h.dtype)
+        if not _rotary.norm_supported(rows, weight, table, table):
+            return None
+    with jax.named_scope(SCOPE_ATTENTION), jax.named_scope(SCOPE_ROPE):
+        # ``_rope``'s own expressions
+        half = cfg.head_dim // 2
+        freqs = cfg.rope_theta ** (
+            -jnp.arange(0, half, dtype=jnp.float32) / half)
+        angles = positions[..., None].astype(jnp.float32) * freqs
+        return jnp.cos(angles), jnp.sin(angles)
+
+
 def _attention(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
-               mask=None):
+               mask=None, rope=None):
     """One attention sublayer on tp-local heads and sp-local sequence.
     ``mask``: the key ranges each query row sees (ops/flash_attention.py);
-    None = causal."""
+    None = causal.  ``rope``: :func:`_qk_norm_tables`' tables, where q/k
+    norm and the rotation are one kernel pass on the products' rows."""
+    from ..ops import rope as _rotary
     B, Tl, D = x.shape
     Dh = cfg.head_dim
     # local head counts under tp (weights arrive pre-sharded)
     Hl = lp["wq"].shape[-1] // Dh
     Hkvl = lp["wk"].shape[-1] // Dh
-    q = (x @ lp["wq"].astype(x.dtype)).reshape(B, Tl, Hl, Dh)
-    k = (x @ lp["wk"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
-    v = (x @ lp["wv"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
-    if cfg.qk_norm:
-        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if rope is not None:
+        # the kernel reads the rows the products write and writes the rows
+        # the flash kernels read: nothing positions-minor between them
+        q, k = x @ lp["wq"].astype(x.dtype), x @ lp["wk"].astype(x.dtype)
+        v = (x @ lp["wv"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
+        with jax.named_scope(SCOPE_ROPE):
+            q = _rotary.norm_rotate(q, lp["q_norm"], *rope, cfg.norm_eps)
+            k = _rotary.norm_rotate(k, lp["k_norm"], *rope, cfg.norm_eps)
+        q, k = q.reshape(B, Tl, Hl, Dh), k.reshape(B, Tl, Hkvl, Dh)
+    else:
+        q = (x @ lp["wq"].astype(x.dtype)).reshape(B, Tl, Hl, Dh)
+        k = (x @ lp["wk"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
+        v = (x @ lp["wv"].astype(x.dtype)).reshape(B, Tl, Hkvl, Dh)
+        if cfg.qk_norm:
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+        _rotary.count_xla(normed=cfg.qk_norm)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     # GQA kv heads pass through as-is: ring circulates only the Hkv heads,
     # ulysses repeats to lcm(Hkv, sp) internally only when it must.
     if par.attn == "ulysses":
@@ -572,7 +614,7 @@ def _dropless(cfg: LlamaConfig) -> bool:
 
 
 def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
-          mask=None):
+          mask=None, rope=None):
     """One transformer block (shape-preserving — the pipeline stage unit).
     Returns (x, aux): the load-balance loss of capacity experts (0 for
     dense MLPs), or dropless experts' ``[4]`` routing statistics.  Each
@@ -580,7 +622,7 @@ def block(x, lp, cfg: LlamaConfig, par: ParallelSpec, positions,
     adds are the layer's."""
     with jax.named_scope(SCOPE_ATTENTION):
         a = _attention(_rmsnorm(x, lp["attn_norm"], cfg.norm_eps),
-                       lp, cfg, par, positions, mask)
+                       lp, cfg, par, positions, mask, rope)
     x = x + a
     with jax.named_scope(SCOPE_MLP):
         y, aux = ffn(_rmsnorm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg, par)
@@ -621,9 +663,12 @@ def _layer_stack(h, layers, cfg: LlamaConfig, par: ParallelSpec, positions,
     keep = ("router",) if _dropless(cfg) else ()
     layers = {n: w if n in keep or w.dtype == cfg.dtype
               else w.astype(cfg.dtype) for n, w in layers.items()}
+    rope = _qk_norm_tables(h, layers, cfg, positions)
     # a mask is closed over, not an argument: one known here (numpy)
     # stays so for the kernels' tile tables
     blk = block if mask is None else functools.partial(block, mask=mask)
+    if rope is not None:
+        blk = functools.partial(blk, rope=rope)
     body = blk
     if cfg.remat:
         body = jax.checkpoint(body, static_argnums=(2, 3),

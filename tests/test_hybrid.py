@@ -193,7 +193,9 @@ def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
         count = lambda fam: {s["labels"]["kind"]: s["value"]
                              for s in fam.get("series", [])}
         after = count(metrics.registry().to_dict()["hvd_layer_kind_total"])
-        assert {k: n - count(before).get(k, 0) for k, n in after.items()} == {
+        # (kinds another file's tests counted in this process stand still)
+        grew = {k: n - count(before).get(k, 0) for k, n in after.items()}
+        assert {k: n for k, n in grew.items() if n} == {
             "mamba": 3, "window": 2, "full": 1, "gmu": 2, "cross": 2}
     want, m, kv, seen = h, None, None, {}
     for i, kind in enumerate(kinds):

@@ -92,6 +92,14 @@ def _rope(shape, widths, turned, table):
             a, cos, sin, widths, turned))), operands
 
 
+def _qknorm_rope(shape, table):
+    from horovod_tpu.ops import rope
+    operands = (sds(shape, BF), sds((128,), BF), sds(table, F32),
+                sds(table, F32))
+    return jax.grad(lambda a, w, cos, sin: rope.norm_rotate(
+        a, w, cos, sin, 1e-6).astype(F32).sum(), (0, 1)), operands
+
+
 # the shapes of the seven files' ``*_lower_for_the_chip`` cases
 CALLS = {
     "flash-packed-bert": lambda: _flash(32, 128, 12, 12, 64, causal=False),
@@ -124,6 +132,9 @@ CALLS = {
     "mixer-turned": lambda: _mixer(True),
     "rope-mellum-qkv": lambda: _rope(
         (1, 16384, 5120), (4096, 512, 512), (True, True, False), (16384, 64)),
+    "qknorm-rope-sdar-q": lambda: _qknorm_rope(
+        (2, 8192, 4096), (2, 8192, 64)),
+    "qknorm-rope-sdar-k": lambda: _qknorm_rope((2, 8192, 512), (2, 8192, 64)),
 }
 
 # (kernel, dimension_semantics, vmem_limit_bytes) of every pallas_call the
@@ -222,6 +233,15 @@ HANDED = {
     "rope-mellum-qkv": [
         ("hvd_rope_fwd", (PA, PA), 64 * MiB),
         ("hvd_rope_bwd", (PA, PA), 64 * MiB),
+    ],
+    # (a step's blocks twice and 4 MiB: ops/rope.py says why)
+    "qknorm-rope-sdar-q": [
+        ("hvd_rope_norm_fwd", (PA, PA), 12 * MiB),
+        ("hvd_rope_norm_bwd", (PA, PA), 16 * MiB),
+    ],
+    "qknorm-rope-sdar-k": [
+        ("hvd_rope_norm_fwd", (PA, PA), 5 * MiB),
+        ("hvd_rope_norm_bwd", (PA, PA), 5 * MiB + MiB // 2),
     ],
 }
 
