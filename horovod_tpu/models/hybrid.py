@@ -12,15 +12,21 @@ here when it is set.
 
     h = x + r Mixer(Norm1(x));  y = h + r FF(Norm2(h))
 
-with no bias in a product; positions are a kind's (``attention`` and
-``swa`` with a rotary table, below) and no other kind has any.  ``FF`` is
-the config's: ``W2 (silu(g) * v)``, ``[g ; v] = W1 z``, or, with
+with no bias in a product; positions are a kind's (``attention``, ``swa``
+and ``mla`` with a rotary table, below) and no other kind has any.  ``FF``
+is a layer's: ``W2 (silu(g) * v)``, ``[g ; v] = W1 z``, or, with
 ``cfg.n_experts``, routed experts that drop no token
 (``moe.dropless_moe_layer``: the experts this chip holds, scored by
-``cfg.router_score``, a sigmoid's chosen with the layer's ``router_bias``)
+``cfg.router_score``, a sigmoid's chosen with the layer's ``router_bias``,
+the chosen weights times ``cfg.routed_scaling_factor``)
 plus, with
 ``cfg.n_shared_experts``, that same ``W1`` / ``W2`` as the expert every
-token passes through (``moe.shared_expert``), added once.
+token passes through (``moe.shared_expert``), added once.  With experts,
+the first ``cfg.first_dense_layers`` layers (DeepSeek-V3's
+``first_k_dense_replace``) keep the dense form, ``cfg.dense_d_ff`` wide:
+their leaves differ from the routed layers' of the same kind, so they are a
+stack of their own in the parameters' tree, ``dense_<kind>``
+(:func:`_stack`); at 0 the tree and the program are what they were.
 ``cfg.trunk_norm`` says which norm (``layernorm``: weight and bias;
 ``rmsnorm``: weight), for the layers' two and the final one;
 ``cfg.residual_multiplier`` is ``r``; the embedding's and the logits'
@@ -76,6 +82,25 @@ default computes what the trunk computed before the config carried it.
   (``llama.rope_inv_freq`` has the equations), made once where the step
   is traced and handed to the kind's layers.  A kind without a table has
   no positions (Granite's and Solar's ``attention``).
+* ``mla`` — multi-head latent attention without a query latent
+  (DeepSeek-V3's, arXiv 2412.19437): ``q = u Wq``, a head's
+  ``qk_nope_head_dim`` columns without positions and ``qk_rope_head_dim``
+  rotary ones; ``[c ; k_pe] = u Wkv_a``, the latent ``c`` ``kv_lora_rank``
+  wide and RMS-normed (``kv_norm``), ``k_pe`` ONE rotary key for every
+  head, not normed; ``[k_nope ; v] = c Wkv_b`` a head; ``q_pe`` and
+  ``k_pe`` rotated by the kind's table (``rope_tables``' ``mla``, made for
+  ``qk_rope_head_dim``; rotate-half: a model whose checkpoint stores the
+  rotary columns in pairs hands them over as ``[evens ; odds]``, a
+  permutation of weight columns); ``softmax((q_nope k_nope^T + q_pe
+  k_pe^T) / sqrt(d_nope + d_rope) + causal) v`` through ``W_o``.  The
+  parameters hold every head's ``nope`` columns of ``wq`` before every
+  head's rotary ones and every head's ``k_nope`` columns of ``wkv_b``
+  before every head's ``v``: a product each part, so that the masked
+  kernels read ``q_nope``, ``k_nope``, ``v`` as rows of whole heads where
+  they lie and take ``(q_pe, k_pe)`` as their second pair
+  (``local_attention(pair=)``: the split form; where the kernels refuse it
+  the pair is joined into one query and key, the shared key copied a head).
+  The rotation is XLA's form (``ops/rope.py`` turns whole heads of 128).
 * ``kda`` — Kimi Delta Attention: ``[q ; k ; v] = silu(conv(W_qkv u))``,
   depthwise, causal, ``ssm_conv`` wide, no bias, in ``ssm_heads`` heads,
   keys ``ssm_state`` and values ``ssm_inner / ssm_heads`` wide; ``q`` and
@@ -93,7 +118,8 @@ attention in two calls a layer, ``(q1, k1, [v ; v'])`` and ``(q2, k2, [v
 ; v'])``, the masked flash kernels taking values twice as wide as queries
 and keys.
 
-Parameters are a tree per kind, each leaf stacked over the kind's layers;
+Parameters are a tree per kind (and per ``dense_<kind>``, above), each leaf
+stacked over the stack's layers;
 :func:`layer_stack` runs the kinds in order, scanning runs of equal
 layers, and carries ``(h, m, kv)``.  Under ``cfg.remat`` each layer (a
 run's scan body) is a ``jax.checkpoint`` under the config's policy: the
@@ -112,7 +138,11 @@ mixer's own kernels ``hvd_conv_silu_fwd`` / ``_bwd`` and
 mixer's), ``hvd_kda_mixer`` (the scan's call under ``hvd_kda_scan``),
 ``hvd_attention`` and ``hvd_window_attention`` (``swa``; either's rotary
 table and products under ``hvd_rope`` inside it, and
-``hvd_rope_tables_total{kind, type}`` counts the tables traced); the
+``hvd_rope_tables_total{kind, type}`` counts the tables traced),
+``hvd_mla_attention`` (``mla``: ``u Wkv_a``, the latent's norm and ``c
+Wkv_b`` under ``hvd_mla_latent`` inside it, the rotation under ``hvd_rope``;
+``local_attention``'s ``hvd_mla_call_total{path, form}`` counts its call
+sites); the
 feed-forward under ``hvd_mlp``, routed experts'
 ``hvd_moe_route`` / ``hvd_moe_experts`` and the shared expert's
 ``hvd_moe_shared`` inside it.  Plain data parallelism only: nothing here
@@ -139,9 +169,10 @@ from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
 from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
 from ..scopes import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
-                      SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLP, SCOPE_ROPE,
-                      SCOPE_SHARED, SCOPE_SSD_MIXER, SCOPE_SSD_SCAN,
-                      SCOPE_SSM_MIXER, SCOPE_WINDOW_ATTENTION)
+                      SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLA_ATTENTION,
+                      SCOPE_MLA_LATENT, SCOPE_MLP, SCOPE_ROPE, SCOPE_SHARED,
+                      SCOPE_SSD_MIXER, SCOPE_SSD_SCAN, SCOPE_SSM_MIXER,
+                      SCOPE_WINDOW_ATTENTION)
 from .bert import _layernorm as layer_norm  # fp32 inside, weight and bias
 from . import moe
 from .llama import ParallelSpec, _rmsnorm, rope_table, rotate
@@ -150,14 +181,22 @@ from .llama import ParallelSpec, _rmsnorm, rope_table, rotate
 # ``hvd_ssd_mixer``, ``attention`` under ``hvd_attention``), and ``kda``
 # (PR 42: Kimi Delta Attention under ``hvd_kda_mixer``; ``attention`` takes
 # ``cfg.attn_gate``), and ``swa`` (PR 46: ``attention``'s layer under the
-# window and ``hvd_window_attention``; either takes a rotary table)
+# window and ``hvd_window_attention``; either takes a rotary table), and
+# ``mla`` (PR 49: multi-head latent attention under ``hvd_mla_attention``,
+# its rotary part under the kind's own table)
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention",
-         "kda", "swa")
+         "kda", "swa", "mla")
 _DIFFERENTIAL = ("window", "full", "cross")
 _PLAIN = {"attention": SCOPE_ATTENTION, "swa": SCOPE_WINDOW_ATTENTION}
+# the kinds that may have a rotary table, each with the scope the table is
+# made under (``layer_stack``; ``mla``'s turns its rotary columns alone)
+_TABLED = {**_PLAIN, "mla": SCOPE_MLA_ATTENTION}
 _MATRICES = ("w1", "w2", "in_proj", "x_proj", "dt_proj", "out_proj", "wqkv",
              "wq", "wo", "wgate", "f_a", "f_b", "g_a", "g_b", "b_proj",
-             "we_gate", "we_up", "we_down")
+             "we_gate", "we_up", "we_down", "wkv_a", "wkv_b")
+# a leading dense layer's stack in a trunk with routed experts: its kind's
+# name after this (``dense_mla``): the leaves differ, so the stack does
+_DENSE = "dense_"
 _L2_EPS = 1e-6      # under the root of a head's squared length (fla's)
 
 _m_kinds = _metrics.counter(
@@ -203,10 +242,21 @@ def check(cfg) -> None:
             f"{cfg.ssm_state} wide, {cfg.ssm_inner} value channels")
     if "swa" in kinds and cfg.sliding_window <= 0:
         raise ValueError("a swa layer needs sliding_window > 0")
+    if "mla" in kinds and (
+            min(cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) <= 0 or cfg.qk_rope_head_dim % 2):
+        raise ValueError(
+            "an mla layer needs kv_lora_rank, qk_nope_head_dim, an even "
+            "qk_rope_head_dim and v_head_dim, got "
+            f"{cfg.kv_lora_rank}, {cfg.qk_nope_head_dim}, "
+            f"{cfg.qk_rope_head_dim}, {cfg.v_head_dim}")
     tabled = [kind for kind, _ in cfg.rope_tables]
-    if set(tabled) - set(_PLAIN) or len(set(tabled)) != len(tabled):
-        raise ValueError(f"rope_tables gives each of {tuple(_PLAIN)} one "
+    if set(tabled) - set(_TABLED) or len(set(tabled)) != len(tabled):
+        raise ValueError(f"rope_tables gives each of {tuple(_TABLED)} one "
                          f"table at most, got {tabled!r}")
+    if not 0 <= cfg.first_dense_layers <= len(kinds):
+        raise ValueError("first_dense_layers counts leading layers of the "
+                         f"trunk's {len(kinds)}, got {cfg.first_dense_layers}")
     if cfg.n_experts > 0 and cfg.moe_dispatch != "dropless":
         raise ValueError("routed experts in a trunk of several kinds are "
                          "the dropless ones (moe_dispatch='dropless')")
@@ -231,9 +281,12 @@ def lambda_init(i):
 
 # --------------------------------------------------------------- shapes
 
-def layer_shapes(cfg, kind):
-    """{leaf: shape} of one layer of ``kind``."""
-    D, F, Dh = cfg.d_model, cfg.d_ff, cfg.head_dim
+def layer_shapes(cfg, kind, dense=False):
+    """{leaf: shape} of one layer of ``kind``; ``dense``: a leading layer
+    whose feed-forward is the dense one (``cfg.dense_d_ff`` wide) though
+    the config has routed experts."""
+    D, Dh = cfg.d_model, cfg.head_dim
+    F = (cfg.dense_d_ff or cfg.d_ff) if dense else cfg.d_ff
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
     Di, N, Kc, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank
     shapes = {"norm1_w": (D,), "norm1_b": (D,), "norm2_w": (D,),
@@ -258,6 +311,12 @@ def layer_shapes(cfg, kind):
             "f_b": (N, Hs * N), "dt_bias": (Hs * N,), "A_log": (Hs,),
             "b_proj": (D, Hs), "g_a": (D, Vd), "g_b": (Vd, Di),
             "o_norm": (Vd,), "wo": (Di, D)})
+    elif kind == "mla":
+        R, Dn, Dr, Dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        shapes.update({
+            "wq": (D, H * (Dn + Dr)), "wkv_a": (D, R + Dr), "kv_norm": (R,),
+            "wkv_b": (R, H * (Dn + Dv)), "wo": (H * Dv, D)})
     elif kind == "mamba":
         shapes.update({
             "in_proj": (D, 2 * Di), "conv_w": (Kc, Di), "conv_b": (Di,),
@@ -271,7 +330,7 @@ def layer_shapes(cfg, kind):
             "wq" if kind == "cross" else "wqkv": (D, width),
             "wo": (H * Dh, D), "lambda_q1": (Dh,), "lambda_k1": (Dh,),
             "lambda_q2": (Dh,), "lambda_k2": (Dh,), "subln": (2 * Dh,)})
-    if cfg.n_experts > 0:
+    if cfg.n_experts > 0 and not dense:
         held, S = cfg.experts_held or cfg.n_experts, cfg.n_shared_experts
         del shapes["w1"], shapes["w2"]
         shapes["router"] = (D, cfg.n_experts)
@@ -284,22 +343,39 @@ def layer_shapes(cfg, kind):
     return shapes
 
 
-def _counts(cfg):
-    return {k: cfg.layer_kinds.count(k) for k in KINDS
-            if k in cfg.layer_kinds}
+def _is_dense(cfg, i) -> bool:
+    """Layer ``i`` is a leading dense layer of a trunk with routed experts
+    (in one without, every layer is dense and none is set apart)."""
+    return cfg.n_experts > 0 and i < cfg.first_dense_layers
+
+
+def _stack(cfg, i) -> str:
+    """The name of layer ``i``'s stack in the parameters' tree: its kind,
+    after ``dense_`` where it is a leading dense layer."""
+    return (_DENSE if _is_dense(cfg, i) else "") + cfg.layer_kinds[i]
+
+
+def _stacks(cfg):
+    """{stack: (kind, dense, layers)}: the kinds' stacks in ``KINDS``'
+    order, then the leading dense layers' in the same."""
+    names = [_stack(cfg, i) for i in range(len(cfg.layer_kinds))]
+    return {pre + k: (k, bool(pre), names.count(pre + k))
+            for pre in ("", _DENSE) for k in KINDS if pre + k in names}
 
 
 def count_params(cfg) -> int:
     layers = sum(n * sum(int(np.prod(s))
-                         for s in layer_shapes(cfg, kind).values())
-                 for kind, n in _counts(cfg).items())
+                         for s in layer_shapes(cfg, kind, dense).values())
+                 for kind, dense, n in _stacks(cfg).values())
     return ((1 if cfg.tie_embeddings else 2) * cfg.vocab_size * cfg.d_model
             + layers + (2 if cfg.trunk_norm == "layernorm" else 1)
             * cfg.d_model)
 
 
 def init_layers(cfg, key):
-    """{kind: {leaf: [layers of the kind, ...]}}: matrices normal at
+    """{stack: {leaf: [layers of the stack, ...]}}, a stack a kind (and one
+    more, ``dense_<kind>``, for leading dense layers before routed ones:
+    :func:`_stack`): matrices normal at
     ``fan_in ** -0.5``, norms at 1 and 0, and Mamba's own: ``A_log =
     log(1..N)``, ``D = 1``, ``softplus(dt_bias)`` log-uniform on 1e-3 ..
     1e-1; ``lambda``'s vectors normal(0, 0.1).  Mamba-2's and KDA's ``A_log
@@ -307,13 +383,14 @@ def init_layers(cfg, key):
     check(cfg)
     dt = cfg.param_dtype
     out = {}
-    for a, (kind, n) in enumerate(_counts(cfg).items()):
+    for a, (stack, (kind, dense, n)) in enumerate(_stacks(cfg).items()):
         tree = {}
-        for b, (name, shape) in enumerate(layer_shapes(cfg, kind).items()):
+        for b, (name, shape) in enumerate(
+                layer_shapes(cfg, kind, dense).items()):
             k = jax.random.fold_in(jax.random.fold_in(key, a), b)
             full = (n,) + shape
             if name in ("norm1_w", "norm2_w", "subln", "D", "gate_norm",
-                        "o_norm"):
+                        "o_norm", "kv_norm"):
                 leaf = jnp.ones(full, dt)
             elif name in ("norm1_b", "norm2_b", "conv_b", "router_bias"):
                 leaf = jnp.zeros(full, dt)
@@ -332,15 +409,15 @@ def init_layers(cfg, key):
                 fan_in = shape[1] if name.startswith("we_") else shape[0]
                 leaf = jax.random.normal(k, full, dt) * fan_in ** -0.5
             tree[name] = leaf
-        out[kind] = tree
+        out[stack] = tree
     return out
 
 
 def layer_specs(cfg):
     """PartitionSpecs of :func:`init_layers`' tree: every leaf replicated."""
     from jax.sharding import PartitionSpec as P
-    return {kind: {name: P() for name in layer_shapes(cfg, kind)}
-            for kind in _counts(cfg)}
+    return {stack: {name: P() for name in layer_shapes(cfg, kind, dense)}
+            for stack, (kind, dense, _) in _stacks(cfg).items()}
 
 
 # --------------------------------------------------------------- layers
@@ -445,10 +522,39 @@ def _kda(u, lp, cfg):
     return (o32.reshape(gate.shape) * gate).astype(u.dtype) @ lp["wo"]
 
 
-def _feed_forward(z, lp, cfg):
-    """The config's feed-forward of the normed stream ``z`` -> (its output,
-    routed experts' ``[4]`` statistics or None)."""
-    if cfg.n_experts == 0:
+def latent_attention(u, lp, rope, cfg):
+    """The latent-attention mixer's output ``[B, T, D]`` of the normed
+    stream ``u``; ``rope`` the kind's ``(cos, sin) [T, qk_rope_head_dim /
+    2]`` or None."""
+    B, T, _ = u.shape
+    H, R = cfg.n_heads, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    wq, wb = lp["wq"], lp["wkv_b"]
+    # a product each part of q and of [k_nope ; v]: the kernels read rows
+    # of whole heads where they lie, and no slice of a joint array
+    q_n = (u @ wq[:, :H * Dn]).reshape(B, T, H, Dn)
+    q_r = (u @ wq[:, H * Dn:]).reshape(B, T, H, Dr)
+    with jax.named_scope(SCOPE_MLA_LATENT):
+        c, k_r = jnp.split(u @ lp["wkv_a"], (R,), axis=-1)
+        c = _rmsnorm(c, lp["kv_norm"], cfg.norm_eps)
+        k_n = (c @ wb[:, :H * Dn]).reshape(B, T, H, Dn)
+        v = (c @ wb[:, H * Dn:]).reshape(B, T, H, Dv)
+    k_r = k_r.reshape(B, T, 1, Dr)
+    if rope is not None:
+        # XLA's form: the kernel of ops/rope.py turns whole heads of 128
+        _rotary.count_xla()
+        with jax.named_scope(SCOPE_ROPE):
+            q_r, k_r = rotate(q_r, *rope), rotate(k_r, *rope)
+    o = local_attention(q_n, k_n, v, sm_scale=(Dn + Dr) ** -0.5,
+                        mask=_fa.causal_ranges(T), pair=(q_r, k_r))
+    return o.reshape(B, T, H * Dv) @ lp["wo"]
+
+
+def _feed_forward(z, lp, cfg, dense=False):
+    """The layer's feed-forward of the normed stream ``z`` -> (its output,
+    routed experts' ``[4]`` statistics or None): the dense one where the
+    config has no experts or the layer is a leading ``dense`` one."""
+    if cfg.n_experts == 0 or dense:
         return _mlp(z, lp), None
     y, stats = moe.dropless_moe_layer(z, lp, cfg, ParallelSpec())
     if cfg.n_shared_experts:
@@ -507,10 +613,11 @@ def join(x, y, cfg):
             * y.astype(jnp.float32)).astype(x.dtype)
 
 
-def _layer(kind, emits, cfg):
-    """One layer of ``kind`` as ``f(h, lp, lam0, memory, rope=None) -> (h,
-    emitted, stats)``: ``memory`` is ``m`` for a gmu, ``(k, v)`` for a cross
-    layer, else None; ``rope`` the kind's ``(cos, sin)``
+def _layer(kind, emits, cfg, dense=False):
+    """One layer of ``kind`` (``dense``: a leading layer with the dense
+    feed-forward before routed ones) as ``f(h, lp, lam0, memory, rope=None)
+    -> (h, emitted, stats)``: ``memory`` is ``m`` for a gmu, ``(k, v)`` for a
+    cross layer, else None; ``rope`` the kind's ``(cos, sin)``
     (``llama.rope_table``), None where it has no positions; ``emitted`` is
     what an emitting mamba (``s``) or full layer (``(k, v)``) hands on, else
     None; ``stats`` routed experts' ``[4]`` statistics, None of a dense
@@ -536,6 +643,9 @@ def _layer(kind, emits, cfg):
         elif kind == "kda":
             with jax.named_scope(SCOPE_KDA_MIXER):
                 y = _kda(norm1(), lp, cfg)
+        elif kind == "mla":
+            with jax.named_scope(SCOPE_MLA_ATTENTION):
+                y = latent_attention(norm1(), lp, rope, cfg)
         elif kind in _PLAIN:
             with jax.named_scope(_PLAIN[kind]):
                 u = norm1()
@@ -593,16 +703,19 @@ def _layer(kind, emits, cfg):
         h = join(h, y, cfg)
         with jax.named_scope(SCOPE_MLP):
             y, stats = _feed_forward(
-                norm(h, lp["norm2_w"], lp.get("norm2_b"), cfg), lp, cfg)
+                norm(h, lp["norm2_w"], lp.get("norm2_b"), cfg), lp, cfg,
+                dense)
         return join(h, y, cfg), emitted, stats
 
     return f
 
 
 def _runs(cfg):
-    """[(kind, first of the kind's stack, layers' published ids, emits)]:
-    runs of equal layers in order; an emitting layer is a run of its
-    own.  A run is several layers (one scan) only where it is its kind's
+    """[(stack, first of the stack, layers' published ids, emits)]: runs of
+    equal layers in order, a layer's stack its kind or, a leading dense
+    layer before routed ones, ``dense_<kind>`` (:func:`_stack`); an
+    emitting layer is a run of its own.  A run is several layers (one
+    scan) only where it is its kind's
     whole stack: a scan over a part of a stack takes a slice, whose
     gradient comes back padded to the whole stack and is added to the
     other parts' (three copies of every leaf's gradient, and an optimizer
@@ -617,25 +730,26 @@ def _runs(cfg):
                      and i < kinds.index("cross")) if "cross" in kinds
                  else -1)
     runs, seen = [], {}
-    for i, kind in enumerate(kinds):
+    for i in range(len(kinds)):
+        stack = _stack(cfg, i)
         emits = i in (last_mamba, last_full)
-        at = seen.get(kind, 0)
-        seen[kind] = at + 1
-        if (runs and runs[-1][0] == kind and not emits
+        at = seen.get(stack, 0)
+        seen[stack] = at + 1
+        if (runs and runs[-1][0] == stack and not emits
                 and not runs[-1][3]):
             runs[-1][2].append(ids[i])
         else:
-            runs.append([kind, at, [ids[i]], emits])
+            runs.append([stack, at, [ids[i]], emits])
     # (with routed experts every layer is a run of its own too: a layer
     # then holds 160 M parameters at the solar-open2-250b cell's sizes, and
     # a scan's stacked gradient and its stack of weights cast for the
     # products were 3.3 GB of the step's temporaries)
-    shared = {kind for kind in set(kinds)
-              if sum(r[0] == kind for r in runs) > 1 or cfg.n_experts > 0}
-    return [run for kind, at, ids_, emits in runs
-            for run in ([[kind, at + j, [i], emits]
-                         for j, i in enumerate(ids_)] if kind in shared
-                        else [[kind, at, ids_, emits]])]
+    shared = {stack for stack in seen
+              if sum(r[0] == stack for r in runs) > 1 or cfg.n_experts > 0}
+    return [run for stack, at, ids_, emits in runs
+            for run in ([[stack, at + j, [i], emits]
+                         for j, i in enumerate(ids_)] if stack in shared
+                        else [[stack, at, ids_, emits]])]
 
 
 def layer_stack(h, layers, cfg, policy=None, with_stats=False):
@@ -650,22 +764,26 @@ def layer_stack(h, layers, cfg, policy=None, with_stats=False):
         if kind in cfg.layer_kinds:
             if _metrics.ACTIVE:
                 _m_ropes.inc(kind=kind, type=table.rope_type)
-            with jax.named_scope(_PLAIN[kind]), jax.named_scope(SCOPE_ROPE):
-                ropes[kind] = rope_table(table, cfg.head_dim, h.shape[1])
-    made = {}       # one function a (kind, emits): equal layers trace once
-    for kind, first, ids, emits in _runs(cfg):
+            turned = (cfg.qk_rope_head_dim if kind == "mla"
+                      else cfg.head_dim)
+            with jax.named_scope(_TABLED[kind]), jax.named_scope(SCOPE_ROPE):
+                ropes[kind] = rope_table(table, turned, h.shape[1])
+    stacks = _stacks(cfg)
+    made = {}       # one function a (stack, emits): equal layers trace once
+    for stack, first, ids, emits in _runs(cfg):
+        kind, dense, _ = stacks[stack]
         n = len(ids)
         if _metrics.ACTIVE:
             _m_kinds.inc(n, kind=kind)
-        if (kind, emits) not in made:
-            f = _layer(kind, emits, cfg)
-            made[kind, emits] = (jax.checkpoint(f, policy=policy)
-                                 if cfg.remat else f)
-        f = made[kind, emits]
+        if (stack, emits) not in made:
+            f = _layer(kind, emits, cfg, dense)
+            made[stack, emits] = (jax.checkpoint(f, policy=policy)
+                                  if cfg.remat else f)
+        f = made[stack, emits]
         memory = m if kind == "gmu" else kv if kind == "cross" else None
         lam0 = jnp.asarray([lambda_init(i) for i in ids], jnp.float32)
         lps = jax.tree_util.tree_map(lambda w: w[first:first + n],
-                                     layers[kind])
+                                     layers[stack])
         if n == 1:
             h, emitted, new = f(h, jax.tree_util.tree_map(lambda w: w[0], lps),
                                 lam0[0], memory, ropes.get(kind))

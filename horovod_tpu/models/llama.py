@@ -141,10 +141,18 @@ class LlamaConfig:
     # width; the trunk of several kinds alone)
     router_score: str = "softmax"
     n_shared_experts: int = 0
+    # DeepSeek-V3's ``routed_scaling_factor``: the chosen experts' weights,
+    # renormalised, times this (dropless experts alone; 1.0 = none).  And
+    # its ``first_k_dense_replace``: that many leading layers of a trunk of
+    # several kinds keep a dense feed-forward, ``dense_d_ff`` wide (0 =
+    # ``d_ff``), before the routed ones (0 = every layer routed)
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
     # a trunk whose layers are of several kinds (models/hybrid.py): each
     # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" |
-    # "mamba2" | "attention" | "kda" | "swa" (() = the trunk of identical layers
-    # here),
+    # "mamba2" | "attention" | "kda" | "swa" | "mla" (() = the trunk of
+    # identical layers here),
     # and its index in the published model (() = its place in the trunk);
     # the window of the "window" kind's attention; the state-space
     # mixers' inner width, states (a channel for "mamba", a head's
@@ -159,9 +167,14 @@ class LlamaConfig:
     # attention under the window.  Such a trunk has a fused gate/up MLP
     # (or, with ``n_experts``, dropless routed experts); its layers' norm
     # is ``trunk_norm``: "layernorm" (weight and bias) or "rmsnorm"
-    # (weight).  Positions are a kind's: ``rope_tables`` pairs "attention"
-    # or "swa" with its :class:`RopeTable`, ``((kind, table), ...)``, and
-    # a kind without one (every other kind; () = all) has none.
+    # (weight).  Positions are a kind's: ``rope_tables`` pairs "attention",
+    # "swa" or "mla" with its :class:`RopeTable`, ``((kind, table), ...)``,
+    # and a kind without one (every other kind; () = all) has none.
+    # "mla" is DeepSeek-V3's multi-head latent attention without a query
+    # latent: keys and values come from a latent ``kv_lora_rank`` wide
+    # (normed), a head's scores are a ``qk_nope_head_dim``-wide product with
+    # its own keys plus a ``qk_rope_head_dim``-wide one with the one rotary
+    # key every head shares, its values ``v_head_dim`` wide.
     layer_kinds: tuple = ()
     layer_ids: tuple = ()
     sliding_window: int = 0
@@ -174,6 +187,10 @@ class LlamaConfig:
     ssm_chunk: int = 256
     attn_gate: bool = False
     rope_tables: tuple = ()
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     trunk_norm: str = "layernorm"
     # Granite's four multipliers, of the trunk of several kinds alone
     # (the trunk of identical layers refuses them), each at what a trunk
@@ -201,10 +218,16 @@ class LlamaConfig:
             raise ValueError("router_score must be 'softmax' or 'sigmoid', "
                              f"got {self.router_score!r}")
         if not self.layer_kinds and (self.n_shared_experts or self.attn_gate
-                                     or self.rope_tables):
+                                     or self.rope_tables
+                                     or self.first_dense_layers):
             raise ValueError(
-                "n_shared_experts, attn_gate and rope_tables are wired "
-                "through the trunk of several kinds (layer_kinds) alone")
+                "n_shared_experts, attn_gate, rope_tables and "
+                "first_dense_layers are wired through the trunk of several "
+                "kinds (layer_kinds) alone")
+        if (self.routed_scaling_factor != 1.0
+                and self.moe_dispatch != "dropless"):
+            raise ValueError("routed_scaling_factor is the dropless "
+                             "experts' (moe_dispatch='dropless')")
         multipliers = (self.embedding_multiplier, self.residual_multiplier,
                        self.attention_multiplier, self.logits_scaling)
         if not self.layer_kinds and multipliers != (1.0, 1.0, 0.0, 1.0):

@@ -118,6 +118,44 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   is transposed to ``[B, H, T, D]`` around the kernels (``heads``).  One
   set of kernel bodies; the block specs and :func:`_head` differ.
 
+**A second pair** (``flash_attention(pair=(q2, k2))``; the masked path
+alone).  Multi-head latent attention scores a head as ``q_nope . k_nope +
+q_pe . k_pe``: the first product 128 wide with a key head a query head,
+the second 64 wide with ONE rotary key for every head.  Joined into one
+192-wide key the rotary key is copied a head into HBM (67 MB a layer at 32
+heads and 16,384 positions, again under remat, its cotangent summed over
+the heads on the way back), 192 is no whole lane tile, so the call takes
+the ``heads`` route and q, k, v, out and their gradients are transposed
+around the kernels, and at 16,384 keys the resident ``Tk x (192 + 128) x
+2`` bytes are ``_VMEM_BUDGET`` to the byte.  So the kernels take the second
+pair as it is: ``q2 [B, T, H, D2]``, ``k2 [B, Tk, H2, D2]`` with ``H2 |
+Hkv`` (a grouping of its own: one key head of the pair serves ``H / H2``
+query heads), and add ``q2 k2^T`` to each score tile before the mask and
+the softmax; ``hvd_flash_dq`` accumulates and writes ``dq2`` beside ``dq``;
+``hvd_flash_dkv``, whose grid step is a kv head, writes that kv head's part
+of ``dk2`` in float32 and the caller adds the ``Hkv / H2`` parts of the kv
+heads that share a key head of the pair (one XLA reduction of ``[B, Tk,
+Hkv, D2]`` float32: 268 MB read a layer at the shape above, a third of a
+millisecond beside kernels of tens).  ``q``, ``k``, ``v``, ``out`` and
+their cotangents stay the caller's ``[B, T, H*D]`` rows (``D`` and ``Dv``
+have to be whole lane tiles: nothing is transposed), and ``k2`` is never
+copied a head.  **The pair's heads are padded to whole lane tiles**, zeros
+after their ``D2`` columns (``q2 [B, T, H * 128]``, ``k2 [B, Tk, H2 *
+128]`` at ``D2`` 64), where the call is built, so that a head of the pair
+is a block at an aligned lane offset exactly as a head of ``q`` is and the
+kernel bodies gain three lines each.  The choice against the packed path's
+two heads a lane tile: that needs the other head's lanes zeroed a step (a
+select over ``[bq, 128]`` a head a step, and ``k2`` doubled to 128 lanes
+all the same), for nothing on the MXU, which contracts 128 lanes whether 64
+of them are zeros or another head's; what padding costs is HBM bytes, ``q2``
+and ``dq2`` at twice their 64 columns: 4 x 67 MB a layer forward and
+backward, about a third of a millisecond at 819 GB/s beside about 70 ms of
+kernels, and 4 MB of VMEM for ``k2`` resident at 16,384 keys (a step of one
+head then holds 13 MB of blocks; :func:`_pair_refusal` refuses a shape
+whose step does not fit ``_MASKED_STEP_VMEM``, and the caller joins the
+pair instead: ``ring_attention.local_attention``).  A call without a pair
+builds every kernel as it was; one with it counts as path ``paired``.
+
 Residuals are named.  Both paths' ``custom_vjp`` keep ``(q, k, v, out,
 lse)`` for the backward kernels, and the forward rules pass ``out`` and
 ``lse`` through ``jax.ad_checkpoint.checkpoint_name`` as ``OUT_NAME``
@@ -136,8 +174,10 @@ backward pass to make them again, and the names change nothing in its
 program.
 
 ``hvd_flash_kernel_total{kernel, path, layout}`` counts the kernels
-built, once per traced call site, so a program says which path and which
-layout (``rows`` or ``heads``; packed calls are ``rows``) its shapes took;
+built, once per traced call site, so a program says which path (``packed``,
+``masked``, or ``paired``: the masked kernels built with a second pair) and
+which layout (``rows`` or ``heads``; packed calls are ``rows``) its shapes
+took;
 ``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
 (``live`` = full, ``masked`` = mixed, ``skipped`` = dead) where the call
 is built, when the mask is known there (a numpy array), and
@@ -194,8 +234,9 @@ LSE_NAME = "hvd_flash_lse"
 _count = _pallas.kernel_counter(
     "hvd_flash_kernel_total",
     "Flash-attention Pallas kernels built, one per traced call site; "
-    "path is packed or masked (tiled stopped occurring: several blocks "
-    "without mask= count as masked); layout is rows where the kernels "
+    "path is packed, masked (tiled stopped occurring: several blocks "
+    "without mask= count as masked) or paired (the masked kernels with a "
+    "second query/key pair); layout is rows where the kernels "
     "read and write the caller's [B, T, H*D], heads where the call is "
     "transposed to [B, H, T, D] around them",
     labels=("kernel", "path", "layout"))
@@ -291,8 +332,44 @@ def _row_widths(D, Dv):
     return (D, Dv) if D % _LANES == 0 and Dv % _LANES == 0 else None
 
 
-def _refusal(q, k, v) -> Optional[str]:
-    """Which test keeps the Pallas kernel off this call; None = it runs."""
+def _pair_refusal(q, k, v, pair) -> Optional[str]:
+    """Which test keeps a second query/key pair off the masked kernels of
+    a call that runs without it; None = they take it."""
+    q2, k2 = pair
+    B, T, H, D = q.shape
+    Tk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
+    if (q2.ndim != 4 or k2.ndim != 4 or q2.shape[:3] != q.shape[:3]
+            or k2.shape[:2] != k.shape[:2] or k2.shape[3] != q2.shape[3]
+            or q2.dtype != q.dtype or k2.dtype != q.dtype):
+        return ("the second pair must be q2 [B, T, H, D2], k2 [B, Tk, H2, "
+                "D2] in q's dtype")
+    H2, D2 = k2.shape[2], q2.shape[3]
+    if Hkv % H2:
+        return f"the second pair's {H2} key heads do not divide the {Hkv}"
+    if _row_widths(D, Dv) is None:
+        return (f"a second pair rides the caller's rows: head_dim {D} and "
+                f"the values' {Dv} must be whole lane tiles")
+    if D2 % 64 or D2 > 256:
+        return (f"the second pair's width {D2} is not a multiple of 64 up "
+                "to 256")
+    bq, bk = _block_sizes(T, Tk)
+    step = _dq_step_bytes(1, bq, bk, D, T // bq, Tk, q.dtype.itemsize, Dv,
+                          _padded(D2))
+    if 2 * step[0] + step[1] + step[2] > _MASKED_STEP_VMEM:
+        return (f"one head's step with the second pair's keys resident needs "
+                f"{2 * step[0] + step[1] + step[2]} bytes of VMEM, over "
+                f"{_MASKED_STEP_VMEM}")
+    return None
+
+
+def _padded(d):
+    """``d`` lanes as whole lane tiles."""
+    return -(-d // _LANES) * _LANES
+
+
+def _refusal(q, k, v, pair=None) -> Optional[str]:
+    """Which test keeps the Pallas kernel off this call; None = it runs.
+    ``pair``: a second query/key pair (:func:`flash_attention`)."""
     if (why := _pallas.off_chip()):
         return why
     if q.ndim != 4 or k.ndim != 4:
@@ -322,13 +399,15 @@ def _refusal(q, k, v) -> Optional[str]:
     if resident > _VMEM_BUDGET:
         return (f"resident buffers need {resident} bytes of VMEM, over "
                 f"the {_VMEM_BUDGET} budget")
-    return None
+    return None if pair is None else _pair_refusal(q, k, v, pair)
 
 
-def supported(q, k, v, causal: bool = True, mask=None) -> bool:
+def supported(q, k, v, causal: bool = True, mask=None, pair=None) -> bool:
     """True when the Pallas kernel can run this shape on this backend;
-    the same shapes with or without ``causal`` or a ``mask``."""
-    return _verdict("flash_attention", _refusal(q, k, v), q, k, v)
+    the same shapes with or without ``causal`` or a ``mask``.  ``pair``:
+    whether it runs with this second query/key pair as it is."""
+    return _verdict("flash_attention", _refusal(q, k, v, pair), q, k, v,
+                    *(pair or ()))
 
 
 # ------------------------------------------------- packed (one-block) path
@@ -787,7 +866,7 @@ def _head(ref, h, d, at=slice(None)):
 
 def _mfwd_kernel(tables, q_ref, k_ref, v_ref, r_ref, o_ref, lse_ref, m_ref,
                  l_ref, acc_ref, rb_ref, *, scale, bk, nq, nk, per_batch,
-                 sub):
+                 sub, pair=None):
     """One query tile of ``hb`` query heads that share a kv head (static,
     unrolled: one head's softmax is scheduled under another's products).
     The running statistics live in VMEM, a head at a time in registers:
@@ -799,9 +878,14 @@ def _mfwd_kernel(tables, q_ref, k_ref, v_ref, r_ref, o_ref, lse_ref, m_ref,
     step, for every head and mixed tile of it.  ``tables``: the query
     tiles' key tiles (:func:`_row_tables`); with ``sub = (sq, sk)`` a mixed
     tile is walked by its live sub-tiles, with None it is taken whole
-    (:func:`_key_tiles`)."""
+    (:func:`_key_tiles`).  ``pair``: the second pair's ``(q2_ref, k2_ref)``,
+    a query tile of the step's heads and their one key head's whole keys:
+    its product joins each score tile."""
     hb, bq, Dv = acc_ref.shape
     D = k_ref.shape[-1]
+    if pair:
+        q2_ref, k2_ref = pair
+        D2 = k2_ref.shape[-1]
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -816,11 +900,16 @@ def _mfwd_kernel(tables, q_ref, k_ref, v_ref, r_ref, o_ref, lse_ref, m_ref,
         is wiped by ``corr`` = 0 at their first live key."""
         at = pl.ds(col0, width)
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
+        if pair:
+            k2j = k2_ref[_head(k2_ref, 0, D2, at)]
         if masked:                  # one mask a visit, added by every head
             dead = jnp.where(_rows_live(rb_ref, col0, width, rows), 0.0,
                              NEG_INF)
         for h in range(hb):
             s = _scores(q_ref[_head(q_ref, h, D, rows)], kj, scale, False)
+            if pair:
+                s = s + _scores(q2_ref[_head(q2_ref, h, D2, rows)], k2j,
+                                scale, False)
             if masked:
                 s = s + dead
             m = m_ref[h, rows]
@@ -844,7 +933,7 @@ def _mfwd_kernel(tables, q_ref, k_ref, v_ref, r_ref, o_ref, lse_ref, m_ref,
 
 def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 r_ref, dq_ref, acc_ref, lse_b, delta_b, rb_ref, *, scale, bk,
-                nq, nk, per_batch, sub):
+                nq, nk, per_batch, sub, pair=None):
     """One query tile of ``hb`` query heads that share a kv head, as the
     forward takes them (static, unrolled: a key tile is loaded once for
     all of them and one head's elementwise work is scheduled under
@@ -853,8 +942,13 @@ def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     until the last tile; ``lse_b`` and ``delta_b [hb, bq, 128]`` the heads'
     rows of ``lse`` and ``delta`` as columns alike in every lane and
     ``rb_ref [4, bq, 128]`` the rows' ranges over the lanes, each laid out
-    once a step."""
+    once a step.  ``pair``: the second pair's ``(q2_ref, k2_ref, dq2_ref,
+    acc2_ref)``, ``dq2`` accumulated as ``dq`` is."""
     (hb, bq, D), Dv = acc_ref.shape, v_ref.shape[-1]
+    if pair:
+        q2_ref, k2_ref, dq2_ref, acc2_ref = pair
+        D2 = k2_ref.shape[-1]
+        acc2_ref[...] = jnp.zeros(acc2_ref.shape, jnp.float32)
     i = pl.program_id(2)
     row = (pl.program_id(0) * nq if per_batch else 0) + i
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -868,11 +962,16 @@ def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ``col0`` on."""
         at = pl.ds(col0, width)
         kj, vj = k_ref[_head(k_ref, 0, D, at)], v_ref[_head(v_ref, 0, Dv, at)]
+        if pair:
+            k2j = k2_ref[_head(k2_ref, 0, D2, at)]
         if masked:                  # one mask a tile, added by every head
             dead = jnp.where(_rows_live(rb_ref, col0, width, rows), 0.0,
                              NEG_INF)
         for h in range(hb):
             s = _scores(q_ref[_head(q_ref, h, D, rows)], kj, scale, False)
+            if pair:
+                s = s + _scores(q2_ref[_head(q2_ref, h, D2, rows)], k2j,
+                                scale, False)
             if masked:
                 s = s + dead
             p = jnp.exp(s - _lanes(lse_b[h, rows], width))
@@ -882,15 +981,22 @@ def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds = p * (dp - _lanes(delta_b[h, rows], width)) * scale
             acc_ref[h, rows] += jnp.dot(ds.astype(kj.dtype), kj,
                                         preferred_element_type=jnp.float32)
+            if pair:
+                acc2_ref[h, rows] += jnp.dot(
+                    ds.astype(k2j.dtype), k2j,
+                    preferred_element_type=jnp.float32)
 
     _key_tiles(tables, row, nk, bq, bk, sub, visit)
     for h in range(hb):
         dq_ref[_head(dq_ref, h, D)] = acc_ref[h].astype(dq_ref.dtype)
+        if pair:
+            dq2_ref[_head(dq2_ref, h, D2)] = acc2_ref[h].astype(
+                dq2_ref.dtype)
 
 
 def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *st_ref, scale, P, g,
-                 per_batch, sub):
+                 per_batch, sub, pair=None):
     """One live (key tile, query tile) pair of a kv head's ``g`` query
     heads (static, unrolled).  The tile is formed keys by queries, ``S^T =
     K Q^T [bk, bq]``: a query's ``lse``, ``delta`` and ranges (``r_ref [1,
@@ -902,8 +1008,14 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     sub-tile's rows; with None it is taken whole.  The sub-tiles read the
     query tile's rows of ``lse`` and ``delta`` from ``st_ref [2, g, 1,
     bq]``, laid there once a mixed pair: Mosaic loads a row at a dynamic
-    index whole, not one lane tile of it."""
+    index whole, not one lane tile of it.  ``pair``: the second pair's
+    ``(q2_ref, k2_ref, dk2_ref, dk2_acc)``; ``dk2_ref`` takes this kv head's
+    part of the shared key head's gradient in float32, and the caller adds
+    the parts of the heads that share it."""
     (bk, D), Dv = k_ref.shape[-2:], v_ref.shape[-1]
+    if pair:
+        q2_ref, k2_ref, dk2_ref, dk2_acc = pair
+        D2 = k2_ref.shape[-1]
     bq = r_ref.shape[-1]
     width = 5 if sub else 4
     at = ((pl.program_id(0) * P if per_batch else 0)
@@ -915,27 +1027,34 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if pair:
+            dk2_acc[...] = jnp.zeros_like(dk2_acc)
 
     def whole(n, hq):
         return (lse_ref, delta_ref)[n][0, hq, pl.ds(i, 1), :]
 
-    def pair(masked, keys=slice(None), key0=0, queries=slice(None),
-             stat=whole):
+    def visit(masked, keys=slice(None), key0=0, queries=slice(None),
+              stat=whole):
         """The pair's ``keys`` (a slice from the tile's ``key0`` on) by its
         ``queries`` (a static slice); ``stat(0, head)`` the queries' row of
         ``lse``, ``stat(1, head)`` of ``delta``."""
         kb = k_ref[_head(k_ref, 0, D, keys)]
         vb = v_ref[_head(v_ref, 0, Dv, keys)]
+        if pair:
+            k2b = k2_ref[_head(k2_ref, 0, D2, keys)]
         if masked:
             bounds = [r_ref[0, c:c + 1, queries] for c in range(4)]
             at_key = lax.broadcasted_iota(
                 jnp.int32, (kb.shape[0], bounds[0].shape[1]), 0)
             live = _in_ranges(at_key + (j * bk + key0), *bounds)
-        dk = dv = None
+        dk = dv = dk2 = None
         for hq in range(g):           # static: the kv head's query heads
             qi = q_ref[_head(q_ref, hq, D, queries)]
             doi = do_ref[_head(do_ref, hq, Dv, queries)]
             s = _scores(kb, qi, scale, False)
+            if pair:
+                q2i = q2_ref[_head(q2_ref, hq, D2, queries)]
+                s = s + _scores(k2b, q2i, scale, False)
             if masked:
                 s = jnp.where(live, s, NEG_INF)
             p = jnp.exp(s - stat(0, hq))
@@ -946,10 +1065,15 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds = p * (dp - stat(1, hq)) * scale
             dk = _add(dk, jnp.dot(ds.astype(qi.dtype), qi,
                                   preferred_element_type=jnp.float32))
+            if pair:
+                dk2 = _add(dk2, jnp.dot(ds.astype(q2i.dtype), q2i,
+                                        preferred_element_type=jnp.float32))
         dk_acc[keys] += dk
         dv_acc[keys] += dv
+        if pair:
+            dk2_acc[keys] += dk2
 
-    pl.when(cls == 2)(functools.partial(pair, False))
+    pl.when(cls == 2)(functools.partial(visit, False))
     if sub:
         @pl.when(cls == 1)
         def _():
@@ -958,20 +1082,22 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     st_ref[0][n, hq] = whole(n, hq)
             wq, wk = bq // sub[0], bk // sub[1]
 
-            def visit(c, r):
+            def sub_tile(c, r):
                 key0, queries = pl.multiple_of(c * wk, wk), slice(
                     r * wq, (r + 1) * wq)
-                pair(True, pl.ds(key0, wk), key0, queries,
-                     lambda n, hq: st_ref[0][n, hq, :, queries])
+                visit(True, pl.ds(key0, wk), key0, queries,
+                      lambda n, hq: st_ref[0][n, hq, :, queries])
 
-            _walk(tbl_ref[at + 4], sub, visit)
+            _walk(tbl_ref[at + 4], sub, sub_tile)
     else:
-        pl.when(cls == 1)(functools.partial(pair, True))
+        pl.when(cls == 1)(functools.partial(visit, True))
 
     @pl.when(flags >= 2)
     def _():
         dk_ref[_head(dk_ref, 0, D)] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[_head(dv_ref, 0, Dv)] = dv_acc[...].astype(dv_ref.dtype)
+        if pair:
+            dk2_ref[_head(dk2_ref, 0, D2)] = dk2_acc[...]
 
 
 def _mask_plan(mask, bq, bk, Tk):
@@ -990,7 +1116,24 @@ def _mask_plan(mask, bq, bk, Tk):
 def _tables_first(kernel, n, **static):
     """``kernel`` taking its first ``n`` refs, the tables in SMEM, as one
     tuple."""
-    return lambda *refs: kernel(refs[:n], *refs[n:], **static)
+    return lambda *refs, **pair: kernel(refs[:n], *refs[n:], **static, **pair)
+
+
+def _pair_refs(kernel, pair, *groups):
+    """``kernel`` for a call with a second ``pair``, whose refs come in
+    ``groups`` of ``(how many, the last of them that are the pair's)``: the
+    tables with the inputs, the outputs, the scratch.  The pair's are taken
+    out and handed on as ``pair=``, in their order; without a pair the
+    kernel as it is."""
+    if not pair:
+        return kernel
+    at, end = [], 0
+    for size, last in groups:
+        end += size
+        at += range(end - last, end)
+    return lambda *refs: kernel(
+        *(r for n, r in enumerate(refs) if n not in at),
+        pair=tuple(refs[n] for n in at))
 
 
 def _vmem(*block_bytes, scratch=0):
@@ -1018,49 +1161,54 @@ def _heads_block(rows, heads, n, d, index):
     return pl.BlockSpec(_heads_shape(rows, 1, heads, n, d), at)
 
 
-def _row_specs(rows, bq, D, Dv, Tk, nq, g, bm, hb=1):
+def _row_specs(rows, bq, D, Dv, Tk, nq, g, bm, hb=1, pair=None):
     """Block specs of the kernels that walk a query tile's key tiles
     (grid ``(B, H // hb, nq)``, three tables prefetched): a query tile of
     ``hb`` heads ``D`` wide (q, dq) and ``Dv`` wide (out, do), their kv
     head's whole keys and whole values, the heads' row statistics, the
-    tile's ranges."""
+    tile's ranges; with ``pair = (D2, g2)`` also a query tile ``D2`` wide
+    and the whole keys of the second pair's key head, one to ``g2`` query
+    heads."""
     tile = lambda d: _heads_block(rows, hb, bq, d,
                                   lambda b, h, i, *_: (b, h, i))
-    whole = lambda d: _heads_block(rows, 1, Tk, d,
-                                   lambda b, h, i, *_: (b, h * hb // g, 0))
+    whole = lambda d, g=g: _heads_block(
+        rows, 1, Tk, d, lambda b, h, i, *_: (b, h * hb // g, 0))
     stats = pl.BlockSpec((1, hb, nq, bq), lambda b, h, i, *_: (b, h, 0, 0))
     rng = pl.BlockSpec((1, bq, 4), lambda b, h, i, *_: (bm(b), i, 0))
-    return tile(D), tile(Dv), whole(D), whole(Dv), stats, rng
+    specs = tile(D), tile(Dv), whole(D), whole(Dv), stats, rng
+    return specs + (tile(pair[0]), whole(*pair)) if pair else specs
 
 
-def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None):
+def _fwd_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None, D2=0):
     """VMEM bytes a masked forward grid step of ``hb`` heads holds:
     ``(blocks, scratch, tiles)``, the blocks the pipeline double-buffers
     (whole k and v, ``hb`` tiles of q and of out, their rows of lse, the
     ranges padded to a lane tile), the kernel's scratch (statistics,
     accumulators, the ranges over the lanes) and every head's ``[bq, bk]``
     scores and probabilities in fp32 and the latter cast.  ``Dv``: the
-    values' width where it is not ``D``."""
+    values' width where it is not ``D``; ``D2``: a second pair's (its
+    whole keys and ``hb`` tiles of its queries)."""
     Dv = D if Dv is None else Dv
-    blocks = (Tk * (D + Dv) * itemsize
-              + hb * (bq * (D + Dv) * itemsize + nq * bq * 4)
+    blocks = (Tk * (D + Dv + D2) * itemsize
+              + hb * (bq * (D + Dv + D2) * itemsize + nq * bq * 4)
               + bq * _LANES * 4)
     scratch = (hb * bq * (2 * _LANES + Dv) + 4 * bq * _LANES) * 4
     return blocks, scratch, hb * bq * bk * (8 + itemsize)
 
 
-def _dq_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None):
+def _dq_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None, D2=0):
     """The same for a ``dq`` grid step of ``hb`` heads: whole k and v,
     ``hb`` tiles of q, ``do`` and ``dq`` with their rows of lse and of
     delta, the ranges; the accumulators, the two statistics and the ranges
     over the lanes; and a head's two ``[bq, bk]`` fp32 tiles in flight
     (the probabilities take the scores' place and ``ds`` takes ``dp``'s)
-    with ``ds`` cast."""
+    with ``ds`` cast; a second pair ``D2`` wide brings its whole keys, its
+    queries, ``dq2`` and that accumulator."""
     Dv = D if Dv is None else Dv
-    blocks = (Tk * (D + Dv) * itemsize
-              + hb * (bq * (2 * D + Dv) * itemsize + 2 * nq * bq * 4)
+    blocks = (Tk * (D + Dv + D2) * itemsize
+              + hb * (bq * (2 * D + Dv + 2 * D2) * itemsize + 2 * nq * bq * 4)
               + bq * _LANES * 4)
-    scratch = (hb * bq * (2 * _LANES + D) + 4 * bq * _LANES) * 4
+    scratch = (hb * bq * (2 * _LANES + D + D2) + 4 * bq * _LANES) * 4
     return blocks, scratch, hb * bq * bk * (8 + itemsize)
 
 
@@ -1077,8 +1225,8 @@ def _heads_a_step(step_bytes, g, *shapes):
                if g % hb == 0 and (hb == 1 or fits(hb)))
 
 
-# (g, bq, bk, D, nq, Tk, itemsize, Dv=None) -> heads a step; dq never more
-# than the forward, whose step holds less
+# (g, bq, bk, D, nq, Tk, itemsize, Dv=None, D2=0) -> heads a step; dq never
+# more than the forward, whose step holds less
 _fwd_heads = functools.partial(_heads_a_step, _fwd_step_bytes)
 _dq_heads = functools.partial(_heads_a_step, _dq_step_bytes)
 
@@ -1094,30 +1242,47 @@ def _masked_dims(q, k, v, widths):
     return B, H, Hkv, T, Tk, D, Dv
 
 
-def _masked_fwd(q, k, v, mask, scale, widths):
+def _pair_dims(pair, H):
+    """``(D2, g2)`` of a second pair ``(q2 [B, T, H*D2], k2 [B, Tk,
+    H2*D2])`` in the caller's rows: its width and the query heads a key
+    head of it serves; ``(0, 0)`` of None."""
+    if pair is None:
+        return 0, 0
+    D2 = pair[0].shape[2] // H
+    return D2, H // (pair[1].shape[2] // D2)
+
+
+def _masked_fwd(q, k, v, mask, scale, widths, pair=None):
     """q [B,H,T,D], k [B,Hkv,Tk,D], v [B,Hkv,Tk,Dv] → (out [B,H,T,Dv],
     lse [B,H,nq,bq]); with ``widths = (D, Dv)``, q [B,T,H*D], k
-    [B,Tk,Hkv*D], v [B,Tk,Hkv*Dv] → out [B,T,H*Dv] and the same lse."""
+    [B,Tk,Hkv*D], v [B,Tk,Hkv*Dv] → out [B,T,H*Dv] and the same lse.
+    ``pair``: the second pair in the caller's rows (:func:`_pair_dims`)."""
     B, H, Hkv, T, Tk, D, Dv = _masked_dims(q, k, v, widths)
     rows = widths is not None
     g = H // Hkv
+    D2, g2 = _pair_dims(pair, H)
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
-    hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize, Dv)
+    hb = _fwd_heads(g, bq, bk, D, nq, Tk, q.dtype.itemsize, Dv, D2)
     ranges, classes, sub, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
-                                                        nq, g, bm, hb)
-    _count("fwd", "masked", "rows" if rows else "heads")
+    tile, otile, whole, vwhole, stats, rng, *second = _row_specs(
+        rows, bq, D, Dv, Tk, nq, g, bm, hb, pair and (D2, g2))
+    _count("fwd", "paired" if pair else "masked", "rows" if rows else "heads")
     _count_tiles("fwd", classes, sub)
     blocks, scratch, _ = _fwd_step_bytes(hb, bq, bk, D, nq, Tk,
-                                         q.dtype.itemsize, Dv)
+                                         q.dtype.itemsize, Dv, D2)
     tables = _row_tables(classes, sub)
+    n = len(tables)
+    in_specs = [tile, whole, vwhole, rng, *second]
     return pl.pallas_call(
-        _tables_first(_mfwd_kernel, len(tables), scale=scale, bk=bk, nq=nq,
-                      nk=nk, per_batch=per_batch, sub=sub and sub.grid),
+        _pair_refs(_tables_first(
+            _mfwd_kernel, n, scale=scale, bk=bk, nq=nq, nk=nk,
+            per_batch=per_batch, sub=sub and sub.grid),
+            pair, (n + len(in_specs), 2)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables), grid=(B, H // hb, nq),
-            in_specs=[tile, whole, vwhole, rng], out_specs=[otile, stats],
+            in_specs=in_specs,
+            out_specs=[otile, stats],
             scratch_shapes=[pltpu.VMEM((hb, bq, _LANES), jnp.float32),
                             pltpu.VMEM((hb, bq, _LANES), jnp.float32),
                             pltpu.VMEM((hb, bq, Dv), jnp.float32),
@@ -1129,21 +1294,26 @@ def _masked_fwd(q, k, v, mask, scale, widths):
         compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_pallas.INTERPRET,
         name="hvd_flash_fwd",
-    )(*tables, q, k, v, ranges)
+    )(*tables, q, k, v, ranges, *(pair or ()))
 
 
-def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
-    """``(dq, dk, dv)`` in the operands' own layout (:func:`_masked_fwd`)."""
+def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths, pair=None):
+    """``(dq, dk, dv)`` in the operands' own layout (:func:`_masked_fwd`);
+    with the second ``pair``, ``(dq, dk, dv, dq2, dk2)``: ``dk2`` is the sum
+    over the kv heads that share a key head of it of the parts
+    ``hvd_flash_dkv`` writes, one reduction in float32."""
     B, H, Hkv, T, Tk, D, Dv = _masked_dims(q, k, v, widths)
     rows = widths is not None
     g = H // Hkv
+    D2, g2 = _pair_dims(pair, H)
     bq, bk = _block_sizes(T, Tk)
     nq, nk = T // bq, Tk // bk
     item = q.dtype.itemsize
-    hb = _dq_heads(g, bq, bk, D, nq, Tk, item, Dv)
+    hb = _dq_heads(g, bq, bk, D, nq, Tk, item, Dv, D2)
     ranges, classes, sub, per_batch, bm = _mask_plan(mask, bq, bk, Tk)
-    tile, otile, whole, vwhole, stats, rng = _row_specs(rows, bq, D, Dv, Tk,
-                                                        nq, g, bm, hb)
+    tile, otile, whole, vwhole, stats, rng, *second = _row_specs(
+        rows, bq, D, Dv, Tk, nq, g, bm, hb, pair and (D2, g2))
+    path = "paired" if pair else "masked"
 
     # delta_i = rowsum(dO * O) — cheap elementwise, stays in XLA.
     # When the caller differentiates through the exposed lse (ring-step
@@ -1157,26 +1327,35 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     delta = delta.reshape(B, H, nq, bq) - dlse.astype(jnp.float32)
 
     for kernel in ("dq", "dkv"):
-        _count(kernel, "masked", "rows" if rows else "heads")
+        _count(kernel, path, "rows" if rows else "heads")
         _count_tiles(kernel, classes, sub)
-    blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv)
+    blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv, D2)
     tables = _row_tables(classes, sub)
+    n = len(tables)
+    dq_shape = _sds(_heads_shape(rows, B, H, T, D), q.dtype, q, k, v, do)
+    in_specs = [tile, whole, vwhole, otile, stats, stats, rng, *second]
+    scratch_shapes = ([pltpu.VMEM((hb, bq, D), jnp.float32),
+                       pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                       pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                       pltpu.VMEM((4, bq, _LANES), jnp.int32)]
+                      + ([pltpu.VMEM((hb, bq, D2), jnp.float32)] if pair else []))
     dq = pl.pallas_call(
-        _tables_first(_mdq_kernel, len(tables), scale=scale, bk=bk, nq=nq,
-                      nk=nk, per_batch=per_batch, sub=sub and sub.grid),
+        _pair_refs(_tables_first(
+            _mdq_kernel, n, scale=scale, bk=bk, nq=nq, nk=nk,
+            per_batch=per_batch, sub=sub and sub.grid),
+            # q2 and k2 the last inputs, dq2 after dq, its accumulator last
+            pair, (n + len(in_specs), 2), (2, 1), (len(scratch_shapes), 1)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables), grid=(B, H // hb, nq),
-            in_specs=[tile, whole, vwhole, otile, stats, stats, rng],
-            out_specs=tile,
-            scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32),
-                            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-                            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-                            pltpu.VMEM((4, bq, _LANES), jnp.int32)]),
-        out_shape=_sds(_heads_shape(rows, B, H, T, D), q.dtype, q, k, v, do),
+            in_specs=in_specs,
+            out_specs=[tile, second[0]] if pair else tile,
+            scratch_shapes=scratch_shapes),
+        out_shape=[dq_shape, _sds(pair[0].shape, q.dtype, q, k, v, do)]
+        if pair else dq_shape,
         compiler_params=_vmem(blocks, scratch=scratch),
         interpret=_pallas.INTERPRET,
         name="hvd_flash_dq",
-    )(*tables, q, k, v, do, lse, delta, ranges)
+    )(*tables, q, k, v, do, lse, delta, ranges, *(pair or ()))
 
     table, P = _pair_table(classes, sub)
     # table entry p of batch row b: key tile at [.. + 0], query tile at
@@ -1189,34 +1368,51 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths):
     kv_blk = lambda d: _heads_block(
         rows, 1, bk, d, lambda b, c, p, t: (b, c, t[at(b, p)]))
     row_blk = pl.BlockSpec((1, g, nq, bq), lambda b, c, p, t: (b, c, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_mdkv_kernel, scale=scale, P=P, g=g,
-                          per_batch=per_batch, sub=sub and sub.grid),
+    if pair:
+        # the key head of the second pair that kv head c's queries read
+        k2_blk = _heads_block(
+            rows, 1, bk, D2, lambda b, c, p, t: (b, c * g // g2, t[at(b, p)]))
+    in_specs = [
+        q_blk(D), kv_blk(D), kv_blk(Dv), q_blk(Dv), row_blk, row_blk,
+        # the query tile's ranges, a row a bound: [Bm, 4, T]
+        pl.BlockSpec((1, 4, bq),
+                     lambda b, c, p, t: (bm(b), 0, t[at(b, p) + 1])),
+    ] + ([q_blk(D2), k2_blk] if pair else [])
+    out_specs = [kv_blk(D), kv_blk(Dv)] + ([kv_blk(D2)] if pair else [])
+    scratch_shapes = ([pltpu.VMEM((bk, D), jnp.float32),
+                       pltpu.VMEM((bk, Dv), jnp.float32)]
+                      + ([pltpu.VMEM((2, g, 1, bq), jnp.float32)] if sub else [])
+                      + ([pltpu.VMEM((bk, D2), jnp.float32)] if pair else []))
+    dk, dv, *dk2 = pl.pallas_call(
+        _pair_refs(functools.partial(
+            _mdkv_kernel, scale=scale, P=P, g=g, per_batch=per_batch,
+            sub=sub and sub.grid),
+            # q2 and k2 the last inputs; this kv head's part of dk2 after dk
+            # and dv; its accumulator the last scratch
+            pair, (1 + len(in_specs), 2), (len(out_specs), 1),
+            (len(scratch_shapes), 1)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hkv, P),
-            in_specs=[
-                q_blk(D), kv_blk(D), kv_blk(Dv), q_blk(Dv), row_blk,
-                row_blk,
-                # the query tile's ranges, a row a bound: [Bm, 4, T]
-                pl.BlockSpec((1, 4, bq),
-                             lambda b, c, p, t: (bm(b), 0, t[at(b, p) + 1])),
-            ],
-            out_specs=[kv_blk(D), kv_blk(Dv)],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, Dv), jnp.float32)]
-            + ([pltpu.VMEM((2, g, 1, bq), jnp.float32)] if sub else [])),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
         out_shape=[
             _sds(_heads_shape(rows, B, Hkv, Tk, D), k.dtype, q, k, v, do),
             _sds(_heads_shape(rows, B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
-        ],
-        compiler_params=_vmem(g * bq * (D + Dv) * item,
-                              2 * bk * (D + Dv) * item,
+        ] + ([_sds(_heads_shape(rows, B, Hkv, Tk, D2), jnp.float32,
+                   q, k, v, do)] if pair else []),
+        compiler_params=_vmem(g * bq * (D + Dv + D2) * item,
+                              2 * bk * (D + Dv) * item + bk * D2 * (item + 4),
                               2 * g * T * 4, 8 * bq * 4,
-                              scratch=bk * (D + Dv) * 4),
+                              scratch=bk * (D + Dv + D2) * 4),
         interpret=_pallas.INTERPRET,
         name="hvd_flash_dkv",
-    )(table, q, k, v, do, lse, delta, ranges.transpose(0, 2, 1))
-    return dq, dk, dv
+    )(table, q, k, v, do, lse, delta, ranges.transpose(0, 2, 1),
+      *(pair or ()))
+    if not pair:
+        return dq, dk, dv
+    dq, dq2 = dq
+    dk2 = dk2[0].reshape(B, Tk, -1, g2 // g, D2).sum(3).astype(k.dtype)
+    return dq, dk, dv, dq2, dk2.reshape(pair[1].shape)
 
 
 class _StaticMask:
@@ -1264,6 +1460,33 @@ _masked_attention_lse.defvjp(_masked_attention_lse_fwd,
                              _masked_attention_lse_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _paired_attention_lse(q, k, v, q2, k2, mask, static, scale, widths):
+    """:func:`_masked_attention_lse` with the second pair ``(q2 [B, T,
+    H*D2], k2 [B, Tk, H2*D2])``, whole lane tiles a head, in the caller's
+    rows."""
+    return _masked_fwd(q, k, v, static.ranges if static else mask, scale,
+                       widths, (q2, k2))
+
+
+def _paired_attention_lse_fwd(q, k, v, q2, k2, mask, static, scale, widths):
+    out, lse = _named_residuals(
+        *_paired_attention_lse(q, k, v, q2, k2, mask, static, scale, widths))
+    return (out, lse), (q, k, v, q2, k2, mask, out, lse)
+
+
+def _paired_attention_lse_bwd(static, scale, widths, res, cotangents):
+    do, dlse = cotangents
+    q, k, v, q2, k2, mask, out, lse = res
+    return _masked_bwd(
+        q, k, v, out, lse, do, static.ranges if static else mask, scale,
+        dlse, widths, (q2, k2)) + (None,)
+
+
+_paired_attention_lse.defvjp(_paired_attention_lse_fwd,
+                             _paired_attention_lse_bwd)
+
+
 # ------------------------------------------------------------- public op
 # The GQA group in _mdkv_kernel's q block assumes query heads of one kv
 # group are contiguous (head h ↔ kv head h // g; in the caller's layout
@@ -1272,16 +1495,17 @@ _masked_attention_lse.defvjp(_masked_attention_lse_fwd,
 # Each custom_vjp serves both entry points: the plain path is the lse path
 # with a zero lse cotangent (folded into delta as a cheap subtract).
 
-def _attention_lse(q, k, v, causal, sm_scale, mask=None):
+def _attention_lse(q, k, v, causal, sm_scale, mask=None, pair=None):
     """Both public entry points: ``(out [B,T,H,D], lse [B,H,T])`` by the
     packed path where there is no ``mask`` and :func:`_pack` takes these
-    shapes, else by the masked path."""
-    scale = float(sm_scale if sm_scale is not None
-                  else q.shape[-1] ** -0.5)
+    shapes, else by the masked path; with a second ``pair`` by the masked
+    kernels built with it."""
+    scale = float(sm_scale if sm_scale is not None else
+                  (q.shape[-1] + (pair[0].shape[-1] if pair else 0)) ** -0.5)
     B, T, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
     if mask is None:
-        pack = ((1, 1) if v.shape[-1] != D else
+        pack = ((1, 1) if v.shape[-1] != D or pair else
                 _pack(B, H, Hkv, T, Tk, D, q.dtype.itemsize))
         if pack != (1, 1):
             out, lse = _packed_attention_lse(
@@ -1292,6 +1516,17 @@ def _attention_lse(q, k, v, causal, sm_scale, mask=None):
     static = _StaticMask(mask) if isinstance(mask, np.ndarray) else None
     Dv = v.shape[-1]
     widths = _row_widths(D, Dv)
+    if pair:
+        # a head of the second pair as whole lane tiles, zeros after its
+        # own columns (module docstring, "a second pair")
+        D2 = _padded(pair[0].shape[-1])
+        q2, k2 = (jnp.pad(x, ((0, 0),) * 3 + ((0, D2 - x.shape[-1]),))
+                  .reshape(x.shape[0], x.shape[1], -1) for x in pair)
+        out, lse = _paired_attention_lse(
+            q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
+            v.reshape(B, Tk, Hkv * Dv), q2, k2, None if static else mask,
+            static, scale, widths)
+        return out.reshape(B, T, H, Dv), lse.reshape(B, H, T)
     if widths:
         operands = (q.reshape(B, T, H * D), k.reshape(B, Tk, Hkv * D),
                     v.reshape(B, Tk, Hkv * Dv))
@@ -1304,12 +1539,15 @@ def _attention_lse(q, k, v, causal, sm_scale, mask=None):
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None, mask=None):
+                    sm_scale: Optional[float] = None, mask=None, pair=None):
     """Fused exact attention.  ``q [B,T,H,D]``, ``k [B,Tk,Hkv,D]``, ``v
     [B,Tk,Hkv,Dv]``; ``out [B,T,H,Dv]``.
     ``mask``: the ranges each query row sees (module docstring); given
-    one, ``causal`` is not looked at."""
-    return _attention_lse(q, k, v, causal, sm_scale, mask)[0]
+    one, ``causal`` is not looked at.  ``pair``: a second query/key pair
+    ``(q2 [B,T,H,D2], k2 [B,Tk,H2,D2])``, ``H2 | Hkv``, whose product is
+    added to ``q k^T`` before the softmax (module docstring, "a second
+    pair"); the default scale is then ``(D + D2) ** -0.5``."""
+    return _attention_lse(q, k, v, causal, sm_scale, mask, pair)[0]
 
 
 def flash_attention_lse(q, k, v, causal: bool = True,

@@ -24,7 +24,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import metrics as _metrics
+
 NEG_INF = -1e30
+
+_m_pair = _metrics.counter(
+    "hvd_mla_call_total",
+    "Attention calls with a second query/key pair traced (latent "
+    "attention's), one a call site: path pallas (the masked flash kernels) "
+    "or xla (the blockwise fallback); form split (the kernels add the "
+    "pair's product to the score tile) or joined (one query/key of both "
+    "parts, the shared rotary key copied a head) "
+    "(parallel/ring_attention.py local_attention)", labels=("path", "form"))
 
 
 def _block_attend(q, k, v, m, l, o, q_start, k_start, causal, scale,
@@ -119,9 +130,18 @@ def blockwise_attend(q, k, v, m, l, o, q_start, k_start, causal: bool,
     return m, l, o
 
 
+def join_pair(q, k, pair):
+    """A second query/key pair ``(q2 [B, T, H, D2], k2 [B, Tk, H2, D2])``,
+    ``H2 | Hkv``, whose product is added to ``q k^T`` before the softmax,
+    joined into one: ``([q ; q2], [k ; k2 copied to k's heads])``."""
+    q2, k2 = pair
+    return (jnp.concatenate([q, q2], -1), jnp.concatenate(
+        [k, jnp.repeat(k2, k.shape[2] // k2.shape[2], axis=2)], -1))
+
+
 def local_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_size: int = 512, mask=None):
+                    block_size: int = 512, mask=None, pair=None):
     """Exact single-shard attention with O(T·block) live memory.
 
     On TPU the fused Pallas kernel path
@@ -134,13 +154,27 @@ def local_attention(q, k, v, causal: bool = True,
     v may be ``[B, Tk, Hkv, Dv]``, the result then ``[B, T, H, Dv]``.
     ``mask``: ``[T, 4]`` or ``[B, T, 4]`` key ranges per query row, in
     place of ``causal`` (the kernels skip the tiles they leave empty).
+    ``pair``: a second query/key pair whose product joins the scores
+    (:func:`join_pair`; latent attention's rotary part, one key head for
+    every query head): the kernels take it as it is where they can
+    (``split``), else it is joined into ``q`` and ``k`` here; the default
+    scale is then over both widths.
     """
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    width = q.shape[-1] + (pair[0].shape[-1] if pair else 0)
+    scale = sm_scale if sm_scale is not None else width ** -0.5
 
     from ..ops import flash_attention as _fa
-    if _fa.supported(q, k, v, causal, mask):
+    paired = pair is not None
+    kernels = _fa.supported(q, k, v, causal, mask, pair=pair)
+    if paired and not kernels:
+        (q, k), pair = join_pair(q, k, pair), None
+        kernels = _fa.supported(q, k, v, causal, mask)
+    if paired and _metrics.ACTIVE:
+        _m_pair.inc(path="pallas" if kernels else "xla",
+                    form="split" if pair else "joined")
+    if kernels:
         return _fa.flash_attention(q, k, v, causal=causal, sm_scale=scale,
-                                   mask=mask)
+                                   mask=mask, pair=pair)
     if mask is not None:
         mask = jnp.asarray(mask, jnp.int32)
         mask = mask if mask.ndim == 3 else mask[None]

@@ -33,7 +33,9 @@ SCOPE_SSD_SCAN = "hvd_ssd_scan"    # inside it: ops/ssd_scan.py's call, whatever
 SCOPE_KDA_MIXER = "hvd_kda_mixer"  # Kimi Delta Attention: norm1, projections, conv, scan, gated norm
 SCOPE_KDA_SCAN = "hvd_kda_scan"    # inside it: ops/kda_scan.py's call, whatever computes it
 SCOPE_WINDOW_ATTENTION = "hvd_window_attention"  # plain GQA under the window ("swa"), as hvd_attention
-SCOPE_ROPE = "hvd_rope"            # inside either: a kind's rotary table and its products with q and k
+SCOPE_ROPE = "hvd_rope"            # inside either (or hvd_mla_attention): a kind's rotary table and its products with q and k
+SCOPE_MLA_ATTENTION = "hvd_mla_attention"  # latent attention ("mla"): norm1, projections, rotation, kernels, wo
+SCOPE_MLA_LATENT = "hvd_mla_latent"  # inside it: u Wkv_a, the split, the latent's norm, c Wkv_b
 # Inside ``hvd_mlp`` where the feed-forward is routed (``models/moe.py``)
 SCOPE_ROUTE = "hvd_moe_route"        # router logits, scores, top-k
 SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine

@@ -90,23 +90,26 @@ def _dense_masked_lse(q, k, live):
 # How many query heads a masked forward grid step takes, at every branch
 # of ``_fwd_heads``: (H, Hkv, D), the step's VMEM budget, the heads.
 HEAD_CASES = {
-    "g8-whole-group": ((8, 1, 64), None, 8),
-    "g8-split-group": ((8, 1, 64), 3 << 20, 4),
-    "g8-one-head": ((8, 1, 64), 1 << 20, 1),      # not even two fit
+    # (groups of four at head_dim 64: the interpreter's time is the heads
+    # a kernel unrolls, and a group's whole, a part of it and one head are
+    # the branches; groups of eight run at 128, below)
+    "g4-whole-group": ((4, 1, 64), None, 4),
+    "g4-split-group": ((4, 1, 64), 2 << 20, 2),
+    "g4-one-head": ((4, 1, 64), 1 << 20, 1),      # not even two fit
     "g2-d128": ((4, 2, 128), None, 2),
     "g1-d128": ((2, 2, 128), None, 1),
     "g8-d128": ((8, 1, 128), None, 8),
 }
 WHOLE = (128, None)          # tiles of 128: a mixed tile is taken whole
 MASKED_CASES = (
-    [(mask, per_batch, "g8-whole-group", WHOLE) for mask in sorted(MASKS)
+    [(mask, per_batch, "g4-whole-group", WHOLE) for mask in sorted(MASKS)
      for per_batch in (False, True)]
     + [("block-diffusion", per_batch, heads, WHOLE) for heads in HEAD_CASES
-       if heads != "g8-whole-group" for per_batch in (False, True)]
+       if heads != "g4-whole-group" for per_batch in (False, True)]
     # a mixed tile by its sub-tiles: 2 x 2 of them, and the chip's 4 x 4
     + [(mask, False, "g2-d128", (256, 128))
        for mask in sorted(set(SUB_MASKS) - {"window"})]
-    + [(mask, False, "g8-whole-group", (256, 128))
+    + [(mask, False, "g4-whole-group", (256, 128))
        for mask in ("causal", "first-or-last")]
     + [("block-diffusion", False, "g2-d128", (512, 128))])
 
@@ -135,10 +138,12 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
     before = _kernel_counts()
     if budget is not None:
         monkeypatch.setattr(fa, "_MASKED_STEP_VMEM", budget)
-    B = 2
     assert fa._fwd_heads(H // Hkv, blk, blk, D, T // blk, T, 4) == hb
-    q, k, v = make_qkv(B, T, H, Hkv, D)
     ranges = SUB_MASKS[mask](T)
+    # two batch rows where each has a mask of its own, else one: a grid
+    # step of the interpreter costs what it costs, whatever it computes
+    B = 2 if per_batch or ranges.ndim == 3 else 1
+    q, k, v = make_qkv(B, T, H, Hkv, D)
     live = jnp.asarray(fa.dense_mask(ranges, T))
     given = (jnp.asarray(np.stack([ranges] * B)) if per_batch else
              jnp.asarray(ranges) if ranges.ndim == 3 else ranges)
@@ -162,13 +167,15 @@ def test_masked_kernels_match_dense_masked_attention(mask, per_batch, heads,
     def loss(attend):
         return lambda q, k, v: (attend(q, k, v) ** 2).sum()
 
-    out, lse = fa.flash_attention_lse(q, k, v, mask=given)
+    # one forward kernel for both: the gradients of ``(out ** 2).sum()``
+    # are the forward's own transposed at ``2 out``
+    (out, lse), transposed = jax.vjp(
+        lambda q, k, v: fa.flash_attention_lse(q, k, v, mask=given), q, k, v)
     np.testing.assert_allclose(out, _dense_masked(q, k, v, live),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(lse, _dense_masked_lse(q, k, live),
                                atol=2e-5, rtol=2e-5)
-    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
-        q, k, v, mask=given)), (0, 1, 2))(q, k, v)
+    got = transposed((2 * out, jnp.zeros_like(lse)))
     want = jax.grad(loss(lambda q, k, v: _dense_masked(q, k, v, live)),
                     (0, 1, 2))(q, k, v)
     for a, b, name in zip(got, want, "qkv"):
@@ -531,3 +538,111 @@ def test_differential_attentions_kernels_lower_for_the_chip(kind, monkeypatch):
         (0, 1, 2))(q, k, v)).lower(q, k, v).compile().as_text()
     for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert name in text
+
+
+# ----------------- latent attention's calls: a second query/key pair
+# (models/hybrid.py's ``mla`` kind: a head's scores are a product with its
+# own keys plus one with a rotary key that every head shares; the backward
+# is tests/test_flash_masked_bwd.py)
+
+def _pair_operands(B, T, H, Hkv, H2, D=128, D2=64, Dv=128, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    shapes = ((B, T, H, D), (B, T, Hkv, D), (B, T, Hkv, Dv), (B, T, H, D2),
+              (B, T, H2, D2))
+    return [jax.random.normal(k, s, jnp.float32) for k, s in zip(ks, shapes)]
+
+
+def _joined_dense(q, k, v, q2, k2, live):
+    """Dense attention of the joined form: one query/key of both parts,
+    the second pair's key heads copied to the first's."""
+    from horovod_tpu.parallel.ring_attention import join_pair
+    qq, kk = join_pair(q, k, (q2, k2))
+    return _dense_masked(qq, kk, v, live)
+
+
+@pytest.mark.parametrize("H,Hkv,H2,mask", [
+    (2, 2, 1, "causal"), (4, 2, 1, "window"), (4, 4, 2, "block-diffusion")],
+    ids=["mla-one-shared-key", "gqa-under-one-key", "two-key-heads"])
+def test_second_pair_joins_the_scores_as_the_joined_form_does(
+        H, Hkv, H2, mask, monkeypatch, pallas_interpret):
+    """``flash_attention(pair=(q2, k2))``: the split form (the kernels add
+    the second pair's product to each score tile, the pair's heads padded
+    to a lane tile, its key head read under its own grouping) against dense
+    attention over the joined ``[q ; q2]``, ``[k ; k2 a head]``: a key head
+    for every query head (latent attention's), for a GQA group, and two key
+    heads of the pair over four; ``out`` and ``lse``; the default scale is
+    over both widths; counted as ``paired``."""
+    from horovod_tpu import metrics
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+    T = 256
+    q, k, v, q2, k2 = _pair_operands(1, T, H, Hkv, H2)
+    ranges = MASKS[mask](T)
+    live = jnp.asarray(fa.dense_mask(ranges, T))
+    assert fa.supported(q, k, v, False, ranges, pair=(q2, k2))
+    before = _kernel_counts()
+    out, lse = fa._attention_lse(q, k, v, False, None, ranges, (q2, k2))
+    np.testing.assert_allclose(out, _joined_dense(q, k, v, q2, k2, live),
+                               atol=2e-5, rtol=2e-5)
+    from horovod_tpu.parallel.ring_attention import join_pair
+    np.testing.assert_allclose(lse, _dense_masked_lse(
+        *join_pair(q, k, (q2, k2)), live), atol=2e-5, rtol=2e-5)
+    assert _grew(before) == {("fwd", "paired", "rows")}
+
+
+def test_second_pair_is_refused_where_the_kernels_cannot_take_it(
+        pallas_interpret):
+    q, k, v, q2, k2 = _pair_operands(1, 256, 4, 2, 1)
+    why = lambda *a, **kw: fa._refusal(*a, **kw) or ""
+    assert fa._refusal(q, k, v, (q2, k2)) is None
+    assert "do not divide" in why(q, k, v, (q2, jnp.tile(k2, (1, 1, 4, 1))))
+    assert "whole lane tiles" in why(q[..., :64], k[..., :64], v, (q2, k2))
+    assert "multiple of 64" in why(q, k, v, (q2[..., :32], k2[..., :32]))
+    assert "in q's dtype" in why(q, k, v, (q2.astype(jnp.bfloat16), k2))
+    assert "in q's dtype" in why(q, k, v, (q2[:, :128], k2))
+    # the pair's whole keys stay resident beside k and v: a step that does
+    # not fit is refused, not built
+    long = lambda h, d: jax.ShapeDtypeStruct((1, 65536, h, d), jnp.bfloat16)
+    assert "bytes of VMEM" in why(long(2, 128), long(2, 128), long(2, 128),
+                                  (long(2, 64), long(1, 64)))
+
+
+@pytest.mark.parametrize("form", ["split", "joined"])
+def test_latent_attentions_kernels_lower_at_16384_positions(form, monkeypatch):
+    """Mosaic takes the three masked kernels at the benchmark's
+    kanana-2-30b-a3b cell, causal over 16,384 positions, 32 heads, bf16.
+    ``split``: scores as a 128-wide product a head plus a 64-wide one with
+    the one rotary key all 32 heads share (``pair=``), values 128, on the
+    caller's ``[B, T, H*D]`` with no rank-4 ``transpose`` beside the
+    kernels and the shared key never copied a head.  ``joined``: one
+    192-wide query and key a head, the heads route, whose resident keys and
+    values are the budget to the byte."""
+    import re
+    from horovod_tpu.parallel.ring_attention import join_pair
+    one_chip = _described_chip(monkeypatch)
+    T, H = 16384, 32
+    sds = lambda h, d: jax.ShapeDtypeStruct((1, T, h, d), jnp.bfloat16,
+                                            sharding=one_chip)
+    q, k, v, q2, k2 = sds(H, 128), sds(H, 128), sds(H, 128), sds(H, 64), sds(1, 64)
+    ranges = fa.causal_ranges(T)
+    assert fa.supported(q, k, v, True, ranges, pair=(q2, k2))
+    assert fa.supported(sds(H, 192), sds(H, 192), v, True, ranges)
+    assert T * (192 + 128) * 2 == fa._VMEM_BUDGET
+
+    def attend(q, k, v, q2, k2):
+        if form == "split":
+            return fa.flash_attention(q, k, v, mask=ranges, pair=(q2, k2))
+        return fa.flash_attention(*join_pair(q, k, (q2, k2)), v, mask=ranges,
+                                  sm_scale=192 ** -0.5)
+
+    text = jax.jit(lambda *a: jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        (0, 1, 2, 3, 4))(*a)).lower(q, k, v, q2, k2).compile().as_text()
+    for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
+        assert name in text
+    moved = re.findall(
+        r"= \w+\[1,(?:32,16384|16384,32),\d+\]\S* (?:transpose|copy)\(", text)
+    if form == "split":
+        assert not moved, moved
+    else:       # q, k, v, out and the gradients laid out heads first
+        assert "bf16[1,32,16384,192]" in text

@@ -23,9 +23,11 @@ from test_flash_masked import (SUB_MASKS, WHOLE, _case_id, _dense_masked,
 # ``_dq_heads``, on both layouts: (H, Hkv, D, Dv), the step's VMEM budget,
 # the heads.  ``dkv`` takes the whole group a step whatever that says.
 BACKWARD_CASES = {
-    "g8-whole-group": ((8, 1, 64, 64), None, 8),
-    "g8-split-group": ((8, 1, 64, 64), 4 << 20, 4),
-    "g8-one-head": ((8, 1, 64, 64), 1 << 20, 1),      # not even two fit
+    # (groups of four at head_dim 64, as the forward's cases: groups of
+    # eight run at 128, below)
+    "g4-whole-group": ((4, 1, 64, 64), None, 4),
+    "g4-split-group": ((4, 1, 64, 64), 2 << 20, 2),
+    "g4-one-head": ((4, 1, 64, 64), 1 << 20, 1),      # not even two fit
     "g2-values-twice-as-wide": ((4, 2, 64, 128), None, 2),   # the Phi call
     "g1-d128": ((2, 2, 128, 128), None, 1),
     "g8-d128": ((8, 1, 128, 128), None, 8),
@@ -117,8 +119,8 @@ def _masked_routes(q, k, v, mask, scale, w_out, w_lse):
 
 
 @pytest.mark.parametrize("H,Hkv,Dv,per_batch", [
-    (4, 2, 128, False), (8, 1, 128, True), (2, 2, 256, False)],
-    ids=["g2", "g8-mask-per-row", "g1-values-256"])
+    (4, 2, 128, False), (4, 1, 128, True), (2, 2, 256, False)],
+    ids=["g2", "g4-mask-per-row", "g1-values-256"])
 def test_rows_and_heads_routes_are_equal_to_the_bit(H, Hkv, Dv, per_batch,
                                                     monkeypatch,
                                                     pallas_interpret):
@@ -246,3 +248,48 @@ def test_masked_backward_specs(D, Dv, pallas_interpret):
         blk(g, bq, D), blk(1, bq, D), blk(1, bq, Dv), blk(g, bq, Dv),
         stats(g), stats(g), (1, 4, bq), blk(1, bq, D), blk(1, bq, Dv)],
         [f32(bq, D), f32(bq, Dv), f32(2, g, 1, bq)]]
+
+
+# ----------------- a second query/key pair (latent attention's rotary
+# part: tests/test_flash_masked.py has the forward and the chip's compile)
+
+@pytest.mark.parametrize("H,Hkv,H2,tiles", [
+    (2, 2, 1, (128, None)), (4, 2, 1, (256, 128)), (4, 4, 2, (256, 128))],
+    ids=["mla-one-shared-key", "gqa-under-one-key-by-sub-tiles",
+         "two-key-heads-by-sub-tiles"])
+def test_split_and_joined_forms_agree_backward(H, Hkv, H2, tiles, monkeypatch,
+                                               pallas_interpret):
+    """All five gradients of the split form (``pair=``: ``hvd_flash_dq``
+    writes ``dq2`` beside ``dq``, ``hvd_flash_dkv`` each kv head's part of
+    ``dk2``, added over the heads that share the key) against autodiff
+    through dense attention over the joined query and key; a mixed tile
+    whole and by its sub-tiles; and ``dk2`` of the one shared key is the
+    sum over heads of what a key a head would get."""
+    from test_flash_masked import (_grew, _joined_dense, _kernel_counts,
+                                   _pair_operands)
+    blk, T = _tiles(monkeypatch, tiles)
+    q, k, v, q2, k2 = _pair_operands(1, T, H, Hkv, H2, seed=3)
+    w = jax.random.normal(jax.random.key(9), v.shape[:2] + (H, v.shape[-1]))
+    ranges = fa.causal_ranges(T)
+    live = jnp.asarray(fa.dense_mask(ranges, T))
+    before = _kernel_counts()
+    split = lambda q, k, v, q2, k2: (fa.flash_attention(
+        q, k, v, mask=ranges, pair=(q2, k2)) * w).sum()
+    joined = lambda q, k, v, q2, k2: (
+        _joined_dense(q, k, v, q2, k2, live) * w).sum()
+    got = jax.grad(split, (0, 1, 2, 3, 4))(q, k, v, q2, k2)
+    want = jax.grad(joined, (0, 1, 2, 3, 4))(q, k, v, q2, k2)
+    for a, b, name in zip(got, want, ("q", "k", "v", "q2", "k2")):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
+                                   err_msg=f"d{name}")
+    assert _grew(before) == {(kernel, "paired", "rows")
+                             for kernel in ("fwd", "dq", "dkv")}
+    # a key a kv head in place of the shared one: its gradient's sum over
+    # the heads that shared it is the shared key's
+    if tiles != WHOLE:      # once is enough: the sum is the caller's
+        return
+    g2 = Hkv // H2
+    each = jax.grad(split, 4)(q, k, v, q2, jnp.repeat(k2, g2, axis=2))
+    np.testing.assert_allclose(
+        each.reshape(1, T, H2, g2, -1).sum(3), got[4], atol=2e-4, rtol=2e-4)

@@ -183,12 +183,15 @@ def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
         ("mamba", 0, 1, False), ("mamba", 1, 1, False), ("window", 0, 2, False),
         ("mamba", 2, 1, True), ("full", 0, 1, True), ("gmu", 0, 2, False),
         ("cross", 0, 2, False)]
-    params = llama.init_params(cfg, jax.random.key(0))
+    # (each whole trunk as one compiled program: op by op the interpreter's
+    # dispatch is most of this test's clock)
+    params = jax.jit(lambda key: llama.init_params(cfg, key))(jax.random.key(0))
     assert llama.count_params(cfg) == sum(
         x.size for x in jax.tree_util.tree_leaves(params))
     h = jax.random.normal(jax.random.key(1), (2, 32, 64))
     before = metrics.registry().to_dict().get("hvd_layer_kind_total", {})
-    got = hybrid.layer_stack(h, params["layers"], cfg)
+    got = jax.jit(lambda h_, ls: hybrid.layer_stack(h_, ls, cfg))(
+        h, params["layers"])
     if metrics.ACTIVE:
         count = lambda fam: {s["labels"]["kind"]: s["value"]
                              for s in fam.get("series", [])}
@@ -208,9 +211,9 @@ def test_trunk_scans_runs_of_equal_layers_and_hands_memory_on():
         m, kv = (out, kv) if i == 4 else (m, out) if i == 5 else (m, kv)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     # remat changes no number
-    remat = hybrid.layer_stack(
-        h, params["layers"], _cfg(**{**vars(cfg), "remat": True}),
-        llama.remat_policy("full"))
+    remat = jax.jit(lambda h_, ls: hybrid.layer_stack(
+        h_, ls, _cfg(**{**vars(cfg), "remat": True}),
+        llama.remat_policy("full")))(h, params["layers"])
     np.testing.assert_allclose(remat, got, rtol=1e-6, atol=1e-6)
 
 
@@ -317,20 +320,25 @@ def test_kda_and_routed_trunks_that_cannot_be_are_refused(kw, error):
 
 def test_routed_trunk_hands_on_its_statistics_and_a_dense_one_none():
     cfg = _solar_cfg()
-    params = llama.init_params(cfg, jax.random.key(0))
+    # (each whole trunk as one compiled program: op by op the interpreter's
+    # dispatch is most of this test's clock)
+    stack = lambda c, **kw: jax.jit(
+        lambda h_, ls: hybrid.layer_stack(h_, ls, c, **kw))
+    params = jax.jit(lambda key: llama.init_params(cfg, key))(jax.random.key(0))
     h = jax.random.normal(jax.random.key(1), (2, 32, 64))
-    out, stats = hybrid.layer_stack(h, params["layers"], cfg, with_stats=True)
+    out, stats = stack(cfg, with_stats=True)(h, params["layers"])
     pairs, rows, fullest, layers = np.asarray(stats)
     assert layers == 3 and pairs == rows and 0 < fullest <= pairs
     assert 0 < pairs < 3 * 2 * 32 * 4
-    np.testing.assert_array_equal(out, hybrid.layer_stack(h, params["layers"], cfg))
+    np.testing.assert_array_equal(out, stack(cfg)(h, params["layers"]))
     tokens = jnp.zeros((2, 32), jnp.int32)
-    _, got = llama.loss_fn(params, tokens, tokens, cfg, llama.ParallelSpec(),
-                           with_stats=True)
+    _, got = jax.jit(lambda p: llama.loss_fn(
+        p, tokens, tokens, cfg, llama.ParallelSpec(), with_stats=True))(params)
     assert got.shape == (4,) and got[3] == 3
     dense = _solar_cfg(n_experts=0, n_shared_experts=0)
-    dparams = llama.init_params(dense, jax.random.key(0))
-    _, none = hybrid.layer_stack(h, dparams["layers"], dense, with_stats=True)
+    dparams = jax.jit(lambda key: llama.init_params(dense, key))(
+        jax.random.key(0))
+    _, none = stack(dense, with_stats=True)(h, dparams["layers"])
     assert none is None
     # a layer's own statistics count one layer
     f = hybrid._layer("kda", False, cfg)
@@ -438,6 +446,8 @@ def test_plain_gqa_with_a_rotary_table_follows_its_dense_formula(
         np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()),
                                    rtol=2e-3)
     # without its table the layer is another one; the other kind's too
+    # (on XLA's path: what is looked at is the table, not the kernels)
+    pallas_interpret(False)
     bare = layer(h, lp, 0.0, None)[0]
     other = layer(h, lp, 0.0, None, llama.rope_table(
         YARN if kind == "swa" else PLAIN, DH, T))[0]
@@ -511,3 +521,127 @@ def test_a_softmax_routers_layer_carries_no_selection_bias():
         _solar_cfg(layer_kinds=("kda",), n_layers=1), "kda")
     assert set(hybrid.layer_shapes(cfg, "swa")) == {
         "norm1_w", "norm2_w", "wqkv", "wo", "router", "we_gate", "we_up", "we_down"}
+
+
+# ------------- latent attention ("mla") and a dense layer before routed ones
+# The layer's equations are the benchmark's,
+# benchmark/configs/kanana-2-30b-a3b/reference.py, at its toy sizes
+# (tests/benchmark/test_bench_kanana.py runs the train step against it).
+
+def _latent_cfg(**kw):
+    base = dict(d_model=64, n_heads=4, n_kv_heads=4, head_dim=24, n_layers=3,
+                d_ff=32, dense_d_ff=96, first_dense_layers=1,
+                layer_kinds=("mla",) * 3, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, trunk_norm="rmsnorm",
+                tie_embeddings=False, n_experts=8, expert_top_k=2,
+                experts_held=4, moe_dispatch="dropless",
+                router_score="sigmoid", n_shared_experts=2,
+                routed_scaling_factor=2.448,
+                rope_tables=(("mla", llama.RopeTable(theta=1e6)),))
+    return _cfg(**{**base, **kw})
+
+
+def test_a_dense_layer_before_routed_ones_is_a_stack_of_its_own():
+    """The feed-forward is a layer's: ``first_dense_layers`` leading layers
+    keep the dense one, ``dense_d_ff`` wide, in a stack ``dense_<kind>``
+    beside the kind's routed layers; the statistics handed on count the
+    routed layers alone; at 0 the tree, the runs and the count are what
+    they were."""
+    cfg = _latent_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert list(params["layers"]) == ["mla", "dense_mla"]
+    dense, routed = params["layers"]["dense_mla"], params["layers"]["mla"]
+    latent = {"norm1_w", "norm2_w", "wq", "wkv_a", "kv_norm", "wkv_b", "wo"}
+    assert set(dense) == latent | {"w1", "w2"}
+    assert set(routed) == latent | {"w1", "w2", "router", "router_bias",
+                                    "we_gate", "we_up", "we_down"}
+    assert dense["w1"].shape == (1, 64, 2 * 96) and dense["w2"].shape == (1, 96, 64)
+    assert routed["w1"].shape == (2, 64, 2 * 2 * 32)
+    assert routed["wq"].shape == (2, 64, 4 * 24) and routed["wkv_a"].shape == (2, 64, 40)
+    assert routed["wkv_b"].shape == (2, 32, 4 * 32) and routed["wo"].shape == (2, 64, 64)
+    assert (np.asarray(routed["kv_norm"]) == 1).all()
+    assert llama.count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    specs = llama.param_specs(llama.ParallelSpec(), cfg)
+    assert {k: set(v) for k, v in specs["layers"].items()} == {
+        k: set(v) for k, v in params["layers"].items()}
+    assert [(s, at, ids) for s, at, ids, _ in hybrid._runs(cfg)] == [
+        ("dense_mla", 0, [0]), ("mla", 0, [1]), ("mla", 1, [2])]
+    h = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    out, stats = jax.jit(lambda h, ls: hybrid.layer_stack(
+        h, ls, cfg, with_stats=True))(h, params["layers"])
+    pairs, rows, fullest, layers = np.asarray(stats)
+    assert layers == 2 and pairs == rows and 0 < fullest <= pairs
+    assert out.shape == h.shape and np.isfinite(np.asarray(out)).all()
+    # the dense layer's own function hands on no statistics
+    one = jax.tree_util.tree_map(lambda w: w[0], dense)
+    rope = llama.rope_table(llama.RopeTable(theta=1e6), 8, 32)
+    none = jax.eval_shape(hybrid._layer("mla", False, cfg, dense=True), h, one,
+                          0.0, None, rope)[2]
+    assert none is None
+    # no leading dense layer: the kind's stack alone, as before the field
+    plain = _latent_cfg(first_dense_layers=0)
+    made = lambda c: jax.eval_shape(lambda k: llama.init_params(c, k),
+                                    jax.random.key(0))["layers"]
+    assert list(made(plain)) == ["mla"]
+    assert [s for s, *_ in hybrid._runs(plain)] == ["mla"] * 3
+    # without experts every layer is dense and none is set apart
+    dense_only = _latent_cfg(n_experts=0, n_shared_experts=0,
+                             routed_scaling_factor=1.0)
+    assert list(made(dense_only)) == ["mla"]
+    assert [(s, ids) for s, _, ids, _ in hybrid._runs(dense_only)] == [
+        ("mla", [0, 1, 2])]
+
+
+def test_latent_attention_follows_its_dense_formula():
+    """``latent_attention`` against the equations written out: one product
+    over the joined 24-wide query and key, the rotary key shared by all
+    four heads, the latent normed, the scale over both widths."""
+    cfg = _latent_cfg()
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0], llama.init_params(cfg, jax.random.key(2))["layers"]["mla"])
+    B, T, Hh, R, Dn, Dr, Dv = 2, 32, 4, 32, 16, 8, 16
+    u = jax.random.normal(jax.random.key(3), (B, T, 64))
+    rope = llama.rope_table(llama.RopeTable(theta=1e6), Dr, T)
+    got = hybrid.latent_attention(u, lp, rope, cfg)
+    q = u @ lp["wq"]
+    q_n, q_r = q[..., :Hh * Dn].reshape(B, T, Hh, Dn), q[..., Hh * Dn:].reshape(B, T, Hh, Dr)
+    a = u @ lp["wkv_a"]
+    c, k_r = a[..., :R], a[..., R:].reshape(B, T, 1, Dr)
+    c = c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True) + cfg.norm_eps) * lp["kv_norm"]
+    kv = c @ lp["wkv_b"]
+    k_n, v = kv[..., :Hh * Dn].reshape(B, T, Hh, Dn), kv[..., Hh * Dn:].reshape(B, T, Hh, Dv)
+    q_r, k_r = llama.rotate(q_r, *rope), llama.rotate(k_r, *rope)
+    s = (jnp.einsum("bqhd,bkhd->bhqk", q_n, k_n)
+         + jnp.einsum("bqhd,bkd->bhqk", q_r, k_r[:, :, 0])) * 24 ** -0.5
+    p = jax.nn.softmax(jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf), -1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, T, Hh * Dv) @ lp["wo"]
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+
+
+@pytest.mark.parametrize("kw,error", [
+    (dict(kv_lora_rank=0), "an mla layer needs"),
+    (dict(qk_rope_head_dim=7), "an mla layer needs"),
+    (dict(first_dense_layers=4), "first_dense_layers counts"),
+    (dict(rope_tables=(("kda", llama.RopeTable()),)), "one table at most")])
+def test_latent_trunks_that_cannot_be_are_refused(kw, error):
+    with pytest.raises(ValueError, match=error):
+        hybrid.check(_latent_cfg(**kw))
+
+
+def test_config_fields_of_the_latent_trunk_are_refused_elsewhere():
+    with pytest.raises(ValueError, match="first_dense_layers"):
+        llama.LlamaConfig(first_dense_layers=1)
+    with pytest.raises(ValueError, match="routed_scaling_factor"):
+        llama.LlamaConfig(routed_scaling_factor=2.5)
+
+
+def test_routed_scaling_factor_multiplies_the_chosen_weights():
+    from horovod_tpu.models import moe
+    tokens = jax.random.normal(jax.random.key(0), (16, 64))
+    router = jax.random.normal(jax.random.key(1), (64, 8))
+    i1, w1 = moe.route(tokens, router, 2, "sigmoid", jnp.zeros((8,)))
+    i2, w2 = moe.route(tokens, router, 2, "sigmoid", jnp.zeros((8,)), 2.448)
+    np.testing.assert_array_equal(i1, i2)
+    np.testing.assert_allclose(w2, w1 * 2.448, rtol=1e-6)
+    np.testing.assert_allclose(w1.sum(-1), 1.0, rtol=1e-6)
