@@ -105,8 +105,10 @@ Two paths, chosen from the shapes and the mask (:func:`_pack`; no knob):
   bound) lie along the lanes as they are stored and go down the sublanes
   for nothing, one mask serves the pair's ``g`` heads, and ``dv = P^T do``
   and ``dk = dS^T q`` are plain ``[bk, bq] x [bq, D]`` products.  The
-  backward is still two kernels and 7 products a live pair of a head (S
-  and dP made again in each), bf16 operands, float32 accumulation.  Every
+  backward of a call without a second pair is two kernels and 7 products
+  a live pair of a head (S and dP made again in each), bf16 operands,
+  float32 accumulation; a call with one takes one kernel ("The one
+  backward", below).  Every
   query row has to see at least one key.  Two layouts, chosen from the
   widths where the call is built (no knob): where ``D`` and ``Dv`` are
   whole lane tiles (multiples of 128) the kernels read ``q``, ``k``,
@@ -131,8 +133,8 @@ around the kernels, and at 16,384 keys the resident ``Tk x (192 + 128) x
 pair as it is: ``q2 [B, T, H, D2]``, ``k2 [B, Tk, H2, D2]`` with ``H2 |
 Hkv`` (a grouping of its own: one key head of the pair serves ``H / H2``
 query heads), and add ``q2 k2^T`` to each score tile before the mask and
-the softmax; ``hvd_flash_dq`` accumulates and writes ``dq2`` beside ``dq``;
-``hvd_flash_dkv``, whose grid step is a kv head, writes that kv head's part
+the softmax; the backward accumulates and writes ``dq2`` beside ``dq``
+and, its grid step being a kv head, writes that kv head's part
 of ``dk2`` in float32 and the caller adds the ``Hkv / H2`` parts of the kv
 heads that share a key head of the pair (one XLA reduction of ``[B, Tk,
 Hkv, D2]`` float32: 268 MB read a layer at the shape above, a third of a
@@ -156,6 +158,40 @@ whose step does not fit ``_MASKED_STEP_VMEM``, and the caller joins the
 pair instead: ``ring_attention.local_attention``).  A call without a pair
 builds every kernel as it was; one with it counts as path ``paired``.
 
+**The one backward** (``hvd_flash_dqkv``; a call with a second pair).
+``hvd_flash_dq`` and ``hvd_flash_dkv`` each make ``S`` (two products with a
+pair), ``dP`` (one) and ``exp`` of the tile, and each load q, k, v, ``do``,
+``lse`` and ``delta``: 5 + 6 lane-tile products a live pair of a head where
+8 are needed.  What stops one kernel is where ``dq`` lives while the kernel
+walks key tiles: all ``T`` rows of a kv head's group in float32, ``g x T x
+(D + D2) x 4`` bytes.  Latent attention's group is one head (a key and
+value head a query head): 16.8 MB at 16,384 rows of 128 + the pair's 128,
+which VMEM holds.  So such a call's backward is ``hvd_flash_dkv``'s kernel
+body, grid ``(B, Hkv, P)`` over the live pairs in key-tile order and tiles
+keys by queries, given a group of ``dq`` refs as ``pair=`` is a group
+(``_mdkv_kernel(dq=)``): a visit's ``dS^T [bk, bq]`` is turned once (bf16,
+on the XLU beside the MXU's eight products) and ``dq[query rows] += dS k``,
+``dq2[query rows] += dS k2`` accumulate in ``[g, T, D]`` and ``[g, T, D2]``
+float32 scratch, zeroed at the kv head's first grid step and cast out at
+its last into ``dq`` / ``dq2`` blocks that are the group's whole ``[1, T, g
+x D]`` columns of the caller's rows, at an index that stands still along
+``P``, so the pipeline writes them to HBM once a kv head.  The same
+products in the same dtypes and the same sub-tile walk; ``dk``, ``dv`` and
+``dk2`` are ``hvd_flash_dkv``'s to the bit, ``dq`` and ``dq2`` differ from
+``hvd_flash_dq``'s by float32's order of summation alone.  **Which calls
+take it** (:func:`_one_backward`; from the shapes, no knob): those with a
+second pair whose step, reckoned by :func:`_dqkv_step_bytes` (blocks twice,
+scratch, tiles: 41.0 MB at the shape above, of which the accumulators 16.8
+and the two out blocks twice 16.8), fits ``_DQKV_STEP_VMEM`` (48 MiB); the
+call declares that sum and 4 MiB to Mosaic, not a blanket limit.  A paired
+call that does not fit (a longer ``T``, a larger group under one rotary
+key) keeps ``hvd_flash_dq`` + ``hvd_flash_dkv`` with ``pair=``.  Calls
+without a pair keep their two kernels whatever their bytes: at the other
+models' shapes a kv head's ``dq`` is 8 to 17 MB (8 heads x 4,096 x 128) or
+67 MB (8 x 16,384 x 128), and the benchmark's yardstick for them counts the
+backward at 7 products; taking the one backward to those that fit is
+ROADMAP.md D3.
+
 Residuals are named.  Both paths' ``custom_vjp`` keep ``(q, k, v, out,
 lse)`` for the backward kernels, and the forward rules pass ``out`` and
 ``lse`` through ``jax.ad_checkpoint.checkpoint_name`` as ``OUT_NAME``
@@ -177,7 +213,8 @@ program.
 built, once per traced call site, so a program says which path (``packed``,
 ``masked``, or ``paired``: the masked kernels built with a second pair) and
 which layout (``rows`` or ``heads``; packed calls are ``rows``) its shapes
-took;
+took, and which backward a paired call took (``kernel`` ``dqkv``, or ``dq``
+and ``dkv``);
 ``hvd_flash_tiles_total{kernel, state}`` counts a masked call's tiles
 (``live`` = full, ``masked`` = mixed, ``skipped`` = dead) where the call
 is built, when the mask is known there (a numpy array), and
@@ -226,6 +263,10 @@ _SUB = 256
 # what one grid step of the masked forward or of dq may hold: a quarter of
 # a v5e core's 128 MiB of VMEM, half of the smallest there is (v7x: 64 MiB)
 _MASKED_STEP_VMEM = 32 * 1024 * 1024
+# what one grid step of the one backward of a call with a second pair may
+# hold, the kv head's ``dq`` in float32 among it (``_dqkv_step_bytes``):
+# under half of a v5e core's VMEM and three quarters of a v7x core's
+_DQKV_STEP_VMEM = 48 * 1024 * 1024
 # checkpoint names of the forward kernels' two results ("Residuals are
 # named", above)
 OUT_NAME = "hvd_flash_out"
@@ -236,9 +277,10 @@ _count = _pallas.kernel_counter(
     "Flash-attention Pallas kernels built, one per traced call site; "
     "path is packed, masked (tiled stopped occurring: several blocks "
     "without mask= count as masked) or paired (the masked kernels with a "
-    "second query/key pair); layout is rows where the kernels "
-    "read and write the caller's [B, T, H*D], heads where the call is "
-    "transposed to [B, H, T, D] around them",
+    "second query/key pair; its backward is kernel dqkv, one kernel, where "
+    "a kv head's dq fits VMEM, else dq and dkv); layout is rows where the "
+    "kernels read and write the caller's [B, T, H*D], heads where the call "
+    "is transposed to [B, H, T, D] around them",
     labels=("kernel", "path", "layout"))
 
 
@@ -996,7 +1038,7 @@ def _mdq_kernel(tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  r_ref, dk_ref, dv_ref, dk_acc, dv_acc, *st_ref, scale, P, g,
-                 per_batch, sub, pair=None):
+                 per_batch, sub, pair=None, dq=None):
     """One live (key tile, query tile) pair of a kv head's ``g`` query
     heads (static, unrolled).  The tile is formed keys by queries, ``S^T =
     K Q^T [bk, bq]``: a query's ``lse``, ``delta`` and ranges (``r_ref [1,
@@ -1011,12 +1053,31 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     index whole, not one lane tile of it.  ``pair``: the second pair's
     ``(q2_ref, k2_ref, dk2_ref, dk2_acc)``; ``dk2_ref`` takes this kv head's
     part of the shared key head's gradient in float32, and the caller adds
-    the parts of the heads that share it."""
+    the parts of the heads that share it.  ``dq``: ``(dq_ref, dq2_ref,
+    dq_acc, dq2_acc)`` of the one backward (``hvd_flash_dqkv``: a call with
+    a pair whose step :func:`_dqkv_step_bytes` finds room for): the visit's
+    ``dS^T`` turned once gives ``dq += dS k`` and ``dq2 += dS k2`` of its
+    query rows, in ``dq_acc [g, T, D]`` and ``dq2_acc [g, T, D2]`` float32
+    over all the kv head's pairs, zeroed at its first and cast out at its
+    last into blocks that are the group's whole columns of the caller's
+    rows, which the pipeline writes once a kv head."""
     (bk, D), Dv = k_ref.shape[-2:], v_ref.shape[-1]
     if pair:
         q2_ref, k2_ref, dk2_ref, dk2_acc = pair
         D2 = k2_ref.shape[-1]
     bq = r_ref.shape[-1]
+    if dq:
+        dq_ref, dq2_ref, dq_acc, dq2_acc = dq
+        tiles = lambda n: pl.ds(pl.multiple_of(n * bq, bq), bq)
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            def zero(n, carry):
+                dq_acc[:, tiles(n)] = jnp.zeros((g, bq, D), jnp.float32)
+                dq2_acc[:, tiles(n)] = jnp.zeros((g, bq, D2), jnp.float32)
+                return carry
+
+            lax.fori_loop(0, dq_acc.shape[1] // bq, zero, 0)
     width = 5 if sub else 4
     at = ((pl.program_id(0) * P if per_batch else 0)
           + pl.program_id(2)) * width
@@ -1068,6 +1129,15 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if pair:
                 dk2 = _add(dk2, jnp.dot(ds.astype(q2i.dtype), q2i,
                                         preferred_element_type=jnp.float32))
+            if dq:          # the queries' rows of the kv head's T
+                first = queries.start or 0
+                rows = pl.ds(pl.multiple_of(i * bq, bq) + first,
+                             (queries.stop or bq) - first)
+                dst = ds.astype(kb.dtype).T
+                dq_acc[hq, rows] += jnp.dot(
+                    dst, kb, preferred_element_type=jnp.float32)
+                dq2_acc[hq, rows] += jnp.dot(
+                    dst, k2b, preferred_element_type=jnp.float32)
         dk_acc[keys] += dk
         dv_acc[keys] += dv
         if pair:
@@ -1099,6 +1169,19 @@ def _mdkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if pair:
             dk2_ref[_head(dk2_ref, 0, D2)] = dk2_acc[...]
 
+    if dq:
+        @pl.when(pl.program_id(2) == P - 1)
+        def _():
+            def cast(n, carry):
+                for hq in range(g):
+                    dq_ref[_head(dq_ref, hq, D, tiles(n))] = dq_acc[
+                        hq, tiles(n)].astype(dq_ref.dtype)
+                    dq2_ref[_head(dq2_ref, hq, D2, tiles(n))] = dq2_acc[
+                        hq, tiles(n)].astype(dq2_ref.dtype)
+                return carry
+
+            lax.fori_loop(0, dq_acc.shape[1] // bq, cast, 0)
+
 
 def _mask_plan(mask, bq, bk, Tk):
     """(ranges [Bm, T, 4], tile classes, the mixed tiles' sub-tile classes
@@ -1119,21 +1202,22 @@ def _tables_first(kernel, n, **static):
     return lambda *refs, **pair: kernel(refs[:n], *refs[n:], **static, **pair)
 
 
-def _pair_refs(kernel, pair, *groups):
+def _pair_refs(kernel, pair, *groups, name="pair"):
     """``kernel`` for a call with a second ``pair``, whose refs come in
     ``groups`` of ``(how many, the last of them that are the pair's)``: the
     tables with the inputs, the outputs, the scratch.  The pair's are taken
     out and handed on as ``pair=``, in their order; without a pair the
-    kernel as it is."""
+    kernel as it is.  ``name``: another optional group's keyword (the one
+    backward's ``dq=``), taken out the same way before the pair's."""
     if not pair:
         return kernel
     at, end = [], 0
     for size, last in groups:
         end += size
         at += range(end - last, end)
-    return lambda *refs: kernel(
+    return lambda *refs, **others: kernel(
         *(r for n, r in enumerate(refs) if n not in at),
-        pair=tuple(refs[n] for n in at))
+        **{name: tuple(refs[n] for n in at)}, **others)
 
 
 def _vmem(*block_bytes, scratch=0):
@@ -1210,6 +1294,42 @@ def _dq_step_bytes(hb, bq, bk, D, nq, Tk, itemsize, Dv=None, D2=0):
               + bq * _LANES * 4)
     scratch = (hb * bq * (2 * _LANES + D + D2) + 4 * bq * _LANES) * 4
     return blocks, scratch, hb * bq * bk * (8 + itemsize)
+
+
+def _dqkv_step_bytes(g, bq, bk, D, nq, Tk, itemsize, Dv=None, D2=0):
+    """The same for a grid step of the one backward (``hvd_flash_dqkv``), a
+    live pair of tiles of a kv head's ``g`` query heads: the blocks the
+    pipeline double-buffers (the group's query tile of q, ``do`` and q2; a
+    key tile of k, v and k2, of dk and dv and of dk2's float32 part; the
+    group's whole rows of lse and of delta; the ranges, a row a bound,
+    padded to 8 sublanes; **``dq`` and ``dq2`` of the group's whole ``nq x
+    bq`` rows**, written once a kv head), the scratch (dk, dv and dk2 of a
+    key tile, the sub-tiles' statistics, and **``dq`` and ``dq2`` of all
+    the rows in float32**) and a head's tiles in flight (``S^T`` / ``P^T``
+    and ``dP^T`` / ``dS^T`` in float32, ``P^T``, ``dS^T`` and ``dS`` cast).
+    At the kanana cell's call (``g`` 1, 512 x 512 tiles, 128 + 128 + the
+    pair's padded 128, 16,384 rows, bf16) 9.85 MB of blocks of which 8.39
+    are ``dq`` and ``dq2``, 17.60 MB of scratch of which 16.78 are their
+    accumulators, 3.67 MB of tiles: 40.96 MB a step."""
+    Dv = D if Dv is None else Dv
+    T = nq * bq
+    blocks = ((g * bq + bk) * (D + Dv + D2) * itemsize
+              + bk * ((D + Dv) * itemsize + D2 * 4)
+              + 2 * g * T * 4 + 8 * bq * 4 + g * T * (D + D2) * itemsize)
+    scratch = (bk * (D + Dv + D2) + 2 * g * 8 * bq + g * T * (D + D2)) * 4
+    return blocks, scratch, g * bq * bk * (8 + 3 * itemsize)
+
+
+def _one_backward(g, *shapes):
+    """The bytes a step of the one backward holds where a call with a
+    second pair takes it, else None: :func:`_dqkv_step_bytes` of ``g,
+    *shapes``, the blocks twice, under ``_DQKV_STEP_VMEM``.  Latent
+    attention's group is one head (16.8 MB of ``dq`` and ``dq2`` in float32
+    at 16,384 rows); a longer sequence or a larger group under one rotary
+    key keeps ``hvd_flash_dq`` + ``hvd_flash_dkv``."""
+    blocks, scratch, tiles = _dqkv_step_bytes(g, *shapes)
+    step = 2 * blocks + scratch + tiles
+    return step if step <= _DQKV_STEP_VMEM else None
 
 
 def _heads_a_step(step_bytes, g, *shapes):
@@ -1326,7 +1446,9 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths, pair=None):
                        out.astype(jnp.float32).reshape(heads))
     delta = delta.reshape(B, H, nq, bq) - dlse.astype(jnp.float32)
 
-    for kernel in ("dq", "dkv"):
+    # the one backward: a second pair, and room for the kv head's dq
+    one = pair and _one_backward(g, bq, bk, D, nq, Tk, item, Dv, D2)
+    for kernel in ("dqkv",) if one else ("dq", "dkv"):
         _count(kernel, path, "rows" if rows else "heads")
         _count_tiles(kernel, classes, sub)
     blocks, scratch, _ = _dq_step_bytes(hb, bq, bk, D, nq, Tk, item, Dv, D2)
@@ -1339,7 +1461,7 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths, pair=None):
                        pltpu.VMEM((hb, bq, _LANES), jnp.float32),
                        pltpu.VMEM((4, bq, _LANES), jnp.int32)]
                       + ([pltpu.VMEM((hb, bq, D2), jnp.float32)] if pair else []))
-    dq = pl.pallas_call(
+    dq = None if one else pl.pallas_call(
         _pair_refs(_tables_first(
             _mdq_kernel, n, scale=scale, bk=bk, nq=nq, nk=nk,
             per_batch=per_batch, sub=sub and sub.grid),
@@ -1383,35 +1505,55 @@ def _masked_bwd(q, k, v, out, lse, do, mask, scale, dlse, widths, pair=None):
                        pltpu.VMEM((bk, Dv), jnp.float32)]
                       + ([pltpu.VMEM((2, g, 1, bq), jnp.float32)] if sub else [])
                       + ([pltpu.VMEM((bk, D2), jnp.float32)] if pair else []))
-    dk, dv, *dk2 = pl.pallas_call(
-        _pair_refs(functools.partial(
-            _mdkv_kernel, scale=scale, P=P, g=g, per_batch=per_batch,
-            sub=sub and sub.grid),
-            # q2 and k2 the last inputs; this kv head's part of dk2 after dk
-            # and dv; its accumulator the last scratch
-            pair, (1 + len(in_specs), 2), (len(out_specs), 1),
-            (len(scratch_shapes), 1)),
+    out_shape = [
+        _sds(_heads_shape(rows, B, Hkv, Tk, D), k.dtype, q, k, v, do),
+        _sds(_heads_shape(rows, B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
+    ] + ([_sds(_heads_shape(rows, B, Hkv, Tk, D2), jnp.float32,
+               q, k, v, do)] if pair else [])
+    kernel = _pair_refs(functools.partial(
+        _mdkv_kernel, scale=scale, P=P, g=g, per_batch=per_batch,
+        sub=sub and sub.grid),
+        # q2 and k2 the last inputs; this kv head's part of dk2 after dk
+        # and dv; its accumulator the last scratch
+        pair, (1 + len(in_specs), 2), (len(out_specs), 1),
+        (len(scratch_shapes), 1))
+    if one:
+        # dq and dq2 the last results, the group's whole rows at an index
+        # that stands still along P; their accumulators the last scratch
+        rows_blk = lambda d: _heads_block(rows, g, T, d,
+                                          lambda b, c, p, t: (b, c, 0))
+        kernel = _pair_refs(
+            kernel, True, (1 + len(in_specs), 0), (len(out_specs) + 2, 2),
+            (len(scratch_shapes) + 2, 2), name="dq")
+        out_specs += [rows_blk(D), rows_blk(D2)]
+        out_shape += [dq_shape, _sds(pair[0].shape, q.dtype, q, k, v, do)]
+        scratch_shapes += [pltpu.VMEM((g, T, D), jnp.float32),
+                           pltpu.VMEM((g, T, D2), jnp.float32)]
+        # what the step holds and 4 MiB for Mosaic's own, not the 24 of
+        # ``_vmem``: XLA keeps a call's limit clear of its own fast-memory
+        # buffers (PERF.md, question 27)
+        limit = _pallas.params(vmem=one + 4 * 1024 * 1024)
+    else:
+        limit = _vmem(g * bq * (D + Dv + D2) * item,
+                      2 * bk * (D + Dv) * item + bk * D2 * (item + 4),
+                      2 * g * T * 4, 8 * bq * 4,
+                      scratch=bk * (D + Dv + D2) * 4)
+    dk, dv, *others = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hkv, P),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
-        out_shape=[
-            _sds(_heads_shape(rows, B, Hkv, Tk, D), k.dtype, q, k, v, do),
-            _sds(_heads_shape(rows, B, Hkv, Tk, Dv), v.dtype, q, k, v, do),
-        ] + ([_sds(_heads_shape(rows, B, Hkv, Tk, D2), jnp.float32,
-                   q, k, v, do)] if pair else []),
-        compiler_params=_vmem(g * bq * (D + Dv + D2) * item,
-                              2 * bk * (D + Dv) * item + bk * D2 * (item + 4),
-                              2 * g * T * 4, 8 * bq * 4,
-                              scratch=bk * (D + Dv + D2) * 4),
+        out_shape=out_shape,
+        compiler_params=limit,
         interpret=_pallas.INTERPRET,
-        name="hvd_flash_dkv",
+        name="hvd_flash_dqkv" if one else "hvd_flash_dkv",
     )(table, q, k, v, do, lse, delta, ranges.transpose(0, 2, 1),
       *(pair or ()))
     if not pair:
         return dq, dk, dv
-    dq, dq2 = dq
-    dk2 = dk2[0].reshape(B, Tk, -1, g2 // g, D2).sum(3).astype(k.dtype)
+    dk2, dq, dq2 = others if one else others + list(dq)
+    dk2 = dk2.reshape(B, Tk, -1, g2 // g, D2).sum(3).astype(k.dtype)
     return dq, dk, dv, dq2, dk2.reshape(pair[1].shape)
 
 

@@ -16,7 +16,7 @@ from _helpers import (block_diffusion_ranges as _block_diffusion_ranges,
                       pallas_calls as _pallas_calls)
 from horovod_tpu.ops import flash_attention as fa
 from test_flash_masked import (SUB_MASKS, WHOLE, _case_id, _dense_masked,
-                               _dense_masked_lse, _tiles)
+                               _dense_masked_lse, _packed_documents, _tiles)
 
 
 # How many query heads a ``dq`` grid step takes, at every branch of
@@ -204,35 +204,26 @@ def test_backward_heads_a_step_rule():
                         assert hb <= fa._fwd_heads(g, *shapes)
 
 
-@pytest.mark.parametrize("D,Dv", [(128, 128), (64, 128)],
-                         ids=["rows", "heads-values-128"])
-def test_masked_backward_specs(D, Dv, pallas_interpret):
-    """The backward's two calls.  ``dq``: the forward's grid, ``hb`` heads
-    of a group a step on their kv head's whole keys and values, with
-    ``do``, their rows of ``lse`` and of ``delta`` and the tile's ranges;
-    in scratch the float32 accumulator ``[hb, bq, D]``, ``lse`` and
-    ``delta`` as columns over the lanes and the ranges over the lanes.
-    ``dkv``: a step a live pair of tiles, the group's query tiles on one
-    key tile, the ranges a row a bound ``[1, 4, bq]``, two float32
-    accumulators and, the mask cutting tiles that are walked by sub-tiles,
-    the query tile's rows of ``lse`` and ``delta`` ``[2, g, 1, bq]``.  On
-    the caller's layout at ``head_dim`` 128, transposed
-    around the kernels at 64 (values 128 wide: the Phi call)."""
-    B, T, H, Hkv = 2, 2048, 16, 4
-    bq, nq, g = 512, 4, 4
-    hb = fa._dq_heads(g, bq, bq, D, nq, T, 2, Dv)
-    assert hb == 4
+def _assert_two_backward_calls(B, T, H, Hkv, D, Dv, ranges, hb):
+    """An unpaired call's backward traces to ``hvd_flash_dq`` and
+    ``hvd_flash_dkv`` with the specs :func:`test_masked_backward_specs`
+    says; ``hb``: the heads a ``dq`` step takes."""
+    bq = 512
+    nq, g = T // bq, H // Hkv
+    assert fa._dq_heads(g, bq, bq, D, nq, T, 2, Dv) == hb
     q, k, v = (jax.ShapeDtypeStruct((B, T, h, d), jnp.bfloat16)
                for h, d in ((H, D), (Hkv, D), (Hkv, Dv)))
-    ranges = _block_diffusion_ranges(T // 2, 4)
     classes = fa.tile_classes(ranges[None], bq, bq, T)
     P = fa._pair_table(classes)[1]
     assert P == int((classes >= 1).sum())
-    calls = {name: rest for name, *rest in _pallas_calls(
+    found = _pallas_calls(
         lambda q, k, v: jax.grad(
             lambda q, k, v: fa.flash_attention(
                 q, k, v, mask=ranges).astype(jnp.float32).sum(),
-            (0, 1, 2))(q, k, v), q, k, v, scratch=True)}
+            (0, 1, 2))(q, k, v), q, k, v, scratch=True)
+    assert [name for name, *_ in found] == [
+        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+    calls = {name: rest for name, *rest in found}
     if D % 128 == 0:
         blk = lambda heads, n, d: (1, n, heads * d)
     else:
@@ -250,6 +241,24 @@ def test_masked_backward_specs(D, Dv, pallas_interpret):
         [f32(bq, D), f32(bq, Dv), f32(2, g, 1, bq)]]
 
 
+@pytest.mark.parametrize("D,Dv", [(128, 128), (64, 128)],
+                         ids=["rows", "heads-values-128"])
+def test_masked_backward_specs(D, Dv, pallas_interpret):
+    """The backward's two calls.  ``dq``: the forward's grid, ``hb`` heads
+    of a group a step on their kv head's whole keys and values, with
+    ``do``, their rows of ``lse`` and of ``delta`` and the tile's ranges;
+    in scratch the float32 accumulator ``[hb, bq, D]``, ``lse`` and
+    ``delta`` as columns over the lanes and the ranges over the lanes.
+    ``dkv``: a step a live pair of tiles, the group's query tiles on one
+    key tile, the ranges a row a bound ``[1, 4, bq]``, two float32
+    accumulators and, the mask cutting tiles that are walked by sub-tiles,
+    the query tile's rows of ``lse`` and ``delta`` ``[2, g, 1, bq]``.  On
+    the caller's layout at ``head_dim`` 128, transposed
+    around the kernels at 64 (values 128 wide: the Phi call)."""
+    _assert_two_backward_calls(2, 2048, 16, 4, D, Dv,
+                               _block_diffusion_ranges(1024, 4), hb=4)
+
+
 # ----------------- a second query/key pair (latent attention's rotary
 # part: tests/test_flash_masked.py has the forward and the chip's compile)
 
@@ -259,12 +268,13 @@ def test_masked_backward_specs(D, Dv, pallas_interpret):
          "two-key-heads-by-sub-tiles"])
 def test_split_and_joined_forms_agree_backward(H, Hkv, H2, tiles, monkeypatch,
                                                pallas_interpret):
-    """All five gradients of the split form (``pair=``: ``hvd_flash_dq``
-    writes ``dq2`` beside ``dq``, ``hvd_flash_dkv`` each kv head's part of
-    ``dk2``, added over the heads that share the key) against autodiff
-    through dense attention over the joined query and key; a mixed tile
-    whole and by its sub-tiles; and ``dk2`` of the one shared key is the
-    sum over heads of what a key a head would get."""
+    """All five gradients of the split form (``pair=``: the one backward,
+    ``hvd_flash_dqkv``, writes ``dq`` and ``dq2`` of a kv head's group
+    beside ``dk``, ``dv`` and the kv head's part of ``dk2``, added over the
+    heads that share the key) against autodiff through dense attention
+    over the joined query and key; a mixed tile whole and by its
+    sub-tiles; and ``dk2`` of the one shared key is the sum over heads of
+    what a key a head would get."""
     from test_flash_masked import (_grew, _joined_dense, _kernel_counts,
                                    _pair_operands)
     blk, T = _tiles(monkeypatch, tiles)
@@ -284,7 +294,7 @@ def test_split_and_joined_forms_agree_backward(H, Hkv, H2, tiles, monkeypatch,
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4,
                                    err_msg=f"d{name}")
     assert _grew(before) == {(kernel, "paired", "rows")
-                             for kernel in ("fwd", "dq", "dkv")}
+                             for kernel in ("fwd", "dqkv")}
     # a key a kv head in place of the shared one: its gradient's sum over
     # the heads that shared it is the shared key's
     if tiles != WHOLE:      # once is enough: the sum is the caller's
@@ -293,3 +303,118 @@ def test_split_and_joined_forms_agree_backward(H, Hkv, H2, tiles, monkeypatch,
     each = jax.grad(split, 4)(q, k, v, q2, jnp.repeat(k2, g2, axis=2))
     np.testing.assert_allclose(
         each.reshape(1, T, H2, g2, -1).sum(3), got[4], atol=2e-4, rtol=2e-4)
+
+
+# ----------------- the one backward of a call with a second pair
+# (``hvd_flash_dqkv``: ``hvd_flash_dkv``'s grid and orientation, ``dq`` and
+# ``dq2`` of a kv head's group held in VMEM across its key tiles)
+
+def _rows_masks(T):
+    """``[2, T, 4]``, a mask a batch row: causal inside documents cut off
+    every tile's edge in one, a window of 200 keys in the other, so the
+    rows' tables of live pairs differ in length and one is padded."""
+    return np.stack([_packed_documents(T)[0], fa.window_ranges(T, 200)])
+
+
+ONE_BACKWARD_CASES = {
+    # (H, Hkv, H2), (block, sub-tile), a mask a batch row
+    "mla-whole-tiles-one-mask": ((2, 2, 1), WHOLE, False),
+    "mla-sub-tiles-a-mask-a-row": ((2, 2, 1), (256, 128), True),
+    "gqa-under-one-key-whole-tiles-a-mask-a-row": ((4, 2, 1), WHOLE, True),
+    "gqa-under-one-key-sub-tiles-one-mask": ((4, 2, 1), (256, 128), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_BACKWARD_CASES))
+def test_one_backward_equals_the_two_kernels(case, monkeypatch,
+                                             pallas_interpret):
+    """``hvd_flash_dqkv`` against ``hvd_flash_dq`` + ``hvd_flash_dkv`` built
+    by the same builder for the same residuals (the step's budget set to
+    nothing: the rule then keeps two kernels), on three key tiles or more,
+    so that ``dq`` is carried in VMEM across key tiles: ``dk``, ``dv`` and
+    ``dk2`` are the same bits (the same products in the same order), ``dq``
+    and ``dq2`` equal up to float32's order of summation (a key tile at a
+    time over the grid's steps there, over one step's loop here); a mixed
+    tile whole and by its sub-tiles, one mask known where the call is built
+    and one a batch row, traced, whose shorter table of pairs is padded."""
+    from test_flash_masked import _grew, _kernel_counts, _pair_operands
+    (H, Hkv, H2), tiles, per_row = ONE_BACKWARD_CASES[case]
+    blk, _ = _tiles(monkeypatch, tiles)
+    T, B = 3 * blk if blk > 128 else 512, 2 if per_row else 1
+    assert T // blk >= 3
+    q, k, v, q2, k2 = _pair_operands(B, T, H, Hkv, H2, seed=5)
+    flat = lambda x, pad=0: jnp.pad(
+        x, ((0, 0),) * 3 + ((0, pad),)).reshape(*x.shape[:2], -1)
+    operands = flat(q), flat(k), flat(v)
+    pair = flat(q2, 64), flat(k2, 64)
+    mask = jnp.asarray(_rows_masks(T)) if per_row else fa.causal_ranges(T)
+    scale = 192 ** -0.5
+    out, lse = fa._masked_fwd(*operands, mask, scale, (128, 128), pair)
+    do = jax.random.normal(jax.random.key(6), out.shape)
+    dlse = jax.random.normal(jax.random.key(7), lse.shape)
+    backward = lambda: fa._masked_bwd(*operands, out, lse, do, mask, scale,
+                                      dlse, (128, 128), pair)
+    before = _kernel_counts()
+    one = backward()
+    assert _grew(before) == {("dqkv", "paired", "rows")}
+    monkeypatch.setattr(fa, "_DQKV_STEP_VMEM", 0)
+    before = _kernel_counts()
+    two = backward()
+    assert _grew(before) == {("dq", "paired", "rows"),
+                             ("dkv", "paired", "rows")}
+    for a, b, name in zip(one, two, ("dq", "dk", "dv", "dq2", "dk2")):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.isfinite(np.asarray(a)).all(), name
+        if name in ("dq", "dq2"):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5,
+                                       err_msg=name)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _paired_backward_kernels(T, H, Hkv):
+    """The kernels' names of the backward of a causal call with a second
+    pair, 128 + 64 wide on values of 128, one shared key (shapes only)."""
+    sds = lambda h, d: jax.ShapeDtypeStruct((1, T, h, d), jnp.bfloat16)
+    ranges = fa.causal_ranges(T)
+    return [name for name, *_ in _pallas_calls(
+        lambda *a: jax.grad(lambda *a: fa.flash_attention(
+            *a[:3], mask=ranges, pair=a[3:]).astype(jnp.float32).sum(),
+            (0, 1, 2, 3, 4))(*a),
+        sds(H, 128), sds(Hkv, 128), sds(Hkv, 128), sds(H, 64), sds(1, 64))]
+
+
+def test_which_calls_take_the_one_backward():
+    """The rule, from the step's bytes: at the kanana cell's call (one row
+    of 16,384, 32 heads each with its keys and values, 128 + the pair's 64
+    padded to 128, bf16) the kv head's ``dq`` and ``dq2`` are 16.8 MB in
+    float32, the step 41 MB, and the one kernel is built; twice the rows,
+    or a group of eight under one rotary key, keep two kernels."""
+    cell = (512, 512, 128, 32, 16384, 2, 128, 128)
+    blocks, scratch, tiles = fa._dqkv_step_bytes(1, *cell)
+    assert 16384 * 256 * 4 < scratch < 16384 * 256 * 4 + (1 << 20)
+    assert (fa._MASKED_STEP_VMEM < 2 * blocks + scratch + tiles
+            == fa._one_backward(1, *cell) == 40_960_000
+            < fa._DQKV_STEP_VMEM)
+    assert fa._one_backward(1, 512, 512, 128, 64, 32768, 2, 128, 128) is None
+    assert fa._one_backward(8, *cell) is None
+    assert _paired_backward_kernels(16384, 32, 32) == [
+        "hvd_flash_fwd", "hvd_flash_dqkv"]
+    assert _paired_backward_kernels(32768, 32, 32) == [
+        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+    assert _paired_backward_kernels(16384, 32, 4) == [
+        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+
+
+@pytest.mark.parametrize("B,T,H,Hkv,D,Dv,mask,hb", [
+    (2, 8192, 32, 4, 128, 128, lambda: _block_diffusion_ranges(4096, 4), 4),
+    (1, 8192, 20, 10, 64, 128, lambda: fa.window_ranges(8192, 512), 2),
+    (1, 16384, 32, 4, 128, 128, lambda: fa.window_ranges(16384, 1024), 2),
+], ids=["sdar", "phi-window", "mellum-window"])
+def test_calls_without_a_pair_keep_two_kernels(B, T, H, Hkv, D, Dv, mask, hb):
+    """The accepted cells' calls, which give no second pair (a kv head's
+    ``dq`` there is 16.8 MB at SDAR's eight heads of 4,096 x 2 rows, 67 MB
+    at Mellum's): ``hvd_flash_dq`` and ``hvd_flash_dkv`` with the specs
+    :func:`test_masked_backward_specs` holds, whatever the one backward's
+    budget would say of their bytes."""
+    _assert_two_backward_calls(B, T, H, Hkv, D, Dv, mask(), hb)

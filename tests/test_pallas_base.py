@@ -15,15 +15,20 @@ sds = jax.ShapeDtypeStruct
 PA, AR = "parallel", "arbitrary"
 
 
-def _flash(B, T, H, Hkv, D, Dv=None, causal=True, mask=None):
+def _flash(B, T, H, Hkv, D, Dv=None, causal=True, mask=None, pair=None):
+    """``pair = (H2, D2)``: a second query/key pair ``D2`` wide, ``H2`` key
+    heads of it."""
     from horovod_tpu.ops import flash_attention as fa
     q, k, v = (sds((B, T, h, d), BF) for h, d in (
         (H, D), (Hkv, D), (Hkv, Dv or D)))
+    second = (sds((B, T, H, pair[1]), BF),
+              sds((B, T, pair[0], pair[1]), BF)) if pair else ()
     if callable(mask):
         mask = mask(fa)
-    return jax.grad(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=causal, mask=mask).astype(F32).sum(),
-        (0, 1, 2)), (q, k, v)
+    return jax.grad(lambda q, k, v, *second: fa.flash_attention(
+        q, k, v, causal=causal, mask=mask,
+        pair=second or None).astype(F32).sum(),
+        tuple(range(3 + len(second)))), (q, k, v, *second)
 
 
 def _experts(call, R, D, F, E, tokens):
@@ -113,6 +118,11 @@ CALLS = {
         1, 8192, 20, 10, 64, 128, mask=lambda fa: fa.window_ranges(8192, 512)),
     "flash-masked-phi-causal": lambda: _flash(
         1, 8192, 20, 10, 64, 128, mask=lambda fa: fa.causal_ranges(8192)),
+    # the kanana cell's latent attention: a key and value head a query head
+    # and one rotary key for all 32
+    "flash-paired-kanana": lambda: _flash(
+        1, 16384, 32, 32, 128, mask=lambda fa: fa.causal_ranges(16384),
+        pair=(1, 64)),
     "experts-sdar-forward": lambda: _experts(
         "forward", 24576, 2048, 768, 16, 16384),
     "experts-sdar-backward": lambda: _experts(
@@ -172,6 +182,13 @@ HANDED = {
         ("hvd_flash_fwd", None, 35520512),
         ("hvd_flash_dq", None, 35651584),
         ("hvd_flash_dkv", None, 27426816),
+    ],
+    # the one backward: its step's blocks twice, its scratch (16.8 MB of
+    # them dq and dq2 of a kv head's 16,384 rows in float32), its tiles and
+    # 4 MiB, ``flash_attention._dqkv_step_bytes``: 45,154,304, not 64 MiB
+    "flash-paired-kanana": [
+        ("hvd_flash_fwd", None, 53608448),
+        ("hvd_flash_dqkv", None, 40_960_000 + 4 * MiB),
     ],
     "experts-sdar-forward": [
         ("hvd_moe_gmm_gate_up", (AR,), 38535168),
