@@ -117,6 +117,20 @@ class Counter(_Metric):
         with self._lock:
             self._child(labels)[0] += amount
 
+    def inc_unless_held(self, amount: float = 1.0, **labels) -> bool:
+        """:meth:`inc` for a caller that may be running inside the very
+        thread that holds this family's lock (a ``gc.callbacks`` function:
+        a collection starts between any two bytecodes, also those of a
+        scrape's ``series()``).  Takes the lock only if it is free and
+        says whether it counted."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._child(labels)[0] += amount
+        finally:
+            self._lock.release()
+        return True
+
     def value(self, **labels) -> float:
         with self._lock:
             child = self._children.get(

@@ -30,6 +30,7 @@ from ..optim import overlap as _overlap
 from ..parallel.ring_attention import local_attention, ring_attention
 from ..scopes import (SCOPE_ATTENTION, SCOPE_EMBED, SCOPE_FORWARD, SCOPE_HEAD,
                       SCOPE_MLP, SCOPE_OPTIMIZER, SCOPE_REDUCE)
+from ..tracing import TracedStep
 from .llama import ParallelSpec, remat_policy
 
 
@@ -320,7 +321,7 @@ def make_dp_finetune_step(cfg: BertConfig, mesh, axis: str, optimizer,
             out_specs=(P(), P(), P()), check_vma=True)(
                 params, opt_state, tokens, labels)
 
-    return step
+    return TracedStep(step, 2)
 
 
 def count_params(cfg: BertConfig) -> int:

@@ -623,6 +623,7 @@ def shutdown():
             if _metrics.RECORDING:
                 _metrics.event("runtime.shutdown")
             _metrics.stop_exposition()
+            _tracing.shutdown()
             if _STATE.engine is not None:
                 _STATE.engine.stop()
             if _STATE.timeline is not None:

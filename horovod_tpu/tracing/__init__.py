@@ -24,6 +24,12 @@ phase gated each round*:
   per-host gating-fraction table that cross-checks the stall
   inspector's straggler EWMA with evidence.
 
+A jitted job never enters the engine; what it has is start-up's spans
+in the same ring and, in a second ring of their own (:func:`steps`), one
+``step`` span a call of a builder's step with the steps still in flight
+and a ``gc`` span a pause of the collector (:mod:`.step`).  A scrape
+carries both rings.
+
 Hot-path discipline (hvdmetrics/hvdchaos precedent): every
 instrumented site guards on ``tracing.ACTIVE`` — one attribute load
 and a false branch under ``HOROVOD_TRACE=0``.  Env table: docs/env.md;
@@ -33,6 +39,7 @@ span schema and offset method: docs/observability.md.
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import threading
 from typing import Optional
@@ -70,6 +77,7 @@ def probes(environ=os.environ) -> int:
 ACTIVE = _env_on(ENV_ENABLE)
 
 _BUFFER = SpanBuffer(capacity=_env_capacity())
+_STEPS = SpanBuffer(capacity=_env_capacity())
 
 
 def buffer() -> SpanBuffer:
@@ -78,12 +86,29 @@ def buffer() -> SpanBuffer:
     return _BUFFER
 
 
+def steps() -> SpanBuffer:
+    """The ring of the step loop (``step`` and ``gc`` spans: ``step.py``),
+    of the same class and capacity as :func:`buffer` and apart from it:
+    a job dispatches millions of steps, and they must never push a
+    start-up span out of the default ring.  The collector's closed spans
+    enter it here: ``GcWatch`` cannot take the ring's lock itself."""
+    _GC_WATCH.hand_on(_STEPS)
+    return _STEPS
+
+
 def swap_buffer(buf: SpanBuffer) -> SpanBuffer:
     """Replace the default buffer, returning the old one (tests only:
     isolates a scenario's spans; the engine reads the module default
     per call, so the swap takes effect immediately)."""
     global _BUFFER
     old, _BUFFER = _BUFFER, buf
+    return old
+
+
+def swap_steps(buf: SpanBuffer) -> SpanBuffer:
+    """:func:`swap_buffer` for the step loop's ring (tests only)."""
+    global _STEPS
+    old, _STEPS = _STEPS, buf
     return old
 
 
@@ -144,18 +169,33 @@ def set_context(round: Optional[int] = None, cycle: Optional[int] = None,
 
 def set_identity(process: Optional[int] = None, host: Optional[str] = None,
                  epoch: Optional[int] = None):
-    _BUFFER.set_identity(process=process, host=host, epoch=epoch)
+    for ring in (_BUFFER, _STEPS):
+        ring.set_identity(process=process, host=host, epoch=epoch)
+
+
+def _snapshot() -> dict:
+    """Both rings as one scrape payload: the default buffer's identity
+    and clock sample, the step loop's spans after its own (``seq`` counts
+    within a ring), ``dropped`` summed."""
+    snap = _BUFFER.snapshot()
+    loop = steps().snapshot()
+    snap["spans"] += loop["spans"]
+    snap["dropped"] += loop["dropped"]
+    return snap
 
 
 def pull_handler(payload):
-    """``JsonRpcServer`` POST handler over the CURRENT default buffer
-    (resolved per call so ``swap_buffer`` takes effect)."""
-    return _BUFFER.pull_handler()(payload)
+    """``JsonRpcServer`` POST handler over the CURRENT rings (resolved
+    per call so ``swap_buffer`` takes effect): a probe is the default
+    buffer's, the scrape carries both rings' spans."""
+    if isinstance(payload, dict) and payload.get("probe"):
+        return _BUFFER.pull_handler()(payload)
+    return _snapshot()
 
 
 def local_trace() -> dict:
-    """This process's buffer as a Chrome trace (``GET /trace``)."""
-    return merge.local_trace(_BUFFER)
+    """This process's two rings as a Chrome trace (``GET /trace``)."""
+    return merge.local_trace(_snapshot())
 
 
 def enable():
@@ -173,7 +213,22 @@ def init_from_env(environ=os.environ):
     idempotent across elastic re-inits): refresh the ACTIVE flag and
     resize the default buffer if the capacity changed (newest spans are
     kept — a re-init mid-job must not drop the history a post-mortem
-    scrape wants)."""
+    scrape wants), and watch the collector's pauses (``step.GcWatch``)."""
     global ACTIVE
     ACTIVE = _env_on(ENV_ENABLE, environ=environ)
-    _BUFFER.set_capacity(_env_capacity(environ))
+    for ring in (_BUFFER, _STEPS):
+        ring.set_capacity(_env_capacity(environ))
+    if _GC_WATCH not in gc.callbacks:
+        gc.callbacks.append(_GC_WATCH)
+
+
+def shutdown():
+    """Take out of the interpreter what :func:`init_from_env` put there
+    (``hvd.shutdown()`` calls it; the rings and their spans stay)."""
+    if _GC_WATCH in gc.callbacks:
+        gc.callbacks.remove(_GC_WATCH)
+
+
+from .step import GcWatch, TracedStep  # noqa: E402,F401  (reads this module)
+
+_GC_WATCH = GcWatch()

@@ -25,7 +25,6 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Tuple
 
-from .span import SpanBuffer
 
 #: Sentinel: resolve the RPC signing secret from the environment (the
 #: launcher/driver default); pass ``secret=None`` explicitly for
@@ -139,10 +138,10 @@ def chrome_trace(workers: Dict[str, Tuple[Dict, float, float]],
             "otherData": other}
 
 
-def local_trace(buffer: SpanBuffer) -> Dict:
+def local_trace(snap: Dict) -> Dict:
     """The single-process view (``GET /trace`` on any server): this
-    buffer rendered as a Chrome trace with zero offset/error."""
-    snap = buffer.snapshot()
+    process's scrape payload rendered as a Chrome trace with zero
+    offset/error."""
     return chrome_trace({str(snap.get("process", 0)): (snap, 0.0, 0.0)})
 
 

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .models import llama as llama_mod
 from .models.llama import LlamaConfig, ParallelSpec
 from .parallel.mesh import ParallelMesh
+from .tracing import TracedStep
 # the names the steps put on their parts, handed on under this module's name
 from .scopes import (  # noqa: F401
     SCOPE_FORWARD, SCOPE_REDUCE, SCOPE_OPTIMIZER, SCOPE_SYNC_BN, SCOPE_EMBED,
@@ -329,8 +330,8 @@ def make_llama_train_step(cfg: LlamaConfig, pmesh: ParallelMesh,
                 ov_tx.init, out_shardings=ov_sharding)(params)
             return params, opt_state
 
-        return TrainStep(step_fn=step_fn, init_fn=ov_init_fn, par=par,
-                         mesh=mesh, data_spec=data_spec,
+        return TrainStep(step_fn=TracedStep(step_fn, 2), init_fn=ov_init_fn,
+                         par=par, mesh=mesh, data_spec=data_spec,
                          param_sharding=param_sharding)
 
     if objective is not None:
@@ -417,8 +418,9 @@ def make_llama_train_step(cfg: LlamaConfig, pmesh: ParallelMesh,
             opt.init, out_shardings=opt_sharding)(params)
         return params, opt_state
 
-    return TrainStep(step_fn=step_fn, init_fn=init_fn, par=par, mesh=mesh,
-                     data_spec=data_spec, param_sharding=param_sharding)
+    return TrainStep(step_fn=TracedStep(step_fn, 2), init_fn=init_fn,
+                     par=par, mesh=mesh, data_spec=data_spec,
+                     param_sharding=param_sharding)
 
 
 def fsdp_param_specs(param_shapes, dp: int, axis: str = "dp"):
@@ -584,8 +586,9 @@ def make_llama_fsdp_step(cfg: LlamaConfig, pmesh: ParallelMesh,
         opt_state = jax.jit(opt.init, out_shardings=opt_sharding)(params)
         return params, opt_state
 
-    return TrainStep(step_fn=step_fn, init_fn=init_fn, par=par, mesh=mesh,
-                     data_spec=data_spec, param_sharding=param_sharding)
+    return TrainStep(step_fn=TracedStep(step_fn, 2), init_fn=init_fn,
+                     par=par, mesh=mesh, data_spec=data_spec,
+                     param_sharding=param_sharding)
 
 
 def _make_llama_fsdp_overlap_step(cfg: LlamaConfig, pmesh: ParallelMesh,
@@ -663,8 +666,9 @@ def _make_llama_fsdp_overlap_step(cfg: LlamaConfig, pmesh: ParallelMesh,
             out_shardings=state_sharding)(params)
         return params, opt_state
 
-    return TrainStep(step_fn=step_fn, init_fn=init_fn, par=par, mesh=mesh,
-                     data_spec=data_spec, param_sharding=param_sharding)
+    return TrainStep(step_fn=TracedStep(step_fn, 2), init_fn=init_fn,
+                     par=par, mesh=mesh, data_spec=data_spec,
+                     param_sharding=param_sharding)
 
 
 def make_data_sharding(ts: TrainStep):
@@ -757,6 +761,6 @@ def make_classifier_train_step(forward_fn, model_init_fn, pmesh: ParallelMesh,
         opt_state = jax.jit(opt.init, out_shardings=replicated)(params)
         return params, state, opt_state
 
-    return ClassifierTrainStep(step_fn=step_fn, init_fn=init_fn,
-                               eval_fn=eval_fn, mesh=mesh,
+    return ClassifierTrainStep(step_fn=TracedStep(step_fn, 3),
+                               init_fn=init_fn, eval_fn=eval_fn, mesh=mesh,
                                data_spec=data_spec)
