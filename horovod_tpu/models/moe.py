@@ -24,18 +24,21 @@ Two dispatch paths, ``cfg.moe_dispatch``:
   computes their part of the result.  The (token, expert) pairs whose
   expert is held are sorted by expert and taken in chunks of a fixed
   number of rows, as many chunks as the routing needs
-  (``lax.while_loop``), each through three grouped matrix products and
-  a combine of the weighted rows into their tokens.  Products and
-  combine are the Pallas kernels of :mod:`horovod_tpu.ops.grouped_matmul`
-  where the backend, the widths and the dtype allow (``hvd_moe_gmm_*``
+  (``lax.while_loop``), each a gather of its rows out of their tokens,
+  three grouped matrix products and a combine of the weighted rows into
+  their tokens.  Gather, products and combine are the Pallas kernels of
+  :mod:`horovod_tpu.ops.grouped_matmul` where the backend, the widths and
+  the dtype allow (``hvd_moe_dispatch_tokens`` / ``_dout`` write the
+  rows the pairs fill, in the products' dtype; ``hvd_moe_gmm_*``
   forward and for the rows' gradients, ``hvd_moe_tgmm_*`` for the
   weights'; the activation, the pairs' weights and the sum of the two
   products behind a row's gradient are applied to the fp32 accumulators,
   and a tile of rows past the pairs costs no product;
   ``hvd_moe_combine_out`` / ``_dtok`` add each float32 row to its token,
-  a tile of tokens in VMEM at a time), ``lax.ragged_dot`` with autodiff's
-  backward and ``.at[].add`` elsewhere (CPU, toy widths): one algorithm,
-  no knob; ``hvd_moe_gmm_kernel_total`` says which a program took.  No
+  a tile of tokens in VMEM at a time), ``table[tok]``, ``lax.ragged_dot``
+  with autodiff's backward and ``.at[].add`` elsewhere (CPU, toy widths):
+  one algorithm, no knob; ``hvd_moe_gmm_kernel_total`` says which a
+  program took.  No
   capacity, no dropped pair, and device work in proportion to the pairs
   routed here.  What absent experts would add is left out.
 
@@ -240,7 +243,8 @@ def _pairs(sizes, like):
 
 def _expert_ffn_grads(xs, wg, wu, wd, wt, sizes, dys, dwg, dwu, dwd):
     """The backward of :func:`_expert_ffn` for the cotangent ``dys [R,
-    D]`` (float32): ``(dxs, dwt, dwg, dwu, dwd)``, ``dxs`` float32 and it
+    D]`` (float32, or the rows' dtype already where the kernels run):
+    ``(dxs, dwt, dwg, dwu, dwd)``, ``dxs`` float32 and it
     and ``dwt`` zero on the rows past the sizes' sum, the three weights'
     gradients added to the float32 ``dwg``, ``dwu``, ``dwd`` given.  With
     the kernels gate and up are made again and the down product is not:
@@ -283,6 +287,25 @@ def _expert_ffn_grads(xs, wg, wu, wd, wt, sizes, dys, dwg, dwu, dwd):
             _gmm.tgmm(hw, dys, sizes, dwd, "down"))
 
 
+def _kernels(rows, width, ws) -> bool:
+    """Whether a chunk of ``rows`` rows of ``width`` is the kernels': the
+    grouped products' and, before them, the dispatch's."""
+    return _gmm.supported(
+        jax.ShapeDtypeStruct((rows, width), ws[0].dtype), *ws)
+
+
+def _chunk_of(table, tok, sizes, ws, name):
+    """The chunk's rows, ``table[tok]``: where the grouped products are
+    the kernels', by the dispatch kernel (``hvd_moe_dispatch_<name>``), in
+    the weights' dtype and only the rows the sizes cover (the products
+    and the combine read no other into a result); elsewhere XLA's gather
+    of every row, in the table's dtype."""
+    if _kernels(tok.shape[0], table.shape[1], ws):
+        return _gmm.dispatch(table, tok, sizes, ws[0].dtype, name)
+    _gmm.count_xla(gather=1)
+    return table[tok]
+
+
 def _chunks(order, pair_w, sizes, k, rows):
     """``(n, chunk)``: how many chunks of ``rows`` sorted pairs the
     held pairs fill, and ``chunk(c)`` = (token of each row, its weight,
@@ -315,7 +338,8 @@ def _held_experts(tokens, wg, wu, wd, pair_w, order, sizes, k, rows):
     def body(c, carry):
         out, computed = carry
         tok, wt, here, _ = chunk(c)
-        ys = _expert_ffn(tokens[tok], wg, wu, wd, wt, here)
+        xs = _chunk_of(tokens, tok, here, (wg, wu, wd), "tokens")
+        ys = _expert_ffn(xs, wg, wu, wd, wt, here)
         return (_gmm.combine(ys, tok, here, out, "out", fresh=c == 0),
                 computed + here.sum())
 
@@ -334,6 +358,11 @@ def _held_experts_bwd(k, rows, res, cotangents):
     rows x width is kept from the forward pass."""
     dout = cotangents[0]
     tokens, wg, wu, wd, pair_w, order, sizes = res
+    ws = (wg, wu, wd)
+    if _kernels(rows, tokens.shape[1], ws):
+        # rounded to the rows' dtype once a token, not once a pair behind
+        # the gather (XLA turns ``dout[tok].astype`` around the same way)
+        dout = dout.astype(wg.dtype)
     n, chunk = _chunks(order, pair_w, sizes, k, rows)
     f32 = lambda a: (a * 0).astype(jnp.float32)
 
@@ -341,8 +370,8 @@ def _held_experts_bwd(k, rows, res, cotangents):
         dtok, dwg, dwu, dwd, dwt_sorted = carry
         tok, wt, here, lo = chunk(c)
         dxs, dwt, dwg, dwu, dwd = _expert_ffn_grads(
-            tokens[tok], wg, wu, wd, wt, here,
-            dout[tok].astype(jnp.float32), dwg, dwu, dwd)
+            _chunk_of(tokens, tok, here, ws, "tokens"), wg, wu, wd, wt, here,
+            _chunk_of(dout, tok, here, ws, "dout"), dwg, dwu, dwd)
         return (_gmm.combine(dxs, tok, here, dtok, "dtok", fresh=c == 0),
                 dwg, dwu, dwd,
                 lax.dynamic_update_slice(dwt_sorted, dwt, (lo,)))

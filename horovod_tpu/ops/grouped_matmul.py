@@ -39,14 +39,29 @@ land in one tile of tokens are one run of consecutive rows: the kernel
 walks token tiles, a tile stays in VMEM while its runs come in from HBM,
 and is written once.
 
+And what precedes them, :func:`dispatch` (``hvd_moe_dispatch_<name>``):
+``rows[r] = table[tok[r]]``, a chunk's rows out of their tokens, in the
+products' dtype: the combine's walk with the copies turned around.  XLA's
+gather copies every row of a chunk, the third that no product reads with
+the rest, and from HBM at a third of the bytes' bound (0.818 ms a call of
+the SDAR cell's cotangents, my chip runs, PR 31).  The kernel walks the
+same tiles of tokens: a tile comes through VMEM once (as words of 32 bits,
+two columns each: a row of a packed dtype cannot be read alone), each row
+of a run is moved to its place in a block of 32 rows staged a group, and a
+block leaves for HBM when its last row is in; a block that holds rows of
+two groups or more is staged once for all of them and leaves last.  Rows
+past ``sizes.sum()`` are never written.  The name is no ``hvd_moe_gmm`` /
+``tgmm`` / ``combine``: the benchmark's rooflines sum kernels by those
+prefixes.
+
 ``hvd_moe_gmm_kernel_total{kernel, path}`` counts the calls built, once
 per traced call site: ``path=pallas`` here, ``path=xla`` where the
-caller fell back to ``lax.ragged_dot`` (:func:`count_xla`) or the combine
-to ``.at[].add``.
+caller fell back to ``lax.ragged_dot`` (:func:`count_xla`), the combine
+to ``.at[].add`` or the dispatch (``kernel=gather``) to ``table[tok]``.
 
-Falls back cleanly: :func:`supported` and :func:`combine` gate on
-backend, shapes and dtype (no knob); on a TPU backend each refused shape
-is logged once.
+Falls back cleanly: :func:`supported`, :func:`combine` and
+:func:`dispatch` gate on backend, shapes and dtype (no knob); on a TPU
+backend each refused shape is logged once.
 """
 
 from __future__ import annotations
@@ -79,22 +94,31 @@ _COMBINE_TILE = 512
 _COMBINE_CHUNK = 32
 _COMBINE_RING = 8
 _COMBINE_UNROLL = 4         # rows whose loads go before their stores
-# scalar memory the combine's token ids may take (one int32 a row)
+# scalar memory the token ids may take (one int32 a row), the combine's
+# and the dispatch's alike
 _COMBINE_SMEM = 256 * 1024
+# the dispatch: rows a copy writes (two bfloat16 tiles': a block's
+# bookkeeping and its pass from words to rows cost as much as six rows'
+# moves, and 32 rows a block ran 13% faster than 16 at the Mellum cell's
+# shapes, my chip runs, PR 52), copies in flight
+_DISPATCH_BLOCK = 32
+_DISPATCH_RING = 8
+_DISPATCH_UNROLL = 4
 
 _count = _pallas.kernel_counter(
     "hvd_moe_gmm_kernel_total",
     "Grouped matrix product and combine calls built, one per traced call "
     "site; kernel is gmm (rows x a group's weights), tgmm "
-    "(rows-transposed x rows a group) or combine (rows added to their "
-    "tokens), path is pallas (ops/grouped_matmul.py: a gmm call may hold "
-    "two products) or xla (lax.ragged_dot; the scatter-add)")
+    "(rows-transposed x rows a group), combine (rows added to their "
+    "tokens) or gather (a chunk's rows out of their tokens), path is "
+    "pallas (ops/grouped_matmul.py: a gmm call may hold two products) or "
+    "xla (lax.ragged_dot; the scatter-add; the gather)")
 
 
-def count_xla(gmm: int = 0, tgmm: int = 0) -> None:
+def count_xla(gmm: int = 0, tgmm: int = 0, gather: int = 0) -> None:
     """The caller built that many products as ``lax.ragged_dot`` (or had
-    autodiff build them)."""
-    for kernel, n in (("gmm", gmm), ("tgmm", tgmm)):
+    autodiff build them), that many gathers of a chunk's rows as XLA's."""
+    for kernel, n in (("gmm", gmm), ("tgmm", tgmm), ("gather", gather)):
         if n:
             _count(kernel, "xla", n=n)
 
@@ -381,18 +405,15 @@ def _combine_refusal(rows, out) -> Optional[str]:
     return None
 
 
-def _combine_plan(tok, sizes, tokens: int):
-    """``(chunks [3 Q], first [tiles + 1])`` int32, made on the device.
-    Inside a group the tokens ascend, so the rows of one group that land
-    in one tile of ``_COMBINE_TILE`` tokens are one run of consecutive
-    rows; a run is copied in chunks of ``_COMBINE_CHUNK`` rows from the
-    multiple of 8 at or under its first row.  Chunks are listed tile by
-    tile, group by group: for chunk ``q`` the row its copy starts at and
-    the first and one past the last of the copied rows that are the
-    run's, at ``chunks[3 q : 3 q + 3]``; tile ``i`` takes the chunks
-    ``first[i] <= q < first[i + 1]``.  Rows past ``sizes.sum()`` are in no
-    run."""
-    tt, ch = _COMBINE_TILE, _COMBINE_CHUNK
+def _run_edges(tok, sizes, tokens: int):
+    """``edge [tiles + 1, G]`` int32, made on the device.  Inside a group
+    the tokens ascend, so the rows of group ``g`` that land in tile ``i``
+    of ``_COMBINE_TILE`` tokens are one run of consecutive rows, ``edge[i,
+    g] <= r < edge[i + 1, g]``.  Rows past ``sizes.sum()`` are in no run.
+    The combine's plan and the dispatch's are made from it, and a chunk
+    that calls both makes it once (one expression, which XLA keeps
+    once)."""
+    tt = _COMBINE_TILE
     rows, G, tiles = tok.shape[0], sizes.shape[0], tokens // tt
     ends = jnp.cumsum(sizes.astype(jnp.int32))
     r = jnp.arange(rows, dtype=jnp.int32)
@@ -401,8 +422,21 @@ def _combine_plan(tok, sizes, tokens: int):
     key = jnp.where(r < ends[-1], group * tokens + tok, G * tokens)
     edges = (jnp.arange(tiles + 1, dtype=jnp.int32)[:, None] * tt
              + jnp.arange(G, dtype=jnp.int32)[None] * tokens).reshape(-1)
-    edge = (key[None] < edges[:, None]).sum(1).astype(jnp.int32).reshape(
+    return (key[None] < edges[:, None]).sum(1).astype(jnp.int32).reshape(
         tiles + 1, G)
+
+
+def _combine_plan(tok, sizes, tokens: int):
+    """``(chunks [3 Q], first [tiles + 1])`` int32, made on the device.
+    A run (:func:`_run_edges`) is copied in chunks of ``_COMBINE_CHUNK``
+    rows from the multiple of 8 at or under its first row.  Chunks are
+    listed tile by tile, group by group: for chunk ``q`` the row its copy
+    starts at and the first and one past the last of the copied rows that
+    are the run's, at ``chunks[3 q : 3 q + 3]``; tile ``i`` takes the
+    chunks ``first[i] <= q < first[i + 1]``."""
+    ch = _COMBINE_CHUNK
+    rows, G, tiles = tok.shape[0], sizes.shape[0], tokens // _COMBINE_TILE
+    edge = _run_edges(tok, sizes, tokens)
     lo, hi = edge[:-1].reshape(-1), edge[1:].reshape(-1)
     start = lo // 8 * 8
     n = jnp.where(hi > lo, (hi - start + ch - 1) // ch, 0)
@@ -515,3 +549,181 @@ def combine(rows, tok, sizes, out, name: str, fresh=False):
         interpret=_pallas.INTERPRET,
         name="hvd_moe_combine_" + name,
     )(tok.astype(jnp.int32), chunks, first, fresh, rows, out)
+
+
+# ---------------------------------------------------------- the dispatch
+
+def _dispatch_vmem(table, groups: int):
+    """``(block, scratch)`` bytes of VMEM a dispatch's grid step holds: a
+    tile of the table as it comes, and the words of that tile, of the
+    blocks staged and of the copies in flight."""
+    D, tt, blk = table.shape[1], _COMBINE_TILE, _DISPATCH_BLOCK
+    return (tt * D * table.dtype.itemsize,
+            (tt + (2 * groups + 1 + _DISPATCH_RING) * blk) * D * 2)
+
+
+def _dispatch_refusal(table, tok, sizes, dtype) -> Optional[str]:
+    """Which test keeps the Pallas dispatch off ``table[tok]``; None = it
+    runs.  ``table [N, D]``, ``tok [R]``, ``sizes [G]``, ``dtype`` the
+    rows'."""
+    if (why := _pallas.off_chip()):
+        return why
+    if table.ndim != 2 or tok.ndim != 1:
+        return "the table must be rank 2 and the token ids rank 1"
+    if (why := _pallas.dtype_refusal(table.dtype)):
+        return why
+    if dtype != jnp.bfloat16:
+        return f"rows of {jnp.dtype(dtype)} are not bfloat16"
+    if tok.shape[0] % _DISPATCH_BLOCK or table.shape[0] % _COMBINE_TILE:
+        return (f"{tok.shape[0]} rows are no multiple of {_DISPATCH_BLOCK} "
+                f"or {table.shape[0]} tokens none of {_COMBINE_TILE}")
+    if table.shape[1] % (2 * _LANES):
+        return f"width {table.shape[1]} is no multiple of {2 * _LANES}"
+    if tok.shape[0] * 4 > _COMBINE_SMEM:
+        return (f"{tok.shape[0]} token ids are over the {_COMBINE_SMEM} "
+                "bytes of scalar memory they may take")
+    block, scratch = _dispatch_vmem(table, sizes.shape[0])
+    if 2 * block + scratch > _STEP_VMEM:
+        return (f"a grid step needs {2 * block + scratch} bytes of VMEM, "
+                f"over the {_STEP_VMEM} a step may hold")
+    return None
+
+
+def _dispatch_plan(tok, sizes, tokens: int):
+    """``(edge [(tiles + 1) G], blocks [3 (G + 1)])`` int32, made on the
+    device: the runs' edges (:func:`_run_edges`), and for border ``g``
+    (where group ``g - 1`` ends and ``g`` starts; border ``G`` is the end
+    of the rows the sizes cover) at ``blocks[3 g : 3 g + 3]`` the row it
+    lies at, the first row of the block of ``_DISPATCH_BLOCK`` rows that
+    holds that row, and the first border of the same block: a block that
+    holds a border is staged once, under that border's number, whatever
+    groups its rows are of."""
+    edge = _run_edges(tok, sizes, tokens)
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    at = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    block = at // _DISPATCH_BLOCK * _DISPATCH_BLOCK
+    first = (block[:, None] > block[None]).sum(1)   # blocks ascend
+    return (edge.reshape(-1), jnp.stack(
+        [at, block, first], axis=1).reshape(-1).astype(jnp.int32))
+
+
+def _dispatch_kernel(tok, edge, blocks, table, rows, pairs, stage, buf, sem,
+                     sent, *, tt, G, blk, ring, unroll):
+    half = table.shape[1] // 2
+
+    def bits(x):               # rounded to bfloat16, in a word's high half
+        return lax.bitcast_convert_type(
+            x.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
+
+    # a row of a packed dtype cannot be read alone, so rows move as words
+    # of 32 bits: column c over column c + D / 2, half the sublanes (and
+    # half the VMEM) that float32 rows would take
+    pairs[...] = bits(table[:, :half]) | (bits(table[:, half:]) >> 16)
+    i = pl.program_id(0)
+    t0 = i * tt
+
+    @pl.when(i == 0)
+    def _():
+        sent[0] = 0
+
+    def copy(slot, row):
+        return pltpu.make_async_copy(
+            buf.at[slot], rows.at[pl.ds(pl.multiple_of(row, blk), blk)],
+            sem.at[slot])
+
+    def send(held, row):
+        """The staged block ``held`` to the rows from ``row`` on."""
+        slot = sent[0] % ring
+        pl.when(sent[0] >= ring)(lambda: copy(slot, row).wait())
+        words = stage[held]
+        value = lambda w: lax.bitcast_convert_type(w, jnp.float32).astype(
+            buf.dtype)
+        buf[slot, :, :half] = value(words & jnp.uint32(0xFFFF0000))
+        buf[slot, :, half:] = value(words << 16)
+        copy(slot, row).start()
+        sent[0] = sent[0] + 1
+
+    def run(g, _):
+        lo, hi = edge[i * G + g], edge[(i + 1) * G + g]
+        head, tail = blocks[3 * g + 1], blocks[3 * g + 4]
+
+        def block(b, _):
+            row = b * blk
+            # a block that holds a border waits for the other groups' rows
+            held = jnp.where(row == head, blocks[3 * g + 2], jnp.where(
+                row == tail, blocks[3 * g + 5], G + 1 + g))
+            s, e = jnp.maximum(lo, row), jnp.minimum(hi, row + blk)
+
+            def move(r, n):
+                # loads before stores, so that none waits for another's
+                ts = [tok[r + u] - t0 for u in range(n)]
+                got = [pairs[pl.ds(t, 1), :] for t in ts]
+                for u, x in enumerate(got):
+                    stage[held, pl.ds(r + u - row, 1), :] = x
+                return 0
+
+            many = (e - s) // unroll
+            lax.fori_loop(0, many, lambda m, _: move(s + m * unroll, unroll),
+                          0)
+            lax.fori_loop(s + many * unroll, e, lambda r, _: move(r, 1), 0)
+            pl.when((e == row + blk) & (held > G))(lambda: send(held, row))
+            return 0
+
+        return lax.fori_loop(lo // blk, jnp.where(
+            hi > lo, (hi + blk - 1) // blk, lo // blk), block, 0)
+
+    lax.fori_loop(0, G, run, 0)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        def border(g, _):
+            row = blocks[3 * g + 1]
+            # once a block, and not the one past the rows
+            pl.when((blocks[3 * g + 2] == g) & (row < blocks[3 * G]))(
+                lambda: send(g, row))
+            return 0
+
+        lax.fori_loop(0, G + 1, border, 0)
+        for slot in range(ring):
+            pl.when(slot < sent[0])(lambda slot=slot: copy(slot, 0).wait())
+
+
+def dispatch(table, tok, sizes, dtype, name: str):
+    """``rows [R, D]`` of ``dtype`` (bfloat16) with ``rows[r] =
+    table[tok[r]]`` for the rows the sizes cover, the combine's walk
+    turned around: ``table [N, D]`` bfloat16 or float32 (rounded on the
+    way), ``tok [R]`` int32 ascending inside a group, ``sizes [G]`` rows
+    each.  Rows past ``sizes.sum()`` are not written and hold whatever the
+    buffer held: the grouped products and the combine read none of them
+    into a result.  The kernel walks tiles of tokens: a tile comes through
+    VMEM once, each row of a run is moved to its place in a block of
+    ``_DISPATCH_BLOCK`` rows staged a group, and a block leaves for HBM
+    when its last row is in, a ring of copies in flight; the blocks that
+    hold rows of two groups or more leave last.  Elsewhere
+    (:func:`_dispatch_refusal`) XLA's gather, in the table's dtype."""
+    reason = _dispatch_refusal(table, tok, sizes, dtype)
+    if not _verdict("moe_dispatch", reason, table, tok):
+        _count("gather", "xla")
+        return table[tok]
+    _count("gather", "pallas")
+    (N, D), R, G = table.shape, tok.shape[0], sizes.shape[0]
+    tt, blk, ring = _COMBINE_TILE, _DISPATCH_BLOCK, _DISPATCH_RING
+    edge, blocks = _dispatch_plan(tok, sizes, N)
+    return pl.pallas_call(
+        functools.partial(_dispatch_kernel, tt=tt, G=G, blk=blk, ring=ring,
+                          unroll=_DISPATCH_UNROLL),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N // tt,),
+            in_specs=[pl.BlockSpec((tt, D), lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((tt, D // 2), jnp.uint32),
+                pltpu.VMEM((2 * G + 1, blk, D // 2), jnp.uint32),
+                pltpu.VMEM((ring, blk, D), dtype),
+                pltpu.SemaphoreType.DMA((ring,)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=_sds((R, D), dtype, table, tok, sizes),
+        compiler_params=_limit(*_dispatch_vmem(table, G)),
+        interpret=_pallas.INTERPRET,
+        name="hvd_moe_dispatch_" + name,
+    )(tok.astype(jnp.int32), edge, blocks, table)

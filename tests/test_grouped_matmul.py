@@ -272,6 +272,135 @@ def test_combine_fallback_adds_nothing_past_the_sizes():
     np.testing.assert_array_equal(got, ordered)
 
 
+# ----------------------------------------------------------- the dispatch
+
+DISPATCH_LAYOUTS = {
+    "an-empty-group-first": [[], _ALL[3::7], _ALL[::5], [1023]],
+    "an-empty-group-in-the-middle": COMBINE_LAYOUTS["an-empty-expert"],
+    "an-empty-group-last": [_ALL[3::7], _ALL[::5], _ALL[500:530], []],
+    "every-pair-in-one-group": COMBINE_LAYOUTS["every-pair-on-one-expert"],
+    "no-row-at-all": COMBINE_LAYOUTS["no-row-at-all"],
+    # group 0's run in the second tile of tokens is one row, token 512
+    "a-run-one-row-into-a-tile": [_ALL[400:513], _ALL[505:519], [511], [512]],
+    # borders at rows 3, 8, 21, 121, 122, 635: no multiple of 16 but 8
+    "borders-off-every-block": [[7, 8, 9], _ALL[500:505], _ALL[30:43],
+                                _ALL[460:560], [0], _ALL[1:1024:2] + [1022]],
+    "borders-on-blocks": [_ALL[:512], _ALL[16:48], _ALL[::4]],
+    "six-groups-in-one-block": [[1], [2], [3, 4], [], [5], [600], _ALL[9:90]],
+    # 1,536 pairs: the border after the last group is the end of the rows
+    "rows-to-the-last": COMBINE_LAYOUTS["rows-to-the-last"] + [_ALL[10:19]],
+}
+
+
+def _dispatch_operands(layout, dtype, rows=R, groups=7):
+    """The table, the rows' tokens (garbage past the groups' rows), the
+    sizes (empty groups after the layout's, up to ``groups``: one traced
+    kernel a dtype serves every layout) and how many rows they cover."""
+    tok = np.concatenate([np.sort(np.asarray(g, np.int64)) for g in layout]
+                         + [np.zeros(0, np.int64)])
+    rng = np.random.default_rng(len(tok))
+    p = len(tok)
+    tok = np.concatenate([tok, rng.integers(0, TOKENS, rows - p)])
+    table = jnp.asarray(rng.standard_normal((TOKENS, WIDTH)), dtype)
+    sizes = [len(g) for g in layout] + [0] * (groups - len(layout))
+    return table, jnp.asarray(tok, jnp.int32), jnp.asarray(sizes, jnp.int32), p
+
+
+@jax.jit
+def _dispatched(table, tok, sizes):
+    return gm.dispatch(table, tok, sizes, jnp.bfloat16, "t")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16-to-bf16", "float32-to-bf16"])
+@pytest.mark.parametrize("layout", DISPATCH_LAYOUTS.values(),
+                         ids=DISPATCH_LAYOUTS.keys())
+def test_dispatch_is_the_gather_on_the_rows_the_sizes_cover(
+        layout, dtype, pallas_interpret):
+    """``table[tok]`` in bfloat16, to the last bit, on every row the
+    sizes cover, whatever blocks of 32 rows the groups' borders fall in;
+    the cotangent's table is float32 and its rows leave rounded."""
+    table, tok, sizes, p = _dispatch_operands(layout, dtype)
+    before = _kernel_counts()
+    got = _dispatched(table, tok, sizes)
+    if metrics.ACTIVE:          # the kernel, traced once a dtype
+        assert _grew(before) in ({}, {("gather", "pallas"): 1})
+    assert got.shape == (R, table.shape[1]) and got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(got[:p], np.float32),
+        np.asarray(table[tok[:p]].astype(jnp.bfloat16), np.float32))
+
+
+def test_the_dispatchs_plan_stages_a_block_with_a_border_once():
+    """Borders at rows 0, 3, 3, 20, 36, 36 of blocks 0, 0, 0, 0, 32, 32:
+    the first border of each block names it."""
+    edge, blocks = gm._dispatch_plan(
+        jnp.asarray(np.r_[1, 2, 600, np.arange(17), np.arange(500, 516),
+                          np.zeros(28)], jnp.int32),
+        jnp.asarray([3, 0, 17, 16, 0], jnp.int32), 1024)
+    assert np.asarray(blocks).reshape(-1, 3).tolist() == [
+        [0, 0, 0], [3, 0, 0], [3, 0, 0], [20, 0, 0], [36, 32, 4],
+        [36, 32, 4]]
+    # group 0: two rows under token 512 and one over; group 3: twelve, four
+    assert np.asarray(edge).reshape(3, 5).tolist() == [
+        [0, 3, 3, 20, 36], [2, 3, 20, 32, 36], [3, 3, 20, 36, 36]]
+
+
+def test_rows_the_dispatch_leaves_unwritten_reach_no_result(pallas_interpret):
+    """The dispatch writes no row past the sizes' sum; with NaN there the
+    grouped products, their gradients and the combine stay finite, and
+    zero where a row is of no pair."""
+    E, D, F, rows = 4, WIDTH, 128, 512
+    table, tok, sizes, p = _dispatch_operands(
+        [_ALL[3::7], [], _ALL[::5], _ALL[500:530]], jnp.bfloat16, rows, E)
+    xs = jnp.where((jnp.arange(rows) < p)[:, None],
+                   _dispatched(table, tok, sizes), jnp.nan)
+    ks = jax.random.split(jax.random.key(3), 5)
+    wg, wu, wd = ((jax.random.normal(k, shape) * 0.1).astype(jnp.bfloat16)
+                  for k, shape in zip(ks, ((E, D, F), (E, D, F), (E, F, D))))
+    wt = jax.random.uniform(ks[3], (rows,), jnp.float32)
+    assert gm.supported(xs, wg, wu, wd)
+
+    @jax.jit
+    def consumers(xs):
+        ys = moe._expert_ffn(xs, wg, wu, wd, wt, sizes)
+        held = [jnp.zeros(w.shape, jnp.float32) for w in (wg, wu, wd)]
+        return (ys, gm.combine(ys, tok, sizes, jnp.zeros((TOKENS, D)), "t"),
+                moe._expert_ffn_grads(xs, wg, wu, wd, wt, sizes, xs, *held))
+
+    ys, out, grads = consumers(xs)
+    for a in (ys, out, *grads):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+    assert not np.asarray(ys[p:]).any() and not np.asarray(grads[0][p:]).any()
+
+
+@pytest.mark.parametrize("why,table,rows,reason", [
+    ("cpu-backend", (1024, 256, jnp.bfloat16), 1536, "backend"),
+    ("width-not-128", (1024, 96, jnp.bfloat16), 1536, "width 96 is no multiple of 256"),
+    ("width-128-not-256", (1024, 384, jnp.bfloat16), 1536, "no multiple of 256"),
+    ("half-precision", (1024, 256, jnp.float16), 1536, "neither"),
+    ("rows-of-float32", (1024, 256, jnp.float32), 1536, "not bfloat16"),
+    ("rows-no-block-multiple", (1024, 256, jnp.float32), 1000, "multiple of 32"),
+    ("tokens-no-tile-multiple", (1000, 256, jnp.float32), 1536, "none of 512"),
+    ("token-ids-past-the-smem", (1024, 256, jnp.bfloat16), 131072, "scalar memory"),
+    ("a-step-past-the-vmem", (1024, 16384, jnp.float32), 1536, "VMEM"),
+])
+def test_dispatch_refusals_fall_back_to_the_gather(why, table, rows, reason,
+                                                   pallas_interpret):
+    pallas_interpret(why != "cpu-backend")
+    t = jax.ShapeDtypeStruct(table[:2], table[2])
+    tok, sizes = (jax.ShapeDtypeStruct((rows,), jnp.int32),
+                  jax.ShapeDtypeStruct((4,), jnp.int32))
+    dtype = jnp.float32 if why == "rows-of-float32" else jnp.bfloat16
+    assert reason in gm._dispatch_refusal(t, tok, sizes, dtype)
+    before = _kernel_counts()
+    text = str(jax.make_jaxpr(lambda *a: gm.dispatch(*a, dtype, "t"))(
+        t, tok, sizes))
+    assert "gather" in text and "pallas_call" not in text
+    if metrics.ACTIVE:
+        assert _grew(before) == {("gather", "xla"): 1}
+
+
 # ------------------------------------------------ the expert layer on them
 
 def _layer_operands(dtype, D, F, E=4, k=2, n_tokens=1024, seed=0):
@@ -323,14 +452,16 @@ def test_held_experts_gradients_through_the_kernels(dtype, pallas_interpret):
     assert computed == pairs
     if metrics.ACTIVE:         # forward 3, made again 3, autodiff's 3 + 3
         assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3,
-                                 ("combine", "xla"): 2}
+                                 ("combine", "xla"): 2, ("gather", "xla"): 3}
     before = _kernel_counts()
     pallas_interpret()
     (got, computed), grads = grad()
     assert computed == pairs
     if metrics.ACTIVE:         # gate_up, down; gate_up, dh, dx; three tgmm
-        assert _grew(before) == {("gmm", "pallas"): 5, ("tgmm", "pallas"): 3,
-                                 ("combine", "pallas"): 2}
+        assert _grew(before) == {               # float32 rows are XLA's
+            ("gmm", "pallas"): 5, ("tgmm", "pallas"): 3,
+            ("combine", "pallas"): 2,
+            ("gather", "pallas" if dtype == jnp.bfloat16 else "xla"): 3}
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert abs(got - ref) <= tol * abs(ref) + tol
     for a, b in zip(grads, ref_grads):
@@ -348,7 +479,7 @@ def test_toy_widths_on_the_cpu_take_ragged_dot(pallas_interpret):
     jax.make_jaxpr(jax.grad(lambda *a: loss(*a)[0], (0, 1, 2, 3, 4)))(*args)
     if metrics.ACTIVE:
         assert _grew(before) == {("gmm", "xla"): 9, ("tgmm", "xla"): 3,
-                                 ("combine", "xla"): 2}
+                                 ("combine", "xla"): 2, ("gather", "xla"): 3}
 
 
 @pytest.mark.parametrize("why,rows,weights,reason", [
@@ -373,7 +504,8 @@ def test_refusals_name_their_reason(why, rows, weights, reason,
 
 # ----------------------------------------------------- for the chip
 
-@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+@pytest.mark.parametrize("call", ["forward", "backward", "combine",
+                                  "dispatch"])
 def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
     """Mosaic takes the expert layer's grouped products at the benchmark's
     SDAR cell: a chunk of 24,576 rows of width 2,048 over 16 held experts
@@ -387,7 +519,8 @@ def test_grouped_matmul_kernels_lower_for_the_chip(call, monkeypatch):
     _grouped_kernels_lower(call, monkeypatch, 24576, 2048, 768, 16, 16384)
 
 
-@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+@pytest.mark.parametrize("call", ["forward", "backward", "combine",
+                                  "dispatch"])
 def test_grouped_matmul_kernels_lower_at_a_hidden_size_of_4096(call, monkeypatch):
     """The same at the solar-open2-250b cell: a chunk of 2,560 rows of width
     4,096 over 8 held experts of width 1,280; ``tgmm`` walks its float32
@@ -397,7 +530,8 @@ def test_grouped_matmul_kernels_lower_at_a_hidden_size_of_4096(call, monkeypatch
     _grouped_kernels_lower(call, monkeypatch, 2560, 4096, 1280, 8, 8192)
 
 
-@pytest.mark.parametrize("call", ["forward", "backward", "combine"])
+@pytest.mark.parametrize("call", ["forward", "backward", "combine",
+                                  "dispatch"])
 def test_grouped_matmul_kernels_lower_at_the_most_rows_they_have_had(call, monkeypatch):
     """The same at the mellum2-12b-a2.5b cell: a chunk of 49,152 rows (half
     over the 32,768 pairs that even routing sends a layer) of width 2,304
@@ -429,6 +563,15 @@ def _grouped_kernels_lower(call, monkeypatch, R, D, F, E, tokens):
             sds((tokens, D), jnp.float32)).compile().as_text()
         names = ("hvd_moe_combine_out",)
         assert "scatter" not in text
+    elif call == "dispatch":
+        # the activations' table and the cotangent's, which leaves rounded
+        text = jax.jit(lambda a, b, tok, sizes: (
+            gm.dispatch(a, tok, sizes, jnp.bfloat16, "tokens"),
+            gm.dispatch(b, tok, sizes, jnp.bfloat16, "dout"))).lower(
+            sds((tokens, D), jnp.bfloat16), sds((tokens, D), jnp.float32),
+            sds((R,), jnp.int32), sizes).compile().as_text()
+        names = ("hvd_moe_dispatch_tokens", "hvd_moe_dispatch_dout")
+        assert "gather" not in text
     else:
         held = [sds(w.shape, jnp.float32) for w in (wg, wu, wd)]
         text = jax.jit(moe._expert_ffn_grads, donate_argnums=(7, 8, 9)).lower(

@@ -148,6 +148,64 @@ def test_layer_through_the_combine_kernel_is_the_scatter_adds(
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_layer_through_the_dispatch_kernel_is_the_gathers(
+        dtype, monkeypatch, pallas_interpret):
+    """The layer's output and its five gradients with a chunk's rows
+    gathered by the Pallas dispatch (interpreted; a width of 256, 1,024
+    tokens, so the grouped products are the kernels' too) against the same
+    kernels on ``tokens[tok]`` and ``dout[tok]``, to the last bit: a
+    gather moves bits, and the cotangent's rows are rounded to the rows'
+    dtype once on either path (float32 rows the kernel refuses: both
+    programs are XLA's gathers, and the counter says so).  The routing is
+    uneven: two experts take most pairs, one none, and two chunks are
+    walked."""
+    cfg = dataclasses.replace(CFG, d_model=256, d_ff=128, dtype=dtype)
+    lp = _params(cfg, seed=7)
+    lp["router"] = lp["router"].at[0, 3].add(0.5).at[0, 4].add(0.3).at[
+        0, 2].add(-2.0)
+    x = jax.random.normal(jax.random.key(8), (2, 512, cfg.d_model),
+                          dtype).at[..., 0].set(6.0)
+    t = jax.random.normal(jax.random.key(9), x.shape)
+
+    def run():
+        def loss(x, lp):
+            y, stats = moe.dropless_moe_layer(x, lp, cfg, PAR)
+            return (y * t).sum(), (y, stats)
+
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True))(x, lp)
+        return y, stats, grads
+
+    def gathers():
+        fam = metrics.registry().to_dict().get("hvd_moe_gmm_kernel_total", {})
+        return {s["labels"]["path"]: s["value"] for s in fam.get("series", [])
+                if s["labels"]["kernel"] == "gather"}
+
+    grew = lambda a, b: {k: b[k] - a.get(k, 0) for k in b if b[k] != a.get(k, 0)}
+    before = gathers()
+    y, stats, grads = run()
+    mid = gathers()
+    # the same program with the dispatch refused: XLA's gathers
+    monkeypatch.setattr(grouped_matmul, "_dispatch_refusal",
+                        lambda *a: "refused for the comparison")
+    y2, stats2, grads2 = run()
+    if metrics.ACTIVE:           # tokens forward; tokens and dout backward
+        assert grew(before, mid) == {
+            "pallas" if dtype == jnp.bfloat16 else "xla": 3}
+        assert grew(mid, gathers()) == {"xla": 3}
+    sizes = np.asarray(stats)
+    assert sizes[0] > moe._chunk_rows(1024, 2, 4, 8) and sizes[2] > 800
+    np.testing.assert_array_equal(stats, stats2)
+    np.testing.assert_array_equal(y, y2)
+    assert len(jax.tree_util.tree_leaves(grads)) == 5
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(grads2)):
+        assert np.asarray(a, np.float32).any()
+        np.testing.assert_array_equal(a, b)
+
+
 def test_chunk_rows_follow_even_routing_not_the_worst_case():
     # the cell: 16,384 positions, 8 of 128 a token, 16 held: 16,384 pairs
     # even, half as much again a chunk; the worst case is eight times that
