@@ -2,7 +2,9 @@
 one another: the SambaY family (arXiv 2507.06607; Phi-4-mini-flash), the
 Mamba-2 hybrids (GraniteMoeHybrid; the mixer from arXiv 2405.21060) and
 the delta-rule hybrids (Kimi Linear, arXiv 2510.26692: linear attention
-layers between gated softmax ones, routed experts in every layer).
+layers between gated softmax ones, routed experts in every layer) and the
+short-convolution hybrids (gated short convolutions between grouped-query
+attention layers with q/k norm).
 
 ``LlamaConfig.layer_kinds`` names each layer's kind; :mod:`.llama`'s
 ``init_params``, ``param_specs``, ``count_params`` and ``hidden`` come
@@ -18,7 +20,8 @@ is a layer's: ``W2 (silu(g) * v)``, ``[g ; v] = W1 z``, or, with
 ``cfg.n_experts``, routed experts that drop no token
 (``moe.dropless_moe_layer``: the experts this chip holds, scored by
 ``cfg.router_score``, a sigmoid's chosen with the layer's ``router_bias``,
-the chosen weights times ``cfg.routed_scaling_factor``)
+the chosen weights over their sum plus ``cfg.router_eps``, times
+``cfg.routed_scaling_factor``)
 plus, with
 ``cfg.n_shared_experts``, that same ``W1`` / ``W2`` as the expert every
 token passes through (``moe.shared_expert``), added once.  With experts,
@@ -69,7 +72,12 @@ default computes what the trunk computed before the config carried it.
   k^T + causal) v`` through ``W_o``, ``s = cfg.attention_multiplier`` (0 =
   ``1 / sqrt(Dh)``).  With ``cfg.attn_gate`` the heads' output is gated
   before ``W_o``: ``o * sigmoid(W_gate u)``, elementwise (the form G1 of
-  arXiv 2505.06708).
+  arXiv 2505.06708).  With ``cfg.qk_norm`` ``q`` and ``k`` are RMS-normed a
+  head before the rotation, ``q_norm`` and ``k_norm`` ``[Dh]`` every
+  head's (``llama._rmsnorm`` at ``cfg.norm_eps``): one pass with the
+  rotation (``ops/rope.norm_rotate``, a product each of ``wqkv``'s
+  columns) where that kernel takes the rows, heads of 128 on a TPU, else
+  XLA's form under ``hvd_rope``.
 * ``swa`` — the same layer under a window (Mellum 2's
   ``sliding_attention``): row ``i`` sees the last ``sliding_window`` keys
   with its own, ``[max(0, i - w + 1), i + 1)``.  One body serves both;
@@ -101,6 +109,13 @@ default computes what the trunk computed before the config carried it.
   (``local_attention(pair=)``: the split form; where the kernels refuse it
   the pair is joined into one query and key, the shared key copied a head).
   The rotation is XLA's form (``ops/rope.py`` turns whole heads of 128).
+* ``conv`` — the gated short convolution, gated on both sides: ``[B ;
+  C ; x] = W_in u``, three slices ``d_model`` wide in that order; ``g = B *
+  x``; ``c_t = sum_j w[j] g_(t - Kc + 1 + j)`` a channel, depthwise, causal,
+  ``ssm_conv`` taps, ``g`` zero before a row's first position, no bias, no
+  activation; the mixer gives ``W_out (C * c)``.  No state but the last
+  ``ssm_conv - 1`` positions and no positions of its own.  The elementwise
+  chain (:func:`gated_conv`) is float32 inside, the operands' dtype out.
 * ``kda`` — Kimi Delta Attention: ``[q ; k ; v] = silu(conv(W_qkv u))``,
   depthwise, causal, ``ssm_conv`` wide, no bias, in ``ssm_heads`` heads,
   keys ``ssm_state`` and values ``ssm_inner / ssm_heads`` wide; ``q`` and
@@ -132,6 +147,8 @@ again in the backward pass like any other, their scan included.
 
 ``hvd_layer_kind_total{kind}`` counts the layers traced; the mixers run
 under the scopes ``hvd_ssm_mixer``, ``hvd_gmu``, ``hvd_diff_attention``,
+``hvd_conv_mixer`` (``conv``: its norm and two products, the elementwise
+chain ``B * x``, taps, ``C *`` under ``hvd_gated_conv`` inside it),
 ``hvd_ssd_mixer`` (the scan's call inside it under ``hvd_ssd_scan``; the
 mixer's own kernels ``hvd_conv_silu_fwd`` / ``_bwd`` and
 ``hvd_gated_norm_fwd`` / ``_bwd`` under no scope of their own, rows of the
@@ -168,7 +185,8 @@ from ..ops.selective_scan import selective_scan
 from ..ops.ssd_scan import ssd_scan, ssd_scan_turned
 from ..ops.ssd_scan import supported as ssd_scan_supported
 from ..parallel.ring_attention import local_attention
-from ..scopes import (SCOPE_ATTENTION, SCOPE_DIFF_ATTENTION, SCOPE_GMU,
+from ..scopes import (SCOPE_ATTENTION, SCOPE_CONV_MIXER,
+                      SCOPE_DIFF_ATTENTION, SCOPE_GATED_CONV, SCOPE_GMU,
                       SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_MLA_ATTENTION,
                       SCOPE_MLA_LATENT, SCOPE_MLP, SCOPE_ROPE, SCOPE_SHARED,
                       SCOPE_SSD_MIXER, SCOPE_SSD_SCAN, SCOPE_SSM_MIXER,
@@ -183,9 +201,11 @@ from .llama import ParallelSpec, _rmsnorm, rope_table, rotate
 # ``cfg.attn_gate``), and ``swa`` (PR 46: ``attention``'s layer under the
 # window and ``hvd_window_attention``; either takes a rotary table), and
 # ``mla`` (PR 49: multi-head latent attention under ``hvd_mla_attention``,
-# its rotary part under the kind's own table)
+# its rotary part under the kind's own table), and ``conv`` (PR 53: the
+# gated short convolution under ``hvd_conv_mixer``; ``attention`` and
+# ``swa`` take ``cfg.qk_norm``)
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mamba2", "attention",
-         "kda", "swa", "mla")
+         "kda", "swa", "mla", "conv")
 _DIFFERENTIAL = ("window", "full", "cross")
 _PLAIN = {"attention": SCOPE_ATTENTION, "swa": SCOPE_WINDOW_ATTENTION}
 # the kinds that may have a rotary table, each with the scope the table is
@@ -303,6 +323,11 @@ def layer_shapes(cfg, kind, dense=False):
         shapes.update({"wqkv": (D, (H + 2 * Hkv) * Dh), "wo": (H * Dh, D)})
         if cfg.attn_gate:
             shapes["wgate"] = (D, H * Dh)
+        if cfg.qk_norm:
+            shapes.update({"q_norm": (Dh,), "k_norm": (Dh,)})
+    elif kind == "conv":
+        shapes.update({"in_proj": (D, 3 * D), "conv_w": (Kc, D),
+                       "out_proj": (D, D)})
     elif kind == "kda":
         Hs, Vd = cfg.ssm_heads, cfg.ssm_inner // cfg.ssm_heads
         conv = 2 * Hs * N + Di
@@ -390,7 +415,7 @@ def init_layers(cfg, key):
             k = jax.random.fold_in(jax.random.fold_in(key, a), b)
             full = (n,) + shape
             if name in ("norm1_w", "norm2_w", "subln", "D", "gate_norm",
-                        "o_norm", "kv_norm"):
+                        "o_norm", "kv_norm", "q_norm", "k_norm"):
                 leaf = jnp.ones(full, dt)
             elif name in ("norm1_b", "norm2_b", "conv_b", "router_bias"):
                 leaf = jnp.zeros(full, dt)
@@ -426,14 +451,21 @@ def _mlp(u, lp):
     return moe.shared_expert(u, lp["w1"], lp["w2"])
 
 
-def _conv_silu(xs, lp, Kc):
-    """``silu(conv(xs) + b)``: depthwise, causal, ``Kc`` wide, in float32;
-    back in ``xs``'s dtype."""
+def _taps(x32, w):
+    """The depthwise causal convolution of float32 ``x32 [B, T, C]`` under
+    the taps ``w [Kc, C]``: ``w[Kc - 1]`` on the position itself, zeros
+    before a row's first position."""
+    Kc, T = w.shape[0], x32.shape[1]
+    padded = jnp.pad(x32, ((0, 0), (Kc - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + T] * w[j].astype(jnp.float32)
+               for j in range(Kc))
+
+
+def _conv_silu(xs, lp):
+    """``silu(conv(xs) + b)``: depthwise, causal, ``ssm_conv`` wide, in
+    float32; back in ``xs``'s dtype."""
     f32 = jnp.float32
-    T = xs.shape[1]
-    padded = jnp.pad(xs.astype(f32), ((0, 0), (Kc - 1, 0), (0, 0)))
-    return jax.nn.silu(sum(padded[:, j:j + T] * lp["conv_w"][j].astype(f32)
-                           for j in range(Kc))
+    return jax.nn.silu(_taps(xs.astype(f32), lp["conv_w"])
                        + lp["conv_b"].astype(f32)).astype(xs.dtype)
 
 
@@ -443,7 +475,7 @@ def _mamba(u, lp, cfg):
     N, R = cfg.ssm_state, cfg.ssm_dt_rank
     f32 = jnp.float32
     xs, z = jnp.split(u @ lp["in_proj"], 2, axis=-1)
-    xs = _conv_silu(xs, lp, cfg.ssm_conv)
+    xs = _conv_silu(xs, lp)
     r, Bm, Cm = jnp.split(xs @ lp["x_proj"], (R, R + N), axis=-1)
     delta = jax.nn.softplus(
         jnp.dot(r, lp["dt_proj"], preferred_element_type=f32)
@@ -520,6 +552,82 @@ def _kda(u, lp, cfg):
                           + cfg.norm_eps) * lp["o_norm"].astype(f32)
     gate = jax.nn.sigmoid(((u @ lp["g_a"]) @ lp["g_b"]).astype(f32))
     return (o32.reshape(gate.shape) * gate).astype(u.dtype) @ lp["wo"]
+
+
+def gated_conv(b, c, x, w):
+    """``c * conv(b * x)``, the gated short convolution's elementwise chain:
+    ``b``, ``c``, ``x`` ``[B, T, D]``, ``w [Kc, D]`` the taps a channel,
+    ``w[Kc - 1]`` on the position itself; depthwise, causal, ``b * x`` zero
+    before a row's first position, no bias and no activation; in float32,
+    back in ``x``'s dtype."""
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_GATED_CONV):
+        return (c.astype(f32) * _taps(b.astype(f32) * x.astype(f32), w)
+                ).astype(x.dtype)
+
+
+def _conv_mixer(u, lp):
+    """The gated short convolution's output ``[B, T, D]``."""
+    b, c, x = jnp.split(u @ lp["in_proj"], 3, axis=-1)
+    return gated_conv(b, c, x, lp["conv_w"]) @ lp["out_proj"]
+
+
+def _normed_qkv(u, lp, rope, cfg):
+    """``(q, k, v)`` a head of the ``attention`` and ``swa`` kinds under
+    ``cfg.qk_norm``: ``q`` and ``k`` RMS-normed a head (``q_norm``,
+    ``k_norm`` ``[Dh]``, every head's) and then rotated where the kind has
+    a table.  Where ``ops/rope.norm_rotate`` takes the rows (a TPU, heads
+    of 128) norm and rotation are its one pass on a product each of
+    ``wqkv``'s columns, as in the llama trunk; else XLA's form."""
+    B, T, _ = u.shape
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    ends = (H * Dh, (H + Hkv) * Dh)
+    rows = lambda n: jax.ShapeDtypeStruct((B, T, n * Dh), u.dtype)
+    if rope is not None and all(
+            _rotary.norm_supported(rows(n), lp[w], *rope)
+            for n, w in ((H, "q_norm"), (Hkv, "k_norm"))):
+        W = lp["wqkv"]
+        with jax.named_scope(SCOPE_ROPE):
+            q = _rotary.norm_rotate(u @ W[:, :ends[0]], lp["q_norm"], *rope,
+                                    cfg.norm_eps)
+            k = _rotary.norm_rotate(u @ W[:, ends[0]:ends[1]], lp["k_norm"],
+                                    *rope, cfg.norm_eps)
+        v = u @ W[:, ends[1]:]
+    else:
+        q, k, v = jnp.split(u @ lp["wqkv"], ends, axis=-1)
+        with jax.named_scope(SCOPE_ROPE):
+            q = _rmsnorm(q.reshape(B, T, H, Dh), lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k.reshape(B, T, Hkv, Dh), lp["k_norm"], cfg.norm_eps)
+            if rope is not None:
+                _rotary.count_xla(normed=True)
+                q, k = rotate(q, *rope), rotate(k, *rope)
+    return (q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh),
+            v.reshape(B, T, Hkv, Dh))
+
+
+def _plain_qkv(u, lp, rope, cfg):
+    """``(q, k, v)`` a head of the ``attention`` and ``swa`` kinds, ``q``
+    and ``k`` rotated where the kind has a table."""
+    B, T, _ = u.shape
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qkv = u @ lp["wqkv"]
+    widths = (H * Dh, Hkv * Dh, Hkv * Dh)
+    joined = rope is not None and _rotary.supported(qkv, *rope, widths)
+    if joined:
+        # the kernel reads the product's columns where they lie and writes
+        # q, k (rotated) and v each whole: no slice before it, and backward
+        # no concatenate
+        with jax.named_scope(SCOPE_ROPE):
+            q, k, v = _rotary.split_rotate(qkv, *rope, widths,
+                                           (True, True, False))
+    else:
+        q, k, v = jnp.split(qkv, (H * Dh, (H + Hkv) * Dh), axis=-1)
+    q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh)
+    if rope is not None and not joined:
+        _rotary.count_xla()
+        with jax.named_scope(SCOPE_ROPE):
+            q, k = rotate(q, *rope), rotate(k, *rope)
+    return q, k, v.reshape(B, T, Hkv, Dh)
 
 
 def latent_attention(u, lp, rope, cfg):
@@ -646,30 +754,18 @@ def _layer(kind, emits, cfg, dense=False):
         elif kind == "mla":
             with jax.named_scope(SCOPE_MLA_ATTENTION):
                 y = latent_attention(norm1(), lp, rope, cfg)
+        elif kind == "conv":
+            with jax.named_scope(SCOPE_CONV_MIXER):
+                y = _conv_mixer(norm1(), lp)
         elif kind in _PLAIN:
             with jax.named_scope(_PLAIN[kind]):
                 u = norm1()
-                qkv = u @ lp["wqkv"]
-                widths = (H * Dh, Hkv * Dh, Hkv * Dh)
-                joined = rope is not None and _rotary.supported(
-                    qkv, *rope, widths)
-                if joined:
-                    # the kernel reads the product's columns where they
-                    # lie and writes q, k (rotated) and v each whole:
-                    # no slice before it, and backward no concatenate
-                    with jax.named_scope(SCOPE_ROPE):
-                        q, k, v = _rotary.split_rotate(
-                            qkv, *rope, widths, (True, True, False))
+                if cfg.qk_norm:
+                    q, k, v = _normed_qkv(u, lp, rope, cfg)
                 else:
-                    q, k, v = jnp.split(qkv, (H * Dh, (H + Hkv) * Dh),
-                                        axis=-1)
-                q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, Hkv, Dh)
-                if rope is not None and not joined:
-                    _rotary.count_xla()
-                    with jax.named_scope(SCOPE_ROPE):
-                        q, k = rotate(q, *rope), rotate(k, *rope)
+                    q, k, v = _plain_qkv(u, lp, rope, cfg)
                 o = local_attention(
-                    q, k, v.reshape(B, T, Hkv, Dh),
+                    q, k, v,
                     sm_scale=cfg.attention_multiplier or None,
                     mask=key_ranges(kind, T, cfg)).reshape(B, T, H * Dh)
                 if cfg.attn_gate:

@@ -122,7 +122,8 @@ class LlamaConfig:
     # n_heads passes head_dim=0 to have it worked out again)
     head_dim: int = 0
     # RMSNorm with a learned weight over each head of q and of k, before
-    # RoPE
+    # RoPE (here, and in the "attention" and "swa" kinds of a trunk of
+    # several kinds)
     qk_norm: bool = False
     # False: an output head ``params["head"] [V, D]`` of its own
     tie_embeddings: bool = True
@@ -145,14 +146,17 @@ class LlamaConfig:
     # renormalised, times this (dropless experts alone; 1.0 = none).  And
     # its ``first_k_dense_replace``: that many leading layers of a trunk of
     # several kinds keep a dense feed-forward, ``dense_d_ff`` wide (0 =
-    # ``d_ff``), before the routed ones (0 = every layer routed)
+    # ``d_ff``), before the routed ones (0 = every layer routed).
+    # ``router_eps`` is added to the chosen scores' sum before they are
+    # divided by it (dropless experts alone; 0.0 = nothing is added)
     routed_scaling_factor: float = 1.0
+    router_eps: float = 0.0
     first_dense_layers: int = 0
     dense_d_ff: int = 0
     # a trunk whose layers are of several kinds (models/hybrid.py): each
     # layer's kind, "mamba" | "window" | "full" | "gmu" | "cross" |
-    # "mamba2" | "attention" | "kda" | "swa" | "mla" (() = the trunk of
-    # identical layers here),
+    # "mamba2" | "attention" | "kda" | "swa" | "mla" | "conv" (() = the
+    # trunk of identical layers here),
     # and its index in the published model (() = its place in the trunk);
     # the window of the "window" kind's attention; the state-space
     # mixers' inner width, states (a channel for "mamba", a head's
@@ -174,7 +178,8 @@ class LlamaConfig:
     # latent: keys and values come from a latent ``kv_lora_rank`` wide
     # (normed), a head's scores are a ``qk_nope_head_dim``-wide product with
     # its own keys plus a ``qk_rope_head_dim``-wide one with the one rotary
-    # key every head shares, its values ``v_head_dim`` wide.
+    # key every head shares, its values ``v_head_dim`` wide.  "conv" is the
+    # gated short convolution, ``ssm_conv`` taps a channel of ``d_model``.
     layer_kinds: tuple = ()
     layer_ids: tuple = ()
     sliding_window: int = 0
@@ -224,10 +229,10 @@ class LlamaConfig:
                 "n_shared_experts, attn_gate, rope_tables and "
                 "first_dense_layers are wired through the trunk of several "
                 "kinds (layer_kinds) alone")
-        if (self.routed_scaling_factor != 1.0
+        if ((self.routed_scaling_factor != 1.0 or self.router_eps)
                 and self.moe_dispatch != "dropless"):
-            raise ValueError("routed_scaling_factor is the dropless "
-                             "experts' (moe_dispatch='dropless')")
+            raise ValueError("routed_scaling_factor and router_eps are the "
+                             "dropless experts' (moe_dispatch='dropless')")
         multipliers = (self.embedding_multiplier, self.residual_multiplier,
                        self.attention_multiplier, self.logits_scaling)
         if not self.layer_kinds and multipliers != (1.0, 1.0, 0.0, 1.0):

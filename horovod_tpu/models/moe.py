@@ -393,7 +393,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 SCORES = ("softmax", "sigmoid")
 
 
-def route(tokens, router, k, score="softmax", bias=None, scaling=1.0):
+def route(tokens, router, k, score="softmax", bias=None, scaling=1.0,
+          eps=0.0):
     """``(expert ids [N, k], weights [N, k] float32)``: every one of the
     router's outputs scored in float32, by ``score``: ``"softmax"`` over
     all of them, or ``"sigmoid"`` of each alone (DeepSeek-V3's); the ``k``
@@ -401,8 +402,9 @@ def route(tokens, router, k, score="softmax", bias=None, scaling=1.0):
     the largest ``score + bias``; the weights are the chosen experts'
     scores (without the bias: it moves the choice and nothing else),
     renormalised over those ``k`` whether their experts are held here or
-    not, times ``scaling`` (DeepSeek-V3's ``routed_scaling_factor``; at 1.0
-    nothing is multiplied).  The bias gets no gradient."""
+    not (their sum plus ``eps``; at 0.0 nothing is added), times
+    ``scaling`` (DeepSeek-V3's ``routed_scaling_factor``; at 1.0 nothing
+    is multiplied).  The bias gets no gradient."""
     if score not in SCORES:
         raise ValueError(f"score must be one of {SCORES}, got {score!r}")
     logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
@@ -414,7 +416,8 @@ def route(tokens, router, k, score="softmax", bias=None, scaling=1.0):
     else:
         _, top_i = lax.top_k(scores + bias.astype(jnp.float32), k)
         top_p = jnp.take_along_axis(scores, top_i, axis=-1)
-    top_w = top_p / top_p.sum(axis=-1, keepdims=True)
+    total = top_p.sum(axis=-1, keepdims=True)
+    top_w = top_p / (total + eps if eps else total)
     return top_i, top_w if scaling == 1.0 else top_w * scaling
 
 
@@ -451,7 +454,7 @@ def dropless_moe_layer(x, lp, cfg, par):
     with jax.named_scope(SCOPE_ROUTE):
         top_i, top_w = route(tokens, lp["router"], k, cfg.router_score,
                              lp.get("router_bias"),
-                             cfg.routed_scaling_factor)
+                             cfg.routed_scaling_factor, cfg.router_eps)
     with jax.named_scope(SCOPE_EXPERTS):
         local = (top_i - cfg.experts_first).reshape(-1)
         e = jnp.where((local >= 0) & (local < held), local, held)

@@ -36,6 +36,8 @@ SCOPE_WINDOW_ATTENTION = "hvd_window_attention"  # plain GQA under the window ("
 SCOPE_ROPE = "hvd_rope"            # inside either (or hvd_mla_attention): a kind's rotary table and its products with q and k
 SCOPE_MLA_ATTENTION = "hvd_mla_attention"  # latent attention ("mla"): norm1, projections, rotation, kernels, wo
 SCOPE_MLA_LATENT = "hvd_mla_latent"  # inside it: u Wkv_a, the split, the latent's norm, c Wkv_b
+SCOPE_CONV_MIXER = "hvd_conv_mixer"  # the gated short convolution ("conv"): norm1, in_proj, the chain, out_proj
+SCOPE_GATED_CONV = "hvd_gated_conv"  # inside it: B * x, the taps and C *, the elementwise chain alone
 # Inside ``hvd_mlp`` where the feed-forward is routed (``models/moe.py``)
 SCOPE_ROUTE = "hvd_moe_route"        # router logits, scores, top-k
 SCOPE_EXPERTS = "hvd_moe_experts"    # sort, grouped products, combine

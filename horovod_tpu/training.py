@@ -39,7 +39,8 @@ from .scopes import (  # noqa: F401
     SCOPE_ATTENTION, SCOPE_MLP, SCOPE_HEAD, SCOPE_STEM, SCOPE_STAGE,
     SCOPE_SSM_MIXER, SCOPE_GMU, SCOPE_DIFF_ATTENTION, SCOPE_SSD_MIXER,
     SCOPE_SSD_SCAN, SCOPE_KDA_MIXER, SCOPE_KDA_SCAN, SCOPE_WINDOW_ATTENTION,
-    SCOPE_ROPE, SCOPE_MLA_ATTENTION, SCOPE_MLA_LATENT)
+    SCOPE_ROPE, SCOPE_MLA_ATTENTION, SCOPE_MLA_LATENT, SCOPE_CONV_MIXER,
+    SCOPE_GATED_CONV)
 
 
 @dataclasses.dataclass

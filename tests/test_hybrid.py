@@ -645,3 +645,226 @@ def test_routed_scaling_factor_multiplies_the_chosen_weights():
     np.testing.assert_array_equal(i1, i2)
     np.testing.assert_allclose(w2, w1 * 2.448, rtol=1e-6)
     np.testing.assert_allclose(w1.sum(-1), 1.0, rtol=1e-6)
+
+
+# ------------------------------------- the gated short convolution (PR 53)
+
+def _conv_cfg(**kw):
+    base = dict(d_model=64, n_heads=4, n_kv_heads=2, n_layers=5, d_ff=32,
+                dense_d_ff=96, first_dense_layers=1, ssm_conv=3,
+                layer_kinds=("conv", "attention", "conv", "conv", "conv"),
+                layer_ids=(0, 2, 3, 4, 5), trunk_norm="rmsnorm", qk_norm=True,
+                n_experts=8, expert_top_k=2, experts_held=4,
+                moe_dispatch="dropless", router_score="sigmoid",
+                router_eps=1e-6,
+                rope_tables=(("attention", llama.RopeTable(theta=1e6)),))
+    return _cfg(**{**base, **kw})
+
+
+def test_the_gated_convolutions_taps_follow_a_hand_written_loop():
+    """``c_t = sum_j w[j] (B x)_(t - 2 + j)`` a channel, zero before the
+    row's first position, times ``C``: a loop in float32, in the order the
+    chain is written, to the bit where each operation is its own program;
+    jitted, XLA contracts a multiply and an add, within two units."""
+    B, T, D, Kc = 2, 16, 8, 3
+    r = jax.random.split(jax.random.key(0), 4)
+    b, c, x = (np.asarray(jax.random.normal(k, (B, T, D))) for k in r[:3])
+    w = np.asarray(jax.random.normal(r[3], (Kc, D)))
+    f32, want = np.float32, np.zeros((B, T, D), np.float32)
+    for n in range(B):
+        for t in range(T):
+            acc = np.zeros((D,), f32)
+            for j in range(Kc):
+                s = t - (Kc - 1) + j
+                g = f32(b[n, s] * x[n, s]) if s >= 0 else np.zeros((D,), f32)
+                acc = f32(acc + f32(g * w[j]))
+            want[n, t] = f32(c[n, t] * acc)
+    np.testing.assert_array_equal(hybrid.gated_conv(b, c, x, w), want)
+    np.testing.assert_allclose(jax.jit(hybrid.gated_conv)(b, c, x, w), want,
+                               rtol=3e-7, atol=1e-6)
+    # in bfloat16 the chain is float32 inside and rounds once
+    low = hybrid.gated_conv(*(jnp.asarray(a, jnp.bfloat16) for a in (b, c, x)), w)
+    assert low.dtype == jnp.bfloat16
+    r16 = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(
+        low, hybrid.gated_conv(r16(b), r16(c), r16(x), w).astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "routed"])
+def test_a_convolution_layer_leaks_neither_across_rows_nor_from_later(dense):
+    """Row 1 perturbed, row 0 does not move; positions past ``t``
+    perturbed, positions up to ``t`` do not move: to the bit, through the
+    whole layer (norm, ``in_proj``, the chain, ``out_proj``, the
+    feed-forward); and the layer does look two positions back, no
+    further."""
+    cfg, T, t = _conv_cfg(), 32, 11
+    stack = ("dense_" if dense else "") + "conv"
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0], llama.init_params(cfg, jax.random.key(0))["layers"][stack])
+    assert set(lp) >= {"in_proj", "conv_w", "out_proj"} and lp["conv_w"].shape == (3, 64)
+    assert lp["in_proj"].shape == (64, 192) and "conv_b" not in lp
+    layer = jax.jit(lambda h: hybrid._layer("conv", False, cfg, dense)(
+        h, lp, 0.0, None)[0])
+    h = jax.random.normal(jax.random.key(1), (2, T, 64))
+    noise = jax.random.normal(jax.random.key(2), (2, T, 64))
+    base = np.asarray(layer(h))
+    other_row = np.asarray(layer(h.at[1].add(noise[1])))
+    np.testing.assert_array_equal(other_row[0], base[0])
+    assert np.abs(other_row[1] - base[1]).max() > 0.1
+    later = np.asarray(layer(h.at[:, t + 1:].add(noise[:, t + 1:])))
+    np.testing.assert_array_equal(later[:, :t + 1], base[:, :t + 1])
+    assert np.abs(later[:, t + 1] - base[:, t + 1]).max() > 0.1
+    # one position moved: it, the next and the one after see it, no other
+    one = np.asarray(layer(h.at[:, t].add(noise[:, t])))
+    moved = np.abs(one - base).max(axis=(0, 2)) > 0
+    assert moved.nonzero()[0].tolist() == [t, t + 1, t + 2]
+
+
+def test_a_trunk_of_convolutions_and_one_normed_attention_layer(monkeypatch):
+    """The tree (``dense_conv`` by ``first_dense_layers``, q/k norm's two
+    leaves in the ``attention`` kind alone), the runs (a routed layer a run
+    of its own), the count, the kinds counted, the statistics of the four
+    routed layers, XLA's normed rotation counted once."""
+    cfg = _conv_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert list(params["layers"]) == ["attention", "conv", "dense_conv"]
+    conv, attn = params["layers"]["conv"], params["layers"]["attention"]
+    routed = {"router", "router_bias", "we_gate", "we_up", "we_down"}
+    frame = {"norm1_w", "norm2_w"}
+    assert set(conv) == frame | routed | {"in_proj", "conv_w", "out_proj"}
+    assert set(attn) == frame | routed | {"wqkv", "wo", "q_norm", "k_norm"}
+    assert set(params["layers"]["dense_conv"]) == frame | {
+        "in_proj", "conv_w", "out_proj", "w1", "w2"}
+    assert conv["conv_w"].shape == (3, 3, 64) and attn["q_norm"].shape == (1, 16)
+    assert (np.asarray(attn["k_norm"]) == 1).all()
+    assert llama.count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    assert [(s, at, ids) for s, at, ids, _ in hybrid._runs(cfg)] == [
+        ("dense_conv", 0, [0]), ("attention", 0, [2]), ("conv", 0, [3]),
+        ("conv", 1, [4]), ("conv", 2, [5])]
+    monkeypatch.setattr(metrics, "ACTIVE", True)
+    series = lambda family: metrics.registry().to_dict().get(
+        family, {}).get("series", [])
+    kinds = lambda: {s["labels"]["kind"]: s["value"]
+                     for s in series("hvd_layer_kind_total")}
+    normed = lambda: sum(
+        s["value"] for s in series("hvd_rope_kernel_total")
+        if s["labels"] == {"kernel": "norm_fwd", "path": "xla"})
+    before, xla = kinds(), normed()
+    h = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    step = jax.jit(lambda h, ls: hybrid.layer_stack(h, ls, cfg, with_stats=True))
+    out, stats = step(h, params["layers"])
+    assert kinds()["conv"] - before.get("conv", 0) == 4
+    assert kinds()["attention"] - before.get("attention", 0) == 1
+    assert normed() - xla == 1
+    pairs, rows, fullest, layers = np.asarray(stats)
+    assert layers == 4 and pairs == rows and 0 < fullest <= pairs
+    assert np.isfinite(np.asarray(out)).all()
+    text = step.lower(h, params["layers"]).compile().as_text()
+    for scope in ("hvd_conv_mixer", "hvd_conv_mixer/hvd_gated_conv",
+                  "hvd_attention/hvd_rope", "hvd_mlp/hvd_moe_route"):
+        assert scope in text, scope
+    # q/k norm is these two kinds' and no leaf of another's
+    assert "q_norm" not in hybrid.layer_shapes(cfg, "conv")
+    assert "q_norm" not in hybrid.layer_shapes(_latent_cfg(qk_norm=True), "mla")
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_qk_norm_in_the_attention_kind_norms_a_head_before_the_rotation(
+        path, pallas_interpret):
+    """One ``attention`` layer under ``cfg.qk_norm`` against the formula
+    written out, forward and every gradient: ``q`` and ``k`` RMS-normed a
+    head by ``q_norm`` / ``k_norm`` (not at 1 here), then rotated.  On XLA's
+    path at heads of 64; where ``ops/rope.norm_rotate`` takes the rows
+    (heads of 128, interpreted here) through its one pass."""
+    from horovod_tpu.ops import rope as rotary
+    pallas_interpret(path == "kernel")
+    heads, kv, dh = (2, 1, 128) if path == "kernel" else (4, 2, 64)
+    T, B, table = 64, 2, llama.RopeTable(theta=1e6)
+    cfg = _cfg(layer_kinds=("attention",), trunk_norm="rmsnorm", qk_norm=True,
+               d_model=heads * dh, n_heads=heads, n_kv_heads=kv,
+               rope_tables=(("attention", table),))
+    lp = jax.tree_util.tree_map(
+        lambda w: w[0], llama.init_params(cfg, jax.random.key(0))["layers"]["attention"])
+    lp["q_norm"] = 1.0 + 0.3 * jax.random.normal(jax.random.key(3), (dh,))
+    lp["k_norm"] = 1.0 + 0.3 * jax.random.normal(jax.random.key(4), (dh,))
+    h = jax.random.normal(jax.random.key(1), (B, T, heads * dh))
+    w = jax.random.normal(jax.random.key(2), (B, T, heads * dh))
+    rope = llama.rope_table(table, dh, T)
+    assert rotary.norm_supported(
+        jax.ShapeDtypeStruct((B, T, heads * dh), h.dtype), lp["q_norm"],
+        *rope) == (path == "kernel")
+    cos, sin = (t[None, :, None] for t in rope)
+    live = fa.dense_mask(fa.causal_ranges(T), T)
+
+    def normed_rot(x, weight):
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * weight
+        x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+    def dense(h, lp):
+        u = hybrid.norm(h, lp["norm1_w"], None, cfg)
+        q, k, v = jnp.split(u @ lp["wqkv"], (heads * dh, (heads + kv) * dh), -1)
+        q = normed_rot(q.reshape(B, T, heads, dh), lp["q_norm"])
+        k = normed_rot(k.reshape(B, T, kv, dh), lp["k_norm"])
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, heads // kv, 2)) * dh ** -0.5
+        p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), -1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p,
+                       jnp.repeat(v.reshape(B, T, kv, dh), heads // kv, 2))
+        a = h + o.reshape(B, T, heads * dh) @ lp["wo"]
+        return a + hybrid._mlp(hybrid.norm(a, lp["norm2_w"], None, cfg), lp)
+
+    layer = hybrid._layer("attention", False, cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda h, lp: (layer(h, lp, 0.0, None, rope)[0] * w).sum(), (0, 1)))(h, lp)
+    want = jax.jit(jax.value_and_grad(
+        lambda h, lp: (dense(h, lp) * w).sum(), (0, 1)))(h, lp)
+    assert abs(float(got[0] - want[0])) < 1e-4 * max(float(jnp.abs(want[0])), 1.0)
+    assert set(got[1][1]) == set(lp) and float(jnp.abs(got[1][1]["q_norm"]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()),
+                                   rtol=2e-3)
+
+
+def test_qk_norm_off_lowers_the_plain_kinds_to_the_parents_text():
+    """A ``swa`` and an ``attention`` layer with routed experts, q/k norm
+    off and ``router_eps`` 0.0: the trunk's lowered text is the parent
+    commit's (a596a6b, jax 0.9.0, on the CPU)."""
+    import hashlib
+    cfg = _mellum_cfg(layer_kinds=("swa", "attention"), n_layers=2, n_experts=8,
+                      expert_top_k=2, experts_held=4, moe_dispatch="dropless",
+                      router_score="sigmoid")
+    assert not cfg.qk_norm and cfg.router_eps == 0.0
+    layers = jax.eval_shape(lambda k: llama.init_params(cfg, k)["layers"],
+                            jax.random.key(0))
+    assert not {"q_norm", "k_norm"} & set(layers["attention"])
+    text = jax.jit(lambda h, ls: hybrid.layer_stack(
+        h, ls, cfg, with_stats=True)).lower(
+            jax.ShapeDtypeStruct((2, 128, H * DH), jnp.float32), layers).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "2f751bd468bcac0f"
+
+
+def test_router_eps_is_added_to_the_chosen_sum_and_at_zero_to_nothing():
+    """``route(eps=0.0)`` is today's to the bit and to the jaxpr; at 1e-6
+    the chosen scores are divided by their sum plus it, which float32
+    holds at four sigmoid scores; the field is the dropless experts'."""
+    from horovod_tpu.models import moe
+    tokens = jax.random.normal(jax.random.key(0), (64, 64))
+    router = jax.random.normal(jax.random.key(1), (64, 32)) * 0.1
+    bias = jnp.zeros((32,))
+    i0, w0 = moe.route(tokens, router, 4, "sigmoid", bias)
+    i1, w1 = moe.route(tokens, router, 4, "sigmoid", bias, eps=0.0)
+    np.testing.assert_array_equal(w0, w1)
+    scores = jax.nn.sigmoid(jnp.dot(tokens, router, precision="highest"))
+    top = jnp.take_along_axis(scores, i0, -1)
+    np.testing.assert_array_equal(w0, top / top.sum(-1, keepdims=True))
+    jaxpr = lambda **kw: str(jax.make_jaxpr(lambda t, r, b: moe.route(
+        t, r, 4, "sigmoid", b, **kw))(tokens, router, bias))
+    assert jaxpr() == jaxpr(eps=0.0) != jaxpr(eps=1e-6)
+    i2, w2 = moe.route(tokens, router, 4, "sigmoid", bias, eps=1e-6)
+    np.testing.assert_array_equal(i2, i0)
+    np.testing.assert_array_equal(w2, top / (top.sum(-1, keepdims=True) + 1e-6))
+    assert float(jnp.abs(w2 - w0).max()) > 0 and float(w2.sum(-1).max()) < 1.0
+    with pytest.raises(ValueError, match="router_eps"):
+        llama.LlamaConfig(router_eps=1e-6)

@@ -457,7 +457,7 @@ def test_models_take_their_scope_names_from_a_leaf_not_from_training(tmp_path):
         "from horovod_tpu import scopes, training\n"
         "from horovod_tpu.models import moe\n"
         "names = [n for n in dir(scopes) if n.startswith('SCOPE_')]\n"
-        "assert len(names) == 24, names\n"
+        "assert len(names) == 26, names\n"
         "for n in names:\n"
         "    home = moe if 'moe' in getattr(scopes, n) else training\n"
         "    assert getattr(home, n) is getattr(scopes, n), n\n"
