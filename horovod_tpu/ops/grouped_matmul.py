@@ -28,7 +28,11 @@ over write zeros to them, one store a tile, or do nothing.  The grid is
   group at a time, added to a float32 ``[G, K, N]`` accumulator that is
   the call's input and, aliased, its output: a group's block stays in
   VMEM over its visits and is written once; a group without rows keeps
-  what it held.
+  what it held.  How much of the block one straight-line body adds is
+  chosen from the shapes for speed (:func:`_tgmm_slab`, the numbers at
+  ``_TGMM_BODY``): a visit whose whole product is over the line walks the
+  block's rows in a loop, every element still one product over the
+  tile's rows added once, so the sums are the whole walk's to the bit.
 
 And what follows the products, :func:`combine`
 (``hvd_moe_combine_<name>``): ``out[tok[r]] += rows[r]``, the rows back
@@ -85,10 +89,32 @@ from ._pallas import (LANES as _LANES, STEP_VMEM as _STEP_VMEM, sds as _sds,
 # visit: 99 visits of 84 tiles, where 512 rows make 57 of 42; 128 rows are
 # no faster), tgmm at 512 (it adds a float32 [K, N] block in VMEM a visit:
 # 6.4 us a visit at 256 rows against 4.1 of MXU time, 10.1 against 8.2 at
-# 512; at 1,024, or with two accumulators a call, a visit takes three
-# times as long)
+# 512)
 _TILE = 256
 _TGMM_TILE = 512
+# The 128^3 products (K/128 x N/128 x 4 at 512 rows) that one straight-line
+# body of tgmm may hold.  Mosaic unrolls a visit's product, and a body over
+# the line runs at a quarter of the MXU whatever it computes; tgmm alone, 8
+# groups of 2,065 rows, 40 visits, bf16, "MXU us" the call's products at
+# 197 TFLOP/s over its visits (my chip runs, PR 54):
+#
+#   accumulator [K, N]        products  us a visit  MXU us
+#   2048 x 768  (SDAR)           384       11.4       6.6
+#   2304 x 896  (Mellum)         504       14.4       8.6
+#   2048 x 1280 (Solar's block)  640       48.8      11.0
+#   2048 x 1536                  768       57.0      13.2
+#   2048 x 1792 (LFM2)           896       65.5      15.4
+#   the same, cut by hand to 1024 x 1792 (448): 13.0 for 7.7; as two such
+#   dots written one after the other (unrolled: 896 again): 65.1; as two
+#   passes of a lax.fori_loop (448 a pass): 24.2; 4 of 512 rows 24.9, 8 of
+#   256 26.0, 16 of 128 26.8; a grid of two blocks 24.6
+#
+# and the transposes read the same to 0.3 us.  So it is the length of the
+# unrolled body, not the product's float32 temporary (two dots of half the
+# size in one body are as slow as one) and not HBM: the line lies between
+# 504 and 640, and what PR 30 met at 1,024 rows a tile, or with two
+# accumulators a call, was this (768 either way).
+_TGMM_BODY = 512
 # the combine: tokens a grid step holds, rows a copy brings, copies in flight
 _COMBINE_TILE = 512
 _COMBINE_CHUNK = 32
@@ -168,6 +194,25 @@ def _tgmm_split(K: int, N: int, item: int) -> int:
                 ) <= _STEP_VMEM:
             return split
     return 0
+
+
+def _tgmm_slab(kb: int, N: int) -> int:
+    """How many rows of a ``[kb, N]`` block (``x``'s columns) one pass of a
+    visit's loop adds in :func:`tgmm`: the most that divide the block in
+    whole numbers of lanes and keep the pass under ``_TGMM_BODY`` products;
+    ``kb`` = the visit makes its product at once, with no loop (2,048 x
+    768, 2,304 x 896 and their transposes; 1,024 of 2,048 x 1,792)."""
+    per = (N // _LANES) * (_TGMM_TILE // _LANES)    # products, 128 rows
+    return max((s for s in range(_LANES, kb + 1, _LANES)
+                if kb % s == 0 and s // _LANES * per <= _TGMM_BODY),
+               default=_LANES)
+
+
+@functools.lru_cache(maxsize=None)
+def _say_walk(K: int, N: int, split: int, slab: int) -> None:
+    _pallas.logger.info(
+        "tgmm walks a [%d, %d] accumulator in %d block(s) of %d rows, %d "
+        "rows a pass", K, N, split, K // split, slab)
 
 
 def supported(rows, *weights) -> bool:
@@ -310,19 +355,28 @@ def gmm(body, rows, weights, sizes, outs, name: str):
     )(table, bounds, *rows, *weights)
 
 
-def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm, axis):
+def _tgmm_kernel(tbl, bounds, x_ref, y_ref, held, acc, *, tm, axis, slab):
     flags, whole, mask = _visit(tbl, bounds, tm, axis)
     pair = flags % 4 == 1
     opens = flags >= 8                 # the group's first visit
+    kb = acc.shape[1]
 
     def add(first, masked):
         # other groups' rows, and whatever lies past the last, reach no
         # product from either side
         keep = (lambda a: jnp.where(mask(), a, jnp.zeros_like(a))) \
             if masked else (lambda a: a)
-        acc[0] = (held if first else acc)[0] + lax.dot_general(
-            keep(x_ref[...]), keep(y_ref[...]), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+        def piece(at):                 # the block's rows ``at``
+            acc[0, at] = (held if first else acc)[0, at] + lax.dot_general(
+                keep(x_ref[:, at]), keep(y_ref[...]),
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+        if slab == kb:
+            piece(slice(None))
+        else:                          # a loop Mosaic does not unroll
+            lax.fori_loop(0, kb // slab, lambda i, _: piece(
+                pl.ds(pl.multiple_of(i * slab, slab), slab)), None)
 
     for first in (True, False):
         for masked in (True, False):
@@ -342,12 +396,15 @@ def tgmm(x, y, sizes, acc, name: str):
     float32, given up to the call (the output takes its place).  An
     accumulator too large for a grid step is walked in blocks of its rows
     (``x``'s columns), the visits once a block: a grid ``(blocks,
-    visits)``."""
+    visits)``; a block whose product is too long a body to run fast is
+    added :func:`_tgmm_slab` rows a pass of a loop inside the visit."""
     R, K, N, tm = x.shape[0], x.shape[1], y.shape[1], _TGMM_TILE
     table, bounds, V = _plan(sizes, R, tm)
     _count("tgmm", "pallas")
     split = _tgmm_split(K, N, x.dtype.itemsize) or 1
     kb = K // split
+    slab = _tgmm_slab(kb, N)
+    _say_walk(K, N, split, slab)
     # an index map's arguments: the grid's ids, then table and bounds
     at = (lambda a: (0, a[0], a[-2])) if split == 1 else (
         lambda a: (a[0], a[1], a[-2]))
@@ -364,14 +421,15 @@ def tgmm(x, y, sizes, acc, name: str):
 
     held = pl.BlockSpec((1, kb, N), group)
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm, axis=int(split > 1)),
+        functools.partial(_tgmm_kernel, tm=tm, axis=int(split > 1),
+                          slab=slab),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(V,) if split == 1 else (split, V),
             in_specs=[row(kb, True), row(N, False), held], out_specs=held),
         out_shape=_sds(acc.shape, jnp.float32, sizes, x, y, acc),
         input_output_aliases={4: 0},
         compiler_params=_limit(tm * (kb + N) * x.dtype.itemsize
-                               + 2 * kb * N * 4, kb * N * 4,
+                               + 2 * kb * N * 4, slab * N * 4,
                                1 + int(split > 1)),
         interpret=_pallas.INTERPRET,
         name="hvd_moe_tgmm_" + name,

@@ -6,6 +6,8 @@ the ``ragged_dot`` path's; which path a program's shapes take; and that
 Mosaic takes the kernels at the benchmark's shapes, compiled for a v5e
 that is described, not attached."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,32 +60,52 @@ def _check_rows(y, x, w, sizes, dtype):
     assert not np.asarray(y[total:], np.float32).any()    # zero, not garbage
 
 
+@functools.lru_cache(maxsize=None)
+def _gmm_of(dtype, transposed):
+    """One product a group, traced and compiled once a dtype."""
+    return jax.jit(lambda x, w, s: gm.gmm(
+        lambda a, b: (gm.dot(a, b, transposed=transposed),), (x,), (w,), s,
+        [(N, dtype)], "t"))
+
+
 @pytest.mark.parametrize("sizes,dtype", CASES)
 def test_gmm_is_ragged_dot(sizes, dtype, pallas_interpret):
     x, w, sizes = _operands(sizes, dtype)
-    y, = jax.jit(lambda x, w, s: gm.gmm(
-        lambda a, b: (gm.dot(a, b),), (x,), (w,), s, [(N, dtype)], "t"))(
-            x, w, sizes)
+    y, = _gmm_of(dtype, False)(x, w, sizes)
     _check_rows(y, x, w, sizes, dtype)
 
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
 def test_gmm_reads_weights_transposed(sizes, dtype, pallas_interpret):
     x, w, sizes = _operands(sizes, dtype, transposed=True)
-    y, = jax.jit(lambda x, w, s: gm.gmm(
-        lambda a, b: (gm.dot(a, b, transposed=True),), (x,), (w,), s,
-        [(N, dtype)], "t"))(x, w, sizes)
+    y, = _gmm_of(dtype, True)(x, w, sizes)
     _check_rows(y, x, w.swapaxes(1, 2), sizes, dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _tgmm_walk(walk):
+    """``tgmm`` jitted once a walk, so traced and compiled once a dtype
+    and width: under what that walk's first caller had patched, and every
+    caller of a walk patches alike."""
+    return jax.jit(lambda *a: gm.tgmm(*a, walk))
+
+
+def _tgmm_operands(sizes, dtype, wide=1):
+    """``x`` (NaN past the sizes' sum, ``wide`` times the ``K`` columns),
+    ``y``, sizes and what the accumulator held."""
+    x, _, sizes = _operands(sizes, dtype)
+    x = jnp.concatenate([x, -x[:, ::-1]][:wide], axis=1)
+    live = (np.arange(R) < int(sizes.sum()))[:, None]
+    y = jnp.where(live, jax.random.normal(jax.random.key(1), (R, N)),
+                  jnp.nan).astype(dtype)
+    return x, y, sizes, jax.random.normal(jax.random.key(3),
+                                          (G, wide * K, N))
 
 
 @pytest.mark.parametrize("sizes,dtype", CASES)
 def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, pallas_interpret):
-    x, _, sizes = _operands(sizes, dtype)
-    live = (np.arange(R) < int(sizes.sum()))[:, None]
-    y = jnp.where(live, jax.random.normal(jax.random.key(1), (R, N)),
-                  jnp.nan).astype(dtype)
-    held = jax.random.normal(jax.random.key(3), (G, K, N))
-    out = jax.jit(lambda *a: gm.tgmm(*a, "t"))(x, y, sizes, held)
+    x, y, sizes, held = _tgmm_operands(sizes, dtype)
+    out = _tgmm_walk("whole")(x, y, sizes, held)
     assert out.dtype == jnp.float32
     ends = np.cumsum(np.asarray(sizes))
     for g, (lo, hi) in enumerate(zip(ends - np.asarray(sizes), ends)):
@@ -94,6 +116,12 @@ def test_tgmm_adds_each_groups_product_to_what_it_held(sizes, dtype, pallas_inte
             np.testing.assert_array_equal(out[g], held[g])   # kept to the bit
 
 
+def _room(kb, item):
+    """The VMEM a grid step needs for a block of ``kb`` rows of the toy
+    accumulator (:func:`gm._tgmm_split`'s sum)."""
+    return 2 * (2 * kb * N * 4 + 2 * gm._TGMM_TILE * max(kb, N) * item)
+
+
 @pytest.mark.parametrize("sizes,dtype", CASES)
 def test_tgmm_walks_a_large_accumulator_in_blocks_of_its_rows(
         sizes, dtype, pallas_interpret, monkeypatch):
@@ -101,22 +129,61 @@ def test_tgmm_walks_a_large_accumulator_in_blocks_of_its_rows(
     (4,096 x 1,280 on the chip; here the step's room is cut to force it) is
     walked in two blocks of its rows over a grid (blocks, visits), to the
     same sums."""
-    x, _, sizes = _operands(sizes, dtype)
-    y = jax.random.normal(jax.random.key(1), (R, N)).astype(dtype)
-    held = jax.random.normal(jax.random.key(3), (G, K, N))
-    whole = jax.jit(lambda *a: gm.tgmm(*a, "t"))(x, y, sizes, held)
+    operands = _tgmm_operands(sizes, dtype)
+    whole = _tgmm_walk("whole")(*operands)
     item = jnp.dtype(dtype).itemsize
     assert gm._tgmm_split(K, N, item) == 1
-    room = 2 * (2 * K // 2 * N * 4 + 2 * gm._TGMM_TILE * max(K // 2, N) * item)
-    monkeypatch.setattr(gm, "_STEP_VMEM", room)
+    monkeypatch.setattr(gm, "_STEP_VMEM", _room(K // 2, item))
     assert gm._tgmm_split(K, N, item) == 2
-    halves = jax.jit(lambda *a: gm.tgmm(*a, "t2"))(x, y, sizes, held)
+    halves = _tgmm_walk("halves")(*operands)
     np.testing.assert_allclose(halves, whole, atol=1e-3, rtol=1e-4)
     # the benchmark's two shapes: SDAR's whole, Solar's in two blocks
     monkeypatch.undo()
     assert gm._tgmm_split(2048, 768, 2) == gm._tgmm_split(768, 2048, 2) == 1
     assert gm._tgmm_split(4096, 1280, 2) == gm._tgmm_split(1280, 4096, 2) == 2
     assert gm._tgmm_split(8192, 2048, 4) == 8 and gm._tgmm_split(16384, 4096, 4) == 0
+
+
+@pytest.mark.parametrize("blocks", [1, 2], ids=["a-block", "two-blocks"])
+@pytest.mark.parametrize("sizes,dtype", CASES)
+def test_tgmm_adds_a_long_product_in_passes_of_a_loop(
+        sizes, dtype, blocks, pallas_interpret, monkeypatch):
+    """A block whose product is over ``_TGMM_BODY`` (896 products at 2,048
+    x 1,792 on the chip; here the line is cut to one pass of 128 rows) is
+    added a slab of its rows a pass of a loop inside the visit: every
+    element the same product over the tile's rows, added once, so the sums
+    are the unlooped walk's to the bit; in a grid of two blocks (Solar's
+    walk) as in one.  On whole numbers: the CPU's product at another width
+    takes its sums in another order (the MXU's does not: bit-equal at the
+    LFM2 cell's shapes, my chip runs, PR 54)."""
+    x, y, sizes, held = _tgmm_operands(sizes, dtype, wide=blocks)
+    operands = (jnp.round(2 * x), jnp.round(2 * y), sizes, jnp.round(8 * held))
+    item, wide = jnp.dtype(dtype).itemsize, blocks * K
+    if blocks == 2:
+        monkeypatch.setattr(gm, "_STEP_VMEM", _room(K, item))
+    assert gm._tgmm_split(wide, N, item) == blocks
+    assert gm._tgmm_slab(K, N) == K
+    at_once = _tgmm_walk(f"at-once-{blocks}")(*operands)
+    monkeypatch.setattr(gm, "_TGMM_BODY", gm._TGMM_TILE // 128)
+    assert gm._tgmm_slab(K, N) == 128
+    looped = _tgmm_walk(f"looped-{blocks}")(*operands)
+    np.testing.assert_array_equal(looped, at_once)
+
+
+@pytest.mark.parametrize("K,N,split,slab", [
+    (2048, 768, 1, 2048), (768, 2048, 1, 768),          # SDAR, kanana
+    (2304, 896, 1, 2304), (896, 2304, 1, 896),          # Mellum
+    (4096, 1280, 2, 1024), (1280, 4096, 2, 128),        # Solar
+    (2048, 1792, 1, 1024), (1792, 2048, 1, 896),        # LFM2
+    (2048, 1536, 1, 1024), (256, 128, 1, 256), (128, 8192, 1, 128),
+])
+def test_a_visits_body_holds_the_products_that_run_fast(K, N, split, slab):
+    """The benchmark's five accumulators and their transposes: 384 and 504
+    products a visit are made at once, as before PR 54; 640 (Solar's block)
+    and 896 (LFM2) in passes of at most ``_TGMM_BODY``, the largest slab
+    that divides the block in whole lanes."""
+    assert gm._tgmm_split(K, N, 2) == split
+    assert gm._tgmm_slab(K // split, N) == slab
 
 
 def test_a_body_of_two_products_and_an_epilogue(pallas_interpret):
@@ -191,6 +258,11 @@ def _combine_operands(layout, carry):
     return rows, tok, sizes, held, p
 
 
+@jax.jit
+def _combined(rows, tok, sizes, held, fresh):
+    return gm.combine(rows, tok, sizes, held, "t", fresh=fresh)
+
+
 @pytest.mark.parametrize("carry", [False, True, "fresh"],
                          ids=["zero", "a-carry", "fresh"])
 @pytest.mark.parametrize("layout", COMBINE_LAYOUTS.values(),
@@ -204,10 +276,10 @@ def test_combine_is_scatter_add(layout, carry, pallas_interpret):
     rows, tok, sizes, held, p = _combine_operands(layout, carry is True)
     before = _kernel_counts()
     fresh = carry == "fresh"       # said fresh, what is held is never read
-    got = np.asarray(jax.jit(lambda *a: gm.combine(*a[:4], "t", fresh=a[4]))(
+    got = np.asarray(_combined(
         rows, tok, sizes, held + np.float32("nan") if fresh else held, fresh))
-    if metrics.ACTIVE:
-        assert _grew(before) == {("combine", "pallas"): 1}
+    if metrics.ACTIVE:          # the kernel, traced once
+        assert _grew(before) in ({}, {("combine", "pallas"): 1})
     ordered = held.copy()
     np.add.at(ordered, tok[:p], rows[:p])
     np.testing.assert_array_equal(got, ordered)
@@ -541,6 +613,18 @@ def test_grouped_matmul_kernels_lower_at_the_most_rows_they_have_had(call, monke
     assert moe._chunk_rows(16384, 8, 16, 64) == 49152
     assert gm._tgmm_split(2304, 896, 2) == gm._tgmm_split(896, 2304, 2) == 1
     _grouped_kernels_lower(call, monkeypatch, 49152, 2304, 896, 16, 16384)
+
+
+@pytest.mark.parametrize("call", ["forward", "backward", "combine",
+                                  "dispatch"])
+def test_grouped_matmul_kernels_lower_at_the_widest_experts(call, monkeypatch):
+    """The same at the lfm2-8b-a1b cell: a chunk of 24,576 rows of width
+    2,048 over 8 held experts of width 1,792; ``tgmm``'s float32 ``[2048,
+    1792]`` and ``[1792, 2048]`` accumulators, 14.7 MB, fit a grid step to
+    the byte and are walked whole, a visit's 896 products in two passes of
+    a loop (a slice of ``x``'s lanes at a traced multiple of the slab)."""
+    assert moe._chunk_rows(16384, 4, 8, 32) == 24576
+    _grouped_kernels_lower(call, monkeypatch, 24576, 2048, 1792, 8, 16384)
 
 
 def _grouped_kernels_lower(call, monkeypatch, R, D, F, E, tokens):

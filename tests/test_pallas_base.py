@@ -135,6 +135,8 @@ CALLS = {
         "backward", 2560, 4096, 1280, 8, 8192),
     "experts-solar-combine": lambda: _experts(
         "combine", 2560, 4096, 1280, 8, 8192),
+    "experts-lfm2-backward": lambda: _experts(
+        "backward", 24576, 2048, 1792, 8, 16384),
     "selective-scan": _selective_scan,
     "ssd-scan": _ssd_scan,
     "kda-scan": _kda_scan,
@@ -213,12 +215,25 @@ HANDED = {
         ("hvd_moe_gmm_gate_up", (AR,), 78118912),
         ("hvd_moe_gmm_dh", (AR,), 61083648),
         ("hvd_moe_gmm_dx", (AR,), 82313216),
-        ("hvd_moe_tgmm_gate", (AR, AR), 76021760),
-        ("hvd_moe_tgmm_up", (AR, AR), 76021760),
-        ("hvd_moe_tgmm_down", (AR, AR), 78905344),
+        # a block's product in passes (``gm._tgmm_slab``: 1,024 rows of
+        # 2,048 x 1,280, 128 of 640 x 4,096): a pass's float32 tile, not
+        # the block's, among the temporaries
+        ("hvd_moe_tgmm_gate", (AR, AR), 70778880),
+        ("hvd_moe_tgmm_up", (AR, AR), 70778880),
+        ("hvd_moe_tgmm_down", (AR, AR), 70516736),
     ],
     "experts-solar-combine": [
         ("hvd_moe_combine_out", (AR,), 54525952),
+    ],
+    # the [2048, 1792] accumulator in and out and twice is 58.7 MB of each
+    # tgmm's sum, a pass of 1,024 (896) rows of it 7.3 more
+    "experts-lfm2-backward": [
+        ("hvd_moe_gmm_gate_up", (AR,), 58195968),
+        ("hvd_moe_gmm_dh", (AR,), 49025024),
+        ("hvd_moe_gmm_dx", (AR,), 60293120),
+        ("hvd_moe_tgmm_gate", (AR,), 90701824),
+        ("hvd_moe_tgmm_up", (AR,), 90701824),
+        ("hvd_moe_tgmm_down", (AR,), 90701824),
     ],
     "selective-scan": [
         ("hvd_ssm_scan_fwd", (PA, AR, AR), 64 * MiB),
